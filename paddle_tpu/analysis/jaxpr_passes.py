@@ -30,10 +30,12 @@ findings baseline/suppress/format exactly like AST ones.
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from .findings import (ERROR, INFO, WARNING, Finding, Location,
                        rule_severity)
@@ -73,13 +75,10 @@ def _nbytes(aval) -> int:
 
 def _trail(eqn, limit: int = 3) -> tuple:
     """User-source frames for an equation, innermost first."""
-    try:
-        from jax._src import source_info_util
-        frames = list(source_info_util.user_frames(eqn.source_info))
-        return tuple((f.file_name, f.start_line, f.function_name)
-                     for f in frames[:limit])
-    except Exception:
-        return ()
+    from jax._src import source_info_util
+    frames = source_info_util.user_frames(eqn.source_info.traceback)
+    return tuple((f.file_name, f.start_line, f.function_name)
+                 for f in itertools.islice(frames, limit))
 
 
 def _eqn_loc(name, eqn) -> Location:
@@ -131,7 +130,7 @@ def _donation_pass(spec, jaxpr, invar_info, findings):
     out_slots = {}
     invar_set = set(map(id, jx.invars))
     for v in jx.outvars:
-        if isinstance(v, jax.core.Literal) or id(v) in invar_set:
+        if isinstance(v, Literal) or id(v) in invar_set:
             continue
         key = (tuple(v.aval.shape), str(v.aval.dtype))
         out_slots[key] = out_slots.get(key, 0) + 1
@@ -197,7 +196,7 @@ def _sweep_dead(eqns, live):
     for eqn in reversed(eqns):
         if {id(v) for v in eqn.outvars} & live:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     live.add(id(v))
         else:
             dead.append(eqn)
@@ -230,13 +229,13 @@ def _dead_pass(spec, jaxpr, invar_info, findings):
                 continue
             for sub in _sub_jaxprs(eqn):
                 sub_live = {id(v) for v in sub.outvars
-                            if not isinstance(v, jax.core.Literal)}
+                            if not isinstance(v, Literal)}
                 sweep(sub, sub_live,
                       f"the `{eqn.primitive.name}` body in {spec.name!r}")
         return live
 
     live = sweep(jx, {id(v) for v in jx.outvars
-                      if not isinstance(v, jax.core.Literal)}, repr(spec.name))
+                      if not isinstance(v, Literal)}, repr(spec.name))
     outvar_ids = {id(v) for v in jx.outvars}
     for v, (argnum, path, _) in zip(jx.invars, invar_info):
         if id(v) not in live and id(v) not in outvar_ids:

@@ -5,7 +5,8 @@ layer provides the flag registry, TCPStore rendezvous, stat gauges and the
 dataloader prefetch ring.  pybind11 is not available in this image, so the
 binding is a plain C ABI + ctypes.
 
-The library is built on demand from csrc/ (g++ is part of the toolchain);
+The library is built from csrc/ by ``make`` at first load (g++ is part of
+the toolchain), which also rebuilds a binary older than its sources;
 `available()` reports whether the native core is loaded, and pure-Python
 fallbacks exist for the flag registry (core.flags) so import never fails.
 """
@@ -39,14 +40,18 @@ class NativeError(RuntimeError):
 
 
 def _build() -> bool:
+    """Bring the library up to date with csrc/.  ``make`` decides: its
+    rule lists the sources, so a binary older than them (the file is
+    git-ignored and survives on disk across commits) is rebuilt and an
+    up-to-date one costs a stat per source."""
     if not (_CSRC / "Makefile").exists():
-        return False
+        return _LIB_PATH.exists()
     try:
         subprocess.run(["make", "-C", str(_CSRC)], check=True,
                        capture_output=True, timeout=180)
-        return _LIB_PATH.exists()
     except (subprocess.SubprocessError, OSError):
         return False
+    return _LIB_PATH.exists()
 
 
 def _configure(lib):
@@ -112,7 +117,7 @@ def load():
         if os.environ.get("PADDLE_TPU_DISABLE_NATIVE"):
             _load_failed = True
             return None
-        if not _LIB_PATH.exists() and not _build():
+        if not _build():
             _load_failed = True
             return None
         try:

@@ -140,7 +140,7 @@ def x64_scope(*dtype_likes):
     """
     import contextlib
 
-    from .jaxcompat import enable_x64
+    from jax import enable_x64
 
     for d in dtype_likes:
         if d is None:

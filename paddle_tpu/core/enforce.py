@@ -101,12 +101,12 @@ _XLA_HINTS = (
      "reduce batch/sequence length, enable remat "
      "(HybridParallelConfig.remat), shard optimizer state (zero_stage>=1), "
      "or add tp/pp axes"),
-    ("DEADLINE_EXCEEDED", "a device operation timed out — on a tunneled "
-     "runtime check the tunnel; multi-host, suspect a desynchronized "
-     "collective (see FLAGS_comm_watchdog_timeout)"),
-    ("UNAVAILABLE", "the backend/plugin is unreachable — verify "
-     "JAX_PLATFORMS and that the TPU runtime is up; probe in a subprocess "
-     "as bench.py:_probe_backend does"),
+    ("DEADLINE_EXCEEDED", "a device operation timed out — multi-host, "
+     "suspect a desynchronized collective (see "
+     "FLAGS_comm_watchdog_timeout)"),
+    ("UNAVAILABLE", "the backend is unreachable — verify JAX_PLATFORMS, "
+     "and that no other process holds the chip (it belongs to one "
+     "process at a time)"),
     ("UNIMPLEMENTED", "XLA cannot lower this op on the current backend — "
      "check dtype (x64 is off by default) and dynamic-shape use"),
     ("INTERNAL", "an XLA/Mosaic compiler fault — if a Pallas kernel is "

@@ -178,7 +178,7 @@ def _partial_sum_prog(jm, spec, reduce_axes):
     if prog is None:
         from jax import lax
 
-        from ...core.jaxcompat import shard_map
+        from jax import shard_map
         # check_vma=False: the "replicated" input really carries per-device
         # partial values; psum performs the pending reduction
         prog = jax.jit(shard_map(lambda x: lax.psum(x, reduce_axes),

@@ -19,9 +19,13 @@ decoder-like config, not just the in-tree LLaMA::
 """
 from __future__ import annotations
 
-# Per-chip hardware constants (v5e-class defaults; override per call).
-DEFAULT_HBM_BYTES = 16e9          # v5e: 16 GB HBM
-DEFAULT_PEAK_FLOPS = 197e12      # v5e: 197 bf16 TFLOP/s
+from ...core.runtime import MODELED_DEVICE, device_peaks
+
+# The chip the tuner plans for, by name, from the published table (a
+# caller planning for another names it per call; a device that is not in
+# the table is an error there).
+DEFAULT_HBM_BYTES = device_peaks(MODELED_DEVICE)["hbm_bytes"]
+DEFAULT_PEAK_FLOPS = device_peaks(MODELED_DEVICE)["bf16_flops"]
 DEFAULT_ICI_BYTES_PER_S = 4.5e10  # v5e: ~45 GB/s per ICI link direction
 
 

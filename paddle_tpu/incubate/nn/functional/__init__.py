@@ -457,7 +457,7 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
         if use_pallas:
             # walk the block table page-by-page (scalar prefetch) — no
             # dense [B, nblk*bs] gather materializes; q joins the cache
-            # dtype (the probe compiled for that combination)
+            # dtype (the combination the eligibility check saw)
             out = _pa.paged_decode_attention(q.astype(kc.dtype), kc, vc,
                                              bt, t + 1)
         else:
@@ -512,9 +512,8 @@ def block_multihead_attention(qkv, key_cache, value_cache, seq_lens_encoder,
         use_pallas = bool(
             get_flag("use_pallas_kernels")
             and (_pa.interpret_mode() or jax.default_backend() == "tpu")
-            and _pa.supports(B, Hc, Hc, Dh, bs,
-                             nblk=int(_arr(block_tables).shape[1]),
-                             dtype=_arr(key_cache).dtype))
+            and _pa.ineligible(Hc, Hc, Dh, bs,
+                               _arr(key_cache).dtype) is None)
         out, kc2, vc2 = D_.apply(
             "block_multihead_attention_decode", decode_impl,
             (qkv, key_cache, value_cache, block_tables, seq_lens_decoder,

@@ -59,8 +59,7 @@ def _norm_core(x, weight, bias, eps, kind):
     """Dispatch one rms/layer-norm op, Pallas-routed when eligible."""
     if kind == "rms":
         if (get_flag("use_pallas_kernels") and weight is not None
-                and rms_norm_fused.supports(x.shape, x.dtype.name,
-                                            w_dtype_name=weight.dtype.name)):
+                and rms_norm_fused.supports(x.shape, x.dtype.name)):
             return D.apply("fused_rms_norm", rms_norm_fused, (x, weight),
                            {"eps": float(eps)})
         def impl(x, *rest, eps, has_w):
@@ -72,8 +71,7 @@ def _norm_core(x, weight, bias, eps, kind):
     else:
         if (get_flag("use_pallas_kernels") and weight is not None
                 and bias is not None
-                and layer_norm_fused.supports(x.shape, x.dtype.name,
-                                              w_dtype_name=weight.dtype.name)):
+                and layer_norm_fused.supports(x.shape, x.dtype.name)):
             return D.apply("fused_layer_norm", layer_norm_fused,
                            (x, weight, bias), {"eps": float(eps)})
         def impl(x, *rest, eps, has_w, has_b):

@@ -10,7 +10,14 @@ bridge):
 
     tiny       2-layer toy (vocab 256) — starts in seconds, CPU-friendly
     llama-sm   ~8-layer small config — a realistic serving shape
-    llama-7b   the full 7B config — TPU-sized
+    llama-7b   LLaMA-7B widths; 32 layers do not fit one 16 GB chip, so
+               cut depth with --layers and hold weights in --dtype
+               bfloat16 (8 layers: 3.8 GB of weights, held twice while
+               the engine keeps its layer-stacked copy)
+
+The process computes on whatever device JAX resolves, and says which on
+its start-up line together with the attention and matmul paths the
+engine chose.  Landing on the CPU without JAX_PLATFORMS=cpu is an error.
 
 SIGINT/SIGTERM trigger a graceful drain: admissions stop (503),
 in-flight streams finish, the engine thread parks, then the process
@@ -20,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
 import os
 import signal
 import sys
@@ -37,9 +45,8 @@ def _ensure_host_devices(n: int) -> None:
         os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
 
 
-def _build_engine(args):
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from ..serving import LLMEngine
+def _model_config(args):
+    from paddle_tpu.models.llama import LlamaConfig
 
     if args.model == "tiny":
         cfg = LlamaConfig.tiny(vocab=256, hidden=64, layers=2, heads=4,
@@ -55,13 +62,48 @@ def _build_engine(args):
             cfg.max_position_embeddings = args.max_model_len
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
+    if args.layers:
+        cfg.num_hidden_layers = args.layers
+    return cfg
 
+
+def _refuse_unservable(args, engine) -> None:
+    """Refuse at start-up what would otherwise serve through a path the
+    flags did not ask for.  ``--kv-dtype int8`` exists to run the int8
+    page kernel; where the engine found on a TPU that the kernel does
+    not take its block size or its pool, every step would go through
+    the dense fake-quant gather instead."""
+    p = engine.paths()
+    if args.kv_dtype == "int8" and p["platform"] == "tpu" \
+            and not p["attention"].startswith("pallas"):
+        raise SystemExit(
+            "--kv-dtype int8: the int8 page kernel does not take this "
+            f"configuration: {p['attention']}")
+
+
+def _build_engine(args, cfg):
+    import jax
+
+    import paddle_tpu
+    from paddle_tpu.models.llama import LlamaForCausalLM
+    from ..serving import LLMEngine
+
+    paddle_tpu.seed(0)
     model = LlamaForCausalLM(cfg)
+    if args.dtype != "float32":
+        model.to(dtype=args.dtype)
     drafter = "ngram" if args.spec_k > 0 else None
+    need = args.tp * args.replicas
+    if len(jax.devices()) < need:
+        raise SystemExit(
+            f"--tp {args.tp} x --replicas {args.replicas} needs {need} "
+            f"devices and JAX has {len(jax.devices())}: every replica "
+            "computes on devices of its own")
 
-    def make_engine():
+    def make_engine(replica: int = 0):
         # shares the model (same weights!) so supervised recovery can
-        # rebuild the engine and replay journals byte-identically
+        # rebuild the engine and replay journals byte-identically; each
+        # replica holds its own copy of them on its own devices
         kv_tier = None
         if args.host_kv_bytes > 0:
             # per-engine tier: each replica spills to its own host pool
@@ -78,18 +120,28 @@ def _build_engine(args):
             enable_prefix_caching=not args.no_prefix_caching,
             drafter=drafter, spec_k=args.spec_k,
             kv_dtype=args.kv_dtype, weight_dtype=args.weight_dtype,
-            tp=args.tp, retain_outputs=False, kv_tier=kv_tier)
+            tp=args.tp, retain_outputs=False, kv_tier=kv_tier,
+            devices=jax.devices()[replica * args.tp:
+                                  (replica + 1) * args.tp])
 
     return make_engine
 
 
-def main(argv=None) -> int:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.inference.frontend",
         description="Serve an LLM over HTTP (OpenAI-style /v1/completions "
                     "with SSE streaming, /healthz, /metrics).")
     ap.add_argument("--model", default="tiny",
                     choices=["tiny", "llama-sm", "llama-7b"])
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: build this many decoder layers "
+                         "(0 = the preset's depth); widths are never cut")
+    ap.add_argument("--dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="dtype the weights are held and the step "
+                         "computes in (KV pages follow it unless "
+                         "--kv-dtype int8)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8000)
     ap.add_argument("--max-num-seqs", type=int, default=8)
@@ -161,15 +213,27 @@ def main(argv=None) -> int:
     ap.add_argument("--slo-availability", type=float, default=0.999,
                     help="SLO objective: fraction of requests that must "
                          "finish without error/quarantine")
-    args = ap.parse_args(argv)
+    return ap
 
-    _ensure_host_devices(args.tp)
+
+def main(argv=None) -> int:
+    args = _parser().parse_args(argv)
+    _ensure_host_devices(args.tp * args.replicas)
+    from paddle_tpu.core.runtime import (CompileWatch,
+                                         configure_compile_cache,
+                                         resolve_device)
+    cache_dir = configure_compile_cache()
+    watch = CompileWatch()
+    device = resolve_device()
+    cfg = _model_config(args)
     print(f"[frontend] building {args.model} engine"
           + (f" x{args.replicas}" if args.replicas > 1 else "")
           + (f" (tp={args.tp})" if args.tp > 1 else "")
-          + " ...", flush=True)
-    make_engine = _build_engine(args)
+          + f" on {json.dumps(device)}, compile cache {cache_dir} ...",
+          flush=True)
+    make_engine = _build_engine(args, cfg)
     engine = make_engine()
+    _refuse_unservable(args, engine)
 
     from .app import ServingFrontend
     frontend = ServingFrontend(
@@ -186,12 +250,20 @@ def main(argv=None) -> int:
                     "deadline_attainment": args.slo_deadline_attainment,
                     "availability": args.slo_availability},
         flight_capacity=args.flight_capacity,
-        anomaly_spool=args.anomaly_spool)
+        anomaly_spool=args.anomaly_spool, compile_watch=watch)
 
     async def run():
         await frontend.start()
+        for i, e in enumerate(frontend.engines):
+            p = e.paths()
+            print(f"[frontend] replica {i}: layers="
+                  f"{cfg.num_hidden_layers} dtype={args.dtype} "
+                  f"devices={p['devices']} attention={p['attention']!r} "
+                  f"matmul={p['matmul']!r}", flush=True)
         print(f"[frontend] listening on http://{frontend.host}:"
               f"{frontend.port}  (model={args.model}, "
+              f"platform={device['platform']}, "
+              f"device_kind={device['kind']!r}, "
               f"max_num_seqs={engine.max_num_seqs})", flush=True)
         stop = asyncio.Event()
         second = asyncio.Event()

@@ -78,10 +78,11 @@ class ServingFrontend:
         they arm the supervised-recovery watchdog (see runner docs).
     replicas: data-parallel engine replicas behind one listener.  1 (the
         default) keeps the single EngineRunner.  D > 1 builds D engines
-        — the passed ``engine`` plus D-1 from ``engine_factory`` (then
-        REQUIRED) — each with its own stepping thread, and routes
-        requests across them with a ReplicaRouter; ``self.runner`` keeps
-        the same surface either way.
+        — the passed ``engine`` plus ``engine_factory(i)`` for replica
+        i > 0 (then REQUIRED; the index lets the factory give each
+        replica its own devices) — each with its own stepping thread,
+        and routes requests across them with a ReplicaRouter;
+        ``self.runner`` keeps the same surface either way.
     router_policy: "affinity" (default) | "least" | "random" — see
         router.py.  Ignored when replicas == 1.
     tracer: optional ``profiler.Tracer`` for the step timeline; falls
@@ -100,6 +101,9 @@ class ServingFrontend:
         plus the slowest flight records to bounded JSON files there; if
         no tracer was passed a small always-on ring is armed so there is
         a window to snapshot.
+    compile_watch: optional ``core.runtime.CompileWatch`` the process
+        created before first device use; ``GET /metrics`` then carries
+        its compile seconds and persistent-cache hits and misses.
     """
 
     def __init__(self, engine, *, model_name: str = "model",
@@ -109,8 +113,9 @@ class ServingFrontend:
                  engine_factory=None, step_deadline_s: float | None = None,
                  replicas: int = 1, router_policy: str = "affinity",
                  tracer=None, slo_config=None, flight_capacity: int = 512,
-                 anomaly_spool: str | None = None):
+                 anomaly_spool: str | None = None, compile_watch=None):
         self.model_name = str(model_name)
+        self.compile_watch = compile_watch
         self.host = host
         self.port = int(port)
         self.default_deadline_s = default_deadline_s
@@ -138,7 +143,7 @@ class ServingFrontend:
             # every replica engine records onto the SAME ring so one
             # trace shows a request crossing http -> router -> runner ->
             # engine with correlated ids
-            for e in getattr(self.runner, "engines", [self.runner.engine]):
+            for e in self.engines:
                 if getattr(e, "tracer", None) is None:
                     e.set_tracer(self.tracer)
         # SLO observatory: windowed telemetry on every replica engine
@@ -149,7 +154,7 @@ class ServingFrontend:
         if anomaly_spool is not None:
             from ...profiler.slo import AnomalySpool
             self.anomaly_spool = AnomalySpool(anomaly_spool)
-        for e in getattr(self.runner, "engines", [self.runner.engine]):
+        for e in self.engines:
             e.stats.enable_windows(slo_config, tracer=self.tracer)
             if int(flight_capacity) > 0 and getattr(e, "flight", None) is None:
                 from ..flight import FlightRecorder
@@ -172,6 +177,11 @@ class ServingFrontend:
         # always the LIVE engine: supervised recovery may have replaced
         # the one this frontend was constructed with
         return self.runner.engine
+
+    @property
+    def engines(self) -> list:
+        """Every replica's live engine (one without a router)."""
+        return list(getattr(self.runner, "engines", [self.runner.engine]))
 
     def _retry_after(self) -> str:
         """Retry-After seconds for 429s, from the live free-page trend
@@ -291,8 +301,10 @@ class ServingFrontend:
                 snap = self.engine.stats.snapshot()
                 router = None
             text = render_metrics(
-                snap, engine=self.engine,
-                frontend=self._frontend_counters(), router=router)
+                snap, engines=self.engines,
+                frontend=self._frontend_counters(), router=router,
+                compiles=None if self.compile_watch is None
+                else self.compile_watch.snapshot())
             self._count("/metrics", 200)
             writer.write(response_bytes(
                 200, text.encode("utf-8"),
@@ -354,10 +366,8 @@ class ServingFrontend:
         return False
 
     def _flight_recorders(self) -> list:
-        return [fl for fl in (
-            getattr(e, "flight", None)
-            for e in getattr(self.runner, "engines", [self.runner.engine]))
-            if fl is not None]
+        return [fl for fl in (getattr(e, "flight", None)
+                              for e in self.engines) if fl is not None]
 
     async def _debug_requests(self, req, writer) -> bool:
         """GET /debug/requests (ranked list) and /debug/requests/<id>

@@ -61,22 +61,27 @@ class _Doc:
         return "\n".join(self.lines) + "\n"
 
 
-def render_metrics(snapshot: dict, *, engine=None,
+def render_metrics(snapshot: dict, *, engines=(),
                    frontend: dict | None = None,
-                   router: dict | None = None) -> str:
+                   router: dict | None = None,
+                   compiles: dict | None = None) -> str:
     """Render one /metrics scrape.
 
     snapshot: ServingStats.snapshot() dict (or the fleet aggregate from
         ``ServingStats.aggregate`` when a router is attached).
-    engine: the live LLMEngine for pool/queue gauges (optional so the
-        renderer stays unit-testable with a bare snapshot).  Under a
-        replica router this is replica 0 — the fleet-wide counters come
-        from the aggregated snapshot, the pool gauges are one replica's.
+    engines: every replica's live LLMEngine (empty keeps the renderer
+        unit-testable with a bare snapshot).  The pool/queue gauges are
+        replica 0's — the fleet-wide counters come from the aggregated
+        snapshot; every replica gets its own record of the device it
+        computes on and the attention and matmul path of each step
+        program it has built.
     frontend: the frontend's own counters —
         {"requests_total": {(route, code): n}, "shed_total": n,
          "active_streams": n, "queue_depth": n, "draining": bool}.
     router: ReplicaRouter.router_counters() — per-replica routing gauges
         labeled {replica="i"}; None for a single-runner frontend.
+    compiles: ``CompileWatch.snapshot()`` — process-wide compile
+        seconds and persistent-cache hits and misses.
     """
     d = _Doc()
     s = snapshot
@@ -327,7 +332,8 @@ def render_metrics(snapshot: dict, *, engine=None,
                   enumerate(router.get("affinity_hits", []))])
 
     # -- engine gauges ----------------------------------------------------
-    if engine is not None:
+    if engines:
+        engine = engines[0]
         pool = engine.blocks
         d.metric("kv_pages", "gauge",
                  "KV page pool occupancy, by state.",
@@ -346,6 +352,31 @@ def render_metrics(snapshot: dict, *, engine=None,
                  "XLA compiles triggered, by program kind.",
                  [({"kind": k}, n)
                   for k, n in sorted(engine.compile_counts.items())])
+        # a step program that compiled the XLA reference in place of
+        # its kernel is visible here, by name, next to its device
+        samples = []
+        for i, e in enumerate(engines):
+            p = e.paths()
+            base = {"replica": str(i), "platform": p["platform"],
+                    "device_kind": p["device_kind"],
+                    "devices": ",".join(p["devices"])}
+            progs = p["programs"] or {"(none built)": {
+                "attention": p["attention"], "matmul": p["matmul"]}}
+            for name, paths in sorted(progs.items()):
+                samples.append(({**base, "program": name, **paths}, 1))
+        d.metric("engine_program_path", "gauge",
+                 "One sample per step program built: the device it runs "
+                 "on and the attention and matmul implementation it "
+                 "compiled.", samples)
+    if compiles is not None:
+        d.metric("compile_seconds_total", "counter",
+                 "Seconds this process spent in the XLA backend "
+                 "compiler (a persistent-cache hit counts its "
+                 "retrieval).", [(None, compiles["compile_seconds"])])
+        d.metric("compile_cache_requests_total", "counter",
+                 "Persistent compilation cache lookups, by result.",
+                 [({"result": "hit"}, compiles["cache_hits"]),
+                  ({"result": "miss"}, compiles["cache_misses"])])
     return d.render()
 
 

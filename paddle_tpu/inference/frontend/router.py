@@ -44,6 +44,7 @@ Per-replica counters (``router_counters()``): ``outstanding_tokens``,
 """
 from __future__ import annotations
 
+import functools
 import random
 import threading
 from collections import OrderedDict
@@ -310,16 +311,17 @@ def build_replicas(engine, engine_factory, n: int, *,
                    max_pending: int | None = None,
                    step_deadline_s: float | None = None) -> list:
     """Construct n replica runners: replica 0 wraps ``engine`` (the one
-    the caller already built), replicas 1..n-1 come fresh from
-    ``engine_factory`` — the same factory contract supervised recovery
-    uses, so every replica shares model weights and recovery works per
-    replica."""
+    the caller already built), replica i > 0 comes from
+    ``engine_factory(i)``.  The index is how a factory gives each
+    replica devices of its own; each runner keeps its index bound, so
+    supervised recovery rebuilds a replica where it was."""
     if n > 1 and engine_factory is None:
         raise ValueError(
             f"replicas={n} needs an engine_factory to build the extra "
             "engine replicas")
-    engines = [engine] + [engine_factory() for _ in range(n - 1)]
+    engines = [engine] + [engine_factory(i) for i in range(1, n)]
     return [EngineRunner(e, max_pending=max_pending,
-                         engine_factory=engine_factory,
+                         engine_factory=None if engine_factory is None
+                         else functools.partial(engine_factory, i),
                          step_deadline_s=step_deadline_s, name=f"r{i}")
             for i, e in enumerate(engines)]

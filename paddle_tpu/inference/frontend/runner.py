@@ -69,10 +69,13 @@ speculatively pre-staged next step die with the old engine.
 from __future__ import annotations
 
 import itertools
+import logging
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+_log = logging.getLogger("paddle_tpu.serving")
 
 __all__ = ["EngineRunner", "RunnerSaturated", "RunnerDraining",
            "StreamHandle"]
@@ -563,6 +566,10 @@ class EngineRunner:
                             self._step_started = None
                     continue
             except Exception:
+                # the boundary that must keep running: say what failed
+                # (a kernel Mosaic refused surfaces here, with its whole
+                # message) before recovery replaces or stops the engine
+                _log.exception("engine step failed (generation %d)", gen)
                 newgen = self._recover(gen)
                 if newgen is None:
                     return

@@ -71,10 +71,9 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import lax, shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.jaxcompat import shard_map
 from ..models.llama import _rms_weight, _rope_positions
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
@@ -279,6 +278,21 @@ class LLMEngine:
         byte-identical to decode_window=1; ``compile_counts`` gains at
         most one "scan" program kind, only when a window launches.
 
+    devices: the ``tp`` jax devices this engine's weights and page
+        pools live on (default: the first ``tp`` of ``jax.devices()``).
+        A replica router passes each replica its own, so that replicas
+        do not share one chip.
+
+    Which attention and which matmul implementation the step programs
+    run is decided once here, from the devices' platform and the
+    kernels' static shape claims, and is readable as
+    ``attention_path`` / ``matmul_path`` (and in ``summary()["paths"]``
+    with every program built so far).  On a TPU the Pallas kernels run
+    wherever they claim the shape; a kernel Mosaic then refuses fails
+    the program's compile, it does not fall back.  The XLA reference
+    paths run on the CPU platform and for shapes a kernel does not
+    claim, and the path string says why.
+
     The engine is SINGLE-THREADED by design: add_request/step/abort must
     all be called from one thread (the frontend's EngineRunner owns that
     thread and bridges other threads in via queues drained at step
@@ -299,10 +313,9 @@ class LLMEngine:
                  tracer=None, overlap: bool = True,
                  decode_window: int = 1,
                  weight_dtype: str = "float32",
-                 kv_tier=None):
+                 kv_tier=None, devices=None):
         cfg = model.config
         self.config = cfg
-        self.params = model.decode_params()
         if kv_dtype not in ("float32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'float32' or 'int8', got {kv_dtype!r}")
@@ -312,11 +325,6 @@ class LLMEngine:
                 "weight_dtype must be 'float32', 'int8' or 'int4', "
                 f"got {weight_dtype!r}")
         self.weight_dtype = weight_dtype
-        # the step's activations keep the model's float dtype even when
-        # the embed table is about to become a quantized pool + scales
-        self._act_dtype = self.params["embed"].dtype
-        if self.weight_dtype != "float32":
-            self.params = self._quantize_params(self.params)
         self.tp = int(tp)
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {tp}")
@@ -328,11 +336,27 @@ class LLMEngine:
                     f"{cfg.num_attention_heads} and num_key_value_heads="
                     f"{cfg.num_key_value_heads} (contiguous head "
                     "partition keeps GQA groups on one shard)")
-            from ..distributed.auto_parallel.process_mesh import ProcessMesh
-            self._mesh = ProcessMesh(list(range(self.tp)),
-                                     dim_names=["tp"]).jax_mesh()
-        else:
-            self._mesh = None
+        devices = list(jax.devices()[:self.tp] if devices is None
+                       else devices)
+        if len(devices) != self.tp:
+            raise ValueError(
+                f"tp={self.tp} needs {self.tp} devices, got {len(devices)} "
+                "(for CPU testing set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={self.tp})")
+        self.devices = devices
+        self._platform = devices[0].platform
+        self._mesh = Mesh(np.asarray(devices), ("tp",)) \
+            if self.tp > 1 else None
+        # the layer-stacked (and quantized) copy is built where it will
+        # live: on the process's default device a replica's transient
+        # copy would sit on chip 0 beside the model and replica 0's own
+        with jax.default_device(devices[0]):
+            self.params = model.decode_params()
+            # the step's activations keep the model's float dtype even
+            # when the embed table becomes a quantized pool + scales
+            self._act_dtype = self.params["embed"].dtype
+            if self.weight_dtype != "float32":
+                self.params = self._quantize_params(self.params)
         # the unembedding shards over vocab only when it divides evenly
         # (padding the vocab axis would poison the per-row finiteness
         # flag); otherwise the head matmul replicates and the per-layer
@@ -374,24 +398,33 @@ class LLMEngine:
         self._hd = cfg.hidden_size // self._nh
         L = cfg.num_hidden_layers
         dt = self._act_dtype
-        if self.kv_dtype == "int8":
-            # int8 pages + a parallel per-page-per-head f32 scale pool
-            # (symmetric: float = int8 * scale).  Scales are written at
-            # commit time inside the step program; the kernel/reference
-            # dequantizes inline at read time, so every host-side page
-            # structure (hashing, CoW, sharing, parking) is unchanged.
-            self._kc = jnp.zeros((L, num_blocks, self._kvh,
-                                  self.block_size, self._hd), jnp.int8)
-            self._vc = jnp.zeros_like(self._kc)
-            self._ks = jnp.zeros((L, num_blocks, self._kvh), jnp.float32)
-            self._vs = jnp.zeros_like(self._ks)
+        with jax.default_device(devices[0]):
+            if self.kv_dtype == "int8":
+                # int8 pages + a parallel per-page-per-head f32 scale
+                # pool (symmetric: float = int8 * scale).  Scales are
+                # written at commit time inside the step program; the
+                # kernel/reference dequantizes inline at read time, so
+                # every host-side page structure (hashing, CoW, sharing,
+                # parking) is unchanged.
+                self._kc = jnp.zeros((L, num_blocks, self._kvh,
+                                      self.block_size, self._hd), jnp.int8)
+                self._vc = jnp.zeros_like(self._kc)
+                self._ks = jnp.zeros((L, num_blocks, self._kvh),
+                                     jnp.float32)
+                self._vs = jnp.zeros_like(self._ks)
+            else:
+                # "float32" means full-width model dtype (f32/bf16) pages
+                self._kc = jnp.zeros((L, num_blocks, self._kvh,
+                                      self.block_size, self._hd), dt)
+                self._vc = jnp.zeros_like(self._kc)
+                self._ks = self._vs = None
+        if self.tp == 1:
+            # the unstacked leaves (embedding, head, final norm) are the
+            # model's own arrays: a replica off the model's device gets
+            # its copy of them here, and nothing moves on the device
+            # that already holds them
+            self.params = jax.device_put(self.params, devices[0])
         else:
-            # "float32" means full-width model dtype (f32/bf16) pages
-            self._kc = jnp.zeros((L, num_blocks, self._kvh,
-                                  self.block_size, self._hd), dt)
-            self._vc = jnp.zeros_like(self._kc)
-            self._ks = self._vs = None
-        if self.tp > 1:
             # lay the pools and the head-partitioned weights out on the
             # mesh ONCE at construction; every step launch then runs
             # without resharding transfers
@@ -441,6 +474,11 @@ class LLMEngine:
         # draft acceptance) only when a drafter exists.
         self._with_logits = drafter is not None
         self._Lq = B * (self.max_spec_k + 1) if self._with_logits else B
+        self.attention_path = self._resolve_attention_path()
+        self.matmul_path = self._resolve_matmul_path()
+        # program name ("ragged:64", "window:4") -> the paths it was built
+        # with; filled at each program BUILD, read by summary()
+        self.program_paths: dict = {}
 
         # decode fast-path buffers (general mixed launches repack from
         # scratch; steady pure-decode steps reuse these).  Two sets:
@@ -585,27 +623,78 @@ class LLMEngine:
         return {"layers": out_layers, "embed_q": eq, "embed_s": es,
                 "norm_f": params["norm_f"], "head_q": hq, "head_s": hs}
 
+    def _resolve_attention_path(self) -> str:
+        """Which attention the step programs run, decided once from the
+        platform and the kernel's static claim.  The interpreted kernel
+        costs a Python step per (Tq, H_kv, nblk) grid cell EVERY launch,
+        so off the TPU the XLA reference (term-identical math) serves
+        unless a test forces the interpreter."""
+        if _pa.INTERPRET is True:
+            return "pallas-interpret"
+        if self._platform != "tpu":
+            return f"xla-reference ({self._platform} platform)"
+        # the worst packable launch (precompile_buckets' ceiling)
+        Tq = self._ragged_bucket(self.max_prefill_tokens + self._Lq)
+        why = _pa.ineligible(self._nh // self.tp, self._kvh // self.tp,
+                             self._hd, self.block_size,
+                             jnp.int8 if self.kv_dtype == "int8"
+                             else self._act_dtype,
+                             launch=(Tq, self.max_num_seqs + 1, self.nblk,
+                                     self.blocks.num_blocks))
+        return "pallas" if why is None else f"xla-reference ({why})"
+
+    def _resolve_matmul_path(self) -> str:
+        """Which matmul the step programs run: XLA's own dot over float
+        weights; over quantized pools the fused dequant kernel on the
+        TPU (or under a forced interpreter) wherever it claims the
+        weight's shape, else its XLA fake-quant reference."""
+        wdt = self.weight_dtype
+        # weight name -> why the kernel does not take it; the step
+        # bodies route by this same record (``_weight_ops``)
+        self._qmm_refused: dict = {}
+        if wdt == "float32":
+            return "xla-dense"
+        if _qm.INTERPRET is True:
+            kernel = "pallas-quant-interpret"
+        elif self._platform == "tpu":
+            kernel = "pallas-quant"
+        else:
+            return f"xla-fake-quant ({self._platform} platform)"
+        for name, q in [(n[:-2], q) for n, q in
+                        self.params["layers"].items() if n.endswith("_q")] \
+                + [("head", self.params["head_q"])]:
+            # per-shard output width for the column-sharded pools
+            sharded = name in ("wq", "wk", "wv") \
+                or (name == "head" and self._shard_head)
+            n_out = q.shape[-1] // (self.tp if sharded else 1)
+            k_in = q.shape[-2] * (2 if wdt == "int4" else 1)
+            why = _qm.ineligible(k_in, n_out, wdt)
+            if why is not None:
+                self._qmm_refused[name] = why
+        if not self._qmm_refused:
+            return kernel
+        return kernel + "; xla-fake-quant for " + ", ".join(
+            f"{n} ({w})" for n, w in sorted(self._qmm_refused.items()))
+
     def _weight_ops(self):
         """(mm, embed, head_logits) for the step bodies, resolved once
         per program build.
 
         f32 engines get the literal dense expressions (byte-identity
         with every pre-quantization program); quantized engines route
-        every projection/MLP/head matmul through the fused
-        dequant-matmul kernel on TPU (or under a forced interpreter)
-        and through its term-identical XLA fake-quant reference
-        everywhere else — the same split-contract the paged attention
-        kernel keeps."""
+        every projection/MLP/head matmul by ``matmul_path``: through the
+        fused dequant-matmul kernel where it runs and claims the weight,
+        through its term-identical XLA fake-quant reference elsewhere —
+        the same split-contract the paged attention kernel keeps."""
         dt = self._act_dtype
         wdt = self.weight_dtype
         if wdt != "float32":
-            use_qmm = _qm.INTERPRET is True or \
-                jax.default_backend() == "tpu"
+            use_qmm = self.matmul_path.startswith("pallas")
+            refused = self._qmm_refused
 
             def mm(h, p, name):
                 q, s = p[name + "_q"], p[name + "_s"]
-                if use_qmm and _qm.supports(h.shape[0], h.shape[1],
-                                            q.shape[-1], wdt):
+                if use_qmm and name not in refused:
                     out = _qm.matmul(h, q, s, weight_dtype=wdt)
                 else:
                     out = _qm.reference_matmul(h, q, s, wdt)
@@ -618,8 +707,7 @@ class LLMEngine:
 
             def head_logits(params, hsel):
                 q, s = params["head_q"], params["head_s"]
-                if use_qmm and _qm.supports(hsel.shape[0], hsel.shape[1],
-                                            q.shape[-1], wdt):
+                if use_qmm and "head" not in refused:
                     return _qm.matmul(hsel.astype(jnp.float32), q, s,
                                       weight_dtype=wdt)
                 return _qm.reference_matmul(hsel, q, s, wdt)
@@ -993,6 +1081,7 @@ class LLMEngine:
         out["weight_bytes_resident_per_shard"] = \
             self.weight_bytes_resident_per_shard()
         out["peak_resident_seqs"] = self.peak_resident_seqs
+        out["paths"] = self.paths()
         out["tuning_cache"] = {
             "path": self._tuning_report["path"],
             "device": self._tuning_report["device"],
@@ -1000,6 +1089,20 @@ class LLMEngine:
                         self._tuning_report["kernels"].items()},
         }
         return out
+
+    def paths(self) -> dict:
+        """Where this engine computes and through which implementations:
+        platform, device kind and devices, the attention and matmul
+        paths, and the paths of every step program built so far.  Safe
+        from the HTTP thread: the record is copied in one step (a build
+        on the engine thread may insert meanwhile) and its entries are
+        never mutated."""
+        return {"platform": self._platform,
+                "device_kind": self.devices[0].device_kind,
+                "devices": [str(d) for d in self.devices],
+                "attention": self.attention_path,
+                "matmul": self.matmul_path,
+                "programs": dict(self.program_paths)}
 
     def kv_page_bytes(self) -> int:
         """MESH-TOTAL device bytes one KV page costs: K and V slabs
@@ -1665,6 +1768,11 @@ class LLMEngine:
             self.stats.record_verify(dur * spec_tokens / total,
                                      n_emitted, occ)
 
+        if batch:
+            # before the rows' callbacks fire: a client that scrapes
+            # /metrics on its stream's last frame finds its tokens counted
+            self.stats.record_decode(dur * len(batch) / total,
+                                     len(batch), occ)
         for req, s in zip(batch, batch_slots):
             if not ok[s]:
                 self._quarantine(req, finished)
@@ -1679,9 +1787,6 @@ class LLMEngine:
                 req.seen[tok] = True
             self._notify_tokens(req, (tok,))
             self._maybe_retire(req, finished)
-        if batch:
-            self.stats.record_decode(dur * len(batch) / total,
-                                     len(batch), occ)
 
     # ------------------------------------------------------------------
     # device-resident decode window (decode_window > 1)
@@ -2431,7 +2536,7 @@ class LLMEngine:
         ahead of any later prefill/decode write into dst."""
         if self._cow_prog is None:
             run, donate = self._make_cow_fn()
-            if jax.default_backend() == "cpu":
+            if self._platform == "cpu":
                 donate = ()
             self._cow_prog = jax.jit(run, donate_argnums=donate)
             self.compile_counts["cow"] += 1
@@ -2446,6 +2551,13 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # the compiled ragged step
     # ------------------------------------------------------------------
+
+    def _record_program(self, name: str) -> None:
+        """Note the paths a step program was just built with.  The
+        builders read ``attention_path`` / ``matmul_path`` at build, so
+        the record is what the program runs."""
+        self.program_paths[name] = {"attention": self.attention_path,
+                                    "matmul": self.matmul_path}
 
     def _ragged_bucket(self, n_tokens: int) -> int:
         """Flat-token bucket for a launch: pure-decode-sized launches pad
@@ -2468,11 +2580,12 @@ class LLMEngine:
         prog = self._ragged_progs.get(Tq)
         if prog is None:
             run, donate = self._make_ragged_fn(Tq)
-            if jax.default_backend() == "cpu":
+            if self._platform == "cpu":
                 donate = ()
             prog = jax.jit(run, donate_argnums=donate)
             self._ragged_progs[Tq] = prog
             self.compile_counts["ragged"] += 1
+            self._record_program(f"ragged:{Tq}")
         return prog
 
     def _make_ragged_fn(self, Tq: int):
@@ -2491,7 +2604,6 @@ class LLMEngine:
         with_logits = self._with_logits
         eps = self.config.rms_norm_eps
         theta = self.config.rope_theta
-        dt = self._act_dtype
         if self.kv_dtype == "int8":
             return self._make_ragged_fn_q8(Tq)
         # under tp the body runs on PER-SHARD shapes: a contiguous block
@@ -2501,13 +2613,7 @@ class LLMEngine:
         nh, kvh = nh // tp, kvh // tp
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
-        # the interpreted kernel costs a Python step per (Tq, H_kv, nblk)
-        # grid cell EVERY launch — serving on CPU uses the XLA reference
-        # path (term-identical math) unless a test forces the interpreter
-        use_pallas = _pa.INTERPRET is True or (
-            jax.default_backend() == "tpu"
-            and _pa.ragged_supports(Tq, nh, kvh, d, bs, B + 1,
-                                    self.nblk, dt))
+        use_pallas = self.attention_path.startswith("pallas")
 
         def run(params, kc, vc, toks, cu, kvl, bt, lidx, samp):
             # toks [Tq] i32, rows packed back-to-back (tail padding maps
@@ -2605,7 +2711,6 @@ class LLMEngine:
         with_logits = self._with_logits
         eps = self.config.rms_norm_eps
         theta = self.config.rope_theta
-        dt = self._act_dtype
         # per-shard head counts under tp (see _make_ragged_fn): the
         # scale pools slice along the same H_kv axis as the page pools,
         # so quantize-at-commit stays a purely per-head-local transform
@@ -2613,10 +2718,7 @@ class LLMEngine:
         nh, kvh = nh // tp, kvh // tp
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
-        use_pallas = _pa.INTERPRET is True or (
-            jax.default_backend() == "tpu"
-            and _pa.ragged_quant_supports(Tq, nh, kvh, d, bs, B + 1,
-                                          self.nblk, dt))
+        use_pallas = self.attention_path.startswith("pallas")
 
         def run(params, kc, vc, ks, vs, fresh, toks, cu, kvl, bt, lidx,
                 samp):
@@ -2747,11 +2849,12 @@ class LLMEngine:
         actually running decode_window > 1."""
         if self._window_prog is None:
             run, donate = self._make_window_fn()
-            if jax.default_backend() == "cpu":
+            if self._platform == "cpu":
                 donate = ()
             self._window_prog = jax.jit(run, donate_argnums=donate)
             self.compile_counts["scan"] = \
                 self.compile_counts.get("scan", 0) + 1
+            self._record_program(f"window:{self.decode_window}")
         return self._window_prog
 
     def _wrap_tp_window(self, run, n_host_args: int):
@@ -2795,17 +2898,13 @@ class LLMEngine:
         K = self.decode_window
         eps = self.config.rms_norm_eps
         theta = self.config.rope_theta
-        dt = self._act_dtype
         if self.kv_dtype == "int8":
             return self._make_window_fn_q8()
         tp = self.tp
         nh, kvh = nh // tp, kvh // tp
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
-        use_pallas = _pa.INTERPRET is True or (
-            jax.default_backend() == "tpu"
-            and _pa.ragged_supports(B, nh, kvh, d, bs, B + 1,
-                                    self.nblk, dt))
+        use_pallas = self.attention_path.startswith("pallas")
 
         def run(params, kc, vc, toks, kvl, active, gen, budgets,
                 eos_ids, base_keys, bt, samp):
@@ -2908,15 +3007,11 @@ class LLMEngine:
         K = self.decode_window
         eps = self.config.rms_norm_eps
         theta = self.config.rope_theta
-        dt = self._act_dtype
         tp = self.tp
         nh, kvh = nh // tp, kvh // tp
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
-        use_pallas = _pa.INTERPRET is True or (
-            jax.default_backend() == "tpu"
-            and _pa.ragged_quant_supports(B, nh, kvh, d, bs, B + 1,
-                                          self.nblk, dt))
+        use_pallas = self.attention_path.startswith("pallas")
 
         def run(params, kc, vc, ks, vs, fresh, toks, kvl, active, gen,
                 budgets, eos_ids, base_keys, bt, samp):

@@ -458,68 +458,21 @@ def _shapes_eligible(shape, dtype_name, kv_shape=None, causal=True) -> bool:
     return s % 128 == 0 and dtype_name in ("float32", "bfloat16")
 
 
-# (shapes, dtype, causal, backend) -> bool.  The r2 bench died because a
-# shape heuristic said yes and Mosaic said no at run time; the authoritative
-# check is an actual lowering, done ONCE per shape and cached.
-_PROBE_CACHE: dict = {}
-_PROBE_LOGGED = False
-
-
-def _probe_lowering(q_sds, k_sds, causal) -> bool:
-    """Compile-probe the fwd+bwd kernels for these abstract shapes.
-
-    Returns False (and logs once) on any lowering/compile failure so callers
-    degrade to `_ref_attention` instead of zeroing the whole program — the
-    TPU analog of the reference's kernel-selection fallback around FA2
-    (flash_attn_kernel.cu dispatch path).
-    """
-    global _PROBE_LOGGED
-    key = (tuple(q_sds.shape), tuple(k_sds.shape), str(q_sds.dtype),
-           bool(causal), jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if INTERPRET:  # interpreter enforces no TPU tiling rules; nothing to probe
-        _PROBE_CACHE[key] = True
-        return True
-
-    def fwd_bwd(q, k, v, g):
-        out, vjp = jax.vjp(
-            lambda q_, k_, v_: _flash_attention(causal, q_, k_, v_), q, k, v)
-        return out, vjp(g)
-
-    try:
-        jax.jit(fwd_bwd).lower(q_sds, k_sds, k_sds, q_sds).compile()
-        ok = True
-    except Exception as e:  # Mosaic/XLA lowering failure -> fallback
-        ok = False
-        if not _PROBE_LOGGED:
-            _PROBE_LOGGED = True
-            import logging
-            logging.getLogger("paddle_tpu").warning(
-                "Pallas flash-attention failed to lower for q=%s k=%s "
-                "(causal=%s): %s -- falling back to the XLA composition",
-                q_sds.shape, k_sds.shape, causal, str(e)[:500])
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
 def use_flash(q, k, causal=True) -> bool:
-    """THE eligibility predicate (single source of truth): flag + static
-    shape check + one-time lowering probe."""
+    """THE eligibility predicate (single source of truth): flag + the
+    kernels' static claim on this platform and shape.  Whether Mosaic
+    accepts the launch is settled by compiling the program that contains
+    it; a refusal there raises, it does not fall back."""
     from ...core.flags import get_flag
     if not get_flag("use_pallas_kernels"):
         return False
-    if not _shapes_eligible(tuple(q.shape), jnp.dtype(q.dtype).name,
-                            tuple(k.shape), bool(causal)):
-        return False
-    return _probe_lowering(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                           jax.ShapeDtypeStruct(k.shape, k.dtype), causal)
+    return _shapes_eligible(tuple(q.shape), jnp.dtype(q.dtype).name,
+                            tuple(k.shape), bool(causal))
 
 
 def attention(q, k, v, causal=True):
-    """Fused attention with automatic fallback: Pallas flash kernels when
-    they provably lower on this backend, else the XLA composition."""
+    """Fused attention: the Pallas flash kernels wherever they claim the
+    shape on this platform, else the XLA composition."""
     if use_flash(q, k, causal):
         return _flash_attention(bool(causal), q, k, v)
     return _ref_attention(q, k, v, causal)
@@ -533,14 +486,7 @@ class _FlashFwd:
 
     @staticmethod
     def supports(shape, dtype_name, kv_shape=None, causal=True) -> bool:
-        if not _shapes_eligible(shape, dtype_name, kv_shape, bool(causal)):
-            return False
-        import numpy as _np
-        dt = jnp.bfloat16 if dtype_name == "bfloat16" else _np.dtype(dtype_name)
-        kv = kv_shape if kv_shape is not None else shape
-        return _probe_lowering(jax.ShapeDtypeStruct(tuple(shape), dt),
-                               jax.ShapeDtypeStruct(tuple(kv), dt),
-                               bool(causal))
+        return _shapes_eligible(shape, dtype_name, kv_shape, bool(causal))
 
     # identity used as the dispatch cache key
     def __hash__(self):

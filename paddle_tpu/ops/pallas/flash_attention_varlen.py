@@ -17,7 +17,6 @@ sentinel segment that matches nothing.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -403,13 +402,11 @@ def _varlen_bwd_rule(causal, sm_scale, res, g):
 
 _varlen_attention.defvjp(_varlen_fwd_rule, _varlen_bwd_rule)
 
-_PROBE_CACHE: dict = {}
-
-
 def use_varlen_flash(q, k, causal) -> bool:
-    """Eligibility + one-time lowering probe (same policy as the fixed-shape
-    kernel, flash_attention.py:use_flash): flag + shape rules + compile
-    probe with XLA-composition fallback on failure."""
+    """Eligibility (same policy as the fixed-shape kernel,
+    flash_attention.py:use_flash): flag + the kernels' static claim on
+    this platform and shape.  A launch Mosaic refuses fails the compile
+    of the program that contains it."""
     from ...core.flags import get_flag
     if not _HAS_PALLAS or not get_flag("use_pallas_kernels"):
         return False
@@ -421,35 +418,4 @@ def use_varlen_flash(q, k, causal) -> bool:
         return False
     if q.shape[2] not in (64, 128, 256):
         return False
-    if jnp.dtype(q.dtype).name not in ("float32", "bfloat16"):
-        return False
-    if _fa.INTERPRET:
-        return True
-    key = (tuple(q.shape), tuple(k.shape), str(q.dtype), bool(causal))
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        sm = 1.0 / math.sqrt(q.shape[-1])
-        nseq = 2
-        q_s = jax.ShapeDtypeStruct(q.shape, q.dtype)
-        k_s = jax.ShapeDtypeStruct(k.shape, k.dtype)
-        cu = jax.ShapeDtypeStruct((nseq + 1,), jnp.int32)
-
-        def fwd_bwd(q, k, v, cq, ck, g):
-            out, vjp = jax.vjp(
-                lambda q_, k_, v_: _varlen_attention(causal, sm, q_, k_, v_,
-                                                     cq, ck), q, k, v)
-            return out, vjp(g)
-
-        jax.jit(fwd_bwd).lower(q_s, k_s, k_s, cu, cu, q_s).compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        import logging
-        logging.getLogger("paddle_tpu").warning(
-            "varlen flash attention failed to lower for q=%s (causal=%s): "
-            "%s -- falling back to the XLA composition",
-            q.shape, causal, str(e)[:300])
-    _PROBE_CACHE[key] = ok
-    return ok
+    return jnp.dtype(q.dtype).name in ("float32", "bfloat16")

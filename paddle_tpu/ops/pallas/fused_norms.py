@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -140,49 +139,13 @@ def _ln_bwd(eps, res, g):
 _ln_pallas.defvjp(_ln_fwd, _ln_bwd)
 
 
-# One-time compile probe per (op, shape, dtype): a shape heuristic alone let
-# a Mosaic-illegal kernel reach the r2 bench — the authoritative eligibility
-# check is an actual lowering (same policy as flash_attention._probe_lowering).
-_PROBE_CACHE: dict = {}
-
-
-def _probe(tag, fn, *sds) -> bool:
-    key = (tag,) + tuple((tuple(s.shape), str(s.dtype)) for s in sds) \
-        + (jax.default_backend(),)
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    try:
-        jax.jit(fn).lower(*sds).compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        import logging
-        logging.getLogger("paddle_tpu").warning(
-            "Pallas %s failed to lower for %s: %s -- using XLA fallback",
-            tag, [s.shape for s in sds], str(e)[:300])
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
-def _np_dt(name):
-    return jnp.bfloat16 if name == "bfloat16" else np.dtype(name)
-
-
 class _RmsNormOp:
     def __call__(self, x, w, eps):
         return _rms_pallas(float(eps), x, w)
 
     @staticmethod
-    def supports(shape, dtype_name, w_dtype_name=None):
-        if not _supports(shape, dtype_name):
-            return False
-        x = jax.ShapeDtypeStruct(tuple(shape), _np_dt(dtype_name))
-        # probe with the ACTUAL weight dtype — master-weight setups keep the
-        # norm weight fp32 against bf16 activations, a different lowering
-        w = jax.ShapeDtypeStruct((shape[-1],),
-                                 _np_dt(w_dtype_name or dtype_name))
-        return _probe("rms_norm", lambda x, w: _rms_pallas(1e-6, x, w), x, w)
+    def supports(shape, dtype_name):
+        return _supports(shape, dtype_name)
 
     def __hash__(self):
         return hash("pallas_rms_norm")
@@ -196,14 +159,8 @@ class _LayerNormOp:
         return _ln_pallas(float(eps), x, w, b)
 
     @staticmethod
-    def supports(shape, dtype_name, w_dtype_name=None):
-        if not _supports(shape, dtype_name):
-            return False
-        x = jax.ShapeDtypeStruct(tuple(shape), _np_dt(dtype_name))
-        v = jax.ShapeDtypeStruct((shape[-1],),
-                                 _np_dt(w_dtype_name or dtype_name))
-        return _probe("layer_norm",
-                      lambda x, w, b: _ln_pallas(1e-6, x, w, b), x, v, v)
+    def supports(shape, dtype_name):
+        return _supports(shape, dtype_name)
 
     def __hash__(self):
         return hash("pallas_layer_norm")

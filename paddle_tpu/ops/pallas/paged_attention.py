@@ -599,175 +599,64 @@ def ragged_paged_reference(q, key_cache, value_cache, block_tables,
         q, key_cache, value_cache, block_tables, seg, rel)
 
 
-_PROBE_CACHE: dict = {}
-_PROBE_LOGGED = False
+# Scalar memory of one TensorCore of the TPU v5e, the only device this was
+# measured on.  The ragged launches prefetch seg, rel, the block table and,
+# over int8 pages, both scale pools into it.  A launch whose operands came
+# to 1036 KiB was refused there at compile time ("Used 1.01M of 1.00M
+# smem"), one of 269 KiB compiled, and the refusal listed a [1025, 32] f32
+# operand at 516 KiB: 32-bit words in (8, 128) tiles.  What Mosaic keeps
+# there for itself was not measured; _SMEM_RESERVE stands in for it.
+_SMEM_BYTES = 1 << 20
+_SMEM_RESERVE = 16 << 10
 
 
-def _probe_lowering(B, H, Hkv, D, bs, nblk, dtype) -> bool:
-    """Compile-probe the decode kernel for these shapes.
+def scalar_prefetch_bytes(Tq, table_rows, nblk, num_blocks, Hkv,
+                          int8: bool) -> int:
+    """Scalar memory the operands a ragged launch prefetches take: seg
+    and rel [Tq] (counted whole lanes of 128 words), the block table
+    [table_rows, nblk] and, over int8 pages, the two [num_blocks, Hkv]
+    f32 scale pools, each padded to (8, 128) tiles of 32-bit words."""
+    def tiled(rows, cols):
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
 
-    The authoritative eligibility check is an actual lowering (the r2
-    bench died on a heuristic yes / Mosaic no — flash_attention.py:453);
-    returns False on any failure so callers degrade to the dense-gather
-    XLA path instead of crashing every serving decode step.
-    """
-    global _PROBE_LOGGED
-    key = (B, H, Hkv, D, bs, nblk, str(dtype), jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if interpret_mode():  # interpreter enforces no TPU tiling rules
-        _PROBE_CACHE[key] = True
-        return True
-    num_blocks = max(nblk * B, 1)
-    try:
-        jax.jit(paged_decode_attention).lower(
-            jax.ShapeDtypeStruct((B, H, D), dtype),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), dtype),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), dtype),
-            jax.ShapeDtypeStruct((B, nblk), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ).compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        if not _PROBE_LOGGED:
-            _PROBE_LOGGED = True
-            import logging
-            logging.getLogger("paddle_tpu.pallas").warning(
-                "paged decode kernel does not lower for "
-                f"B={B} H={H} Hkv={Hkv} D={D} bs={bs}: "
-                f"{type(e).__name__}; falling back to dense gather")
-    _PROBE_CACHE[key] = ok
-    return ok
+    need = 2 * -(-Tq // 128) * 128 * 4 + tiled(table_rows, nblk)
+    if int8:
+        need += 2 * tiled(num_blocks, Hkv)
+    return need
 
 
-def supports(B, H, Hkv, D, bs, nblk=None, dtype=jnp.float32) -> bool:
-    """Eligibility for the pallas decode kernel: shape heuristic, then an
-    actual lowering probe (cached)."""
-    if H % Hkv != 0:
-        return False
-    if D % 128 != 0 and D not in (64,):
-        return False
-    if bs % 8 != 0:
-        return False
-    if nblk is None:
-        return True     # shape-only query (no probe possible yet)
-    return _probe_lowering(B, H, Hkv, D, bs, nblk, dtype)
-
-
-def _probe_ragged_lowering(Tq, H, Hkv, D, bs, R, nblk, dtype) -> bool:
-    """Compile-probe the ragged kernel for these shapes (cached; same
-    degrade-don't-crash contract as `_probe_lowering`)."""
-    global _PROBE_LOGGED
-    key = ("ragged", Tq, H, Hkv, D, bs, R, nblk, str(dtype),
-           jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if interpret_mode():  # interpreter enforces no TPU tiling rules
-        _PROBE_CACHE[key] = True
-        return True
-    num_blocks = max(nblk * R, 1)
-    try:
-        jax.jit(ragged_paged_attention_segrel).lower(
-            jax.ShapeDtypeStruct((Tq, H, D), dtype),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), dtype),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), dtype),
-            jax.ShapeDtypeStruct((R, nblk), jnp.int32),
-            jax.ShapeDtypeStruct((Tq,), jnp.int32),
-            jax.ShapeDtypeStruct((Tq,), jnp.int32),
-        ).compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        if not _PROBE_LOGGED:
-            _PROBE_LOGGED = True
-            import logging
-            logging.getLogger("paddle_tpu.pallas").warning(
-                "ragged paged kernel does not lower for "
-                f"Tq={Tq} H={H} Hkv={Hkv} D={D} bs={bs}: "
-                f"{type(e).__name__}; falling back to dense gather")
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
-def ragged_supports(Tq, H, Hkv, D, bs, R=None, nblk=None,
-                    dtype=jnp.float32) -> bool:
-    """Eligibility for the ragged pallas kernel: shape heuristic, then an
-    actual lowering probe (cached).
-
-    Under tensor parallelism callers pass PER-SHARD head counts (H/tp,
-    Hkv/tp): the kernel launches inside shard_map, so Mosaic lowers and
-    tiles against the shard-local q/kv shapes, never the mesh-global
-    ones.  The engine guarantees tp divides both counts, so the GQA
-    ratio H % Hkv == 0 is shard-invariant."""
-    if H < 1 or Hkv < 1:
-        return False
-    if H % Hkv != 0:
-        return False
-    if D % 128 != 0 and D not in (64,):
-        return False
-    if bs % 8 != 0:
-        return False
-    if R is None or nblk is None:
-        return True     # shape-only query (no probe possible yet)
-    return _probe_ragged_lowering(Tq, H, Hkv, D, bs, R, nblk, dtype)
-
-
-def _probe_ragged_quant_lowering(Tq, H, Hkv, D, bs, R, nblk, dtype) -> bool:
-    """Compile-probe the int8-page ragged kernel (cached; same
-    degrade-don't-crash contract as `_probe_lowering`)."""
-    global _PROBE_LOGGED
-    key = ("ragged-q8", Tq, H, Hkv, D, bs, R, nblk, str(dtype),
-           jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if interpret_mode():  # interpreter enforces no TPU tiling rules
-        _PROBE_CACHE[key] = True
-        return True
-    num_blocks = max(nblk * R, 1)
-    try:
-        jax.jit(ragged_paged_attention_quant_segrel).lower(
-            jax.ShapeDtypeStruct((Tq, H, D), dtype),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), jnp.int8),
-            jax.ShapeDtypeStruct((num_blocks, Hkv, bs, D), jnp.int8),
-            jax.ShapeDtypeStruct((num_blocks, Hkv), jnp.float32),
-            jax.ShapeDtypeStruct((num_blocks, Hkv), jnp.float32),
-            jax.ShapeDtypeStruct((R, nblk), jnp.int32),
-            jax.ShapeDtypeStruct((Tq,), jnp.int32),
-            jax.ShapeDtypeStruct((Tq,), jnp.int32),
-        ).compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        if not _PROBE_LOGGED:
-            _PROBE_LOGGED = True
-            import logging
-            logging.getLogger("paddle_tpu.pallas").warning(
-                "int8 ragged paged kernel does not lower for "
-                f"Tq={Tq} H={H} Hkv={Hkv} D={D} bs={bs}: "
-                f"{type(e).__name__}; falling back to dense fake-quant")
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
-def ragged_quant_supports(Tq, H, Hkv, D, bs, R=None, nblk=None,
-                          dtype=jnp.float32) -> bool:
-    """Eligibility for the int8-page ragged kernel.  Int8 pages carry a
-    (32, 128) minimum tile (vs (8, 128) for f32), so the page-size
-    heuristic is stricter than the float path's before the authoritative
-    lowering probe runs.  As with ``ragged_supports``, tensor-parallel
-    callers pass per-shard head counts."""
-    if H < 1 or Hkv < 1:
-        return False
-    if H % Hkv != 0:
-        return False
-    if D % 128 != 0 and D not in (64,):
-        return False
-    if bs % 32 != 0:
-        return False
-    if R is None or nblk is None:
-        return True     # shape-only query (no probe possible yet)
-    return _probe_ragged_quant_lowering(Tq, H, Hkv, D, bs, R, nblk, dtype)
+def ineligible(H, Hkv, D, bs, kv_dtype=jnp.float32,
+               launch=None) -> str | None:
+    """Why the paged kernels do not claim this shape, or None when they
+    do.  A static claim from shapes alone: whether Mosaic accepts the
+    launch is settled by compiling the program that contains it, and a
+    refusal there raises (interpret mode enforces no tiling rule, so
+    only a compile on the chip can say).  Int8 pages carry a (32, 128)
+    minimum tile, float pages (8, 128).  ``launch`` is the caller's
+    largest ragged launch as ``(Tq, table_rows, nblk, num_blocks)``: its
+    prefetched operands must fit the scalar memory, which bounds the
+    int8 page pool (both scale pools ride there) and the block table
+    (None: not checked, and an overrun fails the compile instead).
+    Tensor-parallel callers pass per-shard head counts: the kernel
+    launches inside shard_map and tiles against the shard-local
+    shapes."""
+    if H < 1 or Hkv < 1 or H % Hkv:
+        return f"{H} query heads are not a multiple of {Hkv} kv heads"
+    if D % 128 and D != 64:
+        return f"head_dim {D} is neither 64 nor a multiple of 128"
+    int8 = jnp.dtype(kv_dtype) == jnp.int8
+    min_bs = 32 if int8 else 8
+    if bs % min_bs:
+        return (f"block_size {bs} is not a multiple of {min_bs} "
+                f"({jnp.dtype(kv_dtype).name} pages)")
+    if launch is not None:
+        Tq, table_rows, nblk, num_blocks = launch
+        need = scalar_prefetch_bytes(Tq, table_rows, nblk, num_blocks,
+                                     Hkv, int8)
+        if need > _SMEM_BYTES - _SMEM_RESERVE:
+            return (f"a [{table_rows}, {nblk}] block table "
+                    + (f"and the scale pools of {num_blocks} int8 pages "
+                       "need" if int8 else "needs")
+                    + f" {need >> 10} KiB of the "
+                    f"{_SMEM_BYTES >> 10} KiB scalar memory (v5e)")
+    return None

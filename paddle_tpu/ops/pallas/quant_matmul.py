@@ -300,61 +300,22 @@ def matmul(x, q, s, *, weight_dtype: str):
 
 
 # ---------------------------------------------------------------------------
-# eligibility: shape heuristics + cached lowering probe
+# eligibility: a static claim from shapes
 # ---------------------------------------------------------------------------
 
-_PROBE_CACHE: dict = {}
-_PROBE_LOGGED = False
-
-
-def _probe_lowering(M, K, N, weight_dtype) -> bool:
-    """Compile-probe the fused kernel for these shapes (cached; the
-    degrade-don't-crash contract of the paged kernels: any failure
-    returns False so callers fall back to the XLA fake-quant path)."""
-    global _PROBE_LOGGED
-    key = (M, K, N, weight_dtype, jax.default_backend())
-    hit = _PROBE_CACHE.get(key)
-    if hit is not None:
-        return hit
-    if interpret_mode():  # interpreter enforces no TPU tiling rules
-        _PROBE_CACHE[key] = True
-        return True
-    G = -(-K // GROUP)
-    qs = jax.ShapeDtypeStruct((K // 2, N), jnp.int8) \
-        if weight_dtype == "int4" \
-        else jax.ShapeDtypeStruct((K, N), jnp.int8)
-    ss = jax.ShapeDtypeStruct((G, N), jnp.float32) \
-        if weight_dtype == "int4" \
-        else jax.ShapeDtypeStruct((N,), jnp.float32)
-    try:
-        jax.jit(functools.partial(matmul, weight_dtype=weight_dtype)) \
-            .lower(jax.ShapeDtypeStruct((M, K), jnp.float32), qs, ss) \
-            .compile()
-        ok = True
-    except Exception as e:
-        ok = False
-        if not _PROBE_LOGGED:
-            _PROBE_LOGGED = True
-            import logging
-            logging.getLogger("paddle_tpu.pallas").warning(
-                "fused dequant matmul does not lower for "
-                f"M={M} K={K} N={N} {weight_dtype}: "
-                f"{type(e).__name__}; falling back to XLA fake-quant")
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
-def supports(M, K, N, weight_dtype: str) -> bool:
-    """Eligibility for the fused kernel: shape heuristic, then an actual
-    lowering probe (cached).  Under tensor parallelism callers pass the
-    PER-SHARD N — column-sharded pools launch inside shard_map, so
-    Mosaic tiles against the shard-local width."""
+def ineligible(K, N, weight_dtype: str) -> str | None:
+    """Why the fused kernel does not claim a [K, N] weight, or None when
+    it does.  Static: whether Mosaic accepts the launch is settled by
+    compiling the program that contains it, and a refusal there raises.
+    Under tensor parallelism callers pass the PER-SHARD N —
+    column-sharded pools launch inside shard_map, so Mosaic tiles
+    against the shard-local width."""
     if weight_dtype not in ("int8", "int4"):
-        return False
-    if M < 1 or K < 2 or N < 1:
-        return False
+        return f"weight_dtype {weight_dtype!r} is not quantized"
+    if K < 2 or N < 1:
+        return f"degenerate weight [{K}, {N}]"
     if weight_dtype == "int4" and K % 2:
-        return False
-    if N % 128 != 0:    # lane tiling: quantized blocks want full lanes
-        return False
-    return _probe_lowering(M, K, N, weight_dtype)
+        return f"int4 packing needs an even K, got {K}"
+    if N % 128:     # lane tiling: quantized blocks want full lanes
+        return f"N={N} is not a multiple of 128 lanes"
+    return None

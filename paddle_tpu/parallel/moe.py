@@ -16,8 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.lax import axis_size as _axis_size
 
-from ..core.jaxcompat import axis_size as _axis_size
 from ..incubate.distributed.models.moe.gating import (
     capacity_for, combine_output, expert_silu_ffn, gate_dispatch)
 

@@ -19,11 +19,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size as _axis_size, pcast as _pcast
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..core.jaxcompat import axis_size as _axis_size, pcast as _pcast, \
-    shard_map
 
 __all__ = ["ring_attention", "ring_self_attention", "zigzag_permutation",
            "zigzag_inverse_permutation"]

@@ -53,11 +53,10 @@ from typing import Any
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.ad_checkpoint import checkpoint_name
+from jax.lax import pcast as _pcast_compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..core.jaxcompat import pcast as _pcast_compat, shard_map
 
 from ..models.llama import LlamaConfig
 from .ring_attention import ring_attention
@@ -364,8 +363,8 @@ def _rms(x, w, eps=1e-6):
 
 
 def _attention(q, k, v):
-    # q/k/v: [m, S, h_loc(, h_kv_loc), d]; causal.  Eligibility + the
-    # one-time Mosaic lowering probe + XLA fallback all live in
+    # q/k/v: [m, S, h_loc(, h_kv_loc), d]; causal.  Eligibility and the
+    # XLA composition for shapes the kernels do not claim live in
     # ops.pallas.flash_attention.attention — the single kernel-selection
     # layer (TPU analog of the reference's flash_attn_kernel.cu dispatch).
     from ..ops.pallas.flash_attention import attention

@@ -37,20 +37,21 @@ _ENV_FORCE = "PADDLE_TPU_TUNE_FORCE"
 
 
 def _default_path() -> str:
-    return os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                        "tuning_cache.json")
+    """Inside the checkout: a kernel's geometry must not depend on a
+    file the commit does not carry."""
+    from ..core.runtime import REPO_ROOT
+    return os.path.join(REPO_ROOT, "tuning_cache.json")
 
 
 def device_kind() -> str:
-    """Canonical device key for cache entries ('cpu', 'tpu-v5-litepod'...).
+    """Canonical device key for cache entries ('cpu', 'tpu-v5-lite'...).
+    Initializes the backend: a parent that hands the chip to children
+    must not call this (it takes the key from an argument or a child).
 
     Imports jax lazily: the cache module itself must stay importable in
     contexts that never touch a backend (the lint CLI, doc tooling)."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        kind = "cpu"
+    import jax
+    kind = jax.devices()[0].device_kind
     return str(kind).strip().lower().replace(" ", "-")
 
 
@@ -232,7 +233,8 @@ _ENV_WARNED: set = set()
 
 def cache_path() -> str:
     """Resolved cache path: explicit set_cache_path() wins, then the
-    PADDLE_TPU_TUNE_CACHE env var, then the per-user default."""
+    PADDLE_TPU_TUNE_CACHE env var, then ``tuning_cache.json`` at the
+    root of the checkout."""
     if _EXPLICIT_PATH is not None:
         return _EXPLICIT_PATH
     return os.environ.get(_ENV_CACHE_PATH) or _default_path()

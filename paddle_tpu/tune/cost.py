@@ -3,20 +3,24 @@
 Off-chip (CPU CI) the autotuner cannot time kernels, but it can still
 rank them: each candidate's runtime is modeled as the roofline max of
 compute time and memory time plus a per-grid-program launch overhead,
-with a VMEM-working-set feasibility gate.  The constants are a generic
-TPU-class device — absolute numbers are meaningless, the RANKING is
-what the sweep persists, and on-chip wall-clock measurement replaces
-this model entirely (``--wall`` mode).
+with a VMEM-working-set feasibility gate.  The peaks are those of one
+named device, ``core.runtime.MODELED_DEVICE``, from the published
+table; the overhead constants were never fitted, so absolute numbers
+are meaningless: the RANKING is what the sweep persists, and on-chip
+wall-clock measurement replaces this model entirely (the default mode
+of autotune.py).
 """
 from __future__ import annotations
 
 import math
 
+from ..core.runtime import MODELED_DEVICE, device_peaks
+
 __all__ = ["estimate", "f32_matmul_estimate", "PEAK_FLOPS", "PEAK_BW",
            "VMEM_BYTES"]
 
-PEAK_FLOPS = 200e12     # flop/s, generic bf16-class systolic peak
-PEAK_BW = 1.0e12        # byte/s HBM
+PEAK_FLOPS = device_peaks(MODELED_DEVICE)["bf16_flops"]
+PEAK_BW = device_peaks(MODELED_DEVICE)["hbm_bytes_per_s"]
 VMEM_BYTES = 64 << 20   # per-core VMEM working-set budget
 PER_PROGRAM_S = 1.2e-6  # grid-program launch/prologue overhead
 PER_TILE_S = 0.1e-6     # per inner-tile loop overhead (k-blocks, pages)

@@ -28,13 +28,16 @@ __all__ = ["CostModelMeasurer", "SubprocessMeasurer", "sweep_kernel",
 
 
 class CostModelMeasurer:
-    """Rank candidates with the roofline model; no jax, no chip."""
+    """Rank candidates with the roofline model; no chip."""
 
     kind = "cost-model"
 
     def measure(self, kernel: TunableKernel, shape: dict,
                 config: dict) -> float:
         return cost.estimate(kernel.name, shape, config)
+
+    def device_kind(self) -> str:
+        return device_kind()
 
 
 # Child source for wall-clock measurement.  It builds a representative
@@ -117,14 +120,11 @@ def build(name, s):
         x = jnp.asarray(rng.randn(m, k), jnp.float32)
         w = jnp.asarray(rng.randn(k, n), jnp.float32)
         q, sc = qm.quantize_weight(w, wdt)
-        if qm.supports(m, k, n, wdt):
-            fn = jax.jit(lambda x, q, sc: qm.matmul(
-                x, q, sc, weight_dtype=wdt))
-        else:
-            # off-chip grace: time the fake-quant reference so
-            # candidates tie and the winner degrades to the defaults
-            fn = jax.jit(lambda x, q, sc: qm.reference_matmul(
-                x, q, sc, wdt))
+        why = qm.ineligible(k, n, wdt)
+        if why is not None:
+            raise SystemExit(f"quant_matmul does not take {s}: {why}")
+        fn = jax.jit(lambda x, q, sc: qm.matmul(
+            x, q, sc, weight_dtype=wdt))
         return fn, (x, q, sc)
     raise SystemExit(f"unknown kernel {name}")
 
@@ -149,6 +149,21 @@ class SubprocessMeasurer:
     def __init__(self, timeout: int = 900, iters: int = 5):
         self.timeout = timeout
         self.iters = iters
+
+    def device_kind(self) -> str:
+        """The device key, asked of a child: a chip belongs to one
+        process at a time, and the candidates' children need it, so the
+        parent of a wall-clock sweep never initializes a backend."""
+        proc = subprocess.run(
+            [sys.executable, "-c",
+             "from paddle_tpu.tune.cache import device_kind; "
+             "print(device_kind())"],
+            capture_output=True, text=True, timeout=self.timeout)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"device probe child failed ({proc.returncode}): "
+                f"{proc.stderr.strip()[-2000:]}")
+        return proc.stdout.strip().splitlines()[-1]
 
     def measure(self, kernel: TunableKernel, shape: dict,
                 config: dict) -> float:
@@ -177,7 +192,7 @@ def sweep_kernel(kernel: TunableKernel, measurer, cache: TuningCache,
 
     Returns report rows: one dict per sweep shape with the winner, the
     default's score, and the modeled/measured speedup."""
-    device = device or device_kind()
+    device = device or measurer.device_kind()
     rows = []
     for shape in kernel.sweep:
         sig = bucket_signature(shape)
@@ -212,7 +227,7 @@ def run_sweep(measurer, cache_file: str, kernels=None,
               device: str | None = None, log=None) -> dict:
     """Sweep (a subset of) the registry, save the cache, return a report."""
     cache = TuningCache(cache_file)
-    device = device or device_kind()
+    device = device or measurer.device_kind()
     names = set(kernels) if kernels else None
     rows = []
     for kern in all_kernels():

@@ -159,10 +159,7 @@ def test_launch_auto_tuner_mode(tmp_path):
     env = dict(os.environ,
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""),
                JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               # the launcher process compiles the probe; share the suite's
-               # persistent compile cache so warm runs don't pay it
-               JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"))
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
     r = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "1", "--log_dir", str(tmp_path / "log"),

@@ -8,9 +8,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from paddle_tpu.core.jaxcompat import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn

@@ -84,7 +84,7 @@ def test_c_softmax_with_cross_entropy_sharded_matches_serial():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
     from paddle_tpu.distributed import collective as C
     from paddle_tpu.distributed.fleet.layers.mpu.mp_ops import (
         _c_softmax_with_cross_entropy,
@@ -130,7 +130,7 @@ def test_sequence_parallel_ops_traced_roundtrip():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.core.jaxcompat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("mp",))
     x = np.random.RandomState(4).randn(8, 4).astype(np.float32)

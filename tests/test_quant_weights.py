@@ -109,7 +109,7 @@ def test_pallas_matmul_matches_reference_oracle(wdt):
     prev = qm.INTERPRET
     qm.INTERPRET = True
     try:
-        assert qm.supports(8, 256, 384, wdt)
+        assert qm.ineligible(256, 384, wdt) is None
         got = np.asarray(qm.matmul(x, q, s, weight_dtype=wdt))
     finally:
         qm.INTERPRET = prev
@@ -118,7 +118,7 @@ def test_pallas_matmul_matches_reference_oracle(wdt):
 
 def test_supports_rejects_unaligned_lanes():
     # N off the 128-lane grid routes callers to the XLA oracle
-    assert not qm.supports(8, 256, 100, "int8")
+    assert "128 lanes" in qm.ineligible(256, 100, "int8")
 
 
 # ---------------------------------------------------------------------------

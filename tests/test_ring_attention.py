@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.core.jaxcompat import shard_map
+from jax import shard_map
 
 from paddle_tpu.parallel import (
     HybridParallelConfig, build_mesh, build_train_step, init_opt_state,

@@ -33,11 +33,13 @@ def _engine(model, **kw):
     return LLMEngine(model, **kw)
 
 
-def _router(model, n=2, policy="affinity", start=True, **ekw):
-    def factory():
+def _router(model, n=2, policy="affinity", start=True, max_pending=None,
+            **ekw):
+    def factory(replica=0):
         return _engine(model, **ekw)
 
-    router = ReplicaRouter(build_replicas(factory(), factory, n),
+    router = ReplicaRouter(build_replicas(factory(), factory, n,
+                                          max_pending=max_pending),
                            policy=policy)
     return router.start() if start else router
 
@@ -72,8 +74,36 @@ def test_build_replicas_requires_factory(model):
         build_replicas(_engine(model), None, 2)
 
 
+def test_recovered_replica_returns_to_its_own_device(model):
+    """build_replicas binds each runner's index into the factory, so a
+    replica rebuilt after a crashed step lands on the device it had."""
+    import jax
+
+    from paddle_tpu.inference.faults import FaultPlan
+
+    def factory(replica=0):
+        return _engine(model, devices=[jax.devices()[replica]])
+
+    runners = build_replicas(factory(), factory, 2)
+    assert [r.engine.devices for r in runners] == [[jax.devices()[0]],
+                                                   [jax.devices()[1]]]
+    old = runners[1].engine
+    old.set_fault_plan(FaultPlan(crash_steps=(2,)))
+    runners[1].start()
+    sink = _Sink()
+    try:
+        runners[1].submit([5, 6, 7], deliver=sink, max_new_tokens=6)
+        _await([sink])
+    finally:
+        assert runners[1].drain(timeout_s=60.0)
+    new = runners[1].engine
+    assert new is not old and sink.out.finish_reason == "length"
+    assert {d for x in jax.tree.leaves(new.params) + [new._kc]
+            for d in x.devices()} == {jax.devices()[1]}
+
+
 def test_router_validates_indexed_runner_names(model):
-    def factory():
+    def factory(replica=0):
         return _engine(model)
 
     runners = build_replicas(factory(), factory, 2)
@@ -82,7 +112,7 @@ def test_router_validates_indexed_runner_names(model):
 
 
 def test_router_rejects_unknown_policy(model):
-    def factory():
+    def factory(replica=0):
         return _engine(model)
 
     with pytest.raises(ValueError, match="policy"):
@@ -177,7 +207,10 @@ def test_32_stream_run_with_aborts_leaves_pools_clean(model):
     4th aborted mid-flight.  Afterwards every replica's page pool must
     hold zero used pages with intact free-list invariants, and the
     router's outstanding-token ledger must read all-zero."""
-    router = _router(model, n=2, policy="affinity")
+    # room for every stream on one replica: routing by tokens sends 17
+    # of the 32 to replica 0, and the default bound of 16 held only when
+    # an engine thread had retired one before the last submit
+    router = _router(model, n=2, policy="affinity", max_pending=32)
     try:
         rng = np.random.RandomState(9)
         sinks = []
@@ -306,7 +339,7 @@ def test_metrics_render_carries_per_replica_series(model):
         router.submit(list(range(10)), deliver=s, max_new_tokens=4)
         _await([s])
         text = render_metrics(router.stats_snapshot(),
-                              engine=router.engine,
+                              engines=router.engines,
                               router=router.router_counters())
         assert "paddle_tpu_replicas 2" in text
         for series in ("replica_outstanding_tokens",

@@ -1,12 +1,13 @@
 """Hardware validation for the Pallas kernels on a REAL TPU chip.
 
-The r2 bench was zeroed by a kernel that passed all interpret-mode tests but
-failed Mosaic lowering on hardware (VERDICT r2 weak #1) — interpret mode
-cannot enforce TPU tiling rules.  These tests compile+run the actual kernels
-whenever a TPU backend is present; on the CPU CI mesh they skip.
+Interpret mode enforces none of Mosaic's tiling rules: flash attention
+once passed every interpret-mode test and failed the (8, 128) block rule
+on its first chip run.  These tests compile and run the actual kernels.
+They are opt-in (the suite runs on the CPU), and once opted in a missing
+TPU is a failure, not a skip.  Through the chip tool:
 
-Run directly (outside the CPU-pinned suite conftest) with:
-    PADDLE_TPU_HW_TESTS=1 python -m pytest tests/test_tpu_hardware.py -q
+    chiprun -- env PADDLE_TPU_HW_TESTS=1 python -m pytest \
+        tests/test_tpu_hardware.py -q -p no:cacheprovider
 """
 import os
 
@@ -20,8 +21,10 @@ if not os.environ.get("PADDLE_TPU_HW_TESTS"):
 import jax
 import jax.numpy as jnp
 
-if jax.default_backend() != "tpu":  # pragma: no cover
-    pytest.skip("no TPU backend", allow_module_level=True)
+if jax.default_backend() != "tpu":
+    raise RuntimeError(
+        "PADDLE_TPU_HW_TESTS=1 asks for the chip and JAX resolved to "
+        f"{jax.default_backend()!r}")
 
 from paddle_tpu.ops.pallas import flash_attention as FA
 from paddle_tpu.ops.pallas import fused_norms as FN
@@ -40,7 +43,7 @@ def test_flash_attention_on_tpu(b, s, h, hk, d, causal):
     q = _rand((b, s, h, d), 0)
     k = _rand((b, s, hk, d), 1)
     v = _rand((b, s, hk, d), 2)
-    assert FA.use_flash(q, k, causal), "lowering probe must accept"
+    assert FA.use_flash(q, k, causal)
     out = jax.jit(lambda q, k, v: FA.attention(q, k, v, causal))(q, k, v)
     ref = FA._ref_attention(q, k, v, causal)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
@@ -99,7 +102,7 @@ def test_varlen_flash_attention_on_tpu():
     q = _rand((T, H, D), 0)
     k = _rand((T, H, D), 1)
     v = _rand((T, H, D), 2)
-    assert FAVL.use_varlen_flash(q, k, True), "varlen lowering probe"
+    assert FAVL.use_varlen_flash(q, k, True)
     sm = 1.0 / float(D) ** 0.5
 
     def oracle(q, k, v):
@@ -202,8 +205,7 @@ def test_paged_decode_on_tpu(H, Hkv, D, bs, nblk):
     bt = jnp.asarray(rng.permutation(num_blocks).reshape(B, nblk),
                      jnp.int32)
     lengths = jnp.asarray([nblk * bs - 7, bs + 3], jnp.int32)
-    assert PA.supports(B, H, Hkv, D, bs, nblk=nblk,
-                       dtype=jnp.bfloat16), "lowering probe must accept"
+    assert PA.ineligible(H, Hkv, D, bs, jnp.bfloat16) is None
     out = jax.jit(PA.paged_decode_attention)(q, kc, vc, bt, lengths)
     ref = PA.paged_decode_reference(q, kc, vc, bt, lengths)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
@@ -243,3 +245,350 @@ def test_varlen_prefill_blha_on_tpu():
     err = float(np.max(np.abs(out.numpy().astype(np.float32)
                               - ref.numpy().astype(np.float32))))
     assert err < 0.06, err
+
+
+# ---------------------------------------------------------------------------
+# serving kernels at the shapes chip_smoke.py launches
+# ---------------------------------------------------------------------------
+
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from chip_smoke import ragged_case  # noqa: E402  (the smoke's own case)
+
+
+def _max_err(out, ref, live):
+    return float(jnp.max(jnp.abs(out[:live].astype(jnp.float32)
+                                 - ref[:live].astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("H,Hkv,dtype,tol", [
+    # bf16 pages: scores accumulate in f32 from exact bf16 products; the
+    # probabilities are rounded to bf16 for the PV matmul and the output
+    # to bf16 (2^-9 relative each, on values of order 1-3)
+    (32, 32, jnp.bfloat16, 2e-2),   # LLaMA-7B / OLMoE: MHA, G = 1
+    (32, 4, jnp.bfloat16, 2e-2),    # GQA 32/4 (Trinity-Mini's shape)
+    # f32 pages: the MXU takes f32 operands in bf16 passes, so this
+    # bound is the bf16 one; an f32-exact kernel would sit near 1e-5
+    (32, 32, jnp.float32, 2e-2),
+])
+def test_ragged_kernel_on_tpu(H, Hkv, dtype, tol):
+    """The serving kernel at head width 128, block size 16: G = H/Hkv
+    rows per program (one for MHA), compared with the dense-gather
+    reference computed in float32 at highest matmul precision."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    D, bs, nblk = 128, 16, 8
+    rng = np.random.RandomState(5)
+    q, bt, seg, rel, num_blocks, live = ragged_case(
+        rng, H, D, bs, nblk, dtype)
+    kc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
+    vc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
+    assert PA.ineligible(H, Hkv, D, bs, dtype) is None
+    out = jax.jit(PA.ragged_paged_attention_segrel_packed)(
+        q, kc, vc, bt, seg, rel)
+    with jax.default_matmul_precision("highest"):
+        ref = PA.ragged_paged_reference_segrel(
+            q.astype(jnp.float32), kc.astype(jnp.float32),
+            vc.astype(jnp.float32), bt, seg, rel)
+    err = _max_err(out, ref, live)
+    print(f"ragged H={H} Hkv={Hkv} {jnp.dtype(dtype).name}: "
+          f"max abs err {err:.3e}")
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert err < tol, err
+
+
+def _int8_page_kernel_err(H, Hkv, pool=None):
+    """The int8-page kernel against its reference on the smoke's case,
+    over a pool of ``pool`` pages (default: the pages the case uses)."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    D, bs, nblk = 128, 32, 4
+    rng = np.random.RandomState(6)
+    q, bt, seg, rel, num_blocks, live = ragged_case(
+        rng, H, D, bs, nblk, jnp.bfloat16)
+    num_blocks = pool or num_blocks
+    kc = jnp.asarray(rng.randint(-127, 128, (num_blocks, Hkv, bs, D),
+                                 dtype=np.int8))
+    vc = jnp.asarray(rng.randint(-127, 128, (num_blocks, Hkv, bs, D),
+                                 dtype=np.int8))
+    ks = jnp.asarray(rng.uniform(0.5, 1.5, (num_blocks, Hkv)) / 127.0,
+                     jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.5, 1.5, (num_blocks, Hkv)) / 127.0,
+                     jnp.float32)
+    assert PA.ineligible(H, Hkv, D, bs, jnp.int8, launch=(
+        q.shape[0], bt.shape[0], nblk, num_blocks)) is None
+    out = jax.jit(PA.ragged_paged_attention_quant_segrel_packed)(
+        q, kc, vc, ks, vs, bt, seg, rel)
+    with jax.default_matmul_precision("highest"):
+        ref = PA.ragged_paged_reference_quant_segrel(
+            q.astype(jnp.float32), kc, vc, ks, vs, bt, seg, rel)
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    return _max_err(out, ref, live)
+
+
+@pytest.mark.parametrize("H,Hkv", [(32, 32), (32, 4)])
+def test_ragged_int8_page_kernel_on_tpu(H, Hkv):
+    """Int8 pages at block size 32 (the int8 (32, 128) tile), scales in
+    SMEM.  The kernel dequantizes to f32 before both matmuls, so the
+    bound is the MXU's bf16-pass bound on f32 operands of order |int8 *
+    scale| ~ 1."""
+    err = _int8_page_kernel_err(H, Hkv)
+    print(f"ragged int8 pages H={H} Hkv={Hkv}: max abs err {err:.3e}")
+    assert err < 2e-2, err
+
+
+def test_int8_page_kernel_at_the_scalar_memory_claim():
+    """The largest int8 page pool ``ineligible`` admits beside this
+    launch's block table compiles (both scale pools ride in scalar
+    memory; 1025 pages overran it at compile time), and the claim is
+    withdrawn eight pages later."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    launch = (32, 4, 4)                 # ragged_case: Tq, table rows, nblk
+
+    def why(n):
+        return PA.ineligible(32, 32, 128, 32, jnp.int8, launch=(*launch, n))
+
+    pool = max(n for n in range(8, 2048, 8) if why(n) is None)
+    assert "scalar memory" in why(pool + 8)
+    need = PA.scalar_prefetch_bytes(*launch, pool, 32, True)
+    err = _int8_page_kernel_err(32, 32, pool=pool)
+    print(f"int8 pages, pool of {pool}: prefetched operands "
+          f"{need >> 10} KiB, max abs err {err:.3e}")
+    assert err < 2e-2, err
+
+
+@pytest.mark.parametrize("K,N", [(4096, 4096), (4096, 11008),
+                                 (11008, 4096), (4096, 32000)])
+def test_quant_matmul_bf16_activations_on_tpu(K, N):
+    """The fused dequant matmul as a bf16 engine launches it at decode:
+    M = max_num_seqs = 8 rows of bf16 activations against LLaMA-7B's
+    projection, MLP and head widths.  The oracle is the dense fake-quant
+    product in float32 at highest precision.  The kernel upcasts x and
+    the dequantized block to f32 and the MXU takes them in bf16 passes:
+    a K-term sum of products of order 1 * 0.02 accumulates a relative
+    error of about 2^-9, so the bound scales with the output's own
+    magnitude (sqrt(K) * 0.02 * 0.6)."""
+    from paddle_tpu.ops.pallas import quant_matmul as QM
+
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(8, K), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(K, N) * 0.02, jnp.float32)
+    q, s = QM.quantize_weight(w, "int8")
+    assert QM.ineligible(K, N, "int8") is None
+    out = jax.jit(lambda x, q, s: QM.matmul(x, q, s, weight_dtype="int8"))(
+        x, q, s)
+    with jax.default_matmul_precision("highest"):
+        ref = QM.reference_matmul(x, q, s, "int8")
+    scale = float(jnp.max(jnp.abs(ref)))
+    err = float(jnp.max(jnp.abs(out - ref)))
+    print(f"quant_matmul K={K} N={N}: max abs err {err:.3e} "
+          f"(max |ref| {scale:.3f})")
+    assert out.dtype == jnp.float32 and out.shape == (8, N)
+    assert err < 1e-2 * scale, (err, scale)
+
+
+# ---------------------------------------------------------------------------
+# LLMEngine at LLaMA-7B widths (depth cut to 2), every path the smoke's
+# float engine does not take
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def llama7b_width_model():
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig.llama_7b()
+    cfg.num_hidden_layers = 2           # depth cut; every width as published
+    paddle.seed(0)
+    return LlamaForCausalLM(cfg).to(dtype="bfloat16")
+
+
+def _serve(engine, vocab, n_new=12):
+    """Three requests through engine.run(): a prompt of one bucket, a
+    prompt chunked over two launches, a short one.  Returns the
+    generated tokens in submission order."""
+    rng = np.random.RandomState(11)
+    outs = {}
+    rids = [engine.add_request(rng.randint(1, vocab, n).tolist(),
+                               max_new_tokens=n_new,
+                               on_finish=lambda o: outs.update({o.rid: o}))
+            for n in (20, 150, 5)]
+    engine.run()
+    for rid in rids:
+        out = outs[rid]
+        # a non-finite logit row is quarantined, which ends the request
+        # with its own finish reason
+        assert out.finish_reason == "length", out.finish_reason
+        assert len(out.generated) == n_new
+        assert all(0 <= t < vocab for t in out.generated)
+    return [outs[rid].generated for rid in rids]
+
+
+@pytest.mark.parametrize("name,kw,matmul", [
+    ("bf16 pages", {}, "xla-dense"),
+    ("int8 pages", {"kv_dtype": "int8", "block_size": 32}, "xla-dense"),
+    ("int8 weights", {"weight_dtype": "int8"}, "pallas-quant"),
+    ("window 4", {"decode_window": 4}, "xla-dense"),
+    ("window 4, int8 pages",
+     {"decode_window": 4, "kv_dtype": "int8", "block_size": 32},
+     "xla-dense"),
+    ("window 4, int8 weights",
+     {"decode_window": 4, "weight_dtype": "int8"}, "pallas-quant"),
+])
+def test_engine_variants_on_tpu(llama7b_width_model, name, kw, matmul):
+    """Each engine variant compiles its kernels on the chip (a refusal
+    by Mosaic raises out of run()), takes no reference path, and serves
+    finite tokens.  With donated page pools and a launch in flight under
+    overlap=True this is also the first run of both off the CPU."""
+    import gc
+
+    from paddle_tpu.inference import LLMEngine
+
+    kw = {"block_size": 16, **kw}
+    eng = LLMEngine(llama7b_width_model, max_num_seqs=8, max_model_len=256,
+                    max_prefill_tokens=128, **kw)
+    assert eng.attention_path == "pallas", eng.attention_path
+    assert eng.matmul_path == matmul, eng.matmul_path
+    toks = _serve(eng, eng.config.vocab_size)
+    paths = eng.paths()
+    print(f"{name}: programs {sorted(paths['programs'])} on "
+          f"{paths['devices']} ({paths['device_kind']}); first tokens "
+          f"{[t[:4] for t in toks]}")
+    assert all(p == {"attention": "pallas", "matmul": matmul}
+               for p in paths["programs"].values()), paths
+    if kw.get("decode_window", 1) > 1:
+        assert "window:4" in paths["programs"], paths
+    del eng
+    gc.collect()
+
+
+# ---------------------------------------------------------------------------
+# four chips (chiprun --chips 4); skipped on a one-chip machine
+# ---------------------------------------------------------------------------
+
+four_chips = pytest.mark.skipif(len(jax.devices()) < 4,
+                                reason="needs a four-chip host")
+
+
+def _cli_engines(argv):
+    """Engines built the way the frontend CLI builds them."""
+    from paddle_tpu.inference.frontend import __main__ as cli
+
+    args = cli._parser().parse_args(
+        ["--model", "llama-7b", "--layers", "2", "--dtype", "bfloat16",
+         "--max-model-len", "256", "--max-prefill-tokens", "128", *argv])
+    make_engine = cli._build_engine(args, cli._model_config(args))
+    return make_engine, args
+
+
+@four_chips
+def test_tp4_engine_on_tpu():
+    """--tp 4: the ragged kernel inside shard_map, 8 heads a shard."""
+    make_engine, _ = _cli_engines(["--tp", "4"])
+    eng = make_engine()
+    assert eng.attention_path == "pallas"
+    for name, x in (("wq", eng.params["layers"]["wq"]),
+                    ("wo", eng.params["layers"]["wo"]),
+                    ("k pages", eng._kc)):
+        shards = [(str(s.device), s.data.shape)
+                  for s in x.addressable_shards]
+        print(f"tp=4 {name}: {shards}")
+        assert len({d for d, _ in shards}) == 4
+    toks = _serve(eng, eng.config.vocab_size)
+    paths = eng.paths()
+    print(f"tp=4 programs {sorted(paths['programs'])} on "
+          f"{paths['devices']}; first tokens {[t[:4] for t in toks]}")
+    assert len(paths["devices"]) == 4
+    assert all(p["attention"] == "pallas"
+               for p in paths["programs"].values()), paths
+
+
+@four_chips
+def test_four_replicas_each_on_its_own_chip():
+    """--replicas 4: every replica's weights and pages on a device of
+    its own, and every replica serves."""
+    import threading
+
+    from paddle_tpu.inference.frontend import ReplicaRouter, build_replicas
+
+    make_engine, _ = _cli_engines(["--replicas", "4"])
+    runners = build_replicas(make_engine(0), make_engine, 4)
+    devices = []
+    for i, r in enumerate(runners):
+        e = r.engine
+        where = {str(d) for x in (e.params["layers"]["wq"], e._kc)
+                 for d in x.devices()}
+        print(f"replica {i}: weights and pages on {sorted(where)}")
+        assert len(where) == 1
+        devices.append(where.pop())
+    assert len(set(devices)) == 4, devices
+    # the stacked copy is built on the replica's device: only chip 0,
+    # which holds the model itself, may peak above the others
+    for d in jax.devices():
+        m = d.memory_stats()
+        print(f"{d}: {m['bytes_in_use'] / 1e9:.2f} GB in use, peak "
+              f"{m['peak_bytes_in_use'] / 1e9:.2f} GB")
+    router = ReplicaRouter(runners, policy="least").start()
+    try:
+        rng = np.random.RandomState(13)
+        done = []
+        for _ in range(8):              # together, so that load spreads
+            ev = threading.Event()
+            out = {}
+
+            def deliver(e, ev=ev, out=out):
+                if e[0] == "finish":
+                    out["finish"] = e[1]
+                    ev.set()
+            router.submit(rng.randint(1, 32000, 30).tolist(),
+                          deliver=deliver, max_new_tokens=8)
+            done.append((ev, out))
+        for ev, out in done:
+            assert ev.wait(600.0), "request never finished"
+            assert out["finish"].finish_reason == "length"
+            assert len(out["finish"].generated) == 8
+    finally:
+        router.close()
+    routed = router.router_counters()["routed_requests"]
+    print(f"requests per replica: {routed}")
+    assert all(n > 0 for n in routed), routed
+    for i, e in enumerate(router.engines):
+        paths = e.paths()
+        print(f"replica {i}: programs {sorted(paths['programs'])} on "
+              f"{paths['devices']}")
+        assert paths["programs"] and all(
+            p["attention"] == "pallas"
+            for p in paths["programs"].values()), paths
+
+
+@four_chips
+def test_train_step_on_four_chips():
+    """Three steps of the hybrid trainer over a dp=2 x tp=2 mesh, bf16,
+    head width 128 so that the flash kernels run: the loss is finite and
+    falls on a repeated batch."""
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.parallel import (
+        HybridParallelConfig, build_mesh, build_train_step, init_opt_state,
+        init_params, shard_opt_state, shard_params)
+
+    cfg = LlamaConfig(vocab_size=32000, hidden_size=1024,
+                      intermediate_size=2816, num_hidden_layers=4,
+                      num_attention_heads=8, num_key_value_heads=8,
+                      max_position_embeddings=512)
+    hp = HybridParallelConfig(dp=2, pp=1, tp=2, dtype=jnp.bfloat16)
+    mesh = build_mesh(hp)
+    print(f"mesh {dict(mesh.shape)}: {[str(d) for d in mesh.devices.flat]}")
+    params = shard_params(init_params(cfg, hp, seed=0), hp, mesh)
+    opt = shard_opt_state(init_opt_state(params), hp, mesh)
+    step = build_train_step(cfg, hp, mesh)
+    tokens = jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (4, 512)), jnp.int32)
+    losses = []
+    for _ in range(3):
+        params, opt, loss = step(params, opt, tokens)
+        losses.append(float(loss))
+    print(f"losses {losses}")
+    assert all(np.isfinite(x) for x in losses), losses
+    assert losses[-1] < losses[0], losses

@@ -43,12 +43,14 @@ def main(argv=None):
     ap.add_argument("--cache", default=None,
                     help="cache file to write (default: the resolved "
                          "runtime path — PADDLE_TPU_TUNE_CACHE or "
-                         "~/.cache/paddle_tpu/tuning_cache.json)")
+                         "tuning_cache.json at the root of the checkout)")
     ap.add_argument("--kernel", action="append", default=None,
                     help="restrict the sweep to this kernel (repeatable)")
     ap.add_argument("--device", default=None,
-                    help="override the device key (default: the attached "
-                         "backend's device kind)")
+                    help="the device key (default: the attached "
+                         "backend's device kind; a wall-clock sweep asks "
+                         "a child for it, so that this process never "
+                         "takes the chip its children need)")
     ap.add_argument("--iters", type=int, default=5,
                     help="timing iterations per candidate (wall-clock)")
     ap.add_argument("--timeout", type=int, default=900,

@@ -140,11 +140,10 @@ int8|int4`` threads quantized weight pools (every record carries
 an N-way tensor-parallel mesh (host devices forced on CPU) through
 every engine the bench builds.
 
-Hardening contract (same as bench.py): the JSON line ALWAYS prints.  The
-backend is probed in a subprocess with a hard timeout before this process
-initializes jax; TPU-plugin failure/hang degrades to a CPU run (the paged
-kernel runs in interpret mode there) with the fallback recorded in
-"backend".  Any engine failure prints the line with an "error" field.
+The run computes on the device JAX resolves and names it in "backend" and
+"device".  Finding no accelerator is an error; a CPU run happens only
+under an explicit JAX_PLATFORMS=cpu.  A mode that raises ends the run with
+its traceback and a non-zero exit code.
 
   python tools/perf/serve_bench.py [--smoke] [--requests N] [--seed S]
                                    [--prefix-share K]
@@ -163,39 +162,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 def _emit(record):
     print(json.dumps(record))
     sys.stdout.flush()
-
-
-def _probe_backend(timeout_s: float = 110.0):
-    """(backend, error_or_None) — subprocess probe, never raises/hangs."""
-    import subprocess
-    import time
-
-    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-        # the caller pinned the platform (CI does, for every test in
-        # the suite): jax can't resolve anything else, so the probe
-        # subprocess would only re-pay a whole jax import to confirm it
-        return "cpu", "JAX_PLATFORMS pinned to cpu"
-
-    err = None
-    for attempt in range(2):
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c",
-                 "import jax; print(jax.default_backend())"],
-                capture_output=True, text=True, timeout=timeout_s)
-            if out.returncode == 0 and out.stdout.strip():
-                backend = out.stdout.strip().splitlines()[-1]
-                if backend != "cpu":
-                    return backend, None
-                err = "probe resolved to cpu"
-                break
-            err = (out.stderr or "").strip()[-300:] or f"rc={out.returncode}"
-        except subprocess.TimeoutExpired:
-            err = f"backend init hang (> {timeout_s}s)"
-        if attempt == 0:
-            time.sleep(5.0)
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    return "cpu", err
 
 
 def _request_stream(rng, n_requests, vocab, max_len):
@@ -802,9 +768,20 @@ def run_router_bench(smoke: bool, n_requests: int, share_ways: int,
                           share_ways, cfg.vocab_size,
                           engine_kw["max_model_len"])
 
-    def make_engine():
+    import jax
+    devs = jax.devices()
+    if backend != "cpu" and len(devs) < replicas * tp:
+        raise RuntimeError(
+            f"{replicas} replicas x tp={tp} need {replicas * tp} devices, "
+            f"JAX has {len(devs)}: replicas sharing a chip measure nothing")
+
+    def make_engine(replica=0):
+        # each replica on devices of its own (the CPU toy shares one)
+        own = devs[replica * tp:(replica + 1) * tp]
         return LLMEngine(model, retain_outputs=False, kv_dtype=kv_dtype, weight_dtype=weight_dtype,
-                         enable_prefix_caching=True, tp=tp, **engine_kw)
+                         enable_prefix_caching=True, tp=tp,
+                         devices=own if len(own) == tp else None,
+                         **engine_kw)
 
     runs = {}
     for policy in ("random", "affinity"):
@@ -1046,7 +1023,7 @@ def run_mixed_bench(smoke: bool, n_requests: int, seed: int, backend: str,
         # spans from all four tiers next to the engine-direct timeline
         from paddle_tpu.inference.frontend import serve_background
 
-        def _factory():
+        def _factory(replica=0):
             # same overlap arm as the headline engine, so the dumped
             # trace is internally consistent (an --overlap off artifact
             # carries zero engine.device_inflight windows anywhere)
@@ -1791,12 +1768,16 @@ def main(argv=None):
     if args.tp > 1 and "xla_force_host_platform_device_count" \
             not in os.environ.get("XLA_FLAGS", ""):
         # must land before this process's first jax import (they are all
-        # function-local below); the probe subprocess inherits it too
+        # function-local below)
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.tp}").strip()
 
-    backend, probe_err = _probe_backend()
+    from paddle_tpu.core.runtime import (configure_compile_cache,
+                                         resolve_device)
+    configure_compile_cache()
+    device = resolve_device()
+    backend = device["platform"]
     if args.http and args.replicas > 1:
         n_requests = args.requests or (16 if (args.smoke
                                               or backend == "cpu") else 64)
@@ -1853,8 +1834,7 @@ def main(argv=None):
     record["tp"] = args.tp
     record["replicas"] = args.replicas
     record["weight_dtype"] = args.weight_dtype
-    if probe_err:
-        record["backend_note"] = f"cpu fallback: {probe_err}"
+    record["device"] = device
     tracer = None
     if args.trace:
         if args.mixed:
@@ -1862,68 +1842,63 @@ def main(argv=None):
             tracer = Tracer()
         else:
             record["trace_note"] = "--trace records the --mixed workload"
-    try:
-        if args.http and args.replicas > 1:
-            record.update(run_router_bench(
-                args.smoke, n_requests, args.prefix_share or 4,
-                args.seed, backend, args.kv_dtype, args.replicas,
-                args.tp, weight_dtype=args.weight_dtype))
-        elif args.decode_window:
-            record.update(run_window_bench(
-                args.smoke, n_requests, args.decode_window, args.seed,
-                backend, args.kv_dtype, args.tp,
-                weight_dtype=args.weight_dtype))
-        elif args.weight_pressure:
-            record.update(run_weight_bench(args.smoke, n_requests,
-                                           args.seed, backend,
-                                           args.weight_dtype,
-                                           kv_dtype=args.kv_dtype,
-                                           tp=args.tp))
-        elif args.memory_pressure:
-            record.update(run_pressure_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp,
-                weight_dtype=args.weight_dtype,
-                host_kv_bytes=args.host_kv_bytes))
-        elif args.chaos:
-            record.update(run_chaos_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
-        elif args.mixed:
-            record.update(run_mixed_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp, tracer=tracer,
-                overlap=args.overlap,
-                weight_dtype=args.weight_dtype,
-                dump_workload=args.dump_workload))
-        elif args.slo:
-            record.update(run_slo_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
-        elif args.http:
-            record.update(run_http_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
-        elif args.spec:
-            record.update(run_spec_bench(
-                args.smoke, n_requests, args.spec, args.seed, backend,
-                args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
-        elif args.prefix_share:
-            record.update(run_prefix_bench(
-                args.smoke, n_requests, args.prefix_share, args.seed,
-                backend, args.kv_dtype, args.tp,
-                weight_dtype=args.weight_dtype))
-        else:
-            record.update(run_bench(
-                args.smoke, n_requests, args.seed, backend,
-                args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
-        if probe_err:
-            record["backend_note"] = f"cpu fallback: {probe_err}"
-        record["tp"] = args.tp
-        record["replicas"] = args.replicas
-        record["weight_dtype"] = args.weight_dtype
-    except Exception as e:  # the line must still print
-        record["error"] = f"{type(e).__name__}: {e}"
+    if args.http and args.replicas > 1:
+        record.update(run_router_bench(
+            args.smoke, n_requests, args.prefix_share or 4,
+            args.seed, backend, args.kv_dtype, args.replicas,
+            args.tp, weight_dtype=args.weight_dtype))
+    elif args.decode_window:
+        record.update(run_window_bench(
+            args.smoke, n_requests, args.decode_window, args.seed,
+            backend, args.kv_dtype, args.tp,
+            weight_dtype=args.weight_dtype))
+    elif args.weight_pressure:
+        record.update(run_weight_bench(args.smoke, n_requests,
+                                       args.seed, backend,
+                                       args.weight_dtype,
+                                       kv_dtype=args.kv_dtype,
+                                       tp=args.tp))
+    elif args.memory_pressure:
+        record.update(run_pressure_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp,
+            weight_dtype=args.weight_dtype,
+            host_kv_bytes=args.host_kv_bytes))
+    elif args.chaos:
+        record.update(run_chaos_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
+    elif args.mixed:
+        record.update(run_mixed_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp, tracer=tracer,
+            overlap=args.overlap,
+            weight_dtype=args.weight_dtype,
+            dump_workload=args.dump_workload))
+    elif args.slo:
+        record.update(run_slo_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
+    elif args.http:
+        record.update(run_http_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
+    elif args.spec:
+        record.update(run_spec_bench(
+            args.smoke, n_requests, args.spec, args.seed, backend,
+            args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
+    elif args.prefix_share:
+        record.update(run_prefix_bench(
+            args.smoke, n_requests, args.prefix_share, args.seed,
+            backend, args.kv_dtype, args.tp,
+            weight_dtype=args.weight_dtype))
+    else:
+        record.update(run_bench(
+            args.smoke, n_requests, args.seed, backend,
+            args.kv_dtype, args.tp, weight_dtype=args.weight_dtype))
+    record["tp"] = args.tp
+    record["replicas"] = args.replicas
+    record["weight_dtype"] = args.weight_dtype
     # every record carries a workload fingerprint; modes that build
     # their stream internally (mixed) stamp a richer one themselves
     record.setdefault("workload_fingerprint", _workload_fingerprint({
