@@ -26,12 +26,26 @@ def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it by itself
-    and this sets nothing.  Where it is not, the cache goes to
-    ``DEFAULT_CACHE_DIR``."""
+    and this sets no directory.  Where it is not, the cache goes to
+    ``DEFAULT_CACHE_DIR``.
+
+    Either way the process lowers with no Python frames in its MLIR
+    locations (``jax_traceback_in_locations_limit`` 0, JAX's own
+    option, set once and for the whole process).  XLA strips its own
+    metadata from the cache's key, but Mosaic serializes a Pallas
+    kernel's module, locations and all, into the custom call's payload,
+    which the key takes as it is: with frames in it a step program
+    reached from another line, another depth of the call stack or
+    another checkout compiled again, 80 to 95 s each on the v5e
+    (PERF.md section 6; tests/test_chip_lowering.py holds the payload
+    to the kernel alone).  The names of scopes and operations stay in
+    the locations; what goes is the source line in HLO metadata and in a
+    message of Mosaic's about a kernel."""
+    import jax
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR
 

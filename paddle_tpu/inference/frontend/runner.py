@@ -393,16 +393,18 @@ class EngineRunner:
             self._by_rid[rid] = h
         return True
 
-    def _admit_inbox(self, gen: int) -> None:
+    def _admit_inbox(self, gen: int) -> int:
+        """Hand the inbox to the engine; returns how many it took in."""
         eng = self.engine
+        taken = 0
         while True:
             with self._lock:
                 if gen != self._gen or not self._inbox:
-                    return
+                    return taken
                 h = self._inbox.popleft()
             if h.done:                # aborted while still queued
                 continue
-            self._admit_one(eng, h, gen)
+            taken += self._admit_one(eng, h, gen)
 
     def _apply_aborts(self, gen: int) -> None:
         while True:
@@ -547,6 +549,7 @@ class EngineRunner:
             time.sleep(poll)
 
     def _loop(self, gen: int) -> None:
+        t_back = 0          # tracer clock when engine.step() last returned
         while True:
             with self._lock:
                 if self._stopped or gen != self._gen:
@@ -555,8 +558,17 @@ class EngineRunner:
             try:
                 self._apply_aborts(gen)
                 self._sweep_deadlines(gen)
-                self._admit_inbox(gen)
+                taken = self._admit_inbox(gen)
                 if eng.has_unfinished():
+                    tr = self._tracer()
+                    if tr is not None and t_back:
+                        # the loop's own turn between two steps: aborts,
+                        # deadlines, intake.  A stall here is the
+                        # runner's, not the engine's (step.stall_s).
+                        tr.complete("runner.between_steps", t_back,
+                                    track=self._trace_track,
+                                    args={"step": eng.launches + 1,
+                                          "taken": taken})
                     self._step_started = (gen, time.monotonic())
                     try:
                         eng.step()
@@ -564,6 +576,7 @@ class EngineRunner:
                         ss = self._step_started
                         if ss is not None and ss[0] == gen:
                             self._step_started = None
+                    t_back = tr.now() if tr is not None else 0
                     continue
             except Exception:
                 # the boundary that must keep running: say what failed
@@ -579,5 +592,6 @@ class EngineRunner:
                 idle = not self._inbox and not self._aborts \
                     and not self._stopped
             if idle:
+                t_back = 0      # an idle wait is not a turn between steps
                 self._wake.wait(self.idle_wait_s)
                 self._wake.clear()

@@ -64,6 +64,8 @@ for that request.
 """
 from __future__ import annotations
 
+import contextlib
+import re
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -77,7 +79,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.llama import _rms_weight, _rope_positions
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
-from ..profiler import RecordEvent, ServingStats
+from ..profiler import ServingStats
 from .faults import InjectedFault
 from .kv_cache import (NULL_BLOCK, BlockManager, BlockPoolExhausted,
                        prefix_chain_hashes)
@@ -154,6 +156,8 @@ class _StepTicket:
     dispatch_s: float                 # host seconds packing + launching
     t_launch: float                   # perf_counter at launch return
     launch_ns: int                    # tracer clock at launch (0 untraced)
+    step: int = 0                     # id of this launch: engine.launches
+                                      # when it was dispatched
     inflight: bool = False            # crossed a step() boundary in flight
     window: int = 0                   # K of a decode-window launch (0 =
                                       # per-step; sampled/fin are [K, B])
@@ -188,6 +192,58 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _named(fn, name: str):
+    """``fn`` under a stable ``__name__``: ``jax.jit`` names the module
+    after it (``jit_ragged_step_t192``), and a device trace names each
+    execution after the module."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(?:\([^=]*?\)|\S+)\s+"
+    r"([\w\-]+)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+def _instruction_scopes(hlo_text: str) -> dict:
+    """{instruction name: {"op_name", "dot"}} of one compiled module's
+    text: ``op_name`` is the instruction's metadata path, which holds
+    the ``jax.named_scope`` names ("jit(ragged_step_t32)/layers/while/
+    body/qkv/dot_general"; empty where XLA made the instruction up),
+    ``dot`` whether it is a matrix product or a fusion that holds one.
+    A device trace names each operation by its instruction's name."""
+    out, calls, holds_dot = {}, {}, {}
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            holds_dot[comp] = False
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        name, opcode = m.group(1), m.group(2)
+        op = _HLO_OP_NAME.search(line)
+        dot = opcode in ("dot", "convolution")
+        holds_dot[comp] = holds_dot[comp] or dot
+        out[name] = {"op_name": op.group(1) if op else "", "dot": dot}
+        c = _HLO_CALLS.search(line)
+        if c and opcode == "fusion":
+            calls[name] = c.group(1)
+    for name, called in calls.items():
+        out[name]["dot"] = holds_dot.get(called, False)
+    return out
+
+
+# what wraps a launch when no tracer is installed: the jitted call keeps
+# one call site either way (see ``_call_program``)
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 class LLMEngine:
@@ -520,6 +576,10 @@ class LLMEngine:
         # what the pre-ragged four-program engine would have padded to
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0}
+        # launches dispatched so far: the STEP ID every trace event
+        # carries (the dispatch half of a step the id of the launch it
+        # prepares, launches + 1; the completion half its ticket's)
+        self.launches = 0
         self._evictions_seen = 0
         self.peak_resident_seqs = 0
         self.stats = ServingStats()
@@ -536,7 +596,9 @@ class LLMEngine:
         # the same zero-cost contract the fault plan keeps
         self.tracer = None
         self._trace_track = "engine"
-        self._trace_steps = 0
+        self._commit_step = 0         # the ticket's id while it commits
+        self._cow_n = 0               # CoW launches and their host time
+        self._cow_ns = 0              # since engine.schedule began
         # resolve this engine's launch geometry from the tuning cache
         # once at build — pure host-side dict reads (no compile) whose
         # provenance summary() and serve_bench records surface
@@ -565,10 +627,15 @@ class LLMEngine:
     def set_tracer(self, tracer) -> None:
         """Install (or clear) a step-timeline Tracer on this engine (and
         on its fault plan, so injected faults land in the trace).  With
-        None installed the step loop performs no trace work at all."""
+        None installed the step loop performs no trace work at all.  A
+        tracer also watches the collector (``host.gc`` spans) for as
+        long as some engine has it installed."""
+        if self.tracer is not None:
+            self.tracer.unwatch_gc(self)
         self.tracer = tracer
         if tracer is not None:
             self._trace_track = tracer.register("engine")
+            tracer.watch_gc(self)
         if self.fault_plan is not None:
             self.fault_plan.tracer = tracer
             self.fault_plan.trace_track = self._trace_track
@@ -879,7 +946,7 @@ class LLMEngine:
                                  "replayed": len(generated),
                                  "max_new_tokens": int(max_new_tokens)})
             tr.instant("request.queued", track=self._trace_track,
-                       args={"rid": rid})
+                       args={"rid": rid, "step": self.launches + 1})
         return rid
 
     def has_unfinished(self) -> bool:
@@ -1081,6 +1148,10 @@ class LLMEngine:
         out["weight_bytes_resident_per_shard"] = \
             self.weight_bytes_resident_per_shard()
         out["peak_resident_seqs"] = self.peak_resident_seqs
+        # counted where the work is launched (_launch_ragged, the window
+        # drain): real against padded query tokens
+        out["tokens_real"] = self.pad_stats["real"]
+        out["tokens_padded"] = self.pad_stats["padded"]
         out["paths"] = self.paths()
         out["tuning_cache"] = {
             "path": self._tuning_report["path"],
@@ -1177,6 +1248,87 @@ class LLMEngine:
         pressure controller is installed)."""
         return 0 if self.pressure is None else self.pressure.tier_entries
 
+    def _pool_structs(self, placed: bool = False) -> tuple:
+        """ShapeDtypeStructs of what every program takes after the
+        parameters: the page pools, then in int8 mode the scale pools
+        (the fresh-page mask of the step programs is not among them).
+        ``placed``: with the live arrays' shardings, so that lowering
+        from the shapes gives the program the engine runs."""
+        pools = (self._kc, self._vc)
+        if self.kv_dtype == "int8":
+            pools += (self._ks, self._vs)
+        return tuple(jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if placed else None)
+            for x in pools)
+
+    def _step_head_structs(self, placed: bool = False) -> tuple:
+        """(params, pools..., fresh mask in int8 mode) as shapes: the
+        leading arguments of both step programs."""
+        sds = jax.ShapeDtypeStruct
+        params = jax.tree_util.tree_map(
+            lambda x: sds(np.shape(x), x.dtype, sharding=getattr(
+                x, "sharding", None) if placed else None), self.params)
+        head = (params,) + self._pool_structs(placed)
+        if self.kv_dtype == "int8":
+            head += (sds((self._kc.shape[1],), jnp.bool_),)
+        return head
+
+    def _ragged_arg_structs(self, Tq: int, placed: bool = False) -> tuple:
+        """The ragged step program's arguments at token bucket ``Tq`` as
+        ShapeDtypeStructs: what ``program_specs`` audits and what a test
+        or ``program_scopes`` lowers, with nothing allocated or run."""
+        sds = jax.ShapeDtypeStruct
+        i32 = jnp.int32
+        B = self.max_num_seqs
+        return self._step_head_structs(placed) + (
+            sds((Tq,), i32), sds((B + 1,), i32), sds((B,), i32),
+            sds((B + 1, self.nblk), i32), sds((self._Lq,), i32),
+            samp_structs(self._Lq, self.config.vocab_size))
+
+    def _window_arg_structs(self, placed: bool = False) -> tuple:
+        """The decode-window driver's arguments as ShapeDtypeStructs:
+        the [B]-wide carry seeds plus the per-row freeze/key inputs."""
+        sds = jax.ShapeDtypeStruct
+        i32 = jnp.int32
+        B = self.max_num_seqs
+
+        def seqs():
+            return sds((B,), i32)
+
+        return self._step_head_structs(placed) + (
+            seqs(), seqs(), sds((B,), jnp.bool_), seqs(), seqs(), seqs(),
+            sds((B, 2), jnp.uint32), sds((B + 1, self.nblk), i32),
+            samp_structs(B, self.config.vocab_size))
+
+    def program_scopes(self, buckets=None) -> dict:
+        """{program name: {instruction name: {"op_name", "dot"}}} for
+        the step programs: the ragged program of each of ``buckets``
+        (default: those built so far) and the decode window's if it is
+        built, under the names the jit was given (``ragged_step_t192``).
+
+        The TPU's trace names a device operation by its HLO
+        instruction ("fusion.199") and carries nothing of the
+        ``jax.named_scope`` it ran under; the compiled module's text
+        does (``op_name``).  This is the map between the two, for
+        whoever reads a device trace (``benchmark/harness/scopes.py``).
+        Built only when asked: each program is lowered from shapes and
+        compiled again, which the persistent compilation cache turns
+        into a read; nothing here runs in a serving step or in set-up."""
+        if buckets is None:
+            buckets = sorted(self._ragged_progs)
+        out = {}
+        for Tq in buckets:
+            compiled = self._get_ragged_prog(Tq).lower(
+                *self._ragged_arg_structs(Tq, placed=True)).compile()
+            out[f"ragged_step_t{Tq}"] = _instruction_scopes(
+                compiled.as_text())
+        if self._window_prog is not None:
+            compiled = self._window_prog.lower(
+                *self._window_arg_structs(placed=True)).compile()
+            out[f"decode_window_k{self.decode_window}"] = \
+                _instruction_scopes(compiled.as_text())
+        return out
+
     def program_specs(self, *, large_bytes: int = 1 << 20) -> list:
         """Every program this engine compiles, as analysis ProgramSpecs.
 
@@ -1189,19 +1341,12 @@ class LLMEngine:
         from ..analysis import ProgramSpec
 
         sds = jax.ShapeDtypeStruct
-        i32 = jnp.int32
-        params = jax.tree_util.tree_map(
-            lambda x: sds(np.shape(x), x.dtype), self.params)
-        kc = sds(self._kc.shape, self._kc.dtype)
-        vc = sds(self._vc.shape, self._vc.dtype)
         dt = self._act_dtype
         declared = dt if np.dtype(dt).name in ("bfloat16", "float16") \
             else None
-        V = self.config.vocab_size
-        B = self.max_num_seqs
         # representative token bucket: the smallest prefill-sized launch
         # (every other bucket traces the same fn at another Tq)
-        Tq = max(self.prefill_token_bucket, B)
+        Tq = max(self.prefill_token_bucket, self.max_num_seqs)
 
         rag_fn, rag_donate = self._make_ragged_fn(Tq)
         cow_fn, cow_donate = self._make_cow_fn()
@@ -1210,58 +1355,22 @@ class LLMEngine:
         # Weight-quantized engines likewise keep the same kinds with a
         # dequant routed through the fused kernel path — their suffix
         # keeps the regenerated serving report's names collision-free
-        # against the f32 engine's.
-        sfx = {"int8": "_w8", "int4": "_w4"}.get(self.weight_dtype, "")
+        # against the f32 engine's.  The quantized step threads the
+        # scale pools (donated along with the page pools) plus the
+        # per-launch fresh-page mask.
+        q8 = "_q8" if self.kv_dtype == "int8" else ""
+        sfx = q8 + {"int8": "_w8", "int4": "_w4"}.get(self.weight_dtype, "")
         sfx += f"_tp{self.tp}" if self.tp > 1 else ""
-
-        def seqs(n):      # [n] i32 token/pos/index vectors
-            return sds((n,), i32)
-
-        # decode-window driver args: the [B]-wide carry seeds plus the
-        # per-row freeze/key inputs (shared tail of both kv dtypes)
-        win_tail = (seqs(B), seqs(B), sds((B,), jnp.bool_), seqs(B),
-                    seqs(B), seqs(B), sds((B, 2), jnp.uint32),
-                    sds((B + 1, self.nblk), i32), samp_structs(B, V))
-
-        if self.kv_dtype == "int8":
-            # the quantized step threads the scale pools (donated along
-            # with the page pools) plus the per-launch fresh-page mask
-            ks = sds(self._ks.shape, self._ks.dtype)
-            vs = sds(self._vs.shape, self._vs.dtype)
-            fresh = sds((self._kc.shape[1],), jnp.bool_)
-            out = [
-                ProgramSpec(
-                    "serving.ragged_step_q8" + sfx, rag_fn,
-                    (params, kc, vc, ks, vs, fresh, seqs(Tq), seqs(B + 1),
-                     seqs(B), sds((B + 1, self.nblk), i32),
-                     seqs(self._Lq), samp_structs(self._Lq, V)),
-                    donate_argnums=rag_donate, declared_dtype=declared,
-                    large_bytes=large_bytes),
-                ProgramSpec(
-                    "serving.cow_copy_q8" + sfx, cow_fn,
-                    (kc, vc, ks, vs, sds((), i32), sds((), i32)),
-                    donate_argnums=cow_donate, declared_dtype=declared,
-                    large_bytes=large_bytes),
-            ]
-            if self.decode_window > 1:
-                win_fn, win_donate = self._make_window_fn()
-                out.append(ProgramSpec(
-                    "serving.decode_window_q8" + sfx, win_fn,
-                    (params, kc, vc, ks, vs, fresh) + win_tail,
-                    donate_argnums=win_donate, declared_dtype=declared,
-                    large_bytes=large_bytes))
-            return out
         out = [
             ProgramSpec(
                 "serving.ragged_step" + sfx, rag_fn,
-                (params, kc, vc, seqs(Tq), seqs(B + 1), seqs(B),
-                 sds((B + 1, self.nblk), i32), seqs(self._Lq),
-                 samp_structs(self._Lq, V)),
+                self._ragged_arg_structs(Tq),
                 donate_argnums=rag_donate, declared_dtype=declared,
                 large_bytes=large_bytes),
             ProgramSpec(
                 "serving.cow_copy" + sfx, cow_fn,
-                (kc, vc, sds((), i32), sds((), i32)),
+                self._pool_structs() + (sds((), jnp.int32),
+                                        sds((), jnp.int32)),
                 donate_argnums=cow_donate, declared_dtype=declared,
                 large_bytes=large_bytes),
         ]
@@ -1269,7 +1378,7 @@ class LLMEngine:
             win_fn, win_donate = self._make_window_fn()
             out.append(ProgramSpec(
                 "serving.decode_window" + sfx, win_fn,
-                (params, kc, vc) + win_tail,
+                self._window_arg_structs(),
                 donate_argnums=win_donate, declared_dtype=declared,
                 large_bytes=large_bytes))
         return out
@@ -1299,17 +1408,19 @@ class LLMEngine:
         With a tracer installed every phase lands in the step timeline
         (dispatch: admit / schedule / pack / block-table stage / device
         launch; complete: block-on-result / sample-commit / retire; plus
-        prestage and the device in-flight window); with none the phase
-        seams are single attribute checks."""
+        prestage and the device in-flight window), each with the id of
+        the launch it belongs to (``step``: see docs/observability.md);
+        with none the phase seams are single attribute checks."""
+        # ONE call site into _step, tracer or none: the line a program
+        # is first reached from must not depend on who is watching
         tr = self.tracer
-        if tr is None:
-            return self._step(None)
-        self._trace_steps += 1
-        t0 = tr.now()
+        if tr is not None:
+            t0 = tr.now()
+            sid = self.launches + 1
         finished = self._step(tr)
-        tr.complete("engine.step", t0, track=self._trace_track,
-                    args={"step": self._trace_steps,
-                          "finished": len(finished)})
+        if tr is not None:
+            tr.complete("engine.step", t0, track=self._trace_track,
+                        args={"step": sid, "finished": len(finished)})
         return finished
 
     def _step(self, tr) -> list:
@@ -1377,7 +1488,8 @@ class LLMEngine:
             self.stats.set_degradation_state(self.pressure.state)
             if tr is not None and self.pressure.state != prev_tier:
                 tr.instant("pressure.tier", track=self._trace_track,
-                           args={"from": prev_tier,
+                           args={"step": self.launches + 1,
+                                 "from": prev_tier,
                                  "to": self.pressure.state,
                                  "name": _TIER_NAMES.get(
                                      self.pressure.state,
@@ -1388,6 +1500,7 @@ class LLMEngine:
                     self.stats.record_parked_evictions(n)
 
         if tr is not None:
+            sid = self.launches + 1     # the launch this call prepares
             t_d = tr.now()
             t = tr.now()
         admitted = self._admit()
@@ -1395,7 +1508,7 @@ class LLMEngine:
             self.stats.record_admission(len(admitted))
         if tr is not None:
             tr.complete("engine.admit", t, track=self._trace_track,
-                        args={"admitted": len(admitted),
+                        args={"step": sid, "admitted": len(admitted),
                               "running": len(self._running),
                               "waiting": len(self._waiting)})
         self.peak_resident_seqs = max(self.peak_resident_seqs,
@@ -1406,6 +1519,8 @@ class LLMEngine:
 
         if tr is not None:
             t = tr.now()
+            ev0 = self.blocks.eviction_count
+            self._cow_n = self._cow_ns = 0
         chunks = self._schedule_prefill_chunks()
 
         # decode-ready set (chunk owners are still mid-prefill, so the
@@ -1429,8 +1544,10 @@ class LLMEngine:
         batch.sort(key=lambda r: r.slot)
         if tr is not None:
             tr.complete("engine.schedule", t, track=self._trace_track,
-                        args={"chunks": len(chunks), "spec": len(spec),
-                              "decode": len(batch)})
+                        args={"step": sid, "chunks": len(chunks),
+                              "spec": len(spec), "decode": len(batch),
+                              "evicted": self.blocks.eviction_count - ev0,
+                              "cow": self._cow_n, "cow_ns": self._cow_ns})
 
         if chunks or spec or batch:
             t0 = time.perf_counter()
@@ -1439,10 +1556,8 @@ class LLMEngine:
                     and self._window_eligible(batch)):
                 launched = self._dispatch_window(batch, tr, t0)
             if not launched:
-                with RecordEvent("llm_engine.ragged_step"):
-                    sampled, logits, fin, spec_slices, chunk_slots, \
-                        batch_slots = self._run_ragged(chunks, spec,
-                                                       batch)
+                sampled, logits, fin, spec_slices, chunk_slots, \
+                    batch_slots = self._run_ragged(chunks, spec, batch)
                 now = time.perf_counter()
                 self._inflight = _StepTicket(
                     chunks=chunks, spec=spec, batch=batch,
@@ -1451,14 +1566,15 @@ class LLMEngine:
                     batch_slots=batch_slots, dispatch_s=now - t0,
                     t_launch=now,
                     launch_ns=tr.now() if tr is not None else 0,
-                    inflight=self.overlap)
+                    step=self.launches, inflight=self.overlap)
         # prestage page credit expires: every reserved page is now
         # either owned by a row this dispatch packed (its ensure() saw
         # the page already in place) or was freed with its retired row
         self._spec_pages.clear()
         if tr is not None:
             tr.complete("engine.dispatch", t_d, track=self._trace_track,
-                        args={"chunks": len(chunks), "spec": len(spec),
+                        args={"step": sid, "chunks": len(chunks),
+                              "spec": len(spec),
                               "decode": len(batch),
                               "launched": self._inflight is not None})
 
@@ -1487,6 +1603,9 @@ class LLMEngine:
                 self.stats.record_fault("inflight_crash")
                 raise InjectedFault(
                     f"injected in-flight crash at plan step {plan.step}")
+        # what this commit emits belongs to the launch that computed it,
+        # not to the step() call that happens to commit it
+        sid = self._commit_step = ticket.step
         if tr is not None:
             t_c = tr.now()
             t = t_c
@@ -1501,7 +1620,7 @@ class LLMEngine:
         self.stats.record_round_trip()
         if tr is not None:
             tr.complete("engine.block_on_result", t,
-                        track=self._trace_track)
+                        track=self._trace_track, args={"step": sid})
             if ticket.launch_ns and ticket.inflight:
                 # X event spanning launch -> materialized: the window
                 # host work can hide inside (step_timeline.py intersects
@@ -1510,7 +1629,8 @@ class LLMEngine:
                 # emit no window: nothing host ran while they flew.
                 tr.complete("engine.device_inflight", ticket.launch_ns,
                             track=self._trace_track,
-                            args={"rows": len(ticket.chunks)
+                            args={"step": sid,
+                                  "rows": len(ticket.chunks)
                                   + len(ticket.spec)
                                   + len(ticket.batch)})
         if ticket.window:
@@ -1565,9 +1685,9 @@ class LLMEngine:
         if tr is not None:
             tr.complete("engine.sample_commit", t,
                         track=self._trace_track,
-                        args={"finished": len(finished)})
+                        args={"step": sid, "finished": len(finished)})
             tr.complete("engine.complete", t_c, track=self._trace_track,
-                        args={"finished": len(finished)})
+                        args={"step": sid, "finished": len(finished)})
 
     def _prestage(self, tr) -> None:
         """Speculatively stage the NEXT dispatch's pure-decode pack
@@ -1608,6 +1728,7 @@ class LLMEngine:
         self._prestaged = None
         if tr is not None:
             t_p = tr.now()
+            sid = self.launches + 1     # the launch this stages for
         # reserve each row's next write: pre-apply cached+2 is exactly
         # the post-apply cached+1 the dispatch's ensure() will ask for,
         # so that ensure becomes a no-op.  Newly taken pages are
@@ -1631,7 +1752,7 @@ class LLMEngine:
             if tr is not None:
                 tr.complete("engine.prestage", t_p,
                             track=self._trace_track,
-                            args={"abandoned": "pool"})
+                            args={"step": sid, "abandoned": "pool"})
             return
         bi = 1 - self._d_cur            # the buffer NOT in flight
         buf = self._dbufs[bi]
@@ -1665,7 +1786,7 @@ class LLMEngine:
                 samp["keys"][s] = self._req_key(req, ahead=1)
         if tr is not None:
             tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"rows": n, "prestage": True})
+                        args={"step": sid, "rows": n, "prestage": True})
             t = tr.now()
         for s, req in enumerate(batch):
             ver = self.blocks.table_version(req.rid)
@@ -1675,11 +1796,11 @@ class LLMEngine:
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
                         track=self._trace_track,
-                        args={"rows": n, "prestage": True})
+                        args={"step": sid, "rows": n, "prestage": True})
         self._prestaged = (bi, layout)
         if tr is not None:
             tr.complete("engine.prestage", t_p, track=self._trace_track,
-                        args={"rows": n})
+                        args={"step": sid, "rows": n})
 
     def _invalidate_bt(self, rid: int) -> None:
         """Drop both decode buffers' staged block-table rows for rid.
@@ -1731,7 +1852,8 @@ class LLMEngine:
                 tr.instant("request.prefill_chunk",
                            track=self._trace_track,
                            args={"rid": req.rid, "tokens": n,
-                                 "done": req.cached >= len(req.tokens)})
+                                 "done": req.cached >= len(req.tokens),
+                                 "step": self._commit_step})
             fl = self.flight
             if fl is not None:
                 fl.prefill_chunk(req.rid, n)
@@ -1749,7 +1871,8 @@ class LLMEngine:
                     if tr is not None:
                         tr.instant("request.first_token",
                                    track=self._trace_track,
-                                   args={"rid": req.rid})
+                                   args={"rid": req.rid,
+                                         "step": self._commit_step})
                 self._notify_tokens(req, (tok,))
                 self._maybe_retire(req, finished)
         if chunks:
@@ -1853,14 +1976,16 @@ class LLMEngine:
             if tr is not None:
                 tr.instant("engine.window_fallback",
                            track=self._trace_track,
-                           args={"rows": len(batch), "k": K})
+                           args={"step": self.launches + 1,
+                                 "rows": len(batch), "k": K})
             return False
         if kp < K:
             self.stats.record_window_shrink()
             if tr is not None:
                 tr.instant("engine.window_shrink",
                            track=self._trace_track,
-                           args={"rows": len(batch), "k": K, "kp": kp})
+                           args={"step": self.launches + 1,
+                                 "rows": len(batch), "k": K, "kp": kp})
         B = self.max_num_seqs
         n = len(batch)
         toks = np.zeros((B,), np.int32)
@@ -1873,6 +1998,7 @@ class LLMEngine:
         bt = np.full((B + 1, self.nblk), NULL_BLOCK, np.int32)
         samp = make_samp(B, self.config.vocab_size)
         if tr is not None:
+            sid = self.launches + 1
             t = tr.now()
         for s, req in enumerate(batch):
             toks[s] = req.generated[-1]
@@ -1895,34 +2021,34 @@ class LLMEngine:
                     jax.random.PRNGKey(req.seed), np.uint32)
         if tr is not None:
             tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"rows": n, "window": kp})
+                        args={"step": sid, "rows": n, "window": kp})
             t = tr.now()
         for s, req in enumerate(batch):
             bt[s] = self.blocks.padded_table(req.rid, self.nblk)
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
                         track=self._trace_track,
-                        args={"rows": n, "window": kp})
+                        args={"step": sid, "rows": n, "window": kp})
         # the window grows tables past anything the per-step buffers
         # staged; force full restages at the next per-step launch
         self._break_decode_layout()
         if tr is not None:
             t = tr.now()
-        with RecordEvent("llm_engine.window_step"):
-            toks_out, fin_out = self._launch_window(
-                toks, kvl, active, gen, budgets, eos_ids, base_keys,
-                bt, samp)
+        toks_out, fin_out = self._launch_window(
+            toks, kvl, active, gen, budgets, eos_ids, base_keys, bt, samp)
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
-                        args={"rows": n, "window": kp})
+                        args={"step": sid, "bucket": B, "tokens": n,
+                              "rows": n, "chunks": 0, "decode": n,
+                              "logit_rows": n, "window": kp})
         now = time.perf_counter()
         self._inflight = _StepTicket(
             chunks=[], spec=[], batch=list(batch), sampled=toks_out,
             logits=None, fin=fin_out, spec_slices=[], chunk_slots=[],
             batch_slots=list(range(n)), dispatch_s=now - t0,
             t_launch=now, launch_ns=tr.now() if tr is not None else 0,
-            inflight=self.overlap, window=kp)
+            step=self.launches, inflight=self.overlap, window=kp)
         return True
 
     def _apply_window(self, batch, batch_slots, sampled, ok, dur,
@@ -2013,7 +2139,7 @@ class LLMEngine:
         tr = self.tracer
         if tr is not None:
             tr.instant("engine.quarantine", track=self._trace_track,
-                       args={"rid": req.rid})
+                       args={"rid": req.rid, "step": self._commit_step})
             tr.async_end("req", f"{self._trace_track}:{req.rid}",
                          args={"finish_reason": "numerical_error"})
         if req.on_finish is not None:
@@ -2070,7 +2196,8 @@ class LLMEngine:
             self.stats.record_kv_spill(len(pending), stored)
             if tr is not None:
                 tr.instant("kv_tier.spill", track=self._trace_track,
-                           args={"pages": len(pending), "stored": stored})
+                           args={"step": self.launches + 1,
+                                 "pages": len(pending), "stored": stored})
 
         restored = []                     # [(block, tier entry)]
         for h in self._tier_wanted_hashes(tier):
@@ -2114,7 +2241,8 @@ class LLMEngine:
             self.stats.record_kv_restore(len(restored))
             if tr is not None:
                 tr.instant("kv_tier.restore", track=self._trace_track,
-                           args={"pages": len(restored)})
+                           args={"step": self.launches + 1,
+                                 "pages": len(restored)})
         self.stats.set_spill_tier(tier.stats())
 
     def _tier_wanted_hashes(self, tier) -> list:
@@ -2206,6 +2334,11 @@ class LLMEngine:
                 fl.admitted(req.rid, queue_wait_s=qw,
                             cache_hit_tokens=req.cached,
                             tier=self._tier())
+            tr = self.tracer
+            if tr is not None:
+                tr.instant("request.admitted", track=self._trace_track,
+                           args={"rid": req.rid, "cached": req.cached,
+                                 "step": self.launches + 1})
         return admitted
 
     def _schedule_prefill_chunks(self) -> list:
@@ -2317,7 +2450,8 @@ class LLMEngine:
         if self.tracer is not None:
             self.tracer.instant("request.preempted",
                                 track=self._trace_track,
-                                args={"rid": req.rid})
+                                args={"rid": req.rid,
+                                      "step": self.launches + 1})
 
     def _maybe_retire(self, req, finished: list) -> None:
         eos = req.eos_token_id
@@ -2354,7 +2488,8 @@ class LLMEngine:
                         tier=self._tier())
         if tr is not None:
             tr.complete("engine.retire", t, track=self._trace_track,
-                        args={"rid": req.rid, "finish_reason": reason})
+                        args={"step": self._commit_step, "rid": req.rid,
+                              "finish_reason": reason})
             tr.async_end("req", f"{self._trace_track}:{req.rid}",
                          args={"finish_reason": reason,
                                "generated": len(req.generated)})
@@ -2534,12 +2669,16 @@ class LLMEngine:
         """Copy page src -> dst across every layer's K and V cache.  The
         copy is dispatched immediately so device program order keeps it
         ahead of any later prefill/decode write into dst."""
+        tr = self.tracer
+        if tr is not None:
+            t = tr.now()
         if self._cow_prog is None:
             run, donate = self._make_cow_fn()
             if self._platform == "cpu":
                 donate = ()
-            self._cow_prog = jax.jit(run, donate_argnums=donate)
-            self.compile_counts["cow"] += 1
+            self._cow_prog = jax.jit(_named(run, "kv_cow"),
+                                     donate_argnums=donate)
+            self._program_built("cow", "kv_cow")
         if self.kv_dtype == "int8":
             self._kc, self._vc, self._ks, self._vs = self._cow_prog(
                 self._kc, self._vc, self._ks, self._vs,
@@ -2547,6 +2686,12 @@ class LLMEngine:
         else:
             self._kc, self._vc = self._cow_prog(
                 self._kc, self._vc, np.int32(src), np.int32(dst))
+        if tr is not None:
+            # no span of its own: a nested one would leave
+            # engine.schedule's self time; the schedule span reports the
+            # sum (``cow``, ``cow_ns``)
+            self._cow_n += 1
+            self._cow_ns += tr.now() - t
 
     # ------------------------------------------------------------------
     # the compiled ragged step
@@ -2558,6 +2703,15 @@ class LLMEngine:
         the record is what the program runs."""
         self.program_paths[name] = {"attention": self.attention_path,
                                     "matmul": self.matmul_path}
+
+    def _program_built(self, kind: str, name: str) -> None:
+        """Count one jitted program (``compile_counts[kind]``) and say so
+        in the trace under the name the jit was given."""
+        self.compile_counts[kind] = self.compile_counts.get(kind, 0) + 1
+        tr = self.tracer
+        if tr is not None:
+            tr.instant("engine.program_built", track=self._trace_track,
+                       args={"name": name, "step": self.launches + 1})
 
     def _ragged_bucket(self, n_tokens: int) -> int:
         """Flat-token bucket for a launch: pure-decode-sized launches pad
@@ -2582,9 +2736,10 @@ class LLMEngine:
             run, donate = self._make_ragged_fn(Tq)
             if self._platform == "cpu":
                 donate = ()
-            prog = jax.jit(run, donate_argnums=donate)
+            name = f"ragged_step_t{Tq}"
+            prog = jax.jit(_named(run, name), donate_argnums=donate)
             self._ragged_progs[Tq] = prog
-            self.compile_counts["ragged"] += 1
+            self._program_built("ragged", name)
             self._record_program(f"ragged:{Tq}")
         return prog
 
@@ -2624,57 +2779,78 @@ class LLMEngine:
             # one row per logit row.  Under tp>1 this traces per shard:
             # kc/vc and the q/k/v projections arrive head-sliced, toks..
             # samp arrive replicated.
+            # the jax.named_scope names below are what a device trace
+            # is read by (docs/observability.md): keep them, and keep
+            # them the same in all four step builders
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
-            x = embed(params, toks)                           # [Tq, H]
+            with jax.named_scope("embed"):
+                x = embed(params, toks)                       # [Tq, H]
 
             def body(x, inp):
                 p, kcl, vcl = inp
-                h = _rms_weight(x, p["ln1"], eps)
-                q = mm(h, p, "wq").reshape(Tq, nh, d)
-                k = mm(h, p, "wk").reshape(Tq, kvh, d)
-                v = mm(h, p, "wv").reshape(Tq, kvh, d)
-                q = _rope_positions(q, rel, theta)
-                k = _rope_positions(k, rel, theta)
-                blk = bt[seg, rel // bs]                      # [Tq]
-                slot = rel % bs
-                kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
-                vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
-                if use_pallas:
-                    # the host packing path owns these buffers: bt is the
-                    # int32 NULL_BLOCK-padded pool table ([B+1] rows, so
-                    # the seg pad sentinel B is the valid null row) and
-                    # seg/rel come int32 from ragged_segments — the
-                    # packed entry skips the per-launch re-clip/re-cast
-                    att = _pa.ragged_paged_attention_segrel_packed(
-                        q, kcl, vcl, bt, seg, rel)
-                else:
-                    att = _pa.ragged_paged_reference_segrel(
-                        q, kcl, vcl, bt, seg, rel)
-                if tp > 1:
-                    # tiled gather concatenates shard head blocks in
-                    # mesh order — exactly the tp=1 head layout, so the
-                    # replicated wo matmul is byte-identical
-                    att = lax.all_gather(att, "tp", axis=1, tiled=True)
-                x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-                h2 = _rms_weight(x, p["ln2"], eps)
-                a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                ).astype(h2.dtype) * mm(h2, p, "up")
-                return x + mm(a, p, "down"), (kcl, vcl)
+                with jax.named_scope("norm"):
+                    h = _rms_weight(x, p["ln1"], eps)
+                with jax.named_scope("qkv"):
+                    q = mm(h, p, "wq").reshape(Tq, nh, d)
+                    k = mm(h, p, "wk").reshape(Tq, kvh, d)
+                    v = mm(h, p, "wv").reshape(Tq, kvh, d)
+                with jax.named_scope("rope"):
+                    q = _rope_positions(q, rel, theta)
+                    k = _rope_positions(k, rel, theta)
+                with jax.named_scope("kv_write"):
+                    blk = bt[seg, rel // bs]                  # [Tq]
+                    slot = rel % bs
+                    kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
+                    vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
+                with jax.named_scope("attn"):
+                    if use_pallas:
+                        # the host packing path owns these buffers: bt is
+                        # the int32 NULL_BLOCK-padded pool table ([B+1]
+                        # rows, so the seg pad sentinel B is the valid
+                        # null row) and seg/rel come int32 from
+                        # ragged_segments — the packed entry skips the
+                        # per-launch re-clip/re-cast
+                        att = _pa.ragged_paged_attention_segrel_packed(
+                            q, kcl, vcl, bt, seg, rel)
+                    else:
+                        att = _pa.ragged_paged_reference_segrel(
+                            q, kcl, vcl, bt, seg, rel)
+                    if tp > 1:
+                        # tiled gather concatenates shard head blocks in
+                        # mesh order — exactly the tp=1 head layout, so
+                        # the replicated wo matmul is byte-identical
+                        att = lax.all_gather(att, "tp", axis=1,
+                                             tiled=True)
+                with jax.named_scope("o_proj"):
+                    x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
+                with jax.named_scope("norm"):
+                    h2 = _rms_weight(x, p["ln2"], eps)
+                with jax.named_scope("mlp"):
+                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
+                                    ).astype(h2.dtype) * mm(h2, p, "up")
+                    x = x + mm(a, p, "down")
+                return x, (kcl, vcl)
 
-            x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-            h = _rms_weight(x, params["norm_f"], eps)
-            hsel = h[lidx]                                    # [Lq, H]
-            logits = head_logits(params, hsel)                # [Lq, V]
-            if shard_head:
-                # vocab-sliced logits -> one gather; sampling then runs
-                # replicated on identical full-width rows
-                logits = lax.all_gather(logits, "tp", axis=1, tiled=True)
-            sampled = sample_tokens(logits, samp)
-            # per-row finiteness flag: the quarantine guard retires a
-            # poisoned row host-side without touching its batchmates
-            # (padded rows may be legitimately non-finite; the host only
-            # consults live slots)
-            fin = jnp.all(jnp.isfinite(logits), axis=-1)      # [Lq]
+            with jax.named_scope("layers"):
+                x, (kc, vc) = lax.scan(body, x,
+                                       (params["layers"], kc, vc))
+            with jax.named_scope("norm"):
+                h = _rms_weight(x, params["norm_f"], eps)
+            with jax.named_scope("head"):
+                hsel = h[lidx]                                # [Lq, H]
+                logits = head_logits(params, hsel)            # [Lq, V]
+                if shard_head:
+                    # vocab-sliced logits -> one gather; sampling then
+                    # runs replicated on identical full-width rows
+                    logits = lax.all_gather(logits, "tp", axis=1,
+                                            tiled=True)
+            with jax.named_scope("sample"):
+                sampled = sample_tokens(logits, samp)
+                # per-row finiteness flag: the quarantine guard retires a
+                # poisoned row host-side without touching its batchmates
+                # (padded rows may be legitimately non-finite; the host
+                # only consults live slots)
+                fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [Lq]
             if with_logits:
                 return sampled, fin, logits, kc, vc
             return sampled, fin, kc, vc
@@ -2719,6 +2895,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
+        quant_attn = _pa.ragged_paged_attention_quant_segrel_packed
 
         def run(params, kc, vc, ks, vs, fresh, toks, cu, kvl, bt, lidx,
                 samp):
@@ -2726,75 +2903,95 @@ class LLMEngine:
             # f32 scale pools (donated with the page pools) and fresh
             # [num_blocks] bool (pages whose scales reset this launch)
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
-            x = embed(params, toks)                           # [Tq, H]
+            with jax.named_scope("embed"):
+                x = embed(params, toks)                       # [Tq, H]
 
             def body(x, inp):
                 p, kcl, vcl, ksl, vsl = inp
-                h = _rms_weight(x, p["ln1"], eps)
-                q = mm(h, p, "wq").reshape(Tq, nh, d)
-                k = mm(h, p, "wk").reshape(Tq, kvh, d)
-                v = mm(h, p, "wv").reshape(Tq, kvh, d)
-                q = _rope_positions(q, rel, theta)
-                k = _rope_positions(k, rel, theta)
-                blk = bt[seg, rel // bs]                      # [Tq]
-                slot = rel % bs
-                kf = k.astype(jnp.float32)
-                vf = v.astype(jnp.float32)
-                ksl = jnp.where(fresh[:, None], 0.0, ksl)
-                vsl = jnp.where(fresh[:, None], 0.0, vsl)
-                ks_old = ksl[blk]                             # [Tq, kvh]
-                vs_old = vsl[blk]
-                ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1)
-                                      / 127.0)
-                vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1)
-                                      / 127.0)
-                ks_new = ksl[blk]
-                vs_new = vsl[blk]
-                rk = jnp.where(ks_new > 0.0,
-                               ks_old / jnp.maximum(ks_new, 1e-30), 0.0)
-                rv = jnp.where(vs_new > 0.0,
-                               vs_old / jnp.maximum(vs_new, 1e-30), 0.0)
-                kp = jnp.round(kcl[blk].astype(jnp.float32)
-                               * rk[:, :, None, None])
-                vp = jnp.round(vcl[blk].astype(jnp.float32)
-                               * rv[:, :, None, None])
-                kcl = kcl.at[blk].set(
-                    jnp.clip(kp, -127, 127).astype(jnp.int8))
-                vcl = vcl.at[blk].set(
-                    jnp.clip(vp, -127, 127).astype(jnp.int8))
-                kq = jnp.round(kf / jnp.maximum(ks_new, 1e-30)[:, :, None])
-                vq = jnp.round(vf / jnp.maximum(vs_new, 1e-30)[:, :, None])
-                kcl = kcl.at[blk, :, slot, :].set(
-                    jnp.clip(kq, -127, 127).astype(jnp.int8))
-                vcl = vcl.at[blk, :, slot, :].set(
-                    jnp.clip(vq, -127, 127).astype(jnp.int8))
-                if use_pallas:
-                    # packed-entry invariant as in the float step; the
-                    # scale pools are born f32 on the host
-                    att = _pa.ragged_paged_attention_quant_segrel_packed(
-                        q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                else:
-                    att = _pa.ragged_paged_reference_quant_segrel(
-                        q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                att = att.astype(x.dtype)
-                if tp > 1:
-                    att = lax.all_gather(att, "tp", axis=1, tiled=True)
-                x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-                h2 = _rms_weight(x, p["ln2"], eps)
-                a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                ).astype(h2.dtype) * mm(h2, p, "up")
-                return x + mm(a, p, "down"), (kcl, vcl, ksl, vsl)
+                with jax.named_scope("norm"):
+                    h = _rms_weight(x, p["ln1"], eps)
+                with jax.named_scope("qkv"):
+                    q = mm(h, p, "wq").reshape(Tq, nh, d)
+                    k = mm(h, p, "wk").reshape(Tq, kvh, d)
+                    v = mm(h, p, "wv").reshape(Tq, kvh, d)
+                with jax.named_scope("rope"):
+                    q = _rope_positions(q, rel, theta)
+                    k = _rope_positions(k, rel, theta)
+                with jax.named_scope("kv_write"):
+                    blk = bt[seg, rel // bs]                  # [Tq]
+                    slot = rel % bs
+                    kf = k.astype(jnp.float32)
+                    vf = v.astype(jnp.float32)
+                    ksl = jnp.where(fresh[:, None], 0.0, ksl)
+                    vsl = jnp.where(fresh[:, None], 0.0, vsl)
+                    ks_old = ksl[blk]                         # [Tq, kvh]
+                    vs_old = vsl[blk]
+                    ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1)
+                                          / 127.0)
+                    vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1)
+                                          / 127.0)
+                    ks_new = ksl[blk]
+                    vs_new = vsl[blk]
+                    rk = jnp.where(ks_new > 0.0,
+                                   ks_old / jnp.maximum(ks_new, 1e-30),
+                                   0.0)
+                    rv = jnp.where(vs_new > 0.0,
+                                   vs_old / jnp.maximum(vs_new, 1e-30),
+                                   0.0)
+                    kp = jnp.round(kcl[blk].astype(jnp.float32)
+                                   * rk[:, :, None, None])
+                    vp = jnp.round(vcl[blk].astype(jnp.float32)
+                                   * rv[:, :, None, None])
+                    kcl = kcl.at[blk].set(
+                        jnp.clip(kp, -127, 127).astype(jnp.int8))
+                    vcl = vcl.at[blk].set(
+                        jnp.clip(vp, -127, 127).astype(jnp.int8))
+                    kq = jnp.round(kf / jnp.maximum(ks_new,
+                                                    1e-30)[:, :, None])
+                    vq = jnp.round(vf / jnp.maximum(vs_new,
+                                                    1e-30)[:, :, None])
+                    kcl = kcl.at[blk, :, slot, :].set(
+                        jnp.clip(kq, -127, 127).astype(jnp.int8))
+                    vcl = vcl.at[blk, :, slot, :].set(
+                        jnp.clip(vq, -127, 127).astype(jnp.int8))
+                with jax.named_scope("attn"):
+                    if use_pallas:
+                        # packed-entry invariant as in the float step;
+                        # the scale pools are born f32 on the host
+                        att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
+                                         seg, rel)
+                    else:
+                        att = _pa.ragged_paged_reference_quant_segrel(
+                            q, kcl, vcl, ksl, vsl, bt, seg, rel)
+                    att = att.astype(x.dtype)
+                    if tp > 1:
+                        att = lax.all_gather(att, "tp", axis=1,
+                                             tiled=True)
+                with jax.named_scope("o_proj"):
+                    x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
+                with jax.named_scope("norm"):
+                    h2 = _rms_weight(x, p["ln2"], eps)
+                with jax.named_scope("mlp"):
+                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
+                                    ).astype(h2.dtype) * mm(h2, p, "up")
+                    x = x + mm(a, p, "down")
+                return x, (kcl, vcl, ksl, vsl)
 
-            x, (kc, vc, ks, vs) = lax.scan(body, x,
-                                           (params["layers"], kc, vc,
-                                            ks, vs))
-            h = _rms_weight(x, params["norm_f"], eps)
-            hsel = h[lidx]                                    # [Lq, H]
-            logits = head_logits(params, hsel)                # [Lq, V]
-            if shard_head:
-                logits = lax.all_gather(logits, "tp", axis=1, tiled=True)
-            sampled = sample_tokens(logits, samp)
-            fin = jnp.all(jnp.isfinite(logits), axis=-1)      # [Lq]
+            with jax.named_scope("layers"):
+                x, (kc, vc, ks, vs) = lax.scan(body, x,
+                                               (params["layers"], kc, vc,
+                                                ks, vs))
+            with jax.named_scope("norm"):
+                h = _rms_weight(x, params["norm_f"], eps)
+            with jax.named_scope("head"):
+                hsel = h[lidx]                                # [Lq, H]
+                logits = head_logits(params, hsel)            # [Lq, V]
+                if shard_head:
+                    logits = lax.all_gather(logits, "tp", axis=1,
+                                            tiled=True)
+            with jax.named_scope("sample"):
+                sampled = sample_tokens(logits, samp)
+                fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [Lq]
             if with_logits:
                 return sampled, fin, logits, kc, vc, ks, vs
             return sampled, fin, kc, vc, ks, vs
@@ -2812,34 +3009,40 @@ class LLMEngine:
         self._fresh_np[:] = False
         return out
 
+    def _call_program(self, prog, args, bucket: int):
+        """The jitted call of a step launch.  It counts the launch (the
+        step id) and, with a tracer installed, brackets the call in one
+        ``engine.launch`` annotation carrying that id, so the profiler's
+        own trace holds a host event a step that joins a device
+        program's execution to the Tracer's ``engine.device_launch``.
+        One call site: the program is the same whoever is watching."""
+        self.launches += 1
+        note = _NO_ANNOTATION if self.tracer is None else \
+            jax.profiler.TraceAnnotation("engine.launch",
+                                         step=self.launches,
+                                         bucket=int(bucket))
+        with note:
+            return prog(*args)
+
     def _launch_ragged(self, Tq, toks, cu, kvl, bt, lidx, samp,
                        real_tokens):
         self.pad_stats["real"] += int(real_tokens)
         self.pad_stats["padded"] += int(Tq)
         prog = self._get_ragged_prog(Tq)
+        tail = (toks, cu, kvl, bt, lidx, samp)
         if self.kv_dtype == "int8":
-            fresh = self._consume_fresh()
-            if self._with_logits:
-                sampled, fin, logits, self._kc, self._vc, self._ks, \
-                    self._vs = prog(
-                        self.params, self._kc, self._vc, self._ks,
-                        self._vs, fresh, toks, cu, kvl, bt, lidx, samp)
-            else:
-                sampled, fin, self._kc, self._vc, self._ks, self._vs = \
-                    prog(self.params, self._kc, self._vc, self._ks,
-                         self._vs, fresh, toks, cu, kvl, bt, lidx, samp)
-                logits = None
-            return sampled, logits, fin
-        if self._with_logits:
-            sampled, fin, logits, self._kc, self._vc = prog(
-                self.params, self._kc, self._vc, toks, cu, kvl, bt,
-                lidx, samp)
+            out = self._call_program(
+                prog, (self.params, self._kc, self._vc, self._ks,
+                       self._vs, self._consume_fresh()) + tail, Tq)
+            self._kc, self._vc, self._ks, self._vs = out[-4:]
+            out = out[:-4]
         else:
-            sampled, fin, self._kc, self._vc = prog(
-                self.params, self._kc, self._vc, toks, cu, kvl, bt,
-                lidx, samp)
-            logits = None
-        return sampled, logits, fin
+            out = self._call_program(
+                prog, (self.params, self._kc, self._vc) + tail, Tq)
+            self._kc, self._vc = out[-2:]
+            out = out[:-2]
+        sampled, fin = out[0], out[1]
+        return sampled, (out[2] if self._with_logits else None), fin
 
     def _get_window_prog(self):
         """The compiled K-step decode window driver (one per engine —
@@ -2851,9 +3054,10 @@ class LLMEngine:
             run, donate = self._make_window_fn()
             if self._platform == "cpu":
                 donate = ()
-            self._window_prog = jax.jit(run, donate_argnums=donate)
-            self.compile_counts["scan"] = \
-                self.compile_counts.get("scan", 0) + 1
+            name = f"decode_window_k{self.decode_window}"
+            self._window_prog = jax.jit(_named(run, name),
+                                        donate_argnums=donate)
+            self._program_built("scan", name)
             self._record_program(f"window:{self.decode_window}")
         return self._window_prog
 
@@ -2905,6 +3109,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
+        float_attn = _pa.ragged_paged_attention_segrel_packed
 
         def run(params, kc, vc, toks, kvl, active, gen, budgets,
                 eos_ids, base_keys, bt, samp):
@@ -2921,51 +3126,67 @@ class LLMEngine:
                 (i, tok, kvl, active, gen, seen, kc, vc, touts,
                  fouts) = carry
                 seg, rel = _pa.decode_window_segments(active, kvl)
-                x = embed(params, tok)                        # [B, H]
+                with jax.named_scope("embed"):
+                    x = embed(params, tok)                    # [B, H]
 
                 def body(x, inp):
                     p, kcl, vcl = inp
-                    h = _rms_weight(x, p["ln1"], eps)
-                    q = mm(h, p, "wq").reshape(B, nh, d)
-                    k = mm(h, p, "wk").reshape(B, kvh, d)
-                    v = mm(h, p, "wv").reshape(B, kvh, d)
-                    q = _rope_positions(q, rel, theta)
-                    k = _rope_positions(k, rel, theta)
-                    blk = bt[seg, rel // bs]                  # [B]
-                    slot = rel % bs
-                    kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
-                    vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
-                    if use_pallas:
-                        att = _pa.ragged_paged_attention_segrel_packed(
-                            q, kcl, vcl, bt, seg, rel)
-                    else:
-                        att = _pa.ragged_paged_reference_segrel(
-                            q, kcl, vcl, bt, seg, rel)
-                    if tp > 1:
-                        att = lax.all_gather(att, "tp", axis=1,
-                                             tiled=True)
-                    x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
-                    h2 = _rms_weight(x, p["ln2"], eps)
-                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                    ).astype(h2.dtype) * mm(h2, p, "up")
-                    return x + mm(a, p, "down"), (kcl, vcl)
+                    with jax.named_scope("norm"):
+                        h = _rms_weight(x, p["ln1"], eps)
+                    with jax.named_scope("qkv"):
+                        q = mm(h, p, "wq").reshape(B, nh, d)
+                        k = mm(h, p, "wk").reshape(B, kvh, d)
+                        v = mm(h, p, "wv").reshape(B, kvh, d)
+                    with jax.named_scope("rope"):
+                        q = _rope_positions(q, rel, theta)
+                        k = _rope_positions(k, rel, theta)
+                    with jax.named_scope("kv_write"):
+                        blk = bt[seg, rel // bs]              # [B]
+                        slot = rel % bs
+                        kcl = kcl.at[blk, :, slot, :].set(
+                            k.astype(kcl.dtype))
+                        vcl = vcl.at[blk, :, slot, :].set(
+                            v.astype(vcl.dtype))
+                    with jax.named_scope("attn"):
+                        if use_pallas:
+                            att = float_attn(q, kcl, vcl, bt, seg, rel)
+                        else:
+                            att = _pa.ragged_paged_reference_segrel(
+                                q, kcl, vcl, bt, seg, rel)
+                        if tp > 1:
+                            att = lax.all_gather(att, "tp", axis=1,
+                                                 tiled=True)
+                    with jax.named_scope("o_proj"):
+                        x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
+                    with jax.named_scope("norm"):
+                        h2 = _rms_weight(x, p["ln2"], eps)
+                    with jax.named_scope("mlp"):
+                        a = jax.nn.silu(
+                            mm(h2, p, "gate").astype(jnp.float32)
+                        ).astype(h2.dtype) * mm(h2, p, "up")
+                        x = x + mm(a, p, "down")
+                    return x, (kcl, vcl)
 
-                x, (kc, vc) = lax.scan(body, x,
-                                       (params["layers"], kc, vc))
-                h = _rms_weight(x, params["norm_f"], eps)
-                # every row is its own logit row (lidx == identity)
-                logits = head_logits(params, h)
-                if shard_head:
-                    logits = lax.all_gather(logits, "tp", axis=1,
-                                            tiled=True)
-                keys = advance_keys(base_keys, gen)
-                sampled = sample_tokens(
-                    logits, {"temps": samp["temps"],
-                             "top_k": samp["top_k"],
-                             "top_p": samp["top_p"],
-                             "penalty": samp["penalty"],
-                             "seen": seen, "keys": keys})
-                fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
+                with jax.named_scope("layers"):
+                    x, (kc, vc) = lax.scan(body, x,
+                                           (params["layers"], kc, vc))
+                with jax.named_scope("norm"):
+                    h = _rms_weight(x, params["norm_f"], eps)
+                with jax.named_scope("head"):
+                    # every row is its own logit row (lidx == identity)
+                    logits = head_logits(params, h)
+                    if shard_head:
+                        logits = lax.all_gather(logits, "tp", axis=1,
+                                                tiled=True)
+                with jax.named_scope("sample"):
+                    keys = advance_keys(base_keys, gen)
+                    sampled = sample_tokens(
+                        logits, {"temps": samp["temps"],
+                                 "top_k": samp["top_k"],
+                                 "top_p": samp["top_p"],
+                                 "penalty": samp["penalty"],
+                                 "seen": seen, "keys": keys})
+                    fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
                 # frozen rows carry their last committed token so the
                 # grid's dead columns hold committed values, never
                 # null-page garbage
@@ -3012,6 +3233,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
+        quant_attn = _pa.ragged_paged_attention_quant_segrel_packed
 
         def run(params, kc, vc, ks, vs, fresh, toks, kvl, active, gen,
                 budgets, eos_ids, base_keys, bt, samp):
@@ -3023,83 +3245,96 @@ class LLMEngine:
                 (i, tok, kvl, active, gen, seen, kc, vc, ks, vs, touts,
                  fouts) = carry
                 seg, rel = _pa.decode_window_segments(active, kvl)
-                x = embed(params, tok)                        # [B, H]
+                with jax.named_scope("embed"):
+                    x = embed(params, tok)                    # [B, H]
 
                 def body(x, inp):
                     p, kcl, vcl, ksl, vsl = inp
-                    h = _rms_weight(x, p["ln1"], eps)
-                    q = mm(h, p, "wq").reshape(B, nh, d)
-                    k = mm(h, p, "wk").reshape(B, kvh, d)
-                    v = mm(h, p, "wv").reshape(B, kvh, d)
-                    q = _rope_positions(q, rel, theta)
-                    k = _rope_positions(k, rel, theta)
-                    blk = bt[seg, rel // bs]                  # [B]
-                    slot = rel % bs
-                    kf = k.astype(jnp.float32)
-                    vf = v.astype(jnp.float32)
-                    ks_old = ksl[blk]                         # [B, kvh]
-                    vs_old = vsl[blk]
-                    ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1)
-                                          / 127.0)
-                    vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1)
-                                          / 127.0)
-                    ks_new = ksl[blk]
-                    vs_new = vsl[blk]
-                    rk = jnp.where(ks_new > 0.0,
-                                   ks_old / jnp.maximum(ks_new, 1e-30),
-                                   0.0)
-                    rv = jnp.where(vs_new > 0.0,
-                                   vs_old / jnp.maximum(vs_new, 1e-30),
-                                   0.0)
-                    kp = jnp.round(kcl[blk].astype(jnp.float32)
-                                   * rk[:, :, None, None])
-                    vp = jnp.round(vcl[blk].astype(jnp.float32)
-                                   * rv[:, :, None, None])
-                    kcl = kcl.at[blk].set(
-                        jnp.clip(kp, -127, 127).astype(jnp.int8))
-                    vcl = vcl.at[blk].set(
-                        jnp.clip(vp, -127, 127).astype(jnp.int8))
-                    kq = jnp.round(kf / jnp.maximum(ks_new,
-                                                    1e-30)[:, :, None])
-                    vq = jnp.round(vf / jnp.maximum(vs_new,
-                                                    1e-30)[:, :, None])
-                    kcl = kcl.at[blk, :, slot, :].set(
-                        jnp.clip(kq, -127, 127).astype(jnp.int8))
-                    vcl = vcl.at[blk, :, slot, :].set(
-                        jnp.clip(vq, -127, 127).astype(jnp.int8))
-                    if use_pallas:
-                        att = \
-                            _pa.ragged_paged_attention_quant_segrel_packed(
+                    with jax.named_scope("norm"):
+                        h = _rms_weight(x, p["ln1"], eps)
+                    with jax.named_scope("qkv"):
+                        q = mm(h, p, "wq").reshape(B, nh, d)
+                        k = mm(h, p, "wk").reshape(B, kvh, d)
+                        v = mm(h, p, "wv").reshape(B, kvh, d)
+                    with jax.named_scope("rope"):
+                        q = _rope_positions(q, rel, theta)
+                        k = _rope_positions(k, rel, theta)
+                    with jax.named_scope("kv_write"):
+                        blk = bt[seg, rel // bs]              # [B]
+                        slot = rel % bs
+                        kf = k.astype(jnp.float32)
+                        vf = v.astype(jnp.float32)
+                        ks_old = ksl[blk]                     # [B, kvh]
+                        vs_old = vsl[blk]
+                        ksl = ksl.at[blk].max(
+                            jnp.max(jnp.abs(kf), axis=-1) / 127.0)
+                        vsl = vsl.at[blk].max(
+                            jnp.max(jnp.abs(vf), axis=-1) / 127.0)
+                        ks_new = ksl[blk]
+                        vs_new = vsl[blk]
+                        rk = jnp.where(ks_new > 0.0,
+                                       ks_old / jnp.maximum(ks_new, 1e-30),
+                                       0.0)
+                        rv = jnp.where(vs_new > 0.0,
+                                       vs_old / jnp.maximum(vs_new, 1e-30),
+                                       0.0)
+                        kp = jnp.round(kcl[blk].astype(jnp.float32)
+                                       * rk[:, :, None, None])
+                        vp = jnp.round(vcl[blk].astype(jnp.float32)
+                                       * rv[:, :, None, None])
+                        kcl = kcl.at[blk].set(
+                            jnp.clip(kp, -127, 127).astype(jnp.int8))
+                        vcl = vcl.at[blk].set(
+                            jnp.clip(vp, -127, 127).astype(jnp.int8))
+                        kq = jnp.round(kf / jnp.maximum(ks_new,
+                                                        1e-30)[:, :, None])
+                        vq = jnp.round(vf / jnp.maximum(vs_new,
+                                                        1e-30)[:, :, None])
+                        kcl = kcl.at[blk, :, slot, :].set(
+                            jnp.clip(kq, -127, 127).astype(jnp.int8))
+                        vcl = vcl.at[blk, :, slot, :].set(
+                            jnp.clip(vq, -127, 127).astype(jnp.int8))
+                    with jax.named_scope("attn"):
+                        if use_pallas:
+                            att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
+                                             seg, rel)
+                        else:
+                            att = _pa.ragged_paged_reference_quant_segrel(
                                 q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                    else:
-                        att = _pa.ragged_paged_reference_quant_segrel(
-                            q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                    att = att.astype(x.dtype)
-                    if tp > 1:
-                        att = lax.all_gather(att, "tp", axis=1,
-                                             tiled=True)
-                    x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
-                    h2 = _rms_weight(x, p["ln2"], eps)
-                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                    ).astype(h2.dtype) * mm(h2, p, "up")
-                    return x + mm(a, p, "down"), (kcl, vcl, ksl, vsl)
+                        att = att.astype(x.dtype)
+                        if tp > 1:
+                            att = lax.all_gather(att, "tp", axis=1,
+                                                 tiled=True)
+                    with jax.named_scope("o_proj"):
+                        x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
+                    with jax.named_scope("norm"):
+                        h2 = _rms_weight(x, p["ln2"], eps)
+                    with jax.named_scope("mlp"):
+                        a = jax.nn.silu(
+                            mm(h2, p, "gate").astype(jnp.float32)
+                        ).astype(h2.dtype) * mm(h2, p, "up")
+                        x = x + mm(a, p, "down")
+                    return x, (kcl, vcl, ksl, vsl)
 
-                x, (kc, vc, ks, vs) = lax.scan(body, x,
-                                               (params["layers"], kc,
-                                                vc, ks, vs))
-                h = _rms_weight(x, params["norm_f"], eps)
-                logits = head_logits(params, h)
-                if shard_head:
-                    logits = lax.all_gather(logits, "tp", axis=1,
-                                            tiled=True)
-                keys = advance_keys(base_keys, gen)
-                sampled = sample_tokens(
-                    logits, {"temps": samp["temps"],
-                             "top_k": samp["top_k"],
-                             "top_p": samp["top_p"],
-                             "penalty": samp["penalty"],
-                             "seen": seen, "keys": keys})
-                fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
+                with jax.named_scope("layers"):
+                    x, (kc, vc, ks, vs) = lax.scan(
+                        body, x, (params["layers"], kc, vc, ks, vs))
+                with jax.named_scope("norm"):
+                    h = _rms_weight(x, params["norm_f"], eps)
+                with jax.named_scope("head"):
+                    logits = head_logits(params, h)
+                    if shard_head:
+                        logits = lax.all_gather(logits, "tp", axis=1,
+                                                tiled=True)
+                with jax.named_scope("sample"):
+                    keys = advance_keys(base_keys, gen)
+                    sampled = sample_tokens(
+                        logits, {"temps": samp["temps"],
+                                 "top_k": samp["top_k"],
+                                 "top_p": samp["top_p"],
+                                 "penalty": samp["penalty"],
+                                 "seen": seen, "keys": keys})
+                    fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
                 sampled = jnp.where(active, sampled, tok)
                 touts = touts.at[i].set(sampled)
                 fouts = fouts.at[i].set(fin | ~active)
@@ -3127,16 +3362,18 @@ class LLMEngine:
     def _launch_window(self, toks, kvl, active, gen, budgets, eos_ids,
                        base_keys, bt, samp):
         prog = self._get_window_prog()
+        tail = (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt,
+                samp)
         if self.kv_dtype == "int8":
-            fresh = self._consume_fresh()
             touts, fouts, self._kc, self._vc, self._ks, self._vs = \
-                prog(self.params, self._kc, self._vc, self._ks,
-                     self._vs, fresh, toks, kvl, active, gen, budgets,
-                     eos_ids, base_keys, bt, samp)
+                self._call_program(
+                    prog, (self.params, self._kc, self._vc, self._ks,
+                           self._vs, self._consume_fresh()) + tail,
+                    self.max_num_seqs)
         else:
-            touts, fouts, self._kc, self._vc = prog(
-                self.params, self._kc, self._vc, toks, kvl, active,
-                gen, budgets, eos_ids, base_keys, bt, samp)
+            touts, fouts, self._kc, self._vc = self._call_program(
+                prog, (self.params, self._kc, self._vc) + tail,
+                self.max_num_seqs)
         return touts, fouts
 
     def _fill_samp(self, samp, s, req):
@@ -3186,6 +3423,7 @@ class LLMEngine:
 
         tr = self.tracer
         if tr is not None:
+            sid = self.launches + 1
             t = tr.now()
         off = 0      # flat-token cursor
         ls = 0       # logit-row cursor
@@ -3210,15 +3448,15 @@ class LLMEngine:
         cu[len(rows) + 1:] = off
         if tr is not None:
             tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"rows": len(rows), "tokens": total,
-                              "bucket": int(Tq)})
+                        args={"step": sid, "rows": len(rows),
+                              "tokens": total, "bucket": int(Tq)})
             t = tr.now()
         for i, (req, _w, _k) in enumerate(rows):
             bt[i] = self.blocks.padded_table(req.rid, self.nblk)
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
                         track=self._trace_track,
-                        args={"rows": len(rows)})
+                        args={"step": sid, "rows": len(rows)})
 
         # padding a four-program step would have cost: a token-bucketed
         # chunk launch, plus the full-width verify launch when anything
@@ -3242,9 +3480,17 @@ class LLMEngine:
         sampled, logits, fin = self._launch_ragged(Tq, toks, cu, kvl, bt,
                                                    lidx, samp, total)
         if tr is not None:
+            # logit rows whose result is used: a chunk that ends its
+            # prompt, every verify position, every decode row
+            logit_rows = sum(1 for r, n in chunks
+                             if r.cached + n == len(r.tokens)) \
+                + sum(n for _, n in spec_slices) + len(batch)
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
-                        args={"bucket": int(Tq)})
+                        args={"step": sid, "bucket": int(Tq),
+                              "tokens": total, "rows": len(rows),
+                              "chunks": len(chunks), "decode": len(batch),
+                              "logit_rows": logit_rows})
         # NO materialization here: sampled/logits/fin return as async
         # device arrays; _complete blocks on them (the dispatch path
         # must never force a host sync on step-program outputs)
@@ -3295,6 +3541,7 @@ class LLMEngine:
             buf.bt_ver.clear()               # force table repacks below
         tr = self.tracer
         if tr is not None:
+            sid = self.launches + 1
             t = tr.now()
         if pre:
             # prestage already wrote kvl and the sampling keys; only
@@ -3314,8 +3561,9 @@ class LLMEngine:
                     samp["keys"][s] = self._req_key(req)
         if tr is not None:
             tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"rows": n, "tokens": n, "bucket": int(Tq),
-                              "fast_path": True, "prestaged": pre})
+                        args={"step": sid, "rows": n, "tokens": n,
+                              "bucket": int(Tq), "fast_path": True,
+                              "prestaged": pre})
             t = tr.now()
         for s, req in enumerate(batch):
             ver = self.blocks.table_version(req.rid)
@@ -3324,7 +3572,8 @@ class LLMEngine:
                 buf.bt_ver[req.rid] = ver
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
-                        track=self._trace_track, args={"rows": n})
+                        track=self._trace_track,
+                        args={"step": sid, "rows": n})
         self.pad_stats["legacy_padded"] += self.max_num_seqs
         if tr is not None:
             t = tr.now()
@@ -3334,7 +3583,9 @@ class LLMEngine:
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
-                        args={"bucket": int(Tq)})
+                        args={"step": sid, "bucket": int(Tq), "tokens": n,
+                              "rows": n, "chunks": 0, "decode": n,
+                              "logit_rows": n})
         self._d_cur = bi
         return sampled, None, fin, [], [], list(range(n))
 

@@ -168,6 +168,7 @@ def _paged_decode_launch(q, key_cache, value_cache, block_tables,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret_mode(),
+        name="paged_decode_attention",
     )(block_tables, lengths, qr,
       *([key_cache] * pages), *([value_cache] * pages))
     return out.reshape(B, H, D)
@@ -416,6 +417,7 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, seg, rel):
         ),
         out_shape=jax.ShapeDtypeStruct((Tq, Hkv, G, D), q.dtype),
         interpret=interpret_mode(),
+        name="ragged_paged_attention",
     )(seg, rel, block_tables, qr,
       *([key_cache] * pages), *([value_cache] * pages))
     return out.reshape(Tq, H, D)
@@ -511,6 +513,7 @@ def _ragged_quant_launch(q, key_cache, value_cache, key_scales,
         ),
         out_shape=jax.ShapeDtypeStruct((Tq, Hkv, G, D), q.dtype),
         interpret=interpret_mode(),
+        name="ragged_paged_attention_q8",
     )(seg, rel, block_tables, key_scales, value_scales, qr,
       *([key_cache] * pages), *([value_cache] * pages))
     return out.reshape(Tq, H, D)

@@ -5,9 +5,8 @@ handful of compiled programs; what matters for serving perf is not one
 op's latency but the shape of the whole stream — per-token latency
 percentiles, how full the decode batch ran, how often the page pool
 forced a preemption, and how many distinct programs XLA had to build.
-``ServingStats`` aggregates exactly that, and the engine additionally
-brackets each phase in ``profiler.RecordEvent`` so engine steps land in
-chrome traces next to model ops when a Profiler is active.
+``ServingStats`` aggregates exactly that; where one step's time went is
+the ``Tracer``'s to say (profiler/trace.py).
 
 A server that stays up for days must not let its stats surface grow with
 traffic: every distribution (per-token latency, TTFT, batch occupancy,

@@ -42,9 +42,11 @@ to its own row in the viewer.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
+import weakref
 from collections import deque
 
 __all__ = ["Tracer"]
@@ -96,6 +98,10 @@ class Tracer:
         self._tracks: dict = {}       # track name -> tid (viewer row)
         self._local = threading.local()
         self.t0_ns = time.perf_counter_ns()   # trace epoch
+        self._gc_owners: dict = {}    # id(owner) -> its finalizer
+        self._gc_t0 = 0
+        self._gc_tid = 0
+        self._gc_parked: deque = deque(maxlen=4096)
 
     # -- clock + tracks -----------------------------------------------------
 
@@ -142,10 +148,26 @@ class Tracer:
 
     def _push(self, ev: tuple) -> None:
         with self._lock:
-            if len(self._events) >= self.capacity:
-                self._events.popleft()
-                self.dropped += 1
-            self._events.append(ev)
+            self._drain_parked()
+            self._put(ev)
+
+    def _put(self, ev: tuple) -> None:  # guarded-by: _lock
+        if len(self._events) >= self.capacity:
+            self._events.popleft()
+            self.dropped += 1
+        self._events.append(ev)
+
+    def _drain_parked(self) -> None:  # guarded-by: _lock
+        """File what the collector parked (``_on_gc`` appends without the
+        lock; one ``popleft`` at a time is safe against that)."""
+        while self._gc_parked:
+            self._put(self._gc_parked.popleft())
+
+    def _file_parked(self) -> None:
+        """Readers call this: the collector's spans reach the ring even
+        if nothing is pushed after them."""
+        with self._lock:
+            self._drain_parked()
 
     def span(self, name: str, track: str | None = None, **args) -> _Span:
         """Context manager for one duration span on this thread's stack.
@@ -179,20 +201,68 @@ class Tracer:
         self._push(("e", name, time.perf_counter_ns(), 0,
                     self._tid(None), args, str(ev_id)))
 
+    # -- the collector ------------------------------------------------------
+
+    def watch_gc(self, owner) -> None:
+        """Record every garbage collection as a ``host.gc`` span
+        (``generation``, ``collected``) on a track of its own, until
+        ``owner`` (an engine) calls ``unwatch_gc`` or is itself freed: a
+        collection stops every Python thread, the engine's among them,
+        and is otherwise invisible in a step that took seconds.  One
+        ``gc.callbacks`` hook however many owners."""
+        key = id(owner)
+        if key in self._gc_owners:
+            return
+        first = not self._gc_owners
+        self._gc_owners[key] = weakref.finalize(owner, self._unwatch, key)
+        if first:
+            self._gc_tid = self._tid("host.gc")
+            gc.callbacks.append(self._on_gc)
+
+    def unwatch_gc(self, owner) -> None:
+        self._unwatch(id(owner))
+
+    def _unwatch(self, key: int) -> None:
+        # also an owner's finalizer, which can run inside a collection
+        # that began under this tracer's lock: so no lock here (the dict
+        # operations are single bytecodes)
+        fin = self._gc_owners.pop(key, None)
+        if fin is not None:
+            fin.detach()
+            if not self._gc_owners:
+                gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # Runs wherever an allocation tripped the collector: possibly
+        # inside _push or a reader, under this tracer's own lock.  So it
+        # takes no lock: it parks the span, and the next _push or reader
+        # files it.  Collections do not nest: one start time.
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        else:
+            t1 = time.perf_counter_ns()
+            self._gc_parked.append(
+                ("X", "host.gc", self._gc_t0, t1 - self._gc_t0,
+                 self._gc_tid, {"generation": info["generation"],
+                                "collected": info["collected"]}, None))
+
     # -- reading ------------------------------------------------------------
 
     def __len__(self) -> int:
+        self._file_parked()
         with self._lock:
             return len(self._events)
 
     def events(self) -> list:
         """Snapshot of the raw event tuples
         (ph, name, ts_ns, dur_ns, tid, args, id), oldest first."""
+        self._file_parked()
         with self._lock:
             return list(self._events)
 
     def clear(self) -> None:
         with self._lock:
+            self._gc_parked.clear()
             self._events.clear()
             self.dropped = 0
             self.unbalanced = 0
@@ -202,6 +272,7 @@ class Tracer:
         "open file" format): thread-name metadata per track, events
         sorted by timestamp, microsecond floats relative to the trace
         epoch.  Drop accounting rides in ``otherData``."""
+        self._file_parked()
         with self._lock:
             events = sorted(self._events, key=lambda e: e[2])
             tracks = dict(self._tracks)
@@ -236,8 +307,7 @@ class Tracer:
     def dump(self, path) -> int:
         """Write ``chrome_trace()`` to ``path``; returns the number of
         non-metadata events written."""
-        with self._lock:
-            n = len(self._events)
+        n = len(self)
         doc = self.chrome_trace()
         with open(path, "w", encoding="utf-8") as f:
             json.dump(doc, f)

@@ -1,0 +1,152 @@
+"""The ragged attention kernel lowered for the chip this repo serves
+on, without the chip: the TPU's compiler is installed here and compiles
+for a v5e that is described, not attached.
+
+What it guards: the custom call that carries the kernel must depend on
+the kernel alone.  Mosaic serializes the kernel's MLIR module into the
+call's payload, and with Python tracebacks in its locations the payload
+(and so the compilation cache's key) changed with the line and the depth
+of the stack the kernel was reached from: a traced and an untraced run
+of one cell each compiled their own copy of every step program.
+`configure_compile_cache()` (core/runtime.py) lowers without Python
+frames; the suite's conftest calls it, as every entry point does.
+
+The topology is described inside a fixture, never at import: one process
+at a time may load the TPU's library, and every pytest worker imports
+every test file.  Keep these tests in this one file."""
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+# (query heads, K/V heads, head size) of the benchmark's configurations
+WIDTHS = {"mistral-7b": (32, 8, 128), "yi-1.5-6b": (32, 4, 128)}
+BLOCK, NUM_BLOCKS, NBLK, ROWS = 16, 4097, 256, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """The kernel itself, not its interpreter (off the TPU the module
+    resolves to interpret mode by default)."""
+    monkeypatch.setattr(pa, "INTERPRET", False)
+
+
+def _shapes(one_chip, model, tq, *, quant=False):
+    h, kvh, d = WIDTHS[model]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    page = jnp.int8 if quant else jnp.bfloat16
+    pool = sds((NUM_BLOCKS, kvh, BLOCK, d), page)
+    scales = (sds((NUM_BLOCKS, kvh), jnp.float32),) * 2 if quant else ()
+    return (sds((tq, h, d), jnp.bfloat16), pool, pool) + scales + (
+        sds((ROWS + 1, NBLK), jnp.int32), sds((tq,), jnp.int32),
+        sds((tq,), jnp.int32))
+
+
+def _payloads(text: str) -> list:
+    return re.findall(r'backend_config\s*=\s*"((?:[^"\\]|\\.)*)"', text)
+
+
+def _direct(*a):
+    return pa.ragged_paged_attention_segrel_packed(*a)
+
+
+def _inner(*a):
+    x = a[0] * 1                # another line ...
+    return pa.ragged_paged_attention_segrel_packed(x, *a[1:])
+
+
+def _through_a_wrapper(*a):
+    with jax.named_scope("attn"):
+        return _inner(*a)       # ... and another depth of the stack
+
+
+@pytest.mark.parametrize("tq", [32, 192])
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_kernel_payload_is_independent_of_the_call_stack(
+        one_chip, compiled_kernels, model, tq):
+    args = _shapes(one_chip, model, tq)
+    a = jax.jit(_direct).lower(*args).as_text()
+    b = jax.jit(_through_a_wrapper).lower(*args).as_text()
+    pa_, pb_ = _payloads(a), _payloads(b)
+    assert len(pa_) == len(pb_) == 1
+    assert pa_ == pb_                      # byte for byte
+    assert "tpu_custom_call" in a
+    assert 'kernel_name = "ragged_paged_attention"' in a
+    assert __file__ not in a and "test_chip_lowering" not in pa_[0]
+
+
+def test_int8_page_kernel_is_named_and_stack_independent(
+        one_chip, compiled_kernels):
+    args = _shapes(one_chip, "mistral-7b", 32, quant=True)
+
+    def direct(*a):
+        return pa.ragged_paged_attention_quant_segrel_packed(*a)
+
+    def wrapped(*a):
+        return direct(*a)
+
+    a = jax.jit(direct).lower(*args).as_text()
+    b = jax.jit(wrapped).lower(*args).as_text()
+    assert _payloads(a) == _payloads(b) and len(_payloads(a)) == 1
+    assert 'kernel_name = "ragged_paged_attention_q8"' in a
+
+
+def test_entry_points_lower_without_python_frames_and_keep_scopes(
+        one_chip, compiled_kernels):
+    """What holds the payload still is one process-wide option of JAX's
+    that every entry point sets with its compile cache (the suite's
+    conftest too): no Python frames in MLIR locations.  The scope and
+    operation names, which `LLMEngine.program_scopes()` reads back from
+    the compiled text, stay."""
+    from paddle_tpu.core.runtime import configure_compile_cache
+    configure_compile_cache()
+    assert jax.config.jax_traceback_in_locations_limit == 0
+    text = jax.jit(_through_a_wrapper).lower(
+        *_shapes(one_chip, "yi-1.5-6b", 32)).as_text(debug_info=True)
+    assert ('loc("jit(_through_a_wrapper)/attn/ragged_paged_attention/'
+            'pallas_call"') in text
+    assert ".py" not in text
+
+
+def test_the_kernel_compiles_for_the_v5e(one_chip, compiled_kernels):
+    """Mistral's 192-token bucket through the chip's own compiler: what
+    it refuses here it refuses on the chip (tiling, fast memory).  The
+    persistent cache is off around it: an entry written for a described
+    chip cannot be read back, and the next run would warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(_direct).lower(
+            *_shapes(one_chip, "mistral-7b", 192)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    text = compiled.as_text()
+    assert "ragged_paged_attention" in text
+    assert "custom-call" in text
