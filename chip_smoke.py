@@ -63,8 +63,16 @@ FULL = {
     "max_num_seqs": 8, "chunk_bucket": 512,
     # prompt lengths: bucket 64, bucket 128, then 512 + 100 in chunks
     "prompts": (40, 100, 612), "new_tokens": 16, "stream_tokens": 48,
-    "kernel": {"H": 32, "Hkv": 32, "D": 128, "bs": 16, "nblk": 8,
-               "dtype": "bfloat16"},
+    # the served model's head layout (one query head a K/V head), then
+    # a group of eight with a chunk row longer than the kernel's q tile,
+    # then that over int8 pages
+    "kernels": [{"H": 32, "Hkv": 32, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (17, 5, 1), "Tq": 32},
+                {"H": 32, "Hkv": 4, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 32, "Hkv": 4, "D": 128, "bs": 32, "nblk": 4,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64,
+                 "pages": "int8"}],
     "start_timeout_s": 300.0, "request_timeout_s": 600.0,
 }
 TINY = {
@@ -75,8 +83,13 @@ TINY = {
     "vocab": 256, "max_num_seqs": 8, "chunk_bucket": 192,
     # bucket 64, bucket 128, then 192 + 68 in chunks
     "prompts": (20, 100, 260), "new_tokens": 4, "stream_tokens": 12,
-    "kernel": {"H": 4, "Hkv": 4, "D": 16, "bs": 16, "nblk": 4,
-               "dtype": "float32"},
+    "kernels": [{"H": 4, "Hkv": 4, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (17, 5, 1), "Tq": 32},
+                {"H": 8, "Hkv": 1, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 8, "Hkv": 1, "D": 16, "bs": 32, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64,
+                 "pages": "int8"}],
     "start_timeout_s": 120.0, "request_timeout_s": 300.0,
 }
 
@@ -95,28 +108,25 @@ def _check(cond, msg):
 # or paddle_tpu, inside its functions)
 # ---------------------------------------------------------------------------
 
-def ragged_case(rng, H, D, bs, nblk, q_dtype):
-    """Three mixed-phase rows as the engine packs them (a fresh 17-token
-    prefill, a 5-token resumed chunk, one decode token) plus tail
-    padding, over a shuffled page table with the engine's [R+1]-row
-    layout whose last row is the null row padding resolves to.  Returns
-    (q, block_tables, seg, rel, num_blocks, live tokens).  Shared with
-    tests/test_tpu_hardware.py."""
+def ragged_case(rng, H, D, bs, nblk, q_dtype, qlens=(17, 5, 1), Tq=32):
+    """Three mixed-phase rows as the engine packs them (a fresh prefill,
+    a resumed chunk, one decode token) plus tail padding, over a
+    shuffled page table with the engine's [R+1]-row layout whose last
+    row is the null row.  Returns (q, block_tables, cu, kv_lens,
+    num_blocks, live tokens).  Shared with tests/test_tpu_hardware.py."""
     import jax.numpy as jnp
     import numpy as np
 
-    from paddle_tpu.ops.pallas import paged_attention as pa
-
-    R, Tq = 3, 32
-    qlens = np.array([17, 5, 1])
-    kvl = np.array([17, 40, nblk * bs - 3], np.int32)
+    R = 3
+    qlens = np.asarray(qlens)
+    kvl = np.array([qlens[0], qlens[1] + 35, nblk * bs - 3], np.int32)
     cu = np.concatenate([[0], np.cumsum(qlens)]).astype(np.int32)
     num_blocks = 1 + R * nblk
     bt = np.zeros((R + 1, nblk), np.int32)
     bt[:R] = 1 + rng.permutation(R * nblk).reshape(R, nblk)
     q = jnp.asarray(rng.randn(Tq, H, D), q_dtype)
-    seg, rel = pa.ragged_segments(jnp.asarray(cu), jnp.asarray(kvl), Tq)
-    return q, jnp.asarray(bt), seg, rel, num_blocks, int(cu[-1])
+    return (q, jnp.asarray(bt), jnp.asarray(cu), jnp.asarray(kvl),
+            num_blocks, int(cu[-1]))
 
 
 def kernel_check(size: dict) -> int:
@@ -138,33 +148,54 @@ def kernel_check(size: dict) -> int:
         print(f"[kernel] platform is {device['platform']!r}, this run "
               f"needs {size['platform']!r}", file=sys.stderr)
         return 1
-    k = size["kernel"]
-    H, Hkv, D, bs, nblk = k["H"], k["Hkv"], k["D"], k["bs"], k["nblk"]
-    dtype = jnp.dtype(k["dtype"])
-    rng = np.random.RandomState(0)
-    q, bt, seg, rel, num_blocks, live = ragged_case(rng, H, D, bs, nblk,
-                                                    dtype)
-    kc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
-    vc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
-    out = jax.jit(pa.ragged_paged_attention_segrel_packed)(
-        q, kc, vc, bt, seg, rel)
-    # the reference materialises [Tq, S, Hkv, D]: fine on this small
-    # table, about 17 GB a layer at a full prefill launch
-    with jax.default_matmul_precision("highest"):
-        ref = pa.ragged_paged_reference_segrel(
-            q.astype(jnp.float32), kc.astype(jnp.float32),
-            vc.astype(jnp.float32), bt, seg, rel)
-    out32 = out[:live].astype(jnp.float32)
-    err = float(jnp.max(jnp.abs(out32 - ref[:live])))
-    finite = bool(jnp.all(jnp.isfinite(out32)))
-    # bf16 pages: scores accumulate in f32 from exact bf16 products;
-    # the probabilities are rounded to bf16 for the PV matmul and the
-    # output to bf16, half an ulp (2^-9 relative) each on values that
-    # reach 4: 1.6e-2 at worst, 1.3e-2 measured on the v5e.  Computing
-    # in anything narrower than bf16 would not pass.  The float32 toy
-    # runs the kernel in the interpreter on the CPU, where only
-    # summation order differs.
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    cases = []
+    for k in size["kernels"]:
+        H, Hkv, D, bs, nblk = k["H"], k["Hkv"], k["D"], k["bs"], k["nblk"]
+        dtype = jnp.dtype(k["dtype"])
+        rng = np.random.RandomState(0)
+        q, bt, cu, kvl, num_blocks, live = ragged_case(
+            rng, H, D, bs, nblk, dtype, k["qlens"], k["Tq"])
+        pool = (num_blocks, Hkv, bs, D)
+        if k.get("pages") == "int8":
+            # int8 pages under per-page-per-head scales: the same body
+            # with a dequantising load
+            kc = jnp.asarray(rng.randint(-127, 128, pool), jnp.int8)
+            vc = jnp.asarray(rng.randint(-127, 128, pool), jnp.int8)
+            ks = jnp.asarray(rng.uniform(0.5, 1.5, pool[:2]) / 127.0,
+                             jnp.float32)
+            vs = jnp.asarray(rng.uniform(0.5, 1.5, pool[:2]) / 127.0,
+                             jnp.float32)
+            out = jax.jit(pa.ragged_paged_attention_quant_packed)(
+                q, kc, vc, ks, vs, bt, cu, kvl)
+            kc = kc.astype(jnp.float32) * ks[:, :, None, None]
+            vc = vc.astype(jnp.float32) * vs[:, :, None, None]
+        else:
+            kc = jnp.asarray(rng.randn(*pool), dtype)
+            vc = jnp.asarray(rng.randn(*pool), dtype)
+            out = jax.jit(pa.ragged_paged_attention_packed)(
+                q, kc, vc, bt, cu, kvl)
+        # the reference materialises [Tq, S, Hkv, D]: fine on this small
+        # table, about 17 GB a layer at a full prefill launch
+        with jax.default_matmul_precision("highest"):
+            ref = pa.ragged_paged_reference(
+                q.astype(jnp.float32), kc.astype(jnp.float32),
+                vc.astype(jnp.float32), bt, cu, kvl)
+        out32 = out.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(out32[:live] - ref[:live])))
+        # live rows finite, padded rows zero (the kernel gives them no
+        # work and writes them so)
+        finite = bool(jnp.all(jnp.isfinite(out32))) \
+            and not bool(jnp.any(out32[live:]))
+        # bf16 pages: scores accumulate in f32 from exact bf16 products;
+        # the probabilities are rounded to bf16 for the PV matmul and
+        # the output to bf16, half an ulp (2^-9 relative) each on values
+        # that reach 4: 1.6e-2 at worst, 1.3e-2 measured on the v5e.
+        # Computing in anything narrower than bf16 would not pass.  The
+        # float32 toy runs the kernel in the interpreter on the CPU,
+        # where only summation order differs.
+        tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        cases.append({"shape": k, "max_abs_err": err, "tolerance": tol,
+                      "finite": finite})
 
     def _version(pkg):
         try:
@@ -176,10 +207,10 @@ def kernel_check(size: dict) -> int:
         "phase": "kernel_check", "device": device,
         "versions": {"jax": jax.__version__, "jaxlib": _version("jaxlib"),
                      "libtpu": _version("libtpu")},
-        "interpret": pa.interpret_mode(), "shape": k,
-        "max_abs_err": err, "tolerance": tol, "finite": finite,
+        "interpret": pa.interpret_mode(), "cases": cases,
         "compile_cache_dir": cache_dir, **watch.snapshot()}), flush=True)
-    return 0 if finite and err < tol else 1
+    return 0 if all(c["finite"] and c["max_abs_err"] < c["tolerance"]
+                    for c in cases) else 1
 
 
 # ---------------------------------------------------------------------------
@@ -452,10 +483,11 @@ def main(argv=None) -> int:
     print(f"[smoke] device: platform={device['platform']} "
           f"device_kind={device['kind']!r} count={device['count']} "
           f"versions={kernel['versions']}", flush=True)
-    print(f"[smoke] ragged kernel vs reference at {kernel['shape']}: max "
-          f"abs err {kernel['max_abs_err']:.3e} (tolerance "
-          f"{kernel['tolerance']:g}), {time.monotonic() - t0:.1f} s",
-          flush=True)
+    for c in kernel["cases"]:
+        print(f"[smoke] ragged kernel vs reference at {c['shape']}: max "
+              f"abs err {c['max_abs_err']:.3e} (tolerance "
+              f"{c['tolerance']:g}), {time.monotonic() - t0:.1f} s",
+              flush=True)
 
     server = Server(size["server"], env)
     try:

@@ -241,6 +241,30 @@ def _instruction_scopes(hlo_text: str) -> dict:
     return out
 
 
+def _scan_layers(body, x, layers, pools):
+    """``lax.scan`` over the stacked layers with the stacked pools (K and
+    V pages; over int8 pages their scales too) in the CARRY: each turn
+    slices its layer's pools out, gives them to ``body(x, (p, *pools))
+    -> (x, pools)`` and writes what comes back in place.  Scanned
+    through as inputs and outputs the pools came back in a new buffer,
+    and aliasing it to the donated input cost a copy of each whole pool
+    a step (3.2 ms a GB on the v5e) that XLA makes up, so that no scope
+    names it in a trace."""
+    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+
+    def turn(carry, inp):
+        x, pools = carry
+        p, l = inp
+        x, new = body(x, (p,) + tuple(
+            lax.dynamic_index_in_dim(c, l, keepdims=False) for c in pools))
+        return (x, tuple(lax.dynamic_update_index_in_dim(c, v, l, 0)
+                         for c, v in zip(pools, new))), None
+
+    (x, pools), _ = lax.scan(turn, (x, tuple(pools)),
+                             (layers, jnp.arange(n, dtype=jnp.int32)))
+    return x, pools
+
+
 # what wraps a launch when no tracer is installed: the jitted call keeps
 # one call site either way (see ``_call_program``)
 _NO_ANNOTATION = contextlib.nullcontext()
@@ -575,7 +599,8 @@ class LLMEngine:
         # padding accounting: real packed tokens vs bucket width, plus
         # what the pre-ragged four-program engine would have padded to
         # (serve_bench --mixed reports the two ratios side by side)
-        self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0}
+        self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
+                          "kv_pages": 0}
         # launches dispatched so far: the STEP ID every trace event
         # carries (the dispatch half of a step the id of the launch it
         # prepares, launches + 1; the completion half its ticket's)
@@ -693,20 +718,18 @@ class LLMEngine:
     def _resolve_attention_path(self) -> str:
         """Which attention the step programs run, decided once from the
         platform and the kernel's static claim.  The interpreted kernel
-        costs a Python step per (Tq, H_kv, nblk) grid cell EVERY launch,
-        so off the TPU the XLA reference (term-identical math) serves
-        unless a test forces the interpreter."""
+        runs its row, block and page loops as XLA loops on the host
+        EVERY launch, so off the TPU the XLA reference (term-identical
+        math) serves unless a test forces the interpreter."""
         if _pa.INTERPRET is True:
             return "pallas-interpret"
         if self._platform != "tpu":
             return f"xla-reference ({self._platform} platform)"
-        # the worst packable launch (precompile_buckets' ceiling)
-        Tq = self._ragged_bucket(self.max_prefill_tokens + self._Lq)
         why = _pa.ineligible(self._nh // self.tp, self._kvh // self.tp,
                              self._hd, self.block_size,
                              jnp.int8 if self.kv_dtype == "int8"
                              else self._act_dtype,
-                             launch=(Tq, self.max_num_seqs + 1, self.nblk,
+                             launch=(self.max_num_seqs + 1, self.nblk,
                                      self.blocks.num_blocks))
         return "pallas" if why is None else f"xla-reference ({why})"
 
@@ -1152,6 +1175,7 @@ class LLMEngine:
         # drain): real against padded query tokens
         out["tokens_real"] = self.pad_stats["real"]
         out["tokens_padded"] = self.pad_stats["padded"]
+        out["kv_pages_live"] = self.pad_stats["kv_pages"]
         out["paths"] = self.paths()
         out["tuning_cache"] = {
             "path": self._tuning_report["path"],
@@ -2805,13 +2829,13 @@ class LLMEngine:
                 with jax.named_scope("attn"):
                     if use_pallas:
                         # the host packing path owns these buffers: bt is
-                        # the int32 NULL_BLOCK-padded pool table ([B+1]
-                        # rows, so the seg pad sentinel B is the valid
-                        # null row) and seg/rel come int32 from
-                        # ragged_segments — the packed entry skips the
-                        # per-launch re-clip/re-cast
-                        att = _pa.ragged_paged_attention_segrel_packed(
-                            q, kcl, vcl, bt, seg, rel)
+                        # the int32 NULL_BLOCK-padded pool table and cu,
+                        # kvl come int32 from the step's packing, so the
+                        # packed entry skips the per-launch re-clip and
+                        # re-cast.  The kernel reads the row layout
+                        # itself; seg/rel are for rope and kv_write
+                        att = _pa.ragged_paged_attention_packed(
+                            q, kcl, vcl, bt, cu, kvl)
                     else:
                         att = _pa.ragged_paged_reference_segrel(
                             q, kcl, vcl, bt, seg, rel)
@@ -2832,8 +2856,8 @@ class LLMEngine:
                 return x, (kcl, vcl)
 
             with jax.named_scope("layers"):
-                x, (kc, vc) = lax.scan(body, x,
-                                       (params["layers"], kc, vc))
+                x, (kc, vc) = _scan_layers(body, x, params["layers"],
+                                           (kc, vc))
             with jax.named_scope("norm"):
                 h = _rms_weight(x, params["norm_f"], eps)
             with jax.named_scope("head"):
@@ -2895,7 +2919,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
-        quant_attn = _pa.ragged_paged_attention_quant_segrel_packed
+        quant_attn = _pa.ragged_paged_attention_quant_packed
 
         def run(params, kc, vc, ks, vs, fresh, toks, cu, kvl, bt, lidx,
                 samp):
@@ -2959,7 +2983,7 @@ class LLMEngine:
                         # packed-entry invariant as in the float step;
                         # the scale pools are born f32 on the host
                         att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
-                                         seg, rel)
+                                         cu, kvl)
                     else:
                         att = _pa.ragged_paged_reference_quant_segrel(
                             q, kcl, vcl, ksl, vsl, bt, seg, rel)
@@ -2978,9 +3002,8 @@ class LLMEngine:
                 return x, (kcl, vcl, ksl, vsl)
 
             with jax.named_scope("layers"):
-                x, (kc, vc, ks, vs) = lax.scan(body, x,
-                                               (params["layers"], kc, vc,
-                                                ks, vs))
+                x, (kc, vc, ks, vs) = _scan_layers(
+                    body, x, params["layers"], (kc, vc, ks, vs))
             with jax.named_scope("norm"):
                 h = _rms_weight(x, params["norm_f"], eps)
             with jax.named_scope("head"):
@@ -3028,6 +3051,7 @@ class LLMEngine:
                        real_tokens):
         self.pad_stats["real"] += int(real_tokens)
         self.pad_stats["padded"] += int(Tq)
+        self.pad_stats["kv_pages"] += self._kv_pages(kvl)
         prog = self._get_ragged_prog(Tq)
         tail = (toks, cu, kvl, bt, lidx, samp)
         if self.kv_dtype == "int8":
@@ -3043,6 +3067,11 @@ class LLMEngine:
             out = out[:-2]
         sampled, fin = out[0], out[1]
         return sampled, (out[2] if self._with_logits else None), fin
+
+    def _kv_pages(self, kvl) -> int:
+        """Pages a launch's rows hold keys in: what the attention kernel
+        walks, of the bucket * nblk page slots of the table."""
+        return int((-(-np.asarray(kvl) // self.block_size)).sum())
 
     def _get_window_prog(self):
         """The compiled K-step decode window driver (one per engine —
@@ -3109,7 +3138,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
-        float_attn = _pa.ragged_paged_attention_segrel_packed
+        float_attn = _pa.ragged_paged_attention_packed
 
         def run(params, kc, vc, toks, kvl, active, gen, budgets,
                 eos_ids, base_keys, bt, samp):
@@ -3126,6 +3155,7 @@ class LLMEngine:
                 (i, tok, kvl, active, gen, seen, kc, vc, touts,
                  fouts) = carry
                 seg, rel = _pa.decode_window_segments(active, kvl)
+                cu_w, kvl_w = _pa.decode_window_rows(active, kvl)
                 with jax.named_scope("embed"):
                     x = embed(params, tok)                    # [B, H]
 
@@ -3149,7 +3179,8 @@ class LLMEngine:
                             v.astype(vcl.dtype))
                     with jax.named_scope("attn"):
                         if use_pallas:
-                            att = float_attn(q, kcl, vcl, bt, seg, rel)
+                            att = float_attn(q, kcl, vcl, bt, cu_w,
+                                             kvl_w)
                         else:
                             att = _pa.ragged_paged_reference_segrel(
                                 q, kcl, vcl, bt, seg, rel)
@@ -3168,8 +3199,8 @@ class LLMEngine:
                     return x, (kcl, vcl)
 
                 with jax.named_scope("layers"):
-                    x, (kc, vc) = lax.scan(body, x,
-                                           (params["layers"], kc, vc))
+                    x, (kc, vc) = _scan_layers(
+                        body, x, params["layers"], (kc, vc))
                 with jax.named_scope("norm"):
                     h = _rms_weight(x, params["norm_f"], eps)
                 with jax.named_scope("head"):
@@ -3233,7 +3264,7 @@ class LLMEngine:
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
         use_pallas = self.attention_path.startswith("pallas")
-        quant_attn = _pa.ragged_paged_attention_quant_segrel_packed
+        quant_attn = _pa.ragged_paged_attention_quant_packed
 
         def run(params, kc, vc, ks, vs, fresh, toks, kvl, active, gen,
                 budgets, eos_ids, base_keys, bt, samp):
@@ -3245,6 +3276,7 @@ class LLMEngine:
                 (i, tok, kvl, active, gen, seen, kc, vc, ks, vs, touts,
                  fouts) = carry
                 seg, rel = _pa.decode_window_segments(active, kvl)
+                cu_w, kvl_w = _pa.decode_window_rows(active, kvl)
                 with jax.named_scope("embed"):
                     x = embed(params, tok)                    # [B, H]
 
@@ -3297,7 +3329,7 @@ class LLMEngine:
                     with jax.named_scope("attn"):
                         if use_pallas:
                             att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
-                                             seg, rel)
+                                             cu_w, kvl_w)
                         else:
                             att = _pa.ragged_paged_reference_quant_segrel(
                                 q, kcl, vcl, ksl, vsl, bt, seg, rel)
@@ -3317,8 +3349,8 @@ class LLMEngine:
                     return x, (kcl, vcl, ksl, vsl)
 
                 with jax.named_scope("layers"):
-                    x, (kc, vc, ks, vs) = lax.scan(
-                        body, x, (params["layers"], kc, vc, ks, vs))
+                    x, (kc, vc, ks, vs) = _scan_layers(
+                        body, x, params["layers"], (kc, vc, ks, vs))
                 with jax.named_scope("norm"):
                     h = _rms_weight(x, params["norm_f"], eps)
                 with jax.named_scope("head"):
@@ -3490,7 +3522,8 @@ class LLMEngine:
                         args={"step": sid, "bucket": int(Tq),
                               "tokens": total, "rows": len(rows),
                               "chunks": len(chunks), "decode": len(batch),
-                              "logit_rows": logit_rows})
+                              "logit_rows": logit_rows,
+                              "kv_pages": self._kv_pages(kvl)})
         # NO materialization here: sampled/logits/fin return as async
         # device arrays; _complete blocks on them (the dispatch path
         # must never force a host sync on step-program outputs)
@@ -3585,7 +3618,8 @@ class LLMEngine:
                         track=self._trace_track,
                         args={"step": sid, "bucket": int(Tq), "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
-                              "logit_rows": n})
+                              "logit_rows": n,
+                              "kv_pages": self._kv_pages(buf.kvl)})
         self._d_cur = bi
         return sampled, None, fin, [], [], list(range(n))
 

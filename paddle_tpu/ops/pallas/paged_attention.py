@@ -5,28 +5,36 @@ non-contiguous fixed-size pages addressed by block tables (the reference's
 paged CUDA decode kernel,
 /root/reference/paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu
 -> block_attn.h).  The XLA composition must first GATHER every sequence's
-pages into a dense [B, nblk*bs] buffer — O(B * max_len) HBM traffic twice
-(gather + read).  These kernels instead walk the block table with Pallas
-scalar prefetch: the grid's page dimension indexes the block table
-directly in each page's BlockSpec index map, so pages stream from HBM to
-VMEM exactly once, with no dense intermediate.
+pages into a dense [B, nblk*bs] buffer: O(B * max_len) HBM traffic twice
+(gather + read).  These kernels walk the block table themselves, from
+scalar memory, so pages stream from HBM to VMEM with no dense
+intermediate.
 
-`ragged_paged_attention` is the serving workhorse (arxiv 2604.15464): the
-grid runs over FLAT query tokens, each token resolves its owning row via
-`cu_seqlens` and masks keys at its absolute position — so a prefill
-chunk, a resumed chunk, a single decode token, and a k-draft verify row
-are all just rows with different query lengths, served by ONE program.
-`paged_decode_attention` is the original one-token-per-row special case,
-kept for the incubating blha path and as a second oracle.
+`ragged_paged_attention` is the serving workhorse (arxiv 2604.15464).  It
+is driven by the ROW layout the step program has (`cu_seqlens`, `kv_lens`,
+the block table, all scalar-prefetched): a prefill chunk, a resumed chunk,
+a single decode token and a k-draft verify row are all just rows with
+different query lengths, served by ONE program.  Its unit of work is a
+tile of one row's queries against that row's live pages: the queries of a
+row are tiled together (tq*G score rows for a row of several queries, G
+for a row of one), the pool stays in HBM and whole pages come from it by
+double-buffered async copies, and the walk ends at the page of the last
+key the tile's last query may see.  A page past a row's kv_len, a padded
+token and a row of no keys get no copy, no loop turn and no arithmetic;
+their output reads zero.  Int8 pages take the same body with a
+dequantising load (`ragged_paged_attention_quant`).
+`paged_decode_attention` is the original one-token-per-row grid kernel
+(a grid step a page slot of the table), kept for the incubating blha path
+and as a second oracle.
 
 Layout: caches are [num_blocks, H_kv, bs, D] (blha cache layout), block
-tables int32, per-row lengths int32.  GQA is native: grid runs over kv
-heads, each kernel instance carries the q-head group [G, D] so the
-[G, bs] score tile keeps the MXU busy.
+tables int32, per-row lengths int32.  GQA is native: a score tile carries
+the q-head group of one kv head, [tq*G, kv_pages*bs].
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,10 +46,14 @@ from jax.experimental.pallas import tpu as pltpu
 # CPU without mutating this global.  Tests that need a forced mode (the
 # fixture in tests/test_paged_attention.py) may still assign True/False
 # here and restore the old value after.  NOTE the serving engine does
-# NOT ride the auto-resolved interpret mode: interpreted decode costs a
-# Python step per (B, H_kv, nblk) grid cell, so LLMEngine uses the XLA
-# reference path off-TPU unless INTERPRET is explicitly True.
+# NOT ride the auto-resolved interpret mode: the interpreted kernels are
+# slow (a step per grid cell, a host loop per row, block and page), so
+# LLMEngine uses the XLA reference path off-TPU unless INTERPRET is
+# explicitly True.
 INTERPRET = None
+
+# K/V heads the ragged kernel's body unrolls at a time (the rest loop)
+_HEADS_UNROLL = 8
 
 
 def interpret_mode() -> bool:
@@ -51,19 +63,25 @@ def interpret_mode() -> bool:
     return bool(INTERPRET)
 
 
-def _pages_per_step(tq, kv_heads, head_dim, page, nblk, dtype):
-    """Trace-time tuned page-walk width for the paged kernels.
+def _tuned(tq, kv_heads, head_dim, page, nblk, dtype) -> dict:
+    """The paged kernels' trace-time lookup in the tuning cache."""
+    from ...tune import kernel_config
+    return kernel_config("paged_attention",
+                         {"tq": tq, "kv_heads": kv_heads,
+                          "head_dim": head_dim, "page": page, "nblk": nblk,
+                          "dtype": jnp.dtype(dtype).name})
 
-    The tuned value only widens the innermost grid step — pages are
+
+def _pages_per_step(tq, kv_heads, head_dim, page, nblk, dtype):
+    """Trace-time tuned page-walk width of the decode grid kernel: the
+    tunable's K/V block in pages, walked inside one grid step.
+
+    The tuned value only widens the innermost grid step: pages are
     still visited in the same ascending order, so the online-softmax
     accumulation (and therefore every output byte) is invariant; only
     the launch-overhead amortization changes."""
-    from ...tune import kernel_config
-    cfg = kernel_config("paged_attention",
-                        {"tq": tq, "kv_heads": kv_heads,
-                         "head_dim": head_dim, "page": page, "nblk": nblk,
-                         "dtype": jnp.dtype(dtype).name})
-    return max(1, min(int(cfg["pages_per_step"]), nblk))
+    cfg = _tuned(tq, kv_heads, head_dim, page, nblk, dtype)
+    return max(1, min(int(cfg["kv_pages"]), nblk))
 
 
 def _page_index(i, pages, j, nblk):
@@ -223,117 +241,253 @@ def paged_decode_reference(q, key_cache, value_cache, block_tables,
     return out.astype(q.dtype)
 
 
-def _ragged_kernel(seg_ref, rel_ref, bt_ref, q_ref, *refs, bs, sm_scale,
-                   pages, nblk):
-    """grid (Tq, H_kv, ceil(nblk/pages)); refs: q [G, D] (one flat
-    token's group for one kv head), then `pages` k pages and `pages` v
-    pages [bs, D] of that token's owning row, o [G, D]; scratch m/l
-    [G, 1] f32, acc [G, D] f32.
+# K/V pages in flight: two slots of a block of K and of V.  A block is
+# cut to this many bytes, so a layout with fat pages (32 K/V heads: 128
+# KB a page) walks fewer pages a block than one with thin ones.
+_KV_BUFFER_BYTES = 8 << 20
 
-    seg[t] names the block-table row owning flat token t; rel[t] is the
-    token's position within that row's KV (0-based), so causality is just
-    `keypos <= rel[t]` — uniform across prefill/resume/decode/verify rows.
-    Pages are walked j=0..pages in ascending order: the accumulation
-    order — and therefore every output byte — is identical for any
-    `pages` width; only launch-overhead amortization changes.
+
+def _ragged_tiles(Tq, Hkv, G, D, bs, nblk, dtype):
+    """Trace-time tuned tile of the ragged kernel: (q tile in tokens,
+    K/V block in pages).  The tuned `q_tile_rows` is the score tile's
+    height, so the token tile follows the program's group size (a tile
+    of tq tokens is tq*G rows of one K/V head); it is cut to a divisor
+    of Tq so flat-token tiles never overhang the bucket."""
+    cfg = _tuned(Tq, Hkv, D, bs, nblk, dtype)
+    tq = max(1, min(int(cfg["q_tile_rows"]) // G, Tq))
+    while Tq % tq:
+        tq -= 1
+    page_bytes = Hkv * bs * D * jnp.dtype(dtype).itemsize
+    kvb = min(int(cfg["kv_pages"]), nblk,
+              _KV_BUFFER_BYTES // (4 * page_bytes))
+    return tq, max(1, kvb)
+
+
+def _ragged_kernel(cu_ref, kvl_ref, bt_ref, *refs, rows, tq, kvb, bs, nblk,
+                   quant):
+    """One invocation walks the launch's rows in order.  Refs: q
+    [Tq, Hkv, G, D] and the same tokens head-major qt [Hkv, Tq*G, D],
+    both pre-scaled, in VMEM; the K and V pools [num_blocks, Hkv, bs, D]
+    left in HBM; o [Tq, Hkv, G, D] in VMEM.  Scratch: kbuf/vbuf
+    [2, kvb, Hkv, bs, D] (two slots of kvb whole pages), DMA semaphores
+    [2, 2] (K/V x slot), m/l [Hkv, tq*G, 1] and acc [Hkv, tq*G, D] f32.
+
+    The unit of work is an ITEM: a tile of one row's queries against
+    that row's live pages.  A row of one query (decode) is one item of
+    G score rows read from q; a longer row (a chunk, a resumed chunk, a
+    verify row) is one item for every flat-token tile of tq tokens it
+    touches, tq*G score rows read from qt, its other rows masked at the
+    store.  An item walks K/V blocks of kvb pages from page 0 to the
+    page of the last key its last query may see and no further: pages
+    come from the pool by one async copy each (a page's [Hkv, bs, D] is
+    contiguous), a block ahead of the arithmetic, and each item starts
+    its successor's first block, so a copy is in flight across items
+    too.  A row of no queries, or of no keys, is an item of no blocks;
+    o is zeroed first, so padding and such rows read zero.
+
+    Over int8 pages (``quant``) two more prefetched operands lead refs:
+    the [num_blocks, Hkv] f32 scale pools, in scalar memory beside the
+    table.  A page is dequantized as it is read for the product (float
+    = int8 * its page's scale for the head), q and the probabilities
+    stay float32, and no dense float copy of a row's K/V ever exists.
     """
-    k_refs = refs[:pages]
-    v_refs = refs[pages:2 * pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
-    t = pl.program_id(0)
-    i = pl.program_id(2)
-    steps = pl.num_programs(2)
-    rel = rel_ref[t]                          # absolute key budget, 0-based
+    if quant:
+        ksc_ref, vsc_ref, *refs = refs
+    (q_ref, qt_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref,
+     acc_ref) = refs
+    Hkv, G, D = q_ref.shape[1:]
+    kv = kvb * bs
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def heads(fn):
+        """fn(h) for every K/V head: unrolled eight at a time (a body of
+        32 heads takes the compiler a minute)."""
+        u = math.gcd(Hkv, _HEADS_UNROLL)
+        if u == Hkv:
+            for h in range(Hkv):
+                fn(h)
+            return
 
-    for j in range(pages):
-        base = (i * pages + j) * bs
+        def step(g, c):
+            for i in range(u):
+                fn(g * u + i)
+            return c
+        jax.lax.fori_loop(0, Hkv // u, step, 0)
 
-        @pl.when(base <= rel)
-        def _tile(base=base, k_ref=k_refs[j], v_ref=v_refs[j]):
-            q = (q_ref[...].astype(jnp.float32) * sm_scale).astype(
-                q_ref.dtype)
-            k = k_ref[...]                     # [bs, D]
-            v = v_ref[...]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [G, bs]
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(pos <= rel, s, -jnp.inf)
-            m_prev = m_ref[...]                # [G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)             # [G, bs]
-            alpha = jnp.exp(m_prev - m_new)    # [G, 1]
-            l_ref[...] = alpha * l_ref[...] + \
-                jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+    def n_pages(r, j):
+        """Pages item (r, j) walks: up to the last key its last query
+        sees (j is not read for a row of at most one query)."""
+        qs, qe = cu_ref[r], cu_ref[r + 1]
+        n_q = qe - qs
+        last = jnp.where(n_q <= 1, qs, jnp.minimum(qe, (j + 1) * tq) - 1)
+        rel_last = kvl_ref[r] - n_q + last - qs
+        np_ = jnp.where(n_q > 0, rel_last // bs + 1, 0)
+        return jnp.clip(np_, 0, nblk)
 
-    @pl.when(i == steps - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    def tiles(r):
+        """Tile range [j0, j1) of row r: one item for a row of at most
+        one query, else the flat-token tiles it touches."""
+        qs, qe = cu_ref[r], cu_ref[r + 1]
+        one = qe - qs <= 1
+        return (jnp.where(one, 0, qs // tq),
+                jnp.where(one, 1, (qe + tq - 1) // tq))
 
+    def each_copy(r, b, np_, slot, act):
+        """``act`` on the K and the V copy of every live page of block b
+        of row r (np_ pages live) into ``slot``; returns their number."""
+        n = jnp.clip(np_ - b * kvb, 0, kvb)
 
-def _ragged_quant_kernel(seg_ref, rel_ref, bt_ref, ksc_ref, vsc_ref,
-                         q_ref, *refs, bs, sm_scale, pages, nblk):
-    """Int8-page variant of `_ragged_kernel`: k/v refs are int8 pages and
-    the per-page-per-head float32 scales ride the scalar-prefetch path
-    (SMEM) next to the block table, so dequantization happens inline as
-    each page streams into VMEM — no dense float intermediate ever
-    exists.  ksc/vsc are [num_blocks, H_kv] f32; each page-slot's scale
-    is looked up through the same clamped `bt[seg[t], i*pages+j]`
-    indirection its BlockSpec index map uses.
-    """
-    k_refs = refs[:pages]
-    v_refs = refs[pages:2 * pages]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
-    t = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
-    steps = pl.num_programs(2)
-    rel = rel_ref[t]                          # absolute key budget, 0-based
+        def one(p, c):
+            blk = bt_ref[r, b * kvb + p]
+            act(pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, p],
+                                      sems.at[0, slot]))
+            act(pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, p],
+                                      sems.at[1, slot]))
+            return c
+        jax.lax.fori_loop(0, n, one, 0)
+        return n
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def start(r, b, np_, slot):
+        each_copy(r, b, np_, slot, lambda d: d.start())
 
-    for j in range(pages):
-        base = (i * pages + j) * bs
-        blk = bt_ref[seg_ref[t], _page_index(i, pages, j, nblk)]
+    def wait(r, b, np_, slot):
+        n = each_copy(r, b, np_, slot, lambda d: d.wait())
 
-        @pl.when(base <= rel)
-        def _tile(base=base, blk=blk, k_ref=k_refs[j], v_ref=v_refs[j]):
-            q = q_ref[...].astype(jnp.float32) * sm_scale
-            k = k_ref[...].astype(jnp.float32) * ksc_ref[blk, h]  # [bs, D]
-            v = v_ref[...].astype(jnp.float32) * vsc_ref[blk, h]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [G, bs]
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(pos <= rel, s, -jnp.inf)
-            m_prev = m_ref[...]                # [G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)             # [G, bs]
-            alpha = jnp.exp(m_prev - m_new)    # [G, 1]
-            l_ref[...] = alpha * l_ref[...] + \
-                jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+        # pages of the block that were not copied hold what the slot
+        # held before; masked scores give them probability 0, and
+        # 0 * NaN is NaN in the P.V product: zero the V side
+        def zero(p, c):
+            vbuf[slot, p] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
+            return c
+        jax.lax.fori_loop(n, kvb, zero, 0)
 
-    @pl.when(i == steps - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    def pages(buf, sc_ref, r, b, slot, h):
+        """Head h of the slot's int8 pages as float32 [kv, D], each page
+        times its own scale."""
+        return jnp.concatenate([
+            buf[slot, p, h].astype(jnp.float32)
+            * sc_ref[bt_ref[r, jnp.minimum(b * kvb + p, nblk - 1)], h]
+            for p in range(kvb)], axis=0)
+
+    def item(r, j, slot, *, one):
+        """Attention of item (r, j): of a row of one query (G score rows
+        read from q) or of a tile (tq*G rows read from qt).  Returns the
+        slot after its last block."""
+        width = 1 if one else tq
+        M = width * G
+        qs, qe = cu_ref[r], cu_ref[r + 1]
+        n_q = qe - qs
+        np_ = n_pages(r, j)
+        nb = (np_ + kvb - 1) // kvb
+        # the successor: the row's next tile, else the next row's first
+        # (past the last row: an item of no pages, nothing to start)
+        more = j + 1 < tiles(r)[1]
+        rn = jnp.where(more, r, r + 1)
+        last = rn >= rows
+        rn = jnp.minimum(rn, rows - 1)
+        jn = jnp.where(more, j + 1, tiles(rn)[0])
+        npn = jnp.where(last, 0, n_pages(rn, jn))
+
+        if one:
+            rel = jnp.full((M, 1), kvl_ref[r] - 1, jnp.int32)
+        else:
+            tok = j * tq + jax.lax.broadcasted_iota(
+                jnp.int32, (M, 1), 0) // G
+            rel = jnp.where((tok >= qs) & (tok < qe),
+                            kvl_ref[r] - n_q + tok - qs, -1)
+
+        @heads
+        def _init(h):
+            m_ref[h, :M] = jnp.full((M, 1), -jnp.inf, jnp.float32)
+            l_ref[h, :M] = jnp.zeros((M, 1), jnp.float32)
+            acc_ref[h, :M] = jnp.zeros((M, D), jnp.float32)
+
+        @pl.when(nb == 0)
+        def _pass_on():
+            start(rn, 0, npn, slot)
+
+        def block(b, slot):
+            @pl.when(b + 1 < nb)
+            def _next_block():
+                start(r, b + 1, np_, 1 - slot)
+
+            @pl.when(b + 1 == nb)
+            def _next_item():
+                start(rn, 0, npn, 1 - slot)
+
+            wait(r, b, np_, slot)
+            keypos = b * kv + jax.lax.broadcasted_iota(
+                jnp.int32, (1, kv), 1)
+            mask = keypos <= rel                       # [M, kv]
+
+            @heads
+            def _head(h):
+                if one:
+                    q = q_ref[qs, h]                   # [G, D]
+                else:
+                    q = qt_ref[h, pl.ds(j * (tq * G), M), :]
+                if quant:
+                    k = pages(kbuf, ksc_ref, r, b, slot, h)
+                    v = pages(vbuf, vsc_ref, r, b, slot, h)
+                else:
+                    k = kbuf[slot, :, h].reshape(kv, D)
+                    v = vbuf[slot, :, h].reshape(kv, D)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [M, kv]
+                s = jnp.where(mask, s, -jnp.inf)
+                m_prev = m_ref[h, :M]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                # a score row of another row of the launch sees no key
+                m_fin = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                p = jnp.exp(s - m_fin)
+                alpha = jnp.exp(m_prev - m_fin)
+                l_ref[h, :M] = alpha * l_ref[h, :M] + \
+                    jnp.sum(p, axis=1, keepdims=True)
+                acc_ref[h, :M] = acc_ref[h, :M] * alpha + \
+                    jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_ref[h, :M] = m_new
+            return 1 - slot
+
+        slot = jax.lax.fori_loop(0, nb, block, slot)
+
+        @pl.when(nb > 0)
+        def _store():
+            @heads
+            def _head(h):
+                l = l_ref[h, :M]
+                out = acc_ref[h, :M] / jnp.where(l == 0.0, 1.0, l)
+                if one:
+                    o_ref[qs, h] = out.astype(o_ref.dtype)
+                else:
+                    t0 = j * tq
+                    tok = t0 + jax.lax.broadcasted_iota(
+                        jnp.int32, (width, 1, 1), 0)
+                    mine = (tok >= qs) & (tok < qe)
+                    old = o_ref[pl.ds(t0, width), h]
+                    o_ref[pl.ds(t0, width), h] = jnp.where(
+                        mine, out.reshape(width, G, D).astype(o_ref.dtype),
+                        old)
+        return slot
+
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    j00, _ = tiles(0)
+    start(0, 0, n_pages(0, j00), 0)
+
+    def row(r, slot):
+        j0, j1 = tiles(r)
+        one = cu_ref[r + 1] - cu_ref[r] <= 1
+
+        def tile(j, slot):
+            return jax.lax.cond(
+                one,
+                functools.partial(item, one=True),
+                functools.partial(item, one=False), r, j, slot)
+        return jax.lax.fori_loop(j0, j1, tile, slot)
+
+    jax.lax.fori_loop(0, rows, row, 0)
 
 
 def ragged_segments(cu_seqlens, kv_lens, n_tokens):
@@ -341,8 +495,10 @@ def ragged_segments(cu_seqlens, kv_lens, n_tokens):
 
     cu_seqlens [R+1] int32 (row r owns flat tokens cu[r]..cu[r+1]);
     kv_lens [R] int32 (valid KV positions per row AFTER this launch's
-    inserts).  Padding tokens past cu[R] get seg == R and rel == 0 so the
-    kernel computes a finite garbage row the caller discards.
+    inserts).  Padding tokens past cu[R] get seg == R and rel == 0: rope
+    and kv_write send them to the table's null row, and the XLA
+    reference computes a finite garbage row the caller discards (the
+    kernel reads the rows themselves and gives padding no work).
     """
     cu = cu_seqlens.astype(jnp.int32)
     kvl = kv_lens.astype(jnp.int32)
@@ -356,7 +512,8 @@ def ragged_segments(cu_seqlens, kv_lens, n_tokens):
 
 
 def decode_window_segments(active, kv_lens):
-    """Per-iteration (seg, rel) for the device-resident decode window.
+    """Per-iteration (seg, rel) for the device-resident decode window
+    (what rope, kv_write and the XLA reference take).
 
     One window iteration carries exactly one flat token per batch row
     (token s belongs to row s), so the ragged searchsorted collapses to
@@ -368,7 +525,7 @@ def decode_window_segments(active, kv_lens):
 
     active [B] bool (row still decoding), kv_lens [B] int32 (valid KV
     positions AFTER this iteration's insert).  Returns (seg [B], rel [B])
-    int32 for the packed/reference segrel attention entry points.
+    int32.
     """
     B = active.shape[0]
     seg = jnp.where(active, jnp.arange(B, dtype=jnp.int32), jnp.int32(B))
@@ -376,84 +533,86 @@ def decode_window_segments(active, kv_lens):
     return seg, rel
 
 
-def _ragged_launch(q, key_cache, value_cache, block_tables, seg, rel):
+def decode_window_rows(active, kv_lens):
+    """The same iteration as rows for the ragged kernel: (cu [B+1],
+    kv_lens [B]).  Every batch row is a row of one query; a frozen row
+    is a row of no keys, which the kernel gives no work and a zero
+    output."""
+    B = active.shape[0]
+    return (jnp.arange(B + 1, dtype=jnp.int32),
+            jnp.where(active, kv_lens.astype(jnp.int32), 0))
+
+
+def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
+                   kv_lens, scales=()):
     """The raw ragged launch.  Callers must satisfy the packed-operand
-    invariant: int32 scalar operands, table entries in [0, num_blocks),
-    seg values naming real table rows (serving's [B+1]-row table makes
-    the pad sentinel B a valid null row)."""
+    invariant: int32 scalar operands, cu_seqlens [R+1] non-decreasing
+    with cu[R] <= Tq, and every table entry in [0, num_blocks).  The
+    table may carry more rows than kv_lens (serving's null row): they
+    are not read.  ``scales`` is empty over float pages and the two
+    [num_blocks, Hkv] f32 scale pools over int8 pages."""
     Tq, H, D = q.shape
     _, Hkv, bs, _ = key_cache.shape
     G = H // Hkv
-    R, nblk = block_tables.shape
+    rows = kv_lens.shape[0]
+    nblk = block_tables.shape[1]
     sm_scale = 1.0 / (D ** 0.5)
-    pages = _pages_per_step(Tq, Hkv, D, bs, nblk, key_cache.dtype)
+    quant = bool(scales)
+    tq, kvb = _ragged_tiles(Tq, Hkv, G, D, bs, nblk, key_cache.dtype)
+    M = tq * G
 
-    kernel = functools.partial(_ragged_kernel, bs=bs, sm_scale=sm_scale,
-                               pages=pages, nblk=nblk)
-    qr = q.reshape(Tq, Hkv, G, D)
-
-    def _kv_spec(j):
-        return pl.BlockSpec(
-            (None, None, bs, D),
-            lambda t, h, i, sg, rl, bt, _j=j:
-            (bt[sg[t], _page_index(i, pages, _j, nblk)], h, 0, 0))
+    kernel = functools.partial(_ragged_kernel, rows=rows, tq=tq, kvb=kvb,
+                               bs=bs, nblk=nblk, quant=quant)
+    # scaled once, and over float pages rounded to q's dtype, as the
+    # score product's operand always was; the head-major copy is what a
+    # tile of several tokens reads, so its tq*G score rows are contiguous
+    qr = (q.astype(jnp.float32) * sm_scale).astype(
+        jnp.float32 if quant else q.dtype).reshape(Tq, Hkv, G, D)
+    qt = qr.transpose(1, 0, 2, 3).reshape(Hkv, Tq * G, D)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # q, qt and o whole (held twice by the pipeline), the page slots,
+    # and m, l (a lane-padded column each) and acc
+    need = 6 * qr.size * qr.dtype.itemsize \
+        + 4 * kvb * Hkv * bs * D * key_cache.dtype.itemsize \
+        + Hkv * M * (2 * 128 + D) * 4
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,             # seg, rel, block_tables
-            grid=(Tq, Hkv, -(-nblk // pages)),
-            in_specs=[
-                pl.BlockSpec((None, None, G, D),
-                             lambda t, h, i, sg, rl, bt: (t, h, 0, 0)),
-            ] + [_kv_spec(j) for j in range(pages)] * 2,
-            out_specs=pl.BlockSpec((None, None, G, D),
-                                   lambda t, h, i, sg, rl, bt: (t, h, 0, 0)),
+            # cu, kv_lens, block_tables and, over int8 pages, the scales
+            num_scalar_prefetch=3 + len(scales),
+            grid=(1,),
+            in_specs=[vmem, vmem, hbm, hbm],
+            out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
+                pltpu.VMEM((2, kvb, Hkv, bs, D), key_cache.dtype),
+                pltpu.VMEM((2, kvb, Hkv, bs, D), value_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),
+                pltpu.VMEM((Hkv, M, D), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((Tq, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, max(16 << 20, 2 * need))),
         interpret=interpret_mode(),
-        name="ragged_paged_attention",
-    )(seg, rel, block_tables, qr,
-      *([key_cache] * pages), *([value_cache] * pages))
+        name="ragged_paged_attention_q8" if quant
+        else "ragged_paged_attention",
+    )(cu_seqlens, kv_lens, block_tables, *scales, qr, qt, key_cache,
+      value_cache)
     return out.reshape(Tq, H, D)
 
 
-def ragged_paged_attention_segrel(q, key_cache, value_cache, block_tables,
-                                  seg, rel):
-    """Ragged attention with precomputed (seg, rel) per flat token.
-
-    q [Tq, H, D]; caches [num_blocks, H_kv, bs, D]; block_tables [R, nblk]
-    int32; seg [Tq] int32 in [0, R] (R == padding sentinel); rel [Tq]
-    int32.  Returns [Tq, H, D].
-
-    Clamps table entries (blha -1 padding) AND seg (R == pad sentinel) so
-    every index map resolves to a real page; padded/overhung tiles are
-    DMA'd but masked or skipped in compute.  Callers that already pack
-    valid int32 operands on the host should use
-    :func:`ragged_paged_attention_segrel_packed`.
-    """
-    R = block_tables.shape[0]
-    block_tables = jnp.clip(block_tables.astype(jnp.int32), 0,
-                            key_cache.shape[0] - 1)
-    seg = jnp.clip(seg.astype(jnp.int32), 0, R - 1)
-    return _ragged_launch(q, key_cache, value_cache, block_tables, seg,
-                          rel.astype(jnp.int32))
-
-
-def ragged_paged_attention_segrel_packed(q, key_cache, value_cache,
-                                         block_tables, seg, rel):
-    """Ragged launch without the defensive clips/casts, for callers that
+def ragged_paged_attention_packed(q, key_cache, value_cache, block_tables,
+                                  cu_seqlens, kv_lens):
+    """Ragged launch without the defensive clip/casts, for callers that
     guarantee the host-packing invariant (serving.py owns these buffers:
     its table pool is int32 and NULL_BLOCK-padded with valid indices,
-    and its [B+1]-row table makes the seg pad sentinel a real null row,
-    so re-normalizing every launch is pure waste)."""
-    return _ragged_launch(q, key_cache, value_cache, block_tables, seg,
-                          rel)
+    cu and kv_lens come int32 from the step's packing)."""
+    return _ragged_launch(q, key_cache, value_cache, block_tables,
+                          cu_seqlens, kv_lens)
 
 
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,
@@ -463,90 +622,43 @@ def ragged_paged_attention(q, key_cache, value_cache, block_tables,
     q [Tq, H, D] (rows packed back-to-back, tail padding allowed);
     caches [num_blocks, H_kv, bs, D]; block_tables [R, nblk] int32;
     cu_seqlens [R+1] int32; kv_lens [R] int32 (valid KV per row AFTER
-    this launch's inserts — a row's queries sit at its LAST kv_lens
-    positions).  Returns [Tq, H, D]; padding rows are finite garbage.
+    this launch's inserts: a row's queries sit at its LAST kv_lens
+    positions).  Returns [Tq, H, D]; padding tokens read zero.  Clamps
+    table entries (the blha convention pads with -1) to real pages.
     """
-    seg, rel = ragged_segments(cu_seqlens, kv_lens, q.shape[0])
-    return ragged_paged_attention_segrel(
-        q, key_cache, value_cache, block_tables, seg, rel)
+    block_tables = jnp.clip(block_tables.astype(jnp.int32), 0,
+                            key_cache.shape[0] - 1)
+    return _ragged_launch(q, key_cache, value_cache, block_tables,
+                          cu_seqlens.astype(jnp.int32),
+                          kv_lens.astype(jnp.int32))
 
 
-def _ragged_quant_launch(q, key_cache, value_cache, key_scales,
-                         value_scales, block_tables, seg, rel):
-    """The raw int8-page ragged launch; same packed-operand invariant as
-    `_ragged_launch`, plus f32 scales."""
-    Tq, H, D = q.shape
-    _, Hkv, bs, _ = key_cache.shape
-    G = H // Hkv
-    R, nblk = block_tables.shape
-    sm_scale = 1.0 / (D ** 0.5)
-    pages = _pages_per_step(Tq, Hkv, D, bs, nblk, key_cache.dtype)
-
-    kernel = functools.partial(_ragged_quant_kernel, bs=bs,
-                               sm_scale=sm_scale, pages=pages, nblk=nblk)
-    qr = q.reshape(Tq, Hkv, G, D)
-
-    def _kv_spec(j):
-        return pl.BlockSpec(
-            (None, None, bs, D),
-            lambda t, h, i, sg, rl, bt, ks, vs, _j=j:
-            (bt[sg[t], _page_index(i, pages, _j, nblk)], h, 0, 0))
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,     # seg, rel, block_tables, ksc, vsc
-            grid=(Tq, Hkv, -(-nblk // pages)),
-            in_specs=[
-                pl.BlockSpec((None, None, G, D),
-                             lambda t, h, i, sg, rl, bt, ks, vs:
-                             (t, h, 0, 0)),
-            ] + [_kv_spec(j) for j in range(pages)] * 2,
-            out_specs=pl.BlockSpec((None, None, G, D),
-                                   lambda t, h, i, sg, rl, bt, ks, vs:
-                                   (t, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((Tq, Hkv, G, D), q.dtype),
-        interpret=interpret_mode(),
-        name="ragged_paged_attention_q8",
-    )(seg, rel, block_tables, key_scales, value_scales, qr,
-      *([key_cache] * pages), *([value_cache] * pages))
-    return out.reshape(Tq, H, D)
-
-
-def ragged_paged_attention_quant_segrel(q, key_cache, value_cache,
-                                        key_scales, value_scales,
-                                        block_tables, seg, rel):
+def ragged_paged_attention_quant(q, key_cache, value_cache, key_scales,
+                                 value_scales, block_tables, cu_seqlens,
+                                 kv_lens):
     """Ragged attention over int8 KV pages with per-page-per-head scales.
 
     q [Tq, H, D] float; caches [num_blocks, H_kv, bs, D] int8;
     key_scales/value_scales [num_blocks, H_kv] f32 (symmetric:
-    float = int8 * scale); block_tables [R, nblk] int32; seg/rel as in
-    `ragged_paged_attention_segrel`.  Returns [Tq, H, D] in q.dtype.
+    float = int8 * scale); the row layout as in
+    `ragged_paged_attention`.  Returns [Tq, H, D] in q.dtype.
     """
-    R = block_tables.shape[0]
     block_tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                             key_cache.shape[0] - 1)
-    seg = jnp.clip(seg.astype(jnp.int32), 0, R - 1)
-    return _ragged_quant_launch(
-        q, key_cache, value_cache, key_scales.astype(jnp.float32),
-        value_scales.astype(jnp.float32), block_tables, seg,
-        rel.astype(jnp.int32))
+    return _ragged_launch(
+        q, key_cache, value_cache, block_tables,
+        cu_seqlens.astype(jnp.int32), kv_lens.astype(jnp.int32),
+        (key_scales.astype(jnp.float32), value_scales.astype(jnp.float32)))
 
 
-def ragged_paged_attention_quant_segrel_packed(q, key_cache, value_cache,
-                                               key_scales, value_scales,
-                                               block_tables, seg, rel):
-    """Int8-page ragged launch without the defensive clips/casts, for
+def ragged_paged_attention_quant_packed(q, key_cache, value_cache,
+                                        key_scales, value_scales,
+                                        block_tables, cu_seqlens, kv_lens):
+    """Int8-page ragged launch without the defensive clip/casts, for
     callers that guarantee the host-packing invariant (serving.py packs
-    int32 tables/seg/rel and f32 scale pools)."""
-    return _ragged_quant_launch(q, key_cache, value_cache, key_scales,
-                                value_scales, block_tables, seg, rel)
+    int32 tables/cu/kv_lens and f32 scale pools)."""
+    return _ragged_launch(q, key_cache, value_cache, block_tables,
+                          cu_seqlens, kv_lens, (key_scales, value_scales))
 
 
 def ragged_paged_reference_quant_segrel(q, key_cache, value_cache,
@@ -603,9 +715,9 @@ def ragged_paged_reference(q, key_cache, value_cache, block_tables,
 
 
 # Scalar memory of one TensorCore of the TPU v5e, the only device this was
-# measured on.  The ragged launches prefetch seg, rel, the block table and,
-# over int8 pages, both scale pools into it.  A launch whose operands came
-# to 1036 KiB was refused there at compile time ("Used 1.01M of 1.00M
+# measured on.  The ragged launch prefetches cu, kv_lens, the block table
+# and, over int8 pages, both scale pools into it.  A launch whose operands
+# came to 1036 KiB was refused there at compile time ("Used 1.01M of 1.00M
 # smem"), one of 269 KiB compiled, and the refusal listed a [1025, 32] f32
 # operand at 516 KiB: 32-bit words in (8, 128) tiles.  What Mosaic keeps
 # there for itself was not measured; _SMEM_RESERVE stands in for it.
@@ -613,16 +725,17 @@ _SMEM_BYTES = 1 << 20
 _SMEM_RESERVE = 16 << 10
 
 
-def scalar_prefetch_bytes(Tq, table_rows, nblk, num_blocks, Hkv,
+def scalar_prefetch_bytes(table_rows, nblk, num_blocks, Hkv,
                           int8: bool) -> int:
-    """Scalar memory the operands a ragged launch prefetches take: seg
-    and rel [Tq] (counted whole lanes of 128 words), the block table
-    [table_rows, nblk] and, over int8 pages, the two [num_blocks, Hkv]
-    f32 scale pools, each padded to (8, 128) tiles of 32-bit words."""
+    """Scalar memory the operands a ragged launch prefetches take: cu
+    and kv_lens (a word a table row, counted in whole lanes of 128
+    words), the block table [table_rows, nblk] and, over int8 pages, the
+    two [num_blocks, Hkv] f32 scale pools, each padded to (8, 128) tiles
+    of 32-bit words."""
     def tiled(rows, cols):
         return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
 
-    need = 2 * -(-Tq // 128) * 128 * 4 + tiled(table_rows, nblk)
+    need = 2 * -(-table_rows // 128) * 128 * 4 + tiled(table_rows, nblk)
     if int8:
         need += 2 * tiled(num_blocks, Hkv)
     return need
@@ -636,26 +749,29 @@ def ineligible(H, Hkv, D, bs, kv_dtype=jnp.float32,
     refusal there raises (interpret mode enforces no tiling rule, so
     only a compile on the chip can say).  Int8 pages carry a (32, 128)
     minimum tile, float pages (8, 128).  ``launch`` is the caller's
-    largest ragged launch as ``(Tq, table_rows, nblk, num_blocks)``: its
-    prefetched operands must fit the scalar memory, which bounds the
-    int8 page pool (both scale pools ride there) and the block table
-    (None: not checked, and an overrun fails the compile instead).
+    largest ragged launch as ``(table_rows, nblk, num_blocks)``: the
+    ragged kernel copies whole pages, which takes a head_dim that fills
+    the 128 lanes (64 is the decode kernel's alone), and its prefetched
+    operands must fit the scalar memory, which bounds the int8 page
+    pool (both scale pools ride there) and the block table (None: the
+    decode kernel's claim, nothing of a launch checked).
     Tensor-parallel callers pass per-shard head counts: the kernel
     launches inside shard_map and tiles against the shard-local
     shapes."""
     if H < 1 or Hkv < 1 or H % Hkv:
         return f"{H} query heads are not a multiple of {Hkv} kv heads"
-    if D % 128 and D != 64:
-        return f"head_dim {D} is neither 64 nor a multiple of 128"
+    if D % 128 and (D != 64 or launch is not None):
+        return (f"head_dim {D} is not a multiple of 128" if launch
+                else f"head_dim {D} is neither 64 nor a multiple of 128")
     int8 = jnp.dtype(kv_dtype) == jnp.int8
     min_bs = 32 if int8 else 8
     if bs % min_bs:
         return (f"block_size {bs} is not a multiple of {min_bs} "
                 f"({jnp.dtype(kv_dtype).name} pages)")
     if launch is not None:
-        Tq, table_rows, nblk, num_blocks = launch
-        need = scalar_prefetch_bytes(Tq, table_rows, nblk, num_blocks,
-                                     Hkv, int8)
+        table_rows, nblk, num_blocks = launch
+        need = scalar_prefetch_bytes(table_rows, nblk, num_blocks, Hkv,
+                                     int8)
         if need > _SMEM_BYTES - _SMEM_RESERVE:
             return (f"a [{table_rows}, {nblk}] block table "
                     + (f"and the scale pools of {num_blocks} int8 pages "

@@ -68,17 +68,25 @@ def _norms(shape: dict, config: dict) -> float:
 
 
 def _paged(shape: dict, config: dict) -> float:
+    """The ragged kernel on a decode launch: the key carries no row
+    layout, so the model takes `tq` rows of one query, each holding
+    keys in half its table.  What is true of the kernel and modeled:
+    it reads a row's live pages once (all K/V heads of a page in one
+    copy), an item a row and a loop turn a K/V block of `kv_pages`
+    pages, and two slots of a block of K and of V live in VMEM.
+    `q_tile_rows` does not touch a one-query row, so candidates tie on
+    it and the default stands; measured on the v5e (PERF.md, PR 26)
+    the wider block won at every row length tried."""
     tq, kvh, d = shape["tq"], shape["kv_heads"], shape["head_dim"]
     page, nblk = shape["page"], shape["nblk"]
     eb = _bytes(shape.get("dtype", "float32"))
-    p = max(1, config["pages_per_step"])
-    steps = math.ceil(nblk / p)
-    programs = tq * kvh * steps
-    flops = 4.0 * tq * kvh * nblk * page * d
-    traffic = eb * tq * kvh * nblk * page * d * 2 + 4.0 * tq * kvh * d
-    # p page-pairs resident per step plus the f32 accumulator
-    vmem = eb * p * page * d * 2 + 4 * d * 3
-    return _roofline(flops, traffic, programs, programs * p, vmem)
+    kvb = max(1, min(config["kv_pages"], nblk))
+    live = max(1, nblk // 2)
+    blocks = tq * math.ceil(live / kvb)
+    flops = 4.0 * tq * kvh * live * page * d
+    traffic = eb * tq * kvh * live * page * d * 2 + 2.0 * eb * tq * kvh * d
+    vmem = eb * 4 * kvb * kvh * page * d + 4 * kvh * (d + 2 * 128)
+    return _roofline(flops, traffic, tq, blocks, vmem)
 
 
 def _weight_bytes_per_elem(dtype: str) -> float:

@@ -116,13 +116,20 @@ register(TunableKernel(
     describe="fused RMS/LayerNorm rows-per-program block",
 ))
 
-# ragged paged attention: KV pages walked per grid step.  pages_per_step
-# widens the innermost grid dim's work without changing the sequential
-# page order, so accumulation — and therefore bytes — is identical.
+# ragged paged attention: the tile of one row's queries against that
+# row's live pages.  q_tile_rows is the score tile's height (a tile of
+# tq tokens is tq * G rows of one K/V head, so the token tile follows the
+# program's group size); kv_pages is the K/V block, in pages, that one
+# turn of the page walk copies and multiplies (cut where two slots of it
+# would overrun the VMEM set aside; the decode grid kernel walks as many
+# pages a grid step).  Defaults: chosen on the v5e at both benchmark
+# layouts (8 K/V heads of group 4, 4 of group 8), 256-page rows: the
+# widest block won at every row length tried, and q_tile_rows made no
+# difference between 128 and 256 (PERF.md, PR 26).
 register(TunableKernel(
     name="paged_attention",
-    space={"pages_per_step": (1, 2, 4, 8)},
-    defaults={"pages_per_step": 1},
+    space={"q_tile_rows": (64, 128, 256), "kv_pages": (8, 16, 32, 64)},
+    defaults={"q_tile_rows": 128, "kv_pages": 32},
     sweep=(
         {"tq": 8, "kv_heads": 4, "head_dim": 128, "page": 16, "nblk": 128,
          "dtype": "float32"},
@@ -131,7 +138,8 @@ register(TunableKernel(
         {"tq": 8, "kv_heads": 4, "head_dim": 128, "page": 32, "nblk": 256,
          "dtype": "int8"},
     ),
-    describe="ragged paged attention KV pages per grid step",
+    describe="ragged paged attention q tile (score rows) and K/V block "
+             "(pages)",
 ))
 
 # fused dequant matmul: int8/int4 weight blocks stream from HBM and

@@ -102,16 +102,35 @@ def build(name, s):
         tq, kvh, d = s["tq"], s["kv_heads"], s["head_dim"]
         page, nblk = s["page"], s["nblk"]
         R = 4
-        kvdt = dt if s.get("dtype") != "int8" else jnp.int8
-        kc = jnp.asarray(rng.randn(R * nblk, kvh, page, d), kvdt)
-        vc = jnp.asarray(rng.randn(R * nblk, kvh, page, d), kvdt)
+        int8 = s.get("dtype") == "int8"
+        if int8:
+            kc = jnp.asarray(rng.randint(-127, 128, (R * nblk, kvh, page, d)),
+                             jnp.int8)
+            vc = jnp.asarray(rng.randint(-127, 128, (R * nblk, kvh, page, d)),
+                             jnp.int8)
+        else:
+            kc = jnp.asarray(rng.randn(R * nblk, kvh, page, d), dt)
+            vc = jnp.asarray(rng.randn(R * nblk, kvh, page, d), dt)
         bt = jnp.asarray(
-            rng.randint(0, R * nblk, (R + 1, nblk)), jnp.int32)
-        q = jnp.asarray(rng.randn(tq, kvh * 2, d), jnp.float32)
-        seg = jnp.asarray(rng.randint(0, R, (tq,)), jnp.int32)
-        rel = jnp.asarray(rng.randint(page, page * nblk, (tq,)), jnp.int32)
-        fn = jax.jit(lambda *a: pa.ragged_paged_attention_segrel(*a))
-        return fn, (q, kc, vc, bt, seg, rel)
+            rng.permutation(R * nblk).reshape(R, nblk), jnp.int32)
+        q = jnp.asarray(rng.randn(tq, kvh * 2, d),
+                        jnp.float32 if int8 else dt)
+        # a chunk row and three rows of one query, each deep in its table
+        qlens = [max(1, tq - 3), 1, 1, 1][:min(R, tq)]
+        cu = np.full(R + 1, sum(qlens))
+        cu[:len(qlens) + 1] = np.concatenate([[0], np.cumsum(qlens)])
+        kvl = np.zeros(R, np.int64)
+        kvl[:len(qlens)] = rng.randint(page * nblk // 2, page * nblk,
+                                       len(qlens))
+        kvl = np.maximum(kvl, cu[1:] - cu[:-1])
+        cu, kvl = jnp.asarray(cu, jnp.int32), jnp.asarray(kvl, jnp.int32)
+        if int8:
+            ks = jnp.asarray(rng.uniform(0.5, 1.5, (R * nblk, kvh)) / 127,
+                             jnp.float32)
+            fn = jax.jit(lambda *a: pa.ragged_paged_attention_quant(*a))
+            return fn, (q, kc, vc, ks, ks, bt, cu, kvl)
+        fn = jax.jit(lambda *a: pa.ragged_paged_attention(*a))
+        return fn, (q, kc, vc, bt, cu, kvl)
     if name == "quant_matmul":
         from paddle_tpu.ops.pallas import quant_matmul as qm
         rng = np.random.RandomState(0)

@@ -51,18 +51,18 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(pa, "INTERPRET", False)
 
 
-def _shapes(one_chip, model, tq, *, quant=False):
+def _shapes(one_chip, model, tq, *, quant=False, num_blocks=NUM_BLOCKS):
     h, kvh, d = WIDTHS[model]
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     page = jnp.int8 if quant else jnp.bfloat16
-    pool = sds((NUM_BLOCKS, kvh, BLOCK, d), page)
-    scales = (sds((NUM_BLOCKS, kvh), jnp.float32),) * 2 if quant else ()
+    pool = sds((num_blocks, kvh, 32 if quant else BLOCK, d), page)
+    scales = (sds((num_blocks, kvh), jnp.float32),) * 2 if quant else ()
     return (sds((tq, h, d), jnp.bfloat16), pool, pool) + scales + (
-        sds((ROWS + 1, NBLK), jnp.int32), sds((tq,), jnp.int32),
-        sds((tq,), jnp.int32))
+        sds((ROWS + 1, NBLK), jnp.int32), sds((ROWS + 1,), jnp.int32),
+        sds((ROWS,), jnp.int32))
 
 
 def _payloads(text: str) -> list:
@@ -70,12 +70,12 @@ def _payloads(text: str) -> list:
 
 
 def _direct(*a):
-    return pa.ragged_paged_attention_segrel_packed(*a)
+    return pa.ragged_paged_attention_packed(*a)
 
 
 def _inner(*a):
     x = a[0] * 1                # another line ...
-    return pa.ragged_paged_attention_segrel_packed(x, *a[1:])
+    return pa.ragged_paged_attention_packed(x, *a[1:])
 
 
 def _through_a_wrapper(*a):
@@ -83,7 +83,7 @@ def _through_a_wrapper(*a):
         return _inner(*a)       # ... and another depth of the stack
 
 
-@pytest.mark.parametrize("tq", [32, 192])
+@pytest.mark.parametrize("tq", [32, 64, 128, 192])
 @pytest.mark.parametrize("model", sorted(WIDTHS))
 def test_kernel_payload_is_independent_of_the_call_stack(
         one_chip, compiled_kernels, model, tq):
@@ -103,7 +103,7 @@ def test_int8_page_kernel_is_named_and_stack_independent(
     args = _shapes(one_chip, "mistral-7b", 32, quant=True)
 
     def direct(*a):
-        return pa.ragged_paged_attention_quant_segrel_packed(*a)
+        return pa.ragged_paged_attention_quant_packed(*a)
 
     def wrapped(*a):
         return direct(*a)
@@ -131,22 +131,77 @@ def test_entry_points_lower_without_python_frames_and_keep_scopes(
     assert ".py" not in text
 
 
-def test_the_kernel_compiles_for_the_v5e(one_chip, compiled_kernels):
-    """Mistral's 192-token bucket through the chip's own compiler: what
-    it refuses here it refuses on the chip (tiling, fast memory).  The
-    persistent cache is off around it: an entry written for a described
-    chip cannot be read back, and the next run would warn."""
+@pytest.fixture()
+def no_persistent_cache():
+    """An entry written for a described chip cannot be read back, and
+    the next run would warn: the persistent cache is off around a
+    compile."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    try:
-        compiled = jax.jit(_direct).lower(
-            *_shapes(one_chip, "mistral-7b", 192)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("tq", [32, 64, 128, 192])
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_the_kernel_compiles_for_the_v5e(one_chip, compiled_kernels,
+                                         no_persistent_cache, model, tq):
+    """Every bucket of both configurations, at the benchmark's 256-page
+    rows and [33, 256] table, through the chip's own compiler: what it
+    refuses here it refuses on the chip (tiling, fast memory).  The
+    result keeps the shape the trace is read by."""
+    args = _shapes(one_chip, model, tq)
+    compiled = jax.jit(_direct).lower(*args).compile()
     text = compiled.as_text()
+    h, kvh, d = WIDTHS[model]
     assert "ragged_paged_attention" in text
-    assert "custom-call" in text
+    assert re.search(rf"bf16\[{tq},{kvh},{h // kvh},{d}\][^\n]* custom-call\(",
+                     text)
+
+
+def test_the_int8_page_kernel_compiles_for_the_v5e(one_chip,
+                                                   compiled_kernels,
+                                                   no_persistent_cache):
+    """The same body with a dequantising load, at the largest int8 pool
+    the scalar memory takes beside the table."""
+    kvh = WIDTHS["mistral-7b"][1]
+    pool = max(n for n in range(8, 4096, 8) if pa.ineligible(
+        32, kvh, 128, 32, jnp.int8, launch=(ROWS + 1, NBLK, n)) is None)
+    args = _shapes(one_chip, "mistral-7b", 32, quant=True, num_blocks=pool)
+    text = jax.jit(pa.ragged_paged_attention_quant_packed).lower(
+        *args).compile().as_text()
+    assert "ragged_paged_attention_q8" in text
+
+
+def test_prefetched_operands_are_what_the_claim_counts(one_chip,
+                                                       compiled_kernels):
+    """`scalar_prefetch_bytes` counts the operands the launch prefetches:
+    cu, kv_lens and the table (no per-token seg or rel), and both scale
+    pools over int8 pages, in the (8, 128) tiles of 32-bit words scalar
+    memory holds them in."""
+    def prefetched(fn, args):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        eqn, = [e for e in jaxpr.jaxpr.eqns if "pallas" in e.primitive.name]
+        n = eqn.params["grid_mapping"].num_index_operands
+        return [v.aval for v in eqn.invars[:n]]
+
+    def tiled(aval):
+        rows, cols = (1,) * (2 - aval.ndim) + aval.shape
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
+
+    kvh = WIDTHS["mistral-7b"][1]
+    ops = prefetched(_direct, _shapes(one_chip, "mistral-7b", 192))
+    assert [a.shape for a in ops] == [(ROWS + 1,), (ROWS,),
+                                      (ROWS + 1, NBLK)]
+    # a 1-D operand takes whole lanes, not a tile of eight rows
+    assert pa.scalar_prefetch_bytes(ROWS + 1, NBLK, NUM_BLOCKS, kvh, False) \
+        == 2 * 128 * 4 + tiled(ops[2])
+    ops = prefetched(pa.ragged_paged_attention_quant_packed,
+                     _shapes(one_chip, "mistral-7b", 32, quant=True))
+    assert [a.shape for a in ops[3:]] == [(NUM_BLOCKS, kvh)] * 2
+    assert pa.scalar_prefetch_bytes(ROWS + 1, NBLK, NUM_BLOCKS, kvh, True) \
+        == 2 * 128 * 4 + sum(tiled(a) for a in ops[2:])

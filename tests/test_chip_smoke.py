@@ -91,16 +91,16 @@ def test_static_ineligibility_takes_the_reference_visibly(tiny_model,
 
 
 def test_prefetched_operands_must_fit_scalar_memory():
-    def why(num_blocks, rows=4, nblk=8, Tq=32, dtype=jnp.int8):
+    def why(num_blocks, rows=4, nblk=8, dtype=jnp.int8):
         return pa.ineligible(32, 32, 128, 32, dtype,
-                             launch=(Tq, rows, nblk, num_blocks))
+                             launch=(rows, nblk, num_blocks))
 
     # the two pools measured on the v5e: 257 pages compiled, 1025 did not
     assert why(257) is None
     assert "scalar memory" in why(1025)
     # 960 pages fit beside a small block table and not beside a large one
     assert why(960) is None
-    assert "[66, 256] block table" in why(960, rows=66, nblk=256, Tq=512)
+    assert "[66, 256] block table" in why(960, rows=66, nblk=256)
     # float pages prefetch no scale pool, whatever the pool's size
     assert why(1 << 20, dtype=jnp.bfloat16) is None
     assert pa.ineligible(32, 32, 128, 32, jnp.int8) is None    # not checked

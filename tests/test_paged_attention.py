@@ -4,6 +4,8 @@ Kernel numerics are pinned against the dense-gather XLA composition
 (the pre-r5 decode path), reference
 block_multi_head_attention_kernel.cu / block_attn.h semantics.
 """
+import json
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -211,78 +213,162 @@ def test_blha_prefill_varlen_pallas_matches_dense():
 # ---------------------------------------------------------------------------
 # ragged serving kernel: edge geometries vs the XLA gather oracle.
 # Prefill chunks, resumed chunks, decode tokens and k-draft verify rows
-# are all just rows with different query_lens — each geometry must match
-# the dense-gather reference on every valid token.
+# are all just rows with different query_lens: each geometry must match
+# the dense-gather reference on every valid token, at both group sizes
+# the benchmark's configurations have, and padding must read zero.
+# The tile is forced small (8 score rows, 2 pages a block) so that these
+# small cases cross q-tile and K/V-block boundaries as a real launch does.
 # ---------------------------------------------------------------------------
 
-def _ragged_case(rng, query_lens, kv_lens, Tq, *, H=4, Hkv=4, D=64, bs=8,
-                 nblk=4, num_blocks=64, contiguous=True):
+BS, NBLK = 8, 6                          # 48 keys a row at most
+
+
+@pytest.fixture()
+def small_tiles(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_TUNE_FORCE", json.dumps(
+        {"paged_attention": {"q_tile_rows": 8, "kv_pages": 2}}))
+
+
+def _ragged_case(rng, query_lens, kv_lens, Tq, *, H=4, Hkv=4, D=64, bs=BS,
+                 nblk=NBLK, num_blocks=64, contiguous=True, rows=None):
     R = len(query_lens)
+    rows = rows or R
     q = jnp.asarray(rng.randn(Tq, H, D), jnp.float32)
     kc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), jnp.float32)
     vc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), jnp.float32)
     if contiguous:
-        picks = np.arange(R * nblk).reshape(R, nblk)
+        picks = 1 + np.arange(rows * nblk).reshape(rows, nblk)
     else:
-        picks = rng.choice(num_blocks, R * nblk,
-                           replace=False).reshape(R, nblk)
+        picks = 1 + rng.choice(num_blocks - 1, rows * nblk,
+                               replace=False).reshape(rows, nblk)
     bt = jnp.asarray(picks, jnp.int32)
-    cu = jnp.asarray(np.concatenate(
-        [[0], np.cumsum(query_lens)]).astype(np.int32))
-    kvl = jnp.asarray(np.asarray(kv_lens, np.int32))
-    return q, kc, vc, bt, cu, kvl
+    cu = np.full(rows + 1, int(np.sum(query_lens)), np.int32)
+    cu[:R + 1] = np.concatenate([[0], np.cumsum(query_lens)])
+    kvl = np.zeros(rows, np.int32)
+    kvl[:R] = kv_lens
+    return q, kc, vc, bt, jnp.asarray(cu), jnp.asarray(kvl)
 
 
 def _check_ragged(q, kc, vc, bt, cu, kvl, atol=2e-5):
     out = np.asarray(pa.ragged_paged_attention(q, kc, vc, bt, cu, kvl))
     ref = np.asarray(pa.ragged_paged_reference(q, kc, vc, bt, cu, kvl))
     total = int(np.asarray(cu)[-1])
-    assert np.isfinite(out).all()        # padding rows: finite garbage
-    np.testing.assert_allclose(out[:total], ref[:total], atol=atol)
+    # a query of a row of no keys (a frozen row of the decode window) is
+    # NaN in the oracle's softmax; the kernel gives it no work
+    keyed = np.isfinite(ref[:total]).all(axis=(1, 2))
+    np.testing.assert_allclose(out[:total][keyed], ref[:total][keyed],
+                               atol=atol)
+    assert not out[:total][~keyed].any()
+    assert not out[total:].any()         # padding: no work, zeros
     return out
 
 
-def test_ragged_all_decode_rows_matches_decode_oracle():
-    """Pure decode geometry: every query_len is 1.  Must match the
-    gather oracle AND the dedicated decode oracle at each row's absolute
-    position (the row's query sits at kv_len - 1)."""
+# name: (query_lens, kv_lens, Tq, keyword arguments of _ragged_case)
+GEOMETRIES = {
+    # pure decode: every query_len is 1
+    "all_decode": ([1] * 4, [1, 9, 17, 48], 4, dict(contiguous=False)),
+    # a fresh chunk of 7 tokens: with tiles of 2 (G=4) or 1 (G=8) tokens
+    # it crosses three q-tile boundaries, and starts inside a tile
+    "chunk_crosses_q_tiles": ([1, 7], [5, 7], 8, {}),
+    # a resumed chunk of 5 behind 40 cached tokens: its tiles walk three
+    # K/V blocks, the last of them partly
+    "resumed_behind_prefix": ([5, 1], [45, 3], 8, {}),
+    # a verify row of k + 1 = 4 drafts next to decode rows
+    "verify_row": ([1, 4, 1], [12, 21, 30], 8, dict(contiguous=False)),
+    # one prefill owning every flat token and every page: cache full
+    "one_row_owns_bucket": ([48], [48], 48, dict(num_blocks=8)),
+    # 7 real tokens, 9 of padding, and table rows no row uses
+    "empty_padded_tail": ([3, 4], [19, 11], 16, dict(rows=4)),
+    # kv_len on a page boundary, one past it, and at nblk * bs
+    "page_boundaries": ([1, 1, 2, 1], [8, 9, 16, 48], 8, {}),
+    # scattered pages, all four row kinds in one launch: prefill chunk
+    # (5), decode (1), verify (4), resumed chunk (3) at a deep offset
+    "noncontiguous_mixed": ([5, 1, 4, 3], [5, 9, 17, 26], 16,
+                            dict(contiguous=False)),
+    # a row of no queries between live rows, and a row of no keys
+    "empty_rows_between": ([2, 0, 1, 1], [10, 0, 0, 20], 4, {}),
+}
+
+
+@pytest.mark.parametrize("G", [4, 8])
+@pytest.mark.parametrize("name", sorted(GEOMETRIES))
+def test_ragged_kernel_matches_reference(small_tiles, name, G):
+    qlens, kvl, Tq, kw = GEOMETRIES[name]
     rng = np.random.RandomState(20)
-    R = 4
-    kvl = rng.randint(1, 4 * 8 + 1, R)
-    q, kc, vc, bt, cu, kvl_j = _ragged_case(rng, [1] * R, kvl, Tq=R,
-                                            contiguous=False)
-    out = _check_ragged(q, kc, vc, bt, cu, kvl_j)
-    dec = pa.paged_decode_reference(q, kc, vc, bt,
-                                    jnp.asarray(kvl, jnp.int32))
-    np.testing.assert_allclose(out, np.asarray(dec), atol=2e-5)
+    args = _ragged_case(rng, qlens, kvl, Tq, H=2 * G, Hkv=2, **kw)
+    out = _check_ragged(*args)
+    if name == "all_decode":
+        # also the dedicated decode oracle, at each row's own position
+        q, kc, vc, bt, _, kvl_j = args
+        dec = pa.paged_decode_reference(q, kc, vc, bt, kvl_j)
+        np.testing.assert_allclose(out, np.asarray(dec), atol=2e-5)
 
 
-def test_ragged_one_row_owns_whole_bucket():
-    """A single sequence's prefill filling every flat token (and every
-    KV page) — the pure varlen-prefill corner, cache exactly full."""
-    rng = np.random.RandomState(21)
-    Tq = 24                              # == nblk * bs == kv_len
-    q, kc, vc, bt, cu, kvl = _ragged_case(rng, [Tq], [Tq], Tq=Tq,
-                                          bs=8, nblk=3)
-    _check_ragged(q, kc, vc, bt, cu, kvl)
+@pytest.mark.parametrize("tiles", [(128, 8), (16, 1), (8, 4)])
+def test_ragged_kernel_at_other_tiles(monkeypatch, tiles):
+    """The mixed launch at the built-in tile (one item a row: the whole
+    case fits a tile and a block) and at two more widths."""
+    monkeypatch.setenv("PADDLE_TPU_TUNE_FORCE", json.dumps(
+        {"paged_attention": {"q_tile_rows": tiles[0],
+                             "kv_pages": tiles[1]}}))
+    qlens, kvl, Tq, kw = GEOMETRIES["noncontiguous_mixed"]
+    _check_ragged(*_ragged_case(np.random.RandomState(23), qlens, kvl, Tq,
+                                H=8, Hkv=4, **kw))
 
 
-def test_ragged_empty_tail_padding_rows():
-    """Real tokens in the front, a long padded tail (the bucket the
-    engine actually launches): resumed chunk at a KV offset + a verify-
-    shaped row, padding never NaN-poisons the valid rows."""
-    rng = np.random.RandomState(22)
-    q, kc, vc, bt, cu, kvl = _ragged_case(
-        rng, [3, 4], [19, 11], Tq=16)    # 7 real tokens, 9 padding
-    _check_ragged(q, kc, vc, bt, cu, kvl)
+@pytest.mark.parametrize("G", [4, 8])
+def test_ragged_kernel_reads_no_page_past_a_rows_length(small_tiles, G):
+    """The mechanism: no copy and no arithmetic for a page at or past a
+    row's ceil(kv_len / bs), for a padded token or for the null page.
+    Every pool page that no row reaches within its kv_len is filled with
+    NaN, page 0 (the null page the table's spare entries name) too: the
+    live rows' output is finite and equal to the clean run's, the padded
+    rows' output is zero."""
+    rng = np.random.RandomState(24)
+    qlens, kvl = [5, 1, 4, 1], [13, 9, 48, 0]
+    q, kc, vc, bt, cu, kvl_j = _ragged_case(
+        rng, qlens, kvl, 16, H=2 * G, Hkv=2, contiguous=False, rows=6)
+    btn = np.asarray(bt).copy()
+    reached = set()
+    for r, n in enumerate(kvl):
+        live = -(-n // BS)
+        reached.update(btn[r, :live].tolist())
+        btn[r, live:] = 0                # as the engine pads: null page
+    dead = np.array(sorted(set(range(kc.shape[0])) - reached))
+    assert 0 in dead and len(dead) > 40
+    clean = np.asarray(pa.ragged_paged_attention(
+        q, kc, vc, jnp.asarray(btn), cu, kvl_j))
+    kcn = kc.at[dead].set(jnp.nan)
+    vcn = vc.at[dead].set(jnp.nan)
+    out = np.asarray(pa.ragged_paged_attention(
+        q, kcn, vcn, jnp.asarray(btn), cu, kvl_j))
+    total = sum(qlens)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[:total - 1], clean[:total - 1])
+    assert not out[total - 1:].any()     # the row of no keys, and padding
 
 
-def test_ragged_noncontiguous_block_table_gqa():
-    """Scattered physical pages (allocator churn order) under GQA, with
-    all four row kinds in one launch: prefill chunk (5), decode (1),
-    verify row (4 = k+1 drafts), resumed chunk (3) at a deep offset."""
-    rng = np.random.RandomState(23)
-    q, kc, vc, bt, cu, kvl = _ragged_case(
-        rng, [5, 1, 4, 3], [5, 9, 17, 26], Tq=16, H=8, Hkv=4,
-        contiguous=False)
-    _check_ragged(q, kc, vc, bt, cu, kvl)
+@pytest.mark.parametrize("G", [4, 8])
+def test_ragged_int8_page_kernel_matches_reference(small_tiles, G):
+    """Int8 pages take the same body with a dequantising load: all four
+    row kinds against the fake-quant oracle (float = int8 * the page's
+    scale for the head), pages of 32 keys as the int8 tile wants."""
+    rng = np.random.RandomState(25)
+    Hkv, D, bs, nblk, nb = 2, 64, 32, 3, 16
+    qlens, kvl, Tq = [5, 1, 4, 3], [5, 40, 90, 96], 16
+    q = jnp.asarray(rng.randn(Tq, Hkv * G, D), jnp.float32)
+    kc = jnp.asarray(rng.randint(-127, 128, (nb, Hkv, bs, D)), jnp.int8)
+    vc = jnp.asarray(rng.randint(-127, 128, (nb, Hkv, bs, D)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.5, 1.5, (nb, Hkv)) / 127, jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.5, 1.5, (nb, Hkv)) / 127, jnp.float32)
+    bt = jnp.asarray(1 + rng.choice(nb - 1, 4 * nblk, replace=False)
+                     .reshape(4, nblk), jnp.int32)
+    cu = jnp.asarray(np.concatenate([[0], np.cumsum(qlens)]), jnp.int32)
+    kvl = jnp.asarray(kvl, jnp.int32)
+    out = np.asarray(pa.ragged_paged_attention_quant(
+        q, kc, vc, ks, vs, bt, cu, kvl))
+    seg, rel = pa.ragged_segments(cu, kvl, Tq)
+    ref = np.asarray(pa.ragged_paged_reference_quant_segrel(
+        q, kc, vc, ks, vs, bt, seg, rel))
+    np.testing.assert_allclose(out[:13], ref[:13], atol=2e-5)
+    assert not out[13:].any()

@@ -274,23 +274,24 @@ def _max_err(out, ref, live):
 ])
 def test_ragged_kernel_on_tpu(H, Hkv, dtype, tol):
     """The serving kernel at head width 128, block size 16: G = H/Hkv
-    rows per program (one for MHA), compared with the dense-gather
+    score rows a query (one for MHA), compared with the dense-gather
     reference computed in float32 at highest matmul precision."""
     from paddle_tpu.ops.pallas import paged_attention as PA
 
     D, bs, nblk = 128, 16, 8
     rng = np.random.RandomState(5)
-    q, bt, seg, rel, num_blocks, live = ragged_case(
+    q, bt, cu, kvl, num_blocks, live = ragged_case(
         rng, H, D, bs, nblk, dtype)
     kc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
     vc = jnp.asarray(rng.randn(num_blocks, Hkv, bs, D), dtype)
-    assert PA.ineligible(H, Hkv, D, bs, dtype) is None
-    out = jax.jit(PA.ragged_paged_attention_segrel_packed)(
-        q, kc, vc, bt, seg, rel)
+    assert PA.ineligible(H, Hkv, D, bs, dtype, launch=(
+        bt.shape[0], nblk, num_blocks)) is None
+    out = jax.jit(PA.ragged_paged_attention_packed)(
+        q, kc, vc, bt, cu, kvl)
     with jax.default_matmul_precision("highest"):
-        ref = PA.ragged_paged_reference_segrel(
+        ref = PA.ragged_paged_reference(
             q.astype(jnp.float32), kc.astype(jnp.float32),
-            vc.astype(jnp.float32), bt, seg, rel)
+            vc.astype(jnp.float32), bt, cu, kvl)
     err = _max_err(out, ref, live)
     print(f"ragged H={H} Hkv={Hkv} {jnp.dtype(dtype).name}: "
           f"max abs err {err:.3e}")
@@ -305,8 +306,9 @@ def _int8_page_kernel_err(H, Hkv, pool=None):
 
     D, bs, nblk = 128, 32, 4
     rng = np.random.RandomState(6)
-    q, bt, seg, rel, num_blocks, live = ragged_case(
+    q, bt, cu, kvl, num_blocks, live = ragged_case(
         rng, H, D, bs, nblk, jnp.bfloat16)
+    seg, rel = PA.ragged_segments(cu, kvl, q.shape[0])
     num_blocks = pool or num_blocks
     kc = jnp.asarray(rng.randint(-127, 128, (num_blocks, Hkv, bs, D),
                                  dtype=np.int8))
@@ -317,9 +319,9 @@ def _int8_page_kernel_err(H, Hkv, pool=None):
     vs = jnp.asarray(rng.uniform(0.5, 1.5, (num_blocks, Hkv)) / 127.0,
                      jnp.float32)
     assert PA.ineligible(H, Hkv, D, bs, jnp.int8, launch=(
-        q.shape[0], bt.shape[0], nblk, num_blocks)) is None
-    out = jax.jit(PA.ragged_paged_attention_quant_segrel_packed)(
-        q, kc, vc, ks, vs, bt, seg, rel)
+        bt.shape[0], nblk, num_blocks)) is None
+    out = jax.jit(PA.ragged_paged_attention_quant_packed)(
+        q, kc, vc, ks, vs, bt, cu, kvl)
     with jax.default_matmul_precision("highest"):
         ref = PA.ragged_paged_reference_quant_segrel(
             q.astype(jnp.float32), kc, vc, ks, vs, bt, seg, rel)
@@ -345,7 +347,7 @@ def test_int8_page_kernel_at_the_scalar_memory_claim():
     withdrawn eight pages later."""
     from paddle_tpu.ops.pallas import paged_attention as PA
 
-    launch = (32, 4, 4)                 # ragged_case: Tq, table rows, nblk
+    launch = (4, 4)                     # ragged_case: table rows, nblk
 
     def why(n):
         return PA.ineligible(32, 32, 128, 32, jnp.int8, launch=(*launch, n))

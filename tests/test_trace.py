@@ -425,6 +425,10 @@ def test_summary_counts_real_and_padded_tokens(model):
     dl = [x["args"] for x in sp if x["name"] == "engine.device_launch"]
     assert sum(a["tokens"] for a in dl) == s["tokens_real"]
     assert sum(a["bucket"] for a in dl) == s["tokens_padded"]
+    # the pages the launches' rows hold keys in, of bucket * nblk slots
+    assert 0 < sum(a["kv_pages"] for a in dl) == s["kv_pages_live"]
+    assert all(a["rows"] <= a["kv_pages"] <= a["bucket"] * eng.nblk
+               for a in dl)
     assert sum(a["logit_rows"] for a in dl) == 8   # every token sampled
     built = [x for x in sp if x["name"] == "engine.program_built"]
     assert len(built) == sum(eng.compile_counts.values()) == 2
