@@ -11,7 +11,11 @@ compiled module's text), and ``scoped_events`` joins the three: the
 module execution that contains an operation names its program, the
 program's map gives the scope ("jit(ragged_step_t192)/layers/while/body/
 qkv/dot_general" is ``qkv``), and the ``engine.launch`` annotation on
-the host plane (same clock) says which launch it ran for.
+the host plane (same clock) says which launch it ran for.  Which scopes
+and kernels a program has is its architecture's to say: ``arch`` below
+is the module ``spec.load_shapes`` finds by the configuration's
+``reference`` (``ctx["arch"]``), and the engine's map is the one the
+engine that served gave before it went (``ctx["program_scopes"]``).
 
 Host side: every Tracer span and request instant carries ``step``, the
 id of the launch it belongs to; the joins below are by that id and never
@@ -23,19 +27,12 @@ do not raise."""
 from __future__ import annotations
 
 import functools
-import gc
-import json
 import os
 import re
 import statistics
 
 from . import spans as S, spec, xplane as X
 
-SCOPES = ("embed", "norm", "qkv", "rope", "kv_write", "attn", "o_proj",
-          "mlp", "head", "sample")
-LOOP = "layers"                     # the scan over layers, its own work
-MATMUL_SCOPES = ("qkv", "o_proj", "mlp", "head")
-KERNEL = "ragged_paged_attention"   # the name= of the pallas_call(s)
 POOL_COPY = "kvpool_copy"
 UNSCOPED = "unscoped"
 
@@ -46,34 +43,29 @@ _SHAPE = re.compile(r"[a-z]+[0-9]*\[([0-9,]*)\]")
 # device side
 # ---------------------------------------------------------------------------
 
-def scope_of(op_name: str):
-    """The innermost of the program's scope names on an operation's
-    path; ``layers`` for what the scan runs outside every phase; None
-    for a path that holds none of them."""
+def scope_of(op_name: str, arch):
+    """The innermost of the program's scope names (``arch.SCOPES``) on
+    an operation's path; ``arch.LOOP`` for what the scan over layers
+    runs outside every phase; None for a path that holds none of them."""
     parts = op_name.split("/")
     for p in reversed(parts):
-        if p in SCOPES:
+        if p in arch.SCOPES:
             return p
-    return LOOP if LOOP in parts else None
+    return arch.LOOP if arch.LOOP in parts else None
 
 
-def is_kernel_name(name: str) -> bool:
-    """An instruction named after the kernel: "ragged_paged_attention.3",
-    "ragged_paged_attention_q8.1"."""
+def is_kernel_name(name: str, arch) -> bool:
+    """An instruction named after one of the architecture's kernels
+    (``arch.KERNELS``): "ragged_paged_attention.3",
+    "%ragged_paged_attention_q8.1"."""
     base = re.sub(r"[.\-_]?\d+$", "", name.lstrip("%"))
-    return base in (KERNEL, KERNEL + "_q8")
+    return base in arch.KERNELS
 
 
-def pool_shapes(cfg: dict) -> set:
-    """Dimension lists of the K/V pool and of one layer of it, as a
-    result shape prints them: [L, num_blocks, kvh, block, d] and
-    [num_blocks, kvh, block, d] (also with a leading 1)."""
-    s = cfg["serving"]
-    nh, kvh = int(cfg["num_attention_heads"]), int(cfg["num_key_value_heads"])
-    d = int(cfg["hidden_size"]) // nh
-    one = [int(s["num_blocks"]), kvh, int(s["block_size"]), d]
-    return {tuple([int(cfg["num_hidden_layers"])] + one), tuple(one),
-            tuple([1] + one)}
+def kernel_ns(events_with_self: list, arch) -> int:
+    """Traced time of the architecture's kernels, found by name."""
+    return sum(e["self_ns"] for e in events_with_self
+               if is_kernel_name(e["name"], arch))
 
 
 def is_pool_shaped(e: dict, shapes: set) -> bool:
@@ -83,44 +75,53 @@ def is_pool_shaped(e: dict, shapes: set) -> bool:
                for m in _SHAPE.findall(e.get("shape") or ""))
 
 
-def classify(e: dict, shapes: set) -> str:
-    """One of SCOPES, ``layers``, ``kvpool_copy`` or ``unscoped``: where
-    this operation's self time is counted.  A pool-shaped result outside
-    ``kv_write`` and ``attn`` is a copy of the pool that the program did
-    not ask for, whatever scope XLA left on it."""
+def classify(e: dict, shapes: set, arch) -> str:
+    """One of the program's scopes, its loop, ``kvpool_copy`` or
+    ``unscoped``: where this operation's self time is counted.  A
+    pool-shaped result (``shapes``: ``arch.pool_shapes(cfg)``) outside
+    the pool's own writers (``arch.POOL_SCOPES``: the page writes and the
+    kernel) is a copy of the pool that the program did not ask for,
+    whatever scope XLA left on it."""
     sc = e.get("scope")
-    if sc not in ("kv_write", "attn") and is_pool_shaped(e, shapes):
+    if sc not in arch.POOL_SCOPES and is_pool_shaped(e, shapes):
         return POOL_COPY
     return sc or UNSCOPED
 
 
-def by_class(events: list, cfg: dict) -> dict:
+def by_class(events: list, cfg: dict, arch) -> dict:
     """Self nanoseconds by class; the values add up to busy time."""
-    shapes = pool_shapes(cfg)
+    shapes = arch.pool_shapes(cfg)
     out: dict = {}
     for e in events:
-        k = classify(e, shapes)
+        k = classify(e, shapes, arch)
         out[k] = out.get(k, 0) + e["self_ns"]
     return out
 
 
-def is_matmul(e: dict, shapes: set) -> bool:
+def is_matmul(e: dict, shapes: set, arch) -> bool:
     """Counted as matrix-product time: an operation that holds a dot,
     whatever its scope (XLA fuses across scopes); everything else under
-    a matmul scope; and the scan's own operations (``layers``), which
+    a matmul scope (``arch.MATMUL_SCOPES``); and the scan's own
+    operations (``arch.LOOP``), which
     are the per-layer slices and layout copies of the stacked weights
     that only the products read: XLA moves the q, k, v and o matrices
     into fast memory with operations of their own, and the product that
     follows then shows a bandwidth no memory has.  Left out, the time
     would hold part of the work and the roofline share pass 100."""
-    k = classify(e, shapes)
+    k = classify(e, shapes, arch)
     if k == POOL_COPY:
         return False
-    return bool(e.get("has_dot")) or k in MATMUL_SCOPES or k == LOOP
+    return bool(e.get("has_dot")) or k in arch.MATMUL_SCOPES \
+        or k == arch.LOOP
+
+
+def matmul_ns(events: list, cfg: dict, arch) -> int:
+    """Self nanoseconds of the events ``is_matmul`` takes."""
+    shapes = arch.pool_shapes(cfg)
+    return sum(e["self_ns"] for e in events if is_matmul(e, shapes, arch))
 
 
 _MODULE = re.compile(r"^jit_([A-Za-z_]\w*?)\(\d+\)$")
-_RAGGED = re.compile(r"^ragged_step_t(\d+)$")
 
 
 def program_of(module_event_name: str):
@@ -161,27 +162,8 @@ def _read(trace_dir: str, plane_name: str) -> tuple:
     return ops, modules, launches
 
 
-@functools.lru_cache(maxsize=2)
-def _program_map(cfg_json: str, buckets: tuple) -> dict:
-    """``LLMEngine.program_scopes`` for these buckets, asked of an
-    engine built for the purpose (the one that served is gone by the
-    time a reader runs): the same configuration gives the same modules,
-    and the compilation cache the same executables.  {} where the
-    program has no such method."""
-    from . import server            # the one file that imports the program
-    cfg = json.loads(cfg_json)
-    model = server.build_model(cfg, 0, {})
-    engine = server.build_engine(cfg, model, {})
-    try:
-        ask = getattr(engine, "program_scopes", None)
-        return ask(list(buckets)) if ask is not None else {}
-    finally:
-        del engine, model
-        gc.collect()
-
-
 def annotate(ops: list, modules: list, launches: list,
-             program_map: dict) -> list:
+             program_map: dict, arch) -> list:
     """Give each operation the program it ran in (the module execution
     that contains it), from that program's map its ``scope`` and
     ``has_dot``, and the ``step`` of the launch it ran for: the last
@@ -201,7 +183,7 @@ def annotate(ops: list, modules: list, launches: list,
         info = program_map.get(prog, {}).get(e["name"].lstrip("%"), {})
         out.append(dict(e, program=prog,
                         op_name=info.get("op_name", ""),
-                        scope=scope_of(info.get("op_name", "")),
+                        scope=scope_of(info.get("op_name", ""), arch),
                         has_dot=bool(info.get("dot")),
                         step=launches[li]["step"] if li >= 0 else None))
     return out
@@ -214,48 +196,19 @@ def trace_dir() -> str:
 def scoped_events(ctx) -> list:
     """The traced window's device operations, clipped to it, each with
     ``self_ns``, ``program``, ``scope``, ``has_dot`` and ``step``.
-    Empty where there is no trace, or the program names neither its
-    jitted programs nor its phases (the modules are then ``jit_run``).
-    Worked out once for a run's readers."""
-    tr = ctx.get("trace")
-    if tr is None:
+    Empty where there is no trace, or the engine gave no map of its
+    programs (``ctx["program_scopes"]``: a program that names neither
+    its jitted programs nor its phases has none to give).  Worked out
+    once for a run's readers and kept in ``ctx``."""
+    tr, pmap = ctx.get("trace"), ctx.get("program_scopes")
+    if tr is None or not pmap:
         return []
-    return _scoped(trace_dir(), tr["plane"], tuple(tr["window"]),
-                   json.dumps(ctx["cfg"], sort_keys=True))
-
-
-@functools.lru_cache(maxsize=2)
-def _scoped(trace_dir: str, plane: str, window: tuple,
-            cfg_json: str) -> list:
-    ops, modules, launches = _read(trace_dir, plane)
-    buckets = sorted({int(m.group(1)) for m in (
-        _RAGGED.match(mod["program"] or "") for mod in modules) if m})
-    pmap = _program_map(cfg_json, tuple(buckets)) if buckets else {}
-    if not pmap:
-        return []
-    evs = annotate(X.self_times(X.clip(ops, *window)), modules, launches,
-                   pmap)
-    return evs if join_holds(evs) else []
-
-
-UNJOINED_MOST = 0.05    # of busy time; the chip runs read 0.0005 to 0.0014
-
-
-def join_holds(evs: list) -> bool:
-    """The map comes from an engine built like the one that was timed,
-    not from that one: where its instruction names do not fit the
-    trace's (another compile, a cache that answered otherwise), most
-    operations find no entry.  Then no share by scope is worth printing:
-    say so and give the readers nothing."""
-    busy = sum(e["self_ns"] for e in evs)
-    lost = sum(e["self_ns"] for e in evs if not e["op_name"])
-    if busy > 0 and lost > UNJOINED_MOST * busy:
-        print(f"[bench] scopes: {100.0 * lost / busy:.1f}% of busy time is "
-              "in operations the rebuilt engine's program_scopes() does "
-              "not name; its map does not fit this trace, no metric by "
-              "scope is reported", flush=True)
-        return False
-    return True
+    if "_scoped_events" not in ctx:
+        ops, modules, launches = _read(trace_dir(), tr["plane"])
+        ctx["_scoped_events"] = annotate(
+            X.self_times(X.clip(ops, *tr["window"])), modules, launches,
+            pmap, ctx["arch"])
+    return ctx["_scoped_events"]
 
 
 def launch_annotations(ctx) -> list:

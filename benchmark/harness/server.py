@@ -1,9 +1,9 @@
 """The system under test, built as ``paddle_tpu/inference/frontend/
 __main__.py`` builds it: the same calls as ``main`` and ``_build_engine``,
-with the ``LlamaConfig`` taken from the configuration's file (the CLI
-knows only its three presets) and the weights handed in from the
+with the model that the configuration's architecture names
+(``builders/<reference>.py``) and the weights handed in from the
 benchmark's own draw.  This is the only harness file that imports the
-program."""
+program; the builders are the only other benchmark files that do."""
 from __future__ import annotations
 
 import time
@@ -28,58 +28,28 @@ def start_jax():
     return cache_dir, watch, device, time.monotonic() - t
 
 
-def llama_config(cfg: dict):
-    from paddle_tpu.models.llama import LlamaConfig
-    return LlamaConfig(
-        vocab_size=int(cfg["vocab_size"]),
-        hidden_size=int(cfg["hidden_size"]),
-        intermediate_size=int(cfg["intermediate_size"]),
-        num_hidden_layers=int(cfg["num_hidden_layers"]),
-        num_attention_heads=int(cfg["num_attention_heads"]),
-        num_key_value_heads=int(cfg["num_key_value_heads"]),
-        max_position_embeddings=int(cfg["serving"]["max_model_len"]),
-        rms_norm_eps=float(cfg["rms_norm_eps"]),
-        rope_theta=float(cfg["rope_theta"]),
-        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)))
-
-
 def build_model(cfg: dict, seed: int, split: dict):
-    """``LlamaForCausalLM(cfg)`` in the served type, then every weight
-    replaced by the benchmark's draw from the seed (one jitted call)."""
+    """The model of the architecture that the configuration's file names
+    (``"reference"``): its builder constructs it in the served type, then
+    every weight is replaced by the benchmark's draw from the seed (one
+    jitted call over the leaves its shapes file lists)."""
     import jax
+    import jax.numpy as jnp
 
-    import paddle_tpu
-    from paddle_tpu.models.llama import LlamaForCausalLM
+    from . import spec, weights as W
 
-    from . import weights as W
-
+    shapes = spec.load_shapes(cfg["reference"])
+    builder = spec.load_builder(cfg["reference"])
     t = time.monotonic()
-    paddle_tpu.seed(0)
-    model = LlamaForCausalLM(llama_config(cfg))
-    dtype = cfg.get("dtype", "bfloat16")
-    if dtype != "float32":
-        model.to(dtype=dtype)
+    model = builder.construct(cfg)
     jax.block_until_ready([p._data for p in model.parameters()])
     split["model_build_s"] = time.monotonic() - t
 
     t = time.monotonic()
-    import jax.numpy as jnp
-    made = W.make_all(cfg, seed, jnp.dtype(dtype))
+    made = W.make_all(shapes.leaves(cfg), seed,
+                      jnp.dtype(cfg.get("dtype", "bfloat16")))
     jax.block_until_ready(made)
-    m = model.model
-    m.embed_tokens.weight._data = made["top"]["embed"]
-    m.norm.weight._data = made["top"]["norm_f"]
-    model.lm_head.weight._data = made["top"]["head"]
-    for lyr, w in zip(m.layers, made["layers"]):
-        lyr.input_layernorm.weight._data = w["ln1"]
-        lyr.self_attn.q_proj.weight._data = w["wq"]
-        lyr.self_attn.k_proj.weight._data = w["wk"]
-        lyr.self_attn.v_proj.weight._data = w["wv"]
-        lyr.self_attn.o_proj.weight._data = w["wo"]
-        lyr.post_attention_layernorm.weight._data = w["ln2"]
-        lyr.mlp.gate_proj.weight._data = w["gate"]
-        lyr.mlp.up_proj.weight._data = w["up"]
-        lyr.mlp.down_proj.weight._data = w["down"]
+    builder.place(model, made)
     split["weights_s"] = time.monotonic() - t
     return model
 

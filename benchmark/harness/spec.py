@@ -1,7 +1,25 @@
 """Finding things by name.  ``BENCHMARK.json`` names cells, their
 configurations, traffic mixes and metrics; each lives in a file of its
 own under ``benchmark/``, so a later PR adds files and entries and edits
-nothing that is there."""
+nothing that is there.  Which name finds which file:
+
+=====================================  ================================
+a cell's ``config``                    the ``file`` of that entry of
+                                       ``configs`` (``configs/*.json``)
+a cell's ``traffic``                   ``traffic/<traffic>.json``
+the traffic file's ``kind``            ``harness/kinds/<kind>.py``
+                                       (``loadgen.load_kind``)
+a metric's ``name``                    ``metrics/<name>.py``
+the configuration file's               ``references/<reference>.py``, the
+``reference``: its architecture        plain reference; ``shapes/
+                                       <reference>.py``, its leaves,
+                                       shapes, names and counts; and
+                                       ``builders/<reference>.py``, the
+                                       program's model of it
+=====================================  ================================
+
+The first two kinds of file that an architecture's name finds import
+nothing of the program; the builder is the one that does."""
 from __future__ import annotations
 
 import importlib.util
@@ -69,6 +87,19 @@ def load_reference(name: str):
     """A configuration's plain reference, found by the name in its file:
     ``references/<name>.py``."""
     return _load_module("references", name, "plain reference")
+
+
+def load_shapes(name: str):
+    """An architecture's leaves, shapes, names and counts, program-free:
+    ``shapes/<name>.py`` (``shapes/llama_dense.py`` says what such a
+    file states)."""
+    return _load_module("shapes", name, "shapes file")
+
+
+def load_builder(name: str):
+    """The program's model of an architecture: ``builders/<name>.py``
+    with ``construct(cfg)`` and ``place(model, made)``."""
+    return _load_module("builders", name, "builder")
 
 
 def metrics_for(bench: dict, group: str, cell: str) -> list:

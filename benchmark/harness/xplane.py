@@ -184,27 +184,6 @@ def by_label(events_with_self: list) -> dict:
     return out
 
 
-def is_attention_kernel(e: dict, kv_heads: int, group: int,
-                        head_dim: int) -> bool:
-    """The ragged paged attention kernel, by category and operand shape:
-    a custom call whose result is [tokens, kv_heads, group, head_dim]."""
-    if (e.get("category") or "").lower() != "custom-call":
-        return False
-    m = re.match(r"[a-z]+[0-9]*\[([0-9,]*)\]", e.get("shape") or "")
-    if not m:
-        return False
-    dimsv = [int(x) for x in m.group(1).split(",") if x]
-    return len(dimsv) == 4 and dimsv[1:] == [kv_heads, group, head_dim]
-
-
-def attention_kernel_ns(events_with_self: list, cfg: dict) -> int:
-    """Traced time of the configuration's attention kernel."""
-    nh, kvh = int(cfg["num_attention_heads"]), int(cfg["num_key_value_heads"])
-    d = int(cfg["hidden_size"]) // nh
-    return sum(e["self_ns"] for e in events_with_self
-               if is_attention_kernel(e, kvh, nh // kvh, d))
-
-
 def attribute_gaps(gaps: list, host_spans: list, offset_ns: int) -> dict:
     """Idle seconds by what the host was doing: each gap goes to the
     host span (on the device clock after ``offset_ns``) that covers most

@@ -1,25 +1,24 @@
 """The least time the chip needs for the attention the algorithm calls
-for, at the rows' real K/V lengths, over the kernel's traced time.  The
+for, at the rows' real K/V lengths, over the kernels' traced time.  The
 rows are those of the steps completed inside the traced window (from the
-Tracer's request events); operations and bytes come from
-``harness/costs.py``, the peaks from ``harness/peaks.py``."""
-from harness import costs, peaks, spans as S, weights as W, xplane as X
+Tracer's request events); operations and bytes come from the
+architecture's shapes file (``attention_row``), the kernels are found by
+the names it lists, the peaks come from ``harness/peaks.py``."""
+from harness import costs, peaks, scopes, spans as S
 
 
 def read(ctx):
     tr = ctx["trace"]
     if tr is None:
         return None
-    ns = X.attention_kernel_ns(tr["events"], ctx["cfg"])
+    ns = scopes.kernel_ns(tr["events"], ctx["arch"])
     if ns <= 0:
         return None
-    m = W.dims(ctx["cfg"])
     h0, h1 = tr["host_window"]
     rows = S.attention_rows(ctx["spans"], h0, h1)
     if not rows:
         return None
-    ops, byt = costs.attention_total(rows, layers=m["L"], heads=m["nh"],
-                                     kv_heads=m["kvh"], head_dim=m["d"])
+    ops, byt = costs.attention_total(ctx["arch"], ctx["cfg"], rows)
     least, _bound = costs.least_seconds(ops, byt,
                                         peaks.peaks(ctx["device_kind"]))
     return 100.0 * least / (ns / 1e9)

@@ -1,7 +1,8 @@
 """Time in copies of the K/V pool that the program did not ask for, over
-device busy time: operations whose result has the pool's shape ([L,
-num_blocks, kvh, block, d] or one layer of it) and that are not under
-``kv_write`` (the page writes) or ``attn`` (the kernel)."""
+device busy time: operations whose result has the pool's shape (the
+whole pool or one layer of it, as the architecture's shapes file gives
+them) and that are not under the scopes of the pool's own writers (the
+page writes and the kernel)."""
 from harness import scopes
 
 
@@ -9,5 +10,6 @@ def read(ctx):
     evs = scopes.scoped_events(ctx)
     if not evs or ctx["trace"]["busy_s"] <= 0:
         return None
-    ns = scopes.by_class(evs, ctx["cfg"]).get(scopes.POOL_COPY, 0)
+    ns = scopes.by_class(evs, ctx["cfg"], ctx["arch"]).get(
+        scopes.POOL_COPY, 0)
     return 100.0 * ns / (ctx["trace"]["busy_s"] * 1e9) if ns > 0 else None

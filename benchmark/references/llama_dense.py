@@ -3,8 +3,9 @@ block, rotary positions, grouped-query attention, SwiGLU.  Straight
 ``jax.numpy`` in float32 at ``highest`` matmul precision: no kernels, no
 cache, no batching across requests (each sequence is one whole causal
 forward pass).  It imports nothing of the program and takes nothing the
-program made: the weights come from ``harness/weights.py`` by the seed,
-one layer at a time so that it fits beside nothing else.
+program made: the weights come from ``harness/weights.py`` by the seed
+(the leaves are those ``shapes/llama_dense.py`` lists), one layer at a
+time so that it fits beside nothing else.
 
 Departure from the Hugging Face code, noted: rotary pairs are the
 interleaved (2i, 2i+1) pairs of the RoFormer paper and of Meta's LLaMA
@@ -98,9 +99,10 @@ def logits_at(cfg: dict, seed: int, seqs: list, score_from: list,
     import jax
     import jax.numpy as jnp
 
-    from harness import weights as W
+    from harness import spec, weights as W
 
-    m = W.dims(cfg)
+    shapes = spec.load_shapes("llama_dense")
+    m, leaves = shapes.dims(cfg), shapes.leaves(cfg)
     eps = float(cfg["rms_norm_eps"])
     theta = float(cfg["rope_theta"])
     dtype = jnp.dtype(cfg.get("dtype", "bfloat16"))
@@ -113,12 +115,12 @@ def logits_at(cfg: dict, seed: int, seqs: list, score_from: list,
                      for f in score_from]).astype(np.int32)
 
     with jax.default_matmul_precision("highest"):
-        top = _prep(W.make_top(cfg, seed, dtype), lower)
+        top = _prep(W.make_top(leaves, seed, dtype), lower)
         x = jax.jit(lambda e, t: e[t])(top["embed"], jnp.asarray(toks))
         layer = jax.jit(lambda x, w: jax.lax.map(
             lambda xs: _block(xs, w, m, eps, theta), x))
         for i in range(m["L"]):
-            w = _prep(W.make_layer(cfg, seed, i, dtype), lower)
+            w = _prep(W.make_layer(leaves, seed, i, dtype), lower)
             x = layer(x, w)
             del w
 

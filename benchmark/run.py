@@ -3,7 +3,14 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One cell, one process tree.  The last line of standard output is the
-result; everything else is on earlier lines.  See PERF.md."""
+result; everything else is on earlier lines.  See PERF.md.
+
+Nothing here knows a cell, a model or a metric: ``--workload`` names an
+entry of ``BENCHMARK.json``, and ``harness/spec.py`` finds the rest by
+name (its docstring has the table): the configuration's file, the
+traffic file and its generator, each metric's reader, and by the
+configuration's ``reference`` the architecture's three files (plain
+reference, shapes and counts, the builder of the program's model)."""
 from __future__ import annotations
 
 import time
@@ -12,6 +19,7 @@ T_START_NS = time.monotonic_ns()
 
 import argparse
 import gc
+import hashlib
 import json
 import os
 import shutil
@@ -41,6 +49,21 @@ def _overlay(base: dict, over: dict) -> dict:
         out[k] = _overlay(out[k], v) if isinstance(v, dict) \
             and isinstance(out.get(k), dict) else v
     return out
+
+
+def rehearsal_traffic_file(traffic: dict) -> str:
+    """A shrunk mix written where the generator's child can read it,
+    under a name made from its content and moved into place whole: two
+    rehearsals at once (the tests under several workers) share a file
+    only where they share the mix."""
+    body = json.dumps(traffic, sort_keys=True)
+    os.makedirs(OUT_DIR, exist_ok=True)
+    path = os.path.join(OUT_DIR, "rehearsal_traffic.%s.json"
+                        % hashlib.sha256(body.encode()).hexdigest()[:12])
+    with open(f"{path}.{os.getpid()}", "w", encoding="utf-8") as f:
+        f.write(body)
+    os.replace(f"{path}.{os.getpid()}", path)
+    return path
 
 
 class _Profile:
@@ -155,6 +178,13 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic_file: str,
             child.wait()
     if prof is not None:
         prof.join()
+    # the engine's own map from instruction to scope, of the programs it
+    # built, asked while it is there to ask
+    program_scopes = {}
+    if trace and hasattr(engine, "program_scopes"):
+        program_scopes = engine.program_scopes()
+        say("[bench] program_scopes", json.dumps(
+            {k: len(v) for k, v in sorted(program_scopes.items())}))
     host_spans = S.normalise(tracer.events()) if tracer is not None else []
     tracer_dropped = tracer.dropped if tracer is not None else 0
     srv.stop(drain_timeout_s=20.0, abort_inflight=True)
@@ -269,7 +299,9 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic_file: str,
                "tracer_dropped": tracer_dropped,
                "c0": got["c0"], "c1": got["c1"],
                "memory_peak_bytes": got["mem_peak"],
-               "device_kind": device["kind"], "trace": None}
+               "device_kind": device["kind"], "trace": None,
+               "arch": spec.load_shapes(cfg["reference"]),
+               "program_scopes": program_scopes}
         if prof is not None and prof.error is None:
             try:
                 ctx["trace"] = _reduce_trace(prof, cfg, host_spans)
@@ -369,10 +401,7 @@ def main(argv=None) -> int:
             over = json.load(f)
         cfg = _overlay(cfg, over.get("config", {}))
         traffic = _overlay(traffic, over.get("traffic", {}))
-        os.makedirs(OUT_DIR, exist_ok=True)
-        traffic_file = os.path.join(OUT_DIR, "rehearsal_traffic.json")
-        with open(traffic_file, "w", encoding="utf-8") as f:
-            json.dump(traffic, f)
+        traffic_file = rehearsal_traffic_file(traffic)
 
     from harness import server
     cache_dir, watch, device, chip_start_s = server.start_jax()

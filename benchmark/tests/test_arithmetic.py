@@ -4,7 +4,7 @@ import statistics
 
 import pytest
 
-from harness import client, costs, peaks, stats
+from harness import client, costs, peaks, spec, stats
 
 
 def test_percentile_hand_made():
@@ -99,18 +99,26 @@ def test_failures_counted_against_attempts():
 
 def test_attention_counts_hand_worked():
     # one decode row at K/V length 100, 32 heads of 128, 8 K/V heads, bf16
-    ops, byt = costs.attention_row(1, 100, heads=32, kv_heads=8, head_dim=128)
+    arch = spec.load_shapes("llama_dense")
+
+    def cfg(heads, kv_heads, head_dim, layers=1):
+        return {"hidden_size": heads * head_dim, "num_attention_heads": heads,
+                "num_key_value_heads": kv_heads, "intermediate_size": 1,
+                "vocab_size": 1, "num_hidden_layers": layers}
+
+    ops, byt = arch.attention_row(cfg(32, 8, 128), 1, 100)
     assert ops == 4 * 32 * 128 * 100
     assert byt == (2 * 100 * 8 * 128 + 2 * 1 * 8 * 128 + 2 * 1 * 32 * 128) * 2
     # a 4-token chunk that ends at length 10 sees 7 + 8 + 9 + 10 keys
-    ops, _ = costs.attention_row(4, 10, heads=2, kv_heads=1, head_dim=8)
+    ops, _ = arch.attention_row(cfg(2, 1, 8), 4, 10)
     assert ops == 4 * 2 * 8 * 34
     # a whole prompt of n tokens: n (n + 1) / 2 pairs
-    ops, _ = costs.attention_row(16, 16, heads=1, kv_heads=1, head_dim=1)
+    ops, _ = arch.attention_row(cfg(1, 1, 1), 16, 16)
     assert ops == 4 * 136
-    tot = costs.attention_total([(1, 100), (1, 100)], layers=3, heads=32,
-                                kv_heads=8, head_dim=128)
-    one = costs.attention_row(1, 100, heads=32, kv_heads=8, head_dim=128)
+    # every layer does the same, and rows add up
+    tot = costs.attention_total(arch, cfg(32, 8, 128, layers=3),
+                                [(1, 100), (1, 100)])
+    one = arch.attention_row(cfg(32, 8, 128), 1, 100)
     assert tot == (one[0] * 6, one[1] * 6)
 
 

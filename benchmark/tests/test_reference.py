@@ -83,15 +83,89 @@ def test_weights_are_the_seeds_alone_and_differ_by_seed():
     import numpy as np
 
     from harness import weights as W
-    a = W.make_all(TINY, 2**31 + 9, jnp.float32)
-    b = W.make_layer(TINY, 2**31 + 9, 1, jnp.float32)
-    for n in W.LAYER_LEAVES:
+    arch = spec.load_shapes("llama_dense")
+    leaves = arch.leaves(TINY)
+    a = W.make_all(leaves, 2**31 + 9, jnp.float32)
+    b = W.make_layer(leaves, 2**31 + 9, 1, jnp.float32)
+    assert list(b) == [n for n, _kind in arch.LAYER]
+    for n in b:
         assert np.array_equal(np.asarray(a["layers"][1][n]), np.asarray(b[n]))
-    t = W.make_top(TINY, 2**31 + 9, jnp.float32)
+    t = W.make_top(leaves, 2**31 + 9, jnp.float32)
+    assert list(t) == [n for n, _kind in arch.TOP]
     assert np.array_equal(np.asarray(a["top"]["head"]), np.asarray(t["head"]))
-    c = W.make_layer(TINY, 2**31 + 10, 1, jnp.float32)
+    c = W.make_layer(leaves, 2**31 + 10, 1, jnp.float32)
     assert not np.array_equal(np.asarray(b["wq"]), np.asarray(c["wq"]))
     assert a["layers"][0]["wk"].shape == (64, 2 * 16)
+
+
+# sha256 over every leaf of make_all in bfloat16 at seed 2**31 + 9, taken
+# with the weights.py of the commit before the leaves moved out of it
+# (PR 26, 6ae3206): the two configurations' leaf lists at a tiny size,
+# Mistral's K/V group of 4 and Yi's of 8
+PARENT_DIGESTS = [
+    ({"hidden_size": 64, "intermediate_size": 224, "num_attention_heads": 8,
+      "num_key_value_heads": 2, "num_hidden_layers": 3, "vocab_size": 512},
+     "abc7126832c5418eb71d301107dfad423e9ba2638a7caf6ef80943468cb7cec5"),
+    ({"hidden_size": 64, "intermediate_size": 172, "num_attention_heads": 8,
+      "num_key_value_heads": 1, "num_hidden_layers": 2, "vocab_size": 1000},
+     "76ac00f216ffd8d24eb594bb6ba448c4dbf778f7b22334b35995b5ec4ef6e4cf")]
+
+
+@pytest.mark.parametrize("cfg,digest", PARENT_DIGESTS,
+                         ids=["mistral-like", "yi-like"])
+def test_every_leaf_has_the_bits_the_parent_drew(cfg, digest):
+    """The leaf order of ``shapes/llama_dense.py`` is the parent's
+    ``leaf_index`` and ``weights.draw`` its ``_leaf``: the same weights
+    at every seed, so the same served tokens."""
+    import hashlib
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from harness import weights as W
+    arch = spec.load_shapes("llama_dense")
+    made = W.make_all(arch.leaves(cfg), 2**31 + 9, jnp.bfloat16)
+    h = hashlib.sha256()
+    labelled = [(n, made["top"][n]) for n, _ in arch.TOP] + [
+        (f"{i}.{n}", lyr[n]) for i, lyr in enumerate(made["layers"])
+        for n, _ in arch.LAYER]
+    assert len(labelled) == 3 + 9 * cfg["num_hidden_layers"]
+    for label, a in labelled:
+        h.update(label.encode())
+        h.update(str(a.shape).encode())
+        h.update(np.asarray(a).view(np.uint16).tobytes())
+    assert h.hexdigest() == digest
+
+
+def test_leaves_may_differ_by_layer_and_have_any_rank():
+    """What the next architecture needs of the draw: a leading layer
+    with leaves of its own, a stack of expert matrices, a bias that
+    starts at nought; each leaf still a function of seed and index."""
+    import math
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from harness import weights as W
+    leaves = [("embed", None, (32, 8), "embedding"),
+              ("ln", 0, (8,), "norm"), ("up", 0, (8, 24), "matrix"),
+              ("ln", 1, (8,), "norm"), ("router_bias", 1, (4,), "zero"),
+              ("experts_up", 1, (4, 8, 16), "matrix")]
+    made = W.make_all(leaves, 5, jnp.float32)
+    assert list(made["top"]) == ["embed"]
+    assert [list(l) for l in made["layers"]] \
+        == [["ln", "up"], ["ln", "router_bias", "experts_up"]]
+    e = np.asarray(made["layers"][1]["experts_up"])
+    assert e.shape == (4, 8, 16)
+    # Xavier over the last two dimensions, not over experts and inputs
+    assert e.std() == pytest.approx(math.sqrt(2.0 / (8 + 16)), rel=0.1)
+    assert not np.asarray(made["layers"][1]["router_bias"]).any()
+    assert not np.array_equal(np.asarray(made["layers"][0]["ln"]),
+                              np.asarray(made["layers"][1]["ln"]))
+    one = W.make_layer(leaves, 5, 1, jnp.float32)
+    assert np.array_equal(np.asarray(one["experts_up"]), e)
+    with pytest.raises(ValueError):
+        W.make_all([("x", None, (2, 2), "uniform")], 5, jnp.float32)
 
 
 SMALL_BF16 = dict(TINY, hidden_size=256, intermediate_size=512,

@@ -90,10 +90,7 @@ def _run(started, break_path, seed):
                              over["config"])
     traffic = bench_run._overlay(spec.load_traffic(cell["traffic"]),
                                  over["traffic"])
-    os.makedirs(bench_run.OUT_DIR, exist_ok=True)
-    tf = os.path.join(bench_run.OUT_DIR, "test_traffic.json")
-    with open(tf, "w") as f:
-        json.dump(traffic, f)
+    tf = bench_run.rehearsal_traffic_file(traffic)
     return bench_run.run_cell(bench, cell, cfg, tf, traffic, seed, 2.0, False,
                               device, watch, break_path=break_path)
 

@@ -13,10 +13,11 @@ import os
 
 import pytest
 
-from harness import costs, costs_matmul, peaks, scopes, spec, weights as W
+from harness import costs, peaks, scopes, spec
 from harness import xplane as X
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+ARCH = spec.load_shapes("llama_dense")
 
 
 # ---------------------------------------------------------------------------
@@ -32,7 +33,9 @@ def _i(name, ts, **args):
 
 
 def test_scope_of_takes_the_innermost_known_name():
-    f = scopes.scope_of
+    def f(op_name):
+        return scopes.scope_of(op_name, ARCH)
+
     assert f("jit(ragged_step_t192)/layers/while/body/qkv/dot_general") \
         == "qkv"
     assert f("jit(ragged_step_t32)/sample/jit(_where)/select_n") == "sample"
@@ -46,26 +49,30 @@ def test_scope_of_takes_the_innermost_known_name():
 
 
 def test_matmul_costs_by_hand():
-    m = {"H": 8, "nh": 2, "kvh": 1, "d": 4, "F": 16, "V": 32, "L": 3}
-    assert costs_matmul.layer_weights(m) == 128 + 64 + 384
-    ops, byt = costs_matmul.step_matmuls(5, 2, m)
+    m = {"hidden_size": 8, "num_attention_heads": 2,
+         "num_key_value_heads": 1, "intermediate_size": 16, "vocab_size": 32,
+         "num_hidden_layers": 3}
+    assert ARCH.dims(m) == {"H": 8, "nh": 2, "kvh": 1, "d": 4, "F": 16,
+                            "V": 32, "L": 3}
+    assert ARCH.layer_weights(ARCH.dims(m)) == 128 + 64 + 384
+    ops, byt = ARCH.step_matmuls(m, 5, 2)
     assert ops == 2 * 5 * 576 * 3 + 2 * 2 * 8 * 32 == 18304
     # weights once, activations per token and layer, logit rows
     assert byt == (576 * 3 + 256) * 2 + 5 * 104 * 3 * 2 \
         + 2 * (8 * 2 + 32 * 4) == 7376
     # more tokens read the weights no more often
-    ops2, byt2 = costs_matmul.step_matmuls(10, 2, m)
+    ops2, byt2 = ARCH.step_matmuls(m, 10, 2)
     assert ops2 - ops == 2 * 5 * 576 * 3 and byt2 - byt == 5 * 104 * 3 * 2
     bench = spec.load_benchmark()
-    mistral = W.dims(spec.load_config(bench, "mistral-7b-v0.3-d8"))
-    assert costs_matmul.layer_weights(mistral) == 218103808
+    mistral = spec.load_config(bench, "mistral-7b-v0.3-d8")
+    assert ARCH.layer_weights(ARCH.dims(mistral)) == 218103808
     # a decode step of 32 tokens is bound by the weights' bytes
     pk = peaks.peaks("TPU v5 lite")
-    o, b = costs_matmul.step_matmuls(32, 32, mistral)
+    o, b = ARCH.step_matmuls(mistral, 32, 32)
     least, bound = costs.least_seconds(o, b, pk)
     assert bound == "memory" and least == pytest.approx(4.62e-3, rel=0.01)
-    assert costs_matmul.least_seconds([(32, 32), (32, 32)], mistral, pk) \
-        == pytest.approx(2 * least)
+    assert costs.matmul_least_seconds(ARCH, mistral, [(32, 32), (32, 32)],
+                                      pk) == pytest.approx(2 * least)
 
 
 def test_prefill_wait_runs_to_the_launch_that_carried_the_first_chunk():
@@ -179,7 +186,7 @@ def rec():
     d["cfg"] = spec.load_config(bench, "mistral-7b-v0.3-d8")
     evs = X.self_times(X.clip(d["events"], *d["window"]))
     d["scoped"] = scopes.annotate(evs, d["modules"], d["launches"],
-                                  d["program_scopes"])
+                                  d["program_scopes"], ARCH)
     return d
 
 
@@ -192,11 +199,11 @@ def test_every_operation_finds_its_program_its_step_and_a_class(rec):
     progs = {(e["program"], e["step"]) for e in rec["scoped"]}
     assert progs == {("ragged_step_t192", 75), ("ragged_step_t32", 76)}
     assert sum(1 for e in rec["scoped"] if e["step"] == 75) == 1133
-    by = scopes.by_class(rec["scoped"], rec["cfg"])
+    by = scopes.by_class(rec["scoped"], rec["cfg"], ARCH)
     # scopes, pool-shaped copies and the unscoped remainder are all of it
     assert sum(by.values()) == BUSY
-    assert set(by) == set(scopes.SCOPES) | {"layers", "kvpool_copy",
-                                            "unscoped"}
+    assert set(by) == set(ARCH.SCOPES) | {ARCH.LOOP, scopes.POOL_COPY,
+                                          scopes.UNSCOPED}
     assert by["attn"] == 494468937 and by["sample"] == 48809572
     assert by["kvpool_copy"] == 52061825 and by["kv_write"] == 13482796
     assert by["mlp"] == 7635952 and by["layers"] == 2002990
@@ -204,41 +211,87 @@ def test_every_operation_finds_its_program_its_step_and_a_class(rec):
     assert 100 * by["unscoped"] / BUSY < 5
 
 
-def test_a_map_that_does_not_fit_the_trace_gives_nothing(rec, capsys):
-    """The map is asked of an engine built again; the join says so when
-    that engine's instruction names are not the trace's."""
-    assert scopes.join_holds(rec["scoped"])
-    assert capsys.readouterr().out == ""
+def test_a_map_that_does_not_fit_the_trace_shows_as_unscoped(rec):
+    """The map is the live engine's own (``ctx["program_scopes"]``), so
+    nothing guards the join any more; a map that does not name the
+    trace's instructions would put all busy time in ``unscoped``, in
+    plain sight on the ``[bench] scopes`` line, and no program's map at
+    all gives the readers nothing."""
     other = {prog: {"x." + name: info for name, info in m.items()}
              for prog, m in rec["program_scopes"].items()}
     evs = X.self_times(X.clip(rec["events"], *rec["window"]))
-    lost = scopes.annotate(evs, rec["modules"], rec["launches"], other)
-    assert not scopes.join_holds(lost)
-    assert "[bench] scopes: 100.0% of busy time" in capsys.readouterr().out
-    assert scopes.join_holds([])
+    lost = scopes.annotate(evs, rec["modules"], rec["launches"], other, ARCH)
+    by = scopes.by_class(lost, rec["cfg"], ARCH)
+    assert set(by) == {scopes.UNSCOPED, scopes.POOL_COPY}
+    assert sum(by.values()) == BUSY
+    ctx = {"trace": {"plane": "/device:TPU:0", "window": rec["window"]},
+           "arch": ARCH, "cfg": rec["cfg"]}
+    assert scopes.scoped_events(dict(ctx, program_scopes={})) == []
+    assert scopes.scoped_events(ctx) == []
+
+
+def test_scoped_events_join_the_engines_map_once(rec, monkeypatch):
+    """``ctx["program_scopes"]`` is what ``run.py`` asked of the engine
+    that served; the join is worked out once for a run's readers."""
+    calls = []
+
+    def read(trace_dir, plane):
+        calls.append(plane)
+        return rec["events"], rec["modules"], rec["launches"]
+
+    monkeypatch.setattr(scopes, "_read", read)
+    ctx = {"trace": {"plane": "/device:TPU:0", "window": rec["window"]},
+           "arch": ARCH, "cfg": rec["cfg"],
+           "program_scopes": rec["program_scopes"]}
+    evs = scopes.scoped_events(ctx)
+    assert scopes.scoped_events(ctx) is evs and calls == ["/device:TPU:0"]
+    assert [(e["name"], e["scope"], e["step"], e["self_ns"]) for e in evs] \
+        == [(e["name"], e["scope"], e["step"], e["self_ns"])
+            for e in rec["scoped"]]
+
+
+def _shaped_like_the_kernel(e, kv_heads, group, head_dim):
+    """How ``attn.*`` found the kernel before they took its name: a
+    custom call whose result is [tokens, kv_heads, group, head_dim]."""
+    import re
+    if (e.get("category") or "").lower() != "custom-call":
+        return False
+    m = re.match(r"[a-z]+[0-9]*\[([0-9,]*)\]", e.get("shape") or "")
+    if not m:
+        return False
+    dimsv = [int(x) for x in m.group(1).split(",") if x]
+    return len(dimsv) == 4 and dimsv[1:] == [kv_heads, group, head_dim]
 
 
 def test_kernel_found_by_name_and_by_shape_is_the_same_time(rec):
-    """So that a later benchmark PR can re-point attn.* to the name with
-    no new recording."""
-    named = [e for e in rec["scoped"] if scopes.is_kernel_name(e["name"])]
+    """attn.* find the kernel by the names the architecture's file
+    lists; on the recording that is what its result's shape found."""
+    named = [e for e in rec["scoped"]
+             if scopes.is_kernel_name(e["name"], ARCH)]
     shaped = [e for e in rec["scoped"]
-              if X.is_attention_kernel(e, 8, 4, 128)]
+              if _shaped_like_the_kernel(e, 8, 4, 128)]
     assert len(named) == len(shaped) == 16           # 8 layers, 2 launches
+    assert [e["name"] for e in named] == [e["name"] for e in shaped]
     assert sum(e["self_ns"] for e in named) == 494468937 \
-        == X.attention_kernel_ns(rec["scoped"], rec["cfg"])
+        == sum(e["self_ns"] for e in shaped) \
+        == scopes.kernel_ns(rec["scoped"], ARCH)
     assert {e["scope"] for e in named} == {"attn"}
     assert all(e["op_name"].endswith(
         "/attn/ragged_paged_attention/pallas_call") for e in named)
-    assert not scopes.is_kernel_name("closed_call.14")
-    assert scopes.is_kernel_name("%ragged_paged_attention_q8.3")
+    assert not scopes.is_kernel_name("closed_call.14", ARCH)
+    assert scopes.is_kernel_name("%ragged_paged_attention_q8.3", ARCH)
+    # attn.device_share on the recording: the kernel over busy time
+    ctx = {"trace": {"events": rec["scoped"], "busy_s": BUSY / 1e9},
+           "arch": ARCH}
+    assert spec.load_reader("attn.device_share")(ctx) \
+        == pytest.approx(100 * 494468937 / BUSY)
 
 
 def test_pool_shaped_copies_are_not_the_page_writes(rec):
-    shapes = scopes.pool_shapes(rec["cfg"])
+    shapes = ARCH.pool_shapes(rec["cfg"])
     assert (8, 4097, 8, 16, 128) in shapes and (4097, 8, 16, 128) in shapes
     copies = [e for e in rec["scoped"]
-              if scopes.classify(e, shapes) == "kvpool_copy"]
+              if scopes.classify(e, shapes, ARCH) == "kvpool_copy"]
     # the two whole-pool copies that close every step, 3.2 ms each
     whole = [e for e in copies if e["category"] == "copy"
              and e["shape"] == "bf16[8,4097,8,16,128]"]
@@ -247,18 +300,18 @@ def test_pool_shaped_copies_are_not_the_page_writes(rec):
     writes = [e for e in rec["scoped"] if e["scope"] == "kv_write"
               and scopes.is_pool_shaped(e, shapes)]
     assert writes and all(
-        scopes.classify(e, shapes) == "kv_write" for e in writes)
+        scopes.classify(e, shapes, ARCH) == "kv_write" for e in writes)
 
 
 def test_matmul_time_takes_in_the_weight_slices(rec):
-    shapes = scopes.pool_shapes(rec["cfg"])
+    shapes = ARCH.pool_shapes(rec["cfg"])
     mm = {s: sum(e["self_ns"] for e in rec["scoped"]
-                 if e["step"] == s and scopes.is_matmul(e, shapes))
+                 if e["step"] == s and scopes.is_matmul(e, shapes, ARCH))
           for s in (75, 76)}
     assert mm == {75: 6117199, 76: 5634547}
     # no product hides outside the four scopes in these programs
     assert not [e for e in rec["scoped"] if e["has_dot"]
-                and e["scope"] not in scopes.MATMUL_SCOPES]
+                and e["scope"] not in ARCH.MATMUL_SCOPES]
     dots = sum(e["self_ns"] for e in rec["scoped"]
                if e["step"] == 76 and e["has_dot"])
     # the products alone read 99.9% of the memory's peak (4.636 ms of
@@ -271,7 +324,7 @@ def _ctx(rec, monkeypatch):
     monkeypatch.setattr(scopes, "launch_annotations",
                         lambda ctx: rec["launches"])
     w0, w1 = rec["window"]
-    return {"cfg": rec["cfg"], "spans": rec["spans"],
+    return {"cfg": rec["cfg"], "spans": rec["spans"], "arch": ARCH,
             "device_kind": "TPU v5 lite",
             # one ns more: launch 77's annotation closes launch 76
             "trace": {"busy_s": BUSY / 1e9, "window": (w0, w1 + 1)}}
@@ -298,10 +351,9 @@ def test_matmul_roofline_on_the_recording_is_under_100(rec, monkeypatch):
     assert {s: (a["tokens"], a["logit_rows"], a["bucket"])
             for s, a in launched.items()} \
         == {75: (146, 31, 192), 76: (31, 31, 32)}
-    m = W.dims(rec["cfg"])
-    assert costs_matmul.step_matmuls(146, 31, m) \
+    assert ARCH.step_matmuls(rec["cfg"], 146, 31) \
         == (517811994624, 3925073920)
-    assert costs_matmul.step_matmuls(31, 31, m) \
+    assert ARCH.step_matmuls(rec["cfg"], 31, 31) \
         == (116500987904, 3796951040)
     got = spec.load_reader("matmul.roofline_share")(ctx)
     least = 3925073920 / 819e9 + 3796951040 / 819e9     # both memory-bound
@@ -330,7 +382,7 @@ def test_a_program_without_names_gives_the_readers_nothing():
     assert scopes.program_of("jit_run(123456)") == "run"
     assert scopes.scoped_events({"trace": None}) == []
     ctx = {"trace": None, "spans": [], "c0": {}, "c1": {}, "cfg": {},
-           "t_open": 0, "t_close": 1}
+           "t_open": 0, "t_close": 1, "arch": ARCH, "program_scopes": {}}
     for name in ("matmul.device_share", "matmul.roofline_share",
                  "sampling.device_share", "kvpool.copy_share",
                  "engine.pad_share", "engine.prefill_wait_p50_ms",

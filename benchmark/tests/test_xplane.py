@@ -1,6 +1,6 @@
 """The trace reduction on hand-made records (the recorded trace has a
 test of its own in test_recorded_trace.py)."""
-from harness import spans as S, xplane as X
+from harness import scopes, spans as S, spec, xplane as X
 
 
 def ev(name, start, dur, cat="", shape=""):
@@ -54,15 +54,22 @@ def test_instruction_text_as_the_tpu_trace_names_it():
     assert X.parse_instruction("bench.mark") == ("bench.mark", "", "")
 
 
-def test_attention_kernel_by_category_and_shape():
-    k = ev("closed_call.14", 0, 1, "custom-call", "bf16[192,8,4,128]")
-    assert X.is_attention_kernel(k, 8, 4, 128)
-    assert not X.is_attention_kernel(k, 4, 8, 128)          # the other model
-    assert not X.is_attention_kernel(
-        ev("fusion.1", 0, 1, "fusion", "bf16[192,8,4,128]"), 8, 4, 128)
-    assert not X.is_attention_kernel(
-        ev("closed_call.2", 0, 1, "custom-call", "bf16[8,4097,8,16,128]"),
-        8, 4, 128)
+def test_attention_kernel_by_the_names_the_architecture_lists():
+    arch = spec.load_shapes("llama_dense")
+    evs = X.self_times([
+        ev("ragged_paged_attention.14", 0, 5, "custom-call",
+           "bf16[192,8,4,128]"),
+        # whatever its result's shape: the other model's head layout
+        ev("ragged_paged_attention_q8.2", 10, 7, "custom-call",
+           "bf16[192,4,8,128]"),
+        # a kernel without the name is not found, nor a fusion that
+        # gives the kernel's shape, nor a name that only starts alike
+        ev("closed_call.14", 20, 1, "custom-call", "bf16[192,8,4,128]"),
+        ev("fusion.1", 30, 1, "fusion", "bf16[192,8,4,128]"),
+        ev("ragged_paged_attention_bwd.1", 40, 1, "custom-call", "")])
+    assert [scopes.is_kernel_name(e["name"], arch) for e in evs] \
+        == [True, True, False, False, False]
+    assert scopes.kernel_ns(evs, arch) == 12
 
 
 def test_gaps_go_to_what_the_host_was_doing():
