@@ -59,6 +59,22 @@ _COERCIONS = {"float", "int", "bool"}
 
 _ATTEN_RE = re.compile(r"atten", re.IGNORECASE)
 
+
+def _declared_attention_kinds(tree) -> int:
+    """How many attention kinds a module declares for its layers: the
+    length of a module-level ``ATTENTION_KINDS = ("...", ...)`` literal
+    of distinct names; 1 where there is none."""
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "ATTENTION_KINDS"
+                for t in node.targets) \
+                and isinstance(node.value, (ast.Tuple, ast.List)):
+            names = {e.value for e in node.value.elts
+                     if isinstance(e, ast.Constant)
+                     and isinstance(e.value, str)}
+            return max(1, len(names))
+    return 1
+
 # real-clock reads and global-RNG calls the simulator tier must not
 # make (nondeterministic-sim); seeded random.Random instances are fine
 _WALL_CLOCK_FNS = {"time", "time_ns", "perf_counter", "perf_counter_ns",
@@ -482,10 +498,14 @@ def lint_source(text: str, path: str = "<string>") -> list:
                          "lax.cond/jnp.where")
 
     # ---- attention-program-budget (serving tier only) --------------------
-    # The engine contract since the ragged refactor: ONE attention-bearing
-    # compiled program per engine (the ragged step).  A second jit root or
-    # pallas_call def that mentions attention in an `inference/` file is a
-    # phase-special kernel sneaking back in.
+    # What the budget protects is "no compile per request": the attention-
+    # bearing compiled program KINDS of an engine are bounded by what its
+    # models' layers are, not by what requests do.  A module of the
+    # inference tier declares the attention kinds its layers may have
+    # (``ATTENTION_KINDS = ("gqa", "mla")``, a literal tuple of names) and
+    # may hold that many attention program kinds; without a declaration
+    # the budget is ONE (the ragged step).  A jit root or pallas_call def
+    # beyond it is a phase-special kernel sneaking back in.
     if "inference" in re.split(r"[\\/]", path):
         progs = set(roots)
         for d in ctx.defs:
@@ -521,12 +541,14 @@ def lint_source(text: str, path: str = "<string>") -> list:
         for d in att:
             if all(d.name != k.name for k in kinds):
                 kinds.append(d)
-        for d in kinds[1:]:
+        budget = _declared_attention_kinds(ctx.tree)
+        for d in kinds[budget:]:
             emit("attention-program-budget", d,
-                 f"compiled def `{d.name}` is a second attention program "
-                 f"kind in the serving tier (first: `{kinds[0].name}`) — "
-                 "budget is 1 attention program per engine; route rows "
-                 "through the single ragged step instead")
+                 f"compiled def `{d.name}` is attention program kind "
+                 f"{kinds.index(d) + 1} in the serving tier (first: "
+                 f"`{kinds[0].name}`) — budget is {budget}: one per "
+                 "attention kind the module's ATTENTION_KINDS declares (1 "
+                 "without it); route rows through the ragged step instead")
 
         # ---- quantized-kv-float32-page (serving tier only) ---------------
         # In the branch an engine takes when configured kv_dtype="int8",

@@ -85,11 +85,12 @@ RULES = {
         "loop) — a fresh cache entry every time, i.e. recompile hazard; "
         "hoist it or key it in a cache dict")),
     "attention-program-budget": (ERROR, "ast", (
-        "a second attention-bearing compiled program (jax.jit or "
-        "pallas_call) in the inference tier — the serving engine budget "
-        "is ONE attention program kind (the ragged step); phase-special "
-        "attention kernels reintroduce bucket fragmentation and "
-        "recompiles")),
+        "an attention-bearing compiled program (jax.jit or pallas_call) "
+        "in the inference tier beyond the budget — one program kind per "
+        "attention kind the module declares for its models' layers "
+        "(ATTENTION_KINDS; ONE, the ragged step, without a declaration); "
+        "phase-special attention kernels reintroduce bucket "
+        "fragmentation and recompiles")),
     "quantized-kv-float32-page": (WARNING, "ast", (
         "a float32 allocation bound to a KV-page-like name inside an "
         "inference-tier kv_dtype == \"int8\" branch — quantized engines "

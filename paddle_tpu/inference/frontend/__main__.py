@@ -14,6 +14,14 @@ bridge):
                cut depth with --layers and hold weights in --dtype
                bfloat16 (8 layers: 3.8 GB of weights, held twice while
                the engine keeps its layer-stacked copy)
+    mla-moe-sm a small latent-attention (MLA) decoder with a leading dense
+               layer and expert layers after it (8 experts, 3 a token)
+    sarvam-105b  sarvam-105b's widths (MLA, 128 routed experts, 8 a
+               token, a shared expert) as ONE chip of an expert-parallel-4
+               host holds it: 32 of the 128 experts a layer, a quarter of
+               the vocabulary.  Cut depth with --layers (6: one dense and
+               five expert layers, 10.9 GB in bfloat16, held once: the
+               engine serves the model's own arrays)
 
 The process computes on whatever device JAX resolves, and says which on
 its start-up line together with the attention and matmul paths the
@@ -60,6 +68,15 @@ def _model_config(args):
         cfg = LlamaConfig.llama_7b()
         if args.max_model_len:
             cfg.max_position_embeddings = args.max_model_len
+    elif args.model == "mla-moe-sm":
+        from paddle_tpu.models.mla_moe import MlaMoeConfig
+        cfg = MlaMoeConfig.tiny(vocab=512, hidden=128, layers=4, heads=4,
+                                experts=8, seq=args.max_model_len or 1024)
+    elif args.model == "sarvam-105b":
+        from paddle_tpu.models.mla_moe import MlaMoeConfig
+        cfg = MlaMoeConfig(
+            vocab_size=262144 // 4, experts_held=32, ep_size=4, ep_rank=0,
+            max_position_embeddings=args.max_model_len or 16384)
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
     if args.layers:
@@ -89,9 +106,14 @@ def _build_engine(args, cfg):
     from ..serving import LLMEngine
 
     paddle_tpu.seed(0)
-    model = LlamaForCausalLM(cfg)
-    if args.dtype != "float32":
-        model.to(dtype=args.dtype)
+    if getattr(cfg, "architecture", None) == "mla_moe":
+        from paddle_tpu.models.mla_moe import MlaMoeForCausalLM
+        # drawn leaf by leaf in the served type: no float32 model first
+        model = MlaMoeForCausalLM(cfg, dtype=args.dtype)
+    else:
+        model = LlamaForCausalLM(cfg)
+        if args.dtype != "float32":
+            model.to(dtype=args.dtype)
     drafter = "ngram" if args.spec_k > 0 else None
     need = args.tp * args.replicas
     if len(jax.devices()) < need:
@@ -133,7 +155,8 @@ def _parser() -> argparse.ArgumentParser:
         description="Serve an LLM over HTTP (OpenAI-style /v1/completions "
                     "with SSE streaming, /healthz, /metrics).")
     ap.add_argument("--model", default="tiny",
-                    choices=["tiny", "llama-sm", "llama-7b"])
+                    choices=["tiny", "llama-sm", "llama-7b", "mla-moe-sm",
+                             "sarvam-105b"])
     ap.add_argument("--layers", type=int, default=0,
                     help="depth cut: build this many decoder layers "
                          "(0 = the preset's depth); widths are never cut")

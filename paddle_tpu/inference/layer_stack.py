@@ -1,0 +1,215 @@
+"""The layers of a serving step as ONE function of what each layer is.
+
+A step program embeds its flat tokens, runs the model's layers and
+scores the logit rows.  ``layer_stack`` is the middle of that for every
+model the engine serves: each layer is (attention kind, FFN kind), and
+the function is told how each kind computes, reads the paged cache and
+writes it.  A model whose layers are all alike with their weights stacked
+(the dense decoder) runs as one ``lax.scan`` over the stack; a model
+whose layers differ, or whose weights are too large to hold a second,
+stacked copy of (a latent-attention decoder with a leading dense layer
+and expert layers after it), runs its layers one after another over the
+model's own arrays.
+
+Attention kinds: ``gqa`` (rotary grouped-query attention over K and V
+pages ``[num_blocks, Hkv, bs, D]``, one layer's slice at a time) and
+``mla`` (latent attention in the absorbed form over ONE pool for all
+layers, ``[L, num_blocks, bs, width]``, written and read in place at the
+layer's index).  FFN kinds: ``swiglu`` and ``moe`` (routed experts held
+here plus shared experts: ``models/mla_moe.py``).
+
+The ``jax.named_scope`` names below are what a device trace is read by
+(docs/observability.md): ``norm``, ``qkv``/``q_proj``/``kv_latent``,
+``rope``, ``kv_write``, ``attn``, ``o_proj``, ``mlp``, and for expert
+layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
+``shared_expert``.
+"""
+from __future__ import annotations
+
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..models import mla_moe as _mm
+from ..models.llama import _rms_weight, _rope_positions
+from ..ops.pallas import mla_attention as _mla
+from ..ops.pallas import paged_attention as _pa
+
+
+def scan_layers(body, x, layers, pools):
+    """``lax.scan`` over the stacked layers with the stacked pools (K and
+    V pages; over int8 pages their scales too) in the CARRY: each turn
+    slices its layer's pools out, gives them to ``body(x, (p, *pools))
+    -> (x, pools)`` and writes what comes back in place.  Scanned
+    through as inputs and outputs the pools came back in a new buffer,
+    and aliasing it to the donated input cost a copy of each whole pool
+    a step (3.2 ms a GB on the v5e) that XLA makes up, so that no scope
+    names it in a trace."""
+    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+
+    def turn(carry, inp):
+        x, pools = carry
+        p, l = inp
+        x, new = body(x, (p,) + tuple(
+            lax.dynamic_index_in_dim(c, l, keepdims=False) for c in pools))
+        return (x, tuple(lax.dynamic_update_index_in_dim(c, v, l, 0)
+                         for c, v in zip(pools, new))), None
+
+    (x, pools), _ = lax.scan(turn, (x, tuple(pools)),
+                             (layers, jnp.arange(n, dtype=jnp.int32)))
+    return x, pools
+
+
+def step_context(**kw) -> SimpleNamespace:
+    """What every layer of one step program shares: the row layout
+    (``Tq``, ``seg``, ``rel``, ``bt``, ``cu``, ``kvl``, ``bs``), the
+    products (``mm``), the model's sizes and whether the kernel runs
+    (``use_pallas``)."""
+    return SimpleNamespace(**kw)
+
+
+# ---------------------------------------------------------------------------
+# attention kinds: (x, h, p, pools, layer, c) -> (x, pools)
+# ---------------------------------------------------------------------------
+
+def _gqa(x, h, p, pools, _layer, c):
+    """Grouped-query attention over this layer's K and V pages."""
+    kcl, vcl = pools
+    Tq, nh, kvh, d, tp, mm = c.Tq, c.nh, c.kvh, c.d, c.tp, c.mm
+    with jax.named_scope("qkv"):
+        q = mm(h, p, "wq").reshape(Tq, nh, d)
+        k = mm(h, p, "wk").reshape(Tq, kvh, d)
+        v = mm(h, p, "wv").reshape(Tq, kvh, d)
+    with jax.named_scope("rope"):
+        q = _rope_positions(q, c.rel, c.theta)
+        k = _rope_positions(k, c.rel, c.theta)
+    with jax.named_scope("kv_write"):
+        blk = c.bt[c.seg, c.rel // c.bs]                  # [Tq]
+        slot = c.rel % c.bs
+        kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
+        vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
+    with jax.named_scope("attn"):
+        if c.use_pallas:
+            # the host packing path owns these buffers: bt is the int32
+            # NULL_BLOCK-padded pool table and cu, kvl come int32 from
+            # the step's packing, so the packed entry skips the
+            # per-launch re-clip and re-cast.  The kernel reads the row
+            # layout itself; seg/rel are for rope and kv_write
+            att = _pa.ragged_paged_attention_packed(
+                q, kcl, vcl, c.bt, c.cu, c.kvl)
+        else:
+            att = _pa.ragged_paged_reference_segrel(
+                q, kcl, vcl, c.bt, c.seg, c.rel)
+        if tp > 1:
+            # tiled gather concatenates shard head blocks in mesh order
+            # — exactly the tp=1 head layout, so the replicated wo
+            # matmul is byte-identical
+            att = lax.all_gather(att, "tp", axis=1, tiled=True)
+    with jax.named_scope("o_proj"):
+        x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
+    return x, (kcl, vcl)
+
+
+def _latent(x, h, p, pools, layer, c):
+    """Latent attention, absorbed form, over the one pool of all layers:
+    the launch's rows ``[c | k_rope | 0...]`` are written in place at
+    (layer, page, slot) and the kernel reads pages where they lie."""
+    (pool,) = pools
+    cfg = c.cfg
+    q, row = _mm.mla_project(h, p, cfg, c.rel, c.inv_freq)
+    with jax.named_scope("kv_write"):
+        blk = c.bt[c.seg, c.rel // c.bs]
+        slot = c.rel % c.bs
+        row = jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1])))
+        pool = pool.at[layer, blk, slot, :].set(row.astype(pool.dtype))
+    with jax.named_scope("attn"):
+        if c.use_pallas:
+            lat = _mla.ragged_latent_attention_packed(
+                q, pool, layer, c.bt, c.cu, c.kvl,
+                latent_dim=cfg.kv_lora_rank, sm_scale=c.sm_scale)
+        else:
+            lat = _mla.mla_ragged_reference_segrel(
+                q, pool[layer], c.bt, c.seg, c.rel,
+                latent_dim=cfg.kv_lora_rank, sm_scale=c.sm_scale)
+    with jax.named_scope("o_proj"):
+        x = x + _mm.mla_output(lat, p, cfg)
+    return x, (pool,)
+
+
+# ---------------------------------------------------------------------------
+# FFN kinds: (x, h2, p, c) -> (x, what an expert layer counted or None)
+# ---------------------------------------------------------------------------
+
+def _swiglu(x, h2, p, c):
+    mm = c.mm
+    with jax.named_scope("mlp"):
+        a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
+                        ).astype(h2.dtype) * mm(h2, p, "up")
+        x = x + mm(a, p, "down")
+    return x, None
+
+
+def _moe(x, h2, p, c):
+    out, counts = _mm.moe_ffn(h2, p, c.cfg, valid=c.seg < c.kvl.shape[0],
+                              use_kernel=c.use_pallas)
+    with jax.named_scope("moe_combine"):
+        return x + out, counts
+
+
+ATTENTION = {"gqa": _gqa, "mla": _latent}
+FFN = {"swiglu": _swiglu, "moe": _moe}
+
+
+def _unrolled(layer, x, layers, pools):
+    """Layers of one kind and shape, one after another, through ONE
+    traced copy of ``layer`` (an inner jit, built while the step program
+    is traced and gone with that trace)."""
+    one = jax.jit(layer)
+    counted = []
+    for index, p in layers:
+        x, pools, counts = one(x, p, pools, jnp.int32(index))
+        if counts is not None:
+            counted.append(counts)
+    return x, pools, counted
+
+
+def layer_stack(x, segments, pools, c):
+    """Run the layers.  ``segments``: [((attention kind, FFN kind),
+    layers, scanned)].  A scanned segment's ``layers`` is a pytree with a
+    leading layer axis and its pools are sliced a layer at a time; an
+    unrolled segment's is [(layer index, that layer's weights)] and its
+    kinds get the whole pools and the index; its layers, alike in kind
+    and shape, are traced and lowered ONCE and called with the index as
+    an operand (traced layer by layer, six layers of two Pallas kernels
+    each took 8.7 s a token bucket of every process start on the v5e's
+    host, compile cache or not).  Returns (x, pools, counts): what the
+    expert layers counted, summed over layers (the largest load: the
+    largest), int32 [4], or None without expert layers."""
+    pools = tuple(pools)
+    counted = []
+    for (attn, ffn), layers, scanned in segments:
+        attention, feed = ATTENTION[attn], FFN[ffn]
+
+        def layer(x, p, pools, index):
+            with jax.named_scope("norm"):
+                h = _rms_weight(x, p["ln1"], c.eps)
+            x, pools = attention(x, h, p, pools, index, c)
+            with jax.named_scope("norm"):
+                h2 = _rms_weight(x, p["ln2"], c.eps)
+            x, counts = feed(x, h2, p, c)
+            return x, pools, counts
+
+        if scanned:
+            x, pools = scan_layers(
+                lambda x, inp: layer(x, inp[0], inp[1:], None)[:2],
+                x, layers, pools)
+        else:
+            x, pools, counts = _unrolled(layer, x, layers, pools)
+            counted += counts
+    if not counted:
+        return x, pools, None
+    all_ = jnp.stack(counted)                              # [layers, 4]
+    return x, pools, jnp.concatenate([jnp.sum(all_[:, :3], axis=0),
+                                      jnp.max(all_[:, 3:], axis=0)])

@@ -77,10 +77,12 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.llama import _rms_weight, _rope_positions
+from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
 from ..profiler import ServingStats
 from .faults import InjectedFault
+from . import layer_stack as _ls
 from .kv_cache import (NULL_BLOCK, BlockManager, BlockPoolExhausted,
                        prefix_chain_hashes)
 from .policy import pack_prefill_chunks
@@ -89,6 +91,12 @@ from .sampling import (advance_keys, make_samp, samp_structs,
                        sample_tokens)
 
 __all__ = ["LLMEngine", "Request", "RequestOutput"]
+
+# the attention kinds a served model's layers may have (the keys of
+# ``layer_stack.ATTENTION``, spelled out: graft-lint reads this literal).
+# An engine's attention-bearing program kinds are bounded by it, whatever
+# its requests do (rule ``attention-program-budget``).
+ATTENTION_KINDS = ("gqa", "mla")
 
 
 @dataclass
@@ -161,6 +169,8 @@ class _StepTicket:
     inflight: bool = False            # crossed a step() boundary in flight
     window: int = 0                   # K of a decode-window launch (0 =
                                       # per-step; sampled/fin are [K, B])
+    counts: object = None             # device array | None: what the
+                                      # launch's expert layers counted
 
 
 class _DecodeBufs:
@@ -241,28 +251,33 @@ def _instruction_scopes(hlo_text: str) -> dict:
     return out
 
 
-def _scan_layers(body, x, layers, pools):
-    """``lax.scan`` over the stacked layers with the stacked pools (K and
-    V pages; over int8 pages their scales too) in the CARRY: each turn
-    slices its layer's pools out, gives them to ``body(x, (p, *pools))
-    -> (x, pools)`` and writes what comes back in place.  Scanned
-    through as inputs and outputs the pools came back in a new buffer,
-    and aliasing it to the donated input cost a copy of each whole pool
-    a step (3.2 ms a GB on the v5e) that XLA makes up, so that no scope
-    names it in a trace."""
-    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+# the int8-page and window builders still scan their layers themselves
+_scan_layers = _ls.scan_layers
 
-    def turn(carry, inp):
-        x, pools = carry
-        p, l = inp
-        x, new = body(x, (p,) + tuple(
-            lax.dynamic_index_in_dim(c, l, keepdims=False) for c in pools))
-        return (x, tuple(lax.dynamic_update_index_in_dim(c, v, l, 0)
-                         for c, v in zip(pools, new))), None
 
-    (x, pools), _ = lax.scan(turn, (x, tuple(pools)),
-                             (layers, jnp.arange(n, dtype=jnp.int32)))
-    return x, pools
+def _refuse_latent_options(**asked) -> None:
+    """A model with latent-attention layers is served from float pages
+    and float weights on one chip, a step a launch.  Each option below
+    needs what its message says before it can be taken."""
+    needs = {
+        "kv_dtype": ("float32", "int8 latent pages need a quantising "
+                     "write and a dequantising load in the latent kernel"),
+        "weight_dtype": ("float32", "int8/int4 weights need quantized "
+                         "expert pools and a grouped dequant product"),
+        "tp": (1, "tp > 1 needs the heads of the absorbed query and the "
+               "experts laid over a mesh, with their exchange"),
+        "drafter": (None, "a drafter needs verify rows' logits from the "
+                    "latent step program"),
+        "decode_window": (1, "decode_window > 1 needs a window builder "
+                          "on the layer-stack function"),
+        "kv_tier": (None, "kv_tier needs the spill and restore of latent "
+                    "pages"),
+    }
+    for name, (supported, why) in needs.items():
+        if asked[name] != supported:
+            raise ValueError(
+                f"{name}={asked[name]!r} is not supported for a model "
+                f"with latent-attention (MLA) layers: {why}")
 
 
 # what wraps a launch when no tracer is installed: the jitted call keeps
@@ -396,6 +411,19 @@ class LLMEngine:
                  kv_tier=None, devices=None):
         cfg = model.config
         self.config = cfg
+        # which layers the model has: (attention kind, FFN kind) each.
+        # ``gqa`` + ``swiglu`` throughout is the dense decoder; a model
+        # of other kinds says so itself (``config.layer_kinds()``)
+        self._layer_kinds = cfg.layer_kinds() \
+            if hasattr(cfg, "layer_kinds") \
+            else [("gqa", "swiglu")] * cfg.num_hidden_layers
+        self._latent = any(a == "mla" for a, _ in self._layer_kinds)
+        self._has_experts = any(f == "moe" for _, f in self._layer_kinds)
+        if self._latent:
+            _refuse_latent_options(
+                kv_dtype=kv_dtype, weight_dtype=weight_dtype, tp=tp,
+                drafter=drafter, decode_window=decode_window,
+                kv_tier=kv_tier)
         if kv_dtype not in ("float32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'float32' or 'int8', got {kv_dtype!r}")
@@ -431,6 +459,10 @@ class LLMEngine:
         # live: on the process's default device a replica's transient
         # copy would sit on chip 0 beside the model and replica 0's own
         with jax.default_device(devices[0]):
+            # the dense decoder's export is a layer-stacked COPY (what
+            # its scan runs over); a model of differing layers exports
+            # its own arrays layer by layer and the engine holds them
+            # once
             self.params = model.decode_params()
             # the step's activations keep the model's float dtype even
             # when the embed table becomes a quantized pool + scales
@@ -474,12 +506,22 @@ class LLMEngine:
         self._staged_hashes: set = set()
 
         self._nh = cfg.num_attention_heads
-        self._kvh = cfg.num_key_value_heads
-        self._hd = cfg.hidden_size // self._nh
         L = cfg.num_hidden_layers
         dt = self._act_dtype
+        if self._latent:
+            # one K/V "head" of the cached row's stored width
+            self._kvh, self._hd = 1, _mla.page_width(cfg.kv_row)
+        else:
+            self._kvh = cfg.num_key_value_heads
+            self._hd = cfg.hidden_size // self._nh
         with jax.default_device(devices[0]):
-            if self.kv_dtype == "int8":
+            if self._latent:
+                # ONE pool for all layers, a row [c | k_rope | 0...] a
+                # token: written in place and read where it lies
+                self._kc = jnp.zeros((L, num_blocks, self.block_size,
+                                      self._hd), dt)
+                self._vc = self._ks = self._vs = None
+            elif self.kv_dtype == "int8":
                 # int8 pages + a parallel per-page-per-head f32 scale
                 # pool (symmetric: float = int8 * scale).  Scales are
                 # written at commit time inside the step program; the
@@ -607,6 +649,11 @@ class LLMEngine:
         self.launches = 0
         self._evictions_seen = 0
         self.peak_resident_seqs = 0
+        # what the step's expert layers counted (in the order the step
+        # program returns them), summed at completion
+        self.moe_counts = {"moe_pairs_here": 0, "moe_pairs_all": 0,
+                           "moe_experts_touched": 0, "moe_load_max": 0}
+        self._launch_counts = None
         self.stats = ServingStats()
         self.stats.set_decode_window(self.decode_window)
         self.stats.set_weight_residency(
@@ -725,6 +772,13 @@ class LLMEngine:
             return "pallas-interpret"
         if self._platform != "tpu":
             return f"xla-reference ({self._platform} platform)"
+        if self._latent:
+            why = _mla.ineligible(
+                self._nh, self._hd, self.config.kv_lora_rank,
+                self.block_size, self._act_dtype,
+                launch=(self.max_num_seqs + 1, self.nblk,
+                        self.blocks.num_blocks))
+            return "pallas" if why is None else f"xla-reference ({why})"
         why = _pa.ineligible(self._nh // self.tp, self._kvh // self.tp,
                              self._hd, self.block_size,
                              jnp.int8 if self.kv_dtype == "int8"
@@ -1137,6 +1191,8 @@ class LLMEngine:
                 "page": self.block_size, "nblk": self.nblk,
                 "dtype": self.kv_dtype},
         }
+        if self._latent:
+            shapes = {"fused_norms": shapes["fused_norms"]}
         if self.weight_dtype != "float32":
             # the decode-shaped MLP projection — the step's biggest
             # weight stream and the shape the sweep's llama-class
@@ -1176,6 +1232,12 @@ class LLMEngine:
         out["tokens_real"] = self.pad_stats["real"]
         out["tokens_padded"] = self.pad_stats["padded"]
         out["kv_pages_live"] = self.pad_stats["kv_pages"]
+        if self._has_experts:
+            # token-expert pairs the held experts computed / routed to
+            # any expert, held experts that got a token (summed over
+            # layers and steps), and the most tokens one held expert
+            # got in one layer of one step
+            out.update(self.moe_counts)
         out["paths"] = self.paths()
         out["tuning_cache"] = {
             "path": self._tuning_report["path"],
@@ -1199,16 +1261,26 @@ class LLMEngine:
                 "matmul": self.matmul_path,
                 "programs": dict(self.program_paths)}
 
+    def _pools(self) -> tuple:
+        """The page pools every program takes after the parameters and
+        gives back: K and V (over int8 pages their scale pools too), or
+        the one latent pool.  Each is [L, num_blocks, ...]."""
+        return tuple(x for x in (self._kc, self._vc, self._ks, self._vs)
+                     if x is not None)
+
+    def _set_pools(self, pools) -> None:
+        names = [n for n in ("_kc", "_vc", "_ks", "_vs")
+                 if getattr(self, n) is not None]
+        for n, x in zip(names, pools):
+            setattr(self, n, x)
+
     def kv_page_bytes(self) -> int:
-        """MESH-TOTAL device bytes one KV page costs: K and V slabs
-        across every layer, plus the page's scale-pool rows in int8
-        mode, summed over every tp shard."""
-        L = self.config.num_hidden_layers
-        per = (2 * L * self._kvh * self.block_size * self._hd
-               * np.dtype(self._kc.dtype).itemsize)
-        if self.kv_dtype == "int8":
-            per += 2 * L * self._kvh * np.dtype(np.float32).itemsize
-        return per
+        """MESH-TOTAL device bytes one KV page costs, by the pools' own
+        shapes: every pool's slab of one page across every layer (K and
+        V, plus the page's scale rows in int8 mode; or the latent rows),
+        summed over every tp shard."""
+        return sum(x.size // x.shape[1] * np.dtype(x.dtype).itemsize
+                   for x in self._pools())
 
     def kv_page_bytes_per_shard(self) -> int:
         """Bytes one KV page costs ON ONE CHIP.  Pools shard along the
@@ -1278,12 +1350,9 @@ class LLMEngine:
         (the fresh-page mask of the step programs is not among them).
         ``placed``: with the live arrays' shardings, so that lowering
         from the shapes gives the program the engine runs."""
-        pools = (self._kc, self._vc)
-        if self.kv_dtype == "int8":
-            pools += (self._ks, self._vs)
         return tuple(jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=x.sharding if placed else None)
-            for x in pools)
+            for x in self._pools())
 
     def _step_head_structs(self, placed: bool = False) -> tuple:
         """(params, pools..., fresh mask in int8 mode) as shapes: the
@@ -1334,7 +1403,9 @@ class LLMEngine:
         instruction ("fusion.199") and carries nothing of the
         ``jax.named_scope`` it ran under; the compiled module's text
         does (``op_name``).  This is the map between the two, for
-        whoever reads a device trace (``benchmark/harness/scopes.py``).
+        whoever reads a device trace (``benchmark/harness/scopes.py``;
+        which scope and kernel names an architecture's programs carry is
+        listed in its ``benchmark/shapes/<name>.py``).
         Built only when asked: each program is lowered from shapes and
         compiled again, which the persistent compilation cache turns
         into a read; nothing here runs in a serving step or in set-up."""
@@ -1590,7 +1661,8 @@ class LLMEngine:
                     batch_slots=batch_slots, dispatch_s=now - t0,
                     t_launch=now,
                     launch_ns=tr.now() if tr is not None else 0,
-                    step=self.launches, inflight=self.overlap)
+                    step=self.launches, inflight=self.overlap,
+                    counts=self._launch_counts)
         # prestage page credit expires: every reserved page is now
         # either owned by a row this dispatch packed (its ensure() saw
         # the page already in place) or was freed with its retired row
@@ -1706,10 +1778,17 @@ class LLMEngine:
             self._apply_ragged(chunks, spec, batch, sampled, ok, spec_ok,
                                spec_logits, chunk_slots, batch_slots,
                                dur, finished)
+        commit_args = {"step": sid, "finished": len(finished)}
+        if ticket.counts is not None:
+            mc = self.moe_counts
+            counted = dict(zip(mc, map(int, np.asarray(ticket.counts))))
+            for name, n in counted.items():
+                mc[name] = max(mc[name], n) if name == "moe_load_max" \
+                    else mc[name] + n
+            commit_args.update(counted)
         if tr is not None:
             tr.complete("engine.sample_commit", t,
-                        track=self._trace_track,
-                        args={"step": sid, "finished": len(finished)})
+                        track=self._trace_track, args=commit_args)
             tr.complete("engine.complete", t_c, track=self._trace_track,
                         args={"step": sid, "finished": len(finished)})
 
@@ -2668,26 +2747,19 @@ class LLMEngine:
     def _make_cow_fn(self):
         """(unjitted page-copy fn, intended donate_argnums) — the spec the
         analyzer sees; _apply_cow jits it (CPU drops donation: the CPU
-        runtime cannot alias and would warn every call).  In int8 mode
-        the copy carries the page's scale-pool rows along with its data
-        — the dst page is a live replica, so BlockManager excludes it
-        from the fresh-page scale reset."""
-        if self.kv_dtype == "int8":
-            def run(kc, vc, ks, vs, s, d):
-                kc = kc.at[:, d].set(kc[:, s])
-                vc = vc.at[:, d].set(vc[:, s])
-                ks = ks.at[:, d].set(ks[:, s])
-                vs = vs.at[:, d].set(vs[:, s])
-                return kc, vc, ks, vs
+        runtime cannot alias and would warn every call).  It copies page
+        s to page d in every pool the engine has, whatever their shapes
+        (each is [L, num_blocks, ...]): K and V; in int8 mode the page's
+        scale-pool rows along with its data — the dst page is a live
+        replica, so BlockManager excludes it from the fresh-page scale
+        reset; for a latent model the one pool of cached rows."""
+        n = len(self._pools())
 
-            return run, (0, 1, 2, 3)
+        def run(*args):
+            s, d = args[n:]
+            return tuple(x.at[:, d].set(x[:, s]) for x in args[:n])
 
-        def run(kc, vc, s, d):
-            kc = kc.at[:, d].set(kc[:, s])
-            vc = vc.at[:, d].set(vc[:, s])
-            return kc, vc
-
-        return run, (0, 1)
+        return run, tuple(range(n))
 
     def _apply_cow(self, src: int, dst: int) -> None:
         """Copy page src -> dst across every layer's K and V cache.  The
@@ -2703,13 +2775,8 @@ class LLMEngine:
             self._cow_prog = jax.jit(_named(run, "kv_cow"),
                                      donate_argnums=donate)
             self._program_built("cow", "kv_cow")
-        if self.kv_dtype == "int8":
-            self._kc, self._vc, self._ks, self._vs = self._cow_prog(
-                self._kc, self._vc, self._ks, self._vs,
-                np.int32(src), np.int32(dst))
-        else:
-            self._kc, self._vc = self._cow_prog(
-                self._kc, self._vc, np.int32(src), np.int32(dst))
+        self._set_pools(self._cow_prog(*self._pools(), np.int32(src),
+                                       np.int32(dst)))
         if tr is not None:
             # no span of its own: a nested one would leave
             # engine.schedule's self time; the schedule span reports the
@@ -2772,92 +2839,75 @@ class LLMEngine:
         max_num_seqs ragged rows.  A prefill chunk, a resumed chunk, a
         decode token, and a k-draft verify window are all rows of the
         same launch, differing only in query length — each layer writes
-        the packed tokens' K/V into the paged cache at their absolute
-        positions, then ragged paged attention lets every token attend
-        to its own row's pages causally.  Sampled tokens come back for
-        the logit rows in ``lidx``; with a drafter the raw [Lq, V]
-        logits ride along for host-side draft acceptance."""
-        nh, kvh, d = self._nh, self._kvh, self._hd
-        bs = self.block_size
-        B = self.max_num_seqs
-        with_logits = self._with_logits
-        eps = self.config.rms_norm_eps
-        theta = self.config.rope_theta
+        the packed tokens' K/V (or latent rows) into the paged cache at
+        their absolute positions, then ragged paged attention lets every
+        token attend to its own row's pages causally.  Sampled tokens
+        come back for the logit rows in ``lidx``; with a drafter the raw
+        [Lq, V] logits ride along for host-side draft acceptance; a
+        model with expert layers also returns what they counted.
+
+        The layers are ``layer_stack`` of the model's layer kinds
+        (inference/layer_stack.py): the dense decoder is one scanned
+        segment over its stacked weights with the K and V pools sliced a
+        layer at a time; a latent-attention model runs layer after layer
+        over its own arrays and one pool."""
+        cfg = self.config
         if self.kv_dtype == "int8":
             return self._make_ragged_fn_q8(Tq)
         # under tp the body runs on PER-SHARD shapes: a contiguous block
         # of nh/tp query heads attending over kvh/tp KV heads (GQA
         # groups never straddle shards — tp divides kvh)
         tp = self.tp
-        nh, kvh = nh // tp, kvh // tp
+        with_logits = self._with_logits
         shard_head = self._shard_head
         mm, embed, head_logits = self._weight_ops()
-        use_pallas = self.attention_path.startswith("pallas")
+        n_pools = len(self._pools())
+        kinds, latent = self._layer_kinds, self._latent
+        eps = cfg.rms_norm_eps
+        # (``run`` below must not close over ``self``: a compiled program
+        # that holds its engine keeps it alive past its last user)
+        shared = dict(
+            Tq=Tq, bs=self.block_size, tp=tp, mm=mm,
+            eps=eps, use_pallas=self.attention_path.startswith("pallas"))
+        if latent:
+            from ..models.mla_moe import softmax_scale, yarn_inv_freq
+            shared.update(cfg=cfg, inv_freq=yarn_inv_freq(cfg),
+                          sm_scale=softmax_scale(cfg))
+        else:
+            shared.update(nh=self._nh // tp, kvh=self._kvh // tp,
+                          d=self._hd, theta=cfg.rope_theta)
 
-        def run(params, kc, vc, toks, cu, kvl, bt, lidx, samp):
-            # toks [Tq] i32, rows packed back-to-back (tail padding maps
-            # to the sentinel row); cu [B+1] i32 row offsets; kvl [B] i32
-            # valid KV per row AFTER this launch's writes; bt [B+1, nblk]
-            # i32 (row B: the null row pads resolve to); lidx [Lq] i32
-            # flat index of each logit row; samp the make_samp pytree,
-            # one row per logit row.  Under tp>1 this traces per shard:
-            # kc/vc and the q/k/v projections arrive head-sliced, toks..
-            # samp arrive replicated.
-            # the jax.named_scope names below are what a device trace
-            # is read by (docs/observability.md): keep them, and keep
-            # them the same in all four step builders
+        def run(params, *rest):
+            # rest: the page pools, then toks [Tq] i32, rows packed
+            # back-to-back (tail padding maps to the sentinel row); cu
+            # [B+1] i32 row offsets; kvl [B] i32 valid KV per row AFTER
+            # this launch's writes; bt [B+1, nblk] i32 (row B: the null
+            # row pads resolve to); lidx [Lq] i32 flat index of each
+            # logit row; samp the make_samp pytree, one row per logit
+            # row.  Under tp>1 this traces per shard: the pools and the
+            # q/k/v projections arrive head-sliced, toks..samp arrive
+            # replicated.
+            # the jax.named_scope names here and in layer_stack are what
+            # a device trace is read by (docs/observability.md): keep
+            # them, and keep them the same in all step builders
+            pools = rest[:n_pools]
+            toks, cu, kvl, bt, lidx, samp = rest[n_pools:]
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
             with jax.named_scope("embed"):
                 x = embed(params, toks)                       # [Tq, H]
-
-            def body(x, inp):
-                p, kcl, vcl = inp
-                with jax.named_scope("norm"):
-                    h = _rms_weight(x, p["ln1"], eps)
-                with jax.named_scope("qkv"):
-                    q = mm(h, p, "wq").reshape(Tq, nh, d)
-                    k = mm(h, p, "wk").reshape(Tq, kvh, d)
-                    v = mm(h, p, "wv").reshape(Tq, kvh, d)
-                with jax.named_scope("rope"):
-                    q = _rope_positions(q, rel, theta)
-                    k = _rope_positions(k, rel, theta)
-                with jax.named_scope("kv_write"):
-                    blk = bt[seg, rel // bs]                  # [Tq]
-                    slot = rel % bs
-                    kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
-                    vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
-                with jax.named_scope("attn"):
-                    if use_pallas:
-                        # the host packing path owns these buffers: bt is
-                        # the int32 NULL_BLOCK-padded pool table and cu,
-                        # kvl come int32 from the step's packing, so the
-                        # packed entry skips the per-launch re-clip and
-                        # re-cast.  The kernel reads the row layout
-                        # itself; seg/rel are for rope and kv_write
-                        att = _pa.ragged_paged_attention_packed(
-                            q, kcl, vcl, bt, cu, kvl)
-                    else:
-                        att = _pa.ragged_paged_reference_segrel(
-                            q, kcl, vcl, bt, seg, rel)
-                    if tp > 1:
-                        # tiled gather concatenates shard head blocks in
-                        # mesh order — exactly the tp=1 head layout, so
-                        # the replicated wo matmul is byte-identical
-                        att = lax.all_gather(att, "tp", axis=1,
-                                             tiled=True)
-                with jax.named_scope("o_proj"):
-                    x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-                with jax.named_scope("norm"):
-                    h2 = _rms_weight(x, p["ln2"], eps)
-                with jax.named_scope("mlp"):
-                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                    ).astype(h2.dtype) * mm(h2, p, "up")
-                    x = x + mm(a, p, "down")
-                return x, (kcl, vcl)
-
+            c = _ls.step_context(seg=seg, rel=rel, bt=bt, cu=cu, kvl=kvl,
+                                 **shared)
+            if latent:
+                # runs of layers of one kind, each over its own arrays
+                segments = []
+                for i, k in enumerate(kinds):
+                    if not segments or segments[-1][0] != k:
+                        segments.append((k, [], False))
+                    segments[-1][1].append((i, params["layers"][i]))
+            else:
+                segments = [(kinds[0], params["layers"], True)]
             with jax.named_scope("layers"):
-                x, (kc, vc) = _scan_layers(body, x, params["layers"],
-                                           (kc, vc))
+                x, pools, counts = _ls.layer_stack(x, segments, pools, c)
             with jax.named_scope("norm"):
                 h = _rms_weight(x, params["norm_f"], eps)
             with jax.named_scope("head"):
@@ -2875,13 +2925,16 @@ class LLMEngine:
                 # (padded rows may be legitimately non-finite; the host
                 # only consults live slots)
                 fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [Lq]
+            out = (sampled, fin)
             if with_logits:
-                return sampled, fin, logits, kc, vc
-            return sampled, fin, kc, vc
+                out += (logits,)
+            if counts is not None:
+                out += (counts,)
+            return out + tuple(pools)
 
         # donation reuses the pool buffers in place; _get_ragged_prog
         # drops it on CPU (that runtime cannot alias and warns per call)
-        return self._wrap_tp(run, 6), (1, 2)
+        return self._wrap_tp(run, 6), tuple(range(1, 1 + n_pools))
 
     def _make_ragged_fn_q8(self, Tq: int):
         """Int8-page variant of the one serving step program: identical
@@ -3053,19 +3106,18 @@ class LLMEngine:
         self.pad_stats["padded"] += int(Tq)
         self.pad_stats["kv_pages"] += self._kv_pages(kvl)
         prog = self._get_ragged_prog(Tq)
-        tail = (toks, cu, kvl, bt, lidx, samp)
+        pools = self._pools()
+        head = (self.params,) + pools
         if self.kv_dtype == "int8":
-            out = self._call_program(
-                prog, (self.params, self._kc, self._vc, self._ks,
-                       self._vs, self._consume_fresh()) + tail, Tq)
-            self._kc, self._vc, self._ks, self._vs = out[-4:]
-            out = out[:-4]
-        else:
-            out = self._call_program(
-                prog, (self.params, self._kc, self._vc) + tail, Tq)
-            self._kc, self._vc = out[-2:]
-            out = out[:-2]
+            head += (self._consume_fresh(),)
+        out = self._call_program(
+            prog, head + (toks, cu, kvl, bt, lidx, samp), Tq)
+        self._set_pools(out[-len(pools):])
+        out = out[:-len(pools)]
         sampled, fin = out[0], out[1]
+        # what the step's expert layers counted rides to the completion
+        # half unmaterialized, like the tokens
+        self._launch_counts = out[2] if self._has_experts else None
         return sampled, (out[2] if self._with_logits else None), fin
 
     def _kv_pages(self, kvl) -> int:

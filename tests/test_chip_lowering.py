@@ -205,3 +205,57 @@ def test_prefetched_operands_are_what_the_claim_counts(one_chip,
     assert [a.shape for a in ops[3:]] == [(NUM_BLOCKS, kvh)] * 2
     assert pa.scalar_prefetch_bytes(ROWS + 1, NBLK, NUM_BLOCKS, kvh, True) \
         == 2 * 128 * 4 + sum(tiled(a) for a in ops[2:])
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention model's kernels (PR 28), at sarvam-105b-ep4's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tq", [32, 576])
+def test_the_latent_kernel_compiles_for_the_v5e(one_chip, compiled_kernels,
+                                                no_persistent_cache, tq):
+    """64 heads on one 640-wide stored row (576 cached numbers), the pool
+    of all six layers left in HBM and read at a layer index, the
+    [33, 1024] table of 16,384-token rows.  A pool declared 576 wide is
+    what the chip's compiler refused (its rows are stored 640 wide, and a
+    copy of whole rows has to say so)."""
+    from paddle_tpu.ops.pallas import mla_attention as mla
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert mla.ineligible(64, 640, 512, 16, jnp.bfloat16,
+                          launch=(33, 1024, 16385)) is None
+    args = (sds((tq, 64, 576), jnp.bfloat16),
+            sds((6, 16385, 16, 640), jnp.bfloat16),
+            sds((33, 1024), jnp.int32), sds((33,), jnp.int32),
+            sds((32,), jnp.int32))
+    text = jax.jit(lambda q, pool, bt, cu, kvl:
+                   mla.ragged_latent_attention_packed(
+                       q, pool, 3, bt, cu, kvl, latent_dim=512,
+                       sm_scale=0.1)).lower(*args).compile().as_text()
+    assert "ragged_latent_attention" in text
+    assert re.search(r"bf16\[\d+,512\][^\n]* custom-call\(", text)
+
+
+@pytest.mark.parametrize("rows", [256, 4608])
+def test_the_grouped_expert_kernel_compiles_for_the_v5e(
+        one_chip, compiled_kernels, no_persistent_cache, rows):
+    """32 experts of 4096 x 2048: both halves of an expert's SwiGLU, at a
+    decode-sized and at a 576-token step's pairs."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    sizes = sds((32,), jnp.int32)
+    up = jax.jit(lambda x, g, u, s: gm.grouped_swiglu(
+        x, g, u, s, use_kernel=True)).lower(
+            sds((rows, 4096), bf), sds((32, 4096, 2048), bf),
+            sds((32, 4096, 2048), bf), sizes).compile().as_text()
+    down = jax.jit(lambda a, w, s: gm.grouped_matmul(
+        a, w, s, use_kernel=True)).lower(
+            sds((rows, 2048), bf), sds((32, 2048, 4096), bf),
+            sizes).compile().as_text()
+    assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down

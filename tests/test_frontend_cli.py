@@ -133,3 +133,30 @@ def test_cli_second_sigint_aborts_inflight():
         assert got["finish"] in ("shutdown", None), got
     finally:
         srv.kill()
+
+
+def test_cli_serves_the_latent_attention_preset():
+    """``--model mla-moe-sm``: a latent-attention decoder with expert
+    layers through the same CLI, engine and HTTP path; the start-up line
+    names its paths and a completion streams to its length."""
+    srv = _Server("--model", "mla-moe-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4", "--max-prefill-tokens", "32")
+    try:
+        port = srv.port()
+        assert "attention='xla-reference (cpu platform)'" in srv.output()
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        body = json.dumps({"prompt": list(range(1, 70)),
+                           "max_tokens": 6}).encode()
+        conn.request("POST", "/v1/completions", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+        conn.close()
+        assert resp.status == 200
+        choice = doc["choices"][0]
+        assert choice["finish_reason"] == "length"
+        assert len(choice["token_ids"]) == 6
+        srv.proc.send_signal(signal.SIGINT)
+        assert srv.proc.wait(timeout=60) == 0
+    finally:
+        srv.kill()

@@ -44,6 +44,8 @@ def _lint_fix(name):
     ("fix_unkeyed_jit.py", "unkeyed-jit", 6, "call", ERROR),
     (os.path.join("inference", "fix_attention_budget.py"),
      "attention-program-budget", 18, "decode_step", ERROR),
+    (os.path.join("inference", "fix_attention_budget_kinds.py"),
+     "attention-program-budget", 17, "decode_attention_step", ERROR),
     (os.path.join("inference", "fix_quantized_kv.py"),
      "quantized-kv-float32-page", 10, "build_pools", WARNING),
     (os.path.join("inference", "fix_weight_matmul.py"),
@@ -93,6 +95,10 @@ def test_serving_engine_within_attention_program_budget():
                                       "serving.py"), root=_REPO)
     assert [f for f in findings
             if f.rule == "attention-program-budget"] == []
+    # the budget is by the layers' attention kinds, which the engine
+    # declares as a literal the rule can read
+    from paddle_tpu.inference import layer_stack, serving
+    assert serving.ATTENTION_KINDS == tuple(layer_stack.ATTENTION)
     assert [f for f in findings
             if f.rule == "quantized-kv-float32-page"] == []
     assert [f for f in findings
@@ -390,7 +396,7 @@ def test_cli_nonzero_on_fixture_tree_json():
     r = _run_cli(_FIX, "--format", "json", "--no-default-baseline")
     assert r.returncode == 1, r.stdout + r.stderr
     doc = json.loads(r.stdout)
-    assert doc["counts"]["ERROR"] == 7          # one per ERROR fixture
+    assert doc["counts"]["ERROR"] == 8          # one per ERROR fixture
     rules = {f["rule"] for f in doc["findings"]}
     assert {"numpy-in-jit", "host-sync-in-jit", "tracer-branch",
             "unkeyed-jit", "attention-program-budget",

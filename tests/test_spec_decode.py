@@ -5,6 +5,8 @@ rollbacks, preemptions, and the sampling LogitProcessor chain."""
 import numpy as np
 import pytest
 
+import paddle_tpu
+
 import jax.numpy as jnp
 
 from paddle_tpu.inference import (BlockManager, DraftModelDrafter,
@@ -20,6 +22,10 @@ CFG = LlamaConfig.tiny(vocab=VOCAB, hidden=32, layers=2, heads=4, ffn=64,
 
 @pytest.fixture(scope="module")
 def model():
+    # the weights these tests were written against: the draw depends on
+    # the process's RNG state, which under several workers is whatever
+    # the worker's earlier files left
+    paddle_tpu.seed(0)
     return LlamaForCausalLM(CFG)
 
 

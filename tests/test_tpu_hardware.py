@@ -299,6 +299,78 @@ def test_ragged_kernel_on_tpu(H, Hkv, dtype, tol):
     assert err < tol, err
 
 
+def test_latent_kernel_on_tpu():
+    """The latent-attention kernel at sarvam-105b-ep4's sizes (64 heads, a
+    576-number row stored 640 wide, six layers in one pool, block 16):
+    a 300-token chunk resumed at 1,000 cached rows, decode rows and a
+    row of no keys, against the dense-gather oracle in float32."""
+    from paddle_tpu.ops.pallas import mla_attention as MLA
+
+    G, row, dc, bs, nblk, L, nb = 64, 576, 512, 16, 96, 6, 600
+    rng = np.random.RandomState(7)
+    rows = [(300, 1300), (1, 1), (0, 0), (1, 777), (1, 1536), (5, 40)]
+    Tq = 320
+    cu = np.zeros(33, np.int32)
+    kvl = np.zeros(32, np.int32)
+    bt = np.zeros((33, nblk), np.int32)
+    free = iter(rng.permutation(np.arange(1, nb)))
+    for r, (n, k) in enumerate(rows):
+        cu[r + 1] = cu[r] + n
+        kvl[r] = k
+        for p in range(-(-k // bs)):
+            bt[r, p] = next(free)
+    cu[len(rows) + 1:] = cu[len(rows)]
+    live = int(cu[len(rows)])
+    q = jnp.asarray(rng.randn(Tq, G, row), jnp.bfloat16)
+    pool = jnp.asarray(rng.randn(L, nb, bs, MLA.page_width(row)) * 0.5,
+                       jnp.bfloat16).at[..., row:].set(0)
+    args = (jnp.asarray(bt), jnp.asarray(cu), jnp.asarray(kvl))
+    out = jax.jit(lambda q, pool, bt, cu, kvl:
+                  MLA.ragged_latent_attention_packed(
+                      q, pool, 4, bt, cu, kvl, latent_dim=dc,
+                      sm_scale=0.05))(q, pool, *args)
+    with jax.default_matmul_precision("highest"):
+        ref = MLA.mla_ragged_reference(
+            q.astype(jnp.float32), pool[4].astype(jnp.float32), *args,
+            latent_dim=dc, sm_scale=0.05)
+    err = _max_err(out, ref, live)
+    print(f"latent kernel: max abs err {err:.3e} over {live} tokens")
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert not bool(jnp.any(out[live:]))        # padding reads zero
+    # scores in f32 from exact bf16 products, probabilities and the
+    # output rounded to bf16 (2^-9 relative, on values of order 1)
+    assert err < 2e-2, err
+
+
+def test_grouped_expert_kernel_on_tpu():
+    """32 experts of 4096 x 2048, 4,608 sorted rows of which about a
+    quarter are live, one expert empty and one over a row tile: the
+    kernel against lax.ragged_dot, both halves of the SwiGLU."""
+    from paddle_tpu.ops.pallas import grouped_matmul as GM
+
+    rng = np.random.RandomState(3)
+    sizes = rng.multinomial(1100, np.ones(32) / 32).astype(np.int32)
+    sizes[5], sizes[9] = 0, 150
+    n = int(sizes.sum())
+    x = jnp.asarray(rng.randn(4608, 4096), jnp.bfloat16)
+    wg = jnp.asarray(rng.randn(32, 4096, 2048) * 0.02, jnp.bfloat16)
+    wu = jnp.asarray(rng.randn(32, 4096, 2048) * 0.02, jnp.bfloat16)
+    wd = jnp.asarray(rng.randn(32, 2048, 4096) * 0.02, jnp.bfloat16)
+    gs = jnp.asarray(sizes)
+    for use_kernel in (True, False):
+        a = jax.jit(lambda *t: GM.grouped_swiglu(
+            *t, use_kernel=use_kernel))(x, wg, wu, gs)
+        y = jax.jit(lambda *t: GM.grouped_matmul(
+            *t, use_kernel=use_kernel))(a, wd, gs)
+        if use_kernel:
+            got = (a[:n].astype(jnp.float32), y[:n])
+    err_a = float(jnp.max(jnp.abs(got[0] - a[:n].astype(jnp.float32))))
+    err_y = float(jnp.max(jnp.abs(got[1] - y[:n])))
+    print(f"grouped kernel against ragged_dot: swiglu {err_a:.3e}, "
+          f"down {err_y:.3e}")
+    assert err_a < 2e-2 and err_y < 5e-2, (err_a, err_y)
+
+
 def _int8_page_kernel_err(H, Hkv, pool=None):
     """The int8-page kernel against its reference on the smoke's case,
     over a pool of ``pool`` pages (default: the pages the case uses)."""
