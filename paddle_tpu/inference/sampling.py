@@ -31,6 +31,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = [
     "LogitProcessor", "RepetitionPenaltyProcessor", "TemperatureProcessor",
@@ -160,22 +161,28 @@ def sample_tokens(logits, samp, chain=DEFAULT_CHAIN):
 
     Greedy rows (temps <= 0) argmax after the greedy-visible stages —
     byte-compatible with generate()'s greedy branch — while sampled rows
-    run the full chain into a per-row categorical draw.
+    run the full chain into a per-row categorical draw.  The sampled
+    tail (sorts and gathers over the whole [B, V]) sits in one branch of
+    a ``lax.cond`` on "some row of this launch is sampled": a scalar
+    predicate is a real conditional on the device, so an all-greedy
+    launch executes the argmax and nothing after it.
     """
     lg = logits.astype(jnp.float32)
     for proc in chain:
         if proc.greedy_visible:
             lg = proc(lg, samp, jnp)
     greedy_tok = jnp.argmax(lg, -1).astype(jnp.int32)
-    for proc in chain:
-        if not proc.greedy_visible:
-            lg = proc(lg, samp, jnp)
 
-    def one(key, row):
-        return jax.random.categorical(key, row)
+    def sampled_tail(lg):
+        for proc in chain:
+            if not proc.greedy_visible:
+                lg = proc(lg, samp, jnp)
+        sampled = jax.vmap(jax.random.categorical)(
+            samp["keys"], lg).astype(jnp.int32)
+        return jnp.where(samp["temps"] <= 0.0, greedy_tok, sampled)
 
-    sampled = jax.vmap(one)(samp["keys"], lg).astype(jnp.int32)
-    return jnp.where(samp["temps"] <= 0.0, greedy_tok, sampled)
+    return lax.cond(jnp.any(samp["temps"] > 0.0), sampled_tail,
+                    lambda _: greedy_tok, lg)
 
 
 def target_dist(logits_row, *, temperature=0.0, top_k=0, top_p=1.0,

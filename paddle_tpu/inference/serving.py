@@ -171,6 +171,8 @@ class _StepTicket:
                                       # per-step; sampled/fin are [K, B])
     counts: object = None             # device array | None: what the
                                       # launch's expert layers counted
+    sample_chain: int = 0             # 1 if a window launch held a sampled
+                                      # row (its drain counts the passes)
 
 
 class _DecodeBufs:
@@ -202,6 +204,12 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _sample_chain(samp) -> int:
+    """1 if a launch's host-side ``samp`` holds a sampled row: the
+    predicate ``sampling.sample_tokens`` branches on, on the device."""
+    return int((samp["temps"] > 0.0).any())
 
 
 def _named(fn, name: str):
@@ -643,6 +651,10 @@ class LLMEngine:
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
                           "kv_pages": 0}
+        # passes of the sampling epilogue, and those whose launch held a
+        # sampled row (temps > 0): the device runs the sampled chain in
+        # exactly those (sampling.sample_tokens branches on the same)
+        self.sample_stats = {"launches": 0, "chain_launches": 0}
         # launches dispatched so far: the STEP ID every trace event
         # carries (the dispatch half of a step the id of the launch it
         # prepares, launches + 1; the completion half its ticket's)
@@ -1232,6 +1244,8 @@ class LLMEngine:
         out["tokens_real"] = self.pad_stats["real"]
         out["tokens_padded"] = self.pad_stats["padded"]
         out["kv_pages_live"] = self.pad_stats["kv_pages"]
+        out["sample_launches"] = self.sample_stats["launches"]
+        out["sample_chain_launches"] = self.sample_stats["chain_launches"]
         if self._has_experts:
             # token-expert pairs the held experts computed / routed to
             # any expert, held experts that got a token (summed over
@@ -1773,7 +1787,8 @@ class LLMEngine:
             t = tr.now()
         if ticket.window:
             self._apply_window(batch, batch_slots, sampled, ok, dur,
-                               finished, ticket.window)
+                               finished, ticket.window,
+                               ticket.sample_chain)
         else:
             self._apply_ragged(chunks, spec, batch, sampled, ok, spec_ok,
                                spec_logits, chunk_slots, batch_slots,
@@ -2137,6 +2152,7 @@ class LLMEngine:
         self._break_decode_layout()
         if tr is not None:
             t = tr.now()
+        chain = _sample_chain(samp)
         toks_out, fin_out = self._launch_window(
             toks, kvl, active, gen, budgets, eos_ids, base_keys, bt, samp)
         if tr is not None:
@@ -2144,18 +2160,20 @@ class LLMEngine:
                         track=self._trace_track,
                         args={"step": sid, "bucket": B, "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
-                              "logit_rows": n, "window": kp})
+                              "logit_rows": n, "window": kp,
+                              "sample_chain": chain})
         now = time.perf_counter()
         self._inflight = _StepTicket(
             chunks=[], spec=[], batch=list(batch), sampled=toks_out,
             logits=None, fin=fin_out, spec_slices=[], chunk_slots=[],
             batch_slots=list(range(n)), dispatch_s=now - t0,
             t_launch=now, launch_ns=tr.now() if tr is not None else 0,
-            step=self.launches, inflight=self.overlap, window=kp)
+            step=self.launches, inflight=self.overlap, window=kp,
+            sample_chain=chain)
         return True
 
     def _apply_window(self, batch, batch_slots, sampled, ok, dur,
-                      finished, window):
+                      finished, window, sample_chain):
         """Drain one completed K-step window: ONE materialized [K, B]
         token (and finiteness) grid commits as up to K per-token steps
         per row, in iteration-major order — the exact per-token sequence
@@ -2203,6 +2221,10 @@ class LLMEngine:
         self.pad_stats["real"] += committed
         self.pad_stats["padded"] += iters * self.max_num_seqs
         self.pad_stats["legacy_padded"] += iters * self.max_num_seqs
+        # one pass of the epilogue an iteration; temps ride the whole
+        # window, so every pass takes the branch the launch's samp named
+        self.sample_stats["launches"] += iters
+        self.sample_stats["chain_launches"] += iters * sample_chain
         if committed:
             self.stats.record_decode(dur, committed, occ, rounds=iters)
         self.stats.set_decode_window(K)
@@ -3105,6 +3127,8 @@ class LLMEngine:
         self.pad_stats["real"] += int(real_tokens)
         self.pad_stats["padded"] += int(Tq)
         self.pad_stats["kv_pages"] += self._kv_pages(kvl)
+        self.sample_stats["launches"] += 1
+        self.sample_stats["chain_launches"] += _sample_chain(samp)
         prog = self._get_ragged_prog(Tq)
         pools = self._pools()
         head = (self.params,) + pools
@@ -3575,7 +3599,8 @@ class LLMEngine:
                               "tokens": total, "rows": len(rows),
                               "chunks": len(chunks), "decode": len(batch),
                               "logit_rows": logit_rows,
-                              "kv_pages": self._kv_pages(kvl)})
+                              "kv_pages": self._kv_pages(kvl),
+                              "sample_chain": _sample_chain(samp)})
         # NO materialization here: sampled/logits/fin return as async
         # device arrays; _complete blocks on them (the dispatch path
         # must never force a host sync on step-program outputs)
@@ -3671,7 +3696,8 @@ class LLMEngine:
                         args={"step": sid, "bucket": int(Tq), "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n,
-                              "kv_pages": self._kv_pages(buf.kvl)})
+                              "kv_pages": self._kv_pages(buf.kvl),
+                              "sample_chain": _sample_chain(samp)})
         self._d_cur = bi
         return sampled, None, fin, [], [], list(range(n))
 

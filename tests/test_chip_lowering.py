@@ -259,3 +259,114 @@ def test_the_grouped_expert_kernel_compiles_for_the_v5e(
             sds((rows, 2048), bf), sds((32, 2048, 4096), bf),
             sizes).compile().as_text()
     assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down
+
+
+# ---------------------------------------------------------------------------
+# the sampling epilogue's branch (PR 29), in a whole ragged step program
+# ---------------------------------------------------------------------------
+
+_CALLED = re.compile(r"\b(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+
+
+def _computations(text: str):
+    """(entry name, {computation: its instruction lines})."""
+    from paddle_tpu.inference.serving import _HLO_COMPUTATION
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            cur = m.group(1)
+            comps[cur] = []
+            if line.startswith("ENTRY"):
+                entry = cur
+        elif cur is not None and " = " in line:
+            comps[cur].append(line)
+    return entry, comps
+
+
+def _branches(line: str) -> list:
+    m = _BRANCHES.search(line)
+    names = m.group(1).split(",") if m else re.findall(
+        r"\b(?:true|false)_computation=%?([\w.\-]+)", line)
+    return [n.strip().lstrip("%") for n in names]
+
+
+def _reached(comps: dict, roots, *, through_conditionals: bool) -> set:
+    """Computations reached from ``roots``; a conditional's branches are
+    followed only where asked."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            if " conditional(" in line:
+                if through_conditionals:
+                    todo += _branches(line)
+            else:
+                todo += _CALLED.findall(line)
+    return seen
+
+
+def _tiny_engine(arch: str):
+    from paddle_tpu.inference import LLMEngine
+    if arch == "llama_dense":
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            vocab=1000, hidden=128, layers=2, heads=4, ffn=256, seq=64))
+    else:
+        from paddle_tpu.models.mla_moe import (MlaMoeConfig,
+                                               MlaMoeForCausalLM)
+        model = MlaMoeForCausalLM(MlaMoeConfig.tiny(
+            vocab=1000, hidden=128, layers=3, heads=4, experts=8, seq=64))
+    return LLMEngine(model, max_num_seqs=4, block_size=8, max_model_len=64,
+                     max_prefill_tokens=32, prefill_token_bucket=16)
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+@pytest.mark.parametrize("arch", ["llama_dense", "mla_moe"])
+def test_the_sampled_chain_compiles_into_one_branch(
+        arch, target, request, no_persistent_cache):
+    """In the compiled text of a ragged step program the sampler's sorts
+    (the ones over the vocabulary) lie in computations that only a
+    conditional's branch reaches: the entry computation and the layer
+    loop hold none, so an all-greedy launch executes none.  What runs in
+    either branch still carries scope ``sample``, which is how
+    ``program_scopes()`` files a traced operation under the sampler."""
+    from paddle_tpu.inference.serving import _instruction_scopes
+    V, Tq = 1000, 16
+    eng = _tiny_engine(arch)
+    structs = eng._ragged_arg_structs(Tq, placed=target == "cpu")
+    if target == "v5e":
+        chip = request.getfixturevalue("one_chip")
+        structs = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+            structs)
+    text = eng._get_ragged_prog(Tq).lower(*structs).compile().as_text()
+    entry, comps = _computations(text)
+    conds = [l for ls in comps.values() for l in ls if " conditional(" in l]
+    assert len(conds) == 1 and "/sample/cond" in conds[0]
+    outside = _reached(comps, [entry], through_conditionals=False)
+    inside = _reached(comps, _branches(conds[0]),
+                      through_conditionals=True)
+    assert entry in outside and not outside & inside
+
+    def vocab_sorts(names):
+        return [l for c in names for l in comps[c]
+                if " sort(" in l and f",{V}]" in l.split(" sort(")[0]]
+
+    assert not vocab_sorts(outside)
+    assert len(vocab_sorts(inside)) == 3      # top-k's, top-p's two
+    # both branches' instructions are the sampler's, by their op_name
+    # (a scalar comparator or reducer carries a bare primitive's name,
+    # and what XLA made up carries none: neither is a traced operation)
+    scopes = _instruction_scopes(text)
+    named = [scopes[m.group(1)]["op_name"] for c in inside
+             for l in comps[c]
+             for m in [re.match(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = ", l)]
+             if m and m.group(1) in scopes]
+    paths = [n for n in named if n.startswith("jit(")]
+    assert len(paths) >= 10
+    assert all("/sample/cond/" in n for n in paths)

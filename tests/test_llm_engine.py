@@ -254,6 +254,29 @@ def test_engine_sampling_deterministic_per_seed(model):
     assert first == run_once(3)
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_summary_counts_launches_that_ran_the_sampled_chain(model,
+                                                            temperature):
+    """``sample_chain_launches`` of ``sample_launches``: the launches
+    whose rows held a request with a temperature, which are the ones in
+    which the step program runs its top-k, top-p and draw.  A greedy run
+    has none; greedy tokens are the oracle's either way."""
+    rng = np.random.RandomState(17)
+    greedy_p = rng.randint(0, VOCAB, 9).tolist()
+    eng = _engine(model)
+    g = eng.add_request(greedy_p, max_new_tokens=10)
+    eng.add_request(rng.randint(0, VOCAB, 6).tolist(), max_new_tokens=4,
+                    temperature=temperature, seed=2)
+    outs = eng.run()
+    s = eng.summary()
+    assert s["sample_launches"] == eng.launches > 0
+    if temperature > 0.0:
+        assert 4 <= s["sample_chain_launches"] < s["sample_launches"]
+    else:
+        assert s["sample_chain_launches"] == 0
+    assert outs[g].generated == _oracle(model, greedy_p, 10)
+
+
 def test_engine_rejects_oversized_request(model):
     eng = _engine(model)
     with pytest.raises(ValueError):

@@ -434,6 +434,39 @@ def test_summary_counts_real_and_padded_tokens(model):
     assert len(built) == sum(eng.compile_counts.values()) == 2
 
 
+@pytest.mark.parametrize("window", [1, 4])
+@pytest.mark.parametrize("sampled", [False, True])
+def test_the_launch_says_which_branch_the_sampler_took(model, sampled,
+                                                       window):
+    """``sample_chain`` on ``engine.device_launch`` is 1 where the
+    launch's rows hold a sampled request (the predicate the step
+    program branches on, read from the same host array), and the
+    summary counts the passes of the epilogue on both sides."""
+    tr = Tracer()
+    eng = _engine(model, tracer=tr, decode_window=window)
+    eng.add_request(list(range(1, 12)), max_new_tokens=6)
+    eng.add_request(list(range(3, 9)), max_new_tokens=3,
+                    temperature=0.8 if sampled else 0.0, seed=5)
+    eng.run()
+    dl = [x["args"] for x in _spans(tr)
+          if x["name"] == "engine.device_launch"]
+    assert all(a["sample_chain"] in (0, 1) for a in dl)
+    s = eng.summary()
+    assert s["sample_launches"] >= len(dl) > 0
+    if window == 1:
+        assert s["sample_launches"] == len(dl)
+        assert s["sample_chain_launches"] \
+            == sum(a["sample_chain"] for a in dl)
+    if not sampled:
+        assert s["sample_chain_launches"] == 0
+        assert not any(a["sample_chain"] for a in dl)
+    else:
+        # from its first chunk to its last token, and not after it has
+        # gone: the other request decodes on alone, greedy
+        assert 0 < s["sample_chain_launches"] < s["sample_launches"]
+        assert dl[0]["sample_chain"] == 1 and dl[-1]["sample_chain"] == 0
+
+
 # ---------------------------------------------------------------------------
 # the watcher does not change what is watched
 # ---------------------------------------------------------------------------
