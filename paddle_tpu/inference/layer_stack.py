@@ -1,26 +1,30 @@
-"""The layers of a serving step as ONE function of what each layer is.
+"""The forward of a serving step as ONE function of what each layer is.
 
 A step program embeds its flat tokens, runs the model's layers and
-scores the logit rows.  ``layer_stack`` is the middle of that for every
-model the engine serves: each layer is (attention kind, FFN kind), and
-the function is told how each kind computes, reads the paged cache and
-writes it.  A model whose layers are all alike with their weights stacked
-(the dense decoder) runs as one ``lax.scan`` over the stack; a model
+scores the logit rows: ``forward``, for every model the engine serves,
+every page type and both drivers (the ragged step and the decode
+window's loop, ``inference/serving.py``).  ``layer_stack`` is the middle
+of it: each layer is (attention kind, FFN kind), and the function is
+told how each kind computes, reads the paged cache and writes it.  A
+model whose layers are all alike with their weights stacked (the dense
+decoder) runs as one ``lax.scan`` over the stack; a model
 whose layers differ, or whose weights are too large to hold a second,
 stacked copy of (a latent-attention decoder with a leading dense layer
 and expert layers after it), runs its layers one after another over the
 model's own arrays.
 
 Attention kinds: ``gqa`` (rotary grouped-query attention over K and V
-pages ``[num_blocks, Hkv, bs, D]``, one layer's slice at a time) and
-``mla`` (latent attention in the absorbed form over ONE pool for all
+pages ``[num_blocks, Hkv, bs, D]``, one layer's slice at a time; float
+pages, or int8 pages with their two scale pools, by what it is handed)
+and ``mla`` (latent attention in the absorbed form over ONE pool for all
 layers, ``[L, num_blocks, bs, width]``, written and read in place at the
 layer's index).  FFN kinds: ``swiglu`` and ``moe`` (routed experts held
 here plus shared experts: ``models/mla_moe.py``).
 
 The ``jax.named_scope`` names below are what a device trace is read by
-(docs/observability.md): ``norm``, ``qkv``/``q_proj``/``kv_latent``,
-``rope``, ``kv_write``, ``attn``, ``o_proj``, ``mlp``, and for expert
+(docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
+``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn``,
+``o_proj``, ``mlp``, and for expert
 layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
 ``shared_expert``.
 """
@@ -65,8 +69,10 @@ def scan_layers(body, x, layers, pools):
 def step_context(**kw) -> SimpleNamespace:
     """What every layer of one step program shares: the row layout
     (``Tq``, ``seg``, ``rel``, ``bt``, ``cu``, ``kvl``, ``bs``), the
-    products (``mm``), the model's sizes and whether the kernel runs
-    (``use_pallas``)."""
+    products (``mm``, and ``embed`` and ``head_logits`` for ``forward``),
+    the model's sizes, whether the kernel runs (``use_pallas``) and,
+    over int8 pages, ``fresh`` ([num_blocks] bool: the pages whose scale
+    rows a layer's commit resets; None where the caller already did)."""
     return SimpleNamespace(**kw)
 
 
@@ -74,9 +80,92 @@ def step_context(**kw) -> SimpleNamespace:
 # attention kinds: (x, h, p, pools, layer, c) -> (x, pools)
 # ---------------------------------------------------------------------------
 
-def _gqa(x, h, p, pools, _layer, c):
-    """Grouped-query attention over this layer's K and V pages."""
+# ``gqa`` over either page type: commit(k, v, pools, blk, slot, c) ->
+# pools writes the step's rows at (page, slot); attend(q, pools, c) ->
+# [Tq, heads, d] reads the pages.  S10 (ROADMAP) lands in these pairs.
+
+def _commit_float(k, v, pools, blk, slot, c):
     kcl, vcl = pools
+    return (kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype)),
+            vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype)))
+
+
+def _attend_float(q, pools, c):
+    if c.use_pallas:
+        # the host packing path owns these buffers: bt is the int32
+        # NULL_BLOCK-padded pool table and cu, kvl come int32 from
+        # the step's packing, so the packed entry skips the
+        # per-launch re-clip and re-cast.  The kernel reads the row
+        # layout itself; seg/rel are for rope and kv_write
+        return _pa.ragged_paged_attention_packed(q, *pools, c.bt, c.cu,
+                                                 c.kvl)
+    return _pa.ragged_paged_reference_segrel(q, *pools, c.bt, c.seg, c.rel)
+
+
+def _commit_int8(k, v, pools, blk, slot, c):
+    """Quantize at commit, per layer, per launch:
+    1. zero the scale rows of ``fresh`` pages (pages BlockManager handed
+       out since the last launch: their old content AND old scales are
+       dead; CoW destinations are excluded — the CoW program copied
+       their scale rows with their data);
+    2. scatter-max each touched page's scale with the incoming tokens'
+       per-head amax/127 (scales only grow while a page is live, so
+       previously committed int8 values never overflow);
+    3. re-encode the touched pages' existing int8 content from the old
+       scale to the grown scale (one extra rounding per growth event —
+       the accepted precision cost of page-granular scales);
+    4. quantize the new tokens at the settled scale and scatter them
+       into their slots.
+    Duplicate page indices across tokens are safe throughout: the
+    scatter-max makes every duplicate observe the same settled scale,
+    so duplicate re-encodes write identical bytes.  Under tp the scale
+    pools slice along the same H_kv axis as the page pools, so all of
+    this stays per-head-local."""
+    kcl, vcl, ksl, vsl = pools
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+    if c.fresh is not None:
+        ksl = jnp.where(c.fresh[:, None], 0.0, ksl)
+        vsl = jnp.where(c.fresh[:, None], 0.0, vsl)
+    ks_old = ksl[blk]                                     # [Tq, kvh]
+    vs_old = vsl[blk]
+    ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1) / 127.0)
+    vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1) / 127.0)
+    ks_new = ksl[blk]
+    vs_new = vsl[blk]
+    rk = jnp.where(ks_new > 0.0, ks_old / jnp.maximum(ks_new, 1e-30), 0.0)
+    rv = jnp.where(vs_new > 0.0, vs_old / jnp.maximum(vs_new, 1e-30), 0.0)
+    kp = jnp.round(kcl[blk].astype(jnp.float32) * rk[:, :, None, None])
+    vp = jnp.round(vcl[blk].astype(jnp.float32) * rv[:, :, None, None])
+    kcl = kcl.at[blk].set(jnp.clip(kp, -127, 127).astype(jnp.int8))
+    vcl = vcl.at[blk].set(jnp.clip(vp, -127, 127).astype(jnp.int8))
+    kq = jnp.round(kf / jnp.maximum(ks_new, 1e-30)[:, :, None])
+    vq = jnp.round(vf / jnp.maximum(vs_new, 1e-30)[:, :, None])
+    kcl = kcl.at[blk, :, slot, :].set(
+        jnp.clip(kq, -127, 127).astype(jnp.int8))
+    vcl = vcl.at[blk, :, slot, :].set(
+        jnp.clip(vq, -127, 127).astype(jnp.int8))
+    return kcl, vcl, ksl, vsl
+
+
+def _attend_int8(q, pools, c):
+    if c.use_pallas:
+        # packed-entry invariant as over float pages; the scale pools
+        # are born f32 on the host
+        att = _pa.ragged_paged_attention_quant_packed(q, *pools, c.bt,
+                                                      c.cu, c.kvl)
+    else:
+        att = _pa.ragged_paged_reference_quant_segrel(q, *pools, c.bt,
+                                                      c.seg, c.rel)
+    return att.astype(q.dtype)
+
+
+def _gqa(x, h, p, pools, _layer, c):
+    """Grouped-query attention over this layer's pages.  What differs
+    between page types is a pair, picked by what the layer is handed:
+    commit the step's K/V rows into the pools, and attend over them."""
+    commit, attend = (_commit_int8, _attend_int8) \
+        if pools[0].dtype == jnp.int8 else (_commit_float, _attend_float)
     Tq, nh, kvh, d, tp, mm = c.Tq, c.nh, c.kvh, c.d, c.tp, c.mm
     with jax.named_scope("qkv"):
         q = mm(h, p, "wq").reshape(Tq, nh, d)
@@ -88,20 +177,9 @@ def _gqa(x, h, p, pools, _layer, c):
     with jax.named_scope("kv_write"):
         blk = c.bt[c.seg, c.rel // c.bs]                  # [Tq]
         slot = c.rel % c.bs
-        kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
-        vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
+        pools = commit(k, v, pools, blk, slot, c)
     with jax.named_scope("attn"):
-        if c.use_pallas:
-            # the host packing path owns these buffers: bt is the int32
-            # NULL_BLOCK-padded pool table and cu, kvl come int32 from
-            # the step's packing, so the packed entry skips the
-            # per-launch re-clip and re-cast.  The kernel reads the row
-            # layout itself; seg/rel are for rope and kv_write
-            att = _pa.ragged_paged_attention_packed(
-                q, kcl, vcl, c.bt, c.cu, c.kvl)
-        else:
-            att = _pa.ragged_paged_reference_segrel(
-                q, kcl, vcl, c.bt, c.seg, c.rel)
+        att = attend(q, pools, c)
         if tp > 1:
             # tiled gather concatenates shard head blocks in mesh order
             # — exactly the tp=1 head layout, so the replicated wo
@@ -109,7 +187,7 @@ def _gqa(x, h, p, pools, _layer, c):
             att = lax.all_gather(att, "tp", axis=1, tiled=True)
     with jax.named_scope("o_proj"):
         x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-    return x, (kcl, vcl)
+    return x, pools
 
 
 def _latent(x, h, p, pools, layer, c):
@@ -213,3 +291,36 @@ def layer_stack(x, segments, pools, c):
     all_ = jnp.stack(counted)                              # [layers, 4]
     return x, pools, jnp.concatenate([jnp.sum(all_[:, :3], axis=0),
                                       jnp.max(all_[:, 3:], axis=0)])
+
+
+def forward(params, toks, pools, c, lidx=None):
+    """One step's forward for every driver: embed the flat tokens, run
+    the layers, norm, and score the logit rows (``lidx``: the flat index
+    of each; None where every row is its own, as in the decode window).
+    ``c.kinds`` is the model's (attention kind, FFN kind) a layer: the
+    dense decoder (``c.scanned``) is one scanned segment over its
+    stacked weights; otherwise runs of layers of one kind, each over its
+    own arrays.  Returns (logits, pools, counts)."""
+    with jax.named_scope("embed"):
+        x = c.embed(params, toks)                             # [Tq, H]
+    if c.scanned:
+        segments = [(c.kinds[0], params["layers"], True)]
+    else:
+        segments = []
+        for i, k in enumerate(c.kinds):
+            if not segments or segments[-1][0] != k:
+                segments.append((k, [], False))
+            segments[-1][1].append((i, params["layers"][i]))
+    with jax.named_scope("layers"):
+        x, pools, counts = layer_stack(x, segments, pools, c)
+    with jax.named_scope("norm"):
+        h = _rms_weight(x, params["norm_f"], c.eps)
+    with jax.named_scope("head"):
+        if lidx is not None:
+            h = h[lidx]                                       # [Lq, H]
+        logits = c.head_logits(params, h)                     # [Lq, V]
+        if c.shard_head:
+            # vocab-sliced logits -> one gather; sampling then runs
+            # replicated on identical full-width rows
+            logits = lax.all_gather(logits, "tp", axis=1, tiled=True)
+    return logits, pools, counts

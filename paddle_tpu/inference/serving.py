@@ -76,7 +76,6 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.llama import _rms_weight, _rope_positions
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
@@ -259,10 +258,6 @@ def _instruction_scopes(hlo_text: str) -> dict:
     return out
 
 
-# the int8-page and window builders still scan their layers themselves
-_scan_layers = _ls.scan_layers
-
-
 def _refuse_latent_options(**asked) -> None:
     """A model with latent-attention layers is served from float pages
     and float weights on one chip, a step a launch.  Each option below
@@ -276,8 +271,9 @@ def _refuse_latent_options(**asked) -> None:
                "experts laid over a mesh, with their exchange"),
         "drafter": (None, "a drafter needs verify rows' logits from the "
                     "latent step program"),
-        "decode_window": (1, "decode_window > 1 needs a window builder "
-                          "on the layer-stack function"),
+        "decode_window": (1, "decode_window > 1 needs a test and a cell: "
+                          "the window loops the layer-stack forward, and "
+                          "nothing has run it over the latent pool"),
         "kv_tier": (None, "kv_tier needs the spill and restore of latent "
                     "pages"),
     }
@@ -385,6 +381,15 @@ class LLMEngine:
         pools live on (default: the first ``tp`` of ``jax.devices()``).
         A replica router passes each replica its own, so that replicas
         do not share one chip.
+
+    The step programs are two drivers round ONE forward
+    (``layer_stack.forward``: embed, the layers by kind, norm, head):
+    ``_make_ragged_fn`` (segments, forward, sample) and, for
+    ``decode_window`` > 1, ``_make_window_fn`` (a ``lax.while_loop``
+    round the same forward at Tq = B).  ``kv_dtype`` adds pools and an
+    input (the scale pools, the fresh-page mask), not a builder: the
+    ``gqa`` layer kind quantizes at commit when the pages it is handed
+    are int8.
 
     Which attention and which matmul implementation the step programs
     run is decided once here, from the devices' platform and the
@@ -930,26 +935,19 @@ class LLMEngine:
             self._param_specs(), params,
             is_leaf=lambda x: isinstance(x, P))
 
-    def _step_specs(self, n_host_args: int):
-        """(in_specs, out_specs) for the shard_map-wrapped ragged step.
+    def _wrap_tp(self, run, n_host_args: int, n_front: int | None = None):
+        """shard_map a step program's body over the tp mesh (identity at
+        tp=1).
 
         KV/scale pools shard along their H_kv axis; params follow
         ``_param_specs``; the ``n_host_args`` trailing host-packed
         operands (tokens, cu_seqlens, kv_lens, block tables, logit
         index, sampling pytree — plus the fresh-page mask in int8 mode)
         replicate, a single P() covering each pytree by prefix.  Every
-        non-pool output (sampled tokens, finiteness flags, logits) is
-        genuinely replicated after the in-step all-gathers, so its
-        out_spec is P().
-        """
-        kv = P(None, None, "tp")
-        pools = (kv, kv) if self.kv_dtype == "float32" else (kv,) * 4
-        in_specs = (self._param_specs(), *pools) + (P(),) * n_host_args
-        out_front = (P(), P(), P()) if self._with_logits else (P(), P())
-        return in_specs, out_front + pools
-
-    def _wrap_tp(self, run, n_host_args: int):
-        """shard_map the step body over the tp mesh (identity at tp=1).
+        one of the ``n_front`` non-pool outputs (default, the ragged
+        step's: sampled tokens, finiteness flags and, with a drafter,
+        logits; the window's two [K, B] grids) is genuinely replicated
+        after the in-step all-gathers, so its out_spec is P().
 
         check_vma=False: the body mixes replicated and sharded operands
         and resolves them with explicit all-gathers, the same contract
@@ -957,9 +955,14 @@ class LLMEngine:
         """
         if self.tp == 1:
             return run
-        in_specs, out_specs = self._step_specs(n_host_args)
-        return shard_map(run, mesh=self._mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        if n_front is None:
+            n_front = 2 + self._with_logits
+        kv = P(None, None, "tp")
+        pools = (kv,) * len(self._pools())
+        return shard_map(
+            run, mesh=self._mesh, check_vma=False,
+            in_specs=(self._param_specs(), *pools) + (P(),) * n_host_args,
+            out_specs=(P(),) * n_front + pools)
 
     # ------------------------------------------------------------------
     # request API
@@ -2153,8 +2156,9 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
         chain = _sample_chain(samp)
-        toks_out, fin_out = self._launch_window(
-            toks, kvl, active, gen, budgets, eos_ids, base_keys, bt, samp)
+        toks_out, fin_out = self._call_program(
+            self._get_window_prog(),
+            (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt, samp), B)
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
@@ -2856,90 +2860,77 @@ class LLMEngine:
             self._record_program(f"ragged:{Tq}")
         return prog
 
+    def _step_shared(self, Tq: int) -> dict:
+        """What the layers of a step program over ``Tq`` flat tokens
+        share whatever the launch holds: ``layer_stack.step_context``
+        minus the row layout, resolved once per program build.  Under
+        tp the body runs on PER-SHARD shapes: a contiguous block of
+        nh/tp query heads attending over kvh/tp KV heads (GQA groups
+        never straddle shards — tp divides kvh)."""
+        cfg = self.config
+        mm, embed, head_logits = self._weight_ops()
+        shared = dict(
+            Tq=Tq, bs=self.block_size, tp=self.tp, mm=mm, embed=embed,
+            head_logits=head_logits, shard_head=self._shard_head,
+            kinds=self._layer_kinds, scanned=not self._latent,
+            eps=cfg.rms_norm_eps,
+            use_pallas=self.attention_path.startswith("pallas"))
+        if self._latent:
+            from ..models.mla_moe import softmax_scale, yarn_inv_freq
+            shared.update(cfg=cfg, inv_freq=yarn_inv_freq(cfg),
+                          sm_scale=softmax_scale(cfg))
+        else:
+            shared.update(nh=self._nh // self.tp, kvh=self._kvh // self.tp,
+                          d=self._hd, theta=cfg.rope_theta)
+        return shared
+
     def _make_ragged_fn(self, Tq: int):
         """The one serving step program: Tq flat query tokens from up to
         max_num_seqs ragged rows.  A prefill chunk, a resumed chunk, a
         decode token, and a k-draft verify window are all rows of the
         same launch, differing only in query length — each layer writes
         the packed tokens' K/V (or latent rows) into the paged cache at
-        their absolute positions, then ragged paged attention lets every
-        token attend to its own row's pages causally.  Sampled tokens
-        come back for the logit rows in ``lidx``; with a drafter the raw
-        [Lq, V] logits ride along for host-side draft acceptance; a
-        model with expert layers also returns what they counted.
+        their absolute positions (over int8 pages it QUANTIZES them at
+        commit time and attention dequantizes at read time), then ragged
+        paged attention lets every token attend to its own row's pages
+        causally.  Sampled tokens come back for the logit rows in
+        ``lidx``; with a drafter the raw [Lq, V] logits ride along for
+        host-side draft acceptance; a model with expert layers also
+        returns what they counted.
 
-        The layers are ``layer_stack`` of the model's layer kinds
-        (inference/layer_stack.py): the dense decoder is one scanned
-        segment over its stacked weights with the K and V pools sliced a
+        The forward is ``layer_stack.forward`` for every model and page
+        type (inference/layer_stack.py): the dense decoder is one
+        scanned segment over its stacked weights with the pools sliced a
         layer at a time; a latent-attention model runs layer after layer
         over its own arrays and one pool."""
-        cfg = self.config
-        if self.kv_dtype == "int8":
-            return self._make_ragged_fn_q8(Tq)
-        # under tp the body runs on PER-SHARD shapes: a contiguous block
-        # of nh/tp query heads attending over kvh/tp KV heads (GQA
-        # groups never straddle shards — tp divides kvh)
-        tp = self.tp
         with_logits = self._with_logits
-        shard_head = self._shard_head
-        mm, embed, head_logits = self._weight_ops()
         n_pools = len(self._pools())
-        kinds, latent = self._layer_kinds, self._latent
-        eps = cfg.rms_norm_eps
+        q8 = self.kv_dtype == "int8"
         # (``run`` below must not close over ``self``: a compiled program
         # that holds its engine keeps it alive past its last user)
-        shared = dict(
-            Tq=Tq, bs=self.block_size, tp=tp, mm=mm,
-            eps=eps, use_pallas=self.attention_path.startswith("pallas"))
-        if latent:
-            from ..models.mla_moe import softmax_scale, yarn_inv_freq
-            shared.update(cfg=cfg, inv_freq=yarn_inv_freq(cfg),
-                          sm_scale=softmax_scale(cfg))
-        else:
-            shared.update(nh=self._nh // tp, kvh=self._kvh // tp,
-                          d=self._hd, theta=cfg.rope_theta)
+        shared = self._step_shared(Tq)
 
         def run(params, *rest):
-            # rest: the page pools, then toks [Tq] i32, rows packed
-            # back-to-back (tail padding maps to the sentinel row); cu
-            # [B+1] i32 row offsets; kvl [B] i32 valid KV per row AFTER
-            # this launch's writes; bt [B+1, nblk] i32 (row B: the null
-            # row pads resolve to); lidx [Lq] i32 flat index of each
-            # logit row; samp the make_samp pytree, one row per logit
-            # row.  Under tp>1 this traces per shard: the pools and the
-            # q/k/v projections arrive head-sliced, toks..samp arrive
-            # replicated.
+            # rest: the page pools (over int8 pages K, V and their
+            # [L, num_blocks, H_kv] f32 scale pools, then fresh
+            # [num_blocks] bool: pages whose scales reset this launch),
+            # then toks [Tq] i32, rows packed back-to-back (tail padding
+            # maps to the sentinel row); cu [B+1] i32 row offsets; kvl
+            # [B] i32 valid KV per row AFTER this launch's writes; bt
+            # [B+1, nblk] i32 (row B: the null row pads resolve to);
+            # lidx [Lq] i32 flat index of each logit row; samp the
+            # make_samp pytree, one row per logit row.  Under tp>1 this
+            # traces per shard: the pools and the q/k/v projections
+            # arrive head-sliced, fresh..samp arrive replicated.
             # the jax.named_scope names here and in layer_stack are what
-            # a device trace is read by (docs/observability.md): keep
-            # them, and keep them the same in all step builders
-            pools = rest[:n_pools]
-            toks, cu, kvl, bt, lidx, samp = rest[n_pools:]
+            # a device trace is read by (docs/observability.md)
+            pools, host = rest[:n_pools], rest[n_pools:]
+            toks, cu, kvl, bt, lidx, samp = host[-6:]
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
-            with jax.named_scope("embed"):
-                x = embed(params, toks)                       # [Tq, H]
             c = _ls.step_context(seg=seg, rel=rel, bt=bt, cu=cu, kvl=kvl,
-                                 **shared)
-            if latent:
-                # runs of layers of one kind, each over its own arrays
-                segments = []
-                for i, k in enumerate(kinds):
-                    if not segments or segments[-1][0] != k:
-                        segments.append((k, [], False))
-                    segments[-1][1].append((i, params["layers"][i]))
-            else:
-                segments = [(kinds[0], params["layers"], True)]
-            with jax.named_scope("layers"):
-                x, pools, counts = _ls.layer_stack(x, segments, pools, c)
-            with jax.named_scope("norm"):
-                h = _rms_weight(x, params["norm_f"], eps)
-            with jax.named_scope("head"):
-                hsel = h[lidx]                                # [Lq, H]
-                logits = head_logits(params, hsel)            # [Lq, V]
-                if shard_head:
-                    # vocab-sliced logits -> one gather; sampling then
-                    # runs replicated on identical full-width rows
-                    logits = lax.all_gather(logits, "tp", axis=1,
-                                            tiled=True)
+                                 fresh=host[0] if q8 else None, **shared)
+            logits, pools, counts = _ls.forward(params, toks, pools, c,
+                                                lidx)
             with jax.named_scope("sample"):
                 sampled = sample_tokens(logits, samp)
                 # per-row finiteness flag: the quarantine guard retires a
@@ -2954,148 +2945,10 @@ class LLMEngine:
                 out += (counts,)
             return out + tuple(pools)
 
-        # donation reuses the pool buffers in place; _get_ragged_prog
-        # drops it on CPU (that runtime cannot alias and warns per call)
-        return self._wrap_tp(run, 6), tuple(range(1, 1 + n_pools))
-
-    def _make_ragged_fn_q8(self, Tq: int):
-        """Int8-page variant of the one serving step program: identical
-        row semantics, but each layer QUANTIZES its packed tokens' K/V
-        at commit time and attention dequantizes at read time.
-
-        Quantize-at-commit, per layer, per launch:
-        1. zero the scale rows of ``fresh`` pages (pages BlockManager
-           handed out since the last launch: their old content AND old
-           scales are dead; CoW destinations are excluded — the CoW
-           program copied their scale rows with their data);
-        2. scatter-max each touched page's scale with the incoming
-           tokens' per-head amax/127 (scales only grow while a page is
-           live, so previously committed int8 values never overflow);
-        3. re-encode the touched pages' existing int8 content from the
-           old scale to the grown scale (one extra rounding per growth
-           event — the accepted precision cost of page-granular scales);
-        4. quantize the new tokens at the settled scale and scatter them
-           into their slots.
-        Duplicate page indices across tokens are safe throughout: the
-        scatter-max makes every duplicate observe the same settled
-        scale, so duplicate re-encodes write identical bytes.
-        """
-        nh, kvh, d = self._nh, self._kvh, self._hd
-        bs = self.block_size
-        B = self.max_num_seqs
-        with_logits = self._with_logits
-        eps = self.config.rms_norm_eps
-        theta = self.config.rope_theta
-        # per-shard head counts under tp (see _make_ragged_fn): the
-        # scale pools slice along the same H_kv axis as the page pools,
-        # so quantize-at-commit stays a purely per-head-local transform
-        tp = self.tp
-        nh, kvh = nh // tp, kvh // tp
-        shard_head = self._shard_head
-        mm, embed, head_logits = self._weight_ops()
-        use_pallas = self.attention_path.startswith("pallas")
-        quant_attn = _pa.ragged_paged_attention_quant_packed
-
-        def run(params, kc, vc, ks, vs, fresh, toks, cu, kvl, bt, lidx,
-                samp):
-            # args as the float step, plus: ks/vs [L, num_blocks, H_kv]
-            # f32 scale pools (donated with the page pools) and fresh
-            # [num_blocks] bool (pages whose scales reset this launch)
-            seg, rel = _pa.ragged_segments(cu, kvl, Tq)
-            with jax.named_scope("embed"):
-                x = embed(params, toks)                       # [Tq, H]
-
-            def body(x, inp):
-                p, kcl, vcl, ksl, vsl = inp
-                with jax.named_scope("norm"):
-                    h = _rms_weight(x, p["ln1"], eps)
-                with jax.named_scope("qkv"):
-                    q = mm(h, p, "wq").reshape(Tq, nh, d)
-                    k = mm(h, p, "wk").reshape(Tq, kvh, d)
-                    v = mm(h, p, "wv").reshape(Tq, kvh, d)
-                with jax.named_scope("rope"):
-                    q = _rope_positions(q, rel, theta)
-                    k = _rope_positions(k, rel, theta)
-                with jax.named_scope("kv_write"):
-                    blk = bt[seg, rel // bs]                  # [Tq]
-                    slot = rel % bs
-                    kf = k.astype(jnp.float32)
-                    vf = v.astype(jnp.float32)
-                    ksl = jnp.where(fresh[:, None], 0.0, ksl)
-                    vsl = jnp.where(fresh[:, None], 0.0, vsl)
-                    ks_old = ksl[blk]                         # [Tq, kvh]
-                    vs_old = vsl[blk]
-                    ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1)
-                                          / 127.0)
-                    vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1)
-                                          / 127.0)
-                    ks_new = ksl[blk]
-                    vs_new = vsl[blk]
-                    rk = jnp.where(ks_new > 0.0,
-                                   ks_old / jnp.maximum(ks_new, 1e-30),
-                                   0.0)
-                    rv = jnp.where(vs_new > 0.0,
-                                   vs_old / jnp.maximum(vs_new, 1e-30),
-                                   0.0)
-                    kp = jnp.round(kcl[blk].astype(jnp.float32)
-                                   * rk[:, :, None, None])
-                    vp = jnp.round(vcl[blk].astype(jnp.float32)
-                                   * rv[:, :, None, None])
-                    kcl = kcl.at[blk].set(
-                        jnp.clip(kp, -127, 127).astype(jnp.int8))
-                    vcl = vcl.at[blk].set(
-                        jnp.clip(vp, -127, 127).astype(jnp.int8))
-                    kq = jnp.round(kf / jnp.maximum(ks_new,
-                                                    1e-30)[:, :, None])
-                    vq = jnp.round(vf / jnp.maximum(vs_new,
-                                                    1e-30)[:, :, None])
-                    kcl = kcl.at[blk, :, slot, :].set(
-                        jnp.clip(kq, -127, 127).astype(jnp.int8))
-                    vcl = vcl.at[blk, :, slot, :].set(
-                        jnp.clip(vq, -127, 127).astype(jnp.int8))
-                with jax.named_scope("attn"):
-                    if use_pallas:
-                        # packed-entry invariant as in the float step;
-                        # the scale pools are born f32 on the host
-                        att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
-                                         cu, kvl)
-                    else:
-                        att = _pa.ragged_paged_reference_quant_segrel(
-                            q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                    att = att.astype(x.dtype)
-                    if tp > 1:
-                        att = lax.all_gather(att, "tp", axis=1,
-                                             tiled=True)
-                with jax.named_scope("o_proj"):
-                    x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-                with jax.named_scope("norm"):
-                    h2 = _rms_weight(x, p["ln2"], eps)
-                with jax.named_scope("mlp"):
-                    a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
-                                    ).astype(h2.dtype) * mm(h2, p, "up")
-                    x = x + mm(a, p, "down")
-                return x, (kcl, vcl, ksl, vsl)
-
-            with jax.named_scope("layers"):
-                x, (kc, vc, ks, vs) = _scan_layers(
-                    body, x, params["layers"], (kc, vc, ks, vs))
-            with jax.named_scope("norm"):
-                h = _rms_weight(x, params["norm_f"], eps)
-            with jax.named_scope("head"):
-                hsel = h[lidx]                                # [Lq, H]
-                logits = head_logits(params, hsel)            # [Lq, V]
-                if shard_head:
-                    logits = lax.all_gather(logits, "tp", axis=1,
-                                            tiled=True)
-            with jax.named_scope("sample"):
-                sampled = sample_tokens(logits, samp)
-                fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [Lq]
-            if with_logits:
-                return sampled, fin, logits, kc, vc, ks, vs
-            return sampled, fin, kc, vc, ks, vs
-
-        # donate the page pools AND scale pools; fresh is input-only
-        return self._wrap_tp(run, 7), (1, 2, 3, 4)
+        # donation reuses the pool buffers (pages and scales) in place;
+        # fresh is input-only.  _get_ragged_prog drops donation on CPU
+        # (that runtime cannot alias and warns per call)
+        return self._wrap_tp(run, 6 + q8), tuple(range(1, 1 + n_pools))
 
     def _consume_fresh(self):
         """Accumulate BlockManager's freshly handed-out pages into the
@@ -3107,20 +2960,30 @@ class LLMEngine:
         self._fresh_np[:] = False
         return out
 
-    def _call_program(self, prog, args, bucket: int):
-        """The jitted call of a step launch.  It counts the launch (the
+    def _call_program(self, prog, host_args, bucket: int):
+        """The jitted call of a step launch: the parameters, the pools
+        (over int8 pages the fresh-page mask after them) and the
+        launch's ``host_args``; the pools that come back are kept and
+        the outputs before them returned.  It counts the launch (the
         step id) and, with a tracer installed, brackets the call in one
         ``engine.launch`` annotation carrying that id, so the profiler's
         own trace holds a host event a step that joins a device
         program's execution to the Tracer's ``engine.device_launch``.
         One call site: the program is the same whoever is watching."""
+        pools = self._pools()
+        args = (self.params,) + pools
+        if self.kv_dtype == "int8":
+            args += (self._consume_fresh(),)
+        args += tuple(host_args)
         self.launches += 1
         note = _NO_ANNOTATION if self.tracer is None else \
             jax.profiler.TraceAnnotation("engine.launch",
                                          step=self.launches,
                                          bucket=int(bucket))
         with note:
-            return prog(*args)
+            out = prog(*args)
+        self._set_pools(out[-len(pools):])
+        return out[:-len(pools)]
 
     def _launch_ragged(self, Tq, toks, cu, kvl, bt, lidx, samp,
                        real_tokens):
@@ -3129,15 +2992,8 @@ class LLMEngine:
         self.pad_stats["kv_pages"] += self._kv_pages(kvl)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
-        prog = self._get_ragged_prog(Tq)
-        pools = self._pools()
-        head = (self.params,) + pools
-        if self.kv_dtype == "int8":
-            head += (self._consume_fresh(),)
-        out = self._call_program(
-            prog, head + (toks, cu, kvl, bt, lidx, samp), Tq)
-        self._set_pools(out[-len(pools):])
-        out = out[:-len(pools)]
+        out = self._call_program(self._get_ragged_prog(Tq),
+                                 (toks, cu, kvl, bt, lidx, samp), Tq)
         sampled, fin = out[0], out[1]
         # what the step's expert layers counted rides to the completion
         # half unmaterialized, like the tokens
@@ -3166,133 +3022,69 @@ class LLMEngine:
             self._record_program(f"window:{self.decode_window}")
         return self._window_prog
 
-    def _wrap_tp_window(self, run, n_host_args: int):
-        """shard_map for the window driver (identity at tp=1).  Same
-        sharding contract as ``_step_specs``: pools slice along H_kv,
-        host-packed operands replicate, and both non-pool outputs (the
-        [K, B] token and finiteness grids) are replicated after the
-        in-body all-gathers — every shard's while_loop sees identical
-        replicated logits, so the active-mask and the early-exit
-        condition agree across shards by construction."""
-        if self.tp == 1:
-            return run
-        kv = P(None, None, "tp")
-        pools = (kv, kv) if self.kv_dtype == "float32" else (kv,) * 4
-        in_specs = (self._param_specs(), *pools) + (P(),) * n_host_args
-        out_specs = (P(), P()) + pools
-        return shard_map(run, mesh=self._mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-
     def _make_window_fn(self):
         """The device-resident K-step decode window program.
 
         One launch runs up to K = ``decode_window`` full decode steps
         without a host round-trip: a ``lax.while_loop`` whose body is
-        EXACTLY the per-step decode program at Tq = B (same layer scan,
-        same paged K/V commit, same ragged attention entry, same
-        LogitProcessor chain) plus the carry bookkeeping the host does
-        between per-step launches — advance kv_lens, re-derive sampler
-        keys as fold_in(base, generated), update the repetition-penalty
-        ``seen`` mask, and freeze rows whose sampled token hits eos or
-        whose generation budget fills (the same predicates
-        ``_maybe_retire`` applies host-side).  Frozen rows redirect to
-        the sentinel block-table row via ``decode_window_segments`` so
-        their writes land in the null page like ragged padding; the
-        loop exits early once every row froze.  The host drains the
-        [K, B] token grid afterwards — logits and tokens never leave
-        the device mid-window, which is the whole point."""
-        nh, kvh, d = self._nh, self._kvh, self._hd
-        bs = self.block_size
+        EXACTLY the per-step decode program at Tq = B (the same
+        ``layer_stack.forward``, the same LogitProcessor chain) plus the
+        carry bookkeeping the host does between per-step launches —
+        advance kv_lens, re-derive sampler keys as fold_in(base,
+        generated), update the repetition-penalty ``seen`` mask, and
+        freeze rows whose sampled token hits eos or whose generation
+        budget fills (the same predicates ``_maybe_retire`` applies
+        host-side).  Frozen rows redirect to the sentinel block-table
+        row via ``decode_window_segments`` so their writes land in the
+        null page like ragged padding; the loop exits early once every
+        row froze.  The host drains the [K, B] token grid afterwards —
+        logits and tokens never leave the device mid-window, which is
+        the whole point.
+
+        Over int8 pages the fresh-page scale reset HOISTS out of the
+        loop.  The per-step program zeroes fresh pages' scale rows
+        inside every layer because each launch consumes one fresh
+        batch; here the whole window's pages are handed out before
+        launch, and an in-body reset would wipe scales grown by earlier
+        window iterations — so the reset runs ONCE, before iteration 0,
+        when every fresh page is still unwritten (byte-equivalent)."""
         B = self.max_num_seqs
         K = self.decode_window
-        eps = self.config.rms_norm_eps
-        theta = self.config.rope_theta
-        if self.kv_dtype == "int8":
-            return self._make_window_fn_q8()
-        tp = self.tp
-        nh, kvh = nh // tp, kvh // tp
-        shard_head = self._shard_head
-        mm, embed, head_logits = self._weight_ops()
-        use_pallas = self.attention_path.startswith("pallas")
-        float_attn = _pa.ragged_paged_attention_packed
+        n_pools = len(self._pools())
+        q8 = self.kv_dtype == "int8"
+        shared = self._step_shared(B)
 
-        def run(params, kc, vc, toks, kvl, active, gen, budgets,
-                eos_ids, base_keys, bt, samp):
-            # toks [B] i32 last committed token per row; kvl [B] i32
-            # valid KV AFTER iteration 0's write; active [B] bool;
-            # gen [B] i32 tokens generated so far (the sampler-key
-            # counter); budgets [B] i32 max_new_tokens; eos_ids [B] i32
-            # (-1: no eos); base_keys [B,2] u32 PRNGKey(seed) per row;
-            # bt [B+1, nblk]; samp the make_samp pytree (its "keys"
-            # field is dead — the body derives keys from base_keys).
+        def run(params, *rest):
+            # rest: the page pools (over int8 pages: and fresh, as the
+            # ragged step), then toks [B] i32 last committed token per
+            # row; kvl [B] i32 valid KV AFTER iteration 0's write;
+            # active [B] bool; gen [B] i32 tokens generated so far (the
+            # sampler-key counter); budgets [B] i32 max_new_tokens;
+            # eos_ids [B] i32 (-1: no eos); base_keys [B,2] u32
+            # PRNGKey(seed) per row; bt [B+1, nblk]; samp the make_samp
+            # pytree (its "keys" field is dead — the body derives keys
+            # from base_keys).
+            pools, host = rest[:n_pools], rest[n_pools:]
+            (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt,
+             samp) = host[-9:]
             rows = jnp.arange(B, dtype=jnp.int32)
+            if q8:
+                pools = pools[:2] + tuple(
+                    jnp.where(host[0][None, :, None], 0.0, scales)
+                    for scales in pools[2:])
 
             def step(carry):
-                (i, tok, kvl, active, gen, seen, kc, vc, touts,
-                 fouts) = carry
+                i, tok, kvl, active, gen, seen, pools, touts, fouts = carry
                 seg, rel = _pa.decode_window_segments(active, kvl)
                 cu_w, kvl_w = _pa.decode_window_rows(active, kvl)
-                with jax.named_scope("embed"):
-                    x = embed(params, tok)                    # [B, H]
-
-                def body(x, inp):
-                    p, kcl, vcl = inp
-                    with jax.named_scope("norm"):
-                        h = _rms_weight(x, p["ln1"], eps)
-                    with jax.named_scope("qkv"):
-                        q = mm(h, p, "wq").reshape(B, nh, d)
-                        k = mm(h, p, "wk").reshape(B, kvh, d)
-                        v = mm(h, p, "wv").reshape(B, kvh, d)
-                    with jax.named_scope("rope"):
-                        q = _rope_positions(q, rel, theta)
-                        k = _rope_positions(k, rel, theta)
-                    with jax.named_scope("kv_write"):
-                        blk = bt[seg, rel // bs]              # [B]
-                        slot = rel % bs
-                        kcl = kcl.at[blk, :, slot, :].set(
-                            k.astype(kcl.dtype))
-                        vcl = vcl.at[blk, :, slot, :].set(
-                            v.astype(vcl.dtype))
-                    with jax.named_scope("attn"):
-                        if use_pallas:
-                            att = float_attn(q, kcl, vcl, bt, cu_w,
-                                             kvl_w)
-                        else:
-                            att = _pa.ragged_paged_reference_segrel(
-                                q, kcl, vcl, bt, seg, rel)
-                        if tp > 1:
-                            att = lax.all_gather(att, "tp", axis=1,
-                                                 tiled=True)
-                    with jax.named_scope("o_proj"):
-                        x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
-                    with jax.named_scope("norm"):
-                        h2 = _rms_weight(x, p["ln2"], eps)
-                    with jax.named_scope("mlp"):
-                        a = jax.nn.silu(
-                            mm(h2, p, "gate").astype(jnp.float32)
-                        ).astype(h2.dtype) * mm(h2, p, "up")
-                        x = x + mm(a, p, "down")
-                    return x, (kcl, vcl)
-
-                with jax.named_scope("layers"):
-                    x, (kc, vc) = _scan_layers(
-                        body, x, params["layers"], (kc, vc))
-                with jax.named_scope("norm"):
-                    h = _rms_weight(x, params["norm_f"], eps)
-                with jax.named_scope("head"):
-                    # every row is its own logit row (lidx == identity)
-                    logits = head_logits(params, h)
-                    if shard_head:
-                        logits = lax.all_gather(logits, "tp", axis=1,
-                                                tiled=True)
+                c = _ls.step_context(seg=seg, rel=rel, bt=bt, cu=cu_w,
+                                     kvl=kvl_w, fresh=None, **shared)
+                # every row is its own logit row (lidx == identity)
+                logits, pools, _ = _ls.forward(params, tok, pools, c)
                 with jax.named_scope("sample"):
                     keys = advance_keys(base_keys, gen)
                     sampled = sample_tokens(
-                        logits, {"temps": samp["temps"],
-                                 "top_k": samp["top_k"],
-                                 "top_p": samp["top_p"],
-                                 "penalty": samp["penalty"],
-                                 "seen": seen, "keys": keys})
+                        logits, dict(samp, seen=seen, keys=keys))
                     fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
                 # frozen rows carry their last committed token so the
                 # grid's dead columns hold committed values, never
@@ -3306,183 +3098,24 @@ class LLMEngine:
                     & (gen + 1 < budgets)
                 adv = active.astype(jnp.int32)
                 return (i + 1, sampled, kvl + adv, nxt, gen + adv,
-                        seen, kc, vc, touts, fouts)
+                        seen, pools, touts, fouts)
 
             def cond(carry):
                 return (carry[0] < K) & jnp.any(carry[3])
 
             carry = (jnp.int32(0), toks, kvl, active, gen,
-                     samp["seen"], kc, vc,
+                     samp["seen"], pools,
                      jnp.zeros((K, B), jnp.int32),
                      jnp.ones((K, B), jnp.bool_))
             carry = lax.while_loop(cond, step, carry)
-            return carry[8], carry[9], carry[6], carry[7]
+            return carry[7:] + tuple(carry[6])
 
-        return self._wrap_tp_window(run, 9), (1, 2)
-
-    def _make_window_fn_q8(self):
-        """Int8-page variant of the decode window: the per-step q8 body
-        verbatim, except the fresh-page scale reset HOISTS out of the
-        loop.  The per-step program zeroes fresh pages' scale rows
-        inside every layer body because each launch consumes one fresh
-        batch; here the whole window's pages are handed out before
-        launch, and an in-body reset would wipe scales grown by earlier
-        window iterations — so the reset runs ONCE, before iteration 0,
-        when every fresh page is still unwritten (byte-equivalent)."""
-        nh, kvh, d = self._nh, self._kvh, self._hd
-        bs = self.block_size
-        B = self.max_num_seqs
-        K = self.decode_window
-        eps = self.config.rms_norm_eps
-        theta = self.config.rope_theta
-        tp = self.tp
-        nh, kvh = nh // tp, kvh // tp
-        shard_head = self._shard_head
-        mm, embed, head_logits = self._weight_ops()
-        use_pallas = self.attention_path.startswith("pallas")
-        quant_attn = _pa.ragged_paged_attention_quant_packed
-
-        def run(params, kc, vc, ks, vs, fresh, toks, kvl, active, gen,
-                budgets, eos_ids, base_keys, bt, samp):
-            rows = jnp.arange(B, dtype=jnp.int32)
-            ks = jnp.where(fresh[None, :, None], 0.0, ks)
-            vs = jnp.where(fresh[None, :, None], 0.0, vs)
-
-            def step(carry):
-                (i, tok, kvl, active, gen, seen, kc, vc, ks, vs, touts,
-                 fouts) = carry
-                seg, rel = _pa.decode_window_segments(active, kvl)
-                cu_w, kvl_w = _pa.decode_window_rows(active, kvl)
-                with jax.named_scope("embed"):
-                    x = embed(params, tok)                    # [B, H]
-
-                def body(x, inp):
-                    p, kcl, vcl, ksl, vsl = inp
-                    with jax.named_scope("norm"):
-                        h = _rms_weight(x, p["ln1"], eps)
-                    with jax.named_scope("qkv"):
-                        q = mm(h, p, "wq").reshape(B, nh, d)
-                        k = mm(h, p, "wk").reshape(B, kvh, d)
-                        v = mm(h, p, "wv").reshape(B, kvh, d)
-                    with jax.named_scope("rope"):
-                        q = _rope_positions(q, rel, theta)
-                        k = _rope_positions(k, rel, theta)
-                    with jax.named_scope("kv_write"):
-                        blk = bt[seg, rel // bs]              # [B]
-                        slot = rel % bs
-                        kf = k.astype(jnp.float32)
-                        vf = v.astype(jnp.float32)
-                        ks_old = ksl[blk]                     # [B, kvh]
-                        vs_old = vsl[blk]
-                        ksl = ksl.at[blk].max(
-                            jnp.max(jnp.abs(kf), axis=-1) / 127.0)
-                        vsl = vsl.at[blk].max(
-                            jnp.max(jnp.abs(vf), axis=-1) / 127.0)
-                        ks_new = ksl[blk]
-                        vs_new = vsl[blk]
-                        rk = jnp.where(ks_new > 0.0,
-                                       ks_old / jnp.maximum(ks_new, 1e-30),
-                                       0.0)
-                        rv = jnp.where(vs_new > 0.0,
-                                       vs_old / jnp.maximum(vs_new, 1e-30),
-                                       0.0)
-                        kp = jnp.round(kcl[blk].astype(jnp.float32)
-                                       * rk[:, :, None, None])
-                        vp = jnp.round(vcl[blk].astype(jnp.float32)
-                                       * rv[:, :, None, None])
-                        kcl = kcl.at[blk].set(
-                            jnp.clip(kp, -127, 127).astype(jnp.int8))
-                        vcl = vcl.at[blk].set(
-                            jnp.clip(vp, -127, 127).astype(jnp.int8))
-                        kq = jnp.round(kf / jnp.maximum(ks_new,
-                                                        1e-30)[:, :, None])
-                        vq = jnp.round(vf / jnp.maximum(vs_new,
-                                                        1e-30)[:, :, None])
-                        kcl = kcl.at[blk, :, slot, :].set(
-                            jnp.clip(kq, -127, 127).astype(jnp.int8))
-                        vcl = vcl.at[blk, :, slot, :].set(
-                            jnp.clip(vq, -127, 127).astype(jnp.int8))
-                    with jax.named_scope("attn"):
-                        if use_pallas:
-                            att = quant_attn(q, kcl, vcl, ksl, vsl, bt,
-                                             cu_w, kvl_w)
-                        else:
-                            att = _pa.ragged_paged_reference_quant_segrel(
-                                q, kcl, vcl, ksl, vsl, bt, seg, rel)
-                        att = att.astype(x.dtype)
-                        if tp > 1:
-                            att = lax.all_gather(att, "tp", axis=1,
-                                                 tiled=True)
-                    with jax.named_scope("o_proj"):
-                        x = x + mm(att.reshape(B, tp * nh * d), p, "wo")
-                    with jax.named_scope("norm"):
-                        h2 = _rms_weight(x, p["ln2"], eps)
-                    with jax.named_scope("mlp"):
-                        a = jax.nn.silu(
-                            mm(h2, p, "gate").astype(jnp.float32)
-                        ).astype(h2.dtype) * mm(h2, p, "up")
-                        x = x + mm(a, p, "down")
-                    return x, (kcl, vcl, ksl, vsl)
-
-                with jax.named_scope("layers"):
-                    x, (kc, vc, ks, vs) = _scan_layers(
-                        body, x, params["layers"], (kc, vc, ks, vs))
-                with jax.named_scope("norm"):
-                    h = _rms_weight(x, params["norm_f"], eps)
-                with jax.named_scope("head"):
-                    logits = head_logits(params, h)
-                    if shard_head:
-                        logits = lax.all_gather(logits, "tp", axis=1,
-                                                tiled=True)
-                with jax.named_scope("sample"):
-                    keys = advance_keys(base_keys, gen)
-                    sampled = sample_tokens(
-                        logits, {"temps": samp["temps"],
-                                 "top_k": samp["top_k"],
-                                 "top_p": samp["top_p"],
-                                 "penalty": samp["penalty"],
-                                 "seen": seen, "keys": keys})
-                    fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [B]
-                sampled = jnp.where(active, sampled, tok)
-                touts = touts.at[i].set(sampled)
-                fouts = fouts.at[i].set(fin | ~active)
-                seen = seen.at[rows, sampled].set(
-                    seen[rows, sampled] | active)
-                nxt = active & (sampled != eos_ids) \
-                    & (gen + 1 < budgets)
-                adv = active.astype(jnp.int32)
-                return (i + 1, sampled, kvl + adv, nxt, gen + adv,
-                        seen, kc, vc, ks, vs, touts, fouts)
-
-            def cond(carry):
-                return (carry[0] < K) & jnp.any(carry[3])
-
-            carry = (jnp.int32(0), toks, kvl, active, gen,
-                     samp["seen"], kc, vc, ks, vs,
-                     jnp.zeros((K, B), jnp.int32),
-                     jnp.ones((K, B), jnp.bool_))
-            carry = lax.while_loop(cond, step, carry)
-            return (carry[10], carry[11], carry[6], carry[7], carry[8],
-                    carry[9])
-
-        return self._wrap_tp_window(run, 10), (1, 2, 3, 4)
-
-    def _launch_window(self, toks, kvl, active, gen, budgets, eos_ids,
-                       base_keys, bt, samp):
-        prog = self._get_window_prog()
-        tail = (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt,
-                samp)
-        if self.kv_dtype == "int8":
-            touts, fouts, self._kc, self._vc, self._ks, self._vs = \
-                self._call_program(
-                    prog, (self.params, self._kc, self._vc, self._ks,
-                           self._vs, self._consume_fresh()) + tail,
-                    self.max_num_seqs)
-        else:
-            touts, fouts, self._kc, self._vc = self._call_program(
-                prog, (self.params, self._kc, self._vc) + tail,
-                self.max_num_seqs)
-        return touts, fouts
+        # both non-pool outputs (the [K, B] token and finiteness grids)
+        # are replicated after the in-body all-gathers — every shard's
+        # while_loop sees identical replicated logits, so the
+        # active-mask and the early-exit condition agree across shards
+        # by construction
+        return self._wrap_tp(run, 9 + q8, 2), tuple(range(1, 1 + n_pools))
 
     def _fill_samp(self, samp, s, req):
         samp["temps"][s] = req.temperature

@@ -30,9 +30,22 @@ os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+@pytest.fixture
+def reference_tree():
+    """Root of the upstream checkout whose ``__all__`` lists the surface
+    tests hold this package to.  It is no part of the repository: where
+    it is absent those tests skip, and say that this is why."""
+    root = "/root/reference"
+    if not os.path.isdir(root):
+        pytest.skip(f"{root} (the upstream source the surface's names are "
+                    "read from) is not on this machine")
+    return root
 
 
 def pytest_configure(config):

@@ -3,7 +3,17 @@ builder now runs on ``layer_stack`` (inference/layer_stack.py), and what
 it traces must be, operation for operation, what the hand-written builder
 of the commit before traced.  ``_parent_ragged_fn`` below is that
 builder, frozen here (PR 27's ``LLMEngine._make_ragged_fn``, ``self``
-spelled ``eng``, comments dropped); the copy-on-write program likewise."""
+spelled ``eng``, comments dropped); the copy-on-write program likewise.
+
+The int8-page step, the decode window and the window over int8 pages
+went onto the same function one PR later (PR 30).  Their frozen side is
+written once below: PR 29's hand-written block with the page type's
+commit and attend (``_frozen_block``), the int8 step round it
+(``_frozen_ragged_fn``) and the window's loop round it
+(``_frozen_window_fn``).  It calls nothing of ``layer_stack`` but
+``scan_layers``, and was compared, jaxpr for jaxpr, with the literal
+text of PR 29's ``_make_ragged_fn_q8``, ``_make_window_fn`` and
+``_make_window_fn_q8`` before those were deleted (CHANGES.md, PR 30)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,8 +21,8 @@ import pytest
 from jax import lax
 
 from paddle_tpu.inference import LLMEngine, serving
-from paddle_tpu.inference.sampling import sample_tokens
-from paddle_tpu.inference.serving import _scan_layers
+from paddle_tpu.inference.sampling import advance_keys, sample_tokens
+from paddle_tpu.inference.layer_stack import scan_layers as _scan_layers
 from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                      _rms_weight, _rope_positions)
 from paddle_tpu.ops.pallas import paged_attention as _pa
@@ -95,6 +105,201 @@ def _parent_ragged_fn(eng, Tq):
     return eng._wrap_tp(run, 6), (1, 2)
 
 
+def _frozen_block(eng, Tq, seg, rel, bt, cu, kvl, fresh):
+    """PR 29's decoder block as ``_scan_layers`` takes it: float pages
+    commit with two scatters, int8 pages quantize at commit (``fresh``
+    None: the caller reset the scales already)."""
+    nh, kvh, d = eng._nh // eng.tp, eng._kvh // eng.tp, eng._hd
+    bs, tp = eng.block_size, eng.tp
+    eps, theta = eng.config.rms_norm_eps, eng.config.rope_theta
+    mm, _, _ = eng._weight_ops()
+    use_pallas = eng.attention_path.startswith("pallas")
+    q8 = eng.kv_dtype == "int8"
+
+    def body(x, inp):
+        p, pools = inp[0], inp[1:]
+        with jax.named_scope("norm"):
+            h = _rms_weight(x, p["ln1"], eps)
+        with jax.named_scope("qkv"):
+            q = mm(h, p, "wq").reshape(Tq, nh, d)
+            k = mm(h, p, "wk").reshape(Tq, kvh, d)
+            v = mm(h, p, "wv").reshape(Tq, kvh, d)
+        with jax.named_scope("rope"):
+            q = _rope_positions(q, rel, theta)
+            k = _rope_positions(k, rel, theta)
+        with jax.named_scope("kv_write"):
+            blk = bt[seg, rel // bs]
+            slot = rel % bs
+            if q8:
+                kcl, vcl, ksl, vsl = pools
+                kf = k.astype(jnp.float32)
+                vf = v.astype(jnp.float32)
+                if fresh is not None:
+                    ksl = jnp.where(fresh[:, None], 0.0, ksl)
+                    vsl = jnp.where(fresh[:, None], 0.0, vsl)
+                ks_old = ksl[blk]
+                vs_old = vsl[blk]
+                ksl = ksl.at[blk].max(jnp.max(jnp.abs(kf), axis=-1)
+                                      / 127.0)
+                vsl = vsl.at[blk].max(jnp.max(jnp.abs(vf), axis=-1)
+                                      / 127.0)
+                ks_new = ksl[blk]
+                vs_new = vsl[blk]
+                rk = jnp.where(ks_new > 0.0,
+                               ks_old / jnp.maximum(ks_new, 1e-30), 0.0)
+                rv = jnp.where(vs_new > 0.0,
+                               vs_old / jnp.maximum(vs_new, 1e-30), 0.0)
+                kp = jnp.round(kcl[blk].astype(jnp.float32)
+                               * rk[:, :, None, None])
+                vp = jnp.round(vcl[blk].astype(jnp.float32)
+                               * rv[:, :, None, None])
+                kcl = kcl.at[blk].set(
+                    jnp.clip(kp, -127, 127).astype(jnp.int8))
+                vcl = vcl.at[blk].set(
+                    jnp.clip(vp, -127, 127).astype(jnp.int8))
+                kq = jnp.round(kf / jnp.maximum(ks_new,
+                                                1e-30)[:, :, None])
+                vq = jnp.round(vf / jnp.maximum(vs_new,
+                                                1e-30)[:, :, None])
+                kcl = kcl.at[blk, :, slot, :].set(
+                    jnp.clip(kq, -127, 127).astype(jnp.int8))
+                vcl = vcl.at[blk, :, slot, :].set(
+                    jnp.clip(vq, -127, 127).astype(jnp.int8))
+                pools = (kcl, vcl, ksl, vsl)
+            else:
+                kcl, vcl = pools
+                kcl = kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype))
+                vcl = vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype))
+                pools = (kcl, vcl)
+        with jax.named_scope("attn"):
+            if q8 and use_pallas:
+                att = _pa.ragged_paged_attention_quant_packed(
+                    q, *pools, bt, cu, kvl)
+            elif q8:
+                att = _pa.ragged_paged_reference_quant_segrel(
+                    q, *pools, bt, seg, rel)
+            elif use_pallas:
+                att = _pa.ragged_paged_attention_packed(
+                    q, *pools, bt, cu, kvl)
+            else:
+                att = _pa.ragged_paged_reference_segrel(
+                    q, *pools, bt, seg, rel)
+            if q8:
+                att = att.astype(x.dtype)
+            if tp > 1:
+                att = lax.all_gather(att, "tp", axis=1, tiled=True)
+        with jax.named_scope("o_proj"):
+            x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
+        with jax.named_scope("norm"):
+            h2 = _rms_weight(x, p["ln2"], eps)
+        with jax.named_scope("mlp"):
+            a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
+                            ).astype(h2.dtype) * mm(h2, p, "up")
+            x = x + mm(a, p, "down")
+        return x, pools
+
+    return body
+
+
+def _frozen_logits(eng, params, toks, pools, body, lidx):
+    """Embed, the scanned block, norm and head round ``body``."""
+    _, embed, head_logits = eng._weight_ops()
+    with jax.named_scope("embed"):
+        x = embed(params, toks)
+    with jax.named_scope("layers"):
+        x, pools = _scan_layers(body, x, params["layers"], pools)
+    with jax.named_scope("norm"):
+        h = _rms_weight(x, params["norm_f"], eng.config.rms_norm_eps)
+    with jax.named_scope("head"):
+        if lidx is not None:
+            h = h[lidx]
+        logits = head_logits(params, h)
+        if eng._shard_head:
+            logits = lax.all_gather(logits, "tp", axis=1, tiled=True)
+    return logits, pools
+
+
+def _frozen_ragged_fn(eng, Tq):
+    """PR 29's step over either page type (``_make_ragged_fn_q8`` over
+    int8 pages)."""
+    q8 = eng.kv_dtype == "int8"
+    n = 4 if q8 else 2
+    with_logits = eng._with_logits
+
+    def run(params, *rest):
+        pools, host = rest[:n], rest[n:]
+        fresh = host[0] if q8 else None
+        toks, cu, kvl, bt, lidx, samp = host[-6:]
+        seg, rel = _pa.ragged_segments(cu, kvl, Tq)
+        body = _frozen_block(eng, Tq, seg, rel, bt, cu, kvl, fresh)
+        logits, pools = _frozen_logits(eng, params, toks, pools, body,
+                                       lidx)
+        with jax.named_scope("sample"):
+            sampled = sample_tokens(logits, samp)
+            fin = jnp.all(jnp.isfinite(logits), axis=-1)
+        if with_logits:
+            return (sampled, fin, logits) + pools
+        return (sampled, fin) + pools
+
+    return eng._wrap_tp(run, 6 + q8), tuple(range(1, 1 + n))
+
+
+def _frozen_window_fn(eng):
+    """PR 29's ``_make_window_fn`` / ``_make_window_fn_q8``: the loop
+    round the block at Tq = B, the int8 scales reset once before it."""
+    B, K = eng.max_num_seqs, eng.decode_window
+    q8 = eng.kv_dtype == "int8"
+    n = 4 if q8 else 2
+
+    def run(params, *rest):
+        pools, host = rest[:n], rest[n:]
+        (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt,
+         samp) = host[-9:]
+        rows = jnp.arange(B, dtype=jnp.int32)
+        if q8:
+            kc, vc, ks, vs = pools
+            ks = jnp.where(host[0][None, :, None], 0.0, ks)
+            vs = jnp.where(host[0][None, :, None], 0.0, vs)
+            pools = (kc, vc, ks, vs)
+
+        def step(carry):
+            i, tok, kvl, active, gen, seen = carry[:6]
+            pools, (touts, fouts) = carry[6:6 + n], carry[6 + n:]
+            seg, rel = _pa.decode_window_segments(active, kvl)
+            cu_w, kvl_w = _pa.decode_window_rows(active, kvl)
+            body = _frozen_block(eng, B, seg, rel, bt, cu_w, kvl_w, None)
+            logits, pools = _frozen_logits(eng, params, tok, pools, body,
+                                           None)
+            with jax.named_scope("sample"):
+                keys = advance_keys(base_keys, gen)
+                sampled = sample_tokens(
+                    logits, {"temps": samp["temps"],
+                             "top_k": samp["top_k"],
+                             "top_p": samp["top_p"],
+                             "penalty": samp["penalty"],
+                             "seen": seen, "keys": keys})
+                fin = jnp.all(jnp.isfinite(logits), axis=-1)
+            sampled = jnp.where(active, sampled, tok)
+            touts = touts.at[i].set(sampled)
+            fouts = fouts.at[i].set(fin | ~active)
+            seen = seen.at[rows, sampled].set(seen[rows, sampled] | active)
+            nxt = active & (sampled != eos_ids) & (gen + 1 < budgets)
+            adv = active.astype(jnp.int32)
+            return (i + 1, sampled, kvl + adv, nxt, gen + adv, seen,
+                    *pools, touts, fouts)
+
+        def cond(carry):
+            return (carry[0] < K) & jnp.any(carry[3])
+
+        carry = (jnp.int32(0), toks, kvl, active, gen, samp["seen"],
+                 *pools, jnp.zeros((K, B), jnp.int32),
+                 jnp.ones((K, B), jnp.bool_))
+        carry = lax.while_loop(cond, step, carry)
+        return carry[6 + n:] + carry[6:6 + n]
+
+    return eng._wrap_tp(run, 9 + q8, 2), tuple(range(1, 1 + n))
+
+
 def _parent_cow_fn(eng):
     def run(kc, vc, s, d):
         kc = kc.at[:, d].set(kc[:, s])
@@ -126,6 +331,57 @@ def test_dense_step_program_is_the_parents(model, kw, Tq):
     old, old_donate = _parent_ragged_fn(eng, Tq)
     assert donate == old_donate == (1, 2)
     assert str(jax.make_jaxpr(new)(*args)) == str(jax.make_jaxpr(old)(*args))
+
+
+_STEP_KW = [{}, {"drafter": "ngram", "spec_k": 2}, {"tp": 2}]
+_STEP_IDS = ["plain", "with_logits", "tp2"]
+
+
+def _same_program(new, old, args):
+    (new, donate), (old, old_donate) = new, old
+    assert tuple(donate) == tuple(old_donate)
+    assert str(jax.make_jaxpr(new)(*args)) == str(jax.make_jaxpr(old)(*args))
+    return tuple(donate)
+
+
+@pytest.mark.parametrize("kw", _STEP_KW, ids=_STEP_IDS)
+@pytest.mark.parametrize("Tq", [4, 32])
+def test_int8_page_step_program_is_the_parents(model, kw, Tq):
+    eng = _engine(model, kv_dtype="int8", **kw)
+    assert _same_program(eng._make_ragged_fn(Tq), _frozen_ragged_fn(eng, Tq),
+                         eng._ragged_arg_structs(Tq)) == (1, 2, 3, 4)
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_decode_window_program_is_the_parents(model, kv_dtype, tp):
+    eng = _engine(model, kv_dtype=kv_dtype, tp=tp, decode_window=4)
+    assert _same_program(eng._make_window_fn(), _frozen_window_fn(eng),
+                         eng._window_arg_structs()) \
+        == ((1, 2, 3, 4) if kv_dtype == "int8" else (1, 2))
+
+
+def test_programs_over_int8_weights_and_pages_are_the_parents(model):
+    eng = _engine(model, kv_dtype="int8", weight_dtype="int8",
+                  decode_window=4)
+    specs = {s.name: s for s in eng.program_specs()}
+    assert sorted(specs) == ["serving.cow_copy_q8_w8",
+                             "serving.decode_window_q8_w8",
+                             "serving.ragged_step_q8_w8"]
+    step, win = (specs["serving.ragged_step_q8_w8"],
+                 specs["serving.decode_window_q8_w8"])
+    _same_program((step.fn, step.donate_argnums),
+                  _frozen_ragged_fn(eng, 16), step.args)
+    _same_program((win.fn, win.donate_argnums), _frozen_window_fn(eng),
+                  win.args)
+
+
+def test_frozen_block_is_the_frozen_float_step(model):
+    """The block the new cases freeze is, over float pages, the step PR 27
+    froze above: one composition, held to the older literal text."""
+    eng = _engine(model)
+    _same_program(_frozen_ragged_fn(eng, 32), _parent_ragged_fn(eng, 32),
+                  eng._ragged_arg_structs(32))
 
 
 def test_dense_programs_of_program_specs_are_the_parents(model):

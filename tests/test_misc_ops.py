@@ -161,10 +161,10 @@ def test_as_strided():
     np.testing.assert_allclose(out.numpy(), want)
 
 
-def test_tensor_method_surface_complete():
+def test_tensor_method_surface_complete(reference_tree):
     """Every reference tensor_method_func name is bound on Tensor."""
     import ast
-    src = open("/root/reference/python/paddle/tensor/__init__.py").read()
+    src = open(reference_tree + "/python/paddle/tensor/__init__.py").read()
     names = []
     for node in ast.walk(ast.parse(src)):
         if isinstance(node, ast.Assign):

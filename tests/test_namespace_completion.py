@@ -129,13 +129,11 @@ def test_text_datasets_and_viterbi_layer():
     assert paths.shape == [1, 4]
 
 
-def test_incubate_surfaces_complete():
+def test_incubate_surfaces_complete(reference_tree):
     for mod, path in [
-            ("incubate.nn",
-             "/root/reference/python/paddle/incubate/nn/__init__.py"),
-            ("incubate",
-             "/root/reference/python/paddle/incubate/__init__.py")]:
-        names = _ref_all(path)
+            ("incubate.nn", "python/paddle/incubate/nn/__init__.py"),
+            ("incubate", "python/paddle/incubate/__init__.py")]:
+        names = _ref_all(os.path.join(reference_tree, path))
         obj = paddle
         for part in mod.split("."):
             obj = getattr(obj, part)
