@@ -161,7 +161,6 @@ def save_state_dict(state_dict, path, process_group=None,
     def _write():
         for fname, local in pending_writes:
             np.save(os.path.join(path, fname), local)
-        _issued_uids.get(os.path.abspath(path), set()).discard(unique_id)
         # metadata LAST: its presence marks the version complete for load
         # (each rank writes its OWN file — no write races; load merges)
         tmp = os.path.join(path, f".metadata_{unique_id}.{rank}.json.tmp")
@@ -169,6 +168,9 @@ def save_state_dict(state_dict, path, process_group=None,
             json.dump(meta, f)
         os.replace(tmp,
                    os.path.join(path, f"metadata_{unique_id}.{rank}.json"))
+        # only now does a directory scan see this uid: dropped before the
+        # metadata was there, a save that scanned in between took it again
+        _issued_uids.get(os.path.abspath(path), set()).discard(unique_id)
         if rank == coordinator_rank and keep is not None:
             _prune_old_versions(path, unique_id, keep)
 

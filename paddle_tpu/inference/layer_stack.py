@@ -14,12 +14,15 @@ and expert layers after it), runs its layers one after another over the
 model's own arrays.
 
 Attention kinds: ``gqa`` (rotary grouped-query attention over K and V
-pages ``[num_blocks, Hkv, bs, D]``, one layer's slice at a time; float
-pages, or int8 pages with their two scale pools, by what it is handed)
-and ``mla`` (latent attention in the absorbed form over ONE pool for all
-layers, ``[L, num_blocks, bs, width]``, written and read in place at the
-layer's index).  FFN kinds: ``swiglu`` and ``moe`` (routed experts held
-here plus shared experts: ``models/mla_moe.py``).
+pages ``[L, num_blocks, Hkv, bs, D]``; float pages, or int8 pages with
+their two scale pools, by what it is handed) and ``mla`` (latent
+attention in the absorbed form over ONE pool, ``[L, num_blocks, bs,
+width]``).  One contract for both, scanned or unrolled: a layer gets the
+pools of ALL layers and its index, scatters the step's rows in place at
+(layer, page, slot) and its kernel reads pages where they lie at a
+prefetched layer index.  No layer-sized slice of a pool is ever made.
+FFN kinds: ``swiglu`` and ``moe`` (routed experts held here plus shared
+experts: ``models/mla_moe.py``).
 
 The ``jax.named_scope`` names below are what a device trace is read by
 (docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
@@ -45,21 +48,22 @@ from ..ops.pallas import paged_attention as _pa
 def scan_layers(body, x, layers, pools):
     """``lax.scan`` over the stacked layers with the stacked pools (K and
     V pages; over int8 pages their scales too) in the CARRY: each turn
-    slices its layer's pools out, gives them to ``body(x, (p, *pools))
-    -> (x, pools)`` and writes what comes back in place.  Scanned
-    through as inputs and outputs the pools came back in a new buffer,
-    and aliasing it to the donated input cost a copy of each whole pool
-    a step (3.2 ms a GB on the v5e) that XLA makes up, so that no scope
-    names it in a trace."""
+    gives ``body(x, p, pools, l) -> (x, pools)`` its layer's weights,
+    the WHOLE pools and the layer index, which is what an unrolled
+    segment's layers get.  The kinds write and read the pools where they
+    lie, at ``l``: a turn that sliced its layer's pages out and wrote
+    them back cost four layer-sized copies of each pool a layer (three
+    quarters of a dense step's device time on the v5e).  Scanned through
+    as inputs and outputs the pools came back in a new buffer, and
+    aliasing it to the donated input cost a copy of each whole pool a
+    step (3.2 ms a GB) that XLA makes up, so that no scope names it in a
+    trace."""
     n = jax.tree_util.tree_leaves(layers)[0].shape[0]
 
     def turn(carry, inp):
-        x, pools = carry
-        p, l = inp
-        x, new = body(x, (p,) + tuple(
-            lax.dynamic_index_in_dim(c, l, keepdims=False) for c in pools))
-        return (x, tuple(lax.dynamic_update_index_in_dim(c, v, l, 0)
-                         for c, v in zip(pools, new))), None
+        (x, pools), (p, l) = carry, inp
+        x, pools = body(x, p, pools, l)
+        return (x, tuple(pools)), None
 
     (x, pools), _ = lax.scan(turn, (x, tuple(pools)),
                              (layers, jnp.arange(n, dtype=jnp.int32)))
@@ -77,20 +81,43 @@ def step_context(**kw) -> SimpleNamespace:
 
 
 # ---------------------------------------------------------------------------
-# attention kinds: (x, h, p, pools, layer, c) -> (x, pools)
+# attention kinds: (x, h, p, pools, layer, c) -> (x, pools), over the
+# pools of ALL layers, written and read in place at ``layer``
 # ---------------------------------------------------------------------------
 
-# ``gqa`` over either page type: commit(k, v, pools, blk, slot, c) ->
-# pools writes the step's rows at (page, slot); attend(q, pools, c) ->
-# [Tq, heads, d] reads the pages.  S10 (ROADMAP) lands in these pairs.
+# ``gqa`` over either page type: commit(k, v, pools, at, c) -> pools
+# writes the step's rows at ``at`` = (layer, page, slot) into the pools
+# of all layers; attend(q, pools, layer, c) -> [Tq, heads, d] reads that
+# layer's pages where they lie.
 
-def _commit_float(k, v, pools, blk, slot, c):
-    kcl, vcl = pools
-    return (kcl.at[blk, :, slot, :].set(k.astype(kcl.dtype)),
-            vcl.at[blk, :, slot, :].set(v.astype(vcl.dtype)))
+def _set_rows(pool, at, rows):
+    """``rows`` [Tq, Hkv, D] into ``pool`` [L, num_blocks, Hkv, bs, D] at
+    (layer, page [Tq], slot [Tq]): every page axis is indexed (layer,
+    page, head, slot, folded into the row's number in the pool seen as
+    ``[L * num_blocks * Hkv * bs, D]``, a bitcast), so the update window
+    is the contiguous minor ``D`` and XLA scatters Tq * Hkv rows into
+    the donated buffer in place: 0.22 ms a layer for K and V at 192
+    tokens and 8 heads on the v5e, 0.04 at 32 (PERF.md, PR 31).
+    ``pool.at[layer, page, :, slot]`` writes the same values, but its
+    window [Hkv, D] straddles the slot axis: XLA then keeps the WHOLE
+    pool in a slot-major layout through the layer loop and copies all
+    of it back to row-major for the kernel's custom call in every layer
+    (tests/test_chip_lowering.py)."""
+    layer, blk, slot = at
+    _, nb, hkv, bs, d = pool.shape
+    heads = jnp.arange(hkv, dtype=jnp.int32)
+    row = ((layer * nb + blk[:, None]) * hkv + heads[None, :]) * bs \
+        + slot[:, None]                                   # [Tq, Hkv]
+    return pool.reshape(-1, d).at[row].set(
+        rows.astype(pool.dtype)).reshape(pool.shape)
 
 
-def _attend_float(q, pools, c):
+def _commit_float(k, v, pools, at, c):
+    kc, vc = pools
+    return _set_rows(kc, at, k), _set_rows(vc, at, v)
+
+
+def _attend_float(q, pools, layer, c):
     if c.use_pallas:
         # the host packing path owns these buffers: bt is the int32
         # NULL_BLOCK-padded pool table and cu, kvl come int32 from
@@ -98,11 +125,12 @@ def _attend_float(q, pools, c):
         # per-launch re-clip and re-cast.  The kernel reads the row
         # layout itself; seg/rel are for rope and kv_write
         return _pa.ragged_paged_attention_packed(q, *pools, c.bt, c.cu,
-                                                 c.kvl)
-    return _pa.ragged_paged_reference_segrel(q, *pools, c.bt, c.seg, c.rel)
+                                                 c.kvl, layer=layer)
+    return _pa.ragged_paged_reference_segrel(
+        q, *(pool[layer] for pool in pools), c.bt, c.seg, c.rel)
 
 
-def _commit_int8(k, v, pools, blk, slot, c):
+def _commit_int8(k, v, pools, at, c):
     """Quantize at commit, per layer, per launch:
     1. zero the scale rows of ``fresh`` pages (pages BlockManager handed
        out since the last launch: their old content AND old scales are
@@ -120,8 +148,13 @@ def _commit_int8(k, v, pools, blk, slot, c):
     scatter-max makes every duplicate observe the same settled scale,
     so duplicate re-encodes write identical bytes.  Under tp the scale
     pools slice along the same H_kv axis as the page pools, so all of
-    this stays per-head-local."""
-    kcl, vcl, ksl, vsl = pools
+    this stays per-head-local.  The page pools are touched in place: a
+    gather of the launch's pages, a scatter of whole pages back and the
+    scatter of the rows; the layer's scale rows (a word a page and head)
+    are taken out and put back."""
+    kc, vc, ks, vs = pools
+    layer, blk, _ = at
+    ksl, vsl = ks[layer], vs[layer]                       # [num_blocks, kvh]
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     if c.fresh is not None:
@@ -135,35 +168,35 @@ def _commit_int8(k, v, pools, blk, slot, c):
     vs_new = vsl[blk]
     rk = jnp.where(ks_new > 0.0, ks_old / jnp.maximum(ks_new, 1e-30), 0.0)
     rv = jnp.where(vs_new > 0.0, vs_old / jnp.maximum(vs_new, 1e-30), 0.0)
-    kp = jnp.round(kcl[blk].astype(jnp.float32) * rk[:, :, None, None])
-    vp = jnp.round(vcl[blk].astype(jnp.float32) * rv[:, :, None, None])
-    kcl = kcl.at[blk].set(jnp.clip(kp, -127, 127).astype(jnp.int8))
-    vcl = vcl.at[blk].set(jnp.clip(vp, -127, 127).astype(jnp.int8))
+    kp = jnp.round(kc[layer, blk].astype(jnp.float32) * rk[:, :, None, None])
+    vp = jnp.round(vc[layer, blk].astype(jnp.float32) * rv[:, :, None, None])
+    kc = kc.at[layer, blk].set(jnp.clip(kp, -127, 127).astype(jnp.int8))
+    vc = vc.at[layer, blk].set(jnp.clip(vp, -127, 127).astype(jnp.int8))
     kq = jnp.round(kf / jnp.maximum(ks_new, 1e-30)[:, :, None])
     vq = jnp.round(vf / jnp.maximum(vs_new, 1e-30)[:, :, None])
-    kcl = kcl.at[blk, :, slot, :].set(
-        jnp.clip(kq, -127, 127).astype(jnp.int8))
-    vcl = vcl.at[blk, :, slot, :].set(
-        jnp.clip(vq, -127, 127).astype(jnp.int8))
-    return kcl, vcl, ksl, vsl
+    return (_set_rows(kc, at, jnp.clip(kq, -127, 127)),
+            _set_rows(vc, at, jnp.clip(vq, -127, 127)),
+            ks.at[layer].set(ksl), vs.at[layer].set(vsl))
 
 
-def _attend_int8(q, pools, c):
+def _attend_int8(q, pools, layer, c):
     if c.use_pallas:
         # packed-entry invariant as over float pages; the scale pools
         # are born f32 on the host
         att = _pa.ragged_paged_attention_quant_packed(q, *pools, c.bt,
-                                                      c.cu, c.kvl)
+                                                      c.cu, c.kvl,
+                                                      layer=layer)
     else:
-        att = _pa.ragged_paged_reference_quant_segrel(q, *pools, c.bt,
-                                                      c.seg, c.rel)
+        att = _pa.ragged_paged_reference_quant_segrel(
+            q, *(pool[layer] for pool in pools), c.bt, c.seg, c.rel)
     return att.astype(q.dtype)
 
 
-def _gqa(x, h, p, pools, _layer, c):
-    """Grouped-query attention over this layer's pages.  What differs
-    between page types is a pair, picked by what the layer is handed:
-    commit the step's K/V rows into the pools, and attend over them."""
+def _gqa(x, h, p, pools, layer, c):
+    """Grouped-query attention over this layer's pages of the pools of
+    all layers.  What differs between page types is a pair, picked by
+    what the layer is handed: commit the step's K/V rows into the pools
+    at (layer, page, slot), and attend over the layer's pages."""
     commit, attend = (_commit_int8, _attend_int8) \
         if pools[0].dtype == jnp.int8 else (_commit_float, _attend_float)
     Tq, nh, kvh, d, tp, mm = c.Tq, c.nh, c.kvh, c.d, c.tp, c.mm
@@ -177,9 +210,9 @@ def _gqa(x, h, p, pools, _layer, c):
     with jax.named_scope("kv_write"):
         blk = c.bt[c.seg, c.rel // c.bs]                  # [Tq]
         slot = c.rel % c.bs
-        pools = commit(k, v, pools, blk, slot, c)
+        pools = commit(k, v, pools, (layer, blk, slot), c)
     with jax.named_scope("attn"):
-        att = attend(q, pools, c)
+        att = attend(q, pools, layer, c)
         if tp > 1:
             # tiled gather concatenates shard head blocks in mesh order
             # — exactly the tp=1 head layout, so the replicated wo
@@ -255,14 +288,16 @@ def _unrolled(layer, x, layers, pools):
 
 def layer_stack(x, segments, pools, c):
     """Run the layers.  ``segments``: [((attention kind, FFN kind),
-    layers, scanned)].  A scanned segment's ``layers`` is a pytree with a
-    leading layer axis and its pools are sliced a layer at a time; an
-    unrolled segment's is [(layer index, that layer's weights)] and its
-    kinds get the whole pools and the index; its layers, alike in kind
-    and shape, are traced and lowered ONCE and called with the index as
-    an operand (traced layer by layer, six layers of two Pallas kernels
-    each took 8.7 s a token bucket of every process start on the v5e's
-    host, compile cache or not).  Returns (x, pools, counts): what the
+    layers, scanned)].  Either way a layer's kinds get its weights, the
+    whole pools and the layer's index, and write and read the pools in
+    place at that index.  A scanned segment's ``layers`` is a pytree
+    with a leading layer axis, one ``lax.scan`` with the pools in its
+    carry; an unrolled segment's is [(layer index, that layer's
+    weights)]: its layers, alike in kind and shape, are traced and
+    lowered ONCE and called with the index as an operand (traced layer
+    by layer, six layers of two Pallas kernels each took 8.7 s a token
+    bucket of every process start on the v5e's host, compile cache or
+    not).  Returns (x, pools, counts): what the
     expert layers counted, summed over layers (the largest load: the
     largest), int32 [4], or None without expert layers."""
     pools = tuple(pools)
@@ -280,9 +315,8 @@ def layer_stack(x, segments, pools, c):
             return x, pools, counts
 
         if scanned:
-            x, pools = scan_layers(
-                lambda x, inp: layer(x, inp[0], inp[1:], None)[:2],
-                x, layers, pools)
+            x, pools = scan_layers(lambda *a: layer(*a)[:2], x, layers,
+                                   pools)
         else:
             x, pools, counts = _unrolled(layer, x, layers, pools)
             counted += counts

@@ -2900,9 +2900,12 @@ class LLMEngine:
 
         The forward is ``layer_stack.forward`` for every model and page
         type (inference/layer_stack.py): the dense decoder is one
-        scanned segment over its stacked weights with the pools sliced a
-        layer at a time; a latent-attention model runs layer after layer
-        over its own arrays and one pool."""
+        scanned segment over its stacked weights, a latent-attention
+        model runs layer after layer over its own arrays.  Either way
+        the donated pools hold all layers, a layer scatters its rows
+        into them in place at (layer, page, slot) and its kernel reads
+        them at a layer index: no layer-sized slice of a pool is made,
+        and the pools that come back are the buffers that went in."""
         with_logits = self._with_logits
         n_pools = len(self._pools())
         q8 = self.kv_dtype == "int8"

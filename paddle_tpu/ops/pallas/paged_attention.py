@@ -27,8 +27,10 @@ dequantising load (`ragged_paged_attention_quant`).
 (a grid step a page slot of the table), kept for the incubating blha path
 and as a second oracle.
 
-Layout: caches are [num_blocks, H_kv, bs, D] (blha cache layout), block
-tables int32, per-row lengths int32.  GQA is native: a score tile carries
+Layout: caches are [num_blocks, H_kv, bs, D] a layer (blha cache layout;
+a step program hands the ragged launch the pools of all layers,
+[L, num_blocks, H_kv, bs, D], and a layer index), block tables int32,
+per-row lengths int32.  GQA is native: a score tile carries
 the q-head group of one kv head, [tq*G, kv_pages*bs].
 """
 from __future__ import annotations
@@ -263,12 +265,14 @@ def _ragged_tiles(Tq, Hkv, G, D, bs, nblk, dtype):
     return tq, max(1, kvb)
 
 
-def _ragged_kernel(cu_ref, kvl_ref, bt_ref, *refs, rows, tq, kvb, bs, nblk,
-                   quant):
+def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
+                   bs, nblk, quant):
     """One invocation walks the launch's rows in order.  Refs: q
     [Tq, Hkv, G, D] and the same tokens head-major qt [Hkv, Tq*G, D],
-    both pre-scaled, in VMEM; the K and V pools [num_blocks, Hkv, bs, D]
-    left in HBM; o [Tq, Hkv, G, D] in VMEM.  Scratch: kbuf/vbuf
+    both pre-scaled, in VMEM; the K and V pools of ALL layers
+    [L, num_blocks, Hkv, bs, D] left in HBM and read at the prefetched
+    layer index ``layer_ref[0]`` (no other layer is touched); o
+    [Tq, Hkv, G, D] in VMEM.  Scratch: kbuf/vbuf
     [2, kvb, Hkv, bs, D] (two slots of kvb whole pages), DMA semaphores
     [2, 2] (K/V x slot), m/l [Hkv, tq*G, 1] and acc [Hkv, tq*G, D] f32.
 
@@ -280,16 +284,18 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, *refs, rows, tq, kvb, bs, nblk,
     store.  An item walks K/V blocks of kvb pages from page 0 to the
     page of the last key its last query may see and no further: pages
     come from the pool by one async copy each (a page's [Hkv, bs, D] is
-    contiguous), a block ahead of the arithmetic, and each item starts
-    its successor's first block, so a copy is in flight across items
-    too.  A row of no queries, or of no keys, is an item of no blocks;
-    o is zeroed first, so padding and such rows read zero.
+    contiguous, in the stacked pool too), a block ahead of the
+    arithmetic, and each item starts its successor's first block, so a
+    copy is in flight across items too.  A row of no queries, or of no
+    keys, is an item of no blocks; o is zeroed first, so padding and
+    such rows read zero.
 
     Over int8 pages (``quant``) two more prefetched operands lead refs:
-    the [num_blocks, Hkv] f32 scale pools, in scalar memory beside the
-    table.  A page is dequantized as it is read for the product (float
-    = int8 * its page's scale for the head), q and the probabilities
-    stay float32, and no dense float copy of a row's K/V ever exists.
+    this layer's [num_blocks, Hkv] f32 scale pools, in scalar memory
+    beside the table.  A page is dequantized as it is read for the
+    product (float = int8 * its page's scale for the head), q and the
+    probabilities stay float32, and no dense float copy of a row's K/V
+    ever exists.
     """
     if quant:
         ksc_ref, vsc_ref, *refs = refs
@@ -297,6 +303,7 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, *refs, rows, tq, kvb, bs, nblk,
      acc_ref) = refs
     Hkv, G, D = q_ref.shape[1:]
     kv = kvb * bs
+    layer = layer_ref[0]
 
     def heads(fn):
         """fn(h) for every K/V head: unrolled eight at a time (a body of
@@ -338,9 +345,11 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, *refs, rows, tq, kvb, bs, nblk,
 
         def one(p, c):
             blk = bt_ref[r, b * kvb + p]
-            act(pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, p],
+            act(pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                      kbuf.at[slot, p],
                                       sems.at[0, slot]))
-            act(pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, p],
+            act(pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                      vbuf.at[slot, p],
                                       sems.at[1, slot]))
             return c
         jax.lax.fori_loop(0, n, one, 0)
@@ -544,15 +553,22 @@ def decode_window_rows(active, kv_lens):
 
 
 def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
-                   kv_lens, scales=()):
-    """The raw ragged launch.  Callers must satisfy the packed-operand
+                   kv_lens, scales=(), layer=None):
+    """The raw ragged launch.  With ``layer`` (an int32 scalar, traced
+    or static) the caches are the pools of ALL layers,
+    [L, num_blocks, Hkv, bs, D], read where they lie at that index,
+    which is prefetched beside the table (a layer-sized slice of a pool
+    would cost a copy of it a layer a step); without it they are one
+    layer's, a stack of one.  Callers must satisfy the packed-operand
     invariant: int32 scalar operands, cu_seqlens [R+1] non-decreasing
     with cu[R] <= Tq, and every table entry in [0, num_blocks).  The
     table may carry more rows than kv_lens (serving's null row): they
-    are not read.  ``scales`` is empty over float pages and the two
-    [num_blocks, Hkv] f32 scale pools over int8 pages."""
+    are not read.  ``scales`` is empty over float pages and the
+    layer's two [num_blocks, Hkv] f32 scale pools over int8 pages."""
+    if layer is None:
+        key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
     Tq, H, D = q.shape
-    _, Hkv, bs, _ = key_cache.shape
+    _, _, Hkv, bs, _ = key_cache.shape
     G = H // Hkv
     rows = kv_lens.shape[0]
     nblk = block_tables.shape[1]
@@ -580,8 +596,9 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            # cu, kv_lens, block_tables and, over int8 pages, the scales
-            num_scalar_prefetch=3 + len(scales),
+            # cu, kv_lens, block_tables, the layer index and, over int8
+            # pages, the scales
+            num_scalar_prefetch=4 + len(scales),
             grid=(1,),
             in_specs=[vmem, vmem, hbm, hbm],
             out_specs=vmem,
@@ -600,19 +617,23 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
         interpret=interpret_mode(),
         name="ragged_paged_attention_q8" if quant
         else "ragged_paged_attention",
-    )(cu_seqlens, kv_lens, block_tables, *scales, qr, qt, key_cache,
+    )(cu_seqlens, kv_lens, block_tables,
+      jnp.asarray(layer, jnp.int32).reshape(1), *scales, qr, qt, key_cache,
       value_cache)
     return out.reshape(Tq, H, D)
 
 
 def ragged_paged_attention_packed(q, key_cache, value_cache, block_tables,
-                                  cu_seqlens, kv_lens):
+                                  cu_seqlens, kv_lens, layer=None):
     """Ragged launch without the defensive clip/casts, for callers that
     guarantee the host-packing invariant (serving.py owns these buffers:
     its table pool is int32 and NULL_BLOCK-padded with valid indices,
-    cu and kv_lens come int32 from the step's packing)."""
+    cu and kv_lens come int32 from the step's packing).  With ``layer``
+    the caches are the pools of all layers, [L, num_blocks, H_kv, bs,
+    D], read in place at that index (what a step program passes);
+    without it, one layer's."""
     return _ragged_launch(q, key_cache, value_cache, block_tables,
-                          cu_seqlens, kv_lens)
+                          cu_seqlens, kv_lens, layer=layer)
 
 
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,
@@ -653,12 +674,19 @@ def ragged_paged_attention_quant(q, key_cache, value_cache, key_scales,
 
 def ragged_paged_attention_quant_packed(q, key_cache, value_cache,
                                         key_scales, value_scales,
-                                        block_tables, cu_seqlens, kv_lens):
+                                        block_tables, cu_seqlens, kv_lens,
+                                        layer=None):
     """Int8-page ragged launch without the defensive clip/casts, for
     callers that guarantee the host-packing invariant (serving.py packs
-    int32 tables/cu/kv_lens and f32 scale pools)."""
+    int32 tables/cu/kv_lens and f32 scale pools).  With ``layer`` all
+    four pools are those of all layers (the scales [L, num_blocks,
+    H_kv]): the pages are read in place, the layer's scale rows, which
+    ride in scalar memory, are sliced out here."""
+    if layer is not None:
+        key_scales, value_scales = key_scales[layer], value_scales[layer]
     return _ragged_launch(q, key_cache, value_cache, block_tables,
-                          cu_seqlens, kv_lens, (key_scales, value_scales))
+                          cu_seqlens, kv_lens, (key_scales, value_scales),
+                          layer)
 
 
 def ragged_paged_reference_quant_segrel(q, key_cache, value_cache,
@@ -728,14 +756,15 @@ _SMEM_RESERVE = 16 << 10
 def scalar_prefetch_bytes(table_rows, nblk, num_blocks, Hkv,
                           int8: bool) -> int:
     """Scalar memory the operands a ragged launch prefetches take: cu
-    and kv_lens (a word a table row, counted in whole lanes of 128
-    words), the block table [table_rows, nblk] and, over int8 pages, the
-    two [num_blocks, Hkv] f32 scale pools, each padded to (8, 128) tiles
-    of 32-bit words."""
+    and kv_lens (a word a table row) and the layer index (one word),
+    each counted in whole lanes of 128 words, the block table
+    [table_rows, nblk] and, over int8 pages, the two [num_blocks, Hkv]
+    f32 scale pools, each padded to (8, 128) tiles of 32-bit words."""
     def tiled(rows, cols):
         return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
 
-    need = 2 * -(-table_rows // 128) * 128 * 4 + tiled(table_rows, nblk)
+    need = (2 * -(-table_rows // 128) + 1) * 128 * 4 \
+        + tiled(table_rows, nblk)
     if int8:
         need += 2 * tiled(num_blocks, Hkv)
     return need

@@ -1,6 +1,7 @@
-"""The ragged attention kernel lowered for the chip this repo serves
-on, without the chip: the TPU's compiler is installed here and compiles
-for a v5e that is described, not attached.
+"""The ragged attention kernel, and the step programs round it, lowered
+for the chip this repo serves on, without the chip: the TPU's compiler
+is installed here and compiles for a v5e that is described, not
+attached.
 
 What it guards: the custom call that carries the kernel must depend on
 the kernel alone.  Mosaic serializes the kernel's MLIR module into the
@@ -14,6 +15,7 @@ frames; the suite's conftest calls it, as every entry point does.
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library, and every pytest worker imports
 every test file.  Keep these tests in this one file."""
+import math
 import re
 
 import pytest
@@ -180,9 +182,9 @@ def test_the_int8_page_kernel_compiles_for_the_v5e(one_chip,
 def test_prefetched_operands_are_what_the_claim_counts(one_chip,
                                                        compiled_kernels):
     """`scalar_prefetch_bytes` counts the operands the launch prefetches:
-    cu, kv_lens and the table (no per-token seg or rel), and both scale
-    pools over int8 pages, in the (8, 128) tiles of 32-bit words scalar
-    memory holds them in."""
+    cu, kv_lens, the table (no per-token seg or rel) and the layer index,
+    and both scale pools over int8 pages, in the (8, 128) tiles of 32-bit
+    words scalar memory holds them in."""
     def prefetched(fn, args):
         jaxpr = jax.make_jaxpr(fn)(*args)
         eqn, = [e for e in jaxpr.jaxpr.eqns if "pallas" in e.primitive.name]
@@ -196,15 +198,16 @@ def test_prefetched_operands_are_what_the_claim_counts(one_chip,
     kvh = WIDTHS["mistral-7b"][1]
     ops = prefetched(_direct, _shapes(one_chip, "mistral-7b", 192))
     assert [a.shape for a in ops] == [(ROWS + 1,), (ROWS,),
-                                      (ROWS + 1, NBLK)]
-    # a 1-D operand takes whole lanes, not a tile of eight rows
+                                      (ROWS + 1, NBLK), (1,)]
+    # a 1-D operand takes whole lanes, not a tile of eight rows: cu,
+    # kv_lens and the layer index
     assert pa.scalar_prefetch_bytes(ROWS + 1, NBLK, NUM_BLOCKS, kvh, False) \
-        == 2 * 128 * 4 + tiled(ops[2])
+        == 3 * 128 * 4 + tiled(ops[2])
     ops = prefetched(pa.ragged_paged_attention_quant_packed,
                      _shapes(one_chip, "mistral-7b", 32, quant=True))
-    assert [a.shape for a in ops[3:]] == [(NUM_BLOCKS, kvh)] * 2
+    assert [a.shape for a in ops[3:]] == [(1,)] + [(NUM_BLOCKS, kvh)] * 2
     assert pa.scalar_prefetch_bytes(ROWS + 1, NBLK, NUM_BLOCKS, kvh, True) \
-        == 2 * 128 * 4 + sum(tiled(a) for a in ops[2:])
+        == 3 * 128 * 4 + tiled(ops[2]) + sum(tiled(a) for a in ops[4:])
 
 
 # ---------------------------------------------------------------------------
@@ -370,3 +373,120 @@ def test_the_sampled_chain_compiles_into_one_branch(
     paths = [n for n in named if n.startswith("jit(")]
     assert len(paths) >= 10
     assert all("/sample/cond/" in n for n in paths)
+
+
+# ---------------------------------------------------------------------------
+# the dense step program reads and writes the K/V pools in place (PR 31)
+# ---------------------------------------------------------------------------
+
+_RESULT = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(")
+
+
+def _dense_step_text(chip, monkeypatch, model, tq, quant):
+    """(compiled text, the pools' shape) of the engine's ragged step
+    program at a configuration's attention widths, the benchmark's pool
+    (4097 pages of 16 tokens; over int8 pages the largest pool the
+    scalar memory takes, pages of 32) and eight scanned layers.  The
+    engine is a tiny one told the head counts (its builder reads
+    nothing else of the model), the arguments are shapes, and the model
+    round the attention is narrow (hidden 256): nothing of that size is
+    allocated here, and nothing but a pool is as large as a pool."""
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.inference.sampling import samp_structs
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    nh, kvh, d = WIDTHS[model]
+    L, H, F, V = 8, 256, 512, 1000
+    bs = 32 if quant else BLOCK
+    nb = max(n for n in range(8, 4096, 8) if pa.ineligible(
+        nh, kvh, d, bs, jnp.int8, launch=(ROWS + 1, NBLK, n)) is None) \
+        if quant else NUM_BLOCKS
+    eng = LLMEngine(
+        LlamaForCausalLM(LlamaConfig.tiny(vocab=V, hidden=H, layers=2,
+                                          heads=2, ffn=64, seq=64)),
+        max_num_seqs=ROWS, block_size=bs, max_model_len=64,
+        max_prefill_tokens=192, prefill_token_bucket=64,
+        kv_dtype="int8" if quant else "float32")
+    monkeypatch.setattr(eng, "_nh", nh)
+    monkeypatch.setattr(eng, "_kvh", kvh)
+    monkeypatch.setattr(eng, "_platform", "tpu")
+    eng.attention_path = eng._resolve_attention_path()
+    assert eng._hd == d and eng.attention_path == "pallas"
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = {"embed": sds((V, H)), "head": sds((H, V)), "norm_f": sds((H,)),
+              "layers": {"ln1": sds((L, H)), "ln2": sds((L, H)),
+                         "wq": sds((L, H, nh * d)), "wk": sds((L, H, kvh * d)),
+                         "wv": sds((L, H, kvh * d)), "wo": sds((L, nh * d, H)),
+                         "gate": sds((L, H, F)), "up": sds((L, H, F)),
+                         "down": sds((L, F, H))}}
+    pool = (L, nb, kvh, bs, d)
+    pools = (sds(pool, jnp.int8 if quant else jnp.bfloat16),) * 2
+    if quant:
+        pools += (sds(pool[:3], jnp.float32),) * 2 + (sds((nb,), jnp.bool_),)
+    i32 = jnp.int32
+    samp = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
+                                  samp_structs(eng._Lq, V))
+    args = (params,) + pools + (
+        sds((tq,), i32), sds((ROWS + 1,), i32), sds((ROWS,), i32),
+        sds((ROWS + 1, NBLK), i32), sds((eng._Lq,), i32), samp)
+    fn, donate = eng._make_ragged_fn(tq)
+    return jax.jit(fn, donate_argnums=donate).lower(
+        *args).compile().as_text(), pool
+
+
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+@pytest.mark.parametrize("tq", [32, 192])
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_the_dense_step_leaves_the_pools_where_they_lie(
+        one_chip, compiled_kernels, no_persistent_cache, monkeypatch, model,
+        tq, pages):
+    """No instruction of the compiled step program makes anything as
+    large as one layer of a K/V pool, whatever its shape or layout,
+    but the scatters of scope ``kv_write`` (in place: the program's
+    parameters are donated).  What else may carry a pool's shape moves
+    no byte: parameters, tuple elements, bitcasts.  And the kernel's
+    custom call takes the pools of all layers, not a layer's slice.
+
+    What this holds off: ``pool.at[layer, page, :, slot, :].set(rows)``
+    writes the same values and fails here.  Its update window [Hkv, D]
+    straddles the slot axis, so XLA lays the scatter's operand out
+    slot-major ({4,2,3,1,0}), keeps the WHOLE pool so through the layer
+    loop, and copies all of it (1.07 GB at Mistral's widths) back to
+    row-major for the custom call in every layer: ``copy
+    bf16[8,4097,8,16,128]`` under ``.../while/body/.../scatter``.  On a
+    layer's slice (PR 30) the same window cost four layer-sized copies
+    a layer: slice, slot-major, row-major, write back."""
+    text, pool = _dense_step_text(one_chip, monkeypatch, model, tq,
+                                  pages == "int8")
+    _, comps = _computations(text)
+    layer = math.prod(pool[1:])
+
+    def writes_rows(line):
+        return " scatter(" in line and "/kv_write/" in line
+
+    large = []
+    for lines in comps.values():
+        for line in lines:
+            m = _RESULT.match(line)
+            if m and math.prod(int(n or 1) for n in
+                               m.group(2).split(",")) >= layer:
+                large.append((m.group(3), line))
+    moved = [line for op, line in large
+             if op not in ("parameter", "get-tuple-element", "bitcast")
+             and not writes_rows(line)
+             and not (op == "fusion" and any(
+                 writes_rows(l) for c in _CALLED.findall(line)
+                 for l in comps[c]))]
+    assert not moved, moved[0][:300]
+    # K's and V's rows, once a layer (over int8 pages: and the launch's
+    # whole pages, re-encoded)
+    assert sum(writes_rows(line) for _, line in large) \
+        == (4 if pages == "int8" else 2)
+    kernel, = [line for lines in comps.values() for line in lines
+               if " custom-call(" in line and "ragged_paged_attention" in line]
+    stacked = "{}[{}]".format("s8" if pages == "int8" else "bf16",
+                              ",".join(map(str, pool)))
+    assert kernel.split("operand_layout_constraints=")[1].count(stacked) == 2

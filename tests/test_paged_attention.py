@@ -348,12 +348,9 @@ def test_ragged_kernel_reads_no_page_past_a_rows_length(small_tiles, G):
     assert not out[total - 1:].any()     # the row of no keys, and padding
 
 
-@pytest.mark.parametrize("G", [4, 8])
-def test_ragged_int8_page_kernel_matches_reference(small_tiles, G):
-    """Int8 pages take the same body with a dequantising load: all four
-    row kinds against the fake-quant oracle (float = int8 * the page's
-    scale for the head), pages of 32 keys as the int8 tile wants."""
-    rng = np.random.RandomState(25)
+def _int8_case(rng, G):
+    """All four row kinds over int8 pages of 32 keys (the int8 tile's
+    height), with each page's scale for each head."""
     Hkv, D, bs, nblk, nb = 2, 64, 32, 3, 16
     qlens, kvl, Tq = [5, 1, 4, 3], [5, 40, 90, 96], 16
     q = jnp.asarray(rng.randn(Tq, Hkv * G, D), jnp.float32)
@@ -364,7 +361,16 @@ def test_ragged_int8_page_kernel_matches_reference(small_tiles, G):
     bt = jnp.asarray(1 + rng.choice(nb - 1, 4 * nblk, replace=False)
                      .reshape(4, nblk), jnp.int32)
     cu = jnp.asarray(np.concatenate([[0], np.cumsum(qlens)]), jnp.int32)
-    kvl = jnp.asarray(kvl, jnp.int32)
+    return q, kc, vc, ks, vs, bt, cu, jnp.asarray(kvl, jnp.int32)
+
+
+@pytest.mark.parametrize("G", [4, 8])
+def test_ragged_int8_page_kernel_matches_reference(small_tiles, G):
+    """Int8 pages take the same body with a dequantising load: all four
+    row kinds against the fake-quant oracle (float = int8 * the page's
+    scale for the head), pages of 32 keys as the int8 tile wants."""
+    q, kc, vc, ks, vs, bt, cu, kvl = _int8_case(np.random.RandomState(25), G)
+    Tq = q.shape[0]
     out = np.asarray(pa.ragged_paged_attention_quant(
         q, kc, vc, ks, vs, bt, cu, kvl))
     seg, rel = pa.ragged_segments(cu, kvl, Tq)
@@ -372,3 +378,42 @@ def test_ragged_int8_page_kernel_matches_reference(small_tiles, G):
         q, kc, vc, ks, vs, bt, seg, rel))
     np.testing.assert_allclose(out[:13], ref[:13], atol=2e-5)
     assert not out[13:].any()
+
+
+def _among_layers(pool, layer, n_layers, other):
+    """``pool`` as layer ``layer`` of ``n_layers``, every other layer
+    filled with ``other``."""
+    stacked = jnp.full((n_layers,) + pool.shape, other, pool.dtype)
+    return stacked.at[layer].set(pool)
+
+
+@pytest.mark.parametrize("G", [4, 8])
+@pytest.mark.parametrize("name", ["all_decode", "noncontiguous_mixed",
+                                  "empty_rows_between", "int8_pages"])
+def test_launch_over_the_pools_of_all_layers_reads_its_layer(small_tiles,
+                                                             name, G):
+    """What a step program launches: the pools of all layers and a
+    layer index, prefetched.  The result is, bit for bit, the launch
+    over that layer's slice, and no other layer is read: the others
+    hold NaN (over int8 pages, whose bytes cannot, their scales do and
+    their pages are all 127)."""
+    rng = np.random.RandomState(26)
+    L, layer = 3, 1
+    if name == "int8_pages":
+        q, kc, vc, ks, vs, *rows = _int8_case(rng, G)
+        one = pa.ragged_paged_attention_quant_packed(q, kc, vc, ks, vs,
+                                                     *rows)
+        out = pa.ragged_paged_attention_quant_packed(
+            q, *(_among_layers(p, layer, L, 127) for p in (kc, vc)),
+            *(_among_layers(s, layer, L, jnp.nan) for s in (ks, vs)),
+            *rows, layer=jnp.int32(layer))
+    else:
+        qlens, kvl, Tq, kw = GEOMETRIES[name]
+        q, kc, vc, *rows = _ragged_case(rng, qlens, kvl, Tq, H=2 * G,
+                                        Hkv=2, **kw)
+        one = pa.ragged_paged_attention_packed(q, kc, vc, *rows)
+        out = pa.ragged_paged_attention_packed(
+            q, *(_among_layers(p, layer, L, jnp.nan) for p in (kc, vc)),
+            *rows, layer=jnp.int32(layer))
+    assert np.isfinite(np.asarray(one)).all() and np.asarray(one).any()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(one))
