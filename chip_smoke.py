@@ -33,6 +33,7 @@ The last line of standard output on success is one JSON object:
 from __future__ import annotations
 
 import argparse
+import functools
 import http.client
 import json
 import os
@@ -72,7 +73,15 @@ FULL = {
                  "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64},
                 {"H": 32, "Hkv": 4, "D": 128, "bs": 32, "nblk": 4,
                  "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64,
-                 "pages": "int8"}],
+                 "pages": "int8"},
+                # a group of seven (a decode item is 7 score rows, not a
+                # multiple of the sublane 8), then that under a window
+                # the second and third rows have passed
+                {"H": 28, "Hkv": 4, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 28, "Hkv": 4, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64,
+                 "window": 32}],
     "start_timeout_s": 300.0, "request_timeout_s": 600.0,
 }
 TINY = {
@@ -89,7 +98,12 @@ TINY = {
                  "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64},
                 {"H": 8, "Hkv": 1, "D": 16, "bs": 32, "nblk": 4,
                  "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64,
-                 "pages": "int8"}],
+                 "pages": "int8"},
+                {"H": 7, "Hkv": 1, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 7, "Hkv": 1, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64,
+                 "window": 32}],
     "start_timeout_s": 120.0, "request_timeout_s": 300.0,
 }
 
@@ -172,14 +186,16 @@ def kernel_check(size: dict) -> int:
         else:
             kc = jnp.asarray(rng.randn(*pool), dtype)
             vc = jnp.asarray(rng.randn(*pool), dtype)
-            out = jax.jit(pa.ragged_paged_attention_packed)(
-                q, kc, vc, bt, cu, kvl)
+            out = jax.jit(functools.partial(
+                pa.ragged_paged_attention_packed, window=k.get("window")))(
+                    q, kc, vc, bt, cu, kvl)
         # the reference materialises [Tq, S, Hkv, D]: fine on this small
         # table, about 17 GB a layer at a full prefill launch
         with jax.default_matmul_precision("highest"):
             ref = pa.ragged_paged_reference(
                 q.astype(jnp.float32), kc.astype(jnp.float32),
-                vc.astype(jnp.float32), bt, cu, kvl)
+                vc.astype(jnp.float32), bt, cu, kvl,
+                window=k.get("window"))
         out32 = out.astype(jnp.float32)
         err = float(jnp.max(jnp.abs(out32[:live] - ref[:live])))
         # live rows finite, padded rows zero (the kernel gives them no

@@ -22,6 +22,15 @@ bridge):
                the vocabulary.  Cut depth with --layers (6: one dense and
                five expert layers, 10.9 GB in bfloat16, held once: the
                engine serves the model's own arrays)
+    smallthinker-sm  a small decoder of global (no positions) and
+               sliding-window (rotary) layers, period 4, every layer
+               with ReGLU experts routed from the pre-attention norm (8
+               experts, 3 a token, window 64).  Two page pools and two
+               block tables: serve it with --no-prefix-caching
+    smallthinker-21b  SmallThinker-21BA3B-Instruct's widths (28 query
+               heads over 4 K/V heads of 128, 64 experts of width 768, 6
+               a token, window 4096).  Cut depth with --layers (8: two
+               periods, 7.9 GB in bfloat16, held once)
 
 The process computes on whatever device JAX resolves, and says which on
 its start-up line together with the attention and matmul paths the
@@ -77,6 +86,16 @@ def _model_config(args):
         cfg = MlaMoeConfig(
             vocab_size=262144 // 4, experts_held=32, ep_size=4, ep_rank=0,
             max_position_embeddings=args.max_model_len or 16384)
+    elif args.model == "smallthinker-sm":
+        from paddle_tpu.models.smallthinker import SmallThinkerConfig
+        cfg = SmallThinkerConfig.tiny(
+            vocab=512, hidden=128, layers=8, heads=7, kv_heads=1,
+            head_dim=32, experts=8, active=3, ffn=64, window=64,
+            seq=args.max_model_len or 1024)
+    elif args.model == "smallthinker-21b":
+        from paddle_tpu.models.smallthinker import SmallThinkerConfig
+        cfg = SmallThinkerConfig(
+            max_position_embeddings=args.max_model_len or 16384)
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
     if args.layers:
@@ -110,6 +129,9 @@ def _build_engine(args, cfg):
         from paddle_tpu.models.mla_moe import MlaMoeForCausalLM
         # drawn leaf by leaf in the served type: no float32 model first
         model = MlaMoeForCausalLM(cfg, dtype=args.dtype)
+    elif getattr(cfg, "architecture", None) == "smallthinker":
+        from paddle_tpu.models.smallthinker import SmallThinkerForCausalLM
+        model = SmallThinkerForCausalLM(cfg, dtype=args.dtype)
     else:
         model = LlamaForCausalLM(cfg)
         if args.dtype != "float32":
@@ -156,7 +178,8 @@ def _parser() -> argparse.ArgumentParser:
                     "with SSE streaming, /healthz, /metrics).")
     ap.add_argument("--model", default="tiny",
                     choices=["tiny", "llama-sm", "llama-7b", "mla-moe-sm",
-                             "sarvam-105b"])
+                             "sarvam-105b", "smallthinker-sm",
+                             "smallthinker-21b"])
     ap.add_argument("--layers", type=int, default=0,
                     help="depth cut: build this many decoder layers "
                          "(0 = the preset's depth); widths are never cut")

@@ -33,6 +33,15 @@ stays resident.  Page lifecycle:
 Block id 0 is reserved as the NULL page: padded scheduler slots point
 every block-table entry at it, so their (masked) cache writes land in a
 page no live sequence owns.
+
+A model whose layers mix global and sliding-window attention has a
+SECOND pool of pages, the window layers', and a second page list a
+sequence (``window=``, ``window_blocks=``): the list above is the global
+layers' and keeps every page while the sequence lives; the window
+layers' holds only the pages with positions a later query can still
+see, and ``window_advance`` gives the others back as the sequence moves
+on (in chunked prefill and in decode alike).  The two pools have id
+spaces of their own, each with its null page 0.
 """
 from __future__ import annotations
 
@@ -73,7 +82,8 @@ class BlockManager:
     """
 
     def __init__(self, num_blocks: int, block_size: int,
-                 enable_prefix_caching: bool = False):
+                 enable_prefix_caching: bool = False,
+                 window: int = 0, window_blocks: int = 0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (one is the reserved null page)")
         if block_size < 1:
@@ -81,6 +91,28 @@ class BlockManager:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.enable_prefix_caching = bool(enable_prefix_caching)
+        # the window layers' pool (0: the model has none): pages 1 ..
+        # window_blocks - 1, a list a sequence indexed by logical page
+        # with the null page where a page was given back or not yet
+        # taken, and the first logical page it still holds
+        self.window = int(window)
+        self.window_blocks = int(window_blocks)
+        if self.window:
+            if self.window_blocks < 2:
+                raise ValueError("a window pool needs >= 2 blocks (one is "
+                                 "its null page)")
+            if self.enable_prefix_caching:
+                raise ValueError(
+                    "prefix caching over a window pool is not supported: a "
+                    "hit would need the window layers' pages of the "
+                    "prefix's last `window` tokens, which their owner has "
+                    "given back")
+        self._wfree = list(range(self.window_blocks - 1, NULL_BLOCK, -1))
+        self._wtables: dict = {}
+        self._wfirst: dict = {}
+        self.window_alloc_count = 0
+        self.window_returned = 0      # pages given back by a live sequence
+        self.window_peak_used = 0
         # LIFO free list (ids 1..num_blocks-1); id 0 stays reserved
         self._free = list(range(self.num_blocks - 1, NULL_BLOCK, -1))
         self._tables: dict = {}          # seq id -> [block ids, in order]
@@ -225,6 +257,10 @@ class BlockManager:
         self._chain[seq_id] = []
         self._version[seq_id] = 0
         self._freed.discard(seq_id)
+        if self.window:
+            # no window page yet: each launch takes what it writes
+            self._wtables[seq_id] = []
+            self._wfirst[seq_id] = 0
         self.alloc_count += need
         self.peak_used = max(self.peak_used, self.num_used)
         return True
@@ -329,6 +365,74 @@ class BlockManager:
             self.peak_used = max(self.peak_used, self.num_used)
         self._tokens[seq_id] = max(self._tokens.get(seq_id, 0), int(n_tokens))
         return True
+
+    # -- the window layers' pages -------------------------------------------
+
+    @property
+    def num_window_free(self) -> int:
+        return len(self._wfree)
+
+    @property
+    def num_window_used(self) -> int:
+        return max(0, self.window_blocks - 1) - len(self._wfree)
+
+    def window_span(self, start: int, end: int) -> tuple:
+        """(first, last + 1) logical pages a window layer needs for
+        queries at positions start .. end - 1: from the page of the
+        lowest key the first of them sees."""
+        bs = self.block_size
+        return (max(0, int(start) - self.window + 1) // bs,
+                -(-int(end) // bs))
+
+    def window_advance(self, seq_id, start: int, end: int) -> None:
+        """Call before a launch that holds seq_id's queries at positions
+        start .. end - 1 (a prefill chunk, a decode token): the window
+        layers' pages wholly below position ``start - window + 1`` go
+        back to their pool (no later query sees them: positions only
+        grow), and pages up to the one that holds ``end - 1`` are taken.
+        Idempotent; bumps the table version when the list changed.
+        Raises BlockPoolExhausted when the window pool is out of pages,
+        which an engine that sized it for its running sequences never
+        sees."""
+        table = self._wtables[seq_id]
+        lo, hi = self.window_span(start, end)
+        first = self._wfirst[seq_id]
+        if lo <= first and hi <= len(table):
+            return
+        for p in range(first, min(lo, len(table))):
+            self._wfree.append(table[p])
+            table[p] = NULL_BLOCK
+            self.window_returned += 1
+        if lo > first:
+            self._wfirst[seq_id] = lo
+        while len(table) < hi:
+            if len(table) < lo:
+                table.append(NULL_BLOCK)    # never written, never read
+                continue
+            if not self._wfree:
+                raise BlockPoolExhausted("no free page in the window pool")
+            table.append(self._wfree.pop())
+            self.window_alloc_count += 1
+        self._version[seq_id] += 1
+        self.window_peak_used = max(self.window_peak_used,
+                                    self.num_window_used)
+
+    def window_table(self, seq_id, width: int) -> np.ndarray:
+        """int32 [width] window-layer table by logical page, the null
+        page where nothing is held."""
+        return self._padded(seq_id, self._wtables[seq_id], width)
+
+    def _window_drop(self, seq_id, keep: int) -> None:
+        """Give back seq_id's window pages from logical page ``keep``
+        on."""
+        table = self._wtables.get(seq_id)
+        if table is None:
+            return
+        for b in reversed(table[keep:]):
+            if b != NULL_BLOCK:
+                self._wfree.append(b)
+        del table[keep:]
+        self._wfirst[seq_id] = min(self._wfirst[seq_id], keep)
 
     def reserve_window(self, rows):
         """All-or-nothing page-slack reservation for a K-step decode window.
@@ -492,6 +596,7 @@ class BlockManager:
         for blk in reversed(table[need:]):
             self._decref(blk)
         del table[need:]
+        self._window_drop(seq_id, need)
         ids = self._ids.get(seq_id)
         if ids is not None and len(ids) > n:
             del ids[n:]
@@ -567,6 +672,9 @@ class BlockManager:
         self.free_count += len(table)
         for b in reversed(table):
             self._decref(b)
+        self._window_drop(seq_id, 0)
+        self._wtables.pop(seq_id, None)
+        self._wfirst.pop(seq_id, None)
         self._freed.add(seq_id)
 
     def evict_parked(self, n: int) -> int:
@@ -679,7 +787,10 @@ class BlockManager:
         """int32 [width] block table padded with the null page (the kernel
         clamps/never reads past `lengths`, and padded entries DMA the null
         page rather than a live one)."""
-        table = self._tables[seq_id]
+        return self._padded(seq_id, self._tables[seq_id], width)
+
+    @staticmethod
+    def _padded(seq_id, table: list, width: int) -> np.ndarray:
         if len(table) > width:
             raise ValueError(
                 f"sequence {seq_id!r} holds {len(table)} pages > table "
@@ -708,7 +819,15 @@ class BlockManager:
         return max(0.0, 1.0 - used_tokens / slots)
 
     def stats(self) -> dict:
+        window = {} if not self.window else {
+            "window": self.window,
+            "window_blocks": self.window_blocks,
+            "window_used_blocks": self.num_window_used,
+            "window_peak_used_blocks": self.window_peak_used,
+            "window_alloc_count": self.window_alloc_count,
+            "window_pages_returned": self.window_returned}
         return {
+            **window,
             "num_blocks": self.num_blocks,
             "block_size": self.block_size,
             "used_blocks": self.num_used,
@@ -770,6 +889,24 @@ class BlockManager:
                 f"hash map points at free block {b}"
             assert h in self._block_hashes.get(b, ()), \
                 f"hash map / block hash mismatch on {b}"
+        # the window layers' pool: every page free or in exactly one list
+        assert self._wtables.keys() == (self._tables.keys() if self.window
+                                        else set()), \
+            "window lists != block tables"
+        held = [b for t in self._wtables.values() for b in t
+                if b != NULL_BLOCK]
+        wfree = set(self._wfree)
+        assert len(wfree) == len(self._wfree), "duplicate window free ids"
+        assert len(held) == len(set(held)), "window page held twice"
+        assert not (wfree & set(held)), "window page both free and held"
+        assert len(wfree) + len(held) == max(0, self.window_blocks - 1), \
+            "window pool accounting broken"
+        assert NULL_BLOCK not in wfree, "window null page leaked"
+        for seq, t in self._wtables.items():
+            first = self._wfirst[seq]
+            assert all(b == NULL_BLOCK for b in t[:first]) and all(
+                b != NULL_BLOCK for b in t[first:]), \
+                f"sequence {seq!r}: window list not [given back | held]"
 
 
 def _page_hash_chain(ids, n_pages, bs):

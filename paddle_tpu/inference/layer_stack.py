@@ -15,24 +15,33 @@ model's own arrays.
 
 Attention kinds: ``gqa`` (rotary grouped-query attention over K and V
 pages ``[L, num_blocks, Hkv, bs, D]``; float pages, or int8 pages with
-their two scale pools, by what it is handed) and ``mla`` (latent
+their two scale pools, by what it is handed), ``mla`` (latent
 attention in the absorbed form over ONE pool, ``[L, num_blocks, bs,
-width]``).  One contract for both, scanned or unrolled: a layer gets the
-pools of ALL layers and its index, scatters the step's rows in place at
-(layer, page, slot) and its kernel reads pages where they lie at a
-prefetched layer index.  No layer-sized slice of a pool is ever made.
-FFN kinds: ``swiglu`` and ``moe`` (routed experts held here plus shared
-experts: ``models/mla_moe.py``).
+width]``) and, for a model that mixes them (``models/smallthinker.py``),
+``gqa_nope`` (``gqa`` without rotary positions: a global layer) and
+``gqa_window`` (rotary, a query sees the ``c.window`` positions up to
+its own).  Such a model has TWO pairs of pools and two block tables: the
+global layers' ``[Lg, num_blocks, ...]`` under ``c.bt`` and the window
+layers' ``[Lw, Nw, ...]`` under ``c.btw``, where a sequence's pages
+below its window have gone back to the pool.  One contract for all,
+scanned or unrolled: a layer gets the pools of ALL layers and its index
+among the layers that share its pools, scatters the step's rows in
+place at (layer, page, slot) and its kernel reads pages where they lie
+at a prefetched layer index.  No layer-sized slice of a pool is ever
+made.  FFN kinds: ``swiglu``, ``moe`` (routed experts held here plus
+shared experts: ``models/mla_moe.py``) and ``moe_reglu`` (ReGLU experts
+whose router reads ``h``, the attention block's input, not ``h2``).
 
 The ``jax.named_scope`` names below are what a device trace is read by
 (docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
-``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn``,
-``o_proj``, ``mlp``, and for expert
+``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn``
+(``attn_window`` on a window layer's), ``o_proj``, ``mlp``, and for expert
 layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
 ``shared_expert``.
 """
 from __future__ import annotations
 
+import functools
 from types import SimpleNamespace
 
 import jax
@@ -40,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models import mla_moe as _mm
+from ..models import smallthinker as _st
 from ..models.llama import _rms_weight, _rope_positions
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
@@ -117,17 +127,21 @@ def _commit_float(k, v, pools, at, c):
     return _set_rows(kc, at, k), _set_rows(vc, at, v)
 
 
-def _attend_float(q, pools, layer, c):
+def _attend_float(q, pools, layer, c, bt=None, window=None):
+    """``bt`` and ``window``: a window layer's table and its width (the
+    dense decoder passes neither)."""
+    bt = c.bt if bt is None else bt
     if c.use_pallas:
         # the host packing path owns these buffers: bt is the int32
         # NULL_BLOCK-padded pool table and cu, kvl come int32 from
         # the step's packing, so the packed entry skips the
         # per-launch re-clip and re-cast.  The kernel reads the row
         # layout itself; seg/rel are for rope and kv_write
-        return _pa.ragged_paged_attention_packed(q, *pools, c.bt, c.cu,
-                                                 c.kvl, layer=layer)
+        return _pa.ragged_paged_attention_packed(
+            q, *pools, bt, c.cu, c.kvl, layer=layer, window=window)
     return _pa.ragged_paged_reference_segrel(
-        q, *(pool[layer] for pool in pools), c.bt, c.seg, c.rel)
+        q, *(pool[layer] for pool in pools), bt, c.seg, c.rel,
+        window=window)
 
 
 def _commit_int8(k, v, pools, at, c):
@@ -192,11 +206,28 @@ def _attend_int8(q, pools, layer, c):
     return att.astype(q.dtype)
 
 
-def _gqa(x, h, p, pools, layer, c):
+def _gqa(x, h, p, pools, layer, c, rope=True, window=False):
     """Grouped-query attention over this layer's pages of the pools of
     all layers.  What differs between page types is a pair, picked by
     what the layer is handed: commit the step's K/V rows into the pools
-    at (layer, page, slot), and attend over the layer's pages."""
+    at (layer, page, slot), and attend over the layer's pages.
+
+    A model of global and window layers (``c.window`` set) hands every
+    layer both pairs of pools, (K, V of the global layers, K, V of the
+    window layers): a layer writes and reads its own pair under its own
+    table and passes the other through.  ``rope``: whether the kind
+    rotates q and k; ``window``: whether it is a window layer, whose
+    attention runs in scope ``attn_window`` under a kernel name of its
+    own."""
+    others = ()
+    bt, scope, over = c.bt, "attn", {}
+    if getattr(c, "window", None) is not None:
+        if window:
+            others, pools = pools[:2], pools[2:]
+            bt, scope = c.btw, "attn_window"
+            over = {"bt": bt, "window": c.window}
+        else:
+            pools, others = pools[:2], pools[2:]
     commit, attend = (_commit_int8, _attend_int8) \
         if pools[0].dtype == jnp.int8 else (_commit_float, _attend_float)
     Tq, nh, kvh, d, tp, mm = c.Tq, c.nh, c.kvh, c.d, c.tp, c.mm
@@ -204,15 +235,16 @@ def _gqa(x, h, p, pools, layer, c):
         q = mm(h, p, "wq").reshape(Tq, nh, d)
         k = mm(h, p, "wk").reshape(Tq, kvh, d)
         v = mm(h, p, "wv").reshape(Tq, kvh, d)
-    with jax.named_scope("rope"):
-        q = _rope_positions(q, c.rel, c.theta)
-        k = _rope_positions(k, c.rel, c.theta)
+    if rope:
+        with jax.named_scope("rope"):
+            q = _rope_positions(q, c.rel, c.theta)
+            k = _rope_positions(k, c.rel, c.theta)
     with jax.named_scope("kv_write"):
-        blk = c.bt[c.seg, c.rel // c.bs]                  # [Tq]
+        blk = bt[c.seg, c.rel // c.bs]                    # [Tq]
         slot = c.rel % c.bs
         pools = commit(k, v, pools, (layer, blk, slot), c)
-    with jax.named_scope("attn"):
-        att = attend(q, pools, layer, c)
+    with jax.named_scope(scope):
+        att = attend(q, pools, layer, c, **over)
         if tp > 1:
             # tiled gather concatenates shard head blocks in mesh order
             # — exactly the tp=1 head layout, so the replicated wo
@@ -220,7 +252,9 @@ def _gqa(x, h, p, pools, layer, c):
             att = lax.all_gather(att, "tp", axis=1, tiled=True)
     with jax.named_scope("o_proj"):
         x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
-    return x, pools
+    if window:
+        return x, tuple(others) + tuple(pools)
+    return x, tuple(pools) + tuple(others)
 
 
 def _latent(x, h, p, pools, layer, c):
@@ -250,10 +284,11 @@ def _latent(x, h, p, pools, layer, c):
 
 
 # ---------------------------------------------------------------------------
-# FFN kinds: (x, h2, p, c) -> (x, what an expert layer counted or None)
+# FFN kinds: (x, h, h2, p, c) -> (x, what an expert layer counted or
+# None); h is the attention block's input, h2 the FFN's own
 # ---------------------------------------------------------------------------
 
-def _swiglu(x, h2, p, c):
+def _swiglu(x, h, h2, p, c):
     mm = c.mm
     with jax.named_scope("mlp"):
         a = jax.nn.silu(mm(h2, p, "gate").astype(jnp.float32)
@@ -262,22 +297,31 @@ def _swiglu(x, h2, p, c):
     return x, None
 
 
-def _moe(x, h2, p, c):
+def _moe(x, h, h2, p, c):
     out, counts = _mm.moe_ffn(h2, p, c.cfg, valid=c.seg < c.kvl.shape[0],
                               use_kernel=c.use_pallas)
     with jax.named_scope("moe_combine"):
         return x + out, counts
 
 
-ATTENTION = {"gqa": _gqa, "mla": _latent}
-FFN = {"swiglu": _swiglu, "moe": _moe}
+def _moe_reglu(x, h, h2, p, c):
+    """ReGLU experts routed by ``h``, the pre-attention norm's output."""
+    out, counts = _st.moe_ffn(h, h2, p, c.cfg,
+                              valid=c.seg < c.kvl.shape[0],
+                              use_kernel=c.use_pallas)
+    with jax.named_scope("moe_combine"):
+        return x + out, counts
 
 
-def _unrolled(layer, x, layers, pools):
-    """Layers of one kind and shape, one after another, through ONE
-    traced copy of ``layer`` (an inner jit, built while the step program
-    is traced and gone with that trace)."""
-    one = jax.jit(layer)
+ATTENTION = {"gqa": _gqa, "mla": _latent,
+             "gqa_window": functools.partial(_gqa, window=True),
+             "gqa_nope": functools.partial(_gqa, rope=False)}
+FFN = {"swiglu": _swiglu, "moe": _moe, "moe_reglu": _moe_reglu}
+
+
+def _unrolled(one, x, layers, pools):
+    """Layers of one kind and shape, one after another, through ``one``,
+    the kind's ONE traced copy (``layer_stack`` keeps it)."""
     counted = []
     for index, p in layers:
         x, pools, counts = one(x, p, pools, jnp.int32(index))
@@ -297,13 +341,16 @@ def layer_stack(x, segments, pools, c):
     lowered ONCE and called with the index as an operand (traced layer
     by layer, six layers of two Pallas kernels each took 8.7 s a token
     bucket of every process start on the v5e's host, compile cache or
-    not).  Returns (x, pools, counts): what the
+    not).  The traced copy is the KIND's, an inner jit built while the
+    step program is traced and gone with that trace: segments that
+    alternate between two kinds (global, window x 3, global, window x 3)
+    trace two layers, not four.  Returns (x, pools, counts): what the
     expert layers counted, summed over layers (the largest load: the
     largest), int32 [4], or None without expert layers."""
     pools = tuple(pools)
     counted = []
-    for (attn, ffn), layers, scanned in segments:
-        attention, feed = ATTENTION[attn], FFN[ffn]
+    def layer_of(kind):
+        attention, feed = ATTENTION[kind[0]], FFN[kind[1]]
 
         def layer(x, p, pools, index):
             with jax.named_scope("norm"):
@@ -311,14 +358,23 @@ def layer_stack(x, segments, pools, c):
             x, pools = attention(x, h, p, pools, index, c)
             with jax.named_scope("norm"):
                 h2 = _rms_weight(x, p["ln2"], c.eps)
-            x, counts = feed(x, h2, p, c)
+            x, counts = feed(x, h, h2, p, c)
             return x, pools, counts
+        return layer
 
+    @functools.cache
+    def traced_layer(kind):
+        """The kind's one inner jit."""
+        return jax.jit(layer_of(kind))
+
+    for kind, layers, scanned in segments:
         if scanned:
+            layer = layer_of(kind)
             x, pools = scan_layers(lambda *a: layer(*a)[:2], x, layers,
                                    pools)
         else:
-            x, pools, counts = _unrolled(layer, x, layers, pools)
+            x, pools, counts = _unrolled(traced_layer(kind), x, layers,
+                                         pools)
             counted += counts
     if not counted:
         return x, pools, None
@@ -334,7 +390,9 @@ def forward(params, toks, pools, c, lidx=None):
     ``c.kinds`` is the model's (attention kind, FFN kind) a layer: the
     dense decoder (``c.scanned``) is one scanned segment over its
     stacked weights; otherwise runs of layers of one kind, each over its
-    own arrays.  Returns (logits, pools, counts)."""
+    own arrays.  A layer's index into its pools is its place in the
+    model, or ``c.pool_index[i]`` where kinds keep pools of their own.
+    Returns (logits, pools, counts)."""
     with jax.named_scope("embed"):
         x = c.embed(params, toks)                             # [Tq, H]
     if c.scanned:
@@ -344,7 +402,9 @@ def forward(params, toks, pools, c, lidx=None):
         for i, k in enumerate(c.kinds):
             if not segments or segments[-1][0] != k:
                 segments.append((k, [], False))
-            segments[-1][1].append((i, params["layers"][i]))
+            at = i if getattr(c, "pool_index", None) is None \
+                else c.pool_index[i]
+            segments[-1][1].append((at, params["layers"][i]))
     with jax.named_scope("layers"):
         x, pools, counts = layer_stack(x, segments, pools, c)
     with jax.named_scope("norm"):

@@ -95,7 +95,7 @@ __all__ = ["LLMEngine", "Request", "RequestOutput"]
 # ``layer_stack.ATTENTION``, spelled out: graft-lint reads this literal).
 # An engine's attention-bearing program kinds are bounded by it, whatever
 # its requests do (rule ``attention-program-budget``).
-ATTENTION_KINDS = ("gqa", "mla")
+ATTENTION_KINDS = ("gqa", "mla", "gqa_window", "gqa_nope")
 
 
 @dataclass
@@ -188,11 +188,13 @@ class _DecodeBufs:
 
     __slots__ = ("toks", "cu", "kvl", "bt", "samp", "layout", "bt_ver")
 
-    def __init__(self, B, nblk, Lq, vocab_size):
+    def __init__(self, B, bt_shape, Lq, vocab_size):
         self.toks = np.zeros((B,), np.int32)
         self.cu = np.zeros((B + 1,), np.int32)
         self.kvl = np.zeros((B,), np.int32)
-        self.bt = np.full((B + 1, nblk), NULL_BLOCK, np.int32)
+        # [B + 1, nblk], or [2, B + 1, nblk] where window layers have a
+        # table of their own (LLMEngine._bt_shape)
+        self.bt = np.full(bt_shape, NULL_BLOCK, np.int32)
         self.samp = make_samp(Lq, vocab_size)
         self.layout: tuple = ()
         self.bt_ver: dict = {}
@@ -258,6 +260,44 @@ def _instruction_scopes(hlo_text: str) -> dict:
     return out
 
 
+def _refuse(what: str, needs: dict, asked: dict) -> None:
+    """Raise by name for the first option of ``asked`` that is not at
+    the one value ``needs`` supports: {name: (supported, why)}."""
+    for name, (supported, why) in needs.items():
+        if asked[name] != supported:
+            raise ValueError(
+                f"{name}={asked[name]!r} is not supported for a model "
+                f"with {what}: {why}")
+
+
+def _refuse_window_options(**asked) -> None:
+    """A model that mixes global and sliding-window layers is served
+    from float pages in two pools under two block tables and float
+    weights on one chip, a step a launch, nothing shared between
+    sequences.  Each option below needs what its message says before it
+    can be taken: nothing has run it over the two tables."""
+    _refuse("sliding-window layers", {
+        "kv_dtype": ("float32", "int8 pages need scale pools for the "
+                     "window layers' pool and their reset when a page "
+                     "comes back from another sequence's window"),
+        "weight_dtype": ("float32", "int8/int4 weights need quantized "
+                         "expert pools and a grouped dequant product"),
+        "tp": (1, "tp > 1 needs both pairs of pools and the experts laid "
+               "over a mesh"),
+        "drafter": (None, "a drafter needs verify rows rolled back in "
+                    "the window layers' page lists too"),
+        "decode_window": (1, "decode_window > 1 needs the window layers' "
+                          "pages advanced inside the device loop"),
+        "kv_tier": (None, "kv_tier needs the spill and restore of both "
+                    "pools' pages"),
+        "enable_prefix_caching": (False, "a prefix hit needs the window "
+                                  "layers' pages of the prefix's last "
+                                  "window of tokens, which their owner "
+                                  "gave back as it moved on (and "
+                                  "copy-on-write over both pools)"),
+    }, asked)
+
+
 def _refuse_latent_options(**asked) -> None:
     """A model with latent-attention layers is served from float pages
     and float weights on one chip, a step a launch.  Each option below
@@ -277,11 +317,7 @@ def _refuse_latent_options(**asked) -> None:
         "kv_tier": (None, "kv_tier needs the spill and restore of latent "
                     "pages"),
     }
-    for name, (supported, why) in needs.items():
-        if asked[name] != supported:
-            raise ValueError(
-                f"{name}={asked[name]!r} is not supported for a model "
-                f"with latent-attention (MLA) layers: {why}")
+    _refuse("latent-attention (MLA) layers", needs, asked)
 
 
 # what wraps a launch when no tracer is installed: the jitted call keeps
@@ -431,12 +467,25 @@ class LLMEngine:
             if hasattr(cfg, "layer_kinds") \
             else [("gqa", "swiglu")] * cfg.num_hidden_layers
         self._latent = any(a == "mla" for a, _ in self._layer_kinds)
-        self._has_experts = any(f == "moe" for _, f in self._layer_kinds)
+        # window layers keep pools and a block table of their own
+        self._windowed = any(a == "gqa_window"
+                             for a, _ in self._layer_kinds)
+        # the dense decoder alone runs as one scan over stacked weights
+        self._scanned = all(k == ("gqa", "swiglu")
+                            for k in self._layer_kinds)
+        self._has_experts = any(f in ("moe", "moe_reglu")
+                                for _, f in self._layer_kinds)
         if self._latent:
             _refuse_latent_options(
                 kv_dtype=kv_dtype, weight_dtype=weight_dtype, tp=tp,
                 drafter=drafter, decode_window=decode_window,
                 kv_tier=kv_tier)
+        if self._windowed:
+            _refuse_window_options(
+                kv_dtype=kv_dtype, weight_dtype=weight_dtype, tp=tp,
+                drafter=drafter, decode_window=decode_window,
+                kv_tier=kv_tier,
+                enable_prefix_caching=bool(enable_prefix_caching))
         if kv_dtype not in ("float32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'float32' or 'int8', got {kv_dtype!r}")
@@ -498,9 +547,24 @@ class LLMEngine:
         self.nblk = -(-self.max_model_len // self.block_size)
         if num_blocks is None:
             num_blocks = 1 + self.max_num_seqs * self.nblk
+        # the window layers' pool is sized HERE, from the model's window
+        # and this engine's own limits: the most max_num_seqs running
+        # sequences can hold at once, each a window of pages, the pages
+        # of the longest chunk in flight and one for a window that
+        # starts inside a page.  So ``num_blocks`` governs the global
+        # layers alone, and a window layer never runs out
+        self._window = int(cfg.sliding_window_size) if self._windowed else 0
+        self._window_blocks = 0
+        if self._windowed:
+            per_seq = min(self.nblk,
+                          -(-self._window // self.block_size)
+                          + -(-self.max_prefill_tokens // self.block_size)
+                          + 1)
+            self._window_blocks = 1 + self.max_num_seqs * per_seq
         self.blocks = BlockManager(
             num_blocks, self.block_size,
-            enable_prefix_caching=self.enable_prefix_caching)
+            enable_prefix_caching=self.enable_prefix_caching,
+            window=self._window, window_blocks=self._window_blocks)
         if self.blocks.num_free < self.nblk:
             raise ValueError(
                 f"num_blocks={num_blocks} cannot hold even one "
@@ -526,9 +590,32 @@ class LLMEngine:
             self._kvh, self._hd = 1, _mla.page_width(cfg.kv_row)
         else:
             self._kvh = cfg.num_key_value_heads
-            self._hd = cfg.hidden_size // self._nh
+            # the configuration's own where it states one: the heads'
+            # total need not be the hidden size (28 x 128 over 2560)
+            self._hd = int(getattr(cfg, "head_dim", 0)
+                           or cfg.hidden_size // self._nh)
+        self._kw = self._vw = None
+        # a layer's index into the pools of its kind (None: its place
+        # in the model, one pool for all layers)
+        self._pool_index = None
         with jax.default_device(devices[0]):
-            if self._latent:
+            if self._windowed:
+                # TWO pairs of pools: the global layers' pages live as
+                # long as their sequence ([Lg, num_blocks, ...], the
+                # block table's), the window layers' come and go
+                # ([Lw, Nw, ...], the window table's)
+                is_w = [a == "gqa_window" for a, _ in self._layer_kinds]
+                self._pool_index = [sum(is_w[:i]) if w
+                                    else i - sum(is_w[:i])
+                                    for i, w in enumerate(is_w)]
+                page = (self._kvh, self.block_size, self._hd)
+                self._kc = jnp.zeros((L - sum(is_w), num_blocks) + page, dt)
+                self._vc = jnp.zeros_like(self._kc)
+                self._kw = jnp.zeros((sum(is_w), self._window_blocks)
+                                     + page, dt)
+                self._vw = jnp.zeros_like(self._kw)
+                self._ks = self._vs = None
+            elif self._latent:
                 # ONE pool for all layers, a row [c | k_rope | 0...] a
                 # token: written in place and read where it lies
                 self._kc = jnp.zeros((L, num_blocks, self.block_size,
@@ -559,6 +646,11 @@ class LLMEngine:
             # its copy of them here, and nothing moves on the device
             # that already holds them
             self.params = jax.device_put(self.params, devices[0])
+            # the pools are COMMITTED to the device like the parameters
+            # (the same buffers, no copy): a program's outputs are, so
+            # the first program launched would otherwise be lowered a
+            # second time at its next launch, whenever that comes
+            self._set_pools(jax.device_put(self._pools(), devices[0]))
         else:
             # lay the pools and the head-partitioned weights out on the
             # mesh ONCE at construction; every step launch then runs
@@ -622,8 +714,14 @@ class LLMEngine:
         # (overlap off only ever touches buffer 0).  lidx is read-only
         # to the program and safely shared between them.
         self.overlap = bool(overlap)
-        self._dbufs = (_DecodeBufs(B, self.nblk, self._Lq, cfg.vocab_size),
-                       _DecodeBufs(B, self.nblk, self._Lq, cfg.vocab_size))
+        # the block table a launch is handed: a row a sequence and the
+        # null row; with window layers two of them, stacked (the global
+        # layers' first)
+        self._bt_shape = ((2,) if self._windowed else ()) \
+            + (B + 1, self.nblk)
+        self._dbufs = (
+            _DecodeBufs(B, self._bt_shape, self._Lq, cfg.vocab_size),
+            _DecodeBufs(B, self._bt_shape, self._Lq, cfg.vocab_size))
         self._d_cur = 0                   # buffer of the latest launch
         self._d_lidx = np.minimum(np.arange(self._Lq), B - 1) \
             .astype(np.int32)
@@ -655,7 +753,7 @@ class LLMEngine:
         # what the pre-ragged four-program engine would have padded to
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
-                          "kv_pages": 0}
+                          "kv_pages": 0, "kv_pages_window": 0}
         # passes of the sampling epilogue, and those whose launch held a
         # sampled row (temps > 0): the device runs the sampled chain in
         # exactly those (sampling.sample_tokens branches on the same)
@@ -671,6 +769,7 @@ class LLMEngine:
         self.moe_counts = {"moe_pairs_here": 0, "moe_pairs_all": 0,
                            "moe_experts_touched": 0, "moe_load_max": 0}
         self._launch_counts = None
+        self._launch_pages: dict = {}     # the latest launch's page counts
         self.stats = ServingStats()
         self.stats.set_decode_window(self.decode_window)
         self.stats.set_weight_residency(
@@ -1247,6 +1346,12 @@ class LLMEngine:
         out["tokens_real"] = self.pad_stats["real"]
         out["tokens_padded"] = self.pad_stats["padded"]
         out["kv_pages_live"] = self.pad_stats["kv_pages"]
+        if self._windowed:
+            # pages the launches' rows held in a window layer (against
+            # kv_pages_live, what one table for all layers would hold),
+            # and pages live sequences gave back as they moved on
+            out["kv_pages_window"] = self.pad_stats["kv_pages_window"]
+            out["window_pages_returned"] = self.blocks.window_returned
         out["sample_launches"] = self.sample_stats["launches"]
         out["sample_chain_launches"] = self.sample_stats["chain_launches"]
         if self._has_experts:
@@ -1281,12 +1386,14 @@ class LLMEngine:
     def _pools(self) -> tuple:
         """The page pools every program takes after the parameters and
         gives back: K and V (over int8 pages their scale pools too), or
-        the one latent pool.  Each is [L, num_blocks, ...]."""
-        return tuple(x for x in (self._kc, self._vc, self._ks, self._vs)
-                     if x is not None)
+        the one latent pool, each [L, num_blocks, ...]; with window
+        layers the global layers' K and V, then the window layers'
+        [Lw, Nw, ...]."""
+        return tuple(x for x in (self._kc, self._vc, self._ks, self._vs,
+                                 self._kw, self._vw) if x is not None)
 
     def _set_pools(self, pools) -> None:
-        names = [n for n in ("_kc", "_vc", "_ks", "_vs")
+        names = [n for n in ("_kc", "_vc", "_ks", "_vs", "_kw", "_vw")
                  if getattr(self, n) is not None]
         for n, x in zip(names, pools):
             setattr(self, n, x)
@@ -1295,9 +1402,20 @@ class LLMEngine:
         """MESH-TOTAL device bytes one KV page costs, by the pools' own
         shapes: every pool's slab of one page across every layer (K and
         V, plus the page's scale rows in int8 mode; or the latent rows),
-        summed over every tp shard."""
+        summed over every tp shard.  With window layers: a page of the
+        block table's pools, the global layers' (the window layers'
+        pages are counted as they are held: ``kv_bytes_resident``)."""
         return sum(x.size // x.shape[1] * np.dtype(x.dtype).itemsize
-                   for x in self._pools())
+                   for x in self._pools() if x is not self._kw
+                   and x is not self._vw)
+
+    def _window_bytes_resident(self) -> int:
+        """Bytes of the window layers' pages that sequences hold."""
+        if not self._windowed:
+            return 0
+        return self.blocks.num_window_used * sum(
+            x.size // x.shape[1] * np.dtype(x.dtype).itemsize
+            for x in (self._kw, self._vw))
 
     def kv_page_bytes_per_shard(self) -> int:
         """Bytes one KV page costs ON ONE CHIP.  Pools shard along the
@@ -1314,14 +1432,15 @@ class LLMEngine:
         Mesh-total under tp; the per-chip figure is
         ``kv_bytes_resident_per_shard``."""
         return ((self.blocks.num_used + self.blocks.num_cached)
-                * self.kv_page_bytes())
+                * self.kv_page_bytes()) + self._window_bytes_resident()
 
     def kv_bytes_resident_per_shard(self) -> int:
         """Resident KV bytes on ONE chip of the tp mesh (equals the
         mesh total at tp=1) — the number a per-chip HBM budget or
         DegradationController threshold should be compared against."""
         return ((self.blocks.num_used + self.blocks.num_cached)
-                * self.kv_page_bytes_per_shard())
+                * self.kv_page_bytes_per_shard()) \
+            + self._window_bytes_resident()
 
     def weight_bytes_resident(self) -> int:
         """MESH-TOTAL device bytes holding the decode weights: the
@@ -1392,7 +1511,7 @@ class LLMEngine:
         B = self.max_num_seqs
         return self._step_head_structs(placed) + (
             sds((Tq,), i32), sds((B + 1,), i32), sds((B,), i32),
-            sds((B + 1, self.nblk), i32), sds((self._Lq,), i32),
+            sds(self._bt_shape, i32), sds((self._Lq,), i32),
             samp_structs(self._Lq, self.config.vocab_size))
 
     def _window_arg_structs(self, placed: bool = False) -> tuple:
@@ -1910,9 +2029,13 @@ class LLMEngine:
                         args={"step": sid, "rows": n, "prestage": True})
             t = tr.now()
         for s, req in enumerate(batch):
+            # the window moves for the NEXT launch's position; what it
+            # gives back the launch in flight has read by the time a
+            # later launch writes it (one device queue)
+            self._advance_window(req, req.cached + 1, req.cached + 2)
             ver = self.blocks.table_version(req.rid)
             if buf.bt_ver.get(req.rid) != ver:
-                buf.bt[s] = self.blocks.padded_table(req.rid, self.nblk)
+                buf.bt[..., s, :] = self._table_row(req)
                 buf.bt_ver[req.rid] = ver
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
@@ -2780,10 +2903,14 @@ class LLMEngine:
         replica, so BlockManager excludes it from the fresh-page scale
         reset; for a latent model the one pool of cached rows."""
         n = len(self._pools())
+        # the pools the block table's page ids index (a window layer's
+        # pool has ids of its own; nothing shares its pages)
+        paged = n - 2 * self._windowed
 
         def run(*args):
             s, d = args[n:]
-            return tuple(x.at[:, d].set(x[:, s]) for x in args[:n])
+            return tuple(x.at[:, d].set(x[:, s]) if i < paged else x
+                         for i, x in enumerate(args[:n]))
 
         return run, tuple(range(n))
 
@@ -2872,7 +2999,7 @@ class LLMEngine:
         shared = dict(
             Tq=Tq, bs=self.block_size, tp=self.tp, mm=mm, embed=embed,
             head_logits=head_logits, shard_head=self._shard_head,
-            kinds=self._layer_kinds, scanned=not self._latent,
+            kinds=self._layer_kinds, scanned=self._scanned,
             eps=cfg.rms_norm_eps,
             use_pallas=self.attention_path.startswith("pallas"))
         if self._latent:
@@ -2882,6 +3009,9 @@ class LLMEngine:
         else:
             shared.update(nh=self._nh // self.tp, kvh=self._kvh // self.tp,
                           d=self._hd, theta=cfg.rope_theta)
+        if self._windowed:
+            shared.update(cfg=cfg, window=self._window,
+                          pool_index=self._pool_index)
         return shared
 
     def _make_ragged_fn(self, Tq: int):
@@ -2909,6 +3039,7 @@ class LLMEngine:
         with_logits = self._with_logits
         n_pools = len(self._pools())
         q8 = self.kv_dtype == "int8"
+        windowed = self._windowed
         # (``run`` below must not close over ``self``: a compiled program
         # that holds its engine keeps it alive past its last user)
         shared = self._step_shared(Tq)
@@ -2930,8 +3061,13 @@ class LLMEngine:
             pools, host = rest[:n_pools], rest[n_pools:]
             toks, cu, kvl, bt, lidx, samp = host[-6:]
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
-            c = _ls.step_context(seg=seg, rel=rel, bt=bt, cu=cu, kvl=kvl,
-                                 fresh=host[0] if q8 else None, **shared)
+            # with window layers bt is both tables, [2, B+1, nblk]: the
+            # global layers', then the window layers' (entries below a
+            # row's window name the null page)
+            tables = dict(bt=bt[0], btw=bt[1]) if windowed else dict(bt=bt)
+            c = _ls.step_context(seg=seg, rel=rel, cu=cu, kvl=kvl,
+                                 fresh=host[0] if q8 else None, **tables,
+                                 **shared)
             logits, pools, counts = _ls.forward(params, toks, pools, c,
                                                 lidx)
             with jax.named_scope("sample"):
@@ -2992,7 +3128,10 @@ class LLMEngine:
                        real_tokens):
         self.pad_stats["real"] += int(real_tokens)
         self.pad_stats["padded"] += int(Tq)
-        self.pad_stats["kv_pages"] += self._kv_pages(kvl)
+        # counted once a launch; ``engine.device_launch`` carries the same
+        pages = self._launch_pages = self._launch_kv_args(cu, kvl)
+        self.pad_stats["kv_pages"] += pages["kv_pages"]
+        self.pad_stats["kv_pages_window"] += pages.get("kv_pages_window", 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
         out = self._call_program(self._get_ragged_prog(Tq),
@@ -3007,6 +3146,42 @@ class LLMEngine:
         """Pages a launch's rows hold keys in: what the attention kernel
         walks, of the bucket * nblk page slots of the table."""
         return int((-(-np.asarray(kvl) // self.block_size)).sum())
+
+    def _kv_pages_window(self, cu, kvl) -> int:
+        """Pages the same rows hold in a WINDOW layer: from the page of
+        the lowest key a row's first query sees to the page of its last
+        key (``_kv_pages``: what one table for all layers would hold)."""
+        kvl = np.asarray(kvl)
+        first = kvl - np.diff(np.asarray(cu))[:len(kvl)]
+        lo = np.maximum(first - self._window + 1, 0) // self.block_size
+        return int(np.where(kvl > 0, -(-kvl // self.block_size) - lo,
+                            0).sum())
+
+    def _launch_kv_args(self, cu, kvl) -> dict:
+        """The page counts of a launch, as ``engine.device_launch``
+        carries them."""
+        pages = self._kv_pages(kvl)
+        if not self._windowed:
+            return {"kv_pages": pages}
+        return {"kv_pages": pages, "kv_pages_uniform": pages,
+                "kv_pages_window": self._kv_pages_window(cu, kvl)}
+
+    def _advance_window(self, req, start: int, end: int) -> None:
+        """Before a launch that holds req's queries at positions start
+        .. end - 1: the window layers give back what lies below the
+        window and take what the launch writes (a table-version bump
+        where that changed anything; nothing without window layers)."""
+        if self._windowed:
+            self.blocks.window_advance(req.rid, start, end)
+
+    def _table_row(self, req):
+        """req's block-table row(s) as a launch takes them: its page
+        list padded to the table's width; with window layers that list
+        and, under it, the window layers'."""
+        row = self.blocks.padded_table(req.rid, self.nblk)
+        if not self._windowed:
+            return row
+        return np.stack([row, self.blocks.window_table(req.rid, self.nblk)])
 
     def _get_window_prog(self):
         """The compiled K-step decode window driver (one per engine —
@@ -3160,7 +3335,7 @@ class LLMEngine:
         toks = np.zeros((Tq,), np.int32)
         cu = np.zeros((B + 1,), np.int32)
         kvl = np.zeros((B,), np.int32)
-        bt = np.full((B + 1, self.nblk), NULL_BLOCK, np.int32)
+        bt = np.full(self._bt_shape, NULL_BLOCK, np.int32)
         lidx = np.zeros((self._Lq,), np.int32)
         samp = make_samp(self._Lq, self.config.vocab_size)
         spec_slices, chunk_slots, batch_slots = [], [], []
@@ -3195,8 +3370,9 @@ class LLMEngine:
                         args={"step": sid, "rows": len(rows),
                               "tokens": total, "bucket": int(Tq)})
             t = tr.now()
-        for i, (req, _w, _k) in enumerate(rows):
-            bt[i] = self.blocks.padded_table(req.rid, self.nblk)
+        for i, (req, w, _k) in enumerate(rows):
+            self._advance_window(req, req.cached, req.cached + len(w))
+            bt[..., i, :] = self._table_row(req)
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
                         track=self._trace_track,
@@ -3235,7 +3411,7 @@ class LLMEngine:
                               "tokens": total, "rows": len(rows),
                               "chunks": len(chunks), "decode": len(batch),
                               "logit_rows": logit_rows,
-                              "kv_pages": self._kv_pages(kvl),
+                              **self._launch_pages,
                               "sample_chain": _sample_chain(samp)})
         # NO materialization here: sampled/logits/fin return as async
         # device arrays; _complete blocks on them (the dispatch path
@@ -3312,9 +3488,11 @@ class LLMEngine:
                               "prestaged": pre})
             t = tr.now()
         for s, req in enumerate(batch):
+            # (a no-op where the prestage already moved the window)
+            self._advance_window(req, int(buf.kvl[s]) - 1, int(buf.kvl[s]))
             ver = self.blocks.table_version(req.rid)
             if buf.bt_ver.get(req.rid) != ver:
-                buf.bt[s] = self.blocks.padded_table(req.rid, self.nblk)
+                buf.bt[..., s, :] = self._table_row(req)
                 buf.bt_ver[req.rid] = ver
         if tr is not None:
             tr.complete("engine.block_table_stage", t,
@@ -3332,7 +3510,7 @@ class LLMEngine:
                         args={"step": sid, "bucket": int(Tq), "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n,
-                              "kv_pages": self._kv_pages(buf.kvl),
+                              **self._launch_pages,
                               "sample_chain": _sample_chain(samp)})
         self._d_cur = bi
         return sampled, None, fin, [], [], list(range(n))

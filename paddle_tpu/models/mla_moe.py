@@ -234,25 +234,22 @@ def route(h2, p, cfg: MlaMoeConfig):
     return idx, g
 
 
-def moe_ffn(h2, p, cfg: MlaMoeConfig, valid=None, use_kernel=False):
-    """The expert layer's contribution to x from h2 [T, H], and its
-    counts (int32 [4]: pairs computed here, pairs routed anywhere,
-    experts here that got a token, most tokens at one expert here).
-    ``valid`` [T] bool marks real tokens; a launch's padding is routed
-    nowhere.  ``use_kernel``: the grouped products by the Pallas kernel
-    (the serving engine on a TPU), else by ``lax.ragged_dot``."""
+def routed_experts(h2, idx, g, p, *, first: int, held: int, valid,
+                   use_kernel: bool, gate: str = "silu"):
+    """The chosen experts held here, for tokens h2 [T, H] that chose
+    experts idx [T, k] (ids over ALL experts) with gates g [T, k]: pairs
+    sorted by expert, the two grouped products (``gate`` names the
+    function on the gate's half: ``silu``, ``relu``), and the weighted
+    sum back in token order.  Returns (out [T, H] in h2's type, pairs
+    computed here, pairs at each held expert [held])."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.pallas import grouped_matmul as _gm
     T, H = h2.shape
-    k, held = cfg.num_experts_per_tok, cfg.experts_held
-    if valid is None:
-        valid = jnp.ones((T,), bool)
-    with jax.named_scope("router"):
-        idx, g = route(h2, p, cfg)
+    k = idx.shape[-1]
     with jax.named_scope("moe_dispatch"):
-        local = idx - cfg.first_expert
+        local = idx - first
         here = (local >= 0) & (local < held) & valid[:, None]
         # pairs sorted by expert; what is not computed here sorts last
         e = jnp.where(here, local, held).reshape(T * k)
@@ -261,7 +258,7 @@ def moe_ffn(h2, p, cfg: MlaMoeConfig, valid=None, use_kernel=False):
         xs = h2[order // k]                                # [T*k, H]
     with jax.named_scope("moe_experts"):
         a = _gm.grouped_swiglu(xs, p["e_gate"], p["e_up"], sizes,
-                               use_kernel=use_kernel)
+                               use_kernel=use_kernel, gate=gate)
         y = _gm.grouped_matmul(a, p["e_down"], sizes,
                                use_kernel=use_kernel)
     with jax.named_scope("moe_combine"):
@@ -271,12 +268,38 @@ def moe_ffn(h2, p, cfg: MlaMoeConfig, valid=None, use_kernel=False):
         back = jnp.argsort(order)                          # pair -> its row
         y = y[back].reshape(T, k, H) * (g * here)[..., None]
         out = jnp.sum(y, axis=1).astype(h2.dtype)
+    return out, n_here, sizes
+
+
+def expert_counts(n_here, sizes, valid, k: int):
+    """What an expert layer counted, int32 [4]: pairs computed here,
+    pairs routed anywhere, experts here that got a token, most tokens at
+    one expert here."""
+    import jax.numpy as jnp
+    return jnp.stack([n_here, jnp.sum(valid).astype(jnp.int32) * k,
+                      jnp.sum(sizes > 0).astype(jnp.int32),
+                      jnp.max(sizes)]).astype(jnp.int32)
+
+
+def moe_ffn(h2, p, cfg: MlaMoeConfig, valid=None, use_kernel=False):
+    """The expert layer's contribution to x from h2 [T, H], and its
+    counts (``expert_counts``).  ``valid`` [T] bool marks real tokens; a
+    launch's padding is routed nowhere.  ``use_kernel``: the grouped
+    products by the Pallas kernel (the serving engine on a TPU), else by
+    ``lax.ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+    if valid is None:
+        valid = jnp.ones((h2.shape[0],), bool)
+    with jax.named_scope("router"):
+        idx, g = route(h2, p, cfg)
+    out, n_here, sizes = routed_experts(
+        h2, idx, g, p, first=cfg.first_expert, held=cfg.experts_held,
+        valid=valid, use_kernel=use_kernel)
     with jax.named_scope("shared_expert"):
         out = out + swiglu(h2, p["s_gate"], p["s_up"], p["s_down"])
-    counts = jnp.stack([n_here, jnp.sum(valid).astype(jnp.int32) * k,
-                        jnp.sum(sizes > 0).astype(jnp.int32),
-                        jnp.max(sizes)]).astype(jnp.int32)
-    return out, counts
+    return out, expert_counts(n_here, sizes, valid,
+                              cfg.num_experts_per_tok)
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,8 @@ an expert, no row dropped and none padded to a capacity.
 + group_sizes[e])`` of x [M, K] by w[e] of w [E, K, N], for every expert
 e in order (``start_e`` the sum of the sizes before it);
 ``grouped_swiglu(x, w_gate, w_up, group_sizes)`` gives ``silu(x w_gate[e])
-* (x w_up[e])`` for the same rows in one pass over x.  Rows past the last
+* (x w_up[e])`` for the same rows in one pass over x, ``grouped_reglu``
+the same with ReLU on the gate.  Rows past the last
 group are not computed: what the result holds there is undefined, and the
 caller masks it.  The work follows the sizes: an expert of no rows costs
 nothing, and all rows on one expert is one dense product.
@@ -86,11 +87,15 @@ def _visits(group_sizes, M, tm):
     return gid, mt.astype(jnp.int32), starts, ends, total.reshape(1)
 
 
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _kernel(gid_ref, mt_ref, starts_ref, ends_ref, n_ref, x_ref, *refs, tm,
-            swiglu):
+            gate):
     """grid (column blocks, visits).  x_ref [tm, K]: the visit's row
-    tile; one or two [K, tn] blocks of the visit's expert; o_ref [tm, tn]
-    stays in place while consecutive visits share a row tile."""
+    tile; one or two [K, tn] blocks of the visit's expert (two with
+    ``gate``, the name of the function on the first product); o_ref
+    [tm, tn] stays in place while consecutive visits share a row tile."""
     *w_refs, o_ref = refs
     v = pl.program_id(1)
 
@@ -101,8 +106,8 @@ def _kernel(gid_ref, mt_ref, starts_ref, ends_ref, n_ref, x_ref, *refs, tm,
         out = jax.lax.dot_general(x, w_refs[0][...],
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        if swiglu:
-            out = jax.nn.silu(out) * jax.lax.dot_general(
+        if gate is not None:
+            out = _GATES[gate](out) * jax.lax.dot_general(
                 x, w_refs[1][...], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         row = mt_ref[v] * tm + jax.lax.broadcasted_iota(
@@ -111,7 +116,7 @@ def _kernel(gid_ref, mt_ref, starts_ref, ends_ref, n_ref, x_ref, *refs, tm,
         o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
 
 
-def _launch(x, ws, group_sizes, out_dtype, swiglu):
+def _launch(x, ws, group_sizes, out_dtype, gate):
     M, K = x.shape
     N = ws[0].shape[-1]
     tm, tn = _tiles(M, K, N, ws[0].dtype)
@@ -127,7 +132,7 @@ def _launch(x, ws, group_sizes, out_dtype, swiglu):
                 + tm * tn * jnp.dtype(out_dtype).itemsize) \
         + 3 * tm * tn * 4
     out = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, swiglu=swiglu),
+        functools.partial(_kernel, tm=tm, gate=gate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(N // tn, n_visits),
@@ -153,21 +158,30 @@ def grouped_matmul(x, w, group_sizes, *, use_kernel: bool,
     ``out_dtype`` (float32 accumulation either way).  ``use_kernel``: the
     Pallas kernel, else ``lax.ragged_dot``."""
     if use_kernel:
-        return _launch(x, (w,), group_sizes, out_dtype, False)
+        return _launch(x, (w,), group_sizes, out_dtype, None)
     return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
                               preferred_element_type=out_dtype)
 
 
-def grouped_swiglu(x, w_gate, w_up, group_sizes, *, use_kernel: bool):
+def grouped_swiglu(x, w_gate, w_up, group_sizes, *, use_kernel: bool,
+                   gate: str = "silu"):
     """``silu(x w_gate[e]) * (x w_up[e])`` of each group's rows, in x's
-    type: the first half of an expert's SwiGLU, x read once."""
+    type: the first half of an expert's SwiGLU, x read once.  ``gate``
+    names the function on the first product (``relu``: ReGLU)."""
     if use_kernel:
-        return _launch(x, (w_gate, w_up), group_sizes, x.dtype, True)
+        return _launch(x, (w_gate, w_up), group_sizes, x.dtype, gate)
     gs = group_sizes.astype(jnp.int32)
-    gate = jax.lax.ragged_dot(x, w_gate, gs,
-                              preferred_element_type=jnp.float32)
+    g = jax.lax.ragged_dot(x, w_gate, gs,
+                           preferred_element_type=jnp.float32)
     up = jax.lax.ragged_dot(x, w_up, gs, preferred_element_type=jnp.float32)
-    return (jax.nn.silu(gate) * up).astype(x.dtype)
+    return (_GATES[gate](g) * up).astype(x.dtype)
+
+
+def grouped_reglu(x, w_gate, w_up, group_sizes, *, use_kernel: bool):
+    """``relu(x w_gate[e]) * (x w_up[e])``: the first half of a ReGLU
+    expert."""
+    return grouped_swiglu(x, w_gate, w_up, group_sizes,
+                          use_kernel=use_kernel, gate="relu")
 
 
 def grouped_matmul_reference(x, w, group_sizes, *, out_dtype=jnp.float32):

@@ -259,6 +259,12 @@ def _ragged_tiles(Tq, Hkv, G, D, bs, nblk, dtype):
     tq = max(1, min(int(cfg["q_tile_rows"]) // G, Tq))
     while Tq % tq:
         tq -= 1
+    # a tile's tq*G score rows are sliced out of the head-major q at
+    # j*tq*G: where a group that is no power of two (7) leaves that off
+    # the sublane 8, take the largest tile below whose rows are on it
+    if (tq * G) % 8:
+        tq = next((t for t in range(tq, 0, -1)
+                   if Tq % t == 0 and (t * G) % 8 == 0), tq)
     page_bytes = Hkv * bs * D * jnp.dtype(dtype).itemsize
     kvb = min(int(cfg["kv_pages"]), nblk,
               _KV_BUFFER_BYTES // (4 * page_bytes))
@@ -266,7 +272,7 @@ def _ragged_tiles(Tq, Hkv, G, D, bs, nblk, dtype):
 
 
 def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
-                   bs, nblk, quant):
+                   bs, nblk, quant, window=None):
     """One invocation walks the launch's rows in order.  Refs: q
     [Tq, Hkv, G, D] and the same tokens head-major qt [Hkv, Tq*G, D],
     both pre-scaled, in VMEM; the K and V pools of ALL layers
@@ -296,6 +302,15 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
     product (float = int8 * its page's scale for the head), q and the
     probabilities stay float32, and no dense float copy of a row's K/V
     ever exists.
+
+    With ``window`` (a layer whose query at position i sees keys i -
+    window < j <= i, its own among them) an item's walk has a FIRST page
+    as well as a last: the page of the lowest key its first query sees.
+    Pages below it are neither copied nor looked up in the table (a
+    sequence that has moved on has given them back: their entries name
+    the null page), and the mask has a lower bound beside the causal
+    one.  Without it the first page is the literal 0 and the body is
+    what it was.
     """
     if quant:
         ksc_ref, vsc_ref, *refs = refs
@@ -330,6 +345,19 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
         np_ = jnp.where(n_q > 0, rel_last // bs + 1, 0)
         return jnp.clip(np_, 0, nblk)
 
+    def page_range(r, j):
+        """(first page, pages) item (r, j) walks.  Without a window:
+        from page 0.  With one: from the page of the lowest key the
+        item's first query sees."""
+        if window is None:
+            return 0, n_pages(r, j)
+        qs, qe = cu_ref[r], cu_ref[r + 1]
+        n_q = qe - qs
+        first = jnp.where(n_q <= 1, qs, jnp.maximum(qs, j * tq))
+        rel_first = kvl_ref[r] - n_q + first - qs
+        p0 = jnp.clip((rel_first - (window - 1)) // bs, 0, nblk)
+        return p0, jnp.maximum(n_pages(r, j) - p0, 0)
+
     def tiles(r):
         """Tile range [j0, j1) of row r: one item for a row of at most
         one query, else the flat-token tiles it touches."""
@@ -338,13 +366,18 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
         return (jnp.where(one, 0, qs // tq),
                 jnp.where(one, 1, (qe + tq - 1) // tq))
 
-    def each_copy(r, b, np_, slot, act):
+    def each_copy(r, b, rng, slot, act):
         """``act`` on the K and the V copy of every live page of block b
-        of row r (np_ pages live) into ``slot``; returns their number."""
+        of row r (``rng``: its first page and how many are live) into
+        ``slot``; returns their number."""
+        p0, np_ = rng
         n = jnp.clip(np_ - b * kvb, 0, kvb)
 
         def one(p, c):
-            blk = bt_ref[r, b * kvb + p]
+            # (no "0 +" where there is no window: the dense programs'
+            # kernel lowers to the module it always did)
+            blk = bt_ref[r, b * kvb + p if window is None
+                         else p0 + b * kvb + p]
             act(pltpu.make_async_copy(k_hbm.at[layer, blk],
                                       kbuf.at[slot, p],
                                       sems.at[0, slot]))
@@ -355,11 +388,11 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
         jax.lax.fori_loop(0, n, one, 0)
         return n
 
-    def start(r, b, np_, slot):
-        each_copy(r, b, np_, slot, lambda d: d.start())
+    def start(r, b, rng, slot):
+        each_copy(r, b, rng, slot, lambda d: d.start())
 
-    def wait(r, b, np_, slot):
-        n = each_copy(r, b, np_, slot, lambda d: d.wait())
+    def wait(r, b, rng, slot):
+        n = each_copy(r, b, rng, slot, lambda d: d.wait())
 
         # pages of the block that were not copied hold what the slot
         # held before; masked scores give them probability 0, and
@@ -369,12 +402,14 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
             return c
         jax.lax.fori_loop(n, kvb, zero, 0)
 
-    def pages(buf, sc_ref, r, b, slot, h):
+    def pages(buf, sc_ref, r, b, p0, slot, h):
         """Head h of the slot's int8 pages as float32 [kv, D], each page
         times its own scale."""
         return jnp.concatenate([
             buf[slot, p, h].astype(jnp.float32)
-            * sc_ref[bt_ref[r, jnp.minimum(b * kvb + p, nblk - 1)], h]
+            * sc_ref[bt_ref[r, jnp.minimum(
+                b * kvb + p if window is None else p0 + b * kvb + p,
+                nblk - 1)], h]
             for p in range(kvb)], axis=0)
 
     def item(r, j, slot, *, one):
@@ -385,7 +420,8 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
         M = width * G
         qs, qe = cu_ref[r], cu_ref[r + 1]
         n_q = qe - qs
-        np_ = n_pages(r, j)
+        rng = page_range(r, j)
+        p0, np_ = rng
         nb = (np_ + kvb - 1) // kvb
         # the successor: the row's next tile, else the next row's first
         # (past the last row: an item of no pages, nothing to start)
@@ -394,7 +430,8 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
         last = rn >= rows
         rn = jnp.minimum(rn, rows - 1)
         jn = jnp.where(more, j + 1, tiles(rn)[0])
-        npn = jnp.where(last, 0, n_pages(rn, jn))
+        p0n, npn = page_range(rn, jn)
+        rngn = (p0n, jnp.where(last, 0, npn))
 
         if one:
             rel = jnp.full((M, 1), kvl_ref[r] - 1, jnp.int32)
@@ -412,21 +449,25 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
 
         @pl.when(nb == 0)
         def _pass_on():
-            start(rn, 0, npn, slot)
+            start(rn, 0, rngn, slot)
 
         def block(b, slot):
             @pl.when(b + 1 < nb)
             def _next_block():
-                start(r, b + 1, np_, 1 - slot)
+                start(r, b + 1, rng, 1 - slot)
 
             @pl.when(b + 1 == nb)
             def _next_item():
-                start(rn, 0, npn, 1 - slot)
+                start(rn, 0, rngn, 1 - slot)
 
-            wait(r, b, np_, slot)
+            wait(r, b, rng, slot)
             keypos = b * kv + jax.lax.broadcasted_iota(
                 jnp.int32, (1, kv), 1)
-            mask = keypos <= rel                       # [M, kv]
+            if window is None:
+                mask = keypos <= rel                   # [M, kv]
+            else:
+                keypos = keypos + p0 * bs
+                mask = (keypos <= rel) & (keypos > rel - window)
 
             @heads
             def _head(h):
@@ -435,8 +476,8 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
                 else:
                     q = qt_ref[h, pl.ds(j * (tq * G), M), :]
                 if quant:
-                    k = pages(kbuf, ksc_ref, r, b, slot, h)
-                    v = pages(vbuf, vsc_ref, r, b, slot, h)
+                    k = pages(kbuf, ksc_ref, r, b, p0, slot, h)
+                    v = pages(vbuf, vsc_ref, r, b, p0, slot, h)
                 else:
                     k = kbuf[slot, :, h].reshape(kv, D)
                     v = vbuf[slot, :, h].reshape(kv, D)
@@ -483,7 +524,7 @@ def _ragged_kernel(cu_ref, kvl_ref, bt_ref, layer_ref, *refs, rows, tq, kvb,
 
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
     j00, _ = tiles(0)
-    start(0, 0, n_pages(0, j00), 0)
+    start(0, 0, page_range(0, j00), 0)
 
     def row(r, slot):
         j0, j1 = tiles(r)
@@ -552,8 +593,11 @@ def decode_window_rows(active, kv_lens):
             jnp.where(active, kv_lens.astype(jnp.int32), 0))
 
 
+WINDOW_KERNEL_NAME = "ragged_paged_attention_window"
+
+
 def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
-                   kv_lens, scales=(), layer=None):
+                   kv_lens, scales=(), layer=None, window=None):
     """The raw ragged launch.  With ``layer`` (an int32 scalar, traced
     or static) the caches are the pools of ALL layers,
     [L, num_blocks, Hkv, bs, D], read where they lie at that index,
@@ -564,7 +608,12 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
     with cu[R] <= Tq, and every table entry in [0, num_blocks).  The
     table may carry more rows than kv_lens (serving's null row): they
     are not read.  ``scales`` is empty over float pages and the
-    layer's two [num_blocks, Hkv] f32 scale pools over int8 pages."""
+    layer's two [num_blocks, Hkv] f32 scale pools over int8 pages.
+    ``window`` (a static int): a query sees its own position and the
+    window - 1 before it; the table's entries for a row's pages below
+    its window are not read.  Such a launch has a kernel name of its
+    own, so that a device trace (which carries names and no scope)
+    tells a window layer's launches from the others'."""
     if layer is None:
         key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
     Tq, H, D = q.shape
@@ -578,7 +627,9 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
     M = tq * G
 
     kernel = functools.partial(_ragged_kernel, rows=rows, tq=tq, kvb=kvb,
-                               bs=bs, nblk=nblk, quant=quant)
+                               bs=bs, nblk=nblk, quant=quant,
+                               window=None if window is None
+                               else int(window))
     # scaled once, and over float pages rounded to q's dtype, as the
     # score product's operand always was; the head-major copy is what a
     # tile of several tokens reads, so its tq*G score rows are contiguous
@@ -616,7 +667,8 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
             vmem_limit_bytes=min(100 << 20, max(16 << 20, 2 * need))),
         interpret=interpret_mode(),
         name="ragged_paged_attention_q8" if quant
-        else "ragged_paged_attention",
+        else "ragged_paged_attention" if window is None
+        else WINDOW_KERNEL_NAME,
     )(cu_seqlens, kv_lens, block_tables,
       jnp.asarray(layer, jnp.int32).reshape(1), *scales, qr, qt, key_cache,
       value_cache)
@@ -624,16 +676,18 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
 
 
 def ragged_paged_attention_packed(q, key_cache, value_cache, block_tables,
-                                  cu_seqlens, kv_lens, layer=None):
+                                  cu_seqlens, kv_lens, layer=None,
+                                  window=None):
     """Ragged launch without the defensive clip/casts, for callers that
     guarantee the host-packing invariant (serving.py owns these buffers:
     its table pool is int32 and NULL_BLOCK-padded with valid indices,
     cu and kv_lens come int32 from the step's packing).  With ``layer``
     the caches are the pools of all layers, [L, num_blocks, H_kv, bs,
     D], read in place at that index (what a step program passes);
-    without it, one layer's."""
+    without it, one layer's.  ``window``: a sliding-window layer's
+    launch (``_ragged_launch``)."""
     return _ragged_launch(q, key_cache, value_cache, block_tables,
-                          cu_seqlens, kv_lens, layer=layer)
+                          cu_seqlens, kv_lens, layer=layer, window=window)
 
 
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,
@@ -704,9 +758,11 @@ def ragged_paged_reference_quant_segrel(q, key_cache, value_cache,
 
 
 def ragged_paged_reference_segrel(q, key_cache, value_cache, block_tables,
-                                  seg, rel):
+                                  seg, rel, window=None):
     """Dense-gather XLA oracle for the ragged kernel (the engine's former
-    chunked-resume math, term for term)."""
+    chunked-resume math, term for term).  ``window``: keys below a
+    query's position less window - 1 are masked as well (what their
+    table entries name is gathered and not used)."""
     Tq, H, D = q.shape
     _, Hkv, bs, _ = key_cache.shape
     R, nblk = block_tables.shape
@@ -728,6 +784,8 @@ def ragged_paged_reference_segrel(q, key_cache, value_cache, block_tables,
                         kq.astype(jnp.float32)) * sm_scale
     keypos = jnp.arange(nblk * bs, dtype=jnp.int32)
     mask = keypos[None, None, :] <= rel[:, None, None]
+    if window is not None:
+        mask &= keypos[None, None, :] > rel[:, None, None] - window
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("qhs,qshd->qhd", probs, vq.astype(jnp.float32))
@@ -735,11 +793,11 @@ def ragged_paged_reference_segrel(q, key_cache, value_cache, block_tables,
 
 
 def ragged_paged_reference(q, key_cache, value_cache, block_tables,
-                           cu_seqlens, kv_lens):
+                           cu_seqlens, kv_lens, window=None):
     """Dense-gather XLA oracle with the public (cu, kv_lens) interface."""
     seg, rel = ragged_segments(cu_seqlens, kv_lens, q.shape[0])
     return ragged_paged_reference_segrel(
-        q, key_cache, value_cache, block_tables, seg, rel)
+        q, key_cache, value_cache, block_tables, seg, rel, window=window)
 
 
 # Scalar memory of one TensorCore of the TPU v5e, the only device this was

@@ -1,9 +1,9 @@
 """Seeds attention-program-budget under a declaration: the module's
-layers have two attention kinds, so two attention program kinds are
-within budget and the third is not."""
+layers have three attention kinds, so three attention program kinds are
+within budget and the fourth is not."""
 import jax
 
-ATTENTION_KINDS = ("gqa", "mla")
+ATTENTION_KINDS = ("gqa", "mla", "gqa_window")
 
 
 def gqa_attention_step(q, k, v):
@@ -14,10 +14,15 @@ def latent_attention_step(q, c):
     return q
 
 
+def window_attention_step(q, k, v):
+    return q
+
+
 def decode_attention_step(q, k, v):
     return q
 
 
 GQA = jax.jit(gqa_attention_step)
 MLA = jax.jit(latent_attention_step)
-DECODE = jax.jit(decode_attention_step)    # a third kind: over budget
+WINDOW = jax.jit(window_attention_step)
+DECODE = jax.jit(decode_attention_step)    # a fourth kind: over budget

@@ -264,6 +264,52 @@ def test_the_grouped_expert_kernel_compiles_for_the_v5e(
     assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+@pytest.mark.parametrize("tq", [32, 64, 576])
+def test_the_kernel_compiles_at_group_7_and_over_a_window(
+        one_chip, compiled_kernels, no_persistent_cache, tq, window):
+    """28 query heads over 4 K/V heads (a decode item is 7 score rows,
+    not a multiple of the sublane 8), the pools of all layers of one
+    kind read at a layer index, a [33, 1024] table: the global layers'
+    launch and the window layers', which has a kernel name of its own."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, pages = (2, 32769) if window is None else (6, 9249)
+    pool = sds((layers, pages, 4, BLOCK, 128), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, bt, cu, kvl, l:
+                   pa.ragged_paged_attention_packed(
+                       q, k, v, bt, cu, kvl, layer=l, window=window)).lower(
+        sds((tq, 28, 128), jnp.bfloat16), pool, pool,
+        sds((ROWS + 1, 1024), jnp.int32), sds((ROWS + 1,), jnp.int32),
+        sds((ROWS,), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    name = "ragged_paged_attention" if window is None \
+        else pa.WINDOW_KERNEL_NAME
+    assert re.search(rf"%{name}(\.\d+)? = bf16\[{tq},4,7,128\]", text)
+
+
+def test_the_reglu_expert_kernel_compiles_for_the_v5e(
+        one_chip, compiled_kernels, no_persistent_cache):
+    """64 experts of 2560 x 768 with ReLU on the gate, at a 576-token
+    step's 3,456 pairs."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    sizes = sds((64,), jnp.int32)
+    up = jax.jit(lambda x, g, u, s: gm.grouped_reglu(
+        x, g, u, s, use_kernel=True)).lower(
+            sds((3456, 2560), bf), sds((64, 2560, 768), bf),
+            sds((64, 2560, 768), bf), sizes).compile().as_text()
+    down = jax.jit(lambda a, w, s: gm.grouped_matmul(
+        a, w, s, use_kernel=True)).lower(
+            sds((3456, 768), bf), sds((64, 768, 2560), bf),
+            sizes).compile().as_text()
+    assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down
+
+
 # ---------------------------------------------------------------------------
 # the sampling epilogue's branch (PR 29), in a whole ragged step program
 # ---------------------------------------------------------------------------

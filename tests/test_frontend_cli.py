@@ -160,3 +160,46 @@ def test_cli_serves_the_latent_attention_preset():
         assert srv.proc.wait(timeout=60) == 0
     finally:
         srv.kill()
+
+
+def test_cli_serves_the_window_and_global_preset():
+    """``--model smallthinker-sm``: global and sliding-window layers over
+    two page pools through the same CLI, engine and HTTP path; a
+    completion whose prompt is longer than the window (64) streams to its
+    length, and ``/metrics`` is served from the engine's summary."""
+    srv = _Server("--model", "smallthinker-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4", "--max-prefill-tokens", "32",
+                  "--no-prefix-caching")
+    try:
+        port = srv.port()
+        assert "attention='xla-reference (cpu platform)'" in srv.output()
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        body = json.dumps({"prompt": list(range(1, 150)),
+                           "max_tokens": 6}).encode()
+        conn.request("POST", "/v1/completions", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+        assert resp.status == 200
+        choice = doc["choices"][0]
+        assert choice["finish_reason"] == "length"
+        assert len(choice["token_ids"]) == 6
+        conn.request("GET", "/metrics")
+        resp = conn.getresponse()
+        assert resp.status == 200 and resp.read()
+        conn.close()
+        srv.proc.send_signal(signal.SIGINT)
+        assert srv.proc.wait(timeout=60) == 0
+    finally:
+        srv.kill()
+
+
+def test_cli_refuses_prefix_caching_over_the_window_pool_by_name():
+    srv = _Server("--model", "smallthinker-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4")
+    try:
+        assert srv.proc.wait(timeout=120) != 0
+        assert "enable_prefix_caching=True is not supported for a model " \
+            "with sliding-window layers" in srv.output()
+    finally:
+        srv.kill()

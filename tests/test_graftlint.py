@@ -45,7 +45,7 @@ def _lint_fix(name):
     (os.path.join("inference", "fix_attention_budget.py"),
      "attention-program-budget", 18, "decode_step", ERROR),
     (os.path.join("inference", "fix_attention_budget_kinds.py"),
-     "attention-program-budget", 17, "decode_attention_step", ERROR),
+     "attention-program-budget", 21, "decode_attention_step", ERROR),
     (os.path.join("inference", "fix_quantized_kv.py"),
      "quantized-kv-float32-page", 10, "build_pools", WARNING),
     (os.path.join("inference", "fix_weight_matmul.py"),

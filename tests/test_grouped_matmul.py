@@ -79,3 +79,27 @@ def test_the_walk_visits_each_groups_tiles_in_order():
     assert (gid[6:] == 5).all() and (mt[6:] == 2).all()   # stands still
     assert list(starts) == [0, 5, 5, 18, 20, 20]
     assert list(ends) == [5, 5, 18, 20, 20, 24]
+
+
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "ragged_dot"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_reglu_products_equal_ragged_dot(case, use_kernel, monkeypatch):
+    """ReGLU's first half, ``relu(x G_e) * (x U_e)``: the kernel with
+    ReLU where SwiGLU has SiLU, and its off-TPU path, against
+    ``lax.ragged_dot`` of each matrix."""
+    monkeypatch.setenv("PADDLE_TPU_TUNE_FORCE",
+                       '{"grouped_matmul": {"row_tile": 8}}')
+    sizes, M = CASES[case]
+    x, w, w2, gs = _case(sizes, M, seed=1)
+    n = int(sum(sizes))
+    with jax.default_matmul_precision("highest"):
+        act = np.asarray(jax.jit(lambda *a: gm.grouped_reglu(
+            *a, use_kernel=use_kernel))(x, w, w2, gs))
+        gate = np.asarray(jax.lax.ragged_dot(x, w, gs))
+        up = np.asarray(jax.lax.ragged_dot(x, w2, gs))
+    assert act.shape == (M, N)
+    np.testing.assert_allclose(act[:n], (np.maximum(gate, 0.0) * up)[:n],
+                               atol=1e-5, rtol=0)
+    if n:
+        assert (act[:n] == 0).any()     # ReLU, not SiLU: some gates shut

@@ -371,6 +371,146 @@ def test_grouped_expert_kernel_on_tpu():
     assert err_a < 2e-2 and err_y < 5e-2, (err_a, err_y)
 
 
+def _timed(fn, *args, n=10):
+    """Median milliseconds of fn(*args) over n calls, compile left out."""
+    import time
+    jax.block_until_ready(fn(*args))
+    ts = []
+    for _ in range(n):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ts.append((time.perf_counter() - t) * 1e3)
+    return sorted(ts)[len(ts) // 2]
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_group_7_kernel_and_its_window_on_tpu(window):
+    """28 query heads over 4 K/V heads (a decode item is 7 score rows,
+    not a multiple of the sublane 8) at the served shapes: pools of all
+    layers read at a layer index, a [33, 1024] table.  A 512-token chunk
+    resumed at 9,000 keys, decode rows under, at and far past the
+    window, a row of no keys; a window layer's table names the null
+    page below each row's window.  Against the dense-gather reference in
+    float32; the launch is timed."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    H, Hkv, D, bs, nblk, L, nb = 28, 4, 128, 16, 1024, 2, 4000
+    rng = np.random.RandomState(11)
+    rows = [(512, 9512), (1, 1), (0, 0), (1, 4096), (1, 4097), (1, 12000),
+            (1, 300), (5, 40), (1, 14999)]
+    Tq = 576
+    cu = np.zeros(33, np.int32)
+    kvl = np.zeros(32, np.int32)
+    bt = np.zeros((33, nblk), np.int32)
+    free = iter(rng.permutation(np.arange(1, nb)))
+    for r, (n, k) in enumerate(rows):
+        cu[r + 1] = cu[r] + n
+        kvl[r] = k
+        first = 0 if window is None else max(0, k - n - window + 1) // bs
+        for p in range(first, -(-k // bs)):
+            bt[r, p] = next(free)
+    cu[len(rows) + 1:] = cu[len(rows)]
+    live = int(cu[len(rows)])
+    q = jnp.asarray(rng.randn(Tq, H, D), jnp.bfloat16)
+    kc = jnp.asarray(rng.randn(L, nb, Hkv, bs, D), jnp.bfloat16)
+    vc = jnp.asarray(rng.randn(L, nb, Hkv, bs, D), jnp.bfloat16)
+    # the null page, which a window layer's walk must not read
+    kc, vc = kc.at[:, 0].set(100.0), vc.at[:, 0].set(100.0)
+    args = (jnp.asarray(bt), jnp.asarray(cu), jnp.asarray(kvl))
+    assert PA.ineligible(H, Hkv, D, bs, jnp.bfloat16,
+                         launch=(33, nblk, nb)) is None
+    fn = jax.jit(lambda q, kc, vc, bt, cu, kvl:
+                 PA.ragged_paged_attention_packed(
+                     q, kc, vc, bt, cu, kvl, layer=1, window=window))
+    out = fn(q, kc, vc, *args)
+    # the plain reference row by row (the dense-gather oracle would
+    # gather [576, 16384] keys a token): a row's keys in order, the whole
+    # score matrix, the causal and the window mask
+    ref = np.zeros((Tq, H, D), np.float32)
+    with jax.default_matmul_precision("highest"):
+        for r, (n, k) in enumerate(rows):
+            if n == 0 or k == 0:
+                continue
+            pages = jnp.asarray(bt[r, :-(-k // bs)])
+            kr = kc[1][pages].astype(jnp.float32).transpose(
+                0, 2, 1, 3).reshape(-1, Hkv, D)[:k]
+            vr = vc[1][pages].astype(jnp.float32).transpose(
+                0, 2, 1, 3).reshape(-1, Hkv, D)[:k]
+            pos = k - n + jnp.arange(n)
+            key = jnp.arange(k)
+            see = key[None, :] <= pos[:, None]
+            if window is not None:
+                see &= key[None, :] > pos[:, None] - window
+            qr = q[cu[r]:cu[r] + n].astype(jnp.float32).reshape(
+                n, Hkv, H // Hkv, D)
+            sc = jnp.einsum("qhgd,khd->hgqk", qr, kr) / np.sqrt(D)
+            pr = jax.nn.softmax(jnp.where(see[None, None], sc, -jnp.inf), -1)
+            vr = jnp.where(see.any(0)[:, None, None], vr, 0.0)
+            ref[cu[r]:cu[r] + n] = np.asarray(jnp.einsum(
+                "hgqk,khd->qhgd", pr, vr)).reshape(n, H, D)
+    ref = jnp.asarray(ref)
+    err = _max_err(out, ref, live)
+    ms = _timed(fn, q, kc, vc, *args)
+    print(f"group 7 kernel window={window}: max abs err {err:.3e} over "
+          f"{live} tokens, {ms:.3f} ms a launch")
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert not bool(jnp.any(out[live:]))
+    assert err < 2e-2, err
+    # 28 decode rows at 6k to 15k keys, as a step of the cell holds them
+    rows = [(1, int(k)) for k in rng.randint(6000, 15000, 28)]
+    cu = np.minimum(np.arange(33), 28).astype(np.int32)
+    kvl = np.zeros(32, np.int32)
+    bt = np.zeros((33, nblk), np.int32)
+    for r, (n, k) in enumerate(rows):
+        kvl[r] = k
+        first = 0 if window is None else max(0, k - n - window + 1) // bs
+        bt[r, first:-(-k // bs)] = rng.randint(1, nb, -(-k // bs) - first)
+    q32 = q[:32]
+    ms = _timed(fn, q32, kc, vc, jnp.asarray(bt), jnp.asarray(cu),
+                jnp.asarray(kvl))
+    print(f"group 7 kernel window={window}: 28 decode rows at 6k-15k keys "
+          f"{ms:.3f} ms a launch")
+
+
+def test_reglu_expert_kernel_on_tpu():
+    """64 experts of 2560 x 768 with ReLU on the gate, 3,456 sorted rows
+    (a 576-token step's pairs), one expert empty and one over a row
+    tile: the kernel against lax.ragged_dot, both halves; timed at the
+    step's pairs and at a decode step's 192."""
+    from paddle_tpu.ops.pallas import grouped_matmul as GM
+
+    rng = np.random.RandomState(3)
+    wg = jnp.asarray(rng.randn(64, 2560, 768) * 0.02, jnp.bfloat16)
+    wu = jnp.asarray(rng.randn(64, 2560, 768) * 0.02, jnp.bfloat16)
+    wd = jnp.asarray(rng.randn(64, 768, 2560) * 0.02, jnp.bfloat16)
+    for M, pairs in ((3456, 3456), (256, 192)):
+        sizes = rng.multinomial(pairs - 150, np.ones(64) / 64).astype(
+            np.int32)
+        sizes[9] += 150 - sizes[5]
+        sizes[5] = 0
+        n = int(sizes.sum())
+        x = jnp.asarray(rng.randn(M, 2560), jnp.bfloat16)
+        gs = jnp.asarray(sizes)
+        fns = {}
+        for use_kernel in (True, False):
+            up = jax.jit(lambda *t, k=use_kernel: GM.grouped_reglu(
+                *t, use_kernel=k))
+            down = jax.jit(lambda *t, k=use_kernel: GM.grouped_matmul(
+                *t, use_kernel=k))
+            a = up(x, wg, wu, gs)
+            y = down(a, wd, gs)
+            fns[use_kernel] = (up, down, a, y)
+        (up, down, a, y), (_, _, a0, y0) = fns[True], fns[False]
+        err_a = float(jnp.max(jnp.abs(a[:n].astype(jnp.float32)
+                                      - a0[:n].astype(jnp.float32))))
+        err_y = float(jnp.max(jnp.abs(y[:n] - y0[:n])))
+        print(f"reglu kernel against ragged_dot at {n} pairs: reglu "
+              f"{err_a:.3e}, down {err_y:.3e}; {_timed(up, x, wg, wu, gs):.3f}"
+              f" + {_timed(down, a, wd, gs):.3f} ms")
+        assert err_a < 2e-2 and err_y < 5e-2, (err_a, err_y)
+        assert float(jnp.mean(a[:n] == 0)) > 0.3     # ReLU shut these
+
+
 def _int8_page_kernel_err(H, Hkv, pool=None):
     """The int8-page kernel against its reference on the smoke's case,
     over a pool of ``pool`` pages (default: the pages the case uses)."""
