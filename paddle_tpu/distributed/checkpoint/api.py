@@ -97,10 +97,13 @@ def save_state_dict(state_dict, path, process_group=None,
                 "save_state_dict: multi-process saves must pass a shared "
                 "unique_id (e.g. the global step) — auto-assignment by "
                 "directory scan races across skewed ranks")
-        uids = set(_existing_uids(path))
         # in-flight async saves haven't written metadata yet: their uids
-        # must count too or back-to-back async saves collide on files
-        uids |= _issued_uids.get(os.path.abspath(path), set())
+        # must count too or back-to-back async saves collide on files.
+        # Read them BEFORE the directory: a writer drops its uid only
+        # after its metadata is there, so one that finishes in between
+        # is seen by the scan (the other order misses it in both)
+        uids = set(_issued_uids.get(os.path.abspath(path), set()))
+        uids |= _existing_uids(path)
         unique_id = (max(uids) + 1) if uids else 0
     _issued_uids.setdefault(os.path.abspath(path), set()).add(unique_id)
     flat = _flatten(state_dict)

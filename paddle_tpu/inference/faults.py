@@ -224,6 +224,16 @@ class FaultPlan:
         return not (self._crash or self._slow or self._nan
                     or self._inflight_crash or self._inflight_slow)
 
+    def armed(self) -> bool:
+        """True while a fault of this plan can still fire in an engine
+        step: one is scheduled and has not fired, or the pool window has
+        not passed.  The engine's seams are defined against the
+        synchronous step (dispatch N+1 after commit N), and an armed
+        plan holds the engine to it."""
+        return not self.exhausted() or (
+            self.pool_window is not None
+            and self.step <= self.pool_window[1])
+
     def __repr__(self):
         return (f"FaultPlan(step={self.step}, crash={self._crash}, "
                 f"slow={self._slow}, nan={self._nan}, "

@@ -77,16 +77,12 @@ class DegradationController:
 
     # -- per-step update ---------------------------------------------------
 
-    def update(self, blocks, spec_reserved: int = 0) -> int:
+    def update(self, blocks) -> int:
         """Observe the pool and move the state machine.  Returns the
-        (possibly new) state.  Called once per engine step.
-
-        ``spec_reserved`` credits back pages the async engine's
-        prestage took SPECULATIVELY for the next launch: at this point
-        of a synchronous step they would still be free, so counting
-        them as used would skew the free-page fraction (and the
-        retry-after trend) against the overlap engine for pages that
-        are not real demand yet.
+        (possibly new) state.  Called once per engine step, at the step
+        boundary: an engine that holds a controller dispatches nothing
+        ahead of a commit, so what it sees is the synchronous step's
+        pool.
 
         Parked (refcount-0 cached) pages count as headroom too,
         mirroring ``BlockManager.can_allocate``: the allocator evicts
@@ -106,7 +102,7 @@ class DegradationController:
         # them as used would double-escalate the very lever (spill-first
         # EVICT_PARKED) that created them
         reclaimable += int(getattr(blocks, "num_spill_pending", 0))
-        free = min(blocks.num_free + reclaimable + int(spec_reserved), total)
+        free = min(blocks.num_free + reclaimable, total)
         f = free / total if total > 0 else 1.0
         self._history.append((time.monotonic(), free))
 

@@ -115,6 +115,10 @@ class Request:
     tokens: list = field(default_factory=list)   # tokens to (re)prefill
     generated: list = field(default_factory=list)
     cached: int = 0                   # positions whose KV is in the pool
+    inflight: int = 0                 # positions the launch in flight
+                                      # writes for it (a chunk's tokens, 1
+                                      # for a decode row): what ``cached``
+                                      # will have grown by at its commit
     arrival: int = 0                  # admission priority (FCFS)
     slot: int = -1                    # stable decode-batch slot
     t_arrival: float = 0.0            # wall clock at add_request (TTFT)
@@ -147,10 +151,11 @@ class _StepTicket:
 
     ``dispatch()`` fills it with the launch's UNMATERIALIZED device
     arrays plus the packed-row layout needed to apply them; ``complete()``
-    pops it, blocks on the arrays, and commits.  The pipeline is depth-1
-    by design: the next dispatch needs the sampled tokens this ticket
-    carries (a decode row's input IS the previous step's output), so at
-    most one launch is ever in flight."""
+    blocks on the arrays and commits.  Between two ``step()`` calls at
+    most one ticket is in flight.  Inside a call there may be two: the
+    next launch is dispatched AHEAD of this one's commit, taking the
+    token a decode row feeds in from ``sampled`` on the device (the step
+    program's ``prev``/``src``), and this ticket is completed after."""
     chunks: list
     spec: list
     batch: list
@@ -172,6 +177,14 @@ class _StepTicket:
                                       # launch's expert layers counted
     sample_chain: int = 0             # 1 if a window launch held a sampled
                                       # row (its drain counts the passes)
+    slot_of: dict = field(default_factory=dict)   # rid -> logit row, of
+                                      # every chunk and decode row aboard
+    dropped: dict = field(default_factory=dict)   # rid -> "free" |
+                                      # "release": rows the commit of the
+                                      # launch BEFORE this one retired
+                                      # while this one already held them;
+                                      # they are dropped unapplied and
+                                      # their pages given back only now
 
 
 class _DecodeBufs:
@@ -179,17 +192,22 @@ class _DecodeBufs:
     fast path.  With overlap on the engine holds TWO and alternates
     launches between them: CPU PJRT may zero-copy alias an aligned host
     array into the program's input, so the buffers of an in-flight
-    launch must not be rewritten until its results materialize.
+    launch must not be rewritten until its results materialize (and a
+    launch dispatched ahead is packed while the one before it flies).
 
     ``bt_ver`` maps rid -> the block-table version staged into THIS
     buffer's ``bt`` row (the per-buffer replacement for the old
     per-request ``bt_version`` field: each buffer tracks its own
     staleness).  ``layout`` is the rid order last packed."""
 
-    __slots__ = ("toks", "cu", "kvl", "bt", "samp", "layout", "bt_ver")
+    __slots__ = ("toks", "src", "cu", "kvl", "bt", "samp", "layout",
+                 "bt_ver")
 
     def __init__(self, B, bt_shape, Lq, vocab_size):
         self.toks = np.zeros((B,), np.int32)
+        # -1: the token is ``toks``'; else the logit row of the launch in
+        # flight whose sample it is (the step program's ``src``)
+        self.src = np.full((B,), -1, np.int32)
         self.cu = np.zeros((B + 1,), np.int32)
         self.kvl = np.zeros((B,), np.int32)
         # [B + 1, nblk], or [2, B + 1, nblk] where window layers have a
@@ -325,6 +343,18 @@ def _refuse_latent_options(**asked) -> None:
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
+class _AheadAbandoned(Exception):
+    """A dispatch ahead of the in-flight launch's commit met something it
+    may not do (``reason``: a key of ``ahead_fallbacks``): this call
+    commits that launch first and dispatches as the synchronous engine
+    does.  What the attempt did before is kept: admissions, page
+    reservations and page copies are what that dispatch would do too."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
+
+
 class LLMEngine:
     """Continuous-batching serving loop over one LlamaForCausalLM.
 
@@ -386,16 +416,30 @@ class LLMEngine:
         mesh-blind.  Testable on CPU via
         ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     overlap: run the step loop as a dispatch/completion PIPELINE (the
-        default).  ``step()`` first pre-stages and completes the launch
-        the previous call left in flight, then dispatches this call's
-        launch WITHOUT materializing its results — JAX async dispatch
-        keeps the device busy across the step boundary while the host
-        does the next call's admission/scheduling/packing.  Greedy
-        output is byte-identical on or off and ``compile_counts`` is
-        unchanged (the pipeline adds zero programs); the visible
-        difference is that a request's outputs surface one ``step()``
-        call later and ``run()`` takes one extra draining call.  False
-        restores the fully synchronous launch-then-block step.
+        default).  A ``step()`` call that finds launch n in flight first
+        schedules, packs and launches n+1 from the state launch n WILL
+        leave (dispatch AHEAD: every row's position is known before n
+        returns, and the one thing that is not, the id of the token n
+        samples, passes from n's ``sampled`` to n+1's input on the
+        device), and only then blocks on n and commits it: the host's
+        whole turn runs under a step of the device, not after it.
+        Inside a call there are two tickets, between calls at most one
+        (so ``abort``, ``has_unfinished`` and recovery see what they
+        did).  Commit order is dispatch order.  Where launch n+1 needs
+        something only commit n can give, the call commits first and
+        dispatches after, as the synchronous step does: a decode window
+        on either side, a drafter, a ``kv_tier``, a pressure controller,
+        an armed fault plan, a row with a repetition penalty, or a
+        reservation or page copy only a preemption could meet
+        (``summary()["ahead_fallbacks"]`` counts each by name beside
+        ``launches_ahead``).  A request that commit n retires on a stop
+        token may already ride n+1: that row is dropped unapplied
+        (``ahead_rows_dropped``) and its pages are given back when n+1
+        has completed.  Greedy output is byte-identical on or off and a
+        token bucket still has ONE program; the visible difference is
+        that a request's outputs surface one ``step()`` call later and
+        ``run()`` takes one extra draining call.  False restores the
+        fully synchronous launch-then-block step.
     decode_window: K > 1 runs STEADY pure-decode packs as one
         device-resident K-step window: a single compiled program loops
         attention -> logit-processor chain -> sampling -> paged K/V
@@ -725,11 +769,29 @@ class LLMEngine:
         self._d_cur = 0                   # buffer of the latest launch
         self._d_lidx = np.minimum(np.arange(self._Lq), B - 1) \
             .astype(np.int32)
-        # dispatch/completion pipeline state (depth-1 queue)
+        # dispatch/completion pipeline state: the one ticket in flight
+        # between step() calls, and inside a call the one dispatched
+        # ahead of its commit (queued behind it on the device)
         self._inflight: _StepTicket | None = None
-        self._prestaged = None            # (buf index, layout) when valid
+        self._queued: _StepTicket | None = None
         self._pending_finished: list = [] # finishes from an abort() flush
-        self._spec_pages: dict = {}       # rid -> pages prestage reserved
+        # while a dispatch runs: the ticket it is dispatched ahead of
+        # (None: nothing in flight, every row's state is committed), and
+        # otherwise why this launch is not ahead (``ahead_fallbacks``)
+        self._ahead_of: _StepTicket | None = None
+        self._not_ahead = "idle" if self.overlap else "sync"
+        # launches dispatched before the launch in front of them was
+        # committed; the others by reason; rows such a launch held of a
+        # request the commit in front of it retired (dropped unapplied)
+        self.launches_ahead = 0
+        self.ahead_fallbacks: dict = {}
+        self.ahead_rows_dropped = 0
+        # what a launch takes as ``prev`` when nothing is in flight:
+        # placed as a launch's ``sampled`` comes back, so that a bucket
+        # has ONE program whichever it is handed
+        self._no_prev = jax.device_put(
+            np.zeros((self._Lq,), np.int32), devices[0] if self.tp == 1
+            else NamedSharding(self._mesh, P()))
 
         # program cache: ONE attention program kind, keyed only by the
         # flat-token bucket Tq.  The counter dict is the test-visible
@@ -787,6 +849,8 @@ class LLMEngine:
         self._commit_step = 0         # the ticket's id while it commits
         self._cow_n = 0               # CoW launches and their host time
         self._cow_ns = 0              # since engine.schedule began
+        self._sched_open = None       # (start, args) of that span, until
+        #                               the launch it chose is packed
         # resolve this engine's launch geometry from the tuning cache
         # once at build — pure host-side dict reads (no compile) whose
         # provenance summary() and serve_bench records surface
@@ -1183,7 +1247,6 @@ class LLMEngine:
         if self._inflight is not None:
             self._complete(self.tracer, self._pending_finished,
                            drop_rid=request_id)
-        self._spec_pages.pop(request_id, None)
         req = None
         for r in self._running:
             if r.rid == request_id:
@@ -1352,6 +1415,13 @@ class LLMEngine:
             # and pages live sequences gave back as they moved on
             out["kv_pages_window"] = self.pad_stats["kv_pages_window"]
             out["window_pages_returned"] = self.blocks.window_returned
+        # launches in all, those dispatched before the launch in front
+        # of them was committed, the others by why not, and the rows
+        # such a launch held of a request that commit retired
+        out["launches"] = self.launches
+        out["launches_ahead"] = self.launches_ahead
+        out["ahead_fallbacks"] = dict(self.ahead_fallbacks)
+        out["ahead_rows_dropped"] = self.ahead_rows_dropped
         out["sample_launches"] = self.sample_stats["launches"]
         out["sample_chain_launches"] = self.sample_stats["chain_launches"]
         if self._has_experts:
@@ -1512,7 +1582,12 @@ class LLMEngine:
         return self._step_head_structs(placed) + (
             sds((Tq,), i32), sds((B + 1,), i32), sds((B,), i32),
             sds(self._bt_shape, i32), sds((self._Lq,), i32),
-            samp_structs(self._Lq, self.config.vocab_size))
+            samp_structs(self._Lq, self.config.vocab_size),
+            # prev (the sampled tokens of the launch in front, where
+            # they came back) and src
+            sds((self._Lq,), i32,
+                sharding=self._no_prev.sharding if placed else None),
+            sds((Tq,), i32))
 
     def _window_arg_structs(self, placed: bool = False) -> tuple:
         """The decode-window driver's arguments as ShapeDtypeStructs:
@@ -1618,30 +1693,61 @@ class LLMEngine:
     # scheduler
     # ------------------------------------------------------------------
 
+    # What the scheduler reads of a row is its state once the launch in
+    # flight has been committed: known before that launch returns,
+    # except for the id of the token it samples.  ``req.inflight`` is 0
+    # for every row when nothing is in flight, and these are then the
+    # committed state itself.
+
+    def _pos(self, req) -> int:
+        """Positions of req whose KV is in the pool, the launch in
+        flight counted."""
+        return req.cached + req.inflight
+
+    def _ngen(self, req) -> int:
+        """Tokens req has generated, the one the launch in flight samples
+        for it counted (a decode row's, a chunk's that ends its prompt)."""
+        return len(req.generated) + (
+            req.inflight > 0
+            and req.cached + req.inflight >= len(req.tokens))
+
     def _decode_ready(self, req) -> bool:
         """Prefill complete and exactly the last generated token's KV is
         still unwritten (the decode step writes it and samples the next)."""
-        return (req.cached >= len(req.tokens)
-                and req.cached == len(req.prompt) + len(req.generated) - 1)
+        pos = self._pos(req)
+        return (pos >= len(req.tokens)
+                and pos == len(req.prompt) + self._ngen(req) - 1)
+
+    def _leaving(self, req) -> bool:
+        """The launch in flight samples req's last token: its commit
+        retires it whatever the token is (a stop token's id is not known
+        ahead, ``max_new_tokens`` is)."""
+        return req.inflight > 0 and self._ngen(req) >= req.max_new_tokens
 
     def step(self) -> list:
         """One engine iteration.  With ``overlap`` on (the default) this
-        is one turn of the dispatch/completion PIPELINE: pre-stage the
-        next pack while the previous call's launch is still on-device,
-        block on and commit that launch, then dispatch this call's
-        launch without materializing it.  Returns the requests that
-        finished — under overlap these are the completions of the
-        PREVIOUS call's dispatch (the pipeline's one-step latency).
-        With ``overlap`` off the dispatch completes in the same call and
-        the step is the classic synchronous admit -> schedule -> launch
-        -> apply -> retire iteration.
+        is one turn of the dispatch/completion PIPELINE: the launch the
+        previous call left in flight is still on the device, so this
+        call first schedules, packs and launches the NEXT one from the
+        state that launch will leave (dispatch ahead: the sampled tokens
+        pass from one launch to the next on the device), and only then
+        blocks on the first and commits it.  Where the next launch needs
+        something only the commit can give (``_ahead_blocked``, and the
+        reasons ``_dispatch`` abandons for) the call commits first and
+        dispatches after, as a synchronous step does.  Returns the
+        requests that finished: under overlap these are the completions
+        of the PREVIOUS call's dispatch (the pipeline's one-step
+        latency).  With ``overlap`` off the dispatch completes in the
+        same call and the step is the classic synchronous admit ->
+        schedule -> launch -> apply -> retire iteration.
 
         With a tracer installed every phase lands in the step timeline
-        (dispatch: admit / schedule / pack / block-table stage / device
-        launch; complete: block-on-result / sample-commit / retire; plus
-        prestage and the device in-flight window), each with the id of
-        the launch it belongs to (``step``: see docs/observability.md);
-        with none the phase seams are single attribute checks."""
+        (dispatch: admit where somebody waited / schedule, which
+        runs on over the packing of the rows it chose / device launch;
+        complete: block-on-result / sample-commit / retire; plus the
+        device in-flight window), each with the id of the launch it
+        belongs to (``step``: see docs/observability.md); with none the
+        phase seams are single attribute checks."""
         # ONE call site into _step, tracer or none: the line a program
         # is first reached from must not depend on who is watching
         tr = self.tracer
@@ -1659,20 +1765,38 @@ class LLMEngine:
         # surface here, so the step()-return channel never drops one
         finished = self._pending_finished
         self._pending_finished = []
+        why = "idle" if self.overlap else "sync"
+        ahead = None
         if self._inflight is not None:
             # the launch from the previous step() call is (possibly)
-            # still running on-device: do next step's speculative host
-            # work first, INSIDE that window, then block on the ticket
-            self._prestage(tr)
+            # still running on-device: the next one goes behind it on
+            # the device's queue first, and the host's whole turn runs
+            # INSIDE that window; then block on the ticket and commit
+            why = self._ahead_blocked(self._inflight)
+            if why is None:
+                try:
+                    ahead = self._dispatch(tr, ahead=self._inflight)
+                    # (nothing to launch yet: what the commit frees may
+                    # give the dispatch below something)
+                    why = "idle"
+                except _AheadAbandoned as e:
+                    why = e.reason
+            self._queued = ahead
             self._complete(tr, finished)
-        if self.kv_tier is not None:
-            # step boundary: no launch is in flight (completion above
-            # materialized the pools), and restores land before this
-            # step's admission packs only the residual prefill suffix
-            self._drain_kv_tier(tr)
-        self._dispatch(tr)
-        if not self.overlap and self._inflight is not None:
-            self._complete(tr, finished)
+            self._queued = None
+        if ahead is None:
+            if self.kv_tier is not None:
+                # step boundary: no launch is in flight (completion
+                # above materialized the pools), and restores land
+                # before this step's admission packs only the residual
+                # prefill suffix
+                self._drain_kv_tier(tr)
+            ahead = self._dispatch(tr, why=why)
+        self._inflight = ahead
+        if ahead is not None:
+            self._ride(ahead, True)
+            if not self.overlap:
+                self._complete(tr, finished)
 
         ev = self.blocks.eviction_count
         if ev != self._evictions_seen:
@@ -1680,20 +1804,74 @@ class LLMEngine:
             self._evictions_seen = ev
         return finished
 
-    def _dispatch(self, tr) -> None:
+    def _ride(self, ticket, on: bool) -> None:
+        """Count ``ticket``'s positions as in flight on its rows (or, at
+        its commit, no longer).  A verify row's and a window's are not
+        known ahead and not counted: nothing is dispatched ahead of
+        either (``_ahead_blocked``)."""
+        if ticket.window:
+            return
+        for req, n in ticket.chunks:
+            req.inflight = n if on else 0
+        for req in ticket.batch:
+            req.inflight = int(on)
+
+    def _ahead_blocked(self, ticket):
+        """Why the next launch cannot be dispatched before ``ticket`` is
+        committed, from what the engine is and holds; None where it can
+        be tried.  Each of these needs the step boundary (nothing in
+        flight, every token on the host): a decode window advances its
+        rows by a count the host learns at the drain; draft acceptance
+        reads the logits on the host; the spill tier copies pages
+        between launches; the pressure signal and an armed fault plan
+        are defined against the synchronous step."""
+        if ticket.window:
+            return "decode_window"
+        if self.drafter is not None:
+            return "drafter"
+        if self.kv_tier is not None:
+            return "kv_tier"
+        if self.pressure is not None:
+            return "pressure"
+        if self.fault_plan is not None and self.fault_plan.armed():
+            return "fault_plan"
+        return None
+
+    def _dispatch(self, tr, ahead=None, why: str = "idle"):
         """Admission + scheduling + packing + block-table staging + the
-        ragged launch, WITHOUT materializing results: the returned
-        device arrays ride an in-flight ``_StepTicket`` (JAX async
-        dispatch — nothing in this path forces a host sync on them).
-        ``_complete`` blocks on the ticket and commits."""
+        ragged launch, WITHOUT materializing results: the device arrays
+        come back in a ``_StepTicket`` (JAX async dispatch — nothing in
+        this path forces a host sync on them), or None where there was
+        nothing to launch.  ``_complete`` blocks on the ticket and
+        commits.
+
+        ``ahead`` is the ticket in flight when this runs BEFORE its
+        commit: the scheduler then reads every row where that launch
+        will leave it (``_pos``, ``_ngen``), a row whose next input is
+        that launch's sample names its logit row in ``src`` and the
+        program takes the token from ``ahead.sampled`` on the device,
+        and nothing is preempted: a reservation or a page copy the pool
+        cannot meet raises ``_AheadAbandoned``, as does a row with a
+        repetition penalty (its ``seen`` mask needs the id on the host)
+        and, with a decode window configured, a pure-decode launch (the
+        window's).  ``why`` is the reason a launch made here is not
+        ahead, for the counter and the trace."""
+        self._ahead_of, self._not_ahead = ahead, why
+        try:
+            return self._dispatch_launch(tr)
+        finally:
+            self._ahead_of = None
+
+    def _dispatch_launch(self, tr):
         plan = self.fault_plan
         if plan is not None:
             # fault seams fire BEFORE any scheduler mutation, so a crash
             # leaves queues and pool in the consistent between-steps
             # state recovery replays from.  advance() here keys the plan
-            # step on DISPATCH order, which equals completion order (the
-            # depth-1 pipeline completes ticket N before dispatching
-            # N+1), so a schedule means the same thing overlap on or off.
+            # step on DISPATCH order, which equals completion order (an
+            # armed plan keeps the pipeline one deep: ticket N completes
+            # before N+1 is dispatched), so a schedule means the same
+            # thing overlap on or off.
             plan.advance()
             if plan.take_pool_entry():
                 self.stats.record_fault("pool")
@@ -1707,15 +1885,8 @@ class LLMEngine:
                     f"injected step crash at plan step {plan.step}")
 
         if self.pressure is not None:
-            # pages the prestage reserved for rows still alive are
-            # credited back: at this point in the SYNC engine's step
-            # they would not have been taken yet, so the free-page
-            # signal (and every tier decision derived from it) sees the
-            # identical per-step timeline
             prev_tier = self.pressure.state
-            self.pressure.update(
-                self.blocks,
-                spec_reserved=sum(self._spec_pages.values()))
+            self.pressure.update(self.blocks)
             self.stats.set_degradation_state(self.pressure.state)
             if tr is not None and self.pressure.state != prev_tier:
                 tr.instant("pressure.tier", track=self._trace_track,
@@ -1730,14 +1901,17 @@ class LLMEngine:
                 if n:
                     self.stats.record_parked_evictions(n)
 
+        ahead = self._ahead_of
         if tr is not None:
             sid = self.launches + 1     # the launch this call prepares
             t_d = tr.now()
             t = tr.now()
+            waited = len(self._waiting)
         admitted = self._admit()
         if admitted:
             self.stats.record_admission(len(admitted))
-        if tr is not None:
+        if tr is not None and waited:
+            # with nobody waiting there is no admission phase to show
             tr.complete("engine.admit", t, track=self._trace_track,
                         args={"step": sid, "admitted": len(admitted),
                               "running": len(self._running),
@@ -1745,52 +1919,81 @@ class LLMEngine:
         self.peak_resident_seqs = max(self.peak_resident_seqs,
                                       len(self._running))
         self.stats.record_prefill_queue(
-            sum(1 for r in self._running if r.cached < len(r.tokens))
+            sum(1 for r in self._running if self._pos(r) < len(r.tokens))
             + len(self._waiting))
 
         if tr is not None:
             t = tr.now()
             ev0 = self.blocks.eviction_count
             self._cow_n = self._cow_ns = 0
-        chunks = self._schedule_prefill_chunks()
+        chunks = spec = batch = ()
+        ticket = None
+        try:
+            if ahead is not None and any(r.seen is not None
+                                         for r in self._running):
+                raise _AheadAbandoned("penalty")
+            chunks = self._schedule_prefill_chunks()
 
-        # decode-ready set (chunk owners are still mid-prefill, so the
-        # row classes are disjoint by construction)
-        batch = [r for r in self._running if self._decode_ready(r)]
-        # speculative sequences pack a [last_token, drafts...] window;
-        # everything else packs a single decode token in the same launch
-        spec, batch = self._split_spec(batch)
-        spec, demoted = self._reserve_verify_pages(spec)
-        batch.extend(demoted)
-        # verify reservation/CoW may have preempted plain-decode members
-        batch = [r for r in batch
-                 if r in self._running and self._decode_ready(r)]
-        batch = self._reserve_decode_pages(batch)
-        # every reservation above can preempt a chunk owner or an
-        # already-reserved row: re-filter each class against the
-        # surviving running set before packing the launch
-        chunks = [(r, n) for r, n in chunks if r in self._running]
-        spec = [(r, d, q) for r, d, q in spec if r in self._running]
-        batch = [r for r in batch if r in self._running]
-        batch.sort(key=lambda r: r.slot)
+            # decode-ready set (chunk owners are still mid-prefill, so
+            # the row classes are disjoint by construction)
+            batch = [r for r in self._running
+                     if self._decode_ready(r) and not self._leaving(r)]
+            # speculative sequences pack a [last_token, drafts...]
+            # window; everything else packs a single decode token in
+            # the same launch
+            spec, batch = self._split_spec(batch)
+            spec, demoted = self._reserve_verify_pages(spec)
+            batch.extend(demoted)
+            # verify reservation/CoW may have preempted plain-decode
+            # members
+            batch = [r for r in batch
+                     if r in self._running and self._decode_ready(r)]
+            batch = self._reserve_decode_pages(batch)
+            # every reservation above can preempt a chunk owner or an
+            # already-reserved row: re-filter each class against the
+            # surviving running set before packing the launch
+            chunks = [(r, n) for r, n in chunks if r in self._running]
+            spec = [(r, d, q) for r, d, q in spec if r in self._running]
+            batch = [r for r in batch if r in self._running]
+            batch.sort(key=lambda r: r.slot)
+            if ahead is not None and self.decode_window > 1 \
+                    and not chunks and batch:
+                raise _AheadAbandoned("decode_window")
+        except _AheadAbandoned as e:
+            if tr is not None:
+                tr.complete("engine.schedule", t, track=self._trace_track,
+                            args={"step": sid, "abandoned": e.reason})
+                tr.complete("engine.dispatch", t_d,
+                            track=self._trace_track,
+                            args={"step": sid, "launched": False,
+                                  "ahead": True, "abandoned": e.reason})
+            raise
         if tr is not None:
-            tr.complete("engine.schedule", t, track=self._trace_track,
-                        args={"step": sid, "chunks": len(chunks),
-                              "spec": len(spec), "decode": len(batch),
-                              "evicted": self.blocks.eviction_count - ev0,
-                              "cow": self._cow_n, "cow_ns": self._cow_ns})
+            sched = {"step": sid, "chunks": len(chunks),
+                     "spec": len(spec), "decode": len(batch),
+                     "evicted": self.blocks.eviction_count - ev0,
+                     "cow": self._cow_n, "cow_ns": self._cow_ns}
+            if chunks or spec or batch:
+                # the span runs on over the packing of what it chose
+                self._sched_open = (t, sched)
+            else:
+                tr.complete("engine.schedule", t, track=self._trace_track,
+                            args=sched)
 
         if chunks or spec or batch:
             t0 = time.perf_counter()
-            launched = False
             if (self.decode_window > 1 and not chunks and not spec
                     and self._window_eligible(batch)):
-                launched = self._dispatch_window(batch, tr, t0)
-            if not launched:
+                ticket = self._dispatch_window(batch, tr, t0)
+            if ticket is None:
                 sampled, logits, fin, spec_slices, chunk_slots, \
                     batch_slots = self._run_ragged(chunks, spec, batch)
                 now = time.perf_counter()
-                self._inflight = _StepTicket(
+                slot_of = {r.rid: s for (r, _), s
+                           in zip(chunks, chunk_slots)}
+                slot_of.update((r.rid, s)
+                               for r, s in zip(batch, batch_slots))
+                ticket = _StepTicket(
                     chunks=chunks, spec=spec, batch=batch,
                     sampled=sampled, logits=logits, fin=fin,
                     spec_slices=spec_slices, chunk_slots=chunk_slots,
@@ -1798,17 +2001,15 @@ class LLMEngine:
                     t_launch=now,
                     launch_ns=tr.now() if tr is not None else 0,
                     step=self.launches, inflight=self.overlap,
-                    counts=self._launch_counts)
-        # prestage page credit expires: every reserved page is now
-        # either owned by a row this dispatch packed (its ensure() saw
-        # the page already in place) or was freed with its retired row
-        self._spec_pages.clear()
+                    counts=self._launch_counts, slot_of=slot_of)
         if tr is not None:
             tr.complete("engine.dispatch", t_d, track=self._trace_track,
                         args={"step": sid, "chunks": len(chunks),
                               "spec": len(spec),
                               "decode": len(batch),
-                              "launched": self._inflight is not None})
+                              "launched": ticket is not None,
+                              "ahead": ahead is not None})
+        return ticket
 
     def _complete(self, tr, finished: list, drop_rid=None) -> None:
         """Block on the in-flight ticket and commit it: materialize the
@@ -1819,9 +2020,13 @@ class LLMEngine:
         ``drop_rid`` (abort-while-in-flight) discards that request's
         packed rows unapplied: no token commit, no retirement, leaving
         the request holding exactly the tokens the aborting caller
-        could observe."""
+        could observe.  The rows in ``ticket.dropped`` go the same way:
+        their requests were retired by the commit in front of this
+        launch, after it had been dispatched; their pages, which this
+        launch still named, are given back here."""
         ticket = self._inflight
         self._inflight = None
+        self._ride(ticket, False)
         plan = self.fault_plan
         if plan is not None and ticket.inflight:
             # completion-order seams: fire while the ticket is genuinely
@@ -1865,6 +2070,11 @@ class LLMEngine:
                                   "rows": len(ticket.chunks)
                                   + len(ticket.spec)
                                   + len(ticket.batch)})
+            if self._queued is not None:
+                # the launch queued behind this one has the device from
+                # here, not from its own jit call: its window starts
+                # where this one's ends, and no step is counted twice
+                self._queued.launch_ns = tr.now()
         if ticket.window:
             # window outputs are [K, B]: the NaN seam corrupts one live
             # row's FIRST iteration (the device kept looping; the drain
@@ -1882,17 +2092,24 @@ class LLMEngine:
         chunk_slots = ticket.chunk_slots
         batch_slots = ticket.batch_slots
         spec_slices = ticket.spec_slices
+        drop = set(ticket.dropped)
         if drop_rid is not None:
-            kc = [i for i, (r, _) in enumerate(chunks) if r.rid != drop_rid]
+            drop.add(drop_rid)
+        if drop:
+            kc = [i for i, (r, _) in enumerate(chunks) if r.rid not in drop]
             chunks = [chunks[i] for i in kc]
             chunk_slots = [chunk_slots[i] for i in kc]
             ks = [i for i, (r, _, _) in enumerate(spec)
-                  if r.rid != drop_rid]
+                  if r.rid not in drop]
             spec = [spec[i] for i in ks]
             spec_slices = [spec_slices[i] for i in ks]
-            kb = [i for i, r in enumerate(batch) if r.rid != drop_rid]
+            kb = [i for i, r in enumerate(batch) if r.rid not in drop]
             batch = [batch[i] for i in kb]
             batch_slots = [batch_slots[i] for i in kb]
+        # the last launch that named these pages has come back
+        for rid, how in ticket.dropped.items():
+            getattr(self.blocks, how)(rid)
+        self.ahead_rows_dropped += len(ticket.dropped)
         spec_ok = [bool(ok[o:o + n].all())
                    for o, n in spec_slices]
         spec_logits = None
@@ -1900,8 +2117,9 @@ class LLMEngine:
             spec_logits = [logits[o:o + n]
                            for o, n in spec_slices]
         # dur is the engine's ACTIVE time on this launch (host packing +
-        # the residual block); the device time hidden under prestage and
-        # the inter-call gap is exactly what the overlap bought
+        # the residual block); the device time hidden under the next
+        # dispatch and the inter-call gap is exactly what the overlap
+        # bought
         dur = ticket.dispatch_s + block_s
         self.stats.record_step(dur, dispatch_s=ticket.dispatch_s,
                                block_s=block_s)
@@ -1929,123 +2147,6 @@ class LLMEngine:
             tr.complete("engine.complete", t_c, track=self._trace_track,
                         args={"step": sid, "finished": len(finished)})
 
-    def _prestage(self, tr) -> None:
-        """Speculatively stage the NEXT dispatch's pure-decode pack
-        while the in-flight ticket runs on-device.
-
-        A surviving decode row's next position is known before the
-        ticket's sampled token is: it packs exactly kv_len+1 next step.
-        So page reservation (``ensure``), the block-table rows, the
-        kv-length column, and the per-row sampling keys all pre-stage
-        into the idle decode buffer; only the token-id column (and the
-        repetition-penalty masks) are patched in at dispatch.  The
-        prestage NEVER preempts — a short pool abandons it, and the
-        partial row-local writes are idempotent (the normal incremental
-        path redoes them).  Rollback rides the existing machinery: a row
-        the completion retires/quarantines (or a later preemption)
-        returns its speculatively reserved page with the rest of its
-        table through free()/release(), and the layout-signature check
-        at dispatch discards the stale pack."""
-        if not self.overlap:
-            return
-        ticket = self._inflight
-        if ticket.window:
-            return                      # the window advanced K positions;
-                                        # its drain re-schedules from live
-                                        # request state, not a prestage
-        if ticket.chunks or ticket.spec or not ticket.batch:
-            return                      # only pure-decode launches
-        if self._waiting:
-            return                      # next step admits -> mixed pack
-        for r in self._running:
-            if r.cached < len(r.tokens):
-                return                  # mid-prefill row -> mixed pack
-        if self.drafter is not None:
-            for r in ticket.batch:
-                if not r.spec_disabled and r.spec_k > 0:
-                    return              # next step may pack verify rows
-        batch = ticket.batch            # already slot-sorted at dispatch
-        self._prestaged = None
-        if tr is not None:
-            t_p = tr.now()
-            sid = self.launches + 1     # the launch this stages for
-        # reserve each row's next write: pre-apply cached+2 is exactly
-        # the post-apply cached+1 the dispatch's ensure() will ask for,
-        # so that ensure becomes a no-op.  Newly taken pages are
-        # tracked per rid so the pressure signal credits them back
-        # until this dispatch (or a retirement) owns them.
-        abandoned = False
-        for req in batch:
-            before = self.blocks.num_free
-            try:
-                if not self.blocks.ensure(req.rid, req.cached + 2):
-                    abandoned = True
-            except BlockPoolExhausted:
-                abandoned = True
-            if abandoned:
-                break
-            took = before - self.blocks.num_free
-            if took > 0:
-                self._spec_pages[req.rid] = \
-                    self._spec_pages.get(req.rid, 0) + took
-        if abandoned:
-            if tr is not None:
-                tr.complete("engine.prestage", t_p,
-                            track=self._trace_track,
-                            args={"step": sid, "abandoned": "pool"})
-            return
-        bi = 1 - self._d_cur            # the buffer NOT in flight
-        buf = self._dbufs[bi]
-        samp = buf.samp
-        n = len(batch)
-        layout = tuple(r.rid for r in batch)
-        if layout != buf.layout:
-            buf.layout = layout
-            buf.bt_ver.clear()
-            buf.bt[:] = NULL_BLOCK
-            buf.kvl[:] = 0
-            buf.cu[:n + 1] = np.arange(n + 1)
-            buf.cu[n + 1:] = n
-            samp["temps"][:] = 0.0
-            samp["top_k"][:] = 0
-            samp["top_p"][:] = 1.0
-            samp["penalty"][:] = 1.0
-            samp["seen"][:] = False
-            for s, req in enumerate(batch):
-                samp["temps"][s] = req.temperature
-                samp["top_k"][s] = req.top_k
-                samp["top_p"][s] = req.top_p
-                samp["penalty"][s] = req.repetition_penalty
-        if tr is not None:
-            t = tr.now()
-        for s, req in enumerate(batch):
-            buf.kvl[s] = req.cached + 2      # post-apply cached+1
-            if req.temperature > 0.0:
-                # the key for the NEXT position: len(generated) will
-                # have advanced by one when this buffer launches
-                samp["keys"][s] = self._req_key(req, ahead=1)
-        if tr is not None:
-            tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"step": sid, "rows": n, "prestage": True})
-            t = tr.now()
-        for s, req in enumerate(batch):
-            # the window moves for the NEXT launch's position; what it
-            # gives back the launch in flight has read by the time a
-            # later launch writes it (one device queue)
-            self._advance_window(req, req.cached + 1, req.cached + 2)
-            ver = self.blocks.table_version(req.rid)
-            if buf.bt_ver.get(req.rid) != ver:
-                buf.bt[..., s, :] = self._table_row(req)
-                buf.bt_ver[req.rid] = ver
-        if tr is not None:
-            tr.complete("engine.block_table_stage", t,
-                        track=self._trace_track,
-                        args={"step": sid, "rows": n, "prestage": True})
-        self._prestaged = (bi, layout)
-        if tr is not None:
-            tr.complete("engine.prestage", t_p, track=self._trace_track,
-                        args={"step": sid, "rows": n})
-
     def _invalidate_bt(self, rid: int) -> None:
         """Drop both decode buffers' staged block-table rows for rid.
         Called whenever a rid's staged table can go stale without a
@@ -2057,12 +2158,10 @@ class LLMEngine:
     def _break_decode_layout(self) -> None:
         """Invalidate the decode fast path entirely: any mixed launch
         (and post-verify truncate) rewrites tables and row order, so
-        both buffers full-restage at their next pure-decode launch and
-        any pre-staged pack is discarded."""
+        both buffers full-restage at their next pure-decode launch."""
         for buf in self._dbufs:
             buf.layout = ()
             buf.bt_ver.clear()
-        self._prestaged = None
 
     def _apply_ragged(self, chunks, spec, batch, sampled, ok, spec_ok,
                       spec_logits, chunk_slots, batch_slots, dur,
@@ -2196,12 +2295,12 @@ class LLMEngine:
             rows.append((req.rid, req.cached + m))
         return self.blocks.reserve_window(rows)
 
-    def _dispatch_window(self, batch: list, tr, t0: float) -> bool:
+    def _dispatch_window(self, batch: list, tr, t0: float):
         """Reserve, pack, and launch one K-step decode window over
         ``batch`` (slot-sorted, first-write pages already ensured).
-        Returns True with the window ticket in flight, or False when
-        the pool could not cover even a 2-token window (the caller runs
-        the per-step path for this step).  Between those extremes the
+        Returns the window's ticket, or None when the pool could not
+        cover even a 2-token window (the caller runs the per-step path
+        for this step).  Between those extremes the
         window ADAPTS: when K tokens of slack don't fit, the dispatch
         retries the reservation at K-1, K-2, ... and runs the largest
         feasible K' device-resident — the per-row generation budgets
@@ -2222,7 +2321,7 @@ class LLMEngine:
                            track=self._trace_track,
                            args={"step": self.launches + 1,
                                  "rows": len(batch), "k": K})
-            return False
+            return None
         if kp < K:
             self.stats.record_window_shrink()
             if tr is not None:
@@ -2264,15 +2363,11 @@ class LLMEngine:
                 base_keys[s] = np.asarray(
                     jax.random.PRNGKey(req.seed), np.uint32)
         if tr is not None:
-            tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"step": sid, "rows": n, "window": kp})
-            t = tr.now()
+            t_bt = tr.now()
         for s, req in enumerate(batch):
             bt[s] = self.blocks.padded_table(req.rid, self.nblk)
         if tr is not None:
-            tr.complete("engine.block_table_stage", t,
-                        track=self._trace_track,
-                        args={"step": sid, "rows": n, "window": kp})
+            self._packed(tr, t, t_bt, rows=n, tokens=n, bucket=B, window=kp)
         # the window grows tables past anything the per-step buffers
         # staged; force full restages at the next per-step launch
         self._break_decode_layout()
@@ -2288,16 +2383,16 @@ class LLMEngine:
                         args={"step": sid, "bucket": B, "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n, "window": kp,
-                              "sample_chain": chain})
+                              "sample_chain": chain,
+                              **self._ahead_args()})
         now = time.perf_counter()
-        self._inflight = _StepTicket(
+        return _StepTicket(
             chunks=[], spec=[], batch=list(batch), sampled=toks_out,
             logits=None, fin=fin_out, spec_slices=[], chunk_slots=[],
             batch_slots=list(range(n)), dispatch_s=now - t0,
             t_launch=now, launch_ns=tr.now() if tr is not None else 0,
             step=self.launches, inflight=self.overlap, window=kp,
             sample_chain=chain)
-        return True
 
     def _apply_window(self, batch, batch_slots, sampled, ok, dur,
                       finished, window, sample_chain):
@@ -2365,8 +2460,7 @@ class LLMEngine:
         prefix cache — corrupt K/V must never become a future cache
         hit).  Clients see finish_reason="numerical_error"; the rest of
         the batch is untouched."""
-        self.blocks.release(req.rid)
-        self._spec_pages.pop(req.rid, None)
+        self._give_back(req.rid, "release")
         self._running.remove(req)
         self._release_slot(req)
         if self.drafter is not None:
@@ -2415,7 +2509,7 @@ class LLMEngine:
     def _drain_kv_tier(self, tr) -> None:
         """Step-boundary tier drain — the ONLY place spill/restore bytes
         cross the HBM/host boundary (graft-lint's host-copy-in-step-path
-        keeps it out of the dispatch/prestage/complete hot phases).
+        keeps it out of the dispatch/complete hot phases).
         Spill: pages evict_parked quarantined copy out to the host pool
         and their HBM blocks free.  Restore: router prefetch hints, then
         the waiting queue's prompt chains, pull tier-resident pages back
@@ -2609,23 +2703,27 @@ class LLMEngine:
             if self.enable_prefix_caching:
                 # may preempt req (False) or drop an earlier chunk's
                 # owner from the accumulator (drop_from)
-                return self._resolve_cow(req, req.cached, drop_from=chunks)
+                return self._resolve_cow(req, self._pos(req),
+                                         drop_from=chunks)
             return True
 
         ordered = sorted(list(self._running), key=lambda r: r.arrival)
         return pack_prefill_chunks(
-            ((r, len(r.tokens) - r.cached) for r in ordered),
+            ((r, len(r.tokens) - self._pos(r)) for r in ordered),
             self.max_prefill_tokens, admit=admit, out=chunks)
 
     def _resolve_cow(self, req, pos: int, drop_from: list | None = None) \
             -> bool:
         """Privatize the page holding ``pos`` if it is shared, preempting
         victims while the pool has no page for the copy.  False when req
-        itself had to be preempted."""
+        itself had to be preempted.  (The copy is queued behind the
+        launch in flight, which does not write the shared page.)"""
         while True:
             try:
                 cw = self.blocks.cow_if_shared(req.rid, pos)
             except BlockPoolExhausted:
+                if self._ahead_of is not None:
+                    raise _AheadAbandoned("pool") from None
                 victim = self._pick_victim(exclude=req)
                 if victim is None:
                     self._preempt(req)
@@ -2643,13 +2741,16 @@ class LLMEngine:
     def _reserve_decode_pages(self, batch: list) -> list:
         """Grow each sequence's table for the token this step will write
         (plus a private copy of a still-shared tail page); preempt the
-        youngest runner whenever the pool comes up short."""
+        youngest runner whenever the pool comes up short (a dispatch
+        ahead never preempts: it is abandoned)."""
         ok = []
         for req in sorted(batch, key=lambda r: r.arrival):
             if req not in self._running:   # evicted as a victim earlier
                 continue
             while req is not None:
-                if not self.blocks.ensure(req.rid, req.cached + 1):
+                if not self.blocks.ensure(req.rid, self._pos(req) + 1):
+                    if self._ahead_of is not None:
+                        raise _AheadAbandoned("pool")
                     victim = self._pick_victim(exclude=req)
                     if victim is None:
                         self._preempt(req)
@@ -2659,7 +2760,7 @@ class LLMEngine:
                     ok = [r for r in ok if r is not victim]
                     continue
                 if self.enable_prefix_caching:
-                    if not self._resolve_cow(req, req.cached):
+                    if not self._resolve_cow(req, self._pos(req)):
                         req = None
                         break
                     ok = [r for r in ok if r in self._running]
@@ -2684,7 +2785,6 @@ class LLMEngine:
         very pages this preemption returned and re-prefills only the
         tail."""
         self.blocks.free(req.rid)
-        self._spec_pages.pop(req.rid, None)
         self._running.remove(req)
         self._release_slot(req)
         req.tokens = list(req.prompt) + list(req.generated)
@@ -2705,6 +2805,22 @@ class LLMEngine:
                                 args={"rid": req.rid,
                                       "step": self.launches + 1})
 
+    def _give_back(self, rid: int, how: str) -> None:
+        """Return a retired request's pages (``how``: ``BlockManager.
+        free``, or ``.release`` for a row that must leave nothing in the
+        prefix cache).  Where the launch queued behind the one being
+        committed already holds the row (a stop token, or a non-finite
+        row, that only this commit could see), that row is dropped
+        unapplied when its launch completes and the pages go back then:
+        until the last launch that names them has come back they are
+        neither handed to another row nor registered in the prefix
+        cache.  One device queue orders everything after."""
+        q = self._queued
+        if q is not None and rid in q.slot_of:
+            q.dropped[rid] = how
+        else:
+            getattr(self.blocks, how)(rid)
+
     def _maybe_retire(self, req, finished: list) -> None:
         eos = req.eos_token_id
         if eos is not None and req.generated[-1] == int(eos):
@@ -2716,8 +2832,7 @@ class LLMEngine:
         tr = self.tracer
         if tr is not None:
             t = tr.now()
-        self.blocks.free(req.rid)
-        self._spec_pages.pop(req.rid, None)
+        self._give_back(req.rid, "free")
         self._running.remove(req)
         self._release_slot(req)
         out = RequestOutput(rid=req.rid, prompt=list(req.prompt),
@@ -3026,7 +3141,10 @@ class LLMEngine:
         causally.  Sampled tokens come back for the logit rows in
         ``lidx``; with a drafter the raw [Lq, V] logits ride along for
         host-side draft acceptance; a model with expert layers also
-        returns what they counted.
+        returns what they counted.  A token the host does not have yet
+        (the sample of the launch in front, when this one is dispatched
+        ahead of its commit) is taken on the device: ``src`` names its
+        logit row in ``prev``, that launch's sampled tokens.
 
         The forward is ``layer_stack.forward`` for every model and page
         type (inference/layer_stack.py): the dense decoder is one
@@ -3053,13 +3171,19 @@ class LLMEngine:
             # [B] i32 valid KV per row AFTER this launch's writes; bt
             # [B+1, nblk] i32 (row B: the null row pads resolve to);
             # lidx [Lq] i32 flat index of each logit row; samp the
-            # make_samp pytree, one row per logit row.  Under tp>1 this
-            # traces per shard: the pools and the q/k/v projections
-            # arrive head-sliced, fresh..samp arrive replicated.
+            # make_samp pytree, one row per logit row; prev [Lq] i32 the
+            # ``sampled`` of the launch in front (not donated; zeros
+            # when none is in flight); src [Tq] i32, for each flat
+            # token -1 (``toks`` holds it) or the row of prev that does.
+            # Under tp>1 this traces per shard: the pools and the q/k/v
+            # projections arrive head-sliced, fresh..src arrive
+            # replicated.
             # the jax.named_scope names here and in layer_stack are what
             # a device trace is read by (docs/observability.md)
             pools, host = rest[:n_pools], rest[n_pools:]
-            toks, cu, kvl, bt, lidx, samp = host[-6:]
+            toks, cu, kvl, bt, lidx, samp, prev, src = host[-8:]
+            with jax.named_scope("prev_tokens"):
+                toks = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], toks)
             seg, rel = _pa.ragged_segments(cu, kvl, Tq)
             # with window layers bt is both tables, [2, B+1, nblk]: the
             # global layers', then the window layers' (entries below a
@@ -3087,7 +3211,7 @@ class LLMEngine:
         # donation reuses the pool buffers (pages and scales) in place;
         # fresh is input-only.  _get_ragged_prog drops donation on CPU
         # (that runtime cannot alias and warns per call)
-        return self._wrap_tp(run, 6 + q8), tuple(range(1, 1 + n_pools))
+        return self._wrap_tp(run, 8 + q8), tuple(range(1, 1 + n_pools))
 
     def _consume_fresh(self):
         """Accumulate BlockManager's freshly handed-out pages into the
@@ -3104,7 +3228,8 @@ class LLMEngine:
         (over int8 pages the fresh-page mask after them) and the
         launch's ``host_args``; the pools that come back are kept and
         the outputs before them returned.  It counts the launch (the
-        step id) and, with a tracer installed, brackets the call in one
+        step id; ahead or, by reason, not) and, with a tracer installed,
+        brackets the call in one
         ``engine.launch`` annotation carrying that id, so the profiler's
         own trace holds a host event a step that joins a device
         program's execution to the Tracer's ``engine.device_launch``.
@@ -3115,6 +3240,11 @@ class LLMEngine:
             args += (self._consume_fresh(),)
         args += tuple(host_args)
         self.launches += 1
+        if self._ahead_of is not None:
+            self.launches_ahead += 1
+        else:
+            self.ahead_fallbacks[self._not_ahead] = \
+                self.ahead_fallbacks.get(self._not_ahead, 0) + 1
         note = _NO_ANNOTATION if self.tracer is None else \
             jax.profiler.TraceAnnotation("engine.launch",
                                          step=self.launches,
@@ -3124,8 +3254,38 @@ class LLMEngine:
         self._set_pools(out[-len(pools):])
         return out[:-len(pools)]
 
+    def _ahead_args(self) -> dict:
+        """What ``engine.device_launch`` says of the pipeline: whether
+        this launch was dispatched before the one in front of it was
+        committed, and the reason where not."""
+        if self._ahead_of is not None:
+            return {"ahead": True}
+        return {"ahead": False, "reason": self._not_ahead}
+
+    def _packed(self, tr, t_pack: int, t_rows: int, **what) -> None:
+        """Ends the ``engine.schedule`` span of the launch being
+        prepared where its host inputs stand packed: choosing the rows
+        and laying them out is one span, with what was packed (``rows``,
+        ``tokens``, ``bucket``) and how long the flat tokens
+        (``pack_ns``, from ``t_pack``) and the table rows (``table_ns``,
+        from ``t_rows``) took of it."""
+        if self._sched_open is None:      # a tracer installed mid-turn
+            return
+        t, args = self._sched_open
+        self._sched_open = None
+        tr.complete("engine.schedule", t, track=self._trace_track,
+                    args={**args, **what, "pack_ns": t_rows - t_pack,
+                          "table_ns": tr.now() - t_rows})
+
     def _launch_ragged(self, Tq, toks, cu, kvl, bt, lidx, samp,
-                       real_tokens):
+                       real_tokens, src=None):
+        """One launch of the step program at bucket ``Tq``.  ``src``:
+        the rows of the in-flight launch's ``sampled`` that ``toks``
+        takes on the device (None: every token is staged in ``toks``)."""
+        ahead = self._ahead_of
+        prev = self._no_prev if ahead is None else ahead.sampled
+        if src is None:
+            src = np.full((Tq,), -1, np.int32)
         self.pad_stats["real"] += int(real_tokens)
         self.pad_stats["padded"] += int(Tq)
         # counted once a launch; ``engine.device_launch`` carries the same
@@ -3134,8 +3294,9 @@ class LLMEngine:
         self.pad_stats["kv_pages_window"] += pages.get("kv_pages_window", 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
-        out = self._call_program(self._get_ragged_prog(Tq),
-                                 (toks, cu, kvl, bt, lidx, samp), Tq)
+        out = self._call_program(
+            self._get_ragged_prog(Tq),
+            (toks, cu, kvl, bt, lidx, samp, prev, src), Tq)
         sampled, fin = out[0], out[1]
         # what the step's expert layers counted rides to the completion
         # half unmaterialized, like the tokens
@@ -3307,6 +3468,12 @@ class LLMEngine:
             # skips per-step key derivation entirely
             samp["keys"][s] = self._req_key(req)
 
+    def _token_src(self, req) -> int:
+        """Where the token req feeds into this launch is: -1 for the
+        host (``req.generated[-1]``), or, when the launch in flight
+        samples it, that launch's logit row for req."""
+        return self._ahead_of.slot_of[req.rid] if req.inflight else -1
+
     def _run_ragged(self, chunks: list, spec: list, batch: list):
         """Pack this step's whole mix as ONE ragged launch.
 
@@ -3325,14 +3492,18 @@ class LLMEngine:
         if not chunks and not spec:
             return self._run_ragged_decode(batch, Tq)
 
-        rows = [(req, req.tokens[req.cached:req.cached + n], "c")
+        # a decode row's token is the last one generated: on the host,
+        # or (dispatch ahead) still on the device, where ``src`` finds it
+        rows = [(req, req.tokens[self._pos(req):self._pos(req) + n], "c")
                 for req, n in chunks]
         rows += [(req, [req.generated[-1]] + list(d), "s")
                  for req, d, _ in spec]
-        rows += [(req, [req.generated[-1]], "d") for req in batch]
+        rows += [(req, [0 if req.inflight else req.generated[-1]], "d")
+                 for req in batch]
 
         B = self.max_num_seqs
         toks = np.zeros((Tq,), np.int32)
+        src = np.full((Tq,), -1, np.int32)
         cu = np.zeros((B + 1,), np.int32)
         kvl = np.zeros((B,), np.int32)
         bt = np.full(self._bt_shape, NULL_BLOCK, np.int32)
@@ -3350,7 +3521,9 @@ class LLMEngine:
             n = len(window)
             toks[off:off + n] = window
             cu[i + 1] = off + n
-            kvl[i] = req.cached + n
+            kvl[i] = self._pos(req) + n
+            if kind == "d" and req.inflight:
+                src[off] = self._token_src(req)
             if kind == "s":
                 # every window position is scored; acceptance is
                 # sequential on host, so the device-sampled rows for
@@ -3366,17 +3539,16 @@ class LLMEngine:
             off += n
         cu[len(rows) + 1:] = off
         if tr is not None:
-            tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"step": sid, "rows": len(rows),
-                              "tokens": total, "bucket": int(Tq)})
-            t = tr.now()
+            t_bt = tr.now()
         for i, (req, w, _k) in enumerate(rows):
-            self._advance_window(req, req.cached, req.cached + len(w))
+            # (what the window gives back the launch in flight has read
+            # by the time a later launch writes it: one device queue)
+            self._advance_window(req, self._pos(req),
+                                 self._pos(req) + len(w))
             bt[..., i, :] = self._table_row(req)
         if tr is not None:
-            tr.complete("engine.block_table_stage", t,
-                        track=self._trace_track,
-                        args={"step": sid, "rows": len(rows)})
+            self._packed(tr, t, t_bt, rows=len(rows), tokens=total,
+                         bucket=int(Tq))
 
         # padding a four-program step would have cost: a token-bucketed
         # chunk launch, plus the full-width verify launch when anything
@@ -3398,12 +3570,12 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
         sampled, logits, fin = self._launch_ragged(Tq, toks, cu, kvl, bt,
-                                                   lidx, samp, total)
+                                                   lidx, samp, total, src)
         if tr is not None:
             # logit rows whose result is used: a chunk that ends its
             # prompt, every verify position, every decode row
             logit_rows = sum(1 for r, n in chunks
-                             if r.cached + n == len(r.tokens)) \
+                             if self._pos(r) + n == len(r.tokens)) \
                 + sum(n for _, n in spec_slices) + len(batch)
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
@@ -3412,7 +3584,8 @@ class LLMEngine:
                               "chunks": len(chunks), "decode": len(batch),
                               "logit_rows": logit_rows,
                               **self._launch_pages,
-                              "sample_chain": _sample_chain(samp)})
+                              "sample_chain": _sample_chain(samp),
+                              **self._ahead_args()})
         # NO materialization here: sampled/logits/fin return as async
         # device arrays; _complete blocks on them (the dispatch path
         # must never force a host sync on step-program outputs)
@@ -3433,18 +3606,13 @@ class LLMEngine:
 
         With overlap on, launches ALTERNATE between the two buffer sets
         (the previous launch may still be in flight and CPU PJRT can
-        alias its input arrays) and a valid ``_prestage`` pack for this
-        buffer+layout shrinks the incremental work to patching the
-        token-id column and the penalty masks."""
+        alias its input arrays)."""
         n = len(batch)
         bi = (1 - self._d_cur) if self.overlap else 0
         buf = self._dbufs[bi]
         samp = buf.samp
         layout = tuple(r.rid for r in batch)
-        pre = self._prestaged == (bi, layout)
-        self._prestaged = None              # single-use
         if layout != buf.layout:
-            pre = False
             buf.layout = layout
             buf.bt[:] = NULL_BLOCK
             buf.kvl[:] = 0
@@ -3465,45 +3633,37 @@ class LLMEngine:
         if tr is not None:
             sid = self.launches + 1
             t = tr.now()
-        if pre:
-            # prestage already wrote kvl and the sampling keys; only
-            # the column that depends on the completed step's SAMPLED
-            # token needs patching
-            for s, req in enumerate(batch):
-                buf.toks[s] = req.generated[-1]
-                if req.seen is not None:
-                    np.copyto(samp["seen"][s], req.seen)
-        else:
-            for s, req in enumerate(batch):
-                buf.toks[s] = req.generated[-1]
-                buf.kvl[s] = req.cached + 1
-                if req.seen is not None:
-                    np.copyto(samp["seen"][s], req.seen)
-                if req.temperature > 0.0:
-                    samp["keys"][s] = self._req_key(req)
-        if tr is not None:
-            tr.complete("engine.pack", t, track=self._trace_track,
-                        args={"step": sid, "rows": n, "tokens": n,
-                              "bucket": int(Tq), "fast_path": True,
-                              "prestaged": pre})
-            t = tr.now()
+        buf.src[:] = -1
         for s, req in enumerate(batch):
-            # (a no-op where the prestage already moved the window)
+            if req.inflight:
+                # the launch in flight samples it: taken on the device
+                buf.toks[s] = 0
+                buf.src[s] = self._token_src(req)
+            else:
+                buf.toks[s] = req.generated[-1]
+            buf.kvl[s] = self._pos(req) + 1
+            if req.seen is not None:
+                np.copyto(samp["seen"][s], req.seen)
+            if req.temperature > 0.0:
+                samp["keys"][s] = self._req_key(req)
+        if tr is not None:
+            t_bt = tr.now()
+        for s, req in enumerate(batch):
             self._advance_window(req, int(buf.kvl[s]) - 1, int(buf.kvl[s]))
             ver = self.blocks.table_version(req.rid)
             if buf.bt_ver.get(req.rid) != ver:
                 buf.bt[..., s, :] = self._table_row(req)
                 buf.bt_ver[req.rid] = ver
         if tr is not None:
-            tr.complete("engine.block_table_stage", t,
-                        track=self._trace_track,
-                        args={"step": sid, "rows": n})
+            self._packed(tr, t, t_bt, rows=n, tokens=n, bucket=int(Tq),
+                         fast_path=True)
         self.pad_stats["legacy_padded"] += self.max_num_seqs
         if tr is not None:
             t = tr.now()
         sampled, _, fin = self._launch_ragged(Tq, buf.toks, buf.cu,
                                               buf.kvl, buf.bt,
-                                              self._d_lidx, samp, n)
+                                              self._d_lidx, samp, n,
+                                              buf.src)
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
@@ -3511,7 +3671,8 @@ class LLMEngine:
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n,
                               **self._launch_pages,
-                              "sample_chain": _sample_chain(samp)})
+                              "sample_chain": _sample_chain(samp),
+                              **self._ahead_args()})
         self._d_cur = bi
         return sampled, None, fin, [], [], list(range(n))
 
@@ -3532,13 +3693,12 @@ class LLMEngine:
         self.stats.record_fault("nan")
         return ok
 
-    def _req_key(self, req, ahead: int = 0):
+    def _req_key(self, req):
         # key for token i of request r depends only on (seed, i): sampling
-        # is reproducible across scheduling orders and preemptions.
-        # ahead=1 derives the NEXT position's key (the prestage path:
-        # len(generated) will have advanced by one at dispatch time)
+        # is reproducible across scheduling orders and preemptions (and
+        # i counts the token the launch in flight samples: ``_ngen``)
         key = jax.random.fold_in(jax.random.PRNGKey(req.seed),
-                                 len(req.generated) + ahead)
+                                 self._ngen(req))
         return np.asarray(key, np.uint32)
 
 

@@ -22,7 +22,7 @@ is therefore three scalars and one refinement table:
                        ``dispatch_s + block_s`` (host packing plus the
                        residual completion block), NOT the launch-to-
                        launch cadence — async overlap hides device time
-                       under prestage, and commit/retire fall outside
+                       under the next dispatch, and commit/retire fall outside
                        the stamped duration.  Simulated ITL samples are
                        step cost x active_frac so simulated percentiles
                        land on the same scale ServingStats reports;
@@ -30,10 +30,10 @@ is therefore three scalars and one refinement table:
                        (cadence is what throughput and TTFT feel)
 
 Calibration is ``tools/perf/step_timeline.py --fit``: it joins each
-``engine.step`` span with its ``engine.pack`` args (tokens, rows) from
-a recorded trace, fits the line by least squares, tabulates pure-decode
-medians (tokens == rows), and measures host share from the host-phase
-spans.  The result is ``sim_calibration.json`` — ``from_json`` here is
+``engine.step`` span with the tokens and rows its ``engine.schedule``
+packed from a recorded trace, fits the line by least squares,
+tabulates pure-decode medians (tokens == rows), and measures host share
+from the host-phase spans.  The result is ``sim_calibration.json`` — ``from_json`` here is
 its exact mirror.  ``default()`` ships coarse CPU-backend numbers so
 the simulator runs uncalibrated (policy COMPARISONS are still
 meaningful; absolute latencies are not).

@@ -446,15 +446,17 @@ def test_chaos_acceptance_recovery_byte_identical(model):
     assert snap["uptime_seconds"] > 0.0
 
 
-def test_inflight_fault_recovery_discards_prestaged_pack(model):
+def test_inflight_fault_recovery_discards_the_launch_in_flight(model):
     """Crash and hang injected WHILE a step is in flight (the async
     pipeline's completion seam, between a launch and its
     materialization): the runner's journal replay must recover exactly
-    as it does for synchronous faults — the in-flight launch and the
-    speculatively pre-staged N+1 pack simply die with the old engine,
-    never having touched the journal.  Every output is byte-identical
-    to the fault-free baseline (these faults poison nothing), zero
-    pages leak (including speculatively reserved ones), and the restart
+    as it does for synchronous faults — the in-flight launch simply
+    dies with the old engine, never having touched the journal (an
+    armed plan keeps the pipeline one deep: nothing is dispatched ahead
+    of a commit until the last fault has fired, and the rebuilt engine
+    goes ahead from there).  Every output is byte-identical to the
+    fault-free baseline (these faults poison nothing), zero pages leak
+    (including those a dispatch ahead reserved), and the restart
     counter advances once per fault."""
     reqs = _requests(24, seed=7)
     base_eng, base = _run_direct(model, reqs)
@@ -498,17 +500,19 @@ def test_inflight_fault_recovery_discards_prestaged_pack(model):
 
     # no poisoned rows here: EVERY stream is byte-identical to the
     # fault-free baseline, token-by-token view included — proof the
-    # discarded in-flight step and its pre-staged successor never
-    # leaked a token into the journal
+    # discarded in-flight step never leaked a token into the journal
     for i, (toks, out) in enumerate(streams):
         assert toks == list(out.generated)
         assert out.generated == base[i].generated, f"request {i} diverged"
         assert out.finish_reason == base[i].finish_reason
 
-    # zero leaked pages, including speculatively reserved prestage pages
+    # zero leaked pages, including those reserved ahead of a commit
     assert fin.blocks.num_used == 0
-    assert fin._spec_pages == {}
+    assert fin._inflight is None and fin._queued is None
     fin.blocks.check_invariants()
+    # the armed plan held the first engine to the synchronous order
+    assert eng.launches_ahead == 0
+    assert eng.ahead_fallbacks.get("fault_plan", 0) >= 1
     assert fin.compile_counts == budget
 
 
@@ -561,7 +565,7 @@ def test_inflight_fault_during_decode_window_replays_byte_identical(model):
         assert out.finish_reason == base[i].finish_reason
 
     assert fin.blocks.num_used == 0
-    assert fin._spec_pages == {}
+    assert fin._inflight is None and fin._queued is None
     fin.blocks.check_invariants()
     # loose on purpose: whether the rebuilt engine's stream reached a
     # window-eligible state again depends on where the faults landed —

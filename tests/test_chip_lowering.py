@@ -421,12 +421,47 @@ def test_the_sampled_chain_compiles_into_one_branch(
     assert all("/sample/cond/" in n for n in paths)
 
 
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
+def test_the_token_handover_is_a_few_small_operations(
+        target, request, no_persistent_cache):
+    """What a launch dispatched ahead of the commit in front of it adds
+    to the step program (PR 34): ``toks = where(src >= 0, prev[src],
+    toks)`` under scope ``prev_tokens``, on [Tq] and [Lq] int32 and
+    nothing larger (the TPU pads the index vector to one tile of 1024
+    words), before the embedding; ``prev`` is not donated (the host
+    still reads it at that launch's commit)."""
+    from paddle_tpu.inference.serving import _instruction_scopes
+    Tq = 16
+    eng = _tiny_engine("llama_dense")
+    structs = eng._ragged_arg_structs(Tq, placed=target == "cpu")
+    assert [s.shape for s in structs[-2:]] == [(eng._Lq,), (Tq,)]
+    if target == "v5e":
+        chip = request.getfixturevalue("one_chip")
+        structs = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+            structs)
+    fn, donate = eng._make_ragged_fn(Tq)
+    assert tuple(donate) == (1, 2)                       # the pools alone
+    text = eng._get_ragged_prog(Tq).lower(*structs).compile().as_text()
+    scopes = _instruction_scopes(text)
+    mine = {n for n, v in scopes.items() if "/prev_tokens/" in v["op_name"]}
+    assert mine
+    sizes = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(1) in mine:
+            sizes.append(math.prod(int(n or 1) for n in
+                                   m.group(2).split(",") if n))
+    assert sizes and max(sizes) <= max(Tq, eng._Lq, 1024)
+
+
 # ---------------------------------------------------------------------------
 # the dense step program reads and writes the K/V pools in place (PR 31)
 # ---------------------------------------------------------------------------
 
 _RESULT = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
                      r"([\w\-]+)\(")
+
 
 
 def _dense_step_text(chip, monkeypatch, model, tq, quant):
@@ -477,7 +512,8 @@ def _dense_step_text(chip, monkeypatch, model, tq, quant):
                                   samp_structs(eng._Lq, V))
     args = (params,) + pools + (
         sds((tq,), i32), sds((ROWS + 1,), i32), sds((ROWS,), i32),
-        sds((ROWS + 1, NBLK), i32), sds((eng._Lq,), i32), samp)
+        sds((ROWS + 1, NBLK), i32), sds((eng._Lq,), i32), samp,
+        sds((eng._Lq,), i32), sds((tq,), i32))           # prev, src
     fn, donate = eng._make_ragged_fn(tq)
     return jax.jit(fn, donate_argnums=donate).lower(
         *args).compile().as_text(), pool

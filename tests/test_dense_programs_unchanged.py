@@ -17,7 +17,14 @@ index), so the assertion is now equality of RESULTS on seeded inputs:
 sampled tokens, finiteness flags, logits where returned and every pool,
 bit for bit, after the step (the same values land in the same pages and
 no other page is touched).  The copy-on-write program and the two
-frozen spellings of the float step are still compared as jaxprs."""
+frozen spellings of the float step are still compared as jaxprs.
+
+PR 34 gives the live step two more arguments, ``prev`` and ``src`` (a
+token the host does not have yet is taken on the device from the
+sampled tokens of the launch in front).  The frozen programs know
+neither: the live program is handed part of the same tokens through
+``prev`` (``_with_prev``) and must give what the frozen one gives on
+the tokens spelled out."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -390,7 +397,7 @@ def _step_args(eng, Tq, seed=0):
     queries and padding."""
     rng = np.random.default_rng(seed)
     B, V = eng.max_num_seqs, eng.config.vocab_size
-    structs = eng._ragged_arg_structs(Tq)
+    structs = eng._ragged_arg_structs(Tq)[:-2]       # less prev, src
     qlen, kvl = ([13, 1, 10, 3], [20, 9, 10, 17]) if Tq >= 32 else \
         ([1, 1, 0, 1], [5, 16, 0, 30])
     cu = np.concatenate([[0], np.cumsum(qlen)]).astype(np.int32)
@@ -418,12 +425,34 @@ def _window_args(eng, seed=0):
         _samp(rng, B, V))
 
 
-def _same_results(new, old, args):
-    """Both programs on the same arguments: every output (tokens,
-    finiteness, logits where returned, then each pool) bit for bit."""
+def _with_prev(args, seed=1):
+    """The live step's arguments for the launch ``args`` spells out: the
+    same tokens, but some of them zeroed in ``toks`` and handed over in
+    ``prev`` with ``src`` naming the row (as a launch dispatched ahead
+    of the commit in front of it gets that launch's samples); the rows
+    of ``prev`` nothing names hold other tokens."""
+    rng = np.random.default_rng(seed)
+    toks, lidx = args[-6], args[-2]
+    Tq, Lq = toks.shape[0], lidx.shape[0]
+    moved = rng.permutation(Tq)[:min(Lq, Tq) // 2 + 1]
+    rows = rng.permutation(Lq)[:len(moved)]
+    prev = rng.integers(1, 90, Lq).astype(np.int32)
+    src = np.full((Tq,), -1, np.int32)
+    staged = toks.copy()
+    prev[rows], src[moved], staged[moved] = toks[moved], rows, 0
+    assert np.any(staged != toks)
+    return args[:-6] + (staged,) + args[-5:] + (prev, src)
+
+
+def _same_results(new, old, args, live_args=None):
+    """Both programs on the same launch (``live_args``: the live
+    program's spelling of it, where it takes more than the frozen one):
+    every output (tokens, finiteness, logits where returned, then each
+    pool) bit for bit."""
     (new, donate), (old, old_donate) = new, old
     assert tuple(donate) == tuple(old_donate)
-    got, want = jax.jit(new)(*args), jax.jit(old)(*args)
+    got = jax.jit(new)(*(live_args or args))
+    want = jax.jit(old)(*args)
     assert len(got) == len(want)
     for g, w in zip(got, want):
         assert g.shape == w.shape and g.dtype == w.dtype
@@ -444,8 +473,9 @@ _STEP_IDS = ["plain", "with_logits", "tp2"]
 @pytest.mark.parametrize("Tq", [4, 32])
 def test_dense_step_program_is_the_parents(model, kw, Tq):
     eng = _engine(model, **kw)
+    args = _step_args(eng, Tq)
     assert _same_results(eng._make_ragged_fn(Tq), _parent_ragged_fn(eng, Tq),
-                         _step_args(eng, Tq)) == (1, 2)
+                         args, _with_prev(args)) == (1, 2)
 
 
 def _same_program(new, old, args):
@@ -459,8 +489,9 @@ def _same_program(new, old, args):
 @pytest.mark.parametrize("Tq", [4, 32])
 def test_int8_page_step_program_is_the_parents(model, kw, Tq):
     eng = _engine(model, kv_dtype="int8", **kw)
+    args = _step_args(eng, Tq)
     assert _same_results(eng._make_ragged_fn(Tq), _frozen_ragged_fn(eng, Tq),
-                         _step_args(eng, Tq)) == (1, 2, 3, 4)
+                         args, _with_prev(args)) == (1, 2, 3, 4)
 
 
 @pytest.mark.parametrize("tp", [1, 2])
@@ -481,8 +512,9 @@ def test_programs_over_int8_weights_and_pages_are_the_parents(model):
                              "serving.ragged_step_q8_w8"]
     step, win = (specs["serving.ragged_step_q8_w8"],
                  specs["serving.decode_window_q8_w8"])
+    args = _step_args(eng, 16)
     _same_results((step.fn, step.donate_argnums),
-                  _frozen_ragged_fn(eng, 16), _step_args(eng, 16))
+                  _frozen_ragged_fn(eng, 16), args, _with_prev(args))
     _same_results((win.fn, win.donate_argnums), _frozen_window_fn(eng),
                   _window_args(eng))
 
@@ -492,7 +524,7 @@ def test_frozen_block_is_the_frozen_float_step(model):
     froze above: one composition, held to the older literal text."""
     eng = _engine(model)
     _same_program(_frozen_ragged_fn(eng, 32), _parent_ragged_fn(eng, 32),
-                  eng._ragged_arg_structs(32))
+                  eng._ragged_arg_structs(32)[:-2])
 
 
 def test_dense_programs_of_program_specs_are_the_parents(model):
@@ -500,9 +532,10 @@ def test_dense_programs_of_program_specs_are_the_parents(model):
     specs = {s.name: s for s in eng.program_specs()}
     assert sorted(specs) == ["serving.cow_copy", "serving.ragged_step"]
     step, cow = specs["serving.ragged_step"], specs["serving.cow_copy"]
+    args = _step_args(eng, 16)
     assert _same_results((step.fn, step.donate_argnums),
                          _parent_ragged_fn(eng, 16),
-                         _step_args(eng, 16)) == (1, 2)
+                         args, _with_prev(args)) == (1, 2)
     old_cow, old_donate = _parent_cow_fn(eng)
     assert str(jax.make_jaxpr(cow.fn)(*cow.args)) \
         == str(jax.make_jaxpr(old_cow)(*cow.args))

@@ -192,10 +192,22 @@ def test_tracing_on_off_byte_identical_with_pinned_compiles(model):
     assert tr.unbalanced == 0 and tr.dropped == 0
     names = {e[1] for e in tr.events()}
     for phase in ("engine.step", "engine.admit", "engine.schedule",
-                  "engine.pack", "engine.block_table_stage",
                   "engine.device_launch", "engine.block_on_result",
                   "engine.sample_commit", "engine.retire"):
         assert phase in names, phase
+    # ``engine.schedule`` runs on over the packing of the rows it chose
+    # and says how long the flat tokens and the table rows took of it;
+    # admission shows only where somebody waited
+    launches = [e for e in tr.events() if e[1] == "engine.device_launch"]
+    packed = [e for e in tr.events() if e[1] == "engine.schedule"
+              and "pack_ns" in e[5]]
+    assert [e[5]["step"] for e in packed] == [e[5]["step"] for e in launches]
+    assert all(e[5]["tokens"] == l[5]["tokens"] and e[5]["rows"] == l[5]["rows"]
+               and 0 <= e[5]["pack_ns"] + e[5]["table_ns"] <= e[3]
+               and e[2] + e[3] <= l[2] for e, l in zip(packed, launches))
+    admits = [e for e in tr.events() if e[1] == "engine.admit"]
+    assert 0 < len(admits) < len(launches)
+    assert all(e[5]["admitted"] or e[5]["waiting"] for e in admits)
     # every request opened AND closed its lifecycle pair
     assert sum(1 for e in tr.events() if e[0] == "b") == 16
     assert sum(1 for e in tr.events() if e[0] == "e") == 16
@@ -315,6 +327,39 @@ def test_every_engine_span_and_request_instant_carries_a_known_step(model):
     sched = [s["args"] for s in sp if s["name"] == "engine.schedule"]
     assert all({"evicted", "cow", "cow_ns"} <= set(a) for a in sched)
     assert tr.unbalanced == 0
+
+
+def test_a_launch_leaves_four_engine_spans_for_the_idle_attribution(model):
+    """The benchmark's trace reduction holds every idle gap of the
+    device against every engine span of the run that is not a wrapper
+    (``harness/xplane.py`` ``attribute_gaps``: about 0.1 s of a traced
+    run for each span, PERF.md section 7), so what a launch leaves there
+    is a budget: schedule (the packing with it), device launch, block on
+    result, sample commit; admission and retirement only where there was
+    one."""
+    rng = np.random.RandomState(9)
+    eng, _tr, sp = _traced_run(
+        model, [(rng.randint(0, VOCAB, n).tolist(), 12) for n in (20, 7, 9)],
+        max_prefill_tokens=16, prefill_token_bucket=16)
+    wrappers = ("engine.step", "engine.dispatch", "engine.complete",
+                "engine.device_inflight")
+    per_launch: dict = {}
+    for s in sp:
+        if s["ph"] == "X" and s["name"].startswith("engine.") \
+                and s["name"] not in wrappers:
+            per_launch.setdefault(s["args"]["step"], []).append(s["name"])
+    # the last turns found nothing to launch: a schedule span each
+    assert set(per_launch.pop(eng.launches + 1, ())) <= {"engine.schedule"}
+    assert sorted(per_launch) == list(range(1, eng.launches + 1))
+    always = ["engine.block_on_result", "engine.device_launch",
+              "engine.sample_commit", "engine.schedule"]
+    for names in per_launch.values():
+        assert sorted(n for n in names if n not in (
+            "engine.admit", "engine.retire")) == always, names
+    # three requests: admitted in one turn, retired one by one
+    flat = [n for names in per_launch.values() for n in names]
+    assert flat.count("engine.admit") == 1
+    assert flat.count("engine.retire") == 3
 
 
 def test_prefill_chunk_carries_the_launching_step_under_overlap(model):

@@ -17,8 +17,10 @@ then three derived numbers:
                          — the fraction of the step the device program
                          is NOT the thing being waited on
   device fraction        device_launch + block_on_result over step time
-  overlap opportunity    per step, min(pack + block_table_stage,
-                         device_launch): the host packing work that an
+  overlap opportunity    per step, min(packing, device_launch), packing
+                         being ``pack_ns`` + ``table_ns`` of
+                         ``engine.schedule`` (an older trace's pack +
+                         block_table_stage): the host packing work that an
                          async engine could overlap UNDER the previous
                          step's device span; summed, as a fraction of
                          step time.  This is the number the async-engine
@@ -52,8 +54,9 @@ Two analysis details added for the fleet simulator:
   ``head_clipped_steps``.
 
 * **``--fit OUT.json``** fits the simulator's ``CostModel`` from the
-  trace: each ``engine.step`` span is joined with its ``engine.pack``
-  args (ragged tokens, rows), then total step wall time regresses on
+  trace: each ``engine.step`` span is joined with the ragged tokens and
+  rows its ``engine.schedule`` packed (an older trace's ``engine.pack``
+  args), then total step wall time regresses on
   packed tokens (base + per-token line), pure-decode steps
   (tokens == rows) tabulate a median-by-rows refinement, and the
   host-only share (step minus device phases) calibrates what a decode
@@ -68,10 +71,20 @@ import argparse
 import json
 import sys
 
+# (``engine.pack`` and ``engine.block_table_stage`` are in traces
+# recorded before ``engine.schedule`` ran on over the packing of the rows
+# it chose: since then it carries what they did, ``rows``, ``tokens``,
+# ``pack_ns`` and ``table_ns``)
 _HOST_PHASES = ("engine.admit", "engine.schedule", "engine.pack",
                 "engine.block_table_stage", "engine.sample_commit",
                 "engine.retire")
 _DEVICE_PHASES = ("engine.device_launch", "engine.block_on_result")
+
+
+def _packing_us(ev) -> float:
+    """What an ``engine.schedule`` span spent packing its launch."""
+    a = ev.get("args", {})
+    return (a.get("pack_ns", 0) + a.get("table_ns", 0)) / 1e3
 _PHASE_ORDER = ("engine.admit", "engine.schedule", "engine.pack",
                 "engine.block_table_stage", "engine.device_launch",
                 "engine.block_on_result", "engine.sample_commit",
@@ -80,7 +93,8 @@ _PHASE_ORDER = ("engine.admit", "engine.schedule", "engine.pack",
 # engine.device_inflight brackets whole launch→materialize windows), so
 # counting them as phases would double-charge host time and drive the
 # untracked remainder negative.  They feed the overlap-achieved
-# computation instead.
+# computation instead.  (``engine.prestage`` is what engines before the
+# dispatch-ahead pipeline wrote; a trace without it reads the same.)
 _WRAPPER_SPANS = ("engine.dispatch", "engine.complete", "engine.prestage",
                   "engine.device_inflight")
 
@@ -185,6 +199,8 @@ def analyze(doc, events, tracks):
         pack = sum(ev["dur"] for ev in mine
                    if ev["name"] in ("engine.pack",
                                      "engine.block_table_stage"))
+        pack += sum(_packing_us(ev) for ev in mine
+                    if ev["name"] == "engine.schedule")
         dev = sum(ev["dur"] for ev in mine
                   if ev["name"] == "engine.device_launch")
         overlap_us += min(pack, dev)
@@ -302,7 +318,9 @@ def fit(doc, events, tracks, flight=None, trace_path=None):
         t0, t1 = st["ts"], st["ts"] + st["dur"]
         mine = [ev for ev in by_tid.get(st["tid"], ())
                 if t0 <= ev["ts"] and ev["ts"] + ev["dur"] <= t1 + 1e-6]
-        packs = [ev for ev in mine if ev["name"] == "engine.pack"]
+        packs = [ev for ev in mine if ev["name"] == "engine.pack"
+                 or (ev["name"] == "engine.schedule"
+                     and "pack_ns" in ev.get("args", {}))]
         tokens = sum(int(ev.get("args", {}).get("tokens", 0))
                      for ev in packs)
         rows = sum(int(ev.get("args", {}).get("rows", 0)) for ev in packs)
