@@ -81,6 +81,14 @@ FULL = {
                  "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64},
                 {"H": 28, "Hkv": 4, "D": 128, "bs": 16, "nblk": 8,
                  "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64,
+                 "window": 32},
+                # two head counts of one model over 8 K/V heads: a group
+                # of six, and a group of eight under a window SHORTER
+                # than the 40-token chunk beside it
+                {"H": 48, "Hkv": 8, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 64, "Hkv": 8, "D": 128, "bs": 16, "nblk": 8,
+                 "dtype": "bfloat16", "qlens": (40, 5, 1), "Tq": 64,
                  "window": 32}],
     "start_timeout_s": 300.0, "request_timeout_s": 600.0,
 }
@@ -102,6 +110,11 @@ TINY = {
                 {"H": 7, "Hkv": 1, "D": 16, "bs": 16, "nblk": 4,
                  "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64},
                 {"H": 7, "Hkv": 1, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64,
+                 "window": 32},
+                {"H": 12, "Hkv": 2, "D": 16, "bs": 16, "nblk": 4,
+                 "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64},
+                {"H": 16, "Hkv": 2, "D": 16, "bs": 16, "nblk": 4,
                  "dtype": "float32", "qlens": (40, 5, 1), "Tq": 64,
                  "window": 32}],
     "start_timeout_s": 120.0, "request_timeout_s": 300.0,

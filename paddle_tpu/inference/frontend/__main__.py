@@ -31,6 +31,16 @@ bridge):
                heads over 4 K/V heads of 128, 64 experts of width 768, 6
                a token, window 4096).  Cut depth with --layers (8: two
                periods, 7.9 GB in bfloat16, held once)
+    laguna-sm  a small decoder of full and sliding-window layers, period
+               4, that differ in their query heads (6 and 8 over 2 K/V
+               heads) and rotary (half the head with YaRN frequencies,
+               the whole head plain), a gated attention output, a dense
+               first layer then 16 small experts (4 a token) beside a
+               shared one, window 64.  Serve it with --no-prefix-caching
+    laguna-xs2  Laguna-XS.2's widths (48 and 64 query heads over 8 K/V
+               heads of 128, 256 experts of width 512, 8 a token, window
+               512).  Cut depth with --layers (7: the dense layer and six
+               sparse ones, 11.3 GB in bfloat16, held once)
 
 The process computes on whatever device JAX resolves, and says which on
 its start-up line together with the attention and matmul paths the
@@ -96,6 +106,16 @@ def _model_config(args):
         from paddle_tpu.models.smallthinker import SmallThinkerConfig
         cfg = SmallThinkerConfig(
             max_position_embeddings=args.max_model_len or 16384)
+    elif args.model == "laguna-sm":
+        from paddle_tpu.models.laguna import LagunaConfig
+        cfg = LagunaConfig.tiny(
+            vocab=512, hidden=128, layers=7, full_heads=6, window_heads=8,
+            kv_heads=2, head_dim=32, experts=16, active=4, ffn=64,
+            dense_ffn=256, window=64, seq=args.max_model_len or 1024)
+    elif args.model == "laguna-xs2":
+        from paddle_tpu.models.laguna import LagunaConfig
+        cfg = LagunaConfig(
+            max_position_embeddings=args.max_model_len or 16384)
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
     if args.layers:
@@ -132,6 +152,9 @@ def _build_engine(args, cfg):
     elif getattr(cfg, "architecture", None) == "smallthinker":
         from paddle_tpu.models.smallthinker import SmallThinkerForCausalLM
         model = SmallThinkerForCausalLM(cfg, dtype=args.dtype)
+    elif getattr(cfg, "architecture", None) == "laguna":
+        from paddle_tpu.models.laguna import LagunaForCausalLM
+        model = LagunaForCausalLM(cfg, dtype=args.dtype)
     else:
         model = LlamaForCausalLM(cfg)
         if args.dtype != "float32":
@@ -179,7 +202,8 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--model", default="tiny",
                     choices=["tiny", "llama-sm", "llama-7b", "mla-moe-sm",
                              "sarvam-105b", "smallthinker-sm",
-                             "smallthinker-21b"])
+                             "smallthinker-21b", "laguna-sm",
+                             "laguna-xs2"])
     ap.add_argument("--layers", type=int, default=0,
                     help="depth cut: build this many decoder layers "
                          "(0 = the preset's depth); widths are never cut")

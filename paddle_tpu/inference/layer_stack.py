@@ -20,7 +20,13 @@ attention in the absorbed form over ONE pool, ``[L, num_blocks, bs,
 width]``) and, for a model that mixes them (``models/smallthinker.py``),
 ``gqa_nope`` (``gqa`` without rotary positions: a global layer) and
 ``gqa_window`` (rotary, a query sees the ``c.window`` positions up to
-its own).  Such a model has TWO pairs of pools and two block tables: the
+its own), and ``gqa_gated`` / ``gqa_gated_window`` (those two with the
+attention output times ``sigmoid(h W_g)`` before ``W_o``:
+``models/laguna.py``).  A grouped-query kind reads ITS number of query
+heads and ITS rotary from ``c.attn[kind]``: the kinds of one model may
+differ in both (48 heads half-rotated with YaRN frequencies on the
+global layers, 64 heads wholly rotated on the window layers).  A model
+with window layers has TWO pairs of pools and two block tables: the
 global layers' ``[Lg, num_blocks, ...]`` under ``c.bt`` and the window
 layers' ``[Lw, Nw, ...]`` under ``c.btw``, where a sequence's pages
 below its window have gone back to the pool.  One contract for all,
@@ -35,8 +41,8 @@ whose router reads ``h``, the attention block's input, not ``h2``).
 The ``jax.named_scope`` names below are what a device trace is read by
 (docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
 ``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn``
-(``attn_window`` on a window layer's), ``o_proj``, ``mlp``, and for expert
-layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
+(``attn_window`` on a window layer's), ``attn_gate``, ``o_proj``, ``mlp``,
+and for expert layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
 ``shared_expert``.
 """
 from __future__ import annotations
@@ -50,7 +56,7 @@ from jax import lax
 
 from ..models import mla_moe as _mm
 from ..models import smallthinker as _st
-from ..models.llama import _rms_weight, _rope_positions
+from ..models.llama import _rms_weight
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 
@@ -84,7 +90,9 @@ def step_context(**kw) -> SimpleNamespace:
     """What every layer of one step program shares: the row layout
     (``Tq``, ``seg``, ``rel``, ``bt``, ``cu``, ``kvl``, ``bs``), the
     products (``mm``, and ``embed`` and ``head_logits`` for ``forward``),
-    the model's sizes, whether the kernel runs (``use_pallas``) and,
+    the model's sizes (for the grouped-query kinds ``attn``: {kind: its
+    query heads ``nh`` and its rotary ``rope(x, pos)``, None for a kind
+    without positions}), whether the kernel runs (``use_pallas``) and,
     over int8 pages, ``fresh`` ([num_blocks] bool: the pages whose scale
     rows a layer's commit resets; None where the caller already did)."""
     return SimpleNamespace(**kw)
@@ -206,7 +214,14 @@ def _attend_int8(q, pools, layer, c):
     return att.astype(q.dtype)
 
 
-def _gqa(x, h, p, pools, layer, c, rope=True, window=False):
+# the grouped-query kinds: (a window layer, a gated output)
+_GQA = {"gqa": (False, False), "gqa_nope": (False, False),
+        "gqa_window": (True, False), "gqa_gated": (False, True),
+        "gqa_gated_window": (True, True)}
+WINDOW_KINDS = tuple(k for k, (window, _) in _GQA.items() if window)
+
+
+def _gqa(x, h, p, pools, layer, c, kind="gqa"):
     """Grouped-query attention over this layer's pages of the pools of
     all layers.  What differs between page types is a pair, picked by
     what the layer is handed: commit the step's K/V rows into the pools
@@ -215,10 +230,13 @@ def _gqa(x, h, p, pools, layer, c, rope=True, window=False):
     A model of global and window layers (``c.window`` set) hands every
     layer both pairs of pools, (K, V of the global layers, K, V of the
     window layers): a layer writes and reads its own pair under its own
-    table and passes the other through.  ``rope``: whether the kind
-    rotates q and k; ``window``: whether it is a window layer, whose
-    attention runs in scope ``attn_window`` under a kernel name of its
-    own."""
+    table and passes the other through.  ``kind`` says whether it is a
+    window layer, whose attention runs in scope ``attn_window`` under a
+    kernel name of its own, and whether its output is gated; how many
+    query heads the kind has and how it rotates q and k (not at all: a
+    kind without positions) is the model's to say, ``c.attn[kind]``."""
+    window, gated = _GQA[kind]
+    nh, rope = c.attn[kind].nh, c.attn[kind].rope
     others = ()
     bt, scope, over = c.bt, "attn", {}
     if getattr(c, "window", None) is not None:
@@ -230,15 +248,15 @@ def _gqa(x, h, p, pools, layer, c, rope=True, window=False):
             pools, others = pools[:2], pools[2:]
     commit, attend = (_commit_int8, _attend_int8) \
         if pools[0].dtype == jnp.int8 else (_commit_float, _attend_float)
-    Tq, nh, kvh, d, tp, mm = c.Tq, c.nh, c.kvh, c.d, c.tp, c.mm
+    Tq, kvh, d, tp, mm = c.Tq, c.kvh, c.d, c.tp, c.mm
     with jax.named_scope("qkv"):
         q = mm(h, p, "wq").reshape(Tq, nh, d)
         k = mm(h, p, "wk").reshape(Tq, kvh, d)
         v = mm(h, p, "wv").reshape(Tq, kvh, d)
-    if rope:
+    if rope is not None:
         with jax.named_scope("rope"):
-            q = _rope_positions(q, c.rel, c.theta)
-            k = _rope_positions(k, c.rel, c.theta)
+            q = rope(q, c.rel)
+            k = rope(k, c.rel)
     with jax.named_scope("kv_write"):
         blk = bt[c.seg, c.rel // c.bs]                    # [Tq]
         slot = c.rel % c.bs
@@ -250,8 +268,13 @@ def _gqa(x, h, p, pools, layer, c, rope=True, window=False):
             # — exactly the tp=1 head layout, so the replicated wo
             # matmul is byte-identical
             att = lax.all_gather(att, "tp", axis=1, tiled=True)
+    att = att.reshape(Tq, tp * nh * d)
+    if gated:
+        with jax.named_scope("attn_gate"):
+            g = jax.nn.sigmoid(mm(h, p, "wg").astype(jnp.float32))
+            att = (att.astype(jnp.float32) * g).astype(att.dtype)
     with jax.named_scope("o_proj"):
-        x = x + mm(att.reshape(Tq, tp * nh * d), p, "wo")
+        x = x + mm(att, p, "wo")
     if window:
         return x, tuple(others) + tuple(pools)
     return x, tuple(pools) + tuple(others)
@@ -313,9 +336,8 @@ def _moe_reglu(x, h, h2, p, c):
         return x + out, counts
 
 
-ATTENTION = {"gqa": _gqa, "mla": _latent,
-             "gqa_window": functools.partial(_gqa, window=True),
-             "gqa_nope": functools.partial(_gqa, rope=False)}
+ATTENTION = {"mla": _latent,
+             **{kind: functools.partial(_gqa, kind=kind) for kind in _GQA}}
 FFN = {"swiglu": _swiglu, "moe": _moe, "moe_reglu": _moe_reglu}
 
 

@@ -65,10 +65,12 @@ for that request.
 from __future__ import annotations
 
 import contextlib
+import functools
 import re
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +78,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..models.llama import _rope_positions
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
@@ -95,7 +98,8 @@ __all__ = ["LLMEngine", "Request", "RequestOutput"]
 # ``layer_stack.ATTENTION``, spelled out: graft-lint reads this literal).
 # An engine's attention-bearing program kinds are bounded by it, whatever
 # its requests do (rule ``attention-program-budget``).
-ATTENTION_KINDS = ("gqa", "mla", "gqa_window", "gqa_nope")
+ATTENTION_KINDS = ("mla", "gqa", "gqa_nope", "gqa_window", "gqa_gated",
+                   "gqa_gated_window")
 
 
 @dataclass
@@ -512,7 +516,7 @@ class LLMEngine:
             else [("gqa", "swiglu")] * cfg.num_hidden_layers
         self._latent = any(a == "mla" for a, _ in self._layer_kinds)
         # window layers keep pools and a block table of their own
-        self._windowed = any(a == "gqa_window"
+        self._windowed = any(a in _ls.WINDOW_KINDS
                              for a, _ in self._layer_kinds)
         # the dense decoder alone runs as one scan over stacked weights
         self._scanned = all(k == ("gqa", "swiglu")
@@ -627,6 +631,7 @@ class LLMEngine:
         self._staged_hashes: set = set()
 
         self._nh = cfg.num_attention_heads
+        self._attn = self._attention_by_kind()
         L = cfg.num_hidden_layers
         dt = self._act_dtype
         if self._latent:
@@ -648,7 +653,7 @@ class LLMEngine:
                 # long as their sequence ([Lg, num_blocks, ...], the
                 # block table's), the window layers' come and go
                 # ([Lw, Nw, ...], the window table's)
-                is_w = [a == "gqa_window" for a, _ in self._layer_kinds]
+                is_w = [a in _ls.WINDOW_KINDS for a, _ in self._layer_kinds]
                 self._pool_index = [sum(is_w[:i]) if w
                                     else i - sum(is_w[:i])
                                     for i, w in enumerate(is_w)]
@@ -831,6 +836,9 @@ class LLMEngine:
         self.moe_counts = {"moe_pairs_here": 0, "moe_pairs_all": 0,
                            "moe_experts_touched": 0, "moe_load_max": 0}
         self._launch_counts = None
+        self._experts_held = sum(
+            cfg.experts_held for _, f in self._layer_kinds
+            if f in ("moe", "moe_reglu"))
         self._launch_pages: dict = {}     # the latest launch's page counts
         self.stats = ServingStats()
         self.stats.set_decode_window(self.decode_window)
@@ -942,6 +950,23 @@ class LLMEngine:
         return {"layers": out_layers, "embed_q": eq, "embed_s": es,
                 "norm_f": params["norm_f"], "head_q": hq, "head_s": hs}
 
+    def _attention_by_kind(self) -> dict:
+        """{grouped-query attention kind: its query heads ``nh`` (a
+        shard's) and its rotary ``rope(x, pos)``}, what a step program
+        hands each kind: the configuration's own where its kinds differ
+        in them (``attention_by_kind``: ``models/laguna.py``), else one
+        head count and one theta for every kind, and no rotary on a kind
+        without positions."""
+        cfg = self.config
+        if self._latent:
+            return {}
+        if hasattr(cfg, "attention_by_kind"):
+            return cfg.attention_by_kind()
+        rope = functools.partial(_rope_positions, theta=cfg.rope_theta)
+        return {a: SimpleNamespace(nh=self._nh // self.tp,
+                                   rope=None if a == "gqa_nope" else rope)
+                for a, _ in self._layer_kinds}
+
     def _resolve_attention_path(self) -> str:
         """Which attention the step programs run, decided once from the
         platform and the kernel's static claim.  The interpreted kernel
@@ -959,12 +984,15 @@ class LLMEngine:
                 launch=(self.max_num_seqs + 1, self.nblk,
                         self.blocks.num_blocks))
             return "pallas" if why is None else f"xla-reference ({why})"
-        why = _pa.ineligible(self._nh // self.tp, self._kvh // self.tp,
-                             self._hd, self.block_size,
-                             jnp.int8 if self.kv_dtype == "int8"
-                             else self._act_dtype,
-                             launch=(self.max_num_seqs + 1, self.nblk,
-                                     self.blocks.num_blocks))
+        # every kind's head count has to be one the kernel claims
+        whys = (_pa.ineligible(a.nh, self._kvh // self.tp,
+                               self._hd, self.block_size,
+                               jnp.int8 if self.kv_dtype == "int8"
+                               else self._act_dtype,
+                               launch=(self.max_num_seqs + 1, self.nblk,
+                                       self.blocks.num_blocks))
+                for a in self._attn.values())
+        why = next((w for w in whys if w is not None), None)
         return "pallas" if why is None else f"xla-reference ({why})"
 
     def _resolve_matmul_path(self) -> str:
@@ -1430,6 +1458,9 @@ class LLMEngine:
             # layers and steps), and the most tokens one held expert
             # got in one layer of one step
             out.update(self.moe_counts)
+            # (layer, expert) pairs held here: what a launch's
+            # moe_experts_touched is a share of
+            out["moe_experts_held"] = self._experts_held
         out["paths"] = self.paths()
         out["tuning_cache"] = {
             "path": self._tuning_report["path"],
@@ -3122,8 +3153,8 @@ class LLMEngine:
             shared.update(cfg=cfg, inv_freq=yarn_inv_freq(cfg),
                           sm_scale=softmax_scale(cfg))
         else:
-            shared.update(nh=self._nh // self.tp, kvh=self._kvh // self.tp,
-                          d=self._hd, theta=cfg.rope_theta)
+            shared.update(attn=self._attn, kvh=self._kvh // self.tp,
+                          d=self._hd)
         if self._windowed:
             shared.update(cfg=cfg, window=self._window,
                           pool_index=self._pool_index)

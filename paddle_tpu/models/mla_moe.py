@@ -127,29 +127,38 @@ class MlaMoeConfig:
 # the layer's arithmetic
 # ---------------------------------------------------------------------------
 
-def yarn_inv_freq(cfg: MlaMoeConfig):
-    """Rotary frequencies of ``deepseek_yarn``: each pair's frequency is
-    the plain one where it turns more than ``beta_fast`` times over the
-    original context, the plain one over ``factor`` where it turns fewer
-    than ``beta_slow`` times, and a linear blend between."""
+def yarn_frequencies(d: int, base: float, factor: float, orig: float,
+                     beta_fast: float, beta_slow: float):
+    """YaRN's rotary frequencies over ``d`` rotated numbers (d/2 pairs):
+    each pair's frequency is the plain one (``base^(-2j/d)``) where it
+    turns more than ``beta_fast`` times over the original context
+    ``orig``, the plain one over ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear blend between."""
     import numpy as np
-    rs, d = cfg.rope_scaling, cfg.qk_rope_head_dim
-    base, factor = float(cfg.rope_theta), float(rs["factor"])
-    orig = float(rs["original_max_position_embeddings"])
     extra = base ** (-np.arange(0, d, 2, dtype=np.float64) / d)
 
     def corr(turns):
         return d * math.log(orig / (turns * 2 * math.pi)) \
             / (2 * math.log(base))
 
-    low = max(math.floor(corr(float(rs["beta_fast"]))), 0)
-    high = min(math.ceil(corr(float(rs["beta_slow"]))), d - 1)
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), d - 1)
     if low == high:
         high += 0.001
     ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
                    / (high - low), 0.0, 1.0)
     keep = 1.0 - ramp
     return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_inv_freq(cfg: MlaMoeConfig):
+    """Rotary frequencies of ``deepseek_yarn`` over the head's
+    ``qk_rope_head_dim`` rope numbers (``yarn_frequencies``)."""
+    rs = cfg.rope_scaling
+    return yarn_frequencies(
+        cfg.qk_rope_head_dim, float(cfg.rope_theta), float(rs["factor"]),
+        float(rs["original_max_position_embeddings"]),
+        float(rs["beta_fast"]), float(rs["beta_slow"]))
 
 
 def softmax_scale(cfg: MlaMoeConfig) -> float:
@@ -227,8 +236,10 @@ def route(h2, p, cfg: MlaMoeConfig):
     import jax.numpy as jnp
     logits = jnp.dot(h2, p["router"], preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32),
-                           cfg.num_experts_per_tok)
+    # a model whose router has no expert bias has no such leaf
+    pick = s + p["router_bias"].astype(jnp.float32) \
+        if "router_bias" in p else s
+    _, idx = jax.lax.top_k(pick, cfg.num_experts_per_tok)
     w = jnp.take_along_axis(s, idx, axis=-1)
     g = cfg.routed_scaling_factor * w / jnp.sum(w, -1, keepdims=True)
     return idx, g

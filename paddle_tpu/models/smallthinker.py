@@ -79,6 +79,11 @@ class SmallThinkerConfig:
                     "or a global layer with them, is not a kind the step "
                     "programs have (layer_stack.ATTENTION)")
 
+    @property
+    def experts_held(self) -> int:
+        """Every expert of a layer is held here."""
+        return self.moe_num_primary_experts
+
     def is_window(self, i: int) -> bool:
         return bool(self.sliding_window_layout[i])
 

@@ -310,6 +310,55 @@ def test_the_reglu_expert_kernel_compiles_for_the_v5e(
     assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down
 
 
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)])
+@pytest.mark.parametrize("tq", [32, 64, 576])
+def test_the_kernel_compiles_at_groups_6_and_8_of_one_model(
+        one_chip, compiled_kernels, no_persistent_cache, tq, heads, window):
+    """Laguna-XS.2's two launches over 8 K/V heads: 48 query heads over
+    whole contexts (a decode item is 6 score rows: off the sublane 8 as
+    7 is) and 64 over a window of 512, 32 pages, SHORTER than a 512-token
+    chunk: an item's page range ends before the chunk does."""
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, pages = (2, 16385) if window is None else (5, 2081)
+    pool = sds((layers, pages, 8, BLOCK, 128), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, bt, cu, kvl, l:
+                   pa.ragged_paged_attention_packed(
+                       q, k, v, bt, cu, kvl, layer=l, window=window)).lower(
+        sds((tq, heads, 128), jnp.bfloat16), pool, pool,
+        sds((ROWS + 1, 1024), jnp.int32), sds((ROWS + 1,), jnp.int32),
+        sds((ROWS,), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    name = "ragged_paged_attention" if window is None \
+        else pa.WINDOW_KERNEL_NAME
+    assert re.search(
+        rf"%{name}(\.\d+)? = bf16\[{tq},8,{heads // 8},128\]", text)
+
+
+@pytest.mark.parametrize("rows", [256, 4608])
+def test_the_grouped_expert_kernel_compiles_at_256_small_groups(
+        one_chip, compiled_kernels, no_persistent_cache, rows):
+    """256 experts of 2048 x 512 (a whole 2 MB matrix a block), about 17
+    rows a group at a 576-token step's 4,608 pairs and one at a decode
+    step's 256: both halves of an expert's SwiGLU."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    sizes = sds((256,), jnp.int32)
+    up = jax.jit(lambda x, g, u, s: gm.grouped_swiglu(
+        x, g, u, s, use_kernel=True)).lower(
+            sds((rows, 2048), bf), sds((256, 2048, 512), bf),
+            sds((256, 2048, 512), bf), sizes).compile().as_text()
+    down = jax.jit(lambda a, w, s: gm.grouped_matmul(
+        a, w, s, use_kernel=True)).lower(
+            sds((rows, 512), bf), sds((256, 512, 2048), bf),
+            sizes).compile().as_text()
+    assert "grouped_expert_matmul" in up and "grouped_expert_matmul" in down
+
+
 # ---------------------------------------------------------------------------
 # the sampling epilogue's branch (PR 29), in a whole ragged step program
 # ---------------------------------------------------------------------------
@@ -490,6 +539,7 @@ def _dense_step_text(chip, monkeypatch, model, tq, quant):
         kv_dtype="int8" if quant else "float32")
     monkeypatch.setattr(eng, "_nh", nh)
     monkeypatch.setattr(eng, "_kvh", kvh)
+    monkeypatch.setattr(eng, "_attn", eng._attention_by_kind())
     monkeypatch.setattr(eng, "_platform", "tpu")
     eng.attention_path = eng._resolve_attention_path()
     assert eng._hd == d and eng.attention_path == "pallas"

@@ -194,6 +194,36 @@ def test_cli_serves_the_window_and_global_preset():
         srv.kill()
 
 
+def test_cli_serves_the_two_head_count_preset():
+    """``--model laguna-sm``: full layers of 6 query heads (half-rotated)
+    and sliding-window layers of 8 (window 64) over 2 K/V heads, a gated
+    attention output, a dense layer then experts beside a shared one,
+    through the same CLI, engine and HTTP path: a completion whose prompt
+    is longer than the window, prefilled in chunks as long as it."""
+    srv = _Server("--model", "laguna-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4", "--max-prefill-tokens", "64",
+                  "--no-prefix-caching")
+    try:
+        port = srv.port()
+        assert "attention='xla-reference (cpu platform)'" in srv.output()
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        body = json.dumps({"prompt": list(range(1, 150)),
+                           "max_tokens": 6}).encode()
+        conn.request("POST", "/v1/completions", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+        assert resp.status == 200
+        choice = doc["choices"][0]
+        assert choice["finish_reason"] == "length"
+        assert len(choice["token_ids"]) == 6
+        conn.close()
+        srv.proc.send_signal(signal.SIGINT)
+        assert srv.proc.wait(timeout=60) == 0
+    finally:
+        srv.kill()
+
+
 def test_cli_refuses_prefix_caching_over_the_window_pool_by_name():
     srv = _Server("--model", "smallthinker-sm", "--dtype", "float32",
                   "--max-num-seqs", "4")

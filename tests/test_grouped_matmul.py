@@ -103,3 +103,43 @@ def test_reglu_products_equal_ragged_dot(case, use_kernel, monkeypatch):
                                atol=1e-5, rtol=0)
     if n:
         assert (act[:n] == 0).any()     # ReLU, not SiLU: some gates shut
+
+
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "ragged_dot"])
+@pytest.mark.parametrize("rows,tile", [(300, 16), (64, 8), (1024, 128)])
+def test_256_groups_of_few_rows_equal_ragged_dot(rows, tile, use_kernel,
+                                                 monkeypatch):
+    """256 experts, 8 a token (models/laguna.py): about a row a group at
+    a decode step, a few at a chunk step, many groups empty; the walk
+    makes ``M / tile + 255`` visits and every group's rows come out as
+    ``lax.ragged_dot`` gives them."""
+    monkeypatch.setenv("PADDLE_TPU_TUNE_FORCE",
+                       '{"grouped_matmul": {"row_tile": %d}}' % tile)
+    experts = 256
+    rng = np.random.default_rng(rows)
+    # pairs as a router deals them: uneven, some experts with none
+    picks = rng.choice(experts, size=rows - 40, p=rng.dirichlet(
+        np.full(experts, 0.5)))
+    sizes = np.bincount(picks, minlength=experts).astype(np.int32)
+    n = int(sizes.sum())
+    assert (sizes == 0).any() and sizes.max() > 2 * n / experts
+    ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+    x = jax.random.normal(ks[0], (rows, K), jnp.float32)
+    w = jax.random.normal(ks[1], (experts, K, N), jnp.float32)
+    w2 = jax.random.normal(ks[2], (experts, K, N), jnp.float32)
+    gs = jnp.asarray(sizes)
+    gid = np.asarray(gm._visits(gs, rows, tile)[0])
+    assert len(gid) == rows // tile + experts - 1
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(lambda *a: gm.grouped_matmul(
+            *a, use_kernel=use_kernel))(x, w, gs))
+        act = np.asarray(jax.jit(lambda *a: gm.grouped_swiglu(
+            *a, use_kernel=use_kernel))(x, w, w2, gs))
+        want = np.asarray(jax.lax.ragged_dot(x, w, gs))
+        up = np.asarray(jax.lax.ragged_dot(x, w2, gs))
+    np.testing.assert_allclose(got[:n], want[:n], atol=1e-5, rtol=0)
+    # a product of two sums of 32 terms: of order 100, so relative
+    np.testing.assert_allclose(
+        act[:n], (np.asarray(jax.nn.silu(want)) * up)[:n], atol=1e-4,
+        rtol=1e-4)

@@ -294,17 +294,17 @@ def test_each_kind_is_traced_once_a_program(model, monkeypatch):
     traced = []
     real = layer_stack._gqa
 
-    def spy(*a, **kw):
-        traced.append((kw.get("rope", True), kw.get("window", False)))
-        return real(*a, **kw)
+    def spy(*a, kind):
+        traced.append(kind)
+        return real(*a, kind=kind)
 
     monkeypatch.setattr(layer_stack, "ATTENTION", {
         **layer_stack.ATTENTION,
-        "gqa_window": lambda *a: spy(*a, window=True),
-        "gqa_nope": lambda *a: spy(*a, rope=False)})
+        "gqa_window": lambda *a: spy(*a, kind="gqa_window"),
+        "gqa_nope": lambda *a: spy(*a, kind="gqa_nope")})
     eng = _engine(model)
     eng._get_ragged_prog(8).lower(*eng._ragged_arg_structs(8))
-    assert sorted(traced) == [(False, False), (True, True)]
+    assert sorted(traced) == ["gqa_nope", "gqa_window"]
 
 
 @pytest.mark.parametrize("kind", ["window", "dense"])

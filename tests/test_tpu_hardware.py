@@ -392,12 +392,33 @@ def test_group_7_kernel_and_its_window_on_tpu(window):
     window, a row of no keys; a window layer's table names the null
     page below each row's window.  Against the dense-gather reference in
     float32; the launch is timed."""
+    _ragged_at_served_shapes(
+        28, 4, window,
+        [(512, 9512), (1, 1), (0, 0), (1, 4096), (1, 4097), (1, 12000),
+         (1, 300), (5, 40), (1, 14999)], (6000, 15000))
+
+
+@pytest.mark.parametrize("H,window", [(48, None), (64, 512)])
+def test_groups_6_and_8_of_one_model_on_tpu(H, window):
+    """Laguna-XS.2's two launches over 8 K/V heads: 48 query heads over
+    whole contexts (a decode item is 6 score rows) and 64 over a window
+    of 512, 32 pages, SHORTER than the 512-token chunk beside it (an
+    item's page range ends before the chunk does); decode rows under, at
+    and far past the window."""
+    _ragged_at_served_shapes(
+        H, 8, window,
+        [(512, 9512), (1, 1), (0, 0), (1, 512), (1, 513), (1, 12000),
+         (1, 300), (5, 40), (1, 13311), (40, 600)], (2000, 13000))
+
+
+def _ragged_at_served_shapes(H, Hkv, window, rows, decode_keys):
+    """The ragged kernel at a served model's heads over the pools of all
+    layers, against the plain reference row by row, then timed at 28
+    decode rows of ``decode_keys`` keys."""
     from paddle_tpu.ops.pallas import paged_attention as PA
 
-    H, Hkv, D, bs, nblk, L, nb = 28, 4, 128, 16, 1024, 2, 4000
+    D, bs, nblk, L, nb = 128, 16, 1024, 2, 4000
     rng = np.random.RandomState(11)
-    rows = [(512, 9512), (1, 1), (0, 0), (1, 4096), (1, 4097), (1, 12000),
-            (1, 300), (5, 40), (1, 14999)]
     Tq = 576
     cu = np.zeros(33, np.int32)
     kvl = np.zeros(32, np.int32)
@@ -451,13 +472,13 @@ def test_group_7_kernel_and_its_window_on_tpu(window):
     ref = jnp.asarray(ref)
     err = _max_err(out, ref, live)
     ms = _timed(fn, q, kc, vc, *args)
-    print(f"group 7 kernel window={window}: max abs err {err:.3e} over "
-          f"{live} tokens, {ms:.3f} ms a launch")
+    print(f"group {H // Hkv} kernel window={window}: max abs err {err:.3e} "
+          f"over {live} tokens, {ms:.3f} ms a launch")
     assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
     assert not bool(jnp.any(out[live:]))
     assert err < 2e-2, err
-    # 28 decode rows at 6k to 15k keys, as a step of the cell holds them
-    rows = [(1, int(k)) for k in rng.randint(6000, 15000, 28)]
+    # 28 decode rows at long keys, as a step of the cell holds them
+    rows = [(1, int(k)) for k in rng.randint(*decode_keys, 28)]
     cu = np.minimum(np.arange(33), 28).astype(np.int32)
     kvl = np.zeros(32, np.int32)
     bt = np.zeros((33, nblk), np.int32)
@@ -468,8 +489,8 @@ def test_group_7_kernel_and_its_window_on_tpu(window):
     q32 = q[:32]
     ms = _timed(fn, q32, kc, vc, jnp.asarray(bt), jnp.asarray(cu),
                 jnp.asarray(kvl))
-    print(f"group 7 kernel window={window}: 28 decode rows at 6k-15k keys "
-          f"{ms:.3f} ms a launch")
+    print(f"group {H // Hkv} kernel window={window}: 28 decode rows at "
+          f"{decode_keys} keys {ms:.3f} ms a launch")
 
 
 def test_reglu_expert_kernel_on_tpu():
@@ -509,6 +530,46 @@ def test_reglu_expert_kernel_on_tpu():
               f" + {_timed(down, a, wd, gs):.3f} ms")
         assert err_a < 2e-2 and err_y < 5e-2, (err_a, err_y)
         assert float(jnp.mean(a[:n] == 0)) > 0.3     # ReLU shut these
+
+
+def test_256_small_experts_kernel_on_tpu():
+    """256 experts of 2048 x 512 (a whole 2 MB matrix a block): 4,608
+    sorted rows of a 576-token step's pairs, about 17 a group, some
+    groups empty and one over a row tile, and a decode step's 256 pairs
+    over about 160 experts: the kernel against lax.ragged_dot, both
+    halves of the SwiGLU; timed."""
+    from paddle_tpu.ops.pallas import grouped_matmul as GM
+
+    rng = np.random.RandomState(5)
+    wg = jnp.asarray(rng.randn(256, 2048, 512) * 0.02, jnp.bfloat16)
+    wu = jnp.asarray(rng.randn(256, 2048, 512) * 0.02, jnp.bfloat16)
+    wd = jnp.asarray(rng.randn(256, 512, 2048) * 0.02, jnp.bfloat16)
+    for M, pairs in ((4608, 4320), (256, 256)):
+        sizes = rng.multinomial(pairs - 150, np.ones(256) / 256).astype(
+            np.int32)
+        sizes[9] += 150 - sizes[5]
+        sizes[5] = 0
+        n = int(sizes.sum())
+        x = jnp.asarray(rng.randn(M, 2048), jnp.bfloat16)
+        gs = jnp.asarray(sizes)
+        fns = {}
+        for use_kernel in (True, False):
+            up = jax.jit(lambda *t, k=use_kernel: GM.grouped_swiglu(
+                *t, use_kernel=k))
+            down = jax.jit(lambda *t, k=use_kernel: GM.grouped_matmul(
+                *t, use_kernel=k))
+            a = up(x, wg, wu, gs)
+            y = down(a, wd, gs)
+            fns[use_kernel] = (up, down, a, y)
+        (up, down, a, y), (_, _, a0, y0) = fns[True], fns[False]
+        err_a = float(jnp.max(jnp.abs(a[:n].astype(jnp.float32)
+                                      - a0[:n].astype(jnp.float32))))
+        err_y = float(jnp.max(jnp.abs(y[:n] - y0[:n])))
+        print(f"256 experts against ragged_dot at {n} pairs over "
+              f"{int((sizes > 0).sum())} experts: swiglu {err_a:.3e}, down "
+              f"{err_y:.3e}; {_timed(up, x, wg, wu, gs):.3f} + "
+              f"{_timed(down, a, wd, gs):.3f} ms")
+        assert err_a < 2e-2 and err_y < 5e-2, (err_a, err_y)
 
 
 def _int8_page_kernel_err(H, Hkv, pool=None):

@@ -69,7 +69,7 @@ def _plain(q, k, v, bt, rows, cu, window):
         mask = key[None, :] <= pos[:, None]
         if window is not None:
             mask &= key[None, :] > pos[:, None] - window
-        qr = q[int(cu[r]):int(cu[r]) + n].reshape(n, HKV, G, D)
+        qr = q[int(cu[r]):int(cu[r]) + n].reshape(n, HKV, -1, D)
         s = np.einsum("qhgd,khd->hgqk", qr, kr) / np.sqrt(D)
         s = np.where(mask[None, None], s, -np.inf)
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -77,7 +77,7 @@ def _plain(q, k, v, bt, rows, cu, window):
         # masked keys may lie in the null page: leave them out of the sum
         o = np.einsum("hgqk,khd->qhgd", p, np.where(
             (mask.any(0))[:, None, None], vr, 0.0))
-        out[int(cu[r]):int(cu[r]) + n] = o.reshape(n, HKV * G, D)
+        out[int(cu[r]):int(cu[r]) + n] = o.reshape(n, -1, D)
     return out
 
 
@@ -159,3 +159,76 @@ def test_tile_rows_stay_on_the_sublane_at_group_7():
         assert Tq % tq == 0 and (tq * 7) % 8 == 0, (Tq, tq)
     assert pa._ragged_tiles(192, 8, 4, 128, 16, 256, jnp.bfloat16)[0] == 32
     assert pa._ragged_tiles(192, 4, 8, 128, 16, 256, jnp.bfloat16)[0] == 16
+
+
+# ---------------------------------------------------------------------------
+# groups 6 and 8 of one model, and a window SHORTER than the chunk
+# (models/laguna.py: 48 and 64 query heads over 8 K/V heads, window 512
+# under 512-token chunks)
+# ---------------------------------------------------------------------------
+
+SHORT = {
+    # a chunk of 40 against a window of 16: every tile's page range is
+    # shorter than the chunk, and its first page moves with the tile
+    "chunk_over_the_window": ([(40, 40)], 40),
+    # the same past the start, beside decode rows at, one past and far
+    # past the window
+    "chunk_past_beside_decode": ([(36, 90), (1, 16), (1, 17), (1, 77)], 40),
+    # two chunks in one launch, one resumed
+    "two_chunks": ([(20, 20), (19, 64)], 40),
+}
+
+
+@pytest.mark.parametrize("group", [6, 8])
+@pytest.mark.parametrize("tiles", [(48, 2), (24, 3)])
+@pytest.mark.parametrize("case", sorted(SHORT))
+def test_a_window_shorter_than_the_chunk_at_groups_6_and_8(case, tiles, group,
+                                                           monkeypatch):
+    rows, Tq = SHORT[case]
+    window = 16
+    monkeypatch.setenv(
+        "PADDLE_TPU_TUNE_FORCE",
+        '{"paged_attention": {"q_tile_rows": %d, "kv_pages": %d}}' % tiles)
+    cu, kvl, bt = _layout(rows, Tq, window, seed=len(case) + group)
+    q = jax.random.normal(jax.random.PRNGKey(group), (Tq, HKV * group, D),
+                          jnp.float32)
+    k, v = _pools(jax.random.PRNGKey(9))
+    got = np.asarray(pa.ragged_paged_attention_packed(
+        q, k, v, bt, cu, kvl, layer=2, window=window))
+    want = _plain(q, k[2], v[2], bt, rows, cu, window)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    oracle = np.asarray(pa.ragged_paged_reference_segrel(
+        q, k[2], v[2], bt, *pa.ragged_segments(cu, kvl, Tq), window=window))
+    live = np.zeros(Tq, bool)
+    for r, (n, kv_len) in enumerate(rows):
+        live[int(cu[r]):int(cu[r]) + n] = kv_len > 0
+    np.testing.assert_allclose(oracle[live], want[live], rtol=2e-5,
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["mixed", "decode_past", "chunk_past"])
+def test_no_window_at_group_6(case, monkeypatch):
+    """The full layers' launch of the same model: group 6, every key."""
+    rows, Tq = CASES[case]
+    monkeypatch.setenv(
+        "PADDLE_TPU_TUNE_FORCE",
+        '{"paged_attention": {"q_tile_rows": 48, "kv_pages": 2}}')
+    cu, kvl, bt = _layout(rows, Tq, None, seed=2)
+    q = jax.random.normal(jax.random.PRNGKey(7), (Tq, HKV * 6, D),
+                          jnp.float32)
+    k, v = _pools(jax.random.PRNGKey(8))
+    got = np.asarray(pa.ragged_paged_attention_packed(q, k, v, bt, cu, kvl,
+                                                      layer=0))
+    np.testing.assert_allclose(
+        got, _plain(q, k[0], v[0], bt, rows, cu, None),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_tile_rows_stay_on_the_sublane_at_group_6():
+    """A group of 6 takes tiles of a multiple of 4 tokens (24 score rows
+    and up) in every bucket of the benchmark's cell."""
+    for Tq in (32, 64, 128, 320, 576):
+        tq, _ = pa._ragged_tiles(Tq, 8, 6, 128, 16, 1024, jnp.bfloat16)
+        assert Tq % tq == 0 and (tq * 6) % 8 == 0, (Tq, tq)
+    assert pa._ragged_tiles(576, 8, 6, 128, 16, 1024, jnp.bfloat16)[0] == 16
+    assert pa._ragged_tiles(576, 8, 8, 128, 16, 1024, jnp.bfloat16)[0] == 16
