@@ -102,9 +102,12 @@ ATTENTION_KINDS = ("mla", "gqa", "gqa_nope", "gqa_window", "gqa_gated",
                    "gqa_gated_window")
 
 
-@dataclass
+@dataclass(eq=False)
 class Request:
-    """One generation request in the engine's queues."""
+    """One generation request in the engine's queues.  Two requests are
+    the same only if they are the same object (``eq=False``: identity
+    comparison and hashing): nothing compares the fields of two, and
+    the engine itself asks ``is``, never ``==``."""
     rid: int
     prompt: list                      # original prompt token ids
     max_new_tokens: int
@@ -124,7 +127,9 @@ class Request:
                                       # for a decode row): what ``cached``
                                       # will have grown by at its commit
     arrival: int = 0                  # admission priority (FCFS)
-    slot: int = -1                    # stable decode-batch slot
+    slot: int = -1                    # stable decode-batch slot: >= 0
+                                      # exactly while the request is in
+                                      # the running set (``_is_running``)
     t_arrival: float = 0.0            # wall clock at add_request (TTFT)
     seen: object = None               # [V] bool penalty mask (lazy)
     spec_proposed: int = 0            # drafts sent to verify (lifetime)
@@ -791,6 +796,9 @@ class LLMEngine:
         self.launches_ahead = 0
         self.ahead_fallbacks: dict = {}
         self.ahead_rows_dropped = 0
+        # times a preemption made the scheduler re-filter rows it had
+        # taken for the launch it was preparing
+        self.sched_refilters = 0
         # what a launch takes as ``prev`` when nothing is in flight:
         # placed as a launch's ``sampled`` comes back, so that a bucket
         # has ONE program whichever it is handed
@@ -1279,14 +1287,13 @@ class LLMEngine:
         for r in self._running:
             if r.rid == request_id:
                 req = r
-                self._running.remove(r)
-                self._release_slot(r)
+                self._leave_running(r)
                 break
         else:
-            for r in self._waiting:
+            for i, r in enumerate(self._waiting):
                 if r.rid == request_id:
                     req = r
-                    self._waiting.remove(r)
+                    del self._waiting[i]
                     break
         if req is None:
             self.stats.record_abort_noop()
@@ -1450,6 +1457,10 @@ class LLMEngine:
         out["launches_ahead"] = self.launches_ahead
         out["ahead_fallbacks"] = dict(self.ahead_fallbacks)
         out["ahead_rows_dropped"] = self.ahead_rows_dropped
+        # times a preemption made the scheduler filter rows it had
+        # already taken against the running set again (0 while nothing
+        # is preempted: the turn then walks each row once)
+        out["sched_refilters"] = self.sched_refilters
         out["sample_launches"] = self.sample_stats["launches"]
         out["sample_chain_launches"] = self.sample_stats["chain_launches"]
         if self._has_experts:
@@ -1956,6 +1967,7 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
             ev0 = self.blocks.eviction_count
+            rf0 = self.sched_refilters
             self._cow_n = self._cow_ns = 0
         chunks = spec = batch = ()
         ticket = None
@@ -1964,6 +1976,9 @@ class LLMEngine:
                                          for r in self._running):
                 raise _AheadAbandoned("penalty")
             chunks = self._schedule_prefill_chunks()
+            # the chunks stand as taken (their own victims dropped); the
+            # rows below are taken from the running set as it is now
+            pre0 = self.stats.preemptions
 
             # decode-ready set (chunk owners are still mid-prefill, so
             # the row classes are disjoint by construction)
@@ -1975,17 +1990,23 @@ class LLMEngine:
             spec, batch = self._split_spec(batch)
             spec, demoted = self._reserve_verify_pages(spec)
             batch.extend(demoted)
-            # verify reservation/CoW may have preempted plain-decode
-            # members
-            batch = [r for r in batch
-                     if r in self._running and self._decode_ready(r)]
+            if self.stats.preemptions != pre0:
+                # verify reservation/CoW preempted plain-decode members
+                self.sched_refilters += 1
+                batch = [r for r in batch
+                         if self._is_running(r) and self._decode_ready(r)]
             batch = self._reserve_decode_pages(batch)
-            # every reservation above can preempt a chunk owner or an
-            # already-reserved row: re-filter each class against the
-            # surviving running set before packing the launch
-            chunks = [(r, n) for r, n in chunks if r in self._running]
-            spec = [(r, d, q) for r, d, q in spec if r in self._running]
-            batch = [r for r in batch if r in self._running]
+            if self.stats.preemptions != pre0:
+                # a reservation above preempted, and its victim may be a
+                # chunk owner or an already-reserved row: re-filter each
+                # class against the surviving running set before packing
+                # the launch.  (A launch dispatched ahead never gets
+                # here: it preempts nobody, it is abandoned.)
+                self.sched_refilters += 1
+                chunks = [(r, n) for r, n in chunks if self._is_running(r)]
+                spec = [(r, d, q) for r, d, q in spec
+                        if self._is_running(r)]
+                batch = [r for r in batch if self._is_running(r)]
             batch.sort(key=lambda r: r.slot)
             if ahead is not None and self.decode_window > 1 \
                     and not chunks and batch:
@@ -2003,7 +2024,8 @@ class LLMEngine:
             sched = {"step": sid, "chunks": len(chunks),
                      "spec": len(spec), "decode": len(batch),
                      "evicted": self.blocks.eviction_count - ev0,
-                     "cow": self._cow_n, "cow_ns": self._cow_ns}
+                     "cow": self._cow_n, "cow_ns": self._cow_ns,
+                     "refilters": self.sched_refilters - rf0}
             if chunks or spec or batch:
                 # the span runs on over the packing of what it chose
                 self._sched_open = (t, sched)
@@ -2469,7 +2491,7 @@ class LLMEngine:
                 committed += 1
                 self._notify_tokens(req, (tok,))
                 self._maybe_retire(req, finished)
-                if req not in self._running:
+                if not self._is_running(req):
                     alive.discard(req.rid)
         self.pad_stats["real"] += committed
         self.pad_stats["padded"] += iters * self.max_num_seqs
@@ -2492,8 +2514,7 @@ class LLMEngine:
         hit).  Clients see finish_reason="numerical_error"; the rest of
         the batch is untouched."""
         self._give_back(req.rid, "release")
-        self._running.remove(req)
-        self._release_slot(req)
+        self._leave_running(req)
         if self.drafter is not None:
             self.drafter.release(req.rid)
         out = RequestOutput(rid=req.rid, prompt=list(req.prompt),
@@ -2654,14 +2675,27 @@ class LLMEngine:
                     break
         return wanted
 
-    def _claim_slot(self, req) -> None:
+    # The running set has ONE membership test, ``_is_running``, and it
+    # is O(1): a request holds a batch slot (``req.slot >= 0``) exactly
+    # while it is in ``_running``.  The invariant is kept here and
+    # nowhere else: ``_join_running`` is the one place ``_running`` is
+    # appended to, ``_leave_running`` the one place it is removed from
+    # (retirement, preemption, quarantine, abort), and nothing else
+    # writes ``req.slot``.
+
+    def _is_running(self, req) -> bool:
+        return req.slot >= 0
+
+    def _join_running(self, req) -> None:
         req.slot = self._slot_used.index(False)
         self._slot_used[req.slot] = True
+        self._running.append(req)
 
-    def _release_slot(self, req) -> None:
-        if req.slot >= 0:
-            self._slot_used[req.slot] = False
-            req.slot = -1
+    def _leave_running(self, req) -> None:
+        run = self._running
+        del run[next(i for i, r in enumerate(run) if r is req)]
+        self._slot_used[req.slot] = False
+        req.slot = -1
 
     def _admit(self) -> list:
         """Pull waiting requests into the running set while batch slots
@@ -2698,8 +2732,7 @@ class LLMEngine:
             req.arrival = self._arrival
             self._arrival += 1
             self._invalidate_bt(req.rid)
-            self._claim_slot(req)
-            self._running.append(req)
+            self._join_running(req)
             admitted.append(req)
             # queue wait = arrival -> this admission (for a preempted
             # request that re-admits, arrival -> LATEST admission: the
@@ -2729,7 +2762,7 @@ class LLMEngine:
         chunks: list = []
 
         def admit(req):
-            if req not in self._running:
+            if not self._is_running(req):
                 return False
             if self.enable_prefix_caching:
                 # may preempt req (False) or drop an earlier chunk's
@@ -2761,6 +2794,7 @@ class LLMEngine:
                     return False
                 self._preempt(victim)
                 if drop_from is not None:
+                    self.sched_refilters += 1
                     drop_from[:] = [c for c in drop_from
                                     if c[0] is not victim]
                 continue
@@ -2776,7 +2810,7 @@ class LLMEngine:
         ahead never preempts: it is abandoned)."""
         ok = []
         for req in sorted(batch, key=lambda r: r.arrival):
-            if req not in self._running:   # evicted as a victim earlier
+            if not self._is_running(req):  # evicted as a victim earlier
                 continue
             while req is not None:
                 if not self.blocks.ensure(req.rid, self._pos(req) + 1):
@@ -2788,13 +2822,19 @@ class LLMEngine:
                         req = None
                         break
                     self._preempt(victim)
+                    self.sched_refilters += 1
                     ok = [r for r in ok if r is not victim]
                     continue
                 if self.enable_prefix_caching:
+                    pre0 = self.stats.preemptions
                     if not self._resolve_cow(req, self._pos(req)):
                         req = None
                         break
-                    ok = [r for r in ok if r in self._running]
+                    if self.stats.preemptions != pre0:
+                        # the copy's victims may stand among the rows
+                        # already reserved
+                        self.sched_refilters += 1
+                        ok = [r for r in ok if self._is_running(r)]
                 break
             if req is not None:
                 ok.append(req)
@@ -2816,8 +2856,7 @@ class LLMEngine:
         very pages this preemption returned and re-prefills only the
         tail."""
         self.blocks.free(req.rid)
-        self._running.remove(req)
-        self._release_slot(req)
+        self._leave_running(req)
         req.tokens = list(req.prompt) + list(req.generated)
         req.cached = 0
         # its freed pages may spill while it waits: re-consult the tier
@@ -2864,8 +2903,7 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
         self._give_back(req.rid, "free")
-        self._running.remove(req)
-        self._release_slot(req)
+        self._leave_running(req)
         out = RequestOutput(rid=req.rid, prompt=list(req.prompt),
                             generated=list(req.generated),
                             finish_reason=reason)
@@ -2949,7 +2987,7 @@ class LLMEngine:
         path keeps the usual victim-preemption behaviour."""
         ok, demoted = [], []
         for req, drafts, qd in spec:
-            if req not in self._running:
+            if not self._is_running(req):
                 continue
             k = len(drafts)
             while k > 0 and not self.blocks.ensure(req.rid,
@@ -2961,11 +2999,14 @@ class LLMEngine:
             drafts = drafts[:k]
             if self.enable_prefix_caching:
                 alive = True
+                pre0 = self.stats.preemptions
                 for pos in self._page_starts(req.cached, req.cached + k):
                     if not self._resolve_cow(req, pos):
                         alive = False           # req itself was preempted
                         break
-                ok = [it for it in ok if it[0] in self._running]
+                if self.stats.preemptions != pre0:
+                    self.sched_refilters += 1
+                    ok = [it for it in ok if self._is_running(it[0])]
                 if not alive:
                     continue
             ok.append((req, drafts, qd))

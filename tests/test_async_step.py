@@ -28,6 +28,10 @@ The contract (CPU, paged kernel in interpret mode):
   do not overlap; overlap-off emits none of the in-flight windows
   (step_timeline.py's "synchronous" reading).
 """
+import json
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -497,6 +501,85 @@ def test_short_pool_falls_back_then_preempts_as_sync_does(model):
     assert s["ahead_fallbacks"].get("pool", 0) >= 1
     assert s["launches_ahead"] >= 1
     _clean(e_on)
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's decisions on a short pool, held to a recording
+# ---------------------------------------------------------------------------
+
+_DECISIONS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "fixtures", "scheduler", "decisions_parent.json")
+
+
+def _decisions_drive(model, overlap, drafter):
+    """A finished conversation, three unrelated requests, then four
+    follow-ups that extend the conversation and diverge inside its
+    cached partial tail page, on a pool of eight pages: the follow-ups'
+    first chunks copy that page on write with no page free
+    (``_resolve_cow`` preempts), and the decode rows' growth preempts in
+    ``_reserve_decode_pages``.  What every launch held, in order, who
+    was preempted where, and every token."""
+    kw = {"drafter": "ngram", "spec_k": 3} if drafter else {}
+    eng = _engine(model, overlap=overlap, max_num_seqs=6, num_blocks=9,
+                  max_prefill_tokens=32, prefill_token_bucket=32, **kw)
+    launches, where = [], {}
+    run_ragged, preempt = eng._run_ragged, eng._preempt
+
+    def record_launch(chunks, spec, batch):
+        launches.append([[[r.rid, n] for r, n in chunks],
+                         [[r.rid, len(d)] for r, d, _ in spec],
+                         [r.rid for r in batch]])
+        return run_ragged(chunks, spec, batch)
+
+    def record_preempt(req):
+        by = sys._getframe(1).f_code.co_name
+        where[by] = where.get(by, 0) + 1
+        return preempt(req)
+
+    eng._run_ragged, eng._preempt = record_launch, record_preempt
+    rng = np.random.RandomState(1)
+    pa = rng.randint(0, VOCAB, 11).tolist()
+    ra = eng.add_request(pa, max_new_tokens=5)
+    base = pa + eng.run()[ra].generated[:4]
+    for _ in range(3):
+        eng.add_request(rng.randint(0, VOCAB, rng.randint(4, 12)).tolist(),
+                        max_new_tokens=12)
+    eng.step()
+    eng.step()
+    for _ in range(4):
+        eng.add_request(base + [int(rng.randint(0, VOCAB))],
+                        max_new_tokens=12)
+    outs = eng.run()
+    return eng, {
+        "launches": launches,
+        "preempted_in": dict(sorted(where.items())),
+        "preemptions": eng.stats.preemptions,
+        "cow_copies": eng.stats.summary()["cow_copies"],
+        "generated": {str(r): list(o.generated)
+                      for r, o in sorted(outs.items())},
+    }
+
+
+@pytest.mark.parametrize("drafter", [False, True], ids=["plain", "drafter"])
+@pytest.mark.parametrize("overlap", [False, True], ids=["sync", "overlap"])
+def test_short_pool_decisions_equal_the_parents_recording(model, overlap,
+                                                          drafter):
+    """Which rows ride which launch is the scheduler's decision, and a
+    cheaper way to reach it must not change it: on a pool that forces
+    preemptions while rows already taken stand reserved (a victim of a
+    copy-on-write among the chunks, of a decode row's growth among the
+    reserved decode and verify rows), every launch's chunks, verify
+    rows and decode rows, the preemption count and every token equal
+    what the parent commit (b650a05) did, recorded from this drive."""
+    with open(_DECISIONS) as f:
+        want = json.load(f)[f"overlap={int(overlap)},drafter={int(drafter)}"]
+    eng, got = _decisions_drive(model, overlap, drafter)
+    assert got["preempted_in"].get("_resolve_cow", 0) > 0
+    assert got["preempted_in"].get("_reserve_decode_pages", 0) > 0
+    if drafter:
+        assert any(spec for _, spec, _ in got["launches"])
+    assert got == want
+    _clean(eng)
 
 
 def test_precompiled_buckets_take_both_kinds_of_launch(model):

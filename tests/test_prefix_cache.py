@@ -85,6 +85,34 @@ def test_cow_on_shared_partial_page():
     bm.check_invariants()
 
 
+@pytest.mark.xfail(strict=True, reason=(
+    "found by PR 36, not its to mend (ROADMAP D17): a page written in place "
+    "by its last owner keeps the full-page hash an earlier owner registered"))
+def test_in_place_write_does_not_leave_an_earlier_owners_hash():
+    """b fills a hit partial page in place and registers it as ITS full
+    page; c hits the same page partially (k=2) while b lives; b retires
+    before c's first write, so c finds the page private and writes slots
+    2 and 3 in place, under b's hash.  d, whose prompt is b's, must not
+    be served c's K/V.  (The engine meets it on a short pool: a
+    copy-on-write's victim is the page's other owner; tests/
+    test_async_step.py's recording holds one such wrong answer, rid 4.)"""
+    bm = BlockManager(9, 4, enable_prefix_caching=True)
+    a = [1, 2, 3, 4, 5, 6]
+    assert bm.acquire("a", a + [7]) == 0
+    bm.commit_prefill("a", 6)
+    bm.free("a")                                  # tail (k=2) registered
+    b = a + [10, 11]
+    assert bm.acquire("b", b + [12]) == 6
+    assert bm.cow_if_shared("b", 6) is None       # private: in place
+    bm.commit_prefill("b", 2)                     # page 1 full: b's hash
+    assert bm.acquire("c", a + [20, 21, 22]) == 6
+    bm.free("b")
+    assert bm.cow_if_shared("c", 6) is None       # private again: in place
+    bm.commit_prefill("c", 2)
+    bm.acquire("d", b + [12, 13])
+    assert bm._tables["d"][1] != bm._tables["c"][1]
+
+
 def test_lru_eviction_only_under_pressure():
     bm = BlockManager(4, 2, enable_prefix_caching=True)   # 3 usable pages
     bm.acquire("p", [7, 8, 9])
