@@ -448,16 +448,9 @@ class ServingFrontend:
 
     async def _completions(self, req, reader, writer) -> bool:
         route = "/v1/completions"
-        # stackless now()/complete() here and below: an asyncio handler
-        # must never hold a span() across an await (coroutines interleave
-        # on one thread and would corrupt the per-thread span stack)
         tr = self.tracer
         try:
-            t_parse = tr.now() if tr is not None else 0
             kwargs, stream, deadline_ms = parse_completion_request(req.body)
-            if tr is not None:
-                tr.complete("http.parse", t_parse, track=self._http_track,
-                            args={"bytes": len(req.body or b"")})
         except ProtocolError as e:
             self._count(route, 400)
             writer.write(response_bytes(400, error_body(400, str(e))))
@@ -547,7 +540,6 @@ class ServingFrontend:
     async def _stream_response(self, request_id, q, reader, writer,
                                inject_drop: bool = False) -> bool:
         route = "/v1/completions"
-        tr = self.tracer
         sse = SSEWriter(writer)
         with self._lock:
             self._active_streams += 1
@@ -569,14 +561,8 @@ class ServingFrontend:
                 else:
                     kind, payload = q.get_nowait()
                 if kind == "token":
-                    t_w = tr.now() if tr is not None else 0
                     await sse.event(stream_token_frame(
                         request_id, self.model_name, payload))
-                    if tr is not None:
-                        tr.complete("http.sse_write", t_w,
-                                    track=self._http_track,
-                                    args={"request_id": request_id,
-                                          "kind": "token"})
                     if inject_drop:
                         # injected mid-stream disconnect: behave exactly
                         # like the client vanished after this frame
@@ -584,15 +570,9 @@ class ServingFrontend:
                         self.runner.abort(request_id, reason="aborted")
                         return False
                 else:
-                    t_w = tr.now() if tr is not None else 0
                     await sse.event(stream_finish_frame(
                         request_id, self.model_name, payload))
                     await sse.done()
-                    if tr is not None:
-                        tr.complete("http.sse_write", t_w,
-                                    track=self._http_track,
-                                    args={"request_id": request_id,
-                                          "kind": "finish"})
                     return True
         except (ConnectionError, asyncio.IncompleteReadError):
             self.runner.abort(request_id, reason="aborted")

@@ -206,6 +206,27 @@ def render_metrics(snapshot: dict, *, engines=(),
     d.metric("step_block_seconds_total", "counter",
              "Cumulative completion-block time (waiting on device "
              "results).", [(None, s.get("block_time_s"))])
+    # the turn, read where it happens: what the engine thread itself
+    # worked (step() less the completion block) against what it waited
+    # on the chip (step_block_seconds_total) says which of the two a
+    # replica's period is; the call and the commit are parts of the turn
+    d.metric("engine_turn_seconds_total", "counter",
+             "Cumulative engine-thread work of step() calls (wall time "
+             "less the completion block): against "
+             "step_block_seconds_total, is this replica waiting on its "
+             "host or on its chip.", [(None, s.get("turn_time_s"))])
+    d.metric("engine_launch_call_seconds_total", "counter",
+             "Cumulative time inside the jitted call of a step launch "
+             "(host-to-device transfer of its host arrays and the jit "
+             "dispatch).", [(None, s.get("launch_call_time_s"))])
+    d.metric("engine_commit_seconds_total", "counter",
+             "Cumulative time committing launches (per-row cache "
+             "commit, stream callbacks, retirement).",
+             [(None, s.get("commit_time_s"))])
+    d.metric("engine_launch_arg_bytes_total", "counter",
+             "Cumulative bytes of host arrays handed to the jitted "
+             "calls of step launches.",
+             [(None, s.get("launch_arg_bytes"))])
     d.metric("step_dispatch_seconds", "gauge",
              "Per-step host dispatch duration.",
              [({"quantile": "0.5"}, _ms(s.get("dispatch_ms_p50"))),

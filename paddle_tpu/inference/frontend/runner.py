@@ -330,12 +330,6 @@ class EngineRunner:
             h.deliver(("finish", out))
         except Exception:
             pass                      # a dead consumer must not kill the loop
-        tr = self._tracer()
-        if tr is not None:
-            tr.instant("runner.finish", track=self._trace_track,
-                       args={"request_id": h.request_id, "rid": h.rid,
-                             "finish_reason": getattr(
-                                 out, "finish_reason", None)})
 
     def _admit_one(self, eng, h, gen: int, generated=None) -> bool:
         """Admit one handle into ``eng`` with generation-guarded

@@ -352,6 +352,33 @@ def _refuse_latent_options(**asked) -> None:
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
+def _timed(fn, split: list, i: int):
+    """``fn`` (of two arguments, as each of ``_row_calls`` is), with the
+    nanoseconds spent inside it added to ``split[i]``."""
+    now = time.perf_counter_ns
+
+    def run(a, b):
+        t0 = now()
+        out = fn(a, b)
+        split[i] += now() - t0
+        return out
+    return run
+
+
+def _host_nbytes(args) -> int:
+    """Bytes of the host arrays among a launch's arguments (a dict's
+    values counted each): what the jitted call has to move to the
+    device.  From ``nbytes``: nothing is copied to count it."""
+    n = 0
+    for a in args:
+        if isinstance(a, np.ndarray):
+            n += a.nbytes
+        elif isinstance(a, dict):
+            for v in a.values():
+                n += v.nbytes
+    return n
+
+
 class _AheadAbandoned(Exception):
     """A dispatch ahead of the in-flight launch's commit met something it
     may not do (``reason``: a key of ``ahead_fallbacks``): this call
@@ -848,6 +875,7 @@ class LLMEngine:
             cfg.experts_held for _, f in self._layer_kinds
             if f in ("moe", "moe_reglu"))
         self._launch_pages: dict = {}     # the latest launch's page counts
+        self._launch_call: dict = {}      # and, traced, its call_ns, arg_bytes
         self.stats = ServingStats()
         self.stats.set_decode_window(self.decode_window)
         self.stats.set_weight_residency(
@@ -861,6 +889,8 @@ class LLMEngine:
         # instrumentation seam is one attribute check and nothing else —
         # the same zero-cost contract the fault plan keeps
         self.tracer = None
+        self._blocked_ns = 0      # inside _complete's block, this step()
+        self._split = None        # the commit under way, by what a row calls
         self._trace_track = "engine"
         self._commit_step = 0         # the ticket's id while it commits
         self._cow_n = 0               # CoW launches and their host time
@@ -1334,6 +1364,24 @@ class LLMEngine:
             for t in toks:
                 req.on_token(req.rid, int(t))
 
+    def _row_calls(self) -> tuple:
+        """What a committed row calls: (the stream callbacks, the retire
+        check, the prefix cache's commit of a chunk, and of a decode
+        token).  ``_split``, set by the commit under way, is None (no
+        tracer: the bare methods, so a row pays nothing for the split)
+        or a list whose entries grow by the nanoseconds inside them: [0]
+        the callbacks, [1] both cache commits, [2] the retire check
+        (``engine.sample_commit``'s ``notify_ns``, ``cache_ns``,
+        ``retire_ns``)."""
+        calls = (self._notify_tokens, self._maybe_retire,
+                 self.blocks.commit_prefill,
+                 self.blocks.commit_decode_token)
+        split = self._split
+        if split is None:
+            return calls
+        return tuple(_timed(f, split, i)
+                     for f, i in zip(calls, (0, 2, 1, 1)))
+
     @property
     def num_decode_programs(self) -> int:
         """Ragged programs at the decode-sized bucket (Tq == max_num_seqs)."""
@@ -1793,10 +1841,15 @@ class LLMEngine:
         # ONE call site into _step, tracer or none: the line a program
         # is first reached from must not depend on who is watching
         tr = self.tracer
+        t0 = time.perf_counter_ns()          # the tracer's clock too
+        self._blocked_ns = 0
         if tr is not None:
-            t0 = tr.now()
             sid = self.launches + 1
         finished = self._step(tr)
+        # the turn: this call's work on the engine thread, which is its
+        # wall time less what _complete spent waiting on the chip
+        self.stats.record_turn(time.perf_counter_ns() - t0
+                               - self._blocked_ns)
         if tr is not None:
             tr.complete("engine.step", t0, track=self._trace_track,
                         args={"step": sid, "finished": len(finished)})
@@ -2099,11 +2152,13 @@ class LLMEngine:
         if tr is not None:
             t_c = tr.now()
             t = t_c
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         sampled = np.asarray(ticket.sampled)
         ok = np.asarray(ticket.fin)
         logits = np.asarray(ticket.logits) if ticket.spec else None
-        block_s = time.perf_counter() - t0
+        block_ns = time.perf_counter_ns() - t0
+        self._blocked_ns += block_ns
+        block_s = block_ns / 1e9
         # ONE host round-trip per completion, whether the launch carried
         # a single step or a whole K-token decode window — the ratio of
         # this counter to emitted tokens is the win the window buys
@@ -2176,8 +2231,10 @@ class LLMEngine:
         dur = ticket.dispatch_s + block_s
         self.stats.record_step(dur, dispatch_s=ticket.dispatch_s,
                                block_s=block_s)
-        if tr is not None:
-            t = tr.now()
+        t = time.perf_counter_ns()
+        # where the commit's time goes, by what a row calls (a tracer
+        # installed; else the rows call the bare methods: _row_calls)
+        split = self._split = None if tr is None else [0, 0, 0]
         if ticket.window:
             self._apply_window(batch, batch_slots, sampled, ok, dur,
                                finished, ticket.window,
@@ -2186,15 +2243,21 @@ class LLMEngine:
             self._apply_ragged(chunks, spec, batch, sampled, ok, spec_ok,
                                spec_logits, chunk_slots, batch_slots,
                                dur, finished)
-        commit_args = {"step": sid, "finished": len(finished)}
+        counted = None
         if ticket.counts is not None:
             mc = self.moe_counts
             counted = dict(zip(mc, map(int, np.asarray(ticket.counts))))
             for name, n in counted.items():
                 mc[name] = max(mc[name], n) if name == "moe_load_max" \
                     else mc[name] + n
-            commit_args.update(counted)
+        self.stats.record_commit(time.perf_counter_ns() - t)
         if tr is not None:
+            commit_args = {"step": sid, "finished": len(finished),
+                           "rows": len(chunks) + len(spec) + len(batch),
+                           "notify_ns": split[0], "cache_ns": split[1],
+                           "retire_ns": split[2]}
+            if counted is not None:
+                commit_args.update(counted)
             tr.complete("engine.sample_commit", t,
                         track=self._trace_track, args=commit_args)
             tr.complete("engine.complete", t_c, track=self._trace_track,
@@ -2230,6 +2293,8 @@ class LLMEngine:
         through the prefix cache or take down its batchmates.  The
         launch duration splits across the stats channels pro-rata by
         packed tokens."""
+        notify, retire, commit_chunk, commit_token = calls = \
+            self._row_calls()
         chunk_tokens = sum(n for _, n in chunks)
         spec_tokens = sum(len(d) + 1 for _, d, _ in spec)
         total = max(chunk_tokens + spec_tokens + len(batch), 1)
@@ -2243,7 +2308,7 @@ class LLMEngine:
                 continue
             req.cached += n
             if self.enable_prefix_caching:
-                self.blocks.commit_prefill(req.rid, n)
+                commit_chunk(req.rid, n)
             if tr is not None:
                 tr.instant("request.prefill_chunk",
                            track=self._trace_track,
@@ -2269,8 +2334,8 @@ class LLMEngine:
                                    track=self._trace_track,
                                    args={"rid": req.rid,
                                          "step": self._commit_step})
-                self._notify_tokens(req, (tok,))
-                self._maybe_retire(req, finished)
+                notify(req, (tok,))
+                retire(req, finished)
         if chunks:
             self.stats.record_prefill(dur * chunk_tokens / total,
                                       chunk_tokens, done)
@@ -2283,7 +2348,7 @@ class LLMEngine:
                     self._quarantine(req, finished)
                     continue
                 n_emitted += self._apply_spec_result(req, drafts, qd, lg,
-                                                     finished)
+                                                     finished, calls)
             self.stats.record_verify(dur * spec_tokens / total,
                                      n_emitted, occ)
 
@@ -2297,15 +2362,14 @@ class LLMEngine:
                 self._quarantine(req, finished)
                 continue
             if self.enable_prefix_caching:
-                self.blocks.commit_decode_token(req.rid,
-                                                req.generated[-1])
+                commit_token(req.rid, req.generated[-1])
             req.cached += 1
             tok = int(sampled[s])
             req.generated.append(tok)
             if req.seen is not None:
                 req.seen[tok] = True
-            self._notify_tokens(req, (tok,))
-            self._maybe_retire(req, finished)
+            notify(req, (tok,))
+            retire(req, finished)
 
     # ------------------------------------------------------------------
     # device-resident decode window (decode_window > 1)
@@ -2436,6 +2500,7 @@ class LLMEngine:
                         args={"step": sid, "bucket": B, "tokens": n,
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n, "window": kp,
+                              **self._launch_call,
                               "sample_chain": chain,
                               **self._ahead_args()})
         now = time.perf_counter()
@@ -2464,6 +2529,7 @@ class LLMEngine:
         still arrives [decode_window, B] wide (the compiled driver's
         static K), so the drain MUST stop at K' or the budget-frozen
         rows would commit their repeated filler columns."""
+        notify, retire, _, commit_token = self._row_calls()
         K = min(int(sampled.shape[0]), int(window))
         occ = len(self._running) / self.max_num_seqs
         alive = {req.rid for req in batch}
@@ -2481,16 +2547,15 @@ class LLMEngine:
                     self._quarantine(req, finished)
                     continue
                 if self.enable_prefix_caching:
-                    self.blocks.commit_decode_token(req.rid,
-                                                    req.generated[-1])
+                    commit_token(req.rid, req.generated[-1])
                 req.cached += 1
                 tok = int(sampled[i, s])
                 req.generated.append(tok)
                 if req.seen is not None:
                     req.seen[tok] = True
                 committed += 1
-                self._notify_tokens(req, (tok,))
-                self._maybe_retire(req, finished)
+                notify(req, (tok,))
+                retire(req, finished)
                 if not self._is_running(req):
                     alive.discard(req.rid)
         self.pad_stats["real"] += committed
@@ -3012,13 +3077,17 @@ class LLMEngine:
             ok.append((req, drafts, qd))
         return ok, demoted
 
-    def _apply_spec_result(self, req, drafts, qd, lg, finished) -> int:
+    def _apply_spec_result(self, req, drafts, qd, lg, finished,
+                           calls) -> int:
         """Turn one sequence's verify logits into emitted tokens: run
         rejection-sampling acceptance, commit the accepted prefix's K/V,
         truncate the rejected tail out of the page table (scrubbing its
         content hashes), and advance the request exactly as that many
-        plain decode steps would have.  Returns tokens emitted."""
+        plain decode steps would have.  Returns tokens emitted.
+        ``calls``: ``_row_calls``."""
         from .spec_decode import verify_and_accept
+
+        notify, retire, _, commit_token = calls
 
         k = len(drafts)
         rng = None
@@ -3045,7 +3114,7 @@ class LLMEngine:
         # written by the NEXT step (decode invariant), not this one.
         if self.enable_prefix_caching:
             for tok in [req.generated[-1]] + emitted[:m - 1]:
-                self.blocks.commit_decode_token(req.rid, tok)
+                commit_token(req.rid, tok)
         req.cached += m
         # roll the speculative tail (rejected drafts + over-reserved
         # pages) back out of the table; prefix-cache hashes covering
@@ -3054,7 +3123,7 @@ class LLMEngine:
         req.generated.extend(emitted)
         if req.seen is not None:
             req.seen[emitted] = True
-        self._notify_tokens(req, emitted)
+        notify(req, emitted)
         j = m - 1 if m == n_acc + 1 else m            # emitted draft count
         if k:                                         # zero-draft rows are
             req.spec_proposed += k                    # plain decode riding
@@ -3073,7 +3142,7 @@ class LLMEngine:
                 self.stats.record_spec_disable()
             self.drafter.commit(
                 req.rid, len(req.prompt) + len(req.generated) - (m - j))
-        self._maybe_retire(req, finished)
+        retire(req, finished)
         return m
 
     # ------------------------------------------------------------------
@@ -3300,7 +3369,12 @@ class LLMEngine:
         (over int8 pages the fresh-page mask after them) and the
         launch's ``host_args``; the pools that come back are kept and
         the outputs before them returned.  It counts the launch (the
-        step id; ahead or, by reason, not) and, with a tracer installed,
+        step id; ahead or, by reason, not), times the call alone and
+        sums the bytes of the host arrays it is handed (``summary()``
+        ``launch_call_time_s``, ``launch_arg_bytes``; ``call_ns`` and
+        ``arg_bytes`` on ``engine.device_launch``: nothing is staged or
+        placed differently for the reading, so the transfer and the
+        dispatch stay one number) and, with a tracer installed,
         brackets the call in one
         ``engine.launch`` annotation carrying that id, so the profiler's
         own trace holds a host event a step that joins a device
@@ -3311,6 +3385,7 @@ class LLMEngine:
         if self.kv_dtype == "int8":
             args += (self._consume_fresh(),)
         args += tuple(host_args)
+        arg_bytes = _host_nbytes(args[1 + len(pools):])
         self.launches += 1
         if self._ahead_of is not None:
             self.launches_ahead += 1
@@ -3322,7 +3397,14 @@ class LLMEngine:
                                          step=self.launches,
                                          bucket=int(bucket))
         with note:
+            t0 = time.perf_counter_ns()
             out = prog(*args)
+            call_ns = time.perf_counter_ns() - t0
+        self.stats.record_launch_call(call_ns, arg_bytes)
+        if self.tracer is not None:
+            # what engine.device_launch says of the call inside it
+            self._launch_call = {"call_ns": call_ns,
+                                 "arg_bytes": arg_bytes}
         self._set_pools(out[-len(pools):])
         return out[:-len(pools)]
 
@@ -3656,6 +3738,7 @@ class LLMEngine:
                               "chunks": len(chunks), "decode": len(batch),
                               "logit_rows": logit_rows,
                               **self._launch_pages,
+                              **self._launch_call,
                               "sample_chain": _sample_chain(samp),
                               **self._ahead_args()})
         # NO materialization here: sampled/logits/fin return as async
@@ -3743,6 +3826,7 @@ class LLMEngine:
                               "rows": n, "chunks": 0, "decode": n,
                               "logit_rows": n,
                               **self._launch_pages,
+                              **self._launch_call,
                               "sample_chain": _sample_chain(samp),
                               **self._ahead_args()})
         self._d_cur = bi

@@ -228,6 +228,17 @@ class ServingStats:
         self.block_time = 0.0
         self._dispatch_lat = _Reservoir(r, seed=5)
         self._block_lat = _Reservoir(r, seed=6)
+        # the turn, read where it happens (always on, integer
+        # nanoseconds of perf_counter_ns): the engine thread's own work
+        # of every step() call (its wall time less the completion
+        # block), and of it the jitted call alone and the commit alone;
+        # block_time against turn_ns says whether a replica waits on its
+        # host or on its chip.  launch_arg_bytes: host arrays handed to
+        # the jitted calls, summed over launches
+        self.turn_ns = 0
+        self.launch_call_ns = 0
+        self.commit_ns = 0
+        self.launch_arg_bytes = 0
         # device-resident decode-window surface (PR 16): how often the
         # host actually blocked on the device, and how many tokens each
         # block drained — the round-trip amortization the K-step window
@@ -333,11 +344,14 @@ class ServingStats:
         pack/stage/launch/sync section regardless of phase mix.
 
         ``dispatch_s``/``block_s`` split that duration into the host
-        dispatch section (admit/schedule/pack/stage/enqueue, which the
-        async engine runs while the previous launch is still on-device)
-        and the completion block (materializing device results).  A
-        caller that can't attribute the split leaves both at 0; the
-        fused total stays authoritative either way."""
+        dispatch section (pack/stage/enqueue: from the rows standing
+        chosen to the jitted call's return, which the async engine runs
+        while the previous launch is still on-device; admission and
+        scheduling lie before it and are in ``record_turn``'s time
+        only) and the completion block (materializing device results:
+        the one place the engine thread waits on the chip).  A caller
+        that can't attribute the split leaves both at 0; the fused
+        total stays authoritative either way."""
         d = float(duration_s)
         self.engine_steps += 1
         self.step_time += d
@@ -349,6 +363,23 @@ class ServingStats:
         w = self._windows
         if w is not None:
             w.record_step(d)
+
+    def record_turn(self, turn_ns: int) -> None:
+        """One ``step()`` call's work on the engine thread: its wall
+        time less what it spent inside the completion block."""
+        self.turn_ns += turn_ns
+
+    def record_launch_call(self, call_ns: int, arg_bytes: int) -> None:
+        """One jitted call of a step program: the time inside the call
+        (the host-to-device transfer of its host arrays and the jit
+        dispatch) and the bytes of those host arrays."""
+        self.launch_call_ns += call_ns
+        self.launch_arg_bytes += arg_bytes
+
+    def record_commit(self, commit_ns: int) -> None:
+        """One launch's commit: applying its rows (cache commit, stream
+        callbacks, retirement) and reading its expert counts."""
+        self.commit_ns += commit_ns
 
     def record_round_trip(self, n: int = 1) -> None:
         """One host<->device completion block: the host materialized a
@@ -693,6 +724,10 @@ class ServingStats:
             "step_time_s": round(self.step_time, 6),
             "dispatch_time_s": round(self.dispatch_time, 6),
             "block_time_s": round(self.block_time, 6),
+            "turn_time_s": round(self.turn_ns / 1e9, 6),
+            "launch_call_time_s": round(self.launch_call_ns / 1e9, 6),
+            "commit_time_s": round(self.commit_ns / 1e9, 6),
+            "launch_arg_bytes": self.launch_arg_bytes,
             "dispatch_ms_p50": round(1e3 * self._dispatch_lat.percentile(50), 3),
             "dispatch_ms_p99": round(1e3 * self._dispatch_lat.percentile(99), 3),
             "block_ms_p50": round(1e3 * self._block_lat.percentile(50), 3),
