@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import re
 import time
 from collections import deque
@@ -168,9 +169,13 @@ class _StepTicket:
     chunks: list
     spec: list
     batch: list
-    sampled: object                   # device array (async, not blocked)
+    sampled: object                   # device array the host never reads:
+                                      # the next launch's ``prev`` (None
+                                      # of a decode-window launch)
+    packed: object                    # device array, on its way to the
+                                      # host since the launch: all the
+                                      # host reads of it (_pack_results)
     logits: object                    # device array | None
-    fin: object                       # device array
     spec_slices: list
     chunk_slots: list
     batch_slots: list
@@ -181,9 +186,8 @@ class _StepTicket:
                                       # when it was dispatched
     inflight: bool = False            # crossed a step() boundary in flight
     window: int = 0                   # K of a decode-window launch (0 =
-                                      # per-step; sampled/fin are [K, B])
-    counts: object = None             # device array | None: what the
-                                      # launch's expert layers counted
+                                      # per-step; the packed grids are
+                                      # [K, B])
     sample_chain: int = 0             # 1 if a window launch held a sampled
                                       # row (its drain counts the passes)
     slot_of: dict = field(default_factory=dict)   # rid -> logit row, of
@@ -377,6 +381,28 @@ def _host_nbytes(args) -> int:
             for v in a.values():
                 n += v.nbytes
     return n
+
+
+def _pack_results(sampled, fin, counts=None):
+    """Everything the HOST reads of a launch, in one int32 vector: the
+    sampled tokens, the finiteness flags as 0/1 and, of a model with
+    expert layers, what they counted.  One array is one device-to-host
+    transfer, which the launch starts itself (``copy_to_host_async``)
+    the moment it is dispatched.  Traced inside a step program."""
+    parts = [sampled.reshape(-1), fin.reshape(-1).astype(jnp.int32)]
+    if counts is not None:
+        parts.append(counts)
+    return jnp.concatenate(parts)
+
+
+def _unpack_results(host, shape):
+    """``_pack_results``' vector, on the host, as (sampled, finiteness
+    flags, expert counts): the first two of ``shape`` ([Lq] of a step,
+    [K, B] of a decode window), the counts whatever lies behind them
+    (empty where nothing was counted)."""
+    n = math.prod(shape)
+    return (host[:n].reshape(shape), host[n:2 * n].reshape(shape) != 0,
+            host[2 * n:])
 
 
 class _AheadAbandoned(Exception):
@@ -864,13 +890,15 @@ class LLMEngine:
         # carries (the dispatch half of a step the id of the launch it
         # prepares, launches + 1; the completion half its ticket's)
         self.launches = 0
+        # completions that found the launch's execution already ended
+        # when they came to read it (_complete)
+        self.reads_ready = 0
         self._evictions_seen = 0
         self.peak_resident_seqs = 0
         # what the step's expert layers counted (in the order the step
         # program returns them), summed at completion
         self.moe_counts = {"moe_pairs_here": 0, "moe_pairs_all": 0,
                            "moe_experts_touched": 0, "moe_load_max": 0}
-        self._launch_counts = None
         self._experts_held = sum(
             cfg.experts_held for _, f in self._layer_kinds
             if f in ("moe", "moe_reglu"))
@@ -1174,9 +1202,9 @@ class LLMEngine:
         index, sampling pytree — plus the fresh-page mask in int8 mode)
         replicate, a single P() covering each pytree by prefix.  Every
         one of the ``n_front`` non-pool outputs (default, the ragged
-        step's: sampled tokens, finiteness flags and, with a drafter,
-        logits; the window's two [K, B] grids) is genuinely replicated
-        after the in-step all-gathers, so its out_spec is P().
+        step's: sampled tokens, the vector the host reads and, with a
+        drafter, logits; the window's one packed vector) is genuinely
+        replicated after the in-step all-gathers, so its out_spec is P().
 
         check_vma=False: the body mixes replicated and sharded operands
         and resolves them with explicit all-gathers, the same contract
@@ -1502,6 +1530,10 @@ class LLMEngine:
         # of them was committed, the others by why not, and the rows
         # such a launch held of a request that commit retired
         out["launches"] = self.launches
+        # launches whose execution had ended when the host came to read
+        # them: the copy to the host, started at dispatch, had its head
+        # start and the host, not the chip, was the pace of that launch
+        out["reads_ready"] = self.reads_ready
         out["launches_ahead"] = self.launches_ahead
         out["ahead_fallbacks"] = dict(self.ahead_fallbacks)
         out["ahead_rows_dropped"] = self.ahead_rows_dropped
@@ -2092,7 +2124,7 @@ class LLMEngine:
                     and self._window_eligible(batch)):
                 ticket = self._dispatch_window(batch, tr, t0)
             if ticket is None:
-                sampled, logits, fin, spec_slices, chunk_slots, \
+                sampled, packed, logits, spec_slices, chunk_slots, \
                     batch_slots = self._run_ragged(chunks, spec, batch)
                 now = time.perf_counter()
                 slot_of = {r.rid: s for (r, _), s
@@ -2101,13 +2133,13 @@ class LLMEngine:
                                for r, s in zip(batch, batch_slots))
                 ticket = _StepTicket(
                     chunks=chunks, spec=spec, batch=batch,
-                    sampled=sampled, logits=logits, fin=fin,
+                    sampled=sampled, packed=packed, logits=logits,
                     spec_slices=spec_slices, chunk_slots=chunk_slots,
                     batch_slots=batch_slots, dispatch_s=now - t0,
                     t_launch=now,
                     launch_ns=tr.now() if tr is not None else 0,
                     step=self.launches, inflight=self.overlap,
-                    counts=self._launch_counts, slot_of=slot_of)
+                    slot_of=slot_of)
         if tr is not None:
             tr.complete("engine.dispatch", t_d, track=self._trace_track,
                         args={"step": sid, "chunks": len(chunks),
@@ -2118,10 +2150,15 @@ class LLMEngine:
         return ticket
 
     def _complete(self, tr, finished: list, drop_rid=None) -> None:
-        """Block on the in-flight ticket and commit it: materialize the
-        sampled tokens / finiteness flags / verify logits, run the NaN
-        seam over the live rows, split the step timing into its
-        dispatch/block halves, and apply + retire.
+        """Block on the in-flight ticket and commit it: ONE read of the
+        packed vector the launch sent on its way at dispatch (sampled
+        tokens, finiteness flags, expert counts: sliced here, on the
+        host; the verify logits beside it where the launch holds spec
+        rows), the NaN seam over the live rows, the step timing split
+        into its dispatch/block halves, then apply + retire.  Whether
+        the execution had ended when the host asked is counted
+        (``reads_ready``) and said on ``engine.block_on_result``
+        (``ready``).
 
         ``drop_rid`` (abort-while-in-flight) discards that request's
         packed rows unapplied: no token commit, no retirement, leaving
@@ -2152,20 +2189,29 @@ class LLMEngine:
         if tr is not None:
             t_c = tr.now()
             t = t_c
+        # had the execution ended when the host got here?  Then the
+        # copy the launch started has had its head start, and the host
+        # was the pace of this launch
+        ready = ticket.packed.is_ready()
+        self.reads_ready += ready
         t0 = time.perf_counter_ns()
-        sampled = np.asarray(ticket.sampled)
-        ok = np.asarray(ticket.fin)
+        host = np.asarray(ticket.packed)
         logits = np.asarray(ticket.logits) if ticket.spec else None
         block_ns = time.perf_counter_ns() - t0
         self._blocked_ns += block_ns
         block_s = block_ns / 1e9
-        # ONE host round-trip per completion, whether the launch carried
-        # a single step or a whole K-token decode window — the ratio of
-        # this counter to emitted tokens is the win the window buys
+        sampled, ok, counts = _unpack_results(
+            host, (self.decode_window, self.max_num_seqs) if ticket.window
+            else (self._Lq,))
+        # ONE host round-trip per completion (one device-to-host read:
+        # the packed vector), whether the launch carried a single step
+        # or a whole K-token decode window — the ratio of this counter
+        # to emitted tokens is the win the window buys
         self.stats.record_round_trip()
         if tr is not None:
             tr.complete("engine.block_on_result", t,
-                        track=self._trace_track, args={"step": sid})
+                        track=self._trace_track,
+                        args={"step": sid, "ready": ready})
             if ticket.launch_ns and ticket.inflight:
                 # X event spanning launch -> materialized: the window
                 # host work can hide inside (step_timeline.py intersects
@@ -2244,9 +2290,9 @@ class LLMEngine:
                                spec_logits, chunk_slots, batch_slots,
                                dur, finished)
         counted = None
-        if ticket.counts is not None:
+        if counts.size:
             mc = self.moe_counts
-            counted = dict(zip(mc, map(int, np.asarray(ticket.counts))))
+            counted = dict(zip(mc, map(int, counts)))
             for name, n in counted.items():
                 mc[name] = max(mc[name], n) if name == "moe_load_max" \
                     else mc[name] + n
@@ -2491,9 +2537,10 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
         chain = _sample_chain(samp)
-        toks_out, fin_out = self._call_program(
+        (packed,) = self._call_program(
             self._get_window_prog(),
             (toks, kvl, active, gen, budgets, eos_ids, base_keys, bt, samp), B)
+        packed.copy_to_host_async()
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
@@ -2505,8 +2552,8 @@ class LLMEngine:
                               **self._ahead_args()})
         now = time.perf_counter()
         return _StepTicket(
-            chunks=[], spec=[], batch=list(batch), sampled=toks_out,
-            logits=None, fin=fin_out, spec_slices=[], chunk_slots=[],
+            chunks=[], spec=[], batch=list(batch), sampled=None,
+            packed=packed, logits=None, spec_slices=[], chunk_slots=[],
             batch_slots=list(range(n)), dispatch_s=now - t0,
             t_launch=now, launch_ns=tr.now() if tr is not None else 0,
             step=self.launches, inflight=self.overlap, window=kp,
@@ -3280,12 +3327,14 @@ class LLMEngine:
         commit time and attention dequantizes at read time), then ragged
         paged attention lets every token attend to its own row's pages
         causally.  Sampled tokens come back for the logit rows in
-        ``lidx``; with a drafter the raw [Lq, V] logits ride along for
-        host-side draft acceptance; a model with expert layers also
-        returns what they counted.  A token the host does not have yet
-        (the sample of the launch in front, when this one is dispatched
-        ahead of its commit) is taken on the device: ``src`` names its
-        logit row in ``prev``, that launch's sampled tokens.
+        ``lidx``, once as they are (the next launch's ``prev``) and
+        once more in the ONE vector the host reads, with the finiteness
+        flags and what a model's expert layers counted behind them
+        (``_pack_results``); with a drafter the raw [Lq, V] logits ride
+        along for host-side draft acceptance.  A token the host does not
+        have yet (the sample of the launch in front, when this one is
+        dispatched ahead of its commit) is taken on the device: ``src``
+        names its logit row in ``prev``, that launch's sampled tokens.
 
         The forward is ``layer_stack.forward`` for every model and page
         type (inference/layer_stack.py): the dense decoder is one
@@ -3342,11 +3391,11 @@ class LLMEngine:
                 # (padded rows may be legitimately non-finite; the host
                 # only consults live slots)
                 fin = jnp.all(jnp.isfinite(logits), axis=-1)  # [Lq]
-            out = (sampled, fin)
+                # sampled stays on the device for the next launch
+                # (``prev``); the host reads the packed vector alone
+                out = (sampled, _pack_results(sampled, fin, counts))
             if with_logits:
                 out += (logits,)
-            if counts is not None:
-                out += (counts,)
             return out + tuple(pools)
 
         # donation reuses the pool buffers (pages and scales) in place;
@@ -3435,7 +3484,10 @@ class LLMEngine:
                        real_tokens, src=None):
         """One launch of the step program at bucket ``Tq``.  ``src``:
         the rows of the in-flight launch's ``sampled`` that ``toks``
-        takes on the device (None: every token is staged in ``toks``)."""
+        takes on the device (None: every token is staged in ``toks``).
+        Returns the launch's ``sampled`` (for the next launch), the
+        packed vector the host reads, already on its way, and the verify
+        logits or None: unmaterialized device arrays."""
         ahead = self._ahead_of
         prev = self._no_prev if ahead is None else ahead.sampled
         if src is None:
@@ -3448,14 +3500,13 @@ class LLMEngine:
         self.pad_stats["kv_pages_window"] += pages.get("kv_pages_window", 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
-        out = self._call_program(
+        sampled, packed, *logits = self._call_program(
             self._get_ragged_prog(Tq),
             (toks, cu, kvl, bt, lidx, samp, prev, src), Tq)
-        sampled, fin = out[0], out[1]
-        # what the step's expert layers counted rides to the completion
-        # half unmaterialized, like the tokens
-        self._launch_counts = out[2] if self._has_experts else None
-        return sampled, (out[2] if self._with_logits else None), fin
+        # the transfer starts when the execution ends, with nobody
+        # asking: the host finds the vector there when it comes to read
+        packed.copy_to_host_async()
+        return sampled, packed, (logits[0] if logits else None)
 
     def _kv_pages(self, kvl) -> int:
         """Pages a launch's rows hold keys in: what the attention kernel
@@ -3530,7 +3581,9 @@ class LLMEngine:
         host-side).  Frozen rows redirect to the sentinel block-table
         row via ``decode_window_segments`` so their writes land in the
         null page like ragged padding; the loop exits early once every
-        row froze.  The host drains the [K, B] token grid afterwards —
+        row froze.  The host drains the [K, B] token grid afterwards (it
+        comes back with the finiteness grid in one vector, as a step's
+        results do: ``_pack_results``) —
         logits and tokens never leave the device mid-window, which is
         the whole point.
 
@@ -3601,14 +3654,14 @@ class LLMEngine:
                      jnp.zeros((K, B), jnp.int32),
                      jnp.ones((K, B), jnp.bool_))
             carry = lax.while_loop(cond, step, carry)
-            return carry[7:] + tuple(carry[6])
+            return (_pack_results(*carry[7:]),) + tuple(carry[6])
 
-        # both non-pool outputs (the [K, B] token and finiteness grids)
-        # are replicated after the in-body all-gathers — every shard's
-        # while_loop sees identical replicated logits, so the
+        # the one non-pool output (the [K, B] token and finiteness grids,
+        # packed) is replicated after the in-body all-gathers — every
+        # shard's while_loop sees identical replicated logits, so the
         # active-mask and the early-exit condition agree across shards
         # by construction
-        return self._wrap_tp(run, 9 + q8, 2), tuple(range(1, 1 + n_pools))
+        return self._wrap_tp(run, 9 + q8, 1), tuple(range(1, 1 + n_pools))
 
     def _fill_samp(self, samp, s, req):
         samp["temps"][s] = req.temperature
@@ -3633,10 +3686,11 @@ class LLMEngine:
 
         Row order: prefill chunks (scheduler order), speculative
         [last_token, drafts...] windows, plain decode tokens (slot
-        order).  Returns (sampled tokens, per-spec-row logits or None,
-        per-logit-row finite flags, spec row slices, chunk logit slots,
-        decode logit slots) — the first three are UNMATERIALIZED device
-        arrays the caller's completion ticket blocks on later."""
+        order).  Returns (sampled tokens, the packed vector the host
+        reads, per-spec-row logits or None, spec row slices, chunk logit
+        slots, decode logit slots) — the first three are UNMATERIALIZED
+        device arrays: the next launch takes the first, the caller's
+        completion ticket blocks on the other two later."""
         total = sum(n for _, n in chunks) \
             + sum(len(d) + 1 for _, d, _ in spec) + len(batch)
         Tq = self._ragged_bucket(total)
@@ -3723,8 +3777,13 @@ class LLMEngine:
 
         if tr is not None:
             t = tr.now()
-        sampled, logits, fin = self._launch_ragged(Tq, toks, cu, kvl, bt,
-                                                   lidx, samp, total, src)
+        sampled, packed, logits = self._launch_ragged(
+            Tq, toks, cu, kvl, bt, lidx, samp, total, src)
+        if spec:
+            # the verify logits are read too: on their way as well
+            logits.copy_to_host_async()
+        else:
+            logits = None
         if tr is not None:
             # logit rows whose result is used: a chunk that ends its
             # prompt, every verify position, every decode row
@@ -3741,12 +3800,11 @@ class LLMEngine:
                               **self._launch_call,
                               "sample_chain": _sample_chain(samp),
                               **self._ahead_args()})
-        # NO materialization here: sampled/logits/fin return as async
+        # NO materialization here: sampled/packed/logits return as async
         # device arrays; _complete blocks on them (the dispatch path
         # must never force a host sync on step-program outputs)
-        if not spec:
-            logits = None
-        return sampled, logits, fin, spec_slices, chunk_slots, batch_slots
+        return sampled, packed, logits, spec_slices, chunk_slots, \
+            batch_slots
 
     def _run_ragged_decode(self, batch: list, Tq: int):
         """Pure-decode launch over the persistent host buffers.  Rows
@@ -3815,10 +3873,10 @@ class LLMEngine:
         self.pad_stats["legacy_padded"] += self.max_num_seqs
         if tr is not None:
             t = tr.now()
-        sampled, _, fin = self._launch_ragged(Tq, buf.toks, buf.cu,
-                                              buf.kvl, buf.bt,
-                                              self._d_lidx, samp, n,
-                                              buf.src)
+        sampled, packed, _ = self._launch_ragged(Tq, buf.toks, buf.cu,
+                                                 buf.kvl, buf.bt,
+                                                 self._d_lidx, samp, n,
+                                                 buf.src)
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,
@@ -3830,7 +3888,7 @@ class LLMEngine:
                               "sample_chain": _sample_chain(samp),
                               **self._ahead_args()})
         self._d_cur = bi
-        return sampled, None, fin, [], [], list(range(n))
+        return sampled, packed, None, [], [], list(range(n))
 
     def _inject_nan(self, ok, live_slots: list):
         """FaultPlan NaN seam: corrupt one LIVE logit row's finiteness

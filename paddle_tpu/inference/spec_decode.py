@@ -197,9 +197,9 @@ class DraftModelDrafter(Drafter):
         bt[0] = eng.blocks.padded_table(rid, eng.nblk)
         lidx = np.asarray([g - 1], np.int32)
         samp = make_samp(1, eng.config.vocab_size)    # greedy defaults
-        sampled, _, _ = eng._launch_ragged(Tq, toks, cu, kvl, bt, lidx,
-                                           samp, g)
-        return int(np.asarray(sampled)[0])
+        _, packed, _ = eng._launch_ragged(Tq, toks, cu, kvl, bt, lidx,
+                                          samp, g)
+        return int(np.asarray(packed)[0])      # row 0's sampled token
 
     def _decode(self, rid, tok, pos):
         # a decode token is just a one-token ragged row (same program)
@@ -211,9 +211,9 @@ class DraftModelDrafter(Drafter):
         bt[0] = eng.blocks.padded_table(rid, eng.nblk)
         lidx = np.zeros((1,), np.int32)
         samp = make_samp(1, eng.config.vocab_size)    # greedy defaults
-        sampled, _, _ = eng._launch_ragged(eng._ragged_bucket(1), toks,
-                                           cu, kvl, bt, lidx, samp, 1)
-        return int(np.asarray(sampled)[0])
+        _, packed, _ = eng._launch_ragged(eng._ragged_bucket(1), toks,
+                                          cu, kvl, bt, lidx, samp, 1)
+        return int(np.asarray(packed)[0])      # row 0's sampled token
 
 
 def verify_and_accept(logits, drafts, *, q_dists=None, temperature=0.0,

@@ -48,6 +48,46 @@ def reference_tree():
     return root
 
 
+@pytest.fixture
+def launch_results(monkeypatch):
+    """Every step launch of the engines a test builds after asking for
+    this, one dict each: ``kind`` (``mixed``: a chunk beside decode
+    rows; ``decode_only``: every row of the batch a decode row;
+    ``padding_rows``: decode rows and rows to spare; else ``other``),
+    ``front`` (the device arrays it gave back before the pools) and
+    ``parts``, the sampled tokens, finiteness flags and, of a model with
+    expert layers, counts as the DEVICE had them, taken where the step
+    program packs them into the one vector the host reads
+    (``serving._pack_results``)."""
+    import numpy as np
+    from paddle_tpu.inference import serving
+
+    parts, seen = [], []
+    real_pack = serving._pack_results
+    real_call = serving.LLMEngine._call_program
+
+    def pack(*results):
+        jax.debug.callback(
+            lambda *a: parts.append([np.asarray(x) for x in a]),
+            *(r for r in results if r is not None), ordered=True)
+        return real_pack(*results)
+
+    def call(self, prog, host_args, bucket):
+        q = np.diff(host_args[1])            # a step's ``cu``
+        chunks, rows = int((q > 1).sum()), int((q > 0).sum())
+        kind = "mixed" if 0 < chunks < rows else "other" if chunks \
+            else "decode_only" if rows == self.max_num_seqs \
+            else "padding_rows"
+        front = real_call(self, prog, host_args, bucket)
+        jax.effects_barrier()
+        seen.append({"kind": kind, "front": front, "parts": parts.pop()})
+        return front
+
+    monkeypatch.setattr(serving, "_pack_results", pack)
+    monkeypatch.setattr(serving.LLMEngine, "_call_program", call)
+    return seen
+
+
 def pytest_configure(config):
     """Register the graft-lint plugin HERE, not via addopts -p: a
     command-line plugin imports before this conftest pins

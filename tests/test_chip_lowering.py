@@ -471,14 +471,41 @@ def test_the_sampled_chain_compiles_into_one_branch(
 
 
 @pytest.mark.parametrize("target", ["cpu", "v5e"])
+@pytest.mark.parametrize("arch", ["llama_dense", "mla_moe"])
+def test_the_step_program_hands_the_host_one_small_vector(
+        arch, target, request, no_persistent_cache):
+    """Beside ``sampled`` (which the launch behind takes on the device)
+    and the pools, a compiled step program has ONE output, int32 and a
+    few hundred bytes: the tokens, the finiteness flags as 0/1 and, of a
+    model with expert layers, its four counts (PR 38): one
+    device-to-host transfer a launch, which the launch itself starts."""
+    Tq = 16
+    eng = _tiny_engine(arch)
+    structs = eng._ragged_arg_structs(Tq, placed=target == "cpu")
+    if target == "v5e":
+        chip = request.getfixturevalue("one_chip")
+        structs = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+            structs)
+    compiled = eng._get_ragged_prog(Tq).lower(*structs).compile()
+    pools = eng._pools()
+    front = compiled.out_info[:-len(pools)]
+    assert [(o.shape, o.dtype) for o in compiled.out_info[-len(pools):]] \
+        == [(p.shape, p.dtype) for p in pools]
+    Lq, counted = eng._Lq, 4 * (arch == "mla_moe")
+    assert [(o.shape, str(o.dtype)) for o in front] \
+        == [((Lq,), "int32"), ((2 * Lq + counted,), "int32")]
+    assert (2 * Lq + counted) * 4 < 1024
+
+
+@pytest.mark.parametrize("target", ["cpu", "v5e"])
 def test_the_token_handover_is_a_few_small_operations(
         target, request, no_persistent_cache):
     """What a launch dispatched ahead of the commit in front of it adds
     to the step program (PR 34): ``toks = where(src >= 0, prev[src],
     toks)`` under scope ``prev_tokens``, on [Tq] and [Lq] int32 and
     nothing larger (the TPU pads the index vector to one tile of 1024
-    words), before the embedding; ``prev`` is not donated (the host
-    still reads it at that launch's commit)."""
+    words), before the embedding; ``prev`` is not donated."""
     from paddle_tpu.inference.serving import _instruction_scopes
     Tq = 16
     eng = _tiny_engine("llama_dense")

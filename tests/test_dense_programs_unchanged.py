@@ -444,6 +444,28 @@ def _with_prev(args, seed=1):
     return args[:-6] + (staged,) + args[-5:] + (prev, src)
 
 
+def _as_frozen(out, n_pools, step):
+    """The live program's outputs in the frozen program's layout.  Since
+    PR 38 tokens and finiteness flags reach the host in one int32 vector
+    (``serving._pack_results``): a step returns it beside ``sampled``
+    (which it must repeat), the decode window returns it alone."""
+    from paddle_tpu.inference.serving import _unpack_results
+    front, pools = out[:-n_pools], out[-n_pools:]
+    if step:
+        sampled, packed, *logits = front
+        toks, fin, counts = _unpack_results(np.asarray(packed),
+                                            sampled.shape)
+        np.testing.assert_array_equal(toks, np.asarray(sampled))
+    else:
+        (packed,), logits = front, []
+        B = 4                                   # _engine's max_num_seqs
+        toks, fin, counts = _unpack_results(
+            np.asarray(packed), (packed.shape[0] // (2 * B), B))
+    assert packed.dtype == np.int32 and toks.dtype == np.int32
+    assert not counts.size          # a dense model counts nothing
+    return (toks, fin, *logits, *pools)
+
+
 def _same_results(new, old, args, live_args=None):
     """Both programs on the same launch (``live_args``: the live
     program's spelling of it, where it takes more than the frozen one):
@@ -451,7 +473,8 @@ def _same_results(new, old, args, live_args=None):
     pool) bit for bit."""
     (new, donate), (old, old_donate) = new, old
     assert tuple(donate) == tuple(old_donate)
-    got = jax.jit(new)(*(live_args or args))
+    got = _as_frozen(jax.jit(new)(*(live_args or args)), len(donate),
+                     live_args is not None)
     want = jax.jit(old)(*args)
     assert len(got) == len(want)
     for g, w in zip(got, want):

@@ -6,7 +6,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from paddle_tpu.inference import BlockManager, LLMEngine
+import paddle_tpu as paddle
+from paddle_tpu.inference import BlockManager, LLMEngine, serving
 from paddle_tpu.inference.kv_cache import NULL_BLOCK
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
@@ -283,3 +284,216 @@ def test_engine_rejects_oversized_request(model):
         eng.add_request(list(range(30)), max_new_tokens=60)   # > max_model_len
     with pytest.raises(ValueError):
         eng.add_request([], max_new_tokens=4)
+
+
+# ---------------------------------------------------------------------------
+# a launch's results reach the host in one array, on its way since dispatch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,n_counts", [((12,), 0), ((12,), 4),
+                                            ((3, 4), 0)])
+def test_pack_and_unpack_are_inverse(shape, n_counts):
+    """``_pack_results`` on the device, ``_unpack_results`` on the host:
+    a step's [Lq] rows with and without expert counts behind them, a
+    decode window's [K, B] grids; flags that are False come back False."""
+    rng = np.random.RandomState(3)
+    sampled = rng.randint(0, 64000, shape).astype(np.int32)
+    fin = rng.rand(*shape) < 0.5
+    assert fin.any() and not fin.all()
+    counts = rng.randint(0, 9000, n_counts).astype(np.int32)
+    packed = serving._pack_results(jnp.asarray(sampled), jnp.asarray(fin),
+                                   jnp.asarray(counts) if n_counts else None)
+    assert packed.dtype == jnp.int32
+    assert packed.shape == (2 * sampled.size + n_counts,)
+    got = serving._unpack_results(np.asarray(packed), shape)
+    assert got[1].dtype == np.bool_
+    for g, w in zip(got, (sampled, fin, counts)):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("kind", ["mixed", "decode_only", "padding_rows"])
+def test_the_packed_vector_holds_the_launchs_results(model, launch_results,
+                                                     kind):
+    """What the host reads of a launch is that launch's ``sampled`` and
+    finiteness flags, slice for slice: in a launch with a chunk beside
+    decode rows, in one of decode rows alone, in one with rows to
+    spare; and ``sampled`` itself still comes back first, for the launch
+    behind to take on the device."""
+    rng = np.random.RandomState(41)
+    n_req = 4 if kind == "decode_only" else 2
+    eng = _engine(model, max_prefill_tokens=8, prefill_token_bucket=8)
+    prompts = [rng.randint(0, VOCAB, n).tolist()
+               for n in (5, 7, 6, 4)[:n_req]]
+    rids = [eng.add_request(p, max_new_tokens=9) for p in prompts]
+    late = rng.randint(0, VOCAB, 19).tolist()
+    n = 0
+    while eng.has_unfinished():
+        eng.step()
+        n += 1
+        if n == 3 and kind == "mixed":
+            rids.append(eng.add_request(late, max_new_tokens=3))
+    Lq = eng._Lq
+    assert len(launch_results) == eng.launches >= 9
+    assert kind in {rec["kind"] for rec in launch_results}
+    for rec in launch_results:
+        sampled, packed = rec["front"]
+        dev_sampled, dev_fin = rec["parts"]
+        assert packed.dtype == jnp.int32 and packed.shape == (2 * Lq,)
+        toks, ok, counts = serving._unpack_results(np.asarray(packed), (Lq,))
+        np.testing.assert_array_equal(toks, dev_sampled)
+        np.testing.assert_array_equal(toks, np.asarray(sampled))
+        np.testing.assert_array_equal(ok, dev_fin)
+        assert not counts.size
+    assert eng.summary()["host_round_trips"] == eng.launches
+
+
+class _Recorded:
+    """Stands in for the device array a ticket carries to ``_complete``:
+    says what was asked of it, in order, and passes each on."""
+
+    def __init__(self, arr, log, ready=None):
+        self.arr, self.log, self.ready = arr, log, ready
+
+    def copy_to_host_async(self):
+        self.log.append("copy_to_host_async")
+        self.arr.copy_to_host_async()
+
+    def is_ready(self):
+        self.log.append("is_ready")
+        return self.arr.is_ready() if self.ready is None else self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append("read")
+        return np.asarray(self.arr)
+
+
+def _record_launches(eng, monkeypatch, ready=lambda step: None):
+    """Each launch's packed vector goes to the engine as a ``_Recorded``
+    (``ready(step)``: what it answers ``is_ready``, None for the
+    truth); every entry to ``_complete`` is noted in the log of the
+    ticket it completes.  Returns {step id: log}."""
+    logs = {}
+    real_call, real_complete = eng._call_program, eng._complete
+
+    def call(prog, host_args, bucket):
+        front = list(real_call(prog, host_args, bucket))
+        at = len(front) > 1           # a step: [sampled, packed]
+        log = logs[eng.launches] = []
+        front[at] = _Recorded(front[at], log, ready(eng.launches))
+        return tuple(front)
+
+    def complete(*a, **k):
+        logs[eng._inflight.step].append("_complete")
+        return real_complete(*a, **k)
+
+    monkeypatch.setattr(eng, "_call_program", call)
+    monkeypatch.setattr(eng, "_complete", complete)
+    return logs
+
+
+@pytest.mark.parametrize("kw", [{"overlap": True}, {"overlap": False},
+                                {"decode_window": 3}],
+                         ids=["ahead", "synchronous", "decode_window"])
+def test_one_read_a_launch_and_its_copy_starts_at_dispatch(model,
+                                                           monkeypatch, kw):
+    """The launch asks for the copy to the host before anyone completes
+    it; ``_complete`` asks whether the result is there and reads it,
+    once; nothing else touches it.  The K-step window goes the same
+    way."""
+    rng = np.random.RandomState(43)
+    eng = _engine(model, **kw)
+    logs = _record_launches(eng, monkeypatch)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (6, 9)]
+    rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
+    outs = eng.run()
+    assert len(logs) == eng.launches >= 4
+    assert all(log == ["copy_to_host_async", "_complete", "is_ready", "read"]
+               for log in logs.values())
+    if "decode_window" in kw:
+        # some launches were windows: fewer round trips than tokens
+        assert eng.compile_counts["scan"] == 1
+        assert eng.summary()["host_round_trips"] == eng.launches < 16
+    for rid, p in zip(rids, prompts):
+        assert outs[rid].generated == _oracle(model, p, 8)
+
+
+def test_reads_ready_counts_the_results_that_were_there(model, monkeypatch):
+    """``reads_ready``: completions that found the execution ended.  It
+    never passes ``launches``, and ``engine.block_on_result`` says the
+    same of each launch."""
+    from paddle_tpu.profiler.trace import Tracer
+    eng = _engine(model)
+    tr = eng.tracer = Tracer()
+    logs = _record_launches(eng, monkeypatch, ready=lambda step: step % 3 > 0)
+    eng.add_request([5, 3, 9, 2, 7], max_new_tokens=10)
+    eng.add_request([8, 1, 4], max_new_tokens=6)
+    eng.run()
+    s = eng.summary()
+    want = {step: step % 3 > 0 for step in logs}
+    assert 0 < s["reads_ready"] == sum(want.values()) < s["launches"]
+    said = {e[5]["step"]: e[5]["ready"] for e in tr.events()
+            if e[1] == "engine.block_on_result"}
+    assert said == want
+    # with nobody standing in, the truth: never more than the launches
+    eng = _engine(model)
+    eng.add_request([5, 3, 9, 2, 7], max_new_tokens=10)
+    eng.run()
+    s = eng.summary()
+    assert 0 <= s["reads_ready"] <= s["launches"] == 10
+
+
+# the parent's tokens (PR 37's tree, commit 1a9155d), from this drive
+_PARENT_FREE = [
+    ("length", [1, 6, 23, 91, 87, 10, 86, 39, 86, 75, 20, 23, 67, 86]),
+    ("aborted", [33, 20, 41, 36, 36]),
+    ("length", [32, 51, 58, 51, 58, 51, 58, 51, 58]),
+    ("length", [86, 66, 40, 21, 6, 23, 91, 50, 27, 37, 57, 80]),
+    ("length", [8, 18, 68, 96, 25, 80, 58])]
+
+
+def _seeded_serve(model, eos=None):
+    """Five requests over 18 launches: prompts that enter in chunks of
+    8 beside decode rows, arrivals mid-stream, an abort of a row the
+    launch in flight holds and, with ``eos`` (a token of request 0's
+    free run), a stop the host learns of when the launch behind already
+    carries the row (``ticket.dropped``)."""
+    rng = np.random.RandomState(38)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (21, 9, 13, 6, 17)]
+    eng = _engine(model, max_prefill_tokens=8, prefill_token_bucket=8)
+    rids = [eng.add_request(prompts[0], max_new_tokens=14, eos_token_id=eos),
+            eng.add_request(prompts[1], max_new_tokens=10)]
+    outs, n = {}, 0
+    while eng.has_unfinished():
+        for o in eng.step():
+            outs[o.rid] = o
+        n += 1
+        if n == 3:
+            rids.append(eng.add_request(prompts[2], max_new_tokens=9))
+        if n == 6:
+            rids.append(eng.add_request(prompts[3], max_new_tokens=12))
+        if n == 9:
+            assert rids[1] in eng._inflight.slot_of
+            outs[rids[1]] = eng.abort(rids[1])
+            rids.append(eng.add_request(prompts[4], max_new_tokens=7))
+    return eng, [(outs[r].finish_reason, list(outs[r].generated))
+                 for r in rids]
+
+
+@pytest.mark.parametrize("stop", [False, True], ids=["free", "stop_token"])
+def test_served_tokens_are_the_parents(stop):
+    """Only WHEN the bytes travel changed: a seeded model serves, token
+    for token, what the tree before PR 38 served (frozen above), through
+    chunks, decode rows, an abort in flight and a dropped row."""
+    paddle.seed(38)
+    model = LlamaForCausalLM(CFG)
+    want = list(_PARENT_FREE)
+    if stop:
+        # the parent's stop run is its free run cut at the stop token
+        want[0] = ("eos", want[0][1][:4])
+    eng, got = _seeded_serve(model, eos=want[0][1][3] if stop else None)
+    assert got == want
+    s = eng.summary()
+    assert (s["launches"], s["launches_ahead"]) == (18, 16)
+    assert s["ahead_rows_dropped"] == int(stop)
+    assert 0 <= s["reads_ready"] <= s["launches"]
+    assert eng.blocks.num_used == 0

@@ -211,6 +211,67 @@ def test_preemption_and_abort_leave_the_pool_sound(cfg):
     assert eng.blocks.num_used == 0
 
 
+@pytest.mark.parametrize("kind", ["mixed", "decode_only", "padding_rows"])
+def test_the_packed_vector_carries_the_expert_counts(cfg, launch_results,
+                                                     monkeypatch, kind):
+    """A launch's results reach the host in one int32 vector (PR 38):
+    behind the sampled tokens and the finiteness flags lie the four
+    numbers the step's expert layers counted, each as the device had
+    it, whether a chunk rides beside decode rows, every row decodes or
+    rows are to spare; ``summary()`` sums what the slices say, with no
+    read of its own."""
+    rng = np.random.default_rng(7)
+    lens = (11, 9, 14, 6) if kind == "decode_only" else (11, 9)
+    eng = _engine(_model(cfg), max_prefill_tokens=16)
+    for n in lens:
+        eng.add_request(rng.integers(0, 512, n).tolist(), max_new_tokens=8)
+    # device arrays ``_complete`` reads: the packed vector and no other
+    reads, real_asarray, real_complete = [], np.asarray, eng._complete
+
+    def asarray(x, *a, **k):
+        if reads and reads[-1] is not None and hasattr(x, "is_ready"):
+            reads.append(x.shape)
+        return real_asarray(x, *a, **k)
+
+    def complete(*a, **k):
+        reads.append(())
+        try:
+            return real_complete(*a, **k)
+        finally:
+            reads.append(None)
+
+    monkeypatch.setattr(np, "asarray", asarray)
+    monkeypatch.setattr(eng, "_complete", complete)
+    n = 0
+    while eng.has_unfinished():
+        eng.step()
+        n += 1
+        if n == 3 and kind == "mixed":
+            eng.add_request(rng.integers(0, 512, 40).tolist(),
+                            max_new_tokens=2)
+    monkeypatch.undo()
+    Lq = eng._Lq
+    assert reads == [(), (2 * Lq + 4,), None] * eng.launches
+    assert kind in {rec["kind"] for rec in launch_results}
+    total = np.zeros(4, np.int64)
+    for rec in launch_results:
+        sampled, packed = rec["front"]
+        dev_sampled, dev_fin, dev_counts = rec["parts"]
+        assert packed.dtype == jnp.int32 and packed.shape == (2 * Lq + 4,)
+        toks, ok, counts = serving._unpack_results(np.asarray(packed), (Lq,))
+        np.testing.assert_array_equal(toks, dev_sampled)
+        np.testing.assert_array_equal(toks, np.asarray(sampled))
+        np.testing.assert_array_equal(ok, dev_fin)
+        np.testing.assert_array_equal(counts, dev_counts)
+        assert counts[0] > 0
+        total[:3] += counts[:3]
+        total[3] = max(total[3], counts[3])
+    s = eng.summary()
+    assert [s[k] for k in eng.moe_counts] == total.tolist()
+    assert s["host_round_trips"] == s["launches"] == len(launch_results)
+    assert 0 <= s["reads_ready"] <= s["launches"]
+
+
 def test_the_shares_add_up_to_the_whole_layer(cfg):
     """(b) four chips' routed parts, plus the shared expert once, are the
     uncut reference's whole expert layer."""

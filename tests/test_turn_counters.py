@@ -99,8 +99,11 @@ def test_the_turn_leaves_out_the_wait_on_the_chip(model, monkeypatch):
     _requests(eng, lens=(6,), new=3)
     eng.run()                                   # compiles
     real = np.asarray
+    reads = []
 
     def slow(x, *a, **k):
+        if hasattr(x, "is_ready"):              # a device array
+            reads.append(x.shape)
         if not isinstance(x, np.ndarray):
             time.sleep(0.02)                    # the device "still runs"
         return real(x, *a, **k)
@@ -116,7 +119,9 @@ def test_the_turn_leaves_out_the_wait_on_the_chip(model, monkeypatch):
     n = eng.launches - n0
     assert n >= 4
     blocked = s.block_time - b0
-    assert blocked >= 0.02 * 2 * n * 0.9        # sampled and fin, a launch
+    # one read a launch since PR 38: tokens and flags in one vector
+    assert reads == [(2 * eng._Lq,)] * n
+    assert blocked >= 0.02 * n * 0.9
     assert (s.turn_ns - t0) / 1e9 + blocked <= wall / 1e9 + 1e-3
     assert (s.turn_ns - t0) / 1e9 < blocked / 2
 
