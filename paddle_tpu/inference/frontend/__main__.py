@@ -41,6 +41,20 @@ bridge):
                heads of 128, 256 experts of width 512, 8 a token, window
                512).  Cut depth with --layers (7: the dense layer and six
                sparse ones, 11.3 GB in bfloat16, held once)
+    dots3-sm   a small latent-attention decoder whose full layers attend
+               to the 16 keys a learned indexer selects and whose
+               sliding-window layers (window 24) keep a wider latent of
+               their own, a headwise gate on both, a dense first layer
+               then 8 experts (3 a token) beside a shared one.  Three
+               pools (latents, index keys, the window layers' latents):
+               serve it with --no-prefix-caching
+    dots3-note-prev  dots3-note-prev's widths (128 heads on a 512-wide
+               latent behind a 64-head top-2048 indexer; 64 heads on a
+               1024-wide latent under a window of 513; 256 experts of
+               width 1536, 8 a token) as ONE chip of an expert-parallel-8
+               slice holds it: 32 of the 256 experts a layer, an eighth
+               of the vocabulary.  Cut depth with --layers (5: the dense
+               layer and one period, 8.2 GB in bfloat16, held once)
 
 The process computes on whatever device JAX resolves, and says which on
 its start-up line together with the attention and matmul paths the
@@ -116,6 +130,16 @@ def _model_config(args):
         from paddle_tpu.models.laguna import LagunaConfig
         cfg = LagunaConfig(
             max_position_embeddings=args.max_model_len or 16384)
+    elif args.model == "dots3-sm":
+        from paddle_tpu.models.dots3 import Dots3Config
+        cfg = Dots3Config.tiny(vocab=512, hidden=128, layers=5, experts=8,
+                               topk=16, window=24,
+                               seq=args.max_model_len or 1024)
+    elif args.model == "dots3-note-prev":
+        from paddle_tpu.models.dots3 import Dots3Config
+        cfg = Dots3Config(
+            vocab_size=152064 // 8, experts_held=32, ep_size=8, ep_rank=0,
+            max_position_embeddings=args.max_model_len or 32768)
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
     if args.layers:
@@ -155,6 +179,9 @@ def _build_engine(args, cfg):
     elif getattr(cfg, "architecture", None) == "laguna":
         from paddle_tpu.models.laguna import LagunaForCausalLM
         model = LagunaForCausalLM(cfg, dtype=args.dtype)
+    elif getattr(cfg, "architecture", None) == "dots3":
+        from paddle_tpu.models.dots3 import Dots3ForCausalLM
+        model = Dots3ForCausalLM(cfg, dtype=args.dtype)
     else:
         model = LlamaForCausalLM(cfg)
         if args.dtype != "float32":
@@ -203,7 +230,7 @@ def _parser() -> argparse.ArgumentParser:
                     choices=["tiny", "llama-sm", "llama-7b", "mla-moe-sm",
                              "sarvam-105b", "smallthinker-sm",
                              "smallthinker-21b", "laguna-sm",
-                             "laguna-xs2"])
+                             "laguna-xs2", "dots3-sm", "dots3-note-prev"])
     ap.add_argument("--layers", type=int, default=0,
                     help="depth cut: build this many decoder layers "
                          "(0 = the preset's depth); widths are never cut")

@@ -15,32 +15,42 @@ model's own arrays.
 
 Attention kinds: ``gqa`` (rotary grouped-query attention over K and V
 pages ``[L, num_blocks, Hkv, bs, D]``; float pages, or int8 pages with
-their two scale pools, by what it is handed), ``mla`` (latent
-attention in the absorbed form over ONE pool, ``[L, num_blocks, bs,
-width]``) and, for a model that mixes them (``models/smallthinker.py``),
-``gqa_nope`` (``gqa`` without rotary positions: a global layer) and
-``gqa_window`` (rotary, a query sees the ``c.window`` positions up to
-its own), and ``gqa_gated`` / ``gqa_gated_window`` (those two with the
-attention output times ``sigmoid(h W_g)`` before ``W_o``:
-``models/laguna.py``).  A grouped-query kind reads ITS number of query
-heads and ITS rotary from ``c.attn[kind]``: the kinds of one model may
-differ in both (48 heads half-rotated with YaRN frequencies on the
-global layers, 64 heads wholly rotated on the window layers).  A model
-with window layers has TWO pairs of pools and two block tables: the
-global layers' ``[Lg, num_blocks, ...]`` under ``c.bt`` and the window
-layers' ``[Lw, Nw, ...]`` under ``c.btw``, where a sequence's pages
-below its window have gone back to the pool.  One contract for all,
-scanned or unrolled: a layer gets the pools of ALL layers and its index
-among the layers that share its pools, scatters the step's rows in
-place at (layer, page, slot) and its kernel reads pages where they lie
-at a prefetched layer index.  No layer-sized slice of a pool is ever
-made.  FFN kinds: ``swiglu``, ``moe`` (routed experts held here plus
+their two scale pools, by what it is handed) and, for a model that mixes
+them (``models/smallthinker.py``), ``gqa_nope`` (``gqa`` without rotary
+positions: a global layer) and ``gqa_window`` (rotary, a query sees the
+``c.window`` positions up to its own), and ``gqa_gated`` /
+``gqa_gated_window`` (those two with the attention output times
+``sigmoid(h W_g)`` before ``W_o``: ``models/laguna.py``).  A
+grouped-query kind reads ITS number of query heads and ITS rotary from
+``c.attn[kind]``: the kinds of one model may differ in both (48 heads
+half-rotated with YaRN frequencies on the global layers, 64 heads wholly
+rotated on the window layers).  The LATENT kinds are one body
+(``_latent``: latent attention in the absorbed form over a pool of ONE
+cached row a token, ``[L, num_blocks, bs, width]``) told by
+``c.attn[kind]`` what its layers have: their heads, ranks and head
+sizes, a full or a low-rank query, their rotary and softmax scale, a
+window, a headwise gate, an indexer.  A kind is then a name for the
+POOLS its layers keep (``c.slots[kind]``): ``mla`` (one pool for all
+layers: ``models/mla_moe.py``), ``mla_select`` (the latents and, beside
+them under the same table and page ids, the keys of an indexer whose
+scores pick the ``topk`` keys each query attends to) and ``mla_window``
+(the window layers' latents, of a row width of their own:
+``models/dots3.py``).  A model with window layers has TWO sets of pools
+and two block tables: the global layers' ``[Lg, num_blocks, ...]`` under
+``c.bt`` and the window layers' ``[Lw, Nw, ...]`` under ``c.btw``, where
+a sequence's pages below its window have gone back to the pool.  One
+contract for all, scanned or unrolled: a layer gets the pools of ALL
+layers and its index among the layers that share its pools, scatters the
+step's rows in place at (layer, page, slot) and its kernel reads pages
+where they lie at a prefetched layer index.  No layer-sized slice of a
+pool is ever made.  FFN kinds: ``swiglu``, ``moe`` (routed experts held here plus
 shared experts: ``models/mla_moe.py``) and ``moe_reglu`` (ReGLU experts
 whose router reads ``h``, the attention block's input, not ``h2``).
 
 The ``jax.named_scope`` names below are what a device trace is read by
 (docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
-``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn``
+``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn_index``
+and ``attn_select`` (an indexer's scores and its selection), ``attn``
 (``attn_window`` on a window layer's), ``attn_gate``, ``o_proj``, ``mlp``,
 and for expert layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
 ``shared_expert``.
@@ -54,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..models import dots3 as _d3
 from ..models import mla_moe as _mm
 from ..models import smallthinker as _st
 from ..models.llama import _rms_weight
@@ -218,7 +229,8 @@ def _attend_int8(q, pools, layer, c):
 _GQA = {"gqa": (False, False), "gqa_nope": (False, False),
         "gqa_window": (True, False), "gqa_gated": (False, True),
         "gqa_gated_window": (True, True)}
-WINDOW_KINDS = tuple(k for k, (window, _) in _GQA.items() if window)
+WINDOW_KINDS = tuple(k for k, (window, _) in _GQA.items() if window) \
+    + ("mla_window",)
 
 
 def _gqa(x, h, p, pools, layer, c, kind="gqa"):
@@ -280,30 +292,90 @@ def _gqa(x, h, p, pools, layer, c, kind="gqa"):
     return x, tuple(pools) + tuple(others)
 
 
-def _latent(x, h, p, pools, layer, c):
-    """Latent attention, absorbed form, over the one pool of all layers:
-    the launch's rows ``[c | k_rope | 0...]`` are written in place at
-    (layer, page, slot) and the kernel reads pages where they lie."""
-    (pool,) = pools
-    cfg = c.cfg
-    q, row = _mm.mla_project(h, p, cfg, c.rel, c.inv_freq)
+# the latent kinds: ONE body, told by ``c.attn[kind]`` what its layer has
+# (a low-rank query, a window, a headwise gate, an indexer); a kind is a
+# name for the pools its layers keep
+LATENT_KINDS = ("mla", "mla_select", "mla_window")
+
+
+def _row_at(c, bt):
+    """(page [Tq], slot [Tq]) of the step's rows under table ``bt``."""
+    return bt[c.seg, c.rel // c.bs], c.rel % c.bs
+
+
+def _put_rows(pool, layer, blk, slot, rows):
+    """``rows`` [Tq, n] into ``pool`` [L, pages, bs, width >= n] at
+    (layer, page, slot), the columns past n zero."""
+    rows = jnp.pad(rows, ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+    return pool.at[layer, blk, slot, :].set(rows.astype(pool.dtype))
+
+
+def _latent(x, h, p, pools, layer, c, kind="mla"):
+    """Latent attention, absorbed form, over this layer's pool of the
+    pools of all layers: the launch's rows ``[c | k_rope | 0...]`` are
+    written in place at (layer, page, slot) and the kernel reads pages
+    where they lie.  ``c.attn[kind]`` is the kind's sizes and what its
+    layers have besides (``models/mla_moe.py``, ``models/dots3.py``
+    ``attention_by_kind``); ``c.slots[kind]`` the places of ITS pools
+    among ``pools`` (the others pass through):
+
+    - a window (``a.window``): the pool is the window layers', under the
+      window table ``c.btw``; scope ``attn_window``, a kernel name of
+      its own;
+    - an indexer (``a.index``): a second pool beside the latents', the
+      index keys ``[L, num_blocks, bs, d]`` under the same table and the
+      same page ids.  The index heads score every key of a query's row
+      (scope ``attn_index``), each query keeps its ``topk`` largest
+      (``attn_select``: ``mla_attention.select_bias``, the exact set of
+      a top-k, by counting), and attention runs over those alone;
+    - a headwise gate (``a.gated``): each head's output times
+      ``sigmoid(h W_g)`` before ``W_o`` (``attn_gate``)."""
+    a = c.attn[kind]
+    slots = c.slots[kind]
+    mine = [pools[i] for i in slots]
+    bt, scope = (c.bt, "attn") if a.window is None \
+        else (c.btw, "attn_window")
+    q, row, c_q = _mm.mla_project(h, p, a, c.rel)
+    select = None
+    if a.index is not None:
+        with jax.named_scope("attn_index"):
+            qi, ki, wi = _d3.index_project(h, c_q, p, a.index, c.rel)
     with jax.named_scope("kv_write"):
-        blk = c.bt[c.seg, c.rel // c.bs]
-        slot = c.rel % c.bs
-        row = jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1])))
-        pool = pool.at[layer, blk, slot, :].set(row.astype(pool.dtype))
-    with jax.named_scope("attn"):
+        blk, slot = _row_at(c, bt)
+        mine[0] = _put_rows(mine[0], layer, blk, slot, row)
+        if a.index is not None:
+            mine[1] = _put_rows(mine[1], layer, blk, slot, ki)
+    if a.index is not None:
+        live = c.seg < c.kvl.shape[0]
+        with jax.named_scope("attn_index"):
+            if c.use_pallas:
+                scores = _mla.ragged_index_scores_packed(
+                    qi, wi, mine[1], layer, bt, c.cu, c.kvl)
+            else:
+                scores = _mla.index_scores_reference_segrel(
+                    qi, wi, mine[1][layer], bt, c.seg)
+        with jax.named_scope("attn_select"):
+            select = _mla.select_bias(scores, jnp.where(live, c.rel, -1),
+                                      a.index.topk)
+    with jax.named_scope(scope):
         if c.use_pallas:
             lat = _mla.ragged_latent_attention_packed(
-                q, pool, layer, c.bt, c.cu, c.kvl,
-                latent_dim=cfg.kv_lora_rank, sm_scale=c.sm_scale)
+                q, mine[0], layer, bt, c.cu, c.kvl, latent_dim=a.dc,
+                sm_scale=a.sm_scale, window=a.window, select=select)
         else:
             lat = _mla.mla_ragged_reference_segrel(
-                q, pool[layer], c.bt, c.seg, c.rel,
-                latent_dim=cfg.kv_lora_rank, sm_scale=c.sm_scale)
+                q, mine[0][layer], bt, c.seg, c.rel, latent_dim=a.dc,
+                sm_scale=a.sm_scale, window=a.window, select=select)
+    gate = None
+    if a.gated:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32))
     with jax.named_scope("o_proj"):
-        x = x + _mm.mla_output(lat, p, cfg)
-    return x, (pool,)
+        x = x + _mm.mla_output(lat, p, a, gate)
+    pools = list(pools)
+    for i, pool in zip(slots, mine):
+        pools[i] = pool
+    return x, tuple(pools)
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +408,8 @@ def _moe_reglu(x, h, h2, p, c):
         return x + out, counts
 
 
-ATTENTION = {"mla": _latent,
+ATTENTION = {**{kind: functools.partial(_latent, kind=kind)
+                for kind in LATENT_KINDS},
              **{kind: functools.partial(_gqa, kind=kind) for kind in _GQA}}
 FFN = {"swiglu": _swiglu, "moe": _moe, "moe_reglu": _moe_reglu}
 

@@ -99,8 +99,8 @@ __all__ = ["LLMEngine", "Request", "RequestOutput"]
 # ``layer_stack.ATTENTION``, spelled out: graft-lint reads this literal).
 # An engine's attention-bearing program kinds are bounded by it, whatever
 # its requests do (rule ``attention-program-budget``).
-ATTENTION_KINDS = ("mla", "gqa", "gqa_nope", "gqa_window", "gqa_gated",
-                   "gqa_gated_window")
+ATTENTION_KINDS = ("mla", "mla_select", "mla_window", "gqa", "gqa_nope",
+                   "gqa_window", "gqa_gated", "gqa_gated_window")
 
 
 @dataclass(eq=False)
@@ -306,7 +306,10 @@ def _refuse_window_options(**asked) -> None:
     from float pages in two pools under two block tables and float
     weights on one chip, a step a launch, nothing shared between
     sequences.  Each option below needs what its message says before it
-    can be taken: nothing has run it over the two tables."""
+    can be taken: nothing has run it over the two tables.  A stack of
+    latent AND window layers (``models/dots3.py``) is refused by this
+    table and by ``_refuse_latent_options`` both, the latent one
+    first."""
     _refuse("sliding-window layers", {
         "kv_dtype": ("float32", "int8 pages need scale pools for the "
                      "window layers' pool and their reset when a page "
@@ -335,7 +338,9 @@ def _refuse_latent_options(**asked) -> None:
     needs what its message says before it can be taken."""
     needs = {
         "kv_dtype": ("float32", "int8 latent pages need a quantising "
-                     "write and a dequantising load in the latent kernel"),
+                     "write and a dequantising load in the latent kernel "
+                     "(and an indexer's keys quantised beside them, with "
+                     "the same in its score kernel)"),
         "weight_dtype": ("float32", "int8/int4 weights need quantized "
                          "expert pools and a grouped dequant product"),
         "tp": (1, "tp > 1 needs the heads of the absorbed query and the "
@@ -572,7 +577,8 @@ class LLMEngine:
         self._layer_kinds = cfg.layer_kinds() \
             if hasattr(cfg, "layer_kinds") \
             else [("gqa", "swiglu")] * cfg.num_hidden_layers
-        self._latent = any(a == "mla" for a, _ in self._layer_kinds)
+        self._latent = any(a in _ls.LATENT_KINDS
+                           for a, _ in self._layer_kinds)
         # window layers keep pools and a block table of their own
         self._windowed = any(a in _ls.WINDOW_KINDS
                              for a, _ in self._layer_kinds)
@@ -692,29 +698,56 @@ class LLMEngine:
         self._attn = self._attention_by_kind()
         L = cfg.num_hidden_layers
         dt = self._act_dtype
+        # a page's (heads, row width), by POOL: the block table's pool
+        # and, with window layers, theirs (a latent kind caches one row
+        # a token, one "head" of the row's stored width, and the kinds
+        # of one model need not agree on it)
         if self._latent:
-            # one K/V "head" of the cached row's stored width
-            self._kvh, self._hd = 1, _mla.page_width(cfg.kv_row)
+            glob, wind = self._latent_pool_kinds()
+            self._kvh, self._hd = 1, _mla.page_width(glob.dc + glob.dr)
+            self._kvh_w, self._hd_w = (1, _mla.page_width(
+                wind.dc + wind.dr)) if wind is not None else (0, 0)
         else:
             self._kvh = cfg.num_key_value_heads
             # the configuration's own where it states one: the heads'
             # total need not be the hidden size (28 x 128 over 2560)
             self._hd = int(getattr(cfg, "head_dim", 0)
                            or cfg.hidden_size // self._nh)
-        self._kw = self._vw = None
+            self._kvh_w, self._hd_w = self._kvh, self._hd
+        self._kw = self._vw = self._ki = None
         # a layer's index into the pools of its kind (None: its place
         # in the model, one pool for all layers)
         self._pool_index = None
         with jax.default_device(devices[0]):
+            is_w = [a in _ls.WINDOW_KINDS for a, _ in self._layer_kinds]
             if self._windowed:
-                # TWO pairs of pools: the global layers' pages live as
-                # long as their sequence ([Lg, num_blocks, ...], the
-                # block table's), the window layers' come and go
-                # ([Lw, Nw, ...], the window table's)
-                is_w = [a in _ls.WINDOW_KINDS for a, _ in self._layer_kinds]
+                # the global layers' pages live as long as their
+                # sequence ([Lg, num_blocks, ...], the block table's),
+                # the window layers' come and go ([Lw, Nw, ...], the
+                # window table's): a layer's index is its place among
+                # the layers that share its pools
                 self._pool_index = [sum(is_w[:i]) if w
                                     else i - sum(is_w[:i])
                                     for i, w in enumerate(is_w)]
+            if self._latent:
+                # ONE pool a kind of layer, a row [c | k_rope | 0...] a
+                # token: written in place and read where it lies; the
+                # window layers' pool has a row width of its own
+                self._kc = jnp.zeros((L - sum(is_w), num_blocks,
+                                      self.block_size, self._hd), dt)
+                if self._windowed:
+                    self._kw = jnp.zeros(
+                        (sum(is_w), self._window_blocks, self.block_size,
+                         self._hd_w), dt)
+                if glob.index is not None:
+                    # the indexer's keys, a row a token a full layer,
+                    # under the block table and its page ids: no
+                    # allocator state of their own
+                    self._ki = jnp.zeros((L - sum(is_w), num_blocks,
+                                          self.block_size, glob.index.d), dt)
+                self._vc = self._ks = self._vs = None
+            elif self._windowed:
+                # TWO pairs of pools
                 page = (self._kvh, self.block_size, self._hd)
                 self._kc = jnp.zeros((L - sum(is_w), num_blocks) + page, dt)
                 self._vc = jnp.zeros_like(self._kc)
@@ -722,12 +755,6 @@ class LLMEngine:
                                      + page, dt)
                 self._vw = jnp.zeros_like(self._kw)
                 self._ks = self._vs = None
-            elif self._latent:
-                # ONE pool for all layers, a row [c | k_rope | 0...] a
-                # token: written in place and read where it lies
-                self._kc = jnp.zeros((L, num_blocks, self.block_size,
-                                      self._hd), dt)
-                self._vc = self._ks = self._vs = None
             elif self.kv_dtype == "int8":
                 # int8 pages + a parallel per-page-per-head f32 scale
                 # pool (symmetric: float = int8 * scale).  Scales are
@@ -770,6 +797,12 @@ class LLMEngine:
             if self._ks is not None:
                 self._ks = jax.device_put(self._ks, kv_sh)
                 self._vs = jax.device_put(self._vs, kv_sh)
+        self._slots = self._pool_slots() if self._latent else None
+        # an indexer's selection (0: the model has none): what a launch's
+        # index_keys_selected counts against
+        self._index_topk = max(
+            (a.index.topk for a in self._attn.values()
+             if getattr(a, "index", None) is not None), default=0)
         # scale-reset feed: pages BlockManager handed out since the last
         # launch (their old scales are dead); consumed by _launch_ragged
         self._fresh_np = np.zeros((num_blocks,), bool)
@@ -881,7 +914,8 @@ class LLMEngine:
         # what the pre-ragged four-program engine would have padded to
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
-                          "kv_pages": 0, "kv_pages_window": 0}
+                          "kv_pages": 0, "kv_pages_window": 0,
+                          "index_keys_visible": 0, "index_keys_selected": 0}
         # passes of the sampling epilogue, and those whose launch held a
         # sampled row (temps > 0): the device runs the sampled chain in
         # exactly those (sampling.sample_tokens branches on the same)
@@ -1016,6 +1050,37 @@ class LLMEngine:
         return {"layers": out_layers, "embed_q": eq, "embed_s": es,
                 "norm_f": params["norm_f"], "head_q": hq, "head_s": hs}
 
+    def _latent_pool_kinds(self):
+        """(sizes of the latent kind under the block table, sizes of the
+        window layers' latent kind or None): a pool holds rows of one
+        width, so a model has at most one latent kind a pool."""
+        kinds = {a for a, _ in self._layer_kinds}
+        if not kinds <= set(_ls.LATENT_KINDS):
+            raise ValueError(
+                f"layers of kinds {sorted(kinds)}: latent and grouped-"
+                "query layers in one model need K/V pools beside the "
+                "latent pool, which no step program has")
+        glob = [k for k in kinds if k not in _ls.WINDOW_KINDS]
+        wind = [k for k in kinds if k in _ls.WINDOW_KINDS]
+        if len(glob) != 1 or len(wind) > 1:
+            raise ValueError(
+                f"latent kinds {sorted(kinds)}: one kind under the block "
+                "table and at most one under the window table")
+        return (self._attn[glob[0]],
+                self._attn[wind[0]] if wind else None)
+
+    def _pool_slots(self) -> dict:
+        """{latent kind: the places of its pools among ``_pools()``}: a
+        kind under the block table has the latent pool and, with an
+        indexer, the index keys' after it; a window kind the window
+        table's."""
+        held = [n for n in self._POOLS if getattr(self, n) is not None]
+        return {k: tuple(held.index(n) for n in (
+            ("_kw",) if k in _ls.WINDOW_KINDS else
+            ("_kc", "_ki") if self._attn[k].index is not None
+            else ("_kc",)))
+            for k in {a for a, _ in self._layer_kinds}}
+
     def _attention_by_kind(self) -> dict:
         """{grouped-query attention kind: its query heads ``nh`` (a
         shard's) and its rotary ``rope(x, pos)``}, what a step program
@@ -1024,8 +1089,6 @@ class LLMEngine:
         head count and one theta for every kind, and no rotary on a kind
         without positions."""
         cfg = self.config
-        if self._latent:
-            return {}
         if hasattr(cfg, "attention_by_kind"):
             return cfg.attention_by_kind()
         rope = functools.partial(_rope_positions, theta=cfg.rope_theta)
@@ -1044,11 +1107,15 @@ class LLMEngine:
         if self._platform != "tpu":
             return f"xla-reference ({self._platform} platform)"
         if self._latent:
-            why = _mla.ineligible(
-                self._nh, self._hd, self.config.kv_lora_rank,
-                self.block_size, self._act_dtype,
-                launch=(self.max_num_seqs + 1, self.nblk,
-                        self.blocks.num_blocks))
+            # every latent kind's heads, row and latent, and an
+            # indexer's key, have to be ones the kernels claim
+            whys = [_mla.ineligible(
+                a.nh, _mla.page_width(a.dc + a.dr), a.dc, self.block_size,
+                self._act_dtype, launch=(self.max_num_seqs + 1, self.nblk,
+                                         self.blocks.num_blocks),
+                index_dim=None if a.index is None else a.index.d)
+                for a in self._attn.values()]
+            why = next((w for w in whys if w is not None), None)
             return "pallas" if why is None else f"xla-reference ({why})"
         # every kind's head count has to be one the kernel claims
         whys = (_pa.ineligible(a.nh, self._kvh // self.tp,
@@ -1526,6 +1593,12 @@ class LLMEngine:
             # and pages live sequences gave back as they moved on
             out["kv_pages_window"] = self.pad_stats["kv_pages_window"]
             out["window_pages_returned"] = self.blocks.window_returned
+        if self._index_topk:
+            # (query, key) pairs the indexed layers' queries saw and
+            # those they selected, a layer's, summed over launches
+            out["index_keys_visible"] = self.pad_stats["index_keys_visible"]
+            out["index_keys_selected"] = \
+                self.pad_stats["index_keys_selected"]
         # launches in all, those dispatched before the launch in front
         # of them was committed, the others by why not, and the rows
         # such a launch held of a request that commit retired
@@ -1575,18 +1648,21 @@ class LLMEngine:
                 "matmul": self.matmul_path,
                 "programs": dict(self.program_paths)}
 
+    # the pools an engine may hold, in the order a program takes them:
+    # those under the block table, then the window table's
+    _POOLS = ("_kc", "_vc", "_ks", "_vs", "_ki", "_kw", "_vw")
+
     def _pools(self) -> tuple:
         """The page pools every program takes after the parameters and
         gives back: K and V (over int8 pages their scale pools too), or
-        the one latent pool, each [L, num_blocks, ...]; with window
-        layers the global layers' K and V, then the window layers'
-        [Lw, Nw, ...]."""
-        return tuple(x for x in (self._kc, self._vc, self._ks, self._vs,
-                                 self._kw, self._vw) if x is not None)
+        the one latent pool (and an indexer's keys beside it), each
+        [L, num_blocks, ...]; with window layers the global layers'
+        pools, then the window layers' [Lw, Nw, ...]."""
+        return tuple(getattr(self, n) for n in self._POOLS
+                     if getattr(self, n) is not None)
 
     def _set_pools(self, pools) -> None:
-        names = [n for n in ("_kc", "_vc", "_ks", "_vs", "_kw", "_vw")
-                 if getattr(self, n) is not None]
+        names = [n for n in self._POOLS if getattr(self, n) is not None]
         for n, x in zip(names, pools):
             setattr(self, n, x)
 
@@ -1607,7 +1683,7 @@ class LLMEngine:
             return 0
         return self.blocks.num_window_used * sum(
             x.size // x.shape[1] * np.dtype(x.dtype).itemsize
-            for x in (self._kw, self._vw))
+            for x in (self._kw, self._vw) if x is not None)
 
     def kv_page_bytes_per_shard(self) -> int:
         """Bytes one KV page costs ON ONE CHIP.  Pools shard along the
@@ -3208,7 +3284,7 @@ class LLMEngine:
         n = len(self._pools())
         # the pools the block table's page ids index (a window layer's
         # pool has ids of its own; nothing shares its pages)
-        paged = n - 2 * self._windowed
+        paged = n - sum(x is not None for x in (self._kw, self._vw))
 
         def run(*args):
             s, d = args[n:]
@@ -3306,9 +3382,7 @@ class LLMEngine:
             eps=cfg.rms_norm_eps,
             use_pallas=self.attention_path.startswith("pallas"))
         if self._latent:
-            from ..models.mla_moe import softmax_scale, yarn_inv_freq
-            shared.update(cfg=cfg, inv_freq=yarn_inv_freq(cfg),
-                          sm_scale=softmax_scale(cfg))
+            shared.update(cfg=cfg, attn=self._attn, slots=self._slots)
         else:
             shared.update(attn=self._attn, kvh=self._kvh // self.tp,
                           d=self._hd)
@@ -3497,7 +3571,9 @@ class LLMEngine:
         # counted once a launch; ``engine.device_launch`` carries the same
         pages = self._launch_pages = self._launch_kv_args(cu, kvl)
         self.pad_stats["kv_pages"] += pages["kv_pages"]
-        self.pad_stats["kv_pages_window"] += pages.get("kv_pages_window", 0)
+        for name in ("kv_pages_window", "index_keys_visible",
+                     "index_keys_selected"):
+            self.pad_stats[name] += pages.get(name, 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
         sampled, packed, *logits = self._call_program(
@@ -3527,10 +3603,30 @@ class LLMEngine:
         """The page counts of a launch, as ``engine.device_launch``
         carries them."""
         pages = self._kv_pages(kvl)
-        if not self._windowed:
-            return {"kv_pages": pages}
-        return {"kv_pages": pages, "kv_pages_uniform": pages,
-                "kv_pages_window": self._kv_pages_window(cu, kvl)}
+        out = {"kv_pages": pages}
+        if self._windowed:
+            out.update(kv_pages_uniform=pages,
+                       kv_pages_window=self._kv_pages_window(cu, kvl))
+        if self._index_topk:
+            out.update(self._index_keys(cu, kvl))
+        return out
+
+    def _index_keys(self, cu, kvl) -> dict:
+        """What an indexed layer's queries of this launch see and what
+        they keep: a query at position p sees p + 1 keys and attends to
+        the ``min(p + 1, index_topk)`` it selects, summed over the
+        launch's rows (one layer's: every indexed layer has the same)."""
+        kvl = np.asarray(kvl, np.int64)
+        n_q = np.diff(np.asarray(cu, np.int64))[:len(kvl)]
+        k = self._index_topk
+        # positions first .. kvl - 1; those below k - 1 keep all they see
+        first = kvl - n_q
+        visible = n_q * kvl - n_q * (n_q - 1) // 2
+        full = np.clip(kvl - np.maximum(first, k - 1), 0, None)  # keep k
+        part = n_q - full                         # positions first ..
+        selected = full * k + part * first + part * (part + 1) // 2
+        return {"index_keys_visible": int(visible.sum()),
+                "index_keys_selected": int(selected.sum())}
 
     def _advance_window(self, req, start: int, end: int) -> None:
         """Before a launch that holds req's queries at positions start

@@ -106,6 +106,19 @@ class MlaMoeConfig:
         return [("mla", "swiglu" if i < self.first_k_dense_replace
                  else "moe") for i in range(self.num_hidden_layers)]
 
+    def attention_by_kind(self) -> dict:
+        """{attention kind: the sizes of its latent attention}, what a
+        step program hands the latent body (``inference/layer_stack.py``
+        ``_latent``): a full query projection with a norm over each
+        head's query, no window, no gate, no indexer."""
+        from types import SimpleNamespace
+        return {"mla": SimpleNamespace(
+            nh=self.num_attention_heads, rq=0, dc=self.kv_lora_rank,
+            dn=self.qk_nope_head_dim, dr=self.qk_rope_head_dim,
+            dv=self.v_head_dim, q_norm=True, eps=self.rms_norm_eps,
+            inv_freq=yarn_inv_freq(self), sm_scale=softmax_scale(self),
+            window=None, gated=False, index=None)}
+
     @staticmethod
     def tiny(vocab=96, hidden=64, layers=3, heads=4, experts=8, held=None,
              ep_size=1, ep_rank=0, seq=256):
@@ -178,44 +191,55 @@ def rope_at(x, pos, inv_freq):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def mla_project(h, p, cfg: MlaMoeConfig, pos, inv_freq):
-    """What attention needs of h [T, H] at positions pos: the absorbed
-    queries [T, heads, kv_lora_rank + rope] and the rows to cache
-    [T, kv_lora_rank + rope]."""
+def mla_project(h, p, a, pos):
+    """What attention needs of h [T, H] at positions pos, by the sizes
+    ``a`` of the layer's kind (``attention_by_kind``): the absorbed
+    queries [T, heads, dc + dr], the rows to cache [T, dc + dr] and,
+    where the query is low-rank (``a.rq``), its normed latent [T, rq]
+    (else None), which an indexer's queries are made from too."""
     import jax
     import jax.numpy as jnp
     T = h.shape[0]
-    nh, dn, dr, dc = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                      cfg.qk_rope_head_dim, cfg.kv_lora_rank)
-    eps = cfg.rms_norm_eps
+    nh, dn, dr, dc, eps = a.nh, a.dn, a.dr, a.dc, a.eps
     with jax.named_scope("q_proj"):
-        q = _rms_weight((h @ p["wq"]).reshape(T, nh, dn + dr),
-                        p["q_norm"], eps)
+        c_q = None
+        if a.rq:
+            c_q = _rms_weight(h @ p["wqa"], p["q_a_norm"], eps)
+            q = (c_q @ p["wqb"]).reshape(T, nh, dn + dr)
+        else:
+            q = (h @ p["wq"]).reshape(T, nh, dn + dr)
+        if a.q_norm:
+            q = _rms_weight(q, p["q_norm"], eps)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
-        w_k = p["wkvb"].reshape(dc, nh, dn + cfg.v_head_dim)[..., :dn]
+        w_k = p["wkvb"].reshape(dc, nh, dn + a.dv)[..., :dn]
         q_abs = jnp.einsum("thd,chd->thc", q_nope, w_k)
     with jax.named_scope("kv_latent"):
         ckv = h @ p["wkva"]
         c = _rms_weight(ckv[:, :dc], p["kv_norm"], eps)
         k_rope = ckv[:, None, dc:]
     with jax.named_scope("rope"):
-        q_rope = rope_at(q_rope, pos, inv_freq)
-        k_rope = rope_at(k_rope, pos, inv_freq)[:, 0]
+        q_rope = rope_at(q_rope, pos, a.inv_freq)
+        k_rope = rope_at(k_rope, pos, a.inv_freq)[:, 0]
     return (jnp.concatenate([q_abs, q_rope], -1),
-            jnp.concatenate([c, k_rope], -1))
+            jnp.concatenate([c, k_rope], -1), c_q)
 
 
-def mla_output(lat, p, cfg: MlaMoeConfig):
-    """The attention block's contribution to x from lat [T, heads,
-    kv_lora_rank], each head's weighted sum of cached latents."""
+def mla_output(lat, p, a, gate=None):
+    """The attention block's contribution to x from lat [T, heads, dc],
+    each head's weighted sum of cached latents; ``gate`` [T, heads]
+    float32 (a headwise output gate): each head's output times its
+    number before ``W_o``."""
     import jax
     import jax.numpy as jnp
     T = lat.shape[0]
-    nh, dn, dv, dc = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                      cfg.v_head_dim, cfg.kv_lora_rank)
+    nh, dn, dv, dc = a.nh, a.dn, a.dv, a.dc
     with jax.named_scope("o_proj"):
         w_v = p["wkvb"].reshape(dc, nh, dn + dv)[..., dn:]
         v = jnp.einsum("thc,chd->thd", lat, w_v)
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            v = (v.astype(jnp.float32) * gate[..., None]).astype(v.dtype)
+    with jax.named_scope("o_proj"):
         return v.reshape(T, nh * dv) @ p["wo"]
 
 

@@ -649,3 +649,144 @@ def test_the_dense_step_leaves_the_pools_where_they_lie(
     stacked = "{}[{}]".format("s8" if pages == "int8" else "bf16",
                               ",".join(map(str, pool)))
     assert kernel.split("operand_layout_constraints=")[1].count(stacked) == 2
+
+
+# ---------------------------------------------------------------------------
+# the indexed and windowed latent kernels (PR 39), at dots3-note-prev-ep8's
+# sizes: 128 heads on a 640-wide row behind a 64-head indexer, 64 heads on
+# a 1152-wide row under a window of 513, the [33, 2048] table of
+# 32,768-token rows
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tq", [32, 576])
+@pytest.mark.parametrize("what", ["window", "selected", "index"])
+def test_the_dots3_kernels_compile_for_the_v5e(one_chip, compiled_kernels,
+                                               no_persistent_cache, what,
+                                               tq):
+    """What the chip's compiler refuses here it refuses on the chip: a
+    copy from a [rows, 1] array (the index heads' weights ride a row of
+    lanes for that), a tile of the selection's bias that starts inside
+    a tile of rows (the bias is item-major for that)."""
+    from paddle_tpu.ops.pallas import mla_attention as mla
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    row = (sds((33, 2048), jnp.int32), sds((33,), jnp.int32),
+           sds((32,), jnp.int32))
+    launch = (33, 2048, 32769)
+    if what == "window":
+        assert mla.ineligible(64, 1152, 1024, 16, launch=launch) is None
+        fn = lambda q, pool, bt, cu, kvl: \
+            mla.ragged_latent_attention_packed(
+                q, pool, 2, bt, cu, kvl, latent_dim=1024, sm_scale=0.0625,
+                window=513)
+        args = (sds((tq, 64, 1088)), sds((3, 2113, 16, 1152))) + row
+        name, out = mla.WINDOW_KERNEL_NAME, r"bf16\[\d+,1024\]"
+    elif what == "selected":
+        assert mla.ineligible(128, 640, 512, 16, launch=launch,
+                              index_dim=128) is None
+        fn = lambda q, pool, sel, bt, cu, kvl: \
+            mla.ragged_latent_attention_packed(
+                q, pool, 1, bt, cu, kvl, latent_dim=512, sm_scale=0.072,
+                select=sel)
+        args = (sds((tq, 128, 576)), sds((2, 32769, 16, 640)),
+                sds((tq, 32768), jnp.float32)) + row
+        name, out = mla.SELECT_KERNEL_NAME, r"bf16\[\d+,512\]"
+    else:
+        fn = lambda q, w, pool, bt, cu, kvl: \
+            mla.ragged_index_scores_packed(q, w, pool, 1, bt, cu, kvl)
+        args = (sds((tq, 64, 128)), sds((tq, 64), jnp.float32),
+                sds((2, 32769, 16, 128))) + row
+        name, out = mla.INDEX_KERNEL_NAME, r"f32\[\d+,32768\]"
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert name in text
+    assert re.search(out + r"[^\n]* custom-call\(", text)
+
+
+def test_the_selection_compiles_without_a_sort(one_chip,
+                                               no_persistent_cache):
+    """The exact top-2048 of 32,768 scores a query, by counting: no sort
+    and no top-k custom call in the program."""
+    from paddle_tpu.ops.pallas import mla_attention as mla
+    args = (jax.ShapeDtypeStruct((576, 32768), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((576,), jnp.int32, sharding=one_chip))
+    text = jax.jit(lambda s, rel: mla.select_bias(s, rel, 2048)).lower(
+        *args).compile().as_text()
+    assert " sort(" not in text and "TopK" not in text
+    assert " while(" in text
+
+
+def test_the_three_pool_step_leaves_the_pools_where_they_lie(
+        one_chip, compiled_kernels, no_persistent_cache, monkeypatch):
+    """The step program of a decoder with indexed full layers and
+    windowed latent layers, at dots3-note-prev's attention widths round
+    a narrow model: nothing shaped as one layer of one of its
+    three arrays, or the whole of one, is made but by the scatters of
+    ``kv_write`` (seven: latents and index keys of two full layers, the
+    latents of three window layers), every kernel takes the array of ALL
+    its layers, and each launch has its name."""
+    import dataclasses
+
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.models.dots3 import Dots3Config, Dots3ForCausalLM
+    from paddle_tpu.ops.pallas import mla_attention as mla
+    cfg = dataclasses.replace(
+        Dots3Config(), vocab_size=1000, hidden_size=256,
+        intermediate_size=512, moe_intermediate_size=64, num_hidden_layers=5,
+        n_routed_experts=8, experts_held=8, max_position_embeddings=4096)
+    eng = LLMEngine(Dots3ForCausalLM(cfg, dtype="bfloat16"),
+                    max_num_seqs=ROWS, block_size=BLOCK, num_blocks=4097,
+                    max_model_len=4096, max_prefill_tokens=192,
+                    prefill_token_bucket=64, enable_prefix_caching=False)
+    monkeypatch.setattr(eng, "_platform", "tpu")
+    eng.attention_path = eng._resolve_attention_path()
+    assert eng.attention_path == "pallas"
+    pools = [p.shape for p in eng._pools()]
+    assert pools == [(2, 4097, 16, 640), (2, 4097, 16, 128),
+                     (3, eng._window_blocks, 16, 1152)]
+    tq = 192
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        eng._ragged_arg_structs(tq))
+    fn, donate = eng._make_ragged_fn(tq)
+    text = jax.jit(fn, donate_argnums=donate).lower(
+        *args).compile().as_text()
+    _, comps = _computations(text)
+    # an array of a pool's shape, or of one layer of it (the queries of
+    # 192 tokens and 128 heads are larger than a layer of this test's
+    # index keys: the shape is what tells a copy of a pool)
+    shaped = {s for p in pools for s in (p, p[1:], (1,) + p[1:])}
+
+    def writes_rows(line):
+        # (a layer is an inner jit: its op_names start at the scope)
+        return " scatter(" in line and "kv_write/" in line
+
+    large = []
+    for lines in comps.values():
+        for line in lines:
+            m = _RESULT.match(line)
+            if m and tuple(int(n) for n in m.group(2).split(",")
+                           if n) in shaped:
+                large.append((m.group(3), line))
+    moved = [line for op, line in large
+             if op not in ("parameter", "get-tuple-element", "bitcast")
+             and not writes_rows(line)
+             and not (op == "fusion" and any(
+                 writes_rows(l) for c in _CALLED.findall(line)
+                 for l in comps[c]))]
+    assert not moved, moved[0][:300]
+    scatters = [line for lines in comps.values() for line in lines
+                if writes_rows(line)]
+    assert len(scatters) == 2 * 2 + 3
+    calls = [line for lines in comps.values() for line in lines
+             if " custom-call(" in line]
+    for name, n, pool in ((mla.SELECT_KERNEL_NAME, 2, pools[0]),
+                          (mla.INDEX_KERNEL_NAME, 2, pools[1]),
+                          (mla.WINDOW_KERNEL_NAME, 3, pools[2])):
+        mine = [c for c in calls if f"{name}" in c.split(" = ")[0]
+                or f'"{name}"' in c or f"/{name}/" in c]
+        assert len(mine) == n, (name, len(mine))
+        stacked = "bf16[{}]".format(",".join(map(str, pool)))
+        assert all(stacked in c for c in mine), name

@@ -224,6 +224,49 @@ def test_cli_serves_the_two_head_count_preset():
         srv.kill()
 
 
+def test_cli_serves_the_indexed_latent_preset():
+    """``--model dots3-sm``: latent full layers that attend to the 16
+    keys an indexer selects, windowed latent layers (window 24) with a
+    wider latent, a headwise gate, experts beside a shared one, through
+    the same CLI, engine and HTTP path: a completion whose prompt is
+    longer than the selection, the window and a chunk."""
+    srv = _Server("--model", "dots3-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4", "--max-prefill-tokens", "64",
+                  "--no-prefix-caching")
+    try:
+        port = srv.port()
+        assert "attention='xla-reference (cpu platform)'" in srv.output()
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+        body = json.dumps({"prompt": list(range(1, 150)),
+                           "max_tokens": 6}).encode()
+        conn.request("POST", "/v1/completions", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        doc = json.loads(resp.read())
+        assert resp.status == 200
+        choice = doc["choices"][0]
+        assert choice["finish_reason"] == "length"
+        assert len(choice["token_ids"]) == 6
+        conn.close()
+        srv.proc.send_signal(signal.SIGINT)
+        assert srv.proc.wait(timeout=60) == 0
+    finally:
+        srv.kill()
+
+
+def test_cli_refuses_int8_pages_over_latent_and_index_pools_by_name():
+    srv = _Server("--model", "dots3-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4", "--no-prefix-caching",
+                  "--kv-dtype", "int8")
+    try:
+        assert srv.proc.wait(timeout=120) != 0
+        assert "kv_dtype='int8' is not supported for a model with " \
+            "latent-attention (MLA) layers" in srv.output()
+        assert "indexer's keys quantised" in srv.output()
+    finally:
+        srv.kill()
+
+
 def test_cli_refuses_prefix_caching_over_the_window_pool_by_name():
     srv = _Server("--model", "smallthinker-sm", "--dtype", "float32",
                   "--max-num-seqs", "4")

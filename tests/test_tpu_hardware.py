@@ -867,3 +867,105 @@ def test_train_step_on_four_chips():
     print(f"losses {losses}")
     assert all(np.isfinite(x) for x in losses), losses
     assert losses[-1] < losses[0], losses
+
+
+def _latent_launch(rows, nblk, nb, bs=16, seed=11):
+    """(cu, kvl, bt) of a launch of ``rows`` [(n_q, kv_len)] under a
+    32-row table of distinct shuffled pages, and its live tokens."""
+    rng = np.random.RandomState(seed)
+    cu = np.zeros(33, np.int32)
+    kvl = np.zeros(32, np.int32)
+    bt = np.zeros((33, nblk), np.int32)
+    free = iter(rng.permutation(np.arange(1, nb)))
+    for r, (n, k) in enumerate(rows):
+        cu[r + 1] = cu[r] + n
+        kvl[r] = k
+        for p in range(-(-k // bs)):
+            bt[r, p] = next(free)
+    cu[len(rows) + 1:] = cu[len(rows)]
+    return (jnp.asarray(bt), jnp.asarray(cu), jnp.asarray(kvl)), \
+        int(cu[len(rows)])
+
+
+def test_windowed_latent_kernel_on_tpu():
+    """The latent kernel under a window at dots3-note-prev's sliding
+    layers' sizes (64 heads, a 1,088-number row stored 1,152 wide, window
+    513): a 300-token chunk resumed at 2,700 cached rows, decode rows
+    under, at and far past the window."""
+    from paddle_tpu.ops.pallas import mla_attention as MLA
+
+    G, row, dc, nblk, nb = 64, 1088, 1024, 256, 900
+    rows = [(300, 3000), (1, 1), (0, 0), (1, 513), (1, 514), (1, 4000),
+            (5, 40)]
+    args, live = _latent_launch(rows, nblk, nb)
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(320, G, row) * 0.5, jnp.bfloat16)
+    pool = jnp.asarray(rng.randn(2, nb, 16, MLA.page_width(row)) * 0.5,
+                       jnp.bfloat16).at[..., row:].set(0)
+    out = jax.jit(lambda q, pool, bt, cu, kvl:
+                  MLA.ragged_latent_attention_packed(
+                      q, pool, 1, bt, cu, kvl, latent_dim=dc,
+                      sm_scale=0.0625, window=513))(q, pool, *args)
+    with jax.default_matmul_precision("highest"):
+        ref = MLA.mla_ragged_reference(
+            q.astype(jnp.float32), pool[1].astype(jnp.float32), *args,
+            latent_dim=dc, sm_scale=0.0625, window=513)
+    err = _max_err(out, ref, live)
+    print(f"windowed latent kernel: max abs err {err:.3e}")
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert not bool(jnp.any(out[live:]))
+    assert err < 3e-2, err
+
+
+def test_indexer_and_selected_keys_on_tpu():
+    """dots3-note-prev's full layers on the chip: the index-score kernel
+    (64 heads of 128) against its oracle, the selection against a literal
+    ``lax.top_k`` of the same scores (the same SET, 2,048 a query), and
+    the latent kernel (128 heads on a 640-wide row) over the selected
+    keys alone."""
+    from paddle_tpu.ops.pallas import mla_attention as MLA
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    nblk, nb = 256, 1200
+    rows = [(300, 3000), (1, 2500), (1, 17), (77, 77), (1, 4000), (0, 0),
+            (20, 1000)]
+    Tq = 448
+    args, live = _latent_launch(rows, nblk, nb)
+    bt, cu, kvl = args
+    rng = np.random.RandomState(5)
+    qi = jnp.asarray(rng.randn(Tq, 64, 128), jnp.bfloat16)
+    wi = jnp.asarray(rng.randn(Tq, 64) * 0.01, jnp.float32)
+    ipool = jnp.asarray(rng.randn(2, nb, 16, 128), jnp.bfloat16)
+    seg, rel = PA.ragged_segments(cu[:len(rows) + 1], kvl[:len(rows)], Tq)
+    rel = jnp.where(seg < len(rows), rel, -1)
+    vis = jnp.arange(nblk * 16)[None, :] <= rel[:, None]
+    got = jax.jit(lambda q, w, pool: MLA.ragged_index_scores_packed(
+        q, w, pool, 1, *args))(qi, wi, ipool)
+    with jax.default_matmul_precision("highest"):
+        want = MLA.index_scores_reference_segrel(
+            qi.astype(jnp.float32), wi, ipool[1].astype(jnp.float32), bt,
+            jnp.minimum(seg, 32))
+    err = float(jnp.max(jnp.abs(jnp.where(vis, got - want, 0))))
+    print(f"index scores: max abs err {err:.3e}")
+    assert err < 2e-2, err
+    mask = jax.jit(lambda s: MLA.select_mask(s, rel, 2048))(got)
+    _, idx = jax.lax.top_k(jnp.where(vis, got, -jnp.inf), 2048)
+    lit = jnp.zeros_like(vis).at[jnp.arange(Tq)[:, None], idx].set(
+        True) & vis
+    assert int(jnp.sum(mask != lit)) == 0
+    assert int(mask[0].sum()) == 2048 and int(mask[300].sum()) == 2048
+    q = jnp.asarray(rng.randn(Tq, 128, 576) * 0.5, jnp.bfloat16)
+    pool = jnp.asarray(rng.randn(2, nb, 16, 640) * 0.5,
+                       jnp.bfloat16).at[..., 576:].set(0)
+    bias = jnp.where(mask, 0.0, -jnp.inf).astype(jnp.float32)
+    out = jax.jit(lambda q, pool, bias: MLA.ragged_latent_attention_packed(
+        q, pool, 1, *args, latent_dim=512, sm_scale=0.072,
+        select=bias))(q, pool, bias)
+    with jax.default_matmul_precision("highest"):
+        ref = MLA.mla_ragged_reference(
+            q.astype(jnp.float32), pool[1].astype(jnp.float32), *args,
+            latent_dim=512, sm_scale=0.072, select=bias)
+    err = _max_err(out, ref, live)
+    print(f"selected-keys latent kernel: max abs err {err:.3e}")
+    assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+    assert err < 3e-2, err
