@@ -641,8 +641,20 @@ class LLMEngine:
             # the step's activations keep the model's float dtype even
             # when the embed table becomes a quantized pool + scales
             self._act_dtype = self.params["embed"].dtype
+            # {name: its heads} of the leaves this engine holds
+            # [L, heads, d, in], which the float ``mm`` contracts on
+            # the weight's LAST axis (``_weight_ops``): q, k and v of
+            # the stacked copy, which is the engine's own to lay out; a
+            # quantized pool keeps the kernel's [in, out], and arrays
+            # held once are the model's
+            self._out_major = {}
             if self.weight_dtype != "float32":
                 self.params = self._quantize_params(self.params)
+            elif self._scanned:
+                self._out_major = {"wq": cfg.num_attention_heads,
+                                   "wk": cfg.num_key_value_heads,
+                                   "wv": cfg.num_key_value_heads}
+                self._hold_out_major(self.params["layers"])
         # the unembedding shards over vocab only when it divides evenly
         # (padding the vocab axis would poison the per-row finiteness
         # flag); otherwise the head matmul replicates and the per-layer
@@ -1050,6 +1062,27 @@ class LLMEngine:
         return {"layers": out_layers, "embed_q": eq, "embed_s": es,
                 "norm_f": params["norm_f"], "head_q": hq, "head_s": hs}
 
+    def _hold_out_major(self, layers) -> None:
+        """Turn the ``_out_major`` leaves of the stacked copy from the
+        export's [L, in, heads * d] to [L, heads, d, in], in place in
+        ``layers``.
+
+        XLA lays q, k and v out head-major for the attention kernel and
+        pushes that back through the rotary into the products, so it
+        reads these weights as [heads, d, hidden]: from [in, out] that
+        was a transposing copy of each matrix in every layer of every
+        step (7 to 9% of a dense step on the v5e); handed [heads, d,
+        in] it slices the layer inside the product that reads it, as it
+        does for the other four matrices.  Leaf by leaf, each export
+        leaf dropped as its replacement stands: the transient is one
+        stack, not three."""
+        for name, heads in self._out_major.items():
+            w = layers.pop(name)
+            n, width, out = w.shape
+            # (one primitive, one result: transposed, then split by head)
+            layers[name] = jax.block_until_ready(lax.reshape(
+                w, (n, heads, out // heads, width), dimensions=(0, 2, 1)))
+
     def _latent_pool_kinds(self):
         """(sizes of the latent kind under the block table, sizes of the
         window layers' latent kind or None): a pool holds rows of one
@@ -1165,8 +1198,10 @@ class LLMEngine:
         """(mm, embed, head_logits) for the step bodies, resolved once
         per program build.
 
-        f32 engines get the literal dense expressions (byte-identity
-        with every pre-quantization program); quantized engines route
+        f32 engines get the literal dense expressions, but for the
+        leaves the engine holds [L, heads, d, in] (``_out_major``): the
+        same sum, contracted on the weight's last axis, [Tq, heads, d]
+        as it comes; quantized engines route
         every projection/MLP/head matmul by ``matmul_path``: through the
         fused dequant-matmul kernel where it runs and claims the weight,
         through its term-identical XLA fake-quant reference elsewhere —
@@ -1197,7 +1232,13 @@ class LLMEngine:
                                       weight_dtype=wdt)
                 return _qm.reference_matmul(hsel, q, s, wdt)
         else:
+            out_major = self._out_major
+
             def mm(h, p, name):
+                if name in out_major:
+                    w = p[name]
+                    return lax.dot_general(
+                        h, w, (((h.ndim - 1,), (w.ndim - 1,)), ((), ())))
                 return h @ p[name]
 
             def embed(params, toks):
@@ -1215,13 +1256,14 @@ class LLMEngine:
     def _param_specs(self) -> dict:
         """PartitionSpec pytree for decode_params under the 1-D tp mesh.
 
-        q/k/v projections column-shard along their HEAD output axis
-        (leading L axis from the per-layer stack, then hidden, then
-        heads*head_dim) — each shard computes its contiguous head block
-        with the full replicated activation, so no contraction is ever
-        split and greedy outputs stay byte-identical to tp=1.  wo, the
-        MLP, and the norms replicate; the unembedding column-shards over
-        vocab only when it divides evenly.
+        q/k/v projections shard along their HEAD axis (leading L axis
+        from the per-layer stack, then heads, head_dim, hidden: the
+        engine's copy holds them [L, heads, d, in],
+        ``_hold_out_major``) — each shard computes its contiguous head
+        block with the full replicated activation, so no contraction is
+        ever split and greedy outputs stay byte-identical to tp=1.  wo,
+        the MLP, and the norms replicate; the unembedding column-shards
+        over vocab only when it divides evenly.
 
         Quantized engines shard the SAME axes: a quantized pool slices
         along its output-column axis exactly like the f32 weight it
@@ -1231,8 +1273,8 @@ class LLMEngine:
         """
         layers = {k: P() for k in self.params["layers"]}
         if self.weight_dtype == "float32":
-            for k in ("wq", "wk", "wv"):
-                layers[k] = P(None, None, "tp")
+            for k in self._out_major:
+                layers[k] = P(None, "tp")
             return {"layers": layers, "embed": P(), "norm_f": P(),
                     "head": P(None, "tp") if self._shard_head else P()}
         for k in ("wq_q", "wk_q", "wv_q"):

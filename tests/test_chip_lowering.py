@@ -540,26 +540,29 @@ _RESULT = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
 
 
 
-def _dense_step_text(chip, monkeypatch, model, tq, quant):
+def _dense_step_text(chip, monkeypatch, model, tq, quant, hidden=256,
+                     ffn=512):
     """(compiled text, the pools' shape) of the engine's ragged step
     program at a configuration's attention widths, the benchmark's pool
     (4097 pages of 16 tokens; over int8 pages the largest pool the
     scalar memory takes, pages of 32) and eight scanned layers.  The
     engine is a tiny one told the head counts (its builder reads
     nothing else of the model), the arguments are shapes, and the model
-    round the attention is narrow (hidden 256): nothing of that size is
-    allocated here, and nothing but a pool is as large as a pool."""
+    round the attention is as wide as asked (``hidden``, ``ffn``;
+    narrow unless told: nothing of that size is allocated here, and
+    nothing but a pool is then as large as a pool).  q, k and v lie as
+    the engine holds them (``_out_major``: [L, heads, d, in])."""
     from paddle_tpu.inference import LLMEngine
     from paddle_tpu.inference.sampling import samp_structs
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     nh, kvh, d = WIDTHS[model]
-    L, H, F, V = 8, 256, 512, 1000
+    L, H, F, V = 8, hidden, ffn, 1000
     bs = 32 if quant else BLOCK
     nb = max(n for n in range(8, 4096, 8) if pa.ineligible(
         nh, kvh, d, bs, jnp.int8, launch=(ROWS + 1, NBLK, n)) is None) \
         if quant else NUM_BLOCKS
     eng = LLMEngine(
-        LlamaForCausalLM(LlamaConfig.tiny(vocab=V, hidden=H, layers=2,
+        LlamaForCausalLM(LlamaConfig.tiny(vocab=V, hidden=2 * d, layers=2,
                                           heads=2, ffn=64, seq=64)),
         max_num_seqs=ROWS, block_size=bs, max_model_len=64,
         max_prefill_tokens=192, prefill_token_bucket=64,
@@ -574,12 +577,15 @@ def _dense_step_text(chip, monkeypatch, model, tq, quant):
     def sds(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
+    layers = {"wq": (H, nh * d), "wk": (H, kvh * d), "wv": (H, kvh * d),
+              "wo": (nh * d, H), "gate": (H, F), "up": (H, F),
+              "down": (F, H)}
+    for name in eng._out_major:
+        width, out = layers[name]
+        layers[name] = (out // d, d, width)
     params = {"embed": sds((V, H)), "head": sds((H, V)), "norm_f": sds((H,)),
               "layers": {"ln1": sds((L, H)), "ln2": sds((L, H)),
-                         "wq": sds((L, H, nh * d)), "wk": sds((L, H, kvh * d)),
-                         "wv": sds((L, H, kvh * d)), "wo": sds((L, nh * d, H)),
-                         "gate": sds((L, H, F)), "up": sds((L, H, F)),
-                         "down": sds((L, F, H))}}
+                         **{k: sds((L,) + v) for k, v in layers.items()}}}
     pool = (L, nb, kvh, bs, d)
     pools = (sds(pool, jnp.int8 if quant else jnp.bfloat16),) * 2
     if quant:
@@ -649,6 +655,88 @@ def test_the_dense_step_leaves_the_pools_where_they_lie(
     stacked = "{}[{}]".format("s8" if pages == "int8" else "bf16",
                               ",".join(map(str, pool)))
     assert kernel.split("operand_layout_constraints=")[1].count(stacked) == 2
+
+
+# ---------------------------------------------------------------------------
+# the layer loop reads each weight inside its product and rewrites none
+# (PR 40)
+# ---------------------------------------------------------------------------
+
+# (hidden, FFN) of the benchmark's dense configurations
+REAL_WIDTHS = {"mistral-7b": (4096, 14336), "yi-1.5-6b": (4096, 11008)}
+_MOVES_NOTHING = ("parameter", "get-tuple-element", "bitcast", "tuple")
+
+
+def _moved_in_the_layer_loop(text: str, least: int) -> tuple:
+    """(the instructions of the layer loop's body that MOVE ``least``
+    elements or more, the fusions among its instructions that only slice
+    that much out of a stacked operand).  What may be that large and
+    moves nothing: parameters, tuple elements, bitcasts; what computes
+    it: a fusion that holds a product; what writes it in place: the
+    scatters of ``kv_write``."""
+    _, comps = _computations(text)
+    loop, = [line for lines in comps.values() for line in lines
+             if " while(" in line and '/layers/while"' in line]
+    body = comps[re.search(r"\bbody=%?([\w.\-]+)", loop).group(1)]
+
+    def inside(line):
+        return [(m.group(3), l) for c in _CALLED.findall(line)
+                for l in comps[c] for m in [_RESULT.match(l)] if m]
+
+    moved, slices = [], []
+    for line in body:
+        m = _RESULT.match(line)
+        if not m or m.group(3) in _MOVES_NOTHING or math.prod(
+                int(n or 1) for n in m.group(2).split(",")) < least:
+            continue
+        ops = inside(line) if m.group(3) == "fusion" else []
+        if ops and all(op in _MOVES_NOTHING + ("constant", "dynamic-slice")
+                       for op, _ in ops):
+            slices.append(line)
+        elif not any(op in ("convolution", "dot")
+                     or (op == "scatter" and "/kv_write/" in l)
+                     for op, l in ops):
+            moved.append(line)
+    return moved, slices
+
+
+@pytest.mark.parametrize("model,tq", [("mistral-7b", 32), ("yi-1.5-6b", 192)])
+def test_the_layer_loop_rewrites_no_weight(one_chip, compiled_kernels,
+                                           no_persistent_cache, monkeypatch,
+                                           model, tq):
+    """At the configurations' REAL hidden and FFN widths (shapes only),
+    inside the layer loop no ``copy``, and no fusion but a product or a
+    ``kv_write`` scatter, has a result as large as one layer of
+    ``wk``: every weight is sliced inside the product that reads it,
+    and nothing is rewritten.
+
+    It FAILS on the parent's layout (q, k, v stacked [L, hidden, out]
+    and ``h @ w``): XLA lays q, k and v out head-major for the kernel,
+    reads each weight as [heads, d, hidden] in ``qkv/dot_general``, and
+    gets there by slicing the layer into fast memory and transposing
+    the whole matrix, in every layer of every step: at Mistral's widths
+    ``constant_dynamic-slice_fusion.6`` then ``copy.91 =
+    bf16[1,4096,4096]{1,2,0 ... S(1)}`` for ``wq``,
+    ``constant_dynamic-slice_fusion.7`` / ``copy.98`` and ``.8`` /
+    ``copy.104`` = ``bf16[1,4096,1024]{1,2,0}`` for ``wk`` and ``wv``
+    (ISSUE 40's compile numbered them ``copy.105``, ``.112``, ``.118``;
+    at Yi's ``copy.75``, ``.80``, ``.84``, k and v ``[1,4096,512]``):
+    48 MB rewritten a layer, 7 to 9% of a dense step's device time.
+    Held [L, heads * d, hidden] the three copies are gone and the three
+    slices into fast memory stay, each product waiting for its own;
+    held [L, heads, d, hidden] (``LLMEngine._hold_out_major``) XLA
+    slices inside the ``qkv/dot_general`` fusions too, as it always did
+    for ``wo``, ``gate``, ``up`` and ``down``.  (The parent's layout
+    gets the copy at hidden 256 too, ``bf16[1,256,1024]{1,2,0}``; the
+    real widths are where it cost 40 us a layer and where it is held
+    off.)"""
+    hidden, ffn = REAL_WIDTHS[model]
+    text, _ = _dense_step_text(one_chip, monkeypatch, model, tq, False,
+                               hidden, ffn)
+    _, kvh, d = WIDTHS[model]
+    moved, slices = _moved_in_the_layer_loop(text, hidden * kvh * d)
+    assert not moved, moved[0][:300]
+    assert not slices, slices[0][:300]
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,14 @@ token the host does not have yet is taken on the device from the
 sampled tokens of the launch in front).  The frozen programs know
 neither: the live program is handed part of the same tokens through
 ``prev`` (``_with_prev``) and must give what the frozen one gives on
-the tokens spelled out."""
+the tokens spelled out.
+
+PR 40 holds the engine's stacked ``wq``, ``wk`` and ``wv`` as
+[L, heads, d, in] and contracts them on the weight's last axis: the same
+sum, not at every shape the same bits as ``h @ w``.  Both sides are handed
+``eng.params`` and take ``mm`` from ``eng._weight_ops()``, so the frozen
+q/k/v products are respelled with the same ``dot_general`` over the same
+leaves, and everything else stays pinned bit for bit."""
 import jax
 import jax.numpy as jnp
 import numpy as np
