@@ -55,6 +55,17 @@ bridge):
                slice holds it: 32 of the 256 experts a layer, an eighth
                of the vocabulary.  Cut depth with --layers (5: the dense
                layer and one period, 8.2 GB in bfloat16, held once)
+    phi4flash-sm  a small decoder-hybrid-decoder: Mamba-1 layers with a
+               state a sequence beside the pages, differential attention
+               under a window of 24 and over all, gated memory units and
+               cross layers that read ONE layer's K/V (12 layers, 4 query
+               heads over 2 K/V heads).  Serve it with --no-prefix-caching
+    phi4-mini-flash-reasoning  Phi-4-mini-flash-reasoning WHOLE: 32
+               layers (9 Mamba-1 of d_inner 5120 and 16 states, 8
+               differential-attention layers under a window of 512 and
+               one over all, 40 query heads over 20 K/V heads of 64, 7
+               memory units, 7 cross layers), a tied 200064-token head:
+               7.7 GB in bfloat16, held once; no --layers cut is needed
 
 The process computes on whatever device JAX resolves, and says which on
 its start-up line together with the attention and matmul paths the
@@ -140,6 +151,15 @@ def _model_config(args):
         cfg = Dots3Config(
             vocab_size=152064 // 8, experts_held=32, ep_size=8, ep_rank=0,
             max_position_embeddings=args.max_model_len or 32768)
+    elif args.model == "phi4flash-sm":
+        from paddle_tpu.models.phi4flash import Phi4FlashConfig
+        cfg = Phi4FlashConfig.tiny(vocab=512, hidden=128, layers=12,
+                                   heads=4, kv_heads=2, ffn=256, window=24,
+                                   seq=args.max_model_len or 1024)
+    elif args.model == "phi4-mini-flash-reasoning":
+        from paddle_tpu.models.phi4flash import Phi4FlashConfig
+        cfg = Phi4FlashConfig(
+            max_position_embeddings=args.max_model_len or 8192)
     else:
         raise SystemExit(f"unknown --model {args.model!r}")
     if args.layers:
@@ -182,6 +202,9 @@ def _build_engine(args, cfg):
     elif getattr(cfg, "architecture", None) == "dots3":
         from paddle_tpu.models.dots3 import Dots3ForCausalLM
         model = Dots3ForCausalLM(cfg, dtype=args.dtype)
+    elif getattr(cfg, "architecture", None) == "phi4flash":
+        from paddle_tpu.models.phi4flash import Phi4FlashForCausalLM
+        model = Phi4FlashForCausalLM(cfg, dtype=args.dtype)
     else:
         model = LlamaForCausalLM(cfg)
         if args.dtype != "float32":
@@ -230,7 +253,8 @@ def _parser() -> argparse.ArgumentParser:
                     choices=["tiny", "llama-sm", "llama-7b", "mla-moe-sm",
                              "sarvam-105b", "smallthinker-sm",
                              "smallthinker-21b", "laguna-sm",
-                             "laguna-xs2", "dots3-sm", "dots3-note-prev"])
+                             "laguna-xs2", "dots3-sm", "dots3-note-prev",
+                             "phi4flash-sm", "phi4-mini-flash-reasoning"])
     ap.add_argument("--layers", type=int, default=0,
                     help="depth cut: build this many decoder layers "
                          "(0 = the preset's depth); widths are never cut")

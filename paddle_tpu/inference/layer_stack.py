@@ -43,7 +43,18 @@ contract for all, scanned or unrolled: a layer gets the pools of ALL
 layers and its index among the layers that share its pools, scatters the
 step's rows in place at (layer, page, slot) and its kernel reads pages
 where they lie at a prefetched layer index.  No layer-sized slice of a
-pool is ever made.  FFN kinds: ``swiglu``, ``moe`` (routed experts held here plus
+pool is ever made.  A STATE-SPACE HYBRID (``models/phi4flash.py``) has
+six kinds of its own: ``ssm`` / ``ssm_keep`` (a Mamba-1 layer, whose
+state a sequence lives beside the pages by batch slot; the second also
+leaves its scan output in the step's MEMORY), ``gmu`` (a gated unit on
+that memory), and differential attention as one body (``_diff``) under a
+window (``diff_window``), over all (``diff``) and over ANOTHER kind's
+pages with no keys or values of its own (``diff_cross``).  Its stack is
+runs of a PERIOD of kinds whose weights the model holds stacked: a
+segment may be a period, one scan whose turn is the period's layers in
+order.  What norm a model's layers have is the step context's to say
+(``c.norm``: RMSNorm with a learned scale unless told).
+FFN kinds: ``swiglu``, ``moe`` (routed experts held here plus
 shared experts: ``models/mla_moe.py``) and ``moe_reglu`` (ReGLU experts
 whose router reads ``h``, the attention block's input, not ``h2``).
 
@@ -51,7 +62,9 @@ The ``jax.named_scope`` names below are what a device trace is read by
 (docs/observability.md): ``embed``, ``layers``, ``head``, ``norm``,
 ``qkv``/``q_proj``/``kv_latent``, ``rope``, ``kv_write``, ``attn_index``
 and ``attn_select`` (an indexer's scores and its selection), ``attn``
-(``attn_window`` on a window layer's), ``attn_gate``, ``o_proj``, ``mlp``,
+(``attn_window`` on a window layer's, ``attn_cross`` on a layer's that
+reads another's pages), ``attn_diff``, ``attn_gate``, ``o_proj``,
+``ssm_proj``, ``ssm_conv``, ``ssm_scan``, ``gmu``, ``mlp``,
 and for expert layers ``router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``,
 ``shared_expert``.
 """
@@ -66,10 +79,12 @@ from jax import lax
 
 from ..models import dots3 as _d3
 from ..models import mla_moe as _mm
+from ..models import phi4flash as _ph
 from ..models import smallthinker as _st
 from ..models.llama import _rms_weight
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
+from ..ops.pallas import selective_scan as _ss
 
 
 def scan_layers(body, x, layers, pools):
@@ -146,9 +161,11 @@ def _commit_float(k, v, pools, at, c):
     return _set_rows(kc, at, k), _set_rows(vc, at, v)
 
 
-def _attend_float(q, pools, layer, c, bt=None, window=None):
+def _attend_float(q, pools, layer, c, bt=None, window=None, sm_scale=None,
+                  name=None):
     """``bt`` and ``window``: a window layer's table and its width (the
-    dense decoder passes neither)."""
+    dense decoder passes neither); ``sm_scale`` and ``name``: a softmax
+    scale and a kernel name of the caller's own (``_diff``)."""
     bt = c.bt if bt is None else bt
     if c.use_pallas:
         # the host packing path owns these buffers: bt is the int32
@@ -157,10 +174,11 @@ def _attend_float(q, pools, layer, c, bt=None, window=None):
         # per-launch re-clip and re-cast.  The kernel reads the row
         # layout itself; seg/rel are for rope and kv_write
         return _pa.ragged_paged_attention_packed(
-            q, *pools, bt, c.cu, c.kvl, layer=layer, window=window)
+            q, *pools, bt, c.cu, c.kvl, layer=layer, window=window,
+            sm_scale=sm_scale, name=name)
     return _pa.ragged_paged_reference_segrel(
         q, *(pool[layer] for pool in pools), bt, c.seg, c.rel,
-        window=window)
+        window=window, sm_scale=sm_scale)
 
 
 def _commit_int8(k, v, pools, at, c):
@@ -230,7 +248,7 @@ _GQA = {"gqa": (False, False), "gqa_nope": (False, False),
         "gqa_window": (True, False), "gqa_gated": (False, True),
         "gqa_gated_window": (True, True)}
 WINDOW_KINDS = tuple(k for k, (window, _) in _GQA.items() if window) \
-    + ("mla_window",)
+    + ("mla_window", "diff_window")
 
 
 def _gqa(x, h, p, pools, layer, c, kind="gqa"):
@@ -379,6 +397,164 @@ def _latent(x, h, p, pools, layer, c, kind="mla"):
 
 
 # ---------------------------------------------------------------------------
+# the kinds of a state-space hybrid (``models/phi4flash.py``).  Their
+# pools, in this order: K and V of the layer whose pages live under the
+# block table, K and V of the window layers, then what a SEQUENCE keeps
+# beside its pages, by batch slot: the convolution's last inputs
+# ``[Ls, slots, taps - 1, d_inner]`` and the scan's state
+# ``[Ls, slots, d_state, d_inner]`` (float32), and last the step's
+# MEMORY ``[Tq, d_inner]``, which no engine holds: ``forward`` makes it,
+# the ``ssm_keep`` layer writes it, the ``gmu`` layers read it and it
+# ends with the step.
+# ---------------------------------------------------------------------------
+
+STATE_KINDS = ("ssm", "ssm_keep")
+# kinds that keep no rows of their own under either table
+POOLLESS_KINDS = STATE_KINDS + ("gmu", "diff_cross")
+CROSS_KERNEL_NAME = "ragged_paged_attention_cross"
+_KV, _KVW, _CONV, _STATE, _MEMORY = slice(0, 2), slice(2, 4), 4, 5, 6
+
+
+def _replaced(pools, at, new):
+    pools = list(pools)
+    pools[at] = new
+    return tuple(pools)
+
+
+def _conv_rows(u, p, tails, c, taps):
+    """The causal convolution over the launch's rows.  ``u`` [Tq, di]
+    its inputs; ``tails`` [R, taps - 1, di] what each row's sequence
+    fed it last before this launch (zero where the row begins it).
+    Returns the convolved rows [Tq, di] float32 and each row's tails
+    after the launch."""
+    Tq = u.shape[0]
+    R, k = tails.shape[0], taps - 1
+    seg = jnp.minimum(c.seg, R - 1)
+    off = jnp.arange(Tq, dtype=jnp.int32) - c.cu[seg]     # place in its row
+    w = p["conv_w"].astype(jnp.float32)
+    uf, tf = u.astype(jnp.float32), tails.astype(jnp.float32)
+    out = w[k] * uf + p["conv_b"].astype(jnp.float32)
+    for back in range(1, taps):
+        # the input ``back`` rows before: of this launch, or of the tail
+        here = jnp.pad(uf, ((back, 0), (0, 0)))[:Tq]
+        before = tf[seg, jnp.clip(k + off - back, 0, k - 1)]
+        out += w[k - back] * jnp.where((off >= back)[:, None], here, before)
+    # a row's last inputs now: tails ++ its rows, the last taps - 1
+    n_q = (c.cu[1:] - c.cu[:-1])[:R]
+    at = n_q[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]   # [R, k]
+    new = u[jnp.clip(c.cu[:R, None] + at - k, 0, Tq - 1)]
+    old = jnp.take_along_axis(tails, jnp.clip(at, 0, k - 1)[:, :, None],
+                              axis=1)
+    return out, jnp.where((at >= k)[:, :, None], new.astype(tails.dtype),
+                          old)
+
+
+def _tail_rows(pool, layer, slots):
+    """The rows of ``pool`` [Ls, slots, k, di], seen as [Ls * slots * k,
+    di], that hold (layer, slots [R])'s tails: [R, k].  As ``_set_rows``
+    does, every axis but the minor one is folded into a row's number,
+    so a gather or a scatter moves contiguous ``di``-wide rows of the
+    donated buffer where it lies."""
+    _, n, k, _ = pool.shape
+    return (layer * n + slots[:, None]) * k \
+        + jnp.arange(k, dtype=jnp.int32)[None, :]
+
+
+def _ssm(x, h, p, pools, layer, c, kind="ssm"):
+    """A Mamba-1 layer over the launch's rows: a row that begins its
+    sequence (``c.state_start``) starts from zeros, one that continues
+    it reads its slot (``c.state_slot``: the row's batch slot, or for a
+    row of no tokens the slot nobody holds); every row writes back what
+    its last token left.  ``ssm_keep`` also leaves its scan output in
+    the step's memory."""
+    a, mm = c.attn[kind], c.mm
+    conv, state = pools[_CONV], pools[_STATE]
+    slots, start = c.state_slot, c.state_start
+    with jax.named_scope("ssm_proj"):
+        uz = mm(h, p, "w_in")
+        u, z = uz[:, :a.di], uz[:, a.di:]
+    with jax.named_scope("ssm_conv"):
+        rows = _tail_rows(conv, layer, slots)
+        flat = conv.reshape(-1, a.di)
+        tails = jnp.where(start[:, None, None], 0, flat[rows])
+        u, tails = _conv_rows(u, p, tails, c, a.taps)
+        u = jax.nn.silu(u).astype(h.dtype)
+        conv = flat.at[rows].set(tails.astype(conv.dtype)) \
+            .reshape(conv.shape)
+    with jax.named_scope("ssm_proj"):
+        delta, A, Bm, Cm = _ph.ssm_maps(u, p, a, mm)
+    with jax.named_scope("ssm_scan"):
+        y, state = _ss.selective_scan(
+            u, delta, A, Bm, Cm, p["D"], state, layer, slots, c.cu, start,
+            use_kernel=c.use_pallas)
+    pools = _replaced(_replaced(pools, _CONV, conv), _STATE, state)
+    if a.keep:
+        pools = _replaced(pools, _MEMORY, y.astype(pools[_MEMORY].dtype))
+    with jax.named_scope("ssm_proj"):
+        gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype)
+        x = x + mm(gated, p, "w_out")
+    return x, pools
+
+
+def _gmu(x, h, p, pools, layer, c, kind="gmu"):
+    """A gated memory unit: the step's memory (the ``ssm_keep`` layer's
+    scan output for these rows) gated by this layer's own projection."""
+    mm = c.mm
+    with jax.named_scope("gmu"):
+        g = jax.nn.silu(mm(h, p, "w_in").astype(jnp.float32))
+        gated = (pools[_MEMORY].astype(jnp.float32) * g).astype(h.dtype)
+        x = x + mm(gated, p, "w_out")
+    return x, pools
+
+
+def _diff(x, h, p, pools, layer, c, kind="diff"):
+    """Differential attention, window, full or cross by ``c.attn[kind]``:
+    two softmax maps a head pair over a value pair, subtracted and
+    normed.  K and V rows are cached as ``kvh`` heads of ``d`` = two
+    heads side by side, and a query head is widened with zeros on the
+    half it does not see: the ragged kernel as it is then returns both
+    maps' outputs at the pair's width (the zeros double the score
+    product's work and change no sum).  A layer with ``a.reads`` computes
+    no keys or values: it attends over the pages of the ``a.reads``
+    kind's ONE layer, under a kernel name of its own."""
+    a, mm = c.attn[kind], c.mm
+    Tq = c.Tq
+    cross = a.reads is not None
+    mine, bt, scope, over = _KV, c.bt, "attn", {}
+    if a.window is not None:
+        mine, bt, scope = _KVW, c.btw, "attn_window"
+        over = {"bt": bt, "window": a.window}
+    elif cross:
+        scope, over = "attn_cross", {"name": CROSS_KERNEL_NAME}
+    with jax.named_scope("qkv"):
+        if cross:
+            q = mm(h, p, "wq") + p["bq"]
+        else:
+            qkv = mm(h, p, "wqkv") + p["bqkv"]
+            q = qkv[:, :a.nh * a.hd]
+            k, v = (qkv[:, a.nh * a.hd:].reshape(Tq, 2, a.kvh, a.d)
+                    .transpose(1, 0, 2, 3))
+        q = _ph.widen(q.reshape(Tq, a.nh, a.hd))
+    if not cross:
+        with jax.named_scope("kv_write"):
+            blk, slot = _row_at(c, bt)
+            pools = list(pools)
+            pools[mine] = _commit_float(k, v, pools[mine],
+                                        (layer, blk, slot), c)
+            pools = tuple(pools)
+    with jax.named_scope(scope):
+        # (the kind a cross layer reads keeps ONE layer: index 0)
+        att = _attend_float(q, pools[mine], 0 if cross else layer, c,
+                            sm_scale=a.hd ** -0.5, **over)
+    with jax.named_scope("attn_diff"):
+        l0 = _ph.lambda_init(a.depth0 + a.stride * layer)
+        att = _ph.diff_combine(att, p, l0, a.eps)
+    with jax.named_scope("o_proj"):
+        x = x + mm(att, p, "wo") + p["bo"]
+    return x, pools
+
+
+# ---------------------------------------------------------------------------
 # FFN kinds: (x, h, h2, p, c) -> (x, what an expert layer counted or
 # None); h is the attention block's input, h2 the FFN's own
 # ---------------------------------------------------------------------------
@@ -408,9 +584,26 @@ def _moe_reglu(x, h, h2, p, c):
         return x + out, counts
 
 
+def _norm(x, p, name, c):
+    """A layer's (or the model's last) norm: RMSNorm with a learned
+    scale, or what the model hands the step context (``c.norm(x, p,
+    name)``: a LayerNorm with weight and bias, ``models/phi4flash.py``)."""
+    norm = getattr(c, "norm", None)
+    if norm is not None:
+        return norm(x, p, name)
+    return _rms_weight(x, p[name], c.eps)
+
+
 ATTENTION = {**{kind: functools.partial(_latent, kind=kind)
                 for kind in LATENT_KINDS},
-             **{kind: functools.partial(_gqa, kind=kind) for kind in _GQA}}
+             **{kind: functools.partial(_gqa, kind=kind) for kind in _GQA},
+             **{kind: functools.partial(_diff, kind=kind)
+                for kind in ("diff", "diff_window", "diff_cross")}}
+# what stands in attention's place in a layer that has none, same
+# signature: a state-space layer, a gated memory unit
+MIXERS = {**{kind: functools.partial(_ssm, kind=kind)
+             for kind in STATE_KINDS},
+          "gmu": _gmu}
 FFN = {"swiglu": _swiglu, "moe": _moe, "moe_reglu": _moe_reglu}
 
 
@@ -445,14 +638,15 @@ def layer_stack(x, segments, pools, c):
     pools = tuple(pools)
     counted = []
     def layer_of(kind):
-        attention, feed = ATTENTION[kind[0]], FFN[kind[1]]
+        attention = ATTENTION.get(kind[0]) or MIXERS[kind[0]]
+        feed = FFN[kind[1]]
 
         def layer(x, p, pools, index):
             with jax.named_scope("norm"):
-                h = _rms_weight(x, p["ln1"], c.eps)
+                h = _norm(x, p, "ln1", c)
             x, pools = attention(x, h, p, pools, index, c)
             with jax.named_scope("norm"):
-                h2 = _rms_weight(x, p["ln2"], c.eps)
+                h2 = _norm(x, p, "ln2", c)
             x, counts = feed(x, h, h2, p, c)
             return x, pools, counts
         return layer
@@ -463,7 +657,18 @@ def layer_stack(x, segments, pools, c):
         return jax.jit(layer_of(kind))
 
     for kind, layers, scanned in segments:
-        if scanned:
+        if scanned and isinstance(kind[0], tuple):
+            # a PERIOD of kinds, each kind's weights stacked over the
+            # repeats: one scan, a turn a repeat, whose number is each
+            # of its layers' index
+            period = [layer_of(k) for k in kind]
+
+            def turn(x, ps, pools, l, period=period):
+                for layer, p in zip(period, ps):
+                    x, pools, _ = layer(x, p, pools, l)
+                return x, pools
+            x, pools = scan_layers(turn, x, layers, pools)
+        elif scanned:
             layer = layer_of(kind)
             x, pools = scan_layers(lambda *a: layer(*a)[:2], x, layers,
                                    pools)
@@ -490,8 +695,21 @@ def forward(params, toks, pools, c, lidx=None):
     Returns (logits, pools, counts)."""
     with jax.named_scope("embed"):
         x = c.embed(params, toks)                             # [Tq, H]
+    memory = getattr(c, "memory", None)
+    if memory is not None:
+        # what one layer of the step leaves for later ones (its width,
+        # its type): a value of the step's own, carried with the pools
+        pools = tuple(pools) + (jnp.zeros((x.shape[0], memory[0]),
+                                          memory[1]),)
     if c.scanned:
         segments = [(c.kinds[0], params["layers"], True)]
+    elif getattr(c, "periods", None) is not None:
+        # runs of a period whose weights the model holds stacked, and
+        # single layers between them (``config.periods()``)
+        segments = [(kinds, ps, True) if n > 1
+                    else (kinds[0], [(index, ps[0])], False)
+                    for (kinds, n, index), ps in zip(c.periods,
+                                                     params["layers"])]
     else:
         segments = []
         for i, k in enumerate(c.kinds):
@@ -502,8 +720,10 @@ def forward(params, toks, pools, c, lidx=None):
             segments[-1][1].append((at, params["layers"][i]))
     with jax.named_scope("layers"):
         x, pools, counts = layer_stack(x, segments, pools, c)
+    if memory is not None:
+        pools = pools[:-1]
     with jax.named_scope("norm"):
-        h = _rms_weight(x, params["norm_f"], c.eps)
+        h = _norm(x, params, "norm_f", c)
     with jax.named_scope("head"):
         if lidx is not None:
             h = h[lidx]                                       # [Lq, H]

@@ -36,7 +36,8 @@ from jax import lax
 __all__ = [
     "LogitProcessor", "RepetitionPenaltyProcessor", "TemperatureProcessor",
     "TopKProcessor", "TopPProcessor", "DEFAULT_CHAIN", "advance_keys",
-    "make_samp", "samp_structs", "sample_tokens", "target_dist",
+    "greedy_tokens", "make_samp", "samp_structs", "sample_tokens",
+    "target_dist",
 ]
 
 _NEG_INF = float("-inf")
@@ -156,6 +157,18 @@ def advance_keys(base_keys, offsets):
     return jax.vmap(jax.random.fold_in)(base_keys, offsets)
 
 
+def greedy_tokens(logits, samp, chain=DEFAULT_CHAIN):
+    """What every row's token is unless the row is sampled: the argmax
+    after the greedy-visible stages, and the logits those stages left
+    (``sample_tokens``' first half; a step program whose sampled tail
+    is compiled apart ends here)."""
+    lg = logits.astype(jnp.float32)
+    for proc in chain:
+        if proc.greedy_visible:
+            lg = proc(lg, samp, jnp)
+    return lg, jnp.argmax(lg, -1).astype(jnp.int32)
+
+
 def sample_tokens(logits, samp, chain=DEFAULT_CHAIN):
     """Device-side per-sequence sampling over [B, V] logits.
 
@@ -167,11 +180,7 @@ def sample_tokens(logits, samp, chain=DEFAULT_CHAIN):
     predicate is a real conditional on the device, so an all-greedy
     launch executes the argmax and nothing after it.
     """
-    lg = logits.astype(jnp.float32)
-    for proc in chain:
-        if proc.greedy_visible:
-            lg = proc(lg, samp, jnp)
-    greedy_tok = jnp.argmax(lg, -1).astype(jnp.int32)
+    lg, greedy_tok = greedy_tokens(logits, samp, chain)
 
     def sampled_tail(lg):
         for proc in chain:

@@ -83,6 +83,7 @@ from ..models.llama import _rope_positions
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import quant_matmul as _qm
+from ..ops.pallas import selective_scan as _scan
 from ..profiler import ServingStats
 from .faults import InjectedFault
 from . import layer_stack as _ls
@@ -90,8 +91,8 @@ from .kv_cache import (NULL_BLOCK, BlockManager, BlockPoolExhausted,
                        prefix_chain_hashes)
 from .policy import pack_prefill_chunks
 from .pressure import STATE_NAMES as _TIER_NAMES
-from .sampling import (advance_keys, make_samp, samp_structs,
-                       sample_tokens)
+from .sampling import (advance_keys, greedy_tokens, make_samp,
+                       samp_structs, sample_tokens)
 
 __all__ = ["LLMEngine", "Request", "RequestOutput"]
 
@@ -100,7 +101,8 @@ __all__ = ["LLMEngine", "Request", "RequestOutput"]
 # An engine's attention-bearing program kinds are bounded by it, whatever
 # its requests do (rule ``attention-program-budget``).
 ATTENTION_KINDS = ("mla", "mla_select", "mla_window", "gqa", "gqa_nope",
-                   "gqa_window", "gqa_gated", "gqa_gated_window")
+                   "gqa_window", "gqa_gated", "gqa_gated_window", "diff",
+                   "diff_window", "diff_cross")
 
 
 @dataclass(eq=False)
@@ -213,8 +215,8 @@ class _DecodeBufs:
     per-request ``bt_version`` field: each buffer tracks its own
     staleness).  ``layout`` is the rid order last packed."""
 
-    __slots__ = ("toks", "src", "cu", "kvl", "bt", "samp", "layout",
-                 "bt_ver")
+    __slots__ = ("toks", "src", "cu", "kvl", "slot", "bt", "samp",
+                 "layout", "bt_ver")
 
     def __init__(self, B, bt_shape, Lq, vocab_size):
         self.toks = np.zeros((B,), np.int32)
@@ -223,6 +225,9 @@ class _DecodeBufs:
         self.src = np.full((B,), -1, np.int32)
         self.cu = np.zeros((B + 1,), np.int32)
         self.kvl = np.zeros((B,), np.int32)
+        # each row's batch slot (where a state-space model keeps its
+        # state); B, the slot nobody holds, for a row of no request
+        self.slot = np.full((B,), B, np.int32)
         # [B + 1, nblk], or [2, B + 1, nblk] where window layers have a
         # table of their own (LLMEngine._bt_shape)
         self.bt = np.full(bt_shape, NULL_BLOCK, np.int32)
@@ -356,6 +361,34 @@ def _refuse_latent_options(**asked) -> None:
     _refuse("latent-attention (MLA) layers", needs, asked)
 
 
+def _refuse_state_options(**asked) -> None:
+    """A model with state-space layers keeps, beside its pages, a state
+    a SEQUENCE a layer (the convolution's last inputs and the scan's
+    state), by batch slot: written by every launch, read by the next.
+    Each option below needs what its message says before it can be
+    taken: nothing has run it over a state.  Such a model has window
+    layers too and is refused by ``_refuse_window_options`` as well,
+    this table first."""
+    _refuse("state-space layers", {
+        "enable_prefix_caching": (False, "a prefix hit needs a snapshot "
+                                  "of the state at the prefix's end (at "
+                                  "page boundaries, beside the pages)"),
+        "drafter": (None, "a drafter needs the state rolled back to the "
+                    "last accepted row of a verify window"),
+        "decode_window": (1, "decode_window > 1 needs the state carried "
+                          "through the device loop's turns"),
+        "kv_tier": (None, "kv_tier needs the spill and restore of a "
+                    "sequence's state with its pages"),
+        "kv_dtype": ("float32", "int8 pages need scale pools beside the "
+                     "two page pools, and nothing has run them beside a "
+                     "state"),
+        "weight_dtype": ("float32", "int8/int4 weights need a dequant "
+                         "product for the scan's projections"),
+        "tp": (1, "tp > 1 needs the state's d_inner and the pages' heads "
+               "laid over a mesh"),
+    }, asked)
+
+
 # what wraps a launch when no tracer is installed: the jitted call keeps
 # one call site either way (see ``_call_program``)
 _NO_ANNOTATION = contextlib.nullcontext()
@@ -398,6 +431,13 @@ def _pack_results(sampled, fin, counts=None):
     if counts is not None:
         parts.append(counts)
     return jnp.concatenate(parts)
+
+
+def _sampled_tail(logits, samp, packed):
+    """``LLMEngine._get_tail_prog``'s program: (sampled, packed)."""
+    with jax.named_scope("sample"):
+        sampled = sample_tokens(logits, samp)
+        return sampled, lax.dynamic_update_slice(packed, sampled, (0,))
 
 
 def _unpack_results(host, shape):
@@ -587,6 +627,20 @@ class LLMEngine:
                             for k in self._layer_kinds)
         self._has_experts = any(f in ("moe", "moe_reglu")
                                 for _, f in self._layer_kinds)
+        # state-space layers keep a state a sequence, by batch slot
+        self._stateful = any(a in _ls.STATE_KINDS
+                             for a, _ in self._layer_kinds)
+        if self._stateful:
+            if not any(a in _ls.WINDOW_KINDS for a, _ in self._layer_kinds):
+                raise ValueError(
+                    "state-space layers without sliding-window layers: "
+                    "the state rides beside the TWO page tables of a "
+                    "window model, and no step program has it beside one")
+            _refuse_state_options(
+                enable_prefix_caching=bool(enable_prefix_caching),
+                drafter=drafter, decode_window=decode_window,
+                kv_tier=kv_tier, kv_dtype=kv_dtype,
+                weight_dtype=weight_dtype, tp=tp)
         if self._latent:
             _refuse_latent_options(
                 kv_dtype=kv_dtype, weight_dtype=weight_dtype, tp=tp,
@@ -725,13 +779,20 @@ class LLMEngine:
             # total need not be the hidden size (28 x 128 over 2560)
             self._hd = int(getattr(cfg, "head_dim", 0)
                            or cfg.hidden_size // self._nh)
+            if hasattr(cfg, "page_shape"):
+                # a cached row need not be laid out as the heads that
+                # made it (two heads of 64 side by side: phi4flash)
+                self._kvh, self._hd = cfg.page_shape()
             self._kvh_w, self._hd_w = self._kvh, self._hd
-        self._kw = self._vw = self._ki = None
+        self._kw = self._vw = self._ki = self._sc = self._ss = None
         # a layer's index into the pools of its kind (None: its place
         # in the model, one pool for all layers)
         self._pool_index = None
         with jax.default_device(devices[0]):
             is_w = [a in _ls.WINDOW_KINDS for a, _ in self._layer_kinds]
+            # layers whose rows live under the block table
+            n_g = sum(not w and a not in _ls.POOLLESS_KINDS
+                      for w, (a, _) in zip(is_w, self._layer_kinds))
             if self._windowed:
                 # the global layers' pages live as long as their
                 # sequence ([Lg, num_blocks, ...], the block table's),
@@ -761,12 +822,20 @@ class LLMEngine:
             elif self._windowed:
                 # TWO pairs of pools
                 page = (self._kvh, self.block_size, self._hd)
-                self._kc = jnp.zeros((L - sum(is_w), num_blocks) + page, dt)
+                self._kc = jnp.zeros((n_g, num_blocks) + page, dt)
                 self._vc = jnp.zeros_like(self._kc)
                 self._kw = jnp.zeros((sum(is_w), self._window_blocks)
                                      + page, dt)
                 self._vw = jnp.zeros_like(self._kw)
                 self._ks = self._vs = None
+                if self._stateful:
+                    # a slot a running sequence and one nobody holds
+                    # (what a launch's rows of no tokens name): the
+                    # convolution's tails in the served type, the scan's
+                    # state float32
+                    conv, scan = cfg.state_shapes(self.max_num_seqs + 1)
+                    self._sc = jnp.zeros(conv, dt)
+                    self._ss = jnp.zeros(scan, jnp.float32)
             elif self.kv_dtype == "int8":
                 # int8 pages + a parallel per-page-per-head f32 scale
                 # pool (symmetric: float = int8 * scale).  Scales are
@@ -853,6 +922,13 @@ class LLMEngine:
         # draft acceptance) only when a drafter exists.
         self._with_logits = drafter is not None
         self._Lq = B * (self.max_spec_k + 1) if self._with_logits else B
+        # Where the MODEL says so (a vocabulary at which the sampled
+        # rows' sorts are most of a step program's code), a step
+        # program ends at the greedy token and hands its logits on; a
+        # launch that holds a sampled row is followed by ONE program of
+        # its own, the same for every token bucket (``_get_tail_prog``)
+        self._tail_apart = bool(getattr(cfg, "sampled_tail_apart", False))
+        self._tail_prog = None
         self.attention_path = self._resolve_attention_path()
         self.matmul_path = self._resolve_matmul_path()
         # program name ("ragged:64", "window:4") -> the paths it was built
@@ -927,7 +1003,8 @@ class LLMEngine:
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
                           "kv_pages": 0, "kv_pages_window": 0,
-                          "index_keys_visible": 0, "index_keys_selected": 0}
+                          "index_keys_visible": 0, "index_keys_selected": 0,
+                          "state_starts": 0, "state_rows": 0}
         # passes of the sampling epilogue, and those whose launch held a
         # sampled row (temps > 0): the device runs the sampled chain in
         # exactly those (sampling.sample_tokens branches on the same)
@@ -1151,13 +1228,16 @@ class LLMEngine:
             why = next((w for w in whys if w is not None), None)
             return "pallas" if why is None else f"xla-reference ({why})"
         # every kind's head count has to be one the kernel claims
-        whys = (_pa.ineligible(a.nh, self._kvh // self.tp,
+        whys = [_pa.ineligible(a.nh, self._kvh // self.tp,
                                self._hd, self.block_size,
                                jnp.int8 if self.kv_dtype == "int8"
                                else self._act_dtype,
                                launch=(self.max_num_seqs + 1, self.nblk,
                                        self.blocks.num_blocks))
-                for a in self._attn.values())
+                for k, a in self._attn.items() if k in _ls.ATTENTION]
+        # and a state-space kind's sizes ones the scan kernel claims
+        whys += [_scan.ineligible(a.di, a.n)
+                 for k, a in self._attn.items() if k in _ls.STATE_KINDS]
         why = next((w for w in whys if w is not None), None)
         return "pallas" if why is None else f"xla-reference ({why})"
 
@@ -1245,6 +1325,12 @@ class LLMEngine:
                 return jnp.take(params["embed"], toks, axis=0)
 
             def head_logits(params, hsel):
+                if "head" not in params:
+                    # a tied head: the embedding [V, H], contracted on H
+                    return lax.dot_general(
+                        hsel.astype(jnp.float32),
+                        params["embed"].astype(jnp.float32),
+                        (((1,), (1,)), ((), ())))
                 return (hsel.astype(jnp.float32)
                         @ params["head"].astype(jnp.float32))
         return mm, embed, head_logits
@@ -1322,7 +1408,7 @@ class LLMEngine:
         if self.tp == 1:
             return run
         if n_front is None:
-            n_front = 2 + self._with_logits
+            n_front = 2 + (self._with_logits or self._tail_apart)
         kv = P(None, None, "tp")
         pools = (kv,) * len(self._pools())
         return shard_map(
@@ -1635,6 +1721,13 @@ class LLMEngine:
             # and pages live sequences gave back as they moved on
             out["kv_pages_window"] = self.pad_stats["kv_pages_window"]
             out["window_pages_returned"] = self.blocks.window_returned
+        if self._stateful:
+            # slots sequences hold a state in now; rows a layer's scan
+            # began from zeros (a sequence's first) and rows it scanned,
+            # summed over launches
+            out["state_slots"] = sum(self._slot_used)
+            out["state_starts"] = self.pad_stats["state_starts"]
+            out["state_rows"] = self.pad_stats["state_rows"]
         if self._index_topk:
             # (query, key) pairs the indexed layers' queries saw and
             # those they selected, a layer's, summed over launches
@@ -1692,14 +1785,18 @@ class LLMEngine:
 
     # the pools an engine may hold, in the order a program takes them:
     # those under the block table, then the window table's
-    _POOLS = ("_kc", "_vc", "_ks", "_vs", "_ki", "_kw", "_vw")
+    # and last what a sequence keeps by batch slot (a state-space model)
+    _POOLS = ("_kc", "_vc", "_ks", "_vs", "_ki", "_kw", "_vw", "_sc", "_ss")
 
     def _pools(self) -> tuple:
         """The page pools every program takes after the parameters and
         gives back: K and V (over int8 pages their scale pools too), or
         the one latent pool (and an indexer's keys beside it), each
         [L, num_blocks, ...]; with window layers the global layers'
-        pools, then the window layers' [Lw, Nw, ...]."""
+        pools, then the window layers' [Lw, Nw, ...]; with state-space
+        layers, last, what a sequence keeps by batch slot
+        [Ls, max_num_seqs + 1, ...]: the convolution's last inputs and
+        the scan's state."""
         return tuple(getattr(self, n) for n in self._POOLS
                      if getattr(self, n) is not None)
 
@@ -1716,8 +1813,8 @@ class LLMEngine:
         block table's pools, the global layers' (the window layers'
         pages are counted as they are held: ``kv_bytes_resident``)."""
         return sum(x.size // x.shape[1] * np.dtype(x.dtype).itemsize
-                   for x in self._pools() if x is not self._kw
-                   and x is not self._vw)
+                   for x in (self._kc, self._vc, self._ks, self._vs,
+                             self._ki) if x is not None)
 
     def _window_bytes_resident(self) -> int:
         """Bytes of the window layers' pages that sequences hold."""
@@ -1819,7 +1916,9 @@ class LLMEngine:
         sds = jax.ShapeDtypeStruct
         i32 = jnp.int32
         B = self.max_num_seqs
-        return self._step_head_structs(placed) + (
+        # with a state each row's batch slot comes first
+        slots = (sds((B,), i32),) if self._stateful else ()
+        return self._step_head_structs(placed) + slots + (
             sds((Tq,), i32), sds((B + 1,), i32), sds((B,), i32),
             sds(self._bt_shape, i32), sds((self._Lq,), i32),
             samp_structs(self._Lq, self.config.vocab_size),
@@ -1927,6 +2026,14 @@ class LLMEngine:
                 self._window_arg_structs(),
                 donate_argnums=win_donate, declared_dtype=declared,
                 large_bytes=large_bytes))
+        if self._tail_apart:
+            V = self.config.vocab_size
+            out.append(ProgramSpec(
+                "serving.sampled_tail" + sfx, _sampled_tail,
+                (sds((self._Lq, V), jnp.float32),
+                 samp_structs(self._Lq, V),
+                 sds((2 * self._Lq + 4 * self._has_experts,), jnp.int32)),
+                declared_dtype=None, large_bytes=large_bytes))
         return out
 
     # ------------------------------------------------------------------
@@ -3408,6 +3515,20 @@ class LLMEngine:
             self._record_program(f"ragged:{Tq}")
         return prog
 
+    def _get_tail_prog(self):
+        """The sampled rows' chain as a program of its own (one an
+        engine, whatever the token bucket; built at the first launch
+        that holds a sampled row): from the logits a step program
+        handed on and the launch's ``samp``, every row's token as
+        ``sample_tokens`` gives it (a greedy row's is the step
+        program's own), once as it is and once in the packed vector's
+        first rows, whose other rows (finiteness, expert counts) pass
+        through."""
+        if self._tail_prog is None:
+            self._tail_prog = jax.jit(_named(_sampled_tail, "sampled_tail"))
+            self._program_built("sampled_tail", "sampled_tail")
+        return self._tail_prog
+
     def _step_shared(self, Tq: int) -> dict:
         """What the layers of a step program over ``Tq`` flat tokens
         share whatever the launch holds: ``layer_stack.step_context``
@@ -3431,6 +3552,10 @@ class LLMEngine:
         if self._windowed:
             shared.update(cfg=cfg, window=self._window,
                           pool_index=self._pool_index)
+        if hasattr(cfg, "step_fields"):
+            # what the MODEL says of its stack, its norm and what its
+            # layers hand one another inside a step
+            shared.update(cfg.step_fields(self._act_dtype))
         return shared
 
     def _make_ragged_fn(self, Tq: int):
@@ -3460,10 +3585,12 @@ class LLMEngine:
         into them in place at (layer, page, slot) and its kernel reads
         them at a layer index: no layer-sized slice of a pool is made,
         and the pools that come back are the buffers that went in."""
-        with_logits = self._with_logits
+        apart = self._tail_apart
+        with_logits = self._with_logits or apart
         n_pools = len(self._pools())
         q8 = self.kv_dtype == "int8"
         windowed = self._windowed
+        stateful = self._stateful
         # (``run`` below must not close over ``self``: a compiled program
         # that holds its engine keeps it alive past its last user)
         shared = self._step_shared(Tq)
@@ -3471,7 +3598,8 @@ class LLMEngine:
         def run(params, *rest):
             # rest: the page pools (over int8 pages K, V and their
             # [L, num_blocks, H_kv] f32 scale pools, then fresh
-            # [num_blocks] bool: pages whose scales reset this launch),
+            # [num_blocks] bool: pages whose scales reset this launch;
+            # with a state instead slots [B] i32, each row's batch slot),
             # then toks [Tq] i32, rows packed back-to-back (tail padding
             # maps to the sentinel row); cu [B+1] i32 row offsets; kvl
             # [B] i32 valid KV per row AFTER this launch's writes; bt
@@ -3495,13 +3623,24 @@ class LLMEngine:
             # global layers', then the window layers' (entries below a
             # row's window name the null page)
             tables = dict(bt=bt[0], btw=bt[1]) if windowed else dict(bt=bt)
+            if stateful:
+                # a row's state: its batch slot, or for a row of no
+                # tokens the slot nobody holds; it starts from zeros
+                # where the row's first token is its sequence's first
+                n_q = cu[1:] - cu[:-1]
+                tables.update(
+                    state_slot=jnp.where(n_q > 0, host[0], kvl.shape[0]),
+                    state_start=kvl == n_q)
             c = _ls.step_context(seg=seg, rel=rel, cu=cu, kvl=kvl,
                                  fresh=host[0] if q8 else None, **tables,
                                  **shared)
             logits, pools, counts = _ls.forward(params, toks, pools, c,
                                                 lidx)
             with jax.named_scope("sample"):
-                sampled = sample_tokens(logits, samp)
+                # (apart: the sampled rows' chain is a program of its
+                # own, which takes the logits returned below)
+                sampled = greedy_tokens(logits, samp)[1] if apart \
+                    else sample_tokens(logits, samp)
                 # per-row finiteness flag: the quarantine guard retires a
                 # poisoned row host-side without touching its batchmates
                 # (padded rows may be legitimately non-finite; the host
@@ -3517,7 +3656,8 @@ class LLMEngine:
         # donation reuses the pool buffers (pages and scales) in place;
         # fresh is input-only.  _get_ragged_prog drops donation on CPU
         # (that runtime cannot alias and warns per call)
-        return self._wrap_tp(run, 8 + q8), tuple(range(1, 1 + n_pools))
+        return self._wrap_tp(run, 8 + q8 + stateful), \
+            tuple(range(1, 1 + n_pools))
 
     def _consume_fresh(self):
         """Accumulate BlockManager's freshly handed-out pages into the
@@ -3597,10 +3737,12 @@ class LLMEngine:
                           "table_ns": tr.now() - t_rows})
 
     def _launch_ragged(self, Tq, toks, cu, kvl, bt, lidx, samp,
-                       real_tokens, src=None):
+                       real_tokens, src=None, slots=None):
         """One launch of the step program at bucket ``Tq``.  ``src``:
         the rows of the in-flight launch's ``sampled`` that ``toks``
         takes on the device (None: every token is staged in ``toks``).
+        ``slots``: each row's batch slot, which a model with a state a
+        sequence takes and no other.
         Returns the launch's ``sampled`` (for the next launch), the
         packed vector the host reads, already on its way, and the verify
         logits or None: unmaterialized device arrays."""
@@ -3614,13 +3756,21 @@ class LLMEngine:
         pages = self._launch_pages = self._launch_kv_args(cu, kvl)
         self.pad_stats["kv_pages"] += pages["kv_pages"]
         for name in ("kv_pages_window", "index_keys_visible",
-                     "index_keys_selected"):
+                     "index_keys_selected", "state_starts", "state_rows"):
             self.pad_stats[name] += pages.get(name, 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
+        host = (toks, cu, kvl, bt, lidx, samp, prev, src)
+        if self._stateful:
+            host = (slots,) + host
         sampled, packed, *logits = self._call_program(
-            self._get_ragged_prog(Tq),
-            (toks, cu, kvl, bt, lidx, samp, prev, src), Tq)
+            self._get_ragged_prog(Tq), host, Tq)
+        if self._tail_apart:
+            if _sample_chain(samp):
+                sampled, packed = self._get_tail_prog()(
+                    logits[0], samp, packed)
+            if not self._with_logits:
+                logits = ()
         # the transfer starts when the execution ends, with nobody
         # asking: the host finds the vector there when it comes to read
         packed.copy_to_host_async()
@@ -3651,6 +3801,12 @@ class LLMEngine:
                        kv_pages_window=self._kv_pages_window(cu, kvl))
         if self._index_topk:
             out.update(self._index_keys(cu, kvl))
+        if self._stateful:
+            n_q = np.diff(np.asarray(cu))[:len(kvl)]
+            out.update(
+                state_starts=int(((np.asarray(kvl) == n_q)
+                                  & (n_q > 0)).sum()),
+                state_rows=int(n_q.sum()))
         return out
 
     def _index_keys(self, cu, kvl) -> dict:
@@ -3852,6 +4008,7 @@ class LLMEngine:
         src = np.full((Tq,), -1, np.int32)
         cu = np.zeros((B + 1,), np.int32)
         kvl = np.zeros((B,), np.int32)
+        slots = np.full((B,), B, np.int32)
         bt = np.full(self._bt_shape, NULL_BLOCK, np.int32)
         lidx = np.zeros((self._Lq,), np.int32)
         samp = make_samp(self._Lq, self.config.vocab_size)
@@ -3868,6 +4025,7 @@ class LLMEngine:
             toks[off:off + n] = window
             cu[i + 1] = off + n
             kvl[i] = self._pos(req) + n
+            slots[i] = req.slot
             if kind == "d" and req.inflight:
                 src[off] = self._token_src(req)
             if kind == "s":
@@ -3916,7 +4074,7 @@ class LLMEngine:
         if tr is not None:
             t = tr.now()
         sampled, packed, logits = self._launch_ragged(
-            Tq, toks, cu, kvl, bt, lidx, samp, total, src)
+            Tq, toks, cu, kvl, bt, lidx, samp, total, src, slots)
         if spec:
             # the verify logits are read too: on their way as well
             logits.copy_to_host_async()
@@ -3967,6 +4125,8 @@ class LLMEngine:
             buf.layout = layout
             buf.bt[:] = NULL_BLOCK
             buf.kvl[:] = 0
+            buf.slot[:] = self.max_num_seqs
+            buf.slot[:n] = [req.slot for req in batch]
             buf.cu[:n + 1] = np.arange(n + 1)
             buf.cu[n + 1:] = n
             samp["temps"][:] = 0.0
@@ -4014,7 +4174,7 @@ class LLMEngine:
         sampled, packed, _ = self._launch_ragged(Tq, buf.toks, buf.cu,
                                                  buf.kvl, buf.bt,
                                                  self._d_lidx, samp, n,
-                                                 buf.src)
+                                                 buf.src, buf.slot)
         if tr is not None:
             tr.complete("engine.device_launch", t,
                         track=self._trace_track,

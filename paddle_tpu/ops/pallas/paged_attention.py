@@ -597,7 +597,8 @@ WINDOW_KERNEL_NAME = "ragged_paged_attention_window"
 
 
 def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
-                   kv_lens, scales=(), layer=None, window=None):
+                   kv_lens, scales=(), layer=None, window=None,
+                   sm_scale=None, name=None):
     """The raw ragged launch.  With ``layer`` (an int32 scalar, traced
     or static) the caches are the pools of ALL layers,
     [L, num_blocks, Hkv, bs, D], read where they lie at that index,
@@ -613,7 +614,10 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
     window - 1 before it; the table's entries for a row's pages below
     its window are not read.  Such a launch has a kernel name of its
     own, so that a device trace (which carries names and no scope)
-    tells a window layer's launches from the others'."""
+    tells a window layer's launches from the others'.  ``sm_scale``:
+    the softmax scale where it is not ``1 / sqrt(D)`` (heads narrower
+    than the rows they are stored in); ``name``: a kernel name of the
+    caller's own, for launches a trace has to tell from both."""
     if layer is None:
         key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
     Tq, H, D = q.shape
@@ -621,7 +625,8 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
     G = H // Hkv
     rows = kv_lens.shape[0]
     nblk = block_tables.shape[1]
-    sm_scale = 1.0 / (D ** 0.5)
+    if sm_scale is None:
+        sm_scale = 1.0 / (D ** 0.5)
     quant = bool(scales)
     tq, kvb = _ragged_tiles(Tq, Hkv, G, D, bs, nblk, key_cache.dtype)
     M = tq * G
@@ -666,7 +671,8 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=min(100 << 20, max(16 << 20, 2 * need))),
         interpret=interpret_mode(),
-        name="ragged_paged_attention_q8" if quant
+        name=name if name is not None
+        else "ragged_paged_attention_q8" if quant
         else "ragged_paged_attention" if window is None
         else WINDOW_KERNEL_NAME,
     )(cu_seqlens, kv_lens, block_tables,
@@ -677,7 +683,7 @@ def _ragged_launch(q, key_cache, value_cache, block_tables, cu_seqlens,
 
 def ragged_paged_attention_packed(q, key_cache, value_cache, block_tables,
                                   cu_seqlens, kv_lens, layer=None,
-                                  window=None):
+                                  window=None, sm_scale=None, name=None):
     """Ragged launch without the defensive clip/casts, for callers that
     guarantee the host-packing invariant (serving.py owns these buffers:
     its table pool is int32 and NULL_BLOCK-padded with valid indices,
@@ -687,7 +693,8 @@ def ragged_paged_attention_packed(q, key_cache, value_cache, block_tables,
     without it, one layer's.  ``window``: a sliding-window layer's
     launch (``_ragged_launch``)."""
     return _ragged_launch(q, key_cache, value_cache, block_tables,
-                          cu_seqlens, kv_lens, layer=layer, window=window)
+                          cu_seqlens, kv_lens, layer=layer, window=window,
+                          sm_scale=sm_scale, name=name)
 
 
 def ragged_paged_attention(q, key_cache, value_cache, block_tables,
@@ -758,7 +765,7 @@ def ragged_paged_reference_quant_segrel(q, key_cache, value_cache,
 
 
 def ragged_paged_reference_segrel(q, key_cache, value_cache, block_tables,
-                                  seg, rel, window=None):
+                                  seg, rel, window=None, sm_scale=None):
     """Dense-gather XLA oracle for the ragged kernel (the engine's former
     chunked-resume math, term for term).  ``window``: keys below a
     query's position less window - 1 are masked as well (what their
@@ -779,7 +786,8 @@ def ragged_paged_reference_segrel(q, key_cache, value_cache, block_tables,
         g = H // Hkv
         kq = jnp.repeat(kq, g, axis=2)
         vq = jnp.repeat(vq, g, axis=2)
-    sm_scale = 1.0 / (D ** 0.5)
+    if sm_scale is None:
+        sm_scale = 1.0 / (D ** 0.5)
     scores = jnp.einsum("qhd,qshd->qhs", q.astype(jnp.float32),
                         kq.astype(jnp.float32)) * sm_scale
     keypos = jnp.arange(nblk * bs, dtype=jnp.int32)

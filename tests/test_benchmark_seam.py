@@ -90,13 +90,20 @@ def test_the_builders_model_has_the_shapes_files_leaves(config):
     elif cfg["reference"] == "mla_moe":
         cfg.update(num_experts=2, expert_parallel=dict(
             cfg["expert_parallel"], router_width=8))
+    elif cfg["reference"] == "phi4flash":
+        # the least depth that holds every kind of its layers
+        cfg.update(num_hidden_layers=12)
     # (any other: the builder draws and allocates nothing, so the
     # published widths stay as they are)
     model = builder.construct(cfg)
     want = sorted(tuple(shape) for _n, _at, shape, _k in arch.leaves(cfg))
     got = sorted(tuple(p._data.shape) for p in model.parameters())
     assert got == want
-    made = {"top": {}, "layers": [{} for _ in range(2)]}
+    # (a leaf's tag is its layer, or a run of the stack whose leaves
+    # are declared stacked)
+    tags = 1 + max(at for _n, at, _s, _k in arch.leaves(cfg)
+                   if at is not None)
+    made = {"top": {}, "layers": [{} for _ in range(tags)]}
     for n, at, shape, _k in arch.leaves(cfg):
         (made["top"] if at is None else made["layers"][at])[n] = \
             jax.ShapeDtypeStruct(shape, "float32")

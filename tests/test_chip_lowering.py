@@ -878,3 +878,165 @@ def test_the_three_pool_step_leaves_the_pools_where_they_lie(
         assert len(mine) == n, (name, len(mine))
         stacked = "bf16[{}]".format(",".join(map(str, pool)))
         assert all(stacked in c for c in mine), name
+
+
+# ---------------------------------------------------------------------------
+# the state-space hybrid (PR 42), at Phi-4-mini-flash's sizes: d_inner
+# 5120 with 16 states, 40 query heads of 64 widened over 10 cached heads
+# of 128, a window of 512 under 256-token chunks (token buckets 32 to
+# 320), each row's batch slot a [32] vector beside the two tables
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tq", [32, 320])
+def test_the_selective_scan_compiles_for_the_v5e(one_chip, compiled_kernels,
+                                                 no_persistent_cache, tq):
+    """The ragged selective scan at the real widths, the state of nine
+    layers and 33 slots read and written IN PLACE (the aliased operand
+    comes back as the second result, and nothing else has its shape)."""
+    from paddle_tpu.ops.pallas import selective_scan as ss
+    di, n = 5120, 16
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = (sds((tq, di)), sds((tq, di)), sds((n, di)), sds((tq, n)),
+            sds((tq, n)), sds((di,)), sds((9, ROWS + 1, n, di)),
+            sds((), jnp.int32), sds((ROWS,), jnp.int32),
+            sds((ROWS + 1,), jnp.int32), sds((ROWS,), jnp.bool_))
+    text = jax.jit(lambda *a: ss.selective_scan(*a, use_kernel=True),
+                   donate_argnums=(6,)).lower(*args).compile().as_text()
+    call, = [l for l in text.splitlines() if " custom-call(" in l
+             and ss.KERNEL_NAME in l]
+    assert f"f32[{tq},{di}]" in call and f"f32[9,{ROWS + 1},{n},{di}]" in call
+    state = [l for l in text.splitlines()
+             if re.search(rf"= \(?f32\[9,{ROWS + 1},{n},{di}\]", l)
+             and " parameter(" not in l and " custom-call(" not in l
+             and " get-tuple-element(" not in l and " bitcast(" not in l]
+    assert not state, state[0][:300]
+
+
+def _phi4flash_step(chip, monkeypatch, tq, layers=12, vocab=1000):
+    """(compiled step program, the shapes of its six pools) of the
+    state-space hybrid at Phi-4-mini-flash's REAL widths (hidden 2560,
+    FFN 10240, d_inner 5120, 40 / 20 heads of 64, window 512) and the
+    benchmark's pools, from shapes alone: the engine is a tiny one told
+    the real configuration (its builder reads nothing else of the
+    model), the parameters are the real model's ShapeDtypeStructs.
+    Twelve layers unless told: (Mamba, window) x 3, Mamba, full,
+    (memory unit, cross) x 2, every kind and both scans."""
+    import dataclasses
+
+    from paddle_tpu.inference import LLMEngine
+    from paddle_tpu.inference.sampling import samp_structs
+    from paddle_tpu.models.phi4flash import (Phi4FlashConfig,
+                                             Phi4FlashForCausalLM)
+    real = dataclasses.replace(
+        Phi4FlashConfig(), num_hidden_layers=layers, vocab_size=vocab,
+        max_position_embeddings=8192)
+    eng = LLMEngine(
+        Phi4FlashForCausalLM(Phi4FlashConfig.tiny(layers=layers, seq=8192),
+                             dtype="bfloat16"),
+        max_num_seqs=ROWS, block_size=BLOCK, num_blocks=1025,
+        max_model_len=8192, max_prefill_tokens=256, prefill_token_bucket=64,
+        enable_prefix_caching=False)
+    monkeypatch.setattr(eng, "config", real)
+    monkeypatch.setattr(eng, "_attn", real.attention_by_kind())
+    monkeypatch.setattr(eng, "_kvh", real.page_shape()[0])
+    monkeypatch.setattr(eng, "_hd", real.page_shape()[1])
+    monkeypatch.setattr(eng, "_window", real.sliding_window)
+    monkeypatch.setattr(eng, "_platform", "tpu")
+    eng.attention_path = eng._resolve_attention_path()
+    assert eng.attention_path == "pallas"
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: sds(s.shape, s.dtype), Phi4FlashForCausalLM(
+            real, dtype="bfloat16", materialize=False).decode_params())
+    page = (10, BLOCK, 128)
+    conv, scan = real.state_shapes(ROWS + 1)
+    pools = [(1, 16385) + page] * 2 + [(layers // 4, 1569) + page] * 2 \
+        + [conv, scan]
+    i32 = jnp.int32
+    samp = jax.tree_util.tree_map(lambda s: sds(s.shape, s.dtype),
+                                  samp_structs(eng._Lq, vocab))
+    args = (params,) + tuple(sds(p) for p in pools[:5]) \
+        + (sds(pools[5], jnp.float32),) + (
+        sds((ROWS,), i32),                               # batch slots
+        sds((tq,), i32), sds((ROWS + 1,), i32), sds((ROWS,), i32),
+        sds((2, ROWS + 1, 512), i32), sds((eng._Lq,), i32), samp,
+        sds((eng._Lq,), i32), sds((tq,), i32))           # prev, src
+    fn, donate = eng._make_ragged_fn(tq)
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile(), pools
+
+
+@pytest.mark.parametrize("tq", [32, 320])
+def test_the_state_space_step_leaves_pools_and_state_where_they_lie(
+        one_chip, compiled_kernels, no_persistent_cache, monkeypatch, tq):
+    """The step program of the state-space hybrid at the real widths
+    compiles for the v5e (Mosaic takes the scan beside three attention
+    launches), and nothing shaped as one of its page pools or as the
+    scan's state, or as one layer of either, is made but by the
+    ``kv_write`` scatters, the kernels (the state comes back from the
+    scan's launch, aliased) and the moves into and out of fast memory
+    that the compiler schedules itself round a loop (``copy-start`` /
+    ``copy-done``, ``slice-start`` / ``slice-done``: at this test's
+    twelve layers the state is 43 MB and the compiler keeps it in fast
+    memory; at the model's 32 it is 97 MB, stays in HBM, and the
+    compiled program, 10.02 GB of arguments and 0.11 GB of temporaries,
+    has none of these: PERF.md section 6, PR 42).  The convolution's
+    tails (9 MB) the compiler does move into fast memory and back, at
+    any depth, and they are not held to this.  Every launch has
+    its name and takes the array of ALL its layers: the full layer's
+    and the cross layers' read the ONE layer's pool."""
+    from paddle_tpu.inference import layer_stack as ls
+    from paddle_tpu.ops.pallas import selective_scan as ss
+    compiled, pools = _phi4flash_step(one_chip, monkeypatch, tq)
+    text = compiled.as_text()
+    _, comps = _computations(text)
+    big = [pools[0], pools[2], pools[5]]
+    shaped = {s for p in big for s in (p, p[1:], (1,) + p[1:])}
+
+    def writes_rows(line):
+        return " scatter(" in line and "kv_write/" in line
+
+    large = []
+    for lines in comps.values():
+        for line in lines:
+            m = _RESULT.match(line)
+            if m and tuple(int(n) for n in m.group(2).split(",")
+                           if n) in shaped:
+                large.append((m.group(3), line))
+    moved = [line for op, line in large
+             if op not in ("parameter", "get-tuple-element", "bitcast",
+                           "custom-call", "copy-start", "copy-done",
+                           "slice-start", "slice-done")
+             and not writes_rows(line)
+             and not (op == "fusion" and any(
+                 writes_rows(l) for c in _CALLED.findall(line)
+                 for l in comps[c]))]
+    assert not moved, moved[0][:300]
+    calls = [line for lines in comps.values() for line in lines
+             if " custom-call(" in line]
+
+    def named(name):
+        return [c for c in calls if re.match(
+            rf"\s+(?:ROOT\s+)?%?{name}(?:\.\d+)? = ", c)]
+
+    def shape_of(pool, dt="bf16"):
+        return "{}[{}]".format(dt, ",".join(map(str, pool)))
+
+    # one traced copy a kind: the window layers' launch and the Mamba
+    # layers' scan once in their scan, the cross layers' once in theirs,
+    # the full layer's and layer L/2's scan once each
+    for name, n, pool, dt in (
+            ("ragged_paged_attention", 1, pools[0], "bf16"),
+            (pa.WINDOW_KERNEL_NAME, 1, pools[2], "bf16"),
+            (ls.CROSS_KERNEL_NAME, 1, pools[0], "bf16"),
+            (ss.KERNEL_NAME, 2, pools[5], "f32")):
+        mine = named(name)
+        assert len(mine) == n, (name, len(mine))
+        assert all(shape_of(pool, dt) in c for c in mine), name
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 256 << 20

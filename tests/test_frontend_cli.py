@@ -254,6 +254,53 @@ def test_cli_serves_the_indexed_latent_preset():
         srv.kill()
 
 
+def test_cli_serves_the_state_space_hybrid_preset():
+    """``--model phi4flash-sm``: Mamba-1 layers with a state a sequence
+    beside the pages, differential attention under a window and over
+    all, memory units and cross layers on one layer's K/V, through the
+    same CLI, engine and HTTP path: a completion whose prompt is longer
+    than the window and a chunk, then a second one in the slot the
+    first left."""
+    srv = _Server("--model", "phi4flash-sm", "--dtype", "float32",
+                  "--max-num-seqs", "1", "--max-prefill-tokens", "64",
+                  "--no-prefix-caching")
+    try:
+        port = srv.port()
+        assert "attention='xla-reference (cpu platform)'" in srv.output()
+        got = []
+        for n in (150, 9):
+            conn = http.client.HTTPConnection("127.0.0.1", port,
+                                              timeout=120)
+            body = json.dumps({"prompt": list(range(1, n)),
+                               "max_tokens": 6}).encode()
+            conn.request("POST", "/v1/completions", body=body,
+                         headers={"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            doc = json.loads(resp.read())
+            assert resp.status == 200
+            choice = doc["choices"][0]
+            assert choice["finish_reason"] == "length"
+            assert len(choice["token_ids"]) == 6
+            got.append(choice["token_ids"])
+            conn.close()
+        srv.proc.send_signal(signal.SIGINT)
+        assert srv.proc.wait(timeout=60) == 0
+    finally:
+        srv.kill()
+
+
+def test_cli_refuses_prefix_caching_over_a_state_by_name():
+    srv = _Server("--model", "phi4flash-sm", "--dtype", "float32",
+                  "--max-num-seqs", "4")
+    try:
+        assert srv.proc.wait(timeout=120) != 0
+        assert "enable_prefix_caching=True is not supported for a model " \
+            "with state-space layers" in srv.output()
+        assert "snapshot of the state" in srv.output()
+    finally:
+        srv.kill()
+
+
 def test_cli_refuses_int8_pages_over_latent_and_index_pools_by_name():
     srv = _Server("--model", "dots3-sm", "--dtype", "float32",
                   "--max-num-seqs", "4", "--no-prefix-caching",

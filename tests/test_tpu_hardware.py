@@ -969,3 +969,118 @@ def test_indexer_and_selected_keys_on_tpu():
     print(f"selected-keys latent kernel: max abs err {err:.3e}")
     assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
     assert err < 3e-2, err
+
+
+# -- the state-space hybrid's kernels (models/phi4flash.py) -----------------
+
+@pytest.mark.parametrize("tq,lens", [
+    (192, [128] + [1] * 30 + [0]), (32, [1] * 29 + [0, 0, 0]),
+    (64, [0, 40, 1, 0] + [0] * 28)])
+def test_selective_scan_kernel_on_tpu(tq, lens):
+    """The ragged selective scan at Phi-4-mini-flash's widths (d_inner
+    5120, 16 states, nine layers' state of 33 slots): a 128-row chunk
+    beside 30 decode rows, a decode-only launch, rows of no tokens and
+    padding, against the XLA form in float32; slots nobody names and
+    the other layers' states stay as they were; the launch is timed."""
+    from paddle_tpu.ops.pallas import selective_scan as SS
+
+    di, n, L, S = 5120, 16, 9, 33
+    rng = np.random.RandomState(5)
+    R = len(lens)
+    cu = np.zeros(R + 1, np.int32)
+    cu[1:] = np.cumsum(lens)
+    real = rng.permutation(S - 1)[:R]
+    slots = np.where(np.asarray(lens) > 0, real, S - 1).astype(np.int32)
+    start = (rng.rand(R) < 0.3)
+    f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)
+    args = (f(tq, di), jax.nn.softplus(f(tq, di) - 3.0),
+            -jnp.exp(0.3 * f(n, di)), f(tq, n), f(tq, n), f(di))
+    state = f(L, S, n, di)
+    tail = (2, jnp.asarray(slots), jnp.asarray(cu), jnp.asarray(start))
+    assert SS.ineligible(di, n) is None
+    kern = jax.jit(lambda *a: SS.selective_scan(*a, use_kernel=True))
+    ref = jax.jit(lambda *a: SS.selective_scan_reference(*a))
+    y, s1 = kern(*args, state, *tail)
+    y0, s0 = ref(*args, state, *tail)
+    live = int(cu[-1])
+    err = float(jnp.max(jnp.abs(y[:live] - y0[:live])))
+    scale = float(jnp.max(jnp.abs(y0[:live])))
+    assert err <= 2e-3 * max(scale, 1.0), (err, scale)
+    assert not np.asarray(y[live:]).any()
+    used = sorted(set(slots[np.asarray(lens) > 0].tolist()))
+    np.testing.assert_allclose(np.asarray(s1[2, used]),
+                               np.asarray(s0[2, used]), rtol=2e-3,
+                               atol=2e-3)
+    idle = sorted(set(range(S - 1)) - set(used))
+    np.testing.assert_array_equal(np.asarray(s1[2, idle]),
+                                  np.asarray(state[2, idle]))
+    np.testing.assert_array_equal(np.asarray(s1)[[0, 1, 3, 8]],
+                                  np.asarray(state)[[0, 1, 3, 8]])
+    ms = _timed(kern, *args, state, *tail)
+    print(f"selective scan tq={tq} rows={live}: {ms:.3f} ms a layer")
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_differential_attention_widened_on_tpu(window):
+    """Differential attention as the engine computes it (40 query heads
+    of 64 widened with zeros to 128 over 10 cached heads of 128, the
+    ragged kernel at scale 1/8) against its plain two-map form (20 key
+    and value heads of 64, a softmax a query head), at the served pool
+    shapes: a 128-token chunk resumed at 1,428 keys, decode rows under,
+    at and past the window."""
+    from paddle_tpu.models.phi4flash import widen
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    nh, kvh, hd, bs, nblk, nb = 40, 20, 64, 16, 512, 3000
+    rows = [(128, 1556), (1, 1), (0, 0), (1, 512), (1, 513), (1, 6000),
+            (1, 300), (5, 40)]
+    rng = np.random.RandomState(3)
+    Tq = 192
+    cu = np.zeros(33, np.int32)
+    kvl = np.zeros(32, np.int32)
+    bt = np.zeros((33, nblk), np.int32)
+    free = iter(rng.permutation(np.arange(1, nb)))
+    for r, (n, k) in enumerate(rows):
+        cu[r + 1] = cu[r] + n
+        kvl[r] = k
+        first = 0 if window is None else max(0, k - n - window + 1) // bs
+        for p in range(first, -(-k // bs)):
+            bt[r, p] = next(free)
+    cu[len(rows) + 1:] = cu[len(rows)]
+    q = jnp.asarray(rng.randn(Tq, nh, hd), jnp.bfloat16)
+    kc = jnp.asarray(rng.randn(1, nb, kvh // 2, bs, 2 * hd), jnp.bfloat16)
+    vc = jnp.asarray(rng.randn(1, nb, kvh // 2, bs, 2 * hd), jnp.bfloat16)
+    kc, vc = kc.at[:, 0].set(100.0), vc.at[:, 0].set(100.0)
+    fn = jax.jit(lambda q, kc, vc, bt, cu, kvl:
+                 PA.ragged_paged_attention_packed(
+                     widen(q), kc, vc, bt, cu, kvl, layer=0, window=window,
+                     sm_scale=hd ** -0.5,
+                     name="ragged_paged_attention_cross"))
+    out = np.asarray(fn(q, kc, vc, jnp.asarray(bt), jnp.asarray(cu),
+                        jnp.asarray(kvl)).astype(jnp.float32))
+    worst = 0.0
+    with jax.default_matmul_precision("highest"):
+        for r, (n, k) in enumerate(rows):
+            if n == 0:
+                continue
+            pages = jnp.asarray(bt[r, :-(-k // bs)])
+            # rows in order, as 20 heads of 64
+            kr = kc[0][pages].astype(jnp.float32).transpose(
+                0, 2, 1, 3).reshape(-1, kvh, hd)[:k]
+            vr = vc[0][pages].astype(jnp.float32).transpose(
+                0, 2, 1, 3).reshape(-1, kvh // 2, 2 * hd)[:k]
+            pos = k - n + jnp.arange(n)
+            key = jnp.arange(k)
+            see = key[None, :] <= pos[:, None]
+            if window is not None:
+                see &= key[None, :] > pos[:, None] - window
+            qr = q[cu[r]:cu[r] + n].astype(jnp.float32)
+            for h in range(nh):
+                # head h: map h % 2 of pair h // 2, key head 2j + h % 2
+                j = h // 4
+                sc = qr[:, h] @ kr[:, 2 * j + h % 2].T / np.sqrt(hd)
+                pr = jax.nn.softmax(jnp.where(see, sc, -jnp.inf), -1)
+                vj = jnp.where(see.any(0)[:, None], vr[:, j], 0.0)
+                worst = max(worst, float(jnp.max(jnp.abs(
+                    pr @ vj - out[cu[r]:cu[r] + n, h]))))
+    assert worst <= 3e-2, worst
