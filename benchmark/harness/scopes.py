@@ -167,8 +167,11 @@ def annotate(ops: list, modules: list, launches: list,
     """Give each operation the program it ran in (the module execution
     that contains it), from that program's map its ``scope`` and
     ``has_dot``, and the ``step`` of the launch it ran for: the last
-    ``engine.launch`` annotated before its module began (the pipeline is
-    one deep, so launch k + 1 is annotated after launch k came back)."""
+    ``engine.launch`` annotated before its module began.  The engine
+    keeps one launch ahead (PR 34): launch k + 1 is annotated while
+    launch k runs, and launch k + 2 only once k's result came back,
+    which is after k + 1's module began (the device runs them in turn);
+    so the last annotation before a module began is still its own."""
     out, mi, li = [], 0, -1
     for e in sorted(ops, key=lambda e: e["start_ns"]):
         while mi < len(modules) and modules[mi]["start_ns"] \
@@ -219,9 +222,13 @@ def launch_annotations(ctx) -> list:
 
 
 def whole_steps(launches: list, window: tuple) -> set:
-    """Ids of the launches whose operations all lie inside the window:
-    annotated inside it, and followed inside it by the next annotation
-    (which is made after their result came back)."""
+    """Ids of the launches annotated inside the window and followed
+    inside it by the next annotation.  That next one is made once the
+    launch IN FRONT of this one came back (the engine keeps one launch
+    ahead since PR 34), so this launch's operations begin inside the
+    window, and all lie inside it but, at most, those of the last id
+    here: a step that the window's end may cut, among the hundreds the
+    readers sum over."""
     w0, w1 = window
     inside = [l for l in launches if w0 <= l["start_ns"] < w1]
     return {a["step"] for a, _b in zip(inside, inside[1:])}

@@ -187,18 +187,35 @@ def by_label(events_with_self: list) -> dict:
 def attribute_gaps(gaps: list, host_spans: list, offset_ns: int) -> dict:
     """Idle seconds by what the host was doing: each gap goes to the
     host span (on the device clock after ``offset_ns``) that covers most
-    of it, innermost first; a gap nothing covers goes to "unattributed".
-    host_spans: [{"name", "ts", "dur"}] on the host clock."""
-    out = {}
-    spans = [(s["ts"] + offset_ns, s["ts"] + s["dur"] + offset_ns,
-              s["dur"], s["name"]) for s in host_spans if s["dur"] > 0]
-    for a, b in gaps:
-        best, best_cov, best_dur = "unattributed", 0, None
-        for x, y, d, name in spans:
+    of it; of spans that cover as much the shorter, of spans alike in
+    both the first in ``host_spans``; a span of no length covers
+    nothing; a gap nothing covers goes to "unattributed".
+    host_spans: [{"name", "ts", "dur"}] on the host clock.
+
+    One walk of the gaps and the spans, each in order of start: a span
+    is taken up once its start lies before a gap's end and dropped for
+    good once its end lies at or before a gap's start, since no later
+    gap starts earlier.  So a gap is held against the spans open round
+    it (the engine thread's nest two or three deep) and not against
+    every span of the run.  The sums are made in the order the gaps
+    were given, as if each had been held against all."""
+    spans = sorted((s["ts"] + offset_ns, s["ts"] + s["dur"] + offset_ns,
+                    s["dur"], i, s["name"])
+                   for i, s in enumerate(host_spans) if s["dur"] > 0)
+    names = ["unattributed"] * len(gaps)
+    live, nxt = [], 0
+    for g in sorted(range(len(gaps)), key=lambda g: gaps[g][0]):
+        a, b = gaps[g]
+        while nxt < len(spans) and spans[nxt][0] < b:
+            live.append(spans[nxt])
+            nxt += 1
+        live = [s for s in live if s[1] > a]
+        best = None                   # (-covered, dur, place in host_spans)
+        for x, y, d, i, name in live:
             cov = min(b, y) - max(a, x)
-            if cov <= 0:
-                continue
-            if cov > best_cov or (cov == best_cov and d < best_dur):
-                best, best_cov, best_dur = name, cov, d
-        out[best] = out.get(best, 0) + (b - a)
+            if cov > 0 and (best is None or (-cov, d, i) < best):
+                best, names[g] = (-cov, d, i), name
+    out = {}
+    for (a, b), name in zip(gaps, names):
+        out[name] = out.get(name, 0) + (b - a)
     return out

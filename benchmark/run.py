@@ -314,15 +314,27 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic_file: str,
             dev["busy_s"] = ctx["trace"]["busy_s"]
             dev["window_s"] = ctx["trace"]["window_s"]
             breakdown = ctx["trace"]["breakdown"]
+        reader_s = {}
         for m in spec.metrics_for(bench, "per_layer", cell["name"]):
+            t_reader = time.monotonic()
             try:
                 v = spec.load_reader(m["name"])(ctx)
             except Exception as e:
                 say(f"[bench] reader {m['name']} failed: "
                     f"{type(e).__name__}: {e}")
                 v = None
+            reader_s[m["name"]] = time.monotonic() - t_reader
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
+        # what the traced run paid to read its trace: the first reader
+        # of the scoped events pays for reading them (kept in ctx)
+        slowest = max(reader_s, key=reader_s.get, default=None)
+        print("[bench] reduction", json.dumps(
+            {**(ctx["trace"] or {}).get("cost", {}),
+             "readers_s": sum(reader_s.values()), "readers": len(reader_s),
+             "slowest_reader": [slowest, reader_s.get(slowest)],
+             "process_s": (time.monotonic_ns() - T_START_NS) / 1e9}),
+            file=sys.stderr, flush=True)
 
     for n in numbers:
         print(f"[bench] compared {n['name']} = {n['value']!r} "
@@ -340,8 +352,31 @@ def run_cell(bench: dict, cell: dict, cfg: dict, traffic_file: str,
     return result
 
 
+def _engine_spans(host_spans: list, t0: int, t1: int) -> list:
+    """The engine thread's spans that an idle gap of the traced window
+    (``t0`` to ``t1`` on the host clock) can go to, in the order they
+    have in ``host_spans``: not the wrappers round a whole turn, and not
+    a span that ends before the window or begins after it, which covers
+    no gap inside."""
+    return [s for s in host_spans if s["ph"] == "X"
+            and s["name"].startswith("engine.")
+            and s["name"] not in ("engine.step", "engine.dispatch",
+                                  "engine.complete",
+                                  "engine.device_inflight")
+            and s["ts"] < t1 and s["ts"] + s["dur"] > t0]
+
+
 def _reduce_trace(prof: _Profile, cfg: dict, host_spans: list) -> dict:
+    """``cost``, beside what the readers read: the seconds each part of
+    the reduction took and the sizes of the two lists it walked, for the
+    ``[bench] reduction`` line."""
     from harness import xplane as X
+
+    stamps = [time.monotonic()]
+
+    def lap() -> float:
+        stamps.append(time.monotonic())
+        return stamps[-1] - stamps[-2]
 
     data = X.read_planes(X.find_xplane(prof.dir))
     planes = X.device_plane_names(data)
@@ -352,6 +387,7 @@ def _reduce_trace(prof: _Profile, cfg: dict, host_spans: list) -> dict:
     if not events:
         raise RuntimeError(f"no operation on {plane} in the trace")
     mark = X.find_host_marker(data, "bench.mark")
+    cost = {"read_xplane_s": lap()}
     if mark is not None:
         offset = mark - prof.t_mark            # device clock - host clock
     else:
@@ -361,17 +397,18 @@ def _reduce_trace(prof: _Profile, cfg: dict, host_spans: list) -> dict:
     busy = X.busy_ns(evs)
     selfs = X.self_times(evs)
     ops = sorted(X.by_label(selfs).items(), key=lambda kv: -kv[1])
-    engine_spans = [s for s in host_spans if s["ph"] == "X"
-                    and s["name"].startswith("engine.")
-                    and s["name"] not in ("engine.step", "engine.dispatch",
-                                          "engine.complete",
-                                          "engine.device_inflight")]
+    cost["clip_busy_self_s"] = lap()
+    engine_spans = _engine_spans(host_spans, prof.t0, prof.t1)
     gaps = X.idle_gaps(evs, w0, w1)
+    cost["idle_gaps_s"] = lap()
     idle = sorted(X.attribute_gaps(gaps, engine_spans, offset).items(),
                   key=lambda kv: -kv[1])
+    cost.update(attribute_gaps_s=lap(), gaps=len(gaps),
+                spans=len(engine_spans), host_events=len(host_spans),
+                device_events=len(events))
     return {"plane": plane, "events": selfs, "window": (w0, w1),
             "offset_ns": offset, "host_window": (prof.t0, prof.t1),
-            "busy_s": busy / 1e9, "window_s": (w1 - w0) / 1e9,
+            "busy_s": busy / 1e9, "window_s": (w1 - w0) / 1e9, "cost": cost,
             "breakdown": {
                 "device_ops": [[k, v / 1e9] for k, v in ops[:10]],
                 "idle_gaps": [[k, v / 1e9] for k, v in idle[:10]]}}

@@ -61,7 +61,7 @@ def test_the_new_files_are_found_by_name(cfg):
         assert callable(spec.load_reader(name))
     for m in bench["per_layer"]:
         if m["name"] in NEW_READERS:
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]     # later cells list them too
             assert m["moves"] == "out_tokens_per_s"
 
 
