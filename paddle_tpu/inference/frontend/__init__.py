@@ -8,8 +8,8 @@ The package that turns ``LLMEngine`` into a server:
   disconnect-abort, graceful drain.
 - ``runner.EngineRunner`` — the thread bridge: one dedicated thread
   steps the single-threaded engine; submit/abort cross over via queues
-  drained at step boundaries; tokens stream out through per-request
-  deliver callbacks.
+  drained at step boundaries; a launch's tokens go out to the
+  per-request deliver callbacks in one hand-over (``LoopDelivery``).
 - ``router.ReplicaRouter`` — data-parallel fan-out: D engine replicas
   (each its own runner thread) behind one EngineRunner-shaped facade,
   with prefix-affinity / least-outstanding-tokens / random routing.
@@ -24,9 +24,10 @@ dependency anywhere under this package.
 """
 from .app import BackgroundServer, ServingFrontend, serve_background
 from .router import ReplicaRouter, build_replicas
-from .runner import (EngineRunner, RunnerDraining, RunnerSaturated,
-                     StreamHandle)
+from .runner import (EngineRunner, LoopDelivery, RunnerDraining,
+                     RunnerSaturated, StreamHandle)
 
 __all__ = ["ServingFrontend", "BackgroundServer", "serve_background",
            "EngineRunner", "RunnerSaturated", "RunnerDraining",
-           "StreamHandle", "ReplicaRouter", "build_replicas"]
+           "StreamHandle", "LoopDelivery", "ReplicaRouter",
+           "build_replicas"]

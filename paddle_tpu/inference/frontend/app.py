@@ -53,7 +53,8 @@ from .protocol import (ProtocolError, completion_response, error_body,
                        parse_completion_request, stream_finish_frame,
                        stream_token_frame)
 from .router import ReplicaRouter, build_replicas
-from .runner import EngineRunner, RunnerDraining, RunnerSaturated
+from .runner import (EngineRunner, LoopDelivery, RunnerDraining,
+                     RunnerSaturated)
 
 __all__ = ["ServingFrontend", "BackgroundServer", "serve_background"]
 
@@ -476,14 +477,9 @@ class ServingFrontend:
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
 
-        def deliver(ev, _loop=loop, _q=q):
-            # engine thread -> event loop; a loop torn down mid-flight
-            # (server stopped) must not kill the engine thread
-            try:
-                _loop.call_soon_threadsafe(_q.put_nowait, ev)
-            except RuntimeError:
-                pass
-
+        # engine thread -> event loop: a launch's events for every
+        # stream of this loop cross in one call (runner._take_launch)
+        deliver = LoopDelivery(loop, q.put_nowait)
         prompt = kwargs.pop("prompt")
         try:
             request_id = self.runner.submit(

@@ -227,6 +227,16 @@ def render_metrics(snapshot: dict, *, engines=(),
              "Cumulative bytes of host arrays handed to the jitted "
              "calls of step launches.",
              [(None, s.get("launch_arg_bytes"))])
+    # how a launch's tokens travel: one hand-over a launch where the
+    # consumers' delivery takes a launch, one a token where it does not
+    d.metric("engine_deliver_tokens_total", "counter",
+             "Cumulative tokens the runner handed to their consumers.",
+             [(None, s.get("deliver_tokens"))])
+    d.metric("engine_deliver_handovers_total", "counter",
+             "Cumulative calls that carried tokens and finishes from "
+             "the engine thread to their consumers (one a launch and "
+             "event loop; one an event for a plain callable).",
+             [(None, s.get("deliver_handovers"))])
     d.metric("step_dispatch_seconds", "gauge",
              "Per-step host dispatch duration.",
              [({"quantile": "0.5"}, _ms(s.get("dispatch_ms_p50"))),

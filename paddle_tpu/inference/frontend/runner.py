@@ -11,19 +11,39 @@ engine's state is consistent):
 
     HTTP thread                     engine thread
     -----------                     -------------
-    submit()  ──▶ inbox deque  ──▶  engine.add_request(...)
+    submit()  ──▶ inbox deque  ──▶  engine.add_request(..., sink=)
     abort()   ──▶ abort deque  ──▶  engine.abort(rid, reason)
                                     engine.step()
-    deliver(ev) ◀── on_token/on_finish callbacks (engine thread) ◀──┘
+    put(ev) ... ◀── ONE hand-over ◀── sink(launch), once a commit ◀──┘
 
-Tokens flow OUT through each request's ``deliver`` callable — invoked on
-the engine thread with ("token", tok) / ("finish", RequestOutput)
-events; the HTTP layer passes a closure that trampolines onto its event
-loop (``loop.call_soon_threadsafe``), a sync caller can pass
-``queue.Queue.put_nowait`` directly.  Backpressure is enforced HERE (not
-in the engine): ``submit`` refuses work past ``max_pending``
-(RunnerSaturated → the HTTP layer's 429) and while draining
-(RunnerDraining → 503).
+Tokens flow OUT a LAUNCH at a time.  Every request is admitted under
+the runner's sink (``_take_launch``), which the engine calls ONCE at the
+end of a commit with everything the launch emitted, in row order:
+``[(rid, tokens, output), ...]`` (a finished request's output after its
+last token).  Under ONE hold of the lock the runner checks the
+generation, appends every token to its handle's journal, marks the
+finished handles and hands the events to each request's ``deliver``:
+
+- a ``LoopDelivery(loop, put)`` (what the HTTP layer passes: ``put`` is
+  its stream's ``asyncio.Queue.put_nowait``) names the event loop its
+  consumer lives on; the events of all such consumers of one loop cross
+  in ONE ``loop.call_soon_threadsafe`` whose callback, on the loop,
+  puts each event where it belongs, in order.  One write to the loop's
+  wake-up pipe and one wake-up of its thread a launch, where a call a
+  token made one of each a token (31 a launch in the benchmark's cells,
+  inside the engine's commit);
+- a plain callable (``queue.Queue.put_nowait`` of a sync caller, the
+  router's settling wrapper) is called an event at a time, with
+  ("token", int) events and exactly one terminal
+  ("finish", RequestOutput).
+
+A terminal event made outside a step (an abort or a deadline between
+steps, a failed admission, recovery giving up) is handed over at once.
+``summary()`` counts ``deliver_tokens`` and ``deliver_handovers`` (calls
+into the consumers' deliveries) beside ``launches``.  Backpressure is
+enforced HERE (not in the engine): ``submit`` refuses work past
+``max_pending`` (RunnerSaturated → the HTTP layer's 429) and while
+draining (RunnerDraining → 503).
 
 Deadlines are runner-owned: each handle carries an absolute monotonic
 deadline covering queue wait AND generation; the stepping thread sweeps
@@ -46,11 +66,13 @@ cache makes the re-prefill cheap, and because sampling keys derive from
 uninterrupted run.  When a step HANGS past ``step_deadline_s``, a
 watchdog thread performs the same recovery and spawns a replacement
 stepping thread; the wedged thread becomes a zombie that exits at its
-next generation check.  Every token/finish callback is GENERATION-
-guarded under the runner lock — a zombie's late deliveries are dropped
-before they can duplicate or reorder what the client sees — and the
-journal append + guard + delivery happen under that one lock, so the
-recovery snapshot is race-free by construction.  The engine's
+next generation check.  Every sink is bound to its GENERATION and
+checked under the runner lock — a zombie's late launch is dropped
+whole, before it can duplicate or reorder what the client sees — and
+guard + journal append + finished marks + hand-over happen under that
+one hold, a launch at a time, so the recovery snapshot is race-free by
+construction: it never sees a journal that holds a token no client was
+handed.  The engine's
 ServingStats object (and any FaultPlan / DegradationController) carries
 over to the rebuilt engine, so uptime and counters describe the
 SERVICE, not one engine incarnation.
@@ -59,7 +81,7 @@ The async engine pipeline (``LLMEngine(overlap=True)``) needs NOTHING
 new here, by construction: ``engine.step()`` still contains the
 blocking completion of whatever launch it materializes, so the
 watchdog's per-call deadline naturally spans dispatch→completion of a
-ticket, and ``on_token`` fires from ``step()``'s returned outputs —
+ticket, and the sink is called at the end of a launch's COMMIT —
 i.e. only at COMPLETION boundaries, never for a launch still in
 flight.  A crash mid-pipeline therefore leaves the journal holding
 exactly the tokens of fully completed steps, which is precisely the
@@ -68,6 +90,7 @@ speculatively pre-staged next step die with the old engine.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import threading
@@ -77,8 +100,8 @@ from dataclasses import dataclass, field
 
 _log = logging.getLogger("paddle_tpu.serving")
 
-__all__ = ["EngineRunner", "RunnerSaturated", "RunnerDraining",
-           "StreamHandle"]
+__all__ = ["EngineRunner", "LoopDelivery", "RunnerSaturated",
+           "RunnerDraining", "StreamHandle"]
 
 
 class RunnerSaturated(RuntimeError):
@@ -87,6 +110,40 @@ class RunnerSaturated(RuntimeError):
 
 class RunnerDraining(RuntimeError):
     """Server is draining — no new work (HTTP 503)."""
+
+
+class LoopDelivery:
+    """A ``deliver`` whose consumer lives on an asyncio event loop:
+    ``put(event)`` is run ON ``loop``.  Called with one event it crosses
+    at once.  The runner, which sees ``loop``, gathers what a launch
+    holds for the consumers of one loop and crosses ONCE for all of them
+    (``hand_over``): one write to the loop's wake-up pipe and one
+    wake-up of its thread a launch, where a call an event makes one of
+    each a token."""
+
+    __slots__ = ("loop", "put")
+
+    def __init__(self, loop, put):
+        self.loop = loop
+        self.put = put
+
+    def __call__(self, event) -> None:
+        self.hand_over(self.loop, ((self.put, event),))
+
+    @staticmethod
+    def hand_over(loop, batch) -> None:
+        """Run ``put(event)`` for every pair of ``batch``, in order, on
+        ``loop``.  A loop torn down mid-flight (server stopped) must not
+        kill the engine thread."""
+        try:
+            loop.call_soon_threadsafe(_put_all, batch)
+        except RuntimeError:
+            pass
+
+
+def _put_all(batch) -> None:
+    for put, event in batch:
+        put(event)
 
 
 @dataclass
@@ -100,7 +157,7 @@ class StreamHandle:
     done: bool = False
     t_submit: float = field(default_factory=time.monotonic)
     # recovery journal: every token delivered so far.  Appended under
-    # the runner lock by the generation-guarded on_token closure; a
+    # the runner lock by the generation-guarded sink (_take_launch); a
     # rebuilt engine replays the request as a continuation of exactly
     # this list.
     emitted: list = field(default_factory=list)
@@ -171,6 +228,7 @@ class EngineRunner:
         # dead — drop everything and exit".
         self._gen = 0
         self._restarts = 0
+        self._sink = None         # the live generation's: _admit_one
         # (generation, t_start) of the step currently executing, or None
         # between steps.  Generation-tagged so a zombie's cleanup cannot
         # clear the replacement thread's timer.
@@ -307,72 +365,126 @@ class EngineRunner:
     # engine thread
     # ------------------------------------------------------------------
 
-    def _finish_handle(self, h, out, gen: int | None = None) -> None:
-        # engine thread only.  ``gen`` guards a stale engine's finish:
-        # after a rebuild the replacement owns the handle, so the old
-        # engine's terminal event must be dropped, not delivered.
-        with self._lock:
-            if gen is not None and gen != self._gen:
-                return
-            if h.done:
-                return
-            h.done = True
-            self._handles.pop(h.request_id, None)
-            if h.rid >= 0:
-                self._by_rid.pop(h.rid, None)
-            self._inflight -= 1
+    def _close(self, h) -> None:  # guarded-by: _lock
+        """The books of a handle's terminal event."""
+        h.done = True
+        self._handles.pop(h.request_id, None)
+        if h.rid >= 0:
+            self._by_rid.pop(h.rid, None)
+        self._inflight -= 1
+
+    def _vote_deadline(self, h, out) -> None:
         if h.deadline is not None:
             # deadline attainment: only deadline-carrying requests vote
             self.engine.stats.record_deadline(
                 getattr(out, "finish_reason", None) != "deadline"
                 and time.monotonic() <= h.deadline)
+
+    def _finish_handle(self, h, out) -> None:
+        # a terminal event made OUTSIDE a step (a failed admission, an
+        # abort of a request the engine never saw, recovery giving up):
+        # handed over at once
+        with self._lock:
+            if h.done:
+                return
+            self._close(h)
+        self._vote_deadline(h, out)
+        self.engine.stats.record_delivery(0, 1)
         try:
             h.deliver(("finish", out))
         except Exception:
             pass                      # a dead consumer must not kill the loop
 
-    def _admit_one(self, eng, h, gen: int, generated=None) -> bool:
-        """Admit one handle into ``eng`` with generation-guarded
-        callbacks.  ``generated`` is the recovery journal (continuation
-        replay); None for a first admission."""
+    def _take_launch(self, gen: int, launch) -> None:
+        """The engine's sink: everything one launch emitted for this
+        runner's requests, ``[(rid, tokens, output), ...]`` in row
+        order, at the end of its commit (or one entry at once, for a
+        request the engine ends between steps).
 
-        def _on_token(rid, tok, h=h, g=gen):
-            # guard + journal append + delivery under ONE lock hold:
-            # the recovery snapshot (which bumps _gen under the same
-            # lock before reading h.emitted) can therefore never miss a
-            # delivered token or race a zombie into a duplicate
-            with self._lock:
-                if g != self._gen or h.done:
-                    return
-                h.emitted.append(tok)
+        Guard + journal append + finished marks + hand-over under ONE
+        lock hold: the recovery snapshot (which bumps _gen under the
+        same lock before reading h.emitted) can therefore never miss a
+        delivered token or race a zombie into a duplicate.  Never
+        journal under the lock and hand over outside it: a recovery in
+        between would replay from tokens no client saw.  A launch of
+        another generation's engine is dropped whole.
+
+        Events for a ``LoopDelivery`` are gathered and cross to their
+        event loop in one call a loop; a plain callable is called an
+        event at a time, here."""
+        tr = self._tracer()
+        by_loop: dict = {}            # event loop -> [(put, event), ...]
+        direct = []                   # [(plain callable, event), ...]
+        closed = []
+        tokens = 0
+        with self._lock:
+            if gen != self._gen:
+                return
+            for rid, toks, out in launch:
+                h = self._by_rid.get(rid)
+                if h is None or h.done:
+                    continue
+                send = h.deliver
+                loop = getattr(send, "loop", None)
+                if loop is None:
+                    batch = direct
+                else:
+                    send = send.put
+                    batch = by_loop.get(loop)
+                    if batch is None:
+                        batch = by_loop[loop] = []
+                emitted = h.emitted
+                for tok in toks:
+                    emitted.append(tok)
+                    batch.append((send, ("token", tok)))
+                    if tr is not None:
+                        # the cross-tier join point, one a token: engine
+                        # rid <-> frontend id
+                        tr.instant("runner.deliver",
+                                   track=self._trace_track,
+                                   args={"request_id": h.request_id,
+                                         "rid": rid,
+                                         "tokens": len(emitted)})
+                tokens += len(toks)
+                if out is not None:
+                    self._close(h)
+                    closed.append((h, out))
+                    batch.append((send, ("finish", out)))
+            # counted before anything crosses: a client that reads the
+            # counters on its stream's last frame finds its tokens there
+            self.engine.stats.record_delivery(
+                tokens, len(direct) + len(by_loop))
+            for deliver, ev in direct:
                 try:
-                    h.deliver(("token", tok))
+                    deliver(ev)
                 except Exception:
-                    pass
-            tr = self._tracer()
-            if tr is not None:
-                # the cross-tier join point: engine rid <-> frontend id
-                tr.instant("runner.deliver", track=self._trace_track,
-                           args={"request_id": h.request_id, "rid": rid,
-                                 "tokens": len(h.emitted)})
+                    pass              # a dead consumer must not kill the loop
+            for loop, batch in by_loop.items():
+                LoopDelivery.hand_over(loop, batch)
+        for h, out in closed:
+            self._vote_deadline(h, out)
 
-        def _on_finish(out, h=h, g=gen):
-            self._finish_handle(h, out, gen=g)
-
+    def _admit_one(self, eng, h, gen: int, generated=None) -> bool:
+        """Admit one handle into ``eng`` under this generation's sink.
+        ``generated`` is the recovery journal (continuation replay);
+        None for a first admission."""
+        sink = self._sink
+        if sink is None or sink.args != (gen,):
+            # ONE object a generation: the engine calls each sink once a
+            # launch, and tells them apart by identity
+            sink = self._sink = functools.partial(self._take_launch, gen)
         params = dict(h.params)
         prompt = params.pop("prompt")
         if generated is not None:
             params["generated"] = list(generated)
         try:
-            rid = eng.add_request(prompt, on_token=_on_token,
-                                  on_finish=_on_finish, **params)
+            rid = eng.add_request(prompt, sink=sink, **params)
         except Exception as e:
             from ..serving import RequestOutput
             self._finish_handle(h, RequestOutput(
                 rid=-1, prompt=list(prompt), generated=list(h.emitted),
                 finish_reason=f"error: {type(e).__name__}: {e}"))
             return False
-        h.rid = rid
         fl = getattr(eng, "flight", None)
         if fl is not None:
             # the same cross-tier join the tracer instants carry:
@@ -384,7 +496,11 @@ class EngineRunner:
                         deadline_s=None if h.deadline is None
                         else h.deadline - time.monotonic())
         with self._lock:
-            self._by_rid[rid] = h
+            # a zombie that admits after its engine was replaced must
+            # not take a rid of the replacement's
+            if gen == self._gen:
+                h.rid = rid
+                self._by_rid[rid] = h
         return True
 
     def _admit_inbox(self, gen: int) -> int:
@@ -410,7 +526,7 @@ class EngineRunner:
             if h is None or h.done:
                 continue
             if h.rid >= 0:
-                # engine.abort fires on_finish -> _finish_handle
+                # engine.abort hands the finish to the sink at once
                 self.engine.abort(h.rid, finish_reason=reason)
             else:
                 # never reached the engine: synthesize the terminal event
@@ -451,6 +567,7 @@ class EngineRunner:
             # the journal snapshot: taken AFTER the generation bump, so
             # no old-generation callback can append past this point
             replay = [(h, list(h.emitted)) for h in live if h.rid >= 0]
+            self._by_rid.clear()      # the dead engine's rids
             requeue = [h for h in live
                        if h.rid < 0 and h not in self._inbox]
         old = self.engine

@@ -139,9 +139,12 @@ class Request:
     spec_accepted: int = 0            # drafts accepted (lifetime)
     spec_disabled: bool = False       # acceptance fell below the floor
     tier_checked: int = -1            # spill-tier generation last consulted
-    # streaming hooks (both called from the engine's stepping thread)
+    # streaming hooks (called from the engine's stepping thread): the
+    # two per-request callbacks, or ONE launch-level sink that takes
+    # everything a commit emitted in one call (``LLMEngine._emit``)
     on_token: object = None           # callable(rid, token) per emission
     on_finish: object = None          # callable(RequestOutput) at the end
+    sink: object = None               # callable([(rid, tokens, output)])
 
 
 @dataclass
@@ -1042,6 +1045,7 @@ class LLMEngine:
         self.tracer = None
         self._blocked_ns = 0      # inside _complete's block, this step()
         self._split = None        # the commit under way, by what a row calls
+        self._emits = None        # ... and by sink, what it has emitted
         self._trace_track = "engine"
         self._commit_step = 0         # the ticket's id while it commits
         self._cow_n = 0               # CoW launches and their host time
@@ -1425,8 +1429,17 @@ class LLMEngine:
                     seed: int = 0, top_k: int = 0, top_p: float = 1.0,
                     repetition_penalty: float = 1.0,
                     spec_k: int | None = None, generated=None,
-                    on_token=None, on_finish=None) -> int:
+                    on_token=None, on_finish=None, sink=None) -> int:
         """Queue one generation request; returns its rid.
+
+        What the request emits goes to ``on_token(rid, token)`` and
+        ``on_finish(RequestOutput)`` as it is committed, or, where the
+        caller hands in a ``sink`` instead, to ONE call a launch:
+        ``sink([(rid, tokens, output), ...])`` at the end of the commit,
+        with every entry of the requests that share the sink in row
+        order (``tokens`` a row's emissions, ``output`` None; a finished
+        request's ``((), RequestOutput)`` follows its last token).  What
+        ends outside a commit (``abort``) reaches the sink at once.
 
         ``generated`` re-admits a request that already emitted tokens
         (the runner's crash-recovery replay): the request enters exactly
@@ -1472,7 +1485,7 @@ class LLMEngine:
                       top_k=int(top_k), top_p=float(top_p),
                       repetition_penalty=float(repetition_penalty),
                       spec_k=spec_k, t_arrival=time.perf_counter(),
-                      on_token=on_token, on_finish=on_finish)
+                      on_token=on_token, on_finish=on_finish, sink=sink)
         if req.repetition_penalty != 1.0:
             req.seen = np.zeros((self.config.vocab_size,), bool)
             req.seen[prompt] = True
@@ -1578,14 +1591,38 @@ class LLMEngine:
             tr.async_end("req", f"{self._trace_track}:{req.rid}",
                          args={"finish_reason": finish_reason,
                                "generated": len(req.generated)})
-        if req.on_finish is not None:
-            req.on_finish(out)
+        self._notify_finish(req, out)
         return out
 
     def _notify_tokens(self, req, toks) -> None:
-        if req.on_token is not None:
+        if req.sink is not None:
+            self._emit(req, [int(t) for t in toks], None)
+        elif req.on_token is not None:
             for t in toks:
                 req.on_token(req.rid, int(t))
+
+    def _notify_finish(self, req, out) -> None:
+        if req.sink is not None:
+            self._emit(req, (), out)
+        elif req.on_finish is not None:
+            req.on_finish(out)
+
+    def _emit(self, req, toks, out) -> None:
+        """File one entry for ``req``'s sink: with the launch being
+        committed, whose sinks ``_hand_over`` calls once each at the
+        commit's end, or, outside a commit (an abort), at once."""
+        entry = (req.rid, toks, out)
+        if self._emits is None:
+            req.sink([entry])
+        else:
+            self._emits.setdefault(req.sink, []).append(entry)
+
+    def _hand_over(self) -> None:
+        """The end of a commit: every sink gets what the launch emitted
+        for its requests, in row order, in ONE call."""
+        emits, self._emits = self._emits, None
+        for sink, launch in emits.items():
+            sink(launch)
 
     def _row_calls(self) -> tuple:
         """What a committed row calls: (the stream callbacks, the retire
@@ -1593,7 +1630,8 @@ class LLMEngine:
         token).  ``_split``, set by the commit under way, is None (no
         tracer: the bare methods, so a row pays nothing for the split)
         or a list whose entries grow by the nanoseconds inside them: [0]
-        the callbacks, [1] both cache commits, [2] the retire check
+        the callbacks (and, at the commit's end, the sinks' hand-over),
+        [1] both cache commits, [2] the retire check
         (``engine.sample_commit``'s ``notify_ns``, ``cache_ns``,
         ``retire_ns``)."""
         calls = (self._notify_tokens, self._maybe_retire,
@@ -2506,6 +2544,7 @@ class LLMEngine:
         # where the commit's time goes, by what a row calls (a tracer
         # installed; else the rows call the bare methods: _row_calls)
         split = self._split = None if tr is None else [0, 0, 0]
+        self._emits = {}
         if ticket.window:
             self._apply_window(batch, batch_slots, sampled, ok, dur,
                                finished, ticket.window,
@@ -2521,6 +2560,11 @@ class LLMEngine:
             for name, n in counted.items():
                 mc[name] = max(mc[name], n) if name == "moe_load_max" \
                     else mc[name] + n
+        # delivery is the commit's: commit_ns and notify_ns hold it whole
+        t_h = time.perf_counter_ns()
+        self._hand_over()
+        if split is not None:
+            split[0] += time.perf_counter_ns() - t_h
         self.stats.record_commit(time.perf_counter_ns() - t)
         if tr is not None:
             commit_args = {"step": sid, "finished": len(finished),
@@ -2877,8 +2921,7 @@ class LLMEngine:
                        args={"rid": req.rid, "step": self._commit_step})
             tr.async_end("req", f"{self._trace_track}:{req.rid}",
                          args={"finish_reason": "numerical_error"})
-        if req.on_finish is not None:
-            req.on_finish(out)
+        self._notify_finish(req, out)
 
     # ------------------------------------------------------------------
     # hierarchical KV tier (host-DRAM spill pool, inference/kv_tier.py)
@@ -3266,8 +3309,7 @@ class LLMEngine:
             tr.async_end("req", f"{self._trace_track}:{req.rid}",
                          args={"finish_reason": reason,
                                "generated": len(req.generated)})
-        if req.on_finish is not None:
-            req.on_finish(out)
+        self._notify_finish(req, out)
 
     # ------------------------------------------------------------------
     # speculative decoding: propose -> verify -> accept/rollback

@@ -239,6 +239,13 @@ class ServingStats:
         self.launch_call_ns = 0
         self.commit_ns = 0
         self.launch_arg_bytes = 0
+        # how a launch's tokens reach their streams (the frontend's
+        # runner counts): tokens handed to consumers, and the calls that
+        # carried them and their finishes across to the consumers'
+        # threads.  Over launches: one hand-over where a launch crosses
+        # whole, one a token where each crosses alone
+        self.deliver_tokens = 0
+        self.deliver_handovers = 0
         # device-resident decode-window surface (PR 16): how often the
         # host actually blocked on the device, and how many tokens each
         # block drained — the round-trip amortization the K-step window
@@ -380,6 +387,12 @@ class ServingStats:
         """One launch's commit: applying its rows (cache commit, stream
         callbacks, retirement) and reading its expert counts."""
         self.commit_ns += commit_ns
+
+    def record_delivery(self, tokens: int, handovers: int) -> None:
+        """Tokens handed to their consumers, and the calls into the
+        consumers' deliveries that carried them (and the finishes)."""
+        self.deliver_tokens += tokens
+        self.deliver_handovers += handovers
 
     def record_round_trip(self, n: int = 1) -> None:
         """One host<->device completion block: the host materialized a
@@ -728,6 +741,8 @@ class ServingStats:
             "launch_call_time_s": round(self.launch_call_ns / 1e9, 6),
             "commit_time_s": round(self.commit_ns / 1e9, 6),
             "launch_arg_bytes": self.launch_arg_bytes,
+            "deliver_tokens": self.deliver_tokens,
+            "deliver_handovers": self.deliver_handovers,
             "dispatch_ms_p50": round(1e3 * self._dispatch_lat.percentile(50), 3),
             "dispatch_ms_p99": round(1e3 * self._dispatch_lat.percentile(99), 3),
             "block_ms_p50": round(1e3 * self._block_lat.percentile(50), 3),

@@ -330,13 +330,15 @@ def test_every_engine_span_and_request_instant_carries_a_known_step(model):
 
 
 def test_a_launch_leaves_four_engine_spans_for_the_idle_attribution(model):
-    """The benchmark's trace reduction holds every idle gap of the
-    device against every engine span of the run that is not a wrapper
-    (``harness/xplane.py`` ``attribute_gaps``: about 0.1 s of a traced
-    run for each span, PERF.md section 7), so what a launch leaves there
-    is a budget: schedule (the packing with it), device launch, block on
-    result, sample commit; admission and retirement only where there was
-    one."""
+    """The benchmark's trace reduction names every idle gap of the
+    device after the engine span of the run, not a wrapper, that it
+    falls in (``harness/xplane.py`` ``attribute_gaps``: one walk of the
+    two sorted lists since PR 44, microseconds a span; until then a
+    double loop, about 0.1 s of a traced run for each span, PERF.md
+    sections 5 and 7), and its metrics name these spans: so what a
+    launch leaves there is held: schedule (the packing with it), device
+    launch, block on result, sample commit; admission and retirement
+    only where there was one."""
     rng = np.random.RandomState(9)
     eng, _tr, sp = _traced_run(
         model, [(rng.randint(0, VOCAB, n).tolist(), 12) for n in (20, 7, 9)],
