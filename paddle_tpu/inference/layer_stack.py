@@ -40,9 +40,14 @@ and two block tables: the global layers' ``[Lg, num_blocks, ...]`` under
 ``c.bt`` and the window layers' ``[Lw, Nw, ...]`` under ``c.btw``, where
 a sequence's pages below its window have gone back to the pool.  One
 contract for all, scanned or unrolled: a layer gets the pools of ALL
-layers and its index among the layers that share its pools, scatters the
+layers and its index among the layers that share its pools, writes the
 step's rows in place at (layer, page, slot) and its kernel reads pages
-where they lie at a prefetched layer index.  No layer-sized slice of a
+where they lie at a prefetched layer index.  How the rows get there is
+the kind's and the page type's: the dense decoder's grouped-query
+layers over float pages move whole pages where the kernels run
+(``_commit_float``: the page writer ``kv_page_write``) and scatter rows
+elsewhere, in an unrolled model and over int8 pages (``_set_rows``); a
+latent kind scatters its one row a token (``_put_rows``).  No layer-sized slice of a
 pool is ever made.  A STATE-SPACE HYBRID (``models/phi4flash.py``) has
 six kinds of its own: ``ssm`` / ``ssm_keep`` (a Mamba-1 layer, whose
 state a sequence lives beside the pages by batch slot; the second also
@@ -82,6 +87,7 @@ from ..models import mla_moe as _mm
 from ..models import phi4flash as _ph
 from ..models import smallthinker as _st
 from ..models.llama import _rms_weight
+from ..ops.pallas import kv_page_write as _kw
 from ..ops.pallas import mla_attention as _mla
 from ..ops.pallas import paged_attention as _pa
 from ..ops.pallas import selective_scan as _ss
@@ -93,7 +99,9 @@ def scan_layers(body, x, layers, pools):
     gives ``body(x, p, pools, l) -> (x, pools)`` its layer's weights,
     the WHOLE pools and the layer index, which is what an unrolled
     segment's layers get.  The kinds write and read the pools where they
-    lie, at ``l``: a turn that sliced its layer's pages out and wrote
+    lie, at ``l`` (a page writer or a row scatter, and the kernel: both
+    take the carried buffers as they are): a turn that sliced its
+    layer's pages out and wrote
     them back cost four layer-sized copies of each pool a layer (three
     quarters of a dense step's device time on the v5e).  Scanned through
     as inputs and outputs the pools came back in a new buffer, and
@@ -129,10 +137,15 @@ def step_context(**kw) -> SimpleNamespace:
 # pools of ALL layers, written and read in place at ``layer``
 # ---------------------------------------------------------------------------
 
-# ``gqa`` over either page type: commit(k, v, pools, at, c) -> pools
-# writes the step's rows at ``at`` = (layer, page, slot) into the pools
-# of all layers; attend(q, pools, layer, c) -> [Tq, heads, d] reads that
-# layer's pages where they lie.
+# ``gqa`` over either page type: commit(k, v, pools, layer, bt, c) ->
+# pools writes the step's rows into the pools of all layers at ``layer``,
+# in the pages the table ``bt`` names; attend(q, pools, layer, c) ->
+# [Tq, heads, d] reads that layer's pages where they lie.
+
+def _row_at(c, bt):
+    """(page [Tq], slot [Tq]) of the step's rows under table ``bt``."""
+    return bt[c.seg, c.rel // c.bs], c.rel % c.bs
+
 
 def _set_rows(pool, at, rows):
     """``rows`` [Tq, Hkv, D] into ``pool`` [L, num_blocks, Hkv, bs, D] at
@@ -141,7 +154,12 @@ def _set_rows(pool, at, rows):
     ``[L * num_blocks * Hkv * bs, D]``, a bitcast), so the update window
     is the contiguous minor ``D`` and XLA scatters Tq * Hkv rows into
     the donated buffer in place: 0.22 ms a layer for K and V at 192
-    tokens and 8 heads on the v5e, 0.04 at 32 (PERF.md, PR 31).
+    tokens and 8 heads on the v5e, 0.04 at 32 (PERF.md, PR 31).  Who
+    still calls it: the commit over float pages where the kernels do not
+    run (``c.use_pallas`` false: off the chip, or a shape they do not
+    claim) or the layers are unrolled, and the commit over int8 pages;
+    the dense decoder's scanned programs write float pages a page at a
+    time (``_commit_float``).
     ``pool.at[layer, page, :, slot]`` writes the same values, but its
     window [Hkv, D] straddles the slot axis: XLA then keeps the WHOLE
     pool in a slot-major layout through the layer loop and copies all
@@ -156,8 +174,21 @@ def _set_rows(pool, at, rows):
         rows.astype(pool.dtype)).reshape(pool.shape)
 
 
-def _commit_float(k, v, pools, at, c):
+def _commit_float(k, v, pools, layer, bt, c):
+    """In the dense decoder's scanned step programs where the kernels
+    run, the page writer (``ops/pallas/kv_page_write.py``: ONE launch
+    named ``kv_page_write`` for K and V, which reads the row layout
+    itself and moves whole pages, in place); elsewhere the row scatter.
+    An unrolled model keeps the scatter on the chip too: a process
+    traces the writer once a kind of layer in every token bucket's
+    program, 0.23 s a time on the v5e's host, and SmallThinker's ten
+    buckets of two kinds put 5 to 6 s on a set-up of 45 (PERF.md, PR 46);
+    one scanned segment traces it once a bucket."""
     kc, vc = pools
+    if c.use_pallas and c.scanned:
+        return tuple(_kw.kv_page_write(k, v, kc, vc, bt, c.cu, c.kvl,
+                                       layer))
+    at = (layer, *_row_at(c, bt))
     return _set_rows(kc, at, k), _set_rows(vc, at, v)
 
 
@@ -181,7 +212,7 @@ def _attend_float(q, pools, layer, c, bt=None, window=None, sm_scale=None,
         window=window, sm_scale=sm_scale)
 
 
-def _commit_int8(k, v, pools, at, c):
+def _commit_int8(k, v, pools, layer, bt, c):
     """Quantize at commit, per layer, per launch:
     1. zero the scale rows of ``fresh`` pages (pages BlockManager handed
        out since the last launch: their old content AND old scales are
@@ -204,7 +235,8 @@ def _commit_int8(k, v, pools, at, c):
     scatter of the rows; the layer's scale rows (a word a page and head)
     are taken out and put back."""
     kc, vc, ks, vs = pools
-    layer, blk, _ = at
+    blk, slot = _row_at(c, bt)
+    at = (layer, blk, slot)
     ksl, vsl = ks[layer], vs[layer]                       # [num_blocks, kvh]
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -288,9 +320,7 @@ def _gqa(x, h, p, pools, layer, c, kind="gqa"):
             q = rope(q, c.rel)
             k = rope(k, c.rel)
     with jax.named_scope("kv_write"):
-        blk = bt[c.seg, c.rel // c.bs]                    # [Tq]
-        slot = c.rel % c.bs
-        pools = commit(k, v, pools, (layer, blk, slot), c)
+        pools = commit(k, v, pools, layer, bt, c)
     with jax.named_scope(scope):
         att = attend(q, pools, layer, c, **over)
         if tp > 1:
@@ -314,11 +344,6 @@ def _gqa(x, h, p, pools, layer, c, kind="gqa"):
 # (a low-rank query, a window, a headwise gate, an indexer); a kind is a
 # name for the pools its layers keep
 LATENT_KINDS = ("mla", "mla_select", "mla_window")
-
-
-def _row_at(c, bt):
-    """(page [Tq], slot [Tq]) of the step's rows under table ``bt``."""
-    return bt[c.seg, c.rel // c.bs], c.rel % c.bs
 
 
 def _put_rows(pool, layer, blk, slot, rows):
@@ -537,10 +562,8 @@ def _diff(x, h, p, pools, layer, c, kind="diff"):
         q = _ph.widen(q.reshape(Tq, a.nh, a.hd))
     if not cross:
         with jax.named_scope("kv_write"):
-            blk, slot = _row_at(c, bt)
             pools = list(pools)
-            pools[mine] = _commit_float(k, v, pools[mine],
-                                        (layer, blk, slot), c)
+            pools[mine] = _commit_float(k, v, pools[mine], layer, bt, c)
             pools = tuple(pools)
     with jax.named_scope(scope):
         # (the kind a cross layer reads keeps ONE layer: index 0)

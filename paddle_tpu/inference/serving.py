@@ -1006,6 +1006,7 @@ class LLMEngine:
         # (serve_bench --mixed reports the two ratios side by side)
         self.pad_stats = {"real": 0, "padded": 0, "legacy_padded": 0,
                           "kv_pages": 0, "kv_pages_window": 0,
+                          "kv_write_pages": 0, "kv_write_tokens": 0,
                           "index_keys_visible": 0, "index_keys_selected": 0,
                           "state_starts": 0, "state_rows": 0}
         # passes of the sampling epilogue, and those whose launch held a
@@ -1753,6 +1754,12 @@ class LLMEngine:
         out["tokens_real"] = self.pad_stats["real"]
         out["tokens_padded"] = self.pad_stats["padded"]
         out["kv_pages_live"] = self.pad_stats["kv_pages"]
+        # pages the launches' new tokens touched and those tokens, a
+        # layer's: pages a token is what writing them costs where whole
+        # pages move (``kv_page_write``): 1.0 on a decode-only launch,
+        # near 1 / block_size on a chunk
+        out["kv_write_pages"] = self.pad_stats["kv_write_pages"]
+        out["kv_write_tokens"] = self.pad_stats["kv_write_tokens"]
         if self._windowed:
             # pages the launches' rows held in a window layer (against
             # kv_pages_live, what one table for all layers would hold),
@@ -3623,7 +3630,7 @@ class LLMEngine:
         type (inference/layer_stack.py): the dense decoder is one
         scanned segment over its stacked weights, a latent-attention
         model runs layer after layer over its own arrays.  Either way
-        the donated pools hold all layers, a layer scatters its rows
+        the donated pools hold all layers, a layer writes its rows
         into them in place at (layer, page, slot) and its kernel reads
         them at a layer index: no layer-sized slice of a pool is made,
         and the pools that come back are the buffers that went in."""
@@ -3797,8 +3804,9 @@ class LLMEngine:
         # counted once a launch; ``engine.device_launch`` carries the same
         pages = self._launch_pages = self._launch_kv_args(cu, kvl)
         self.pad_stats["kv_pages"] += pages["kv_pages"]
-        for name in ("kv_pages_window", "index_keys_visible",
-                     "index_keys_selected", "state_starts", "state_rows"):
+        for name in ("kv_pages_window", "kv_write_pages", "kv_write_tokens",
+                     "index_keys_visible", "index_keys_selected",
+                     "state_starts", "state_rows"):
             self.pad_stats[name] += pages.get(name, 0)
         self.sample_stats["launches"] += 1
         self.sample_stats["chain_launches"] += _sample_chain(samp)
@@ -3823,6 +3831,18 @@ class LLMEngine:
         walks, of the bucket * nblk page slots of the table."""
         return int((-(-np.asarray(kvl) // self.block_size)).sum())
 
+    def _kv_write(self, cu, kvl) -> dict:
+        """Pages a launch's new tokens touch (each row's, from the page
+        of its first new token to the page of its last) and those
+        tokens: what a layer's ``kv_write`` moves and what it writes."""
+        kvl = np.asarray(kvl)
+        n_q = np.diff(np.asarray(cu))[:len(kvl)]
+        bs = self.block_size
+        touched = np.where(n_q > 0,
+                           (kvl - 1) // bs - (kvl - n_q) // bs + 1, 0)
+        return {"kv_write_pages": int(touched.sum()),
+                "kv_write_tokens": int(n_q.sum())}
+
     def _kv_pages_window(self, cu, kvl) -> int:
         """Pages the same rows hold in a WINDOW layer: from the page of
         the lowest key a row's first query sees to the page of its last
@@ -3837,7 +3857,7 @@ class LLMEngine:
         """The page counts of a launch, as ``engine.device_launch``
         carries them."""
         pages = self._kv_pages(kvl)
-        out = {"kv_pages": pages}
+        out = {"kv_pages": pages, **self._kv_write(cu, kvl)}
         if self._windowed:
             out.update(kv_pages_uniform=pages,
                        kv_pages_window=self._kv_pages_window(cu, kvl))

@@ -61,6 +61,9 @@ def test_a_configurations_architecture_finds_its_three_files(config):
     assert isinstance(arch.LOOP, str)
     assert set(arch.MATMUL_SCOPES + arch.SAMPLE_SCOPES + arch.POOL_SCOPES) \
         <= set(arch.SCOPES)
+    # the page writes first, then the kernels: ``kvwrite.device_share``
+    # reads the first, the scope ``layer_stack`` writes a step's rows under
+    assert arch.POOL_SCOPES[0] == "kv_write"
     names = [(n, at) for n, at, _shape, _kind in arch.leaves(cfg)]
     assert len(set(names)) == len(names)
     assert {k for *_, k in arch.leaves(cfg)} \

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops.pallas import kv_page_write as kw
 from paddle_tpu.ops.pallas import paged_attention as pa
 
 # (query heads, K/V heads, head size) of the benchmark's configurations
@@ -177,6 +178,61 @@ def test_the_int8_page_kernel_compiles_for_the_v5e(one_chip,
     text = jax.jit(pa.ragged_paged_attention_quant_packed).lower(
         *args).compile().as_text()
     assert "ragged_paged_attention_q8" in text
+
+
+@pytest.mark.parametrize("tq,kvh,pages,bs,d,dt", [
+    *[(tq, kvh, pages, BLOCK, 128, jnp.bfloat16)
+      for kvh, pages in ((4, 4097), (8, 4097), (10, 1569))
+      for tq in (32, 192, 576)],
+    # the other pages the attention kernel's claim admits (``pa.
+    # ineligible``: float pages of any multiple of 8 slots, heads of any
+    # multiple of 128): a bf16 page-head of 8 rows is half a packed tile
+    (32, 8, 4097, 8, 128, jnp.bfloat16),
+    (192, 8, 4097, 8, 128, jnp.bfloat16),
+    (192, 8, 2049, 32, 128, jnp.bfloat16),
+    (192, 4, 1025, 24, 128, jnp.bfloat16),
+    (192, 8, 1025, 64, 128, jnp.bfloat16),
+    (192, 4, 2049, 16, 256, jnp.bfloat16),
+    (192, 8, 2049, 8, 128, jnp.float32),
+    (192, 8, 2049, 16, 128, jnp.float32),
+    # ... and the widest table it admits ("table"): the writer's four
+    # scratch lists lie in the scalar memory beside it
+    (576, 8, 4097, 8, 128, "table"),
+])
+def test_the_page_writer_compiles_for_the_v5e(one_chip, compiled_kernels,
+                                              no_persistent_cache, tq, kvh,
+                                              pages, bs, d, dt):
+    """The page writer at the benchmark's pools (Yi's and Mistral's of
+    all eight layers, Phi-4-mini-flash's eight window layers of 10 heads)
+    and at the other page shapes the engine would hand it (it runs
+    wherever the attention kernel does), through the chip's own
+    compiler: the rolled load of a page's rows from whole float32 tiles,
+    the page copies both ways, its scalar memory beside the [33, 256]
+    table.  The donated pools come back in their own buffers and nothing
+    else has their shape."""
+    nblk = NBLK
+    if dt == "table":
+        dt = jnp.bfloat16
+        nblk = max(n for n in range(128, 1 << 15, 128) if pa.ineligible(
+            32, kvh, d, bs, dt, launch=(ROWS + 1, n, pages)) is None)
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (8, pages, kvh, bs, d)
+    text = jax.jit(kw.kv_page_write, donate_argnums=(2, 3)).lower(
+        sds((tq, kvh, d)), sds((tq, kvh, d)), sds(pool), sds(pool),
+        sds((ROWS + 1, nblk), jnp.int32), sds((ROWS + 1,), jnp.int32),
+        sds((ROWS,), jnp.int32), sds((), jnp.int32)).compile().as_text()
+    _, comps = _computations(text)
+    stacked = "{}[{}]".format("bf16" if dt == jnp.bfloat16 else "f32",
+                              ",".join(map(str, pool)))
+    assert len(_page_writer_calls(comps, stacked)) == 1
+    made = [line for lines in comps.values() for line in lines
+            if re.search(rf"= \(?{re.escape(stacked)}", line)
+            and not re.search(r" (?:parameter|custom-call|get-tuple-element"
+                              r"|bitcast|tuple)\(", line)]
+    assert not made, made[0][:300]
 
 
 def test_prefetched_operands_are_what_the_claim_counts(one_chip,
@@ -540,6 +596,35 @@ _RESULT = re.compile(r"\s+(?:ROOT\s+)?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
 
 
 
+def _page_writer_calls(comps: dict, *stacked: str) -> list:
+    """The page writer's custom calls in a compiled program.  Each is
+    held to this: its results are a K and a V pool of ALL layers (one of
+    the ``stacked`` shapes, "bf16[8,4097,8,16,128]"), they are its sixth
+    and seventh operands' buffers (``output_to_operand_aliasing``: the
+    pools are written where they lie; without it XLA would make the
+    results anew and copy a whole pool a layer), and those operands
+    are taken from the loop's carry or the program's parameters as they
+    are, not from a copy."""
+    calls = []
+    for lines in comps.values():
+        made_by = {m.group(1): m.group(3) for m in map(_RESULT.match, lines)
+                   if m}
+        for call in lines:
+            if " custom-call(" not in call or not re.match(
+                    rf"\s+(?:ROOT\s+)?%?{kw.KERNEL_NAME}(?:\.\d+)? = ", call):
+                continue
+            calls.append(call)
+            results, rest = call.split(" custom-call(", 1)
+            assert any(results.count(s) == 2 for s in stacked), results[:300]
+            assert ("output_to_operand_aliasing={{0}: (6, {}), "
+                    "{1}: (7, {})}") in rest
+            operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+            assert len(operands) == 8
+            assert all(made_by[o] in ("parameter", "get-tuple-element")
+                       for o in operands[6:]), operands[6:]
+    return calls
+
+
 def _dense_step_text(chip, monkeypatch, model, tq, quant, hidden=256,
                      ffn=512):
     """(compiled text, the pools' shape) of the engine's ragged step
@@ -610,8 +695,11 @@ def test_the_dense_step_leaves_the_pools_where_they_lie(
         tq, pages):
     """No instruction of the compiled step program makes anything as
     large as one layer of a K/V pool, whatever its shape or layout,
-    but the scatters of scope ``kv_write`` (in place: the program's
-    parameters are donated).  What else may carry a pool's shape moves
+    but the writers of scope ``kv_write``, in place (the program's
+    parameters are donated): over float pages the page writer's ONE
+    custom call a layer, whose two results are the pools of all layers
+    and alias its pool operands (``_page_writer_calls``); over int8
+    pages the scatters.  What else may carry a pool's shape moves
     no byte: parameters, tuple elements, bitcasts.  And the kernel's
     custom call takes the pools of all layers, not a layer's slice.
 
@@ -646,14 +734,17 @@ def test_the_dense_step_leaves_the_pools_where_they_lie(
                  writes_rows(l) for c in _CALLED.findall(line)
                  for l in comps[c]))]
     assert not moved, moved[0][:300]
-    # K's and V's rows, once a layer (over int8 pages: and the launch's
-    # whole pages, re-encoded)
-    assert sum(writes_rows(line) for _, line in large) \
-        == (4 if pages == "int8" else 2)
-    kernel, = [line for lines in comps.values() for line in lines
-               if " custom-call(" in line and "ragged_paged_attention" in line]
     stacked = "{}[{}]".format("s8" if pages == "int8" else "bf16",
                               ",".join(map(str, pool)))
+    # over int8 pages K's and V's rows and the launch's whole pages,
+    # re-encoded, once a layer; over float pages no scatter: K's and
+    # V's pages in the writer's one call a layer
+    assert sum(writes_rows(line) for _, line in large) \
+        == (4 if pages == "int8" else 0)
+    assert len(_page_writer_calls(comps, stacked)) \
+        == (0 if pages == "int8" else 1)
+    kernel, = [line for lines in comps.values() for line in lines
+               if " custom-call(" in line and "ragged_paged_attention" in line]
     assert kernel.split("operand_layout_constraints=")[1].count(stacked) == 2
 
 
